@@ -1,0 +1,937 @@
+#!/usr/bin/env python3
+"""Bring-up proof: the retrieve-then-generate path on a TPU v5e, end to end.
+
+    python chip_smoke.py [--seed N] [--layers N]      # one chip, one process
+    python chip_smoke.py --chips 4 [--seed N]         # the sharded path only
+
+The default run drives the system's main path once through the entry points
+a user calls, at the published widths of Llama-3.1-8B (int8 weights + int8
+KV, the one-chip layout of docs/8B.md) and bge-m3, with weights made on the
+device from ``--seed``:
+
+1. device gate — no accelerator, no run (there is no CPU fallback);
+2. every main-path Pallas kernel, compiled by Mosaic, against its XLA oracle
+   at 8B head geometry;
+3. the default deployment shape (coalesce batching, single-fetch RAG,
+   speculation auto) assembled by ``server.main.assemble_service``, warmed,
+   served by a real werkzeug server over sockets: /healthz, /upload_pdf,
+   /index_info, two solo and four concurrent /generate at the reference
+   budget, /metrics;
+4. the paged continuous shape (block-pool KV sized from free HBM, mixed
+   prefill/decode windows, paged draft-and-verify) on the same params;
+5. checks on 3 and 4: token counts, finite logits, the shadow auditor at
+   sample rate 1.0 inside its pinned tolerance, no executable built while
+   /generate requests were in flight, the Pallas path present in the
+   compiled programs, the block pool drained.
+
+Any failed check raises: the exit code is non-zero and no result line is
+printed. Every earlier stdout line is information (JSON, one per event);
+none of its numbers is a benchmark. The last line is the result.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import gc
+import json
+import logging
+import os
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+import urllib.error
+import urllib.request
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+T_START = time.monotonic()
+NEW_TOKENS = 150  # the reference budget (rag.py:172; deploy.yaml)
+AUDIT_TOL = 0.15  # SloConfig.quality_logit_err — the auditor's pinned bound
+ATTN_IMPL = "pallas"  # explicit, never "auto": no backend sniffing on this path
+GIB = float(1 << 30)
+
+QUERIES = (
+    "What does the corpus say about retrieval latency and index throughput?",
+    "Which practices should a delivery team adopt for container deployment?",
+    "Summarize the guidance on observability and testing of data pipelines.",
+    "How do compiler and kernel choices affect memory bandwidth in a cluster?",
+    "What is the advice on security review during a platform migration?",
+    "Describe how batch scheduling and request caching interact at runtime.",
+)
+
+
+def say(phase: str, **fields) -> None:
+    """One information line on stdout."""
+    fields = {"phase": phase, "t": round(time.monotonic() - T_START, 1), **fields}
+    print(json.dumps(fields, default=str), flush=True)
+
+
+def check(cond, what: str) -> None:
+    if not cond:
+        raise AssertionError(what)
+
+
+# ---------------------------------------------------------------------------
+# instruments: executables built, errors logged, HBM
+# ---------------------------------------------------------------------------
+
+
+class CompileCounter:
+    """Counts every executable JAX builds (backend compile or persistent-
+    cache retrieval alike) with the thread that asked for it, plus the
+    persistent cache's hits and misses."""
+
+    def __init__(self):
+        import jax
+
+        self.builds = []  # (thread name, seconds)
+        self.cache_hits = 0
+        self.cache_misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.builds.append((threading.current_thread().name, seconds))
+
+    def _on_event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.cache_misses += 1
+
+    def mark(self) -> int:
+        return len(self.builds)
+
+    def since(self, mark: int):
+        return self.builds[mark:]
+
+
+class ErrorLog(logging.Handler):
+    """Collects every ERROR the package logs: the serving path catches a
+    failed prefix-cache warm-up, WAL restore, post-ingest warm-up or audit
+    and carries on — right for a server, a failure for a smoke."""
+
+    def __init__(self):
+        super().__init__(level=logging.ERROR)
+        self.records = []
+
+    def emit(self, record):
+        self.records.append(self.format(record))
+
+
+def hbm(device) -> dict:
+    stats = device.memory_stats()
+    check(stats, f"memory_stats() is empty on {device}")
+    return stats
+
+
+# ---------------------------------------------------------------------------
+# phase 2: kernels against oracles, compiled by Mosaic, 8B head geometry
+# ---------------------------------------------------------------------------
+
+H, K, HD = 32, 8, 128  # Llama-3.1-8B attention heads
+T_MAX = 4352  # EngineConfig.max_seq_len: the 4096 bucket + 256
+ENC_S = 1536  # the encoder's snug bucket for reference-size chunks
+KNN_ROWS = 131072  # a 128k-vector index snapshot
+
+
+def _block_tables(kv_len, bs: int, mb: int):
+    """Distinct physical blocks (0 stays the null block) for each row's
+    logical blocks, allocated interleaved so neighbours are not adjacent."""
+    import numpy as np
+
+    need = [-(-int(n) // bs) for n in kv_len]
+    tables = np.zeros((len(kv_len), mb), np.int32)
+    phys = 1
+    for j in range(max(need)):
+        for b, n in enumerate(need):
+            if j < n:
+                tables[b, j] = phys
+                phys += 1
+    return tables, phys
+
+
+def kernel_cases(seed: int):
+    """Yields ``(name, run, rtol, atol)``; ``run()`` returns ``(got, want)``
+    arrays (or a list of such pairs). Tolerances are the ones the
+    interpret-mode tests in tests/ pin for the same kernel/oracle pair."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from rag_llm_k8s_tpu.core.config import LlamaConfig
+    from rag_llm_k8s_tpu.models.llama import rope_frequencies
+    from rag_llm_k8s_tpu.ops import attention as A
+    from rag_llm_k8s_tpu.ops.knn import knn_topk_pallas, knn_topk_xla
+
+    root = jax.random.PRNGKey(seed)
+
+    def normal(i, shape):
+        return jax.random.normal(jax.random.fold_in(root, i), shape, jnp.float32)
+
+    i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    L = 2
+
+    # -- paged family (the first time it meets Mosaic) ---------------------
+    def paged(q8: bool, chunk: int):
+        bs = 32 if q8 else 16
+        mb = T_MAX // bs
+        T = T_MAX
+        kv_len = np.array(
+            [T, 37, T // 4 + 1, T // 2 + 1, T - 252, bs, 513, T - 1000 % T], np.int32)
+        if chunk:  # every row holds at least the chunk being prefilled
+            kv_len = np.maximum(kv_len, chunk + np.arange(8) * 3).astype(np.int32)
+        B = len(kv_len)
+        tables, n_blocks = _block_tables(kv_len, bs, mb)
+        ka = normal(1, (L, n_blocks, K, bs, HD))
+        va = normal(2, (L, n_blocks, K, bs, HD))
+        S = chunk or 1
+        q = normal(3, (B, S, H, HD))
+        arena = (ka, va)
+        if q8:
+            (kq, ksc), (vq, vsc) = A.quantize_kv(ka), A.quantize_kv(va)
+            arena = (kq, vq, ksc, vsc)
+        pairs = []
+        for lay in range(L):
+            common = (i32(tables), i32(kv_len), i32(lay))
+            if chunk:
+                fn, ref = (
+                    (A.paged_chunk_attention_q8, A.paged_chunk_attention_xla_q8)
+                    if q8 else (A.paged_chunk_attention, A.paged_chunk_attention_xla)
+                )
+                # the chunk's queries are the last S slots of each row
+                args = (q, *arena, *common, i32(kv_len - S))
+                pairs.append((fn(*args), ref(*args)))
+            else:
+                fn, ref = (
+                    (A.paged_decode_attention_q8, A.paged_decode_attention_xla_q8)
+                    if q8 else (A.paged_decode_attention, A.paged_decode_attention_xla)
+                )
+                args = (q, *arena, *common)
+                pairs.append((fn(*args), ref(*args)))
+        return pairs
+
+    yield "paged_decode_attention", lambda: paged(False, 0), 0.0, 1e-5
+    yield "paged_decode_attention_q8", lambda: paged(True, 0), 0.0, 1e-4
+    yield "paged_chunk_attention", lambda: paged(False, 256), 0.0, 1e-5
+    yield "paged_chunk_attention_q8", lambda: paged(True, 256), 0.0, 1e-4
+
+    # -- dense family -------------------------------------------------------
+    def flash(S, heads, kv_heads, hd, B, causal):
+        q = normal(10, (B, S, heads, hd))
+        k = normal(11, (B, S, kv_heads, hd))
+        v = normal(12, (B, S, kv_heads, hd))
+        if causal:  # the decoder's left-padded prefill
+            kv_start = i32([0, 100][:B])
+            kw = dict(kv_start=kv_start, causal=True)
+            valid = jnp.arange(S)[None, :] >= kv_start[:, None]
+        else:  # the encoder's right-padded bidirectional pass
+            kv_len = i32(np.linspace(S // 3, S, B))
+            kw = dict(kv_len=kv_len, causal=False)
+            valid = jnp.arange(S)[None, :] < kv_len[:, None]
+        m = valid[:, :, None, None]
+        got = A.flash_attention(q, k, v, **kw)
+        want = A.attention_xla(q, k, v, **kw)
+        return jnp.where(m, got, 0), jnp.where(m, want, 0)
+
+    yield "flash_attention[prefill]", lambda: flash(T_MAX - 256, H, K, HD, 1, True), 2e-4, 2e-5
+    yield "flash_attention[encoder hd=64]", lambda: flash(ENC_S, 16, 16, 64, 8, False), 2e-4, 2e-5
+
+    def dense_cache(B):
+        kc = normal(20, (L, B, K, T_MAX, HD))
+        vc = normal(21, (L, B, K, T_MAX, HD))
+        return kc, vc
+
+    def decode(q8: bool):
+        B = 8
+        kc, vc = dense_cache(B)
+        q = normal(22, (B, 1, H, HD))
+        T = T_MAX
+        kv_start = i32([0, 17, 300, 0, T - 352, 1, T // 2, 512])
+        kv_len = i32([T, 400, 301, 128, T - 52, T - 256, T // 2 + 1, 513])
+        cache, fn, ref = (kc, vc), A.decode_attention, A.decode_attention_xla
+        if q8:
+            (kq, ksc), (vq, vsc) = A.quantize_kv(kc), A.quantize_kv(vc)
+            cache = (kq, vq, ksc, vsc)
+            fn, ref = A.decode_attention_q8, A.decode_attention_xla_q8
+        pairs = []
+        for lay in range(L):
+            args = (q, *cache, kv_start, kv_len, i32(lay))
+            pairs.append((fn(*args), ref(*args)))
+        return pairs
+
+    yield "decode_attention", lambda: decode(False), 2e-4, 2e-5
+    yield "decode_attention_q8", lambda: decode(True), 0.0, 0.03
+
+    def chunk(q8: bool):
+        B, S = 2, 512
+        kc, vc = dense_cache(B)
+        q = normal(23, (B, S, H, HD))
+        wi = T_MAX - S
+        tail = (i32([0, T_MAX // 6]), i32([T_MAX, T_MAX]), i32(1), i32(wi))
+        if q8:
+            (kq, ksc), (vq, vsc) = A.quantize_kv(kc), A.quantize_kv(vc)
+            args = (q, kq, vq, ksc, vsc, *tail)
+            return A.chunk_prefill_attention_q8(*args), A.chunk_attention_xla_q8(*args)
+        args = (q, kc, vc, *tail)
+        return A.chunk_prefill_attention(*args), A.chunk_attention_xla(*args)
+
+    yield "chunk_prefill_attention", lambda: chunk(False), 2e-4, 2e-5
+    yield "chunk_prefill_attention_q8", lambda: chunk(True), 0.0, 0.03
+
+    # -- RoPE re-rotation of cached K ----------------------------------------
+    inv = np.asarray(rope_frequencies(LlamaConfig.llama_3_1_8b()), np.float64)
+
+    def rope_host(x, pos):
+        """Rotate-by-halves RoPE at ``pos`` on the host, in float64 — an
+        oracle that shares no code with the device."""
+        half = x.shape[-1] // 2
+        phase = pos[None, :, None, None] * inv
+        c, s = np.cos(phase), np.sin(phase)
+        x1, x2 = x[..., :half], x[..., half:]
+        return np.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+
+    def rerotate():
+        x = np.asarray(normal(30, (1, 8, K, HD)), np.float64)
+        pos = np.arange(8, dtype=np.float64)
+        k_at = jnp.asarray(rope_host(x, pos), jnp.float32)
+        return [
+            (A.rope_rerotate(k_at, jnp.int32(delta), jnp.asarray(inv, jnp.float32)),
+             rope_host(x, pos + delta).astype(np.float32))
+            for delta in (1, 7, -3)
+        ]
+
+    yield "rope_rerotate[delta!=0]", rerotate, 0.0, 1e-5
+
+    def knn():
+        Q, N, D, k = 8, KNN_ROWS, 1024, 5
+        emb = normal(40, (N, D))
+        emb = emb / jnp.linalg.norm(emb, axis=1, keepdims=True)
+        queries = emb[:Q] + 0.01 * normal(41, (Q, D))
+        norms = jnp.sum(emb * emb, axis=1)[None, :]
+        v_got, i_got = knn_topk_pallas(queries, emb, norms, k=k)
+        v_ref, i_ref = knn_topk_xla(queries, emb, norms, k=k)
+        check(
+            np.array_equal(np.asarray(i_got), np.asarray(i_ref)),
+            "knn_topk_pallas ranks differ from knn_topk_xla",
+        )
+        return v_got, v_ref
+
+    yield "knn_topk_pallas", knn, 1e-4, 1e-3
+
+
+def phase_kernels(seed: int) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from rag_llm_k8s_tpu.core.config import LlamaConfig
+    from rag_llm_k8s_tpu.models.llama import rope_frequencies
+    from rag_llm_k8s_tpu.ops import attention as A
+
+    for name, run, rtol, atol in kernel_cases(seed):
+        t0 = time.monotonic()
+        # true-fp32 accumulation on both sides: at default precision the
+        # MXU rounds inputs to bf16 and kernel and oracle differ by rounding
+        # noise, not by bugs (tests_tpu/test_on_chip.py does the same)
+        with jax.default_matmul_precision("highest"):
+            pairs = run()
+        if isinstance(pairs, tuple):
+            pairs = [pairs]
+        err = 0.0
+        for got, want in pairs:
+            got, want = np.asarray(got), np.asarray(want)
+            check(np.isfinite(got).all(), f"{name}: non-finite kernel output")
+            check(np.isfinite(want).all(), f"{name}: non-finite oracle output")
+            err = max(err, float(np.max(np.abs(got - want))))
+            np.testing.assert_allclose(got, want, rtol=rtol, atol=atol, err_msg=name)
+        say("kernel", name=name, max_abs_err=err, rtol=rtol, atol=atol,
+            seconds=round(time.monotonic() - t0, 2))
+
+    # delta == 0 is the identity, bit for bit, in both layouts
+    inv = rope_frequencies(LlamaConfig.llama_3_1_8b())
+    k = jax.random.normal(jax.random.PRNGKey(seed), (2, 1, K, 64, HD), jnp.float32)
+    out = A.rope_rerotate(k, jnp.int32(0), inv)
+    check(np.array_equal(np.asarray(out), np.asarray(k)), "rope_rerotate delta=0 not exact")
+    kq, ks = A.quantize_kv(k)
+    rq, rs = A.rope_rerotate_q8(kq, ks, jnp.int32(7), inv)
+    want = np.asarray(A.rope_rerotate(k, jnp.int32(7), inv))
+    deq = np.asarray(rq.astype(jnp.float32) * rs[..., None])
+    # two quantization round trips, each max|x|/254 per element
+    bound = 2.0 * float(np.max(np.abs(want))) / 127.0 + 1e-6
+    err = float(np.max(np.abs(deq - want)))
+    check(err <= bound, f"rope_rerotate_q8 drift {err} over bound {bound}")
+    say("kernel", name="rope_rerotate[delta=0] + rope_rerotate_q8",
+        max_abs_err=err, bound=bound)
+
+
+# ---------------------------------------------------------------------------
+# inputs made from the seed
+# ---------------------------------------------------------------------------
+
+
+def load_tokenizers():
+    """The repo's own tokenizers at the models' vocabulary scale (128k BPE,
+    250k Unigram), trained here when absent — git does not carry them."""
+    from rag_llm_k8s_tpu.native.build import load_library
+    from rag_llm_k8s_tpu.tokenizer import load_tokenizer
+
+    scale = os.path.join(REPO, "tests", "fixtures", "tokenizers_scale")
+    bpe = os.path.join(scale, "bpe_128k.json")
+    uni = os.path.join(scale, "unigram_250k.json")
+    t0 = time.monotonic()
+    built = not (os.path.exists(bpe) and os.path.exists(uni))
+    if built:  # a child that never touches JAX (or the chip)
+        subprocess.run(
+            [sys.executable, os.path.join(REPO, "tests", "fixtures", "gen_tokenizers.py"),
+             "--scale"],
+            check=True, timeout=600, stdout=subprocess.DEVNULL,
+        )
+    llm_tok, enc_tok = load_tokenizer(bpe), load_tokenizer(uni)
+    native = {name: load_library(name) is not None for name in ("bpe", "indexio")}
+    say("tokenizers", trained_now=built, seconds=round(time.monotonic() - t0, 1),
+        native_libraries=native)
+    check(all(native.values()), f"native libraries fell back to Python: {native}")
+    return llm_tok, enc_tok
+
+
+def http(method: str, url: str, body=None, headers=None, timeout: float = 600.0):
+    """One request over a real socket -> (status, parsed JSON or text)."""
+    data = None
+    headers = dict(headers or {})
+    if body is not None and not isinstance(body, bytes):
+        data = json.dumps(body).encode()
+        headers["Content-Type"] = "application/json"
+    elif body is not None:
+        data = body
+    req = urllib.request.Request(url, data=data, headers=headers, method=method)
+    try:
+        with urllib.request.urlopen(req, timeout=timeout) as r:
+            status, raw = r.status, r.read()
+    except urllib.error.HTTPError as e:
+        status, raw = e.code, e.read()
+    try:
+        return status, json.loads(raw)
+    except ValueError:
+        return status, raw.decode("utf-8", "replace")
+
+
+def multipart_pdf(pdf: bytes, filename: str):
+    boundary = "chipsmoke-7d1f3c"
+    body = (
+        f"--{boundary}\r\nContent-Disposition: form-data; name=\"file\"; "
+        f"filename=\"{filename}\"\r\nContent-Type: application/pdf\r\n\r\n"
+    ).encode() + pdf + f"\r\n--{boundary}--\r\n".encode()
+    return body, {"Content-Type": f"multipart/form-data; boundary={boundary}"}
+
+
+# ---------------------------------------------------------------------------
+# phases 3-5: assemble, warm, serve over sockets, check
+# ---------------------------------------------------------------------------
+
+
+def serve_and_check(name, config, mesh, params, tokenizers, enc_params,
+                    counter: CompileCounter, errors: ErrorLog, seed: int,
+                    n_solo: int = 2, n_burst: int = 4):
+    """One serving shape, start to finish. Returns the engine's fused
+    params so the next shape shares the weight buffers."""
+    import jax
+    import numpy as np
+    from werkzeug.serving import make_server
+
+    from rag_llm_k8s_tpu.server.app import create_app
+    from rag_llm_k8s_tpu.server.main import assemble_service
+    from rag_llm_k8s_tpu.utils.synth import synth_pdf
+
+    llm_tok, enc_tok = tokenizers
+    device = mesh.mesh.devices.flat[0]
+    n_err0 = len(errors.records)
+    service = assemble_service(
+        config, mesh, config.model, params, llm_tok, enc_params, enc_tok
+    )
+    engine = service.engine
+    sched_engine = getattr(service.scheduler, "engine", engine)
+
+    # §2: the explicit Pallas backends, not whatever "auto" would resolve to
+    check(engine.model.attn_impl == ATTN_IMPL == "pallas",
+          "decoder not on the Pallas backend")
+    check(service.encoder.model.attn_impl == "flash", "encoder not on the flash backend")
+
+    mark = counter.mark()
+    hits0, miss0 = counter.cache_hits, counter.cache_misses
+    t0 = time.monotonic()
+    service.warmup()
+    service.restore_from_wal()  # what server/main runs after warmup
+    warm_s = time.monotonic() - t0
+    built = counter.since(mark)
+    say(name, event="warmup", seconds=round(warm_s, 1), executables=len(built),
+        compile_seconds=round(sum(s for _, s in built), 1),
+        cache_hits=counter.cache_hits - hits0,
+        cache_misses=counter.cache_misses - miss0,
+        hbm_in_use_gib=round(hbm(device)["bytes_in_use"] / GIB, 2))
+    check(service.ready, "service not ready after warmup()")
+
+    srv = make_server("127.0.0.1", 0, create_app(service), threaded=True)
+    base = f"http://127.0.0.1:{srv.server_port}"
+    th = threading.Thread(target=srv.serve_forever, name="wsgi", daemon=True)
+    th.start()
+    try:
+        status, body = http("GET", base + "/healthz")
+        check(status == 200 and body["ready"], f"/healthz: {status} {body}")
+        check(body["device_platform"] == "tpu", f"/healthz device: {body}")
+        say(name, event="healthz", engine_mode=body["engine_mode"],
+            device_platform=body["device_platform"])
+
+        mark_ingest = counter.mark()
+        t0 = time.monotonic()
+        payload, headers = multipart_pdf(synth_pdf(seed), "corpus.pdf")
+        status, body = http("POST", base + "/upload_pdf", payload, headers)
+        check(status == 200, f"/upload_pdf: {status} {body}")
+        status, info = http("GET", base + "/index_info")
+        check(status == 200 and info.get("total_vectors", 0) >= 5,
+              f"/index_info: {status} {str(info)[:200]}")
+        # the first ingest into an empty index builds the executables its
+        # new shapes need (encoder batch, fused retrieve, assembly) inside
+        # the upload — by design, and reported, not hidden
+        say(name, event="upload_pdf", seconds=round(time.monotonic() - t0, 1),
+            message=body.get("message"), total_vectors=info["total_vectors"],
+            dimension=info["dimension"],
+            executables_built_by_ingest=len(counter.since(mark_ingest)))
+
+        def generate(query, out):
+            t = time.monotonic()
+            out.append((*http("POST", base + "/generate", {"prompt": query}),
+                        time.monotonic() - t))
+
+        def tokens_served():
+            return int(service.metrics.snapshot().get("query_decode_tokens", 0))
+
+        mark_serve = counter.mark()
+        responses = []
+        tok0 = tokens_served()
+        for q in QUERIES[:n_solo]:
+            generate(q, responses)
+        # the auditor skips a job that finds no headroom within ~2 s; let
+        # the solo audits finish before the burst competes with them
+        check(service.shadow.drain(timeout=600.0), "shadow audits did not finish")
+        burst = [threading.Thread(target=generate, args=(q, responses))
+                 for q in QUERIES[n_solo:n_solo + n_burst]]
+        for t in burst:
+            t.start()
+        for t in burst:
+            t.join(timeout=900)
+            check(not t.is_alive(), "a /generate request did not return")
+        n_req = n_solo + n_burst
+        check(len(responses) == n_req, f"{len(responses)} of {n_req} responses")
+        for status, body, seconds in responses:
+            check(status == 200, f"/generate: {status} {body}")
+            check(isinstance(body.get("generated_text"), str), f"/generate body: {body}")
+            check(body.get("context"), "response carries no retrieved context")
+        served = tokens_served() - tok0
+        check(served == n_req * NEW_TOKENS,
+              f"{served} tokens served, expected {n_req * NEW_TOKENS}")
+        in_flight = [b for b in counter.since(mark_serve) if b[0] != "shadow-audit"]
+        say(name, event="generate", requests=n_req, tokens=served,
+            latencies_s=[round(s, 2) for _, _, s in responses],
+            timings_ms=[r[1]["timings"] for r in responses[:1]],
+            executables_built_in_flight=in_flight)
+        check(not in_flight,
+              f"executables were built while requests were in flight: {in_flight}")
+
+        status, metrics = http("GET", base + "/metrics")
+        check(status == 200 and "rag_request_duration_seconds_bucket" in metrics,
+              "/metrics exposition incomplete")
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        th.join(timeout=30)
+
+    # shadow auditor over everything served: the fast path said what the
+    # exact path says, inside the pinned tolerance
+    check(service.shadow.drain(timeout=600.0), "shadow audits did not finish")
+    st = service.shadow.state()
+    audit_builds = [b for b in counter.since(mark_serve) if b[0] == "shadow-audit"]
+    say(name, event="shadow", audits=st["audits"], skips=st["skips"],
+        err_max=st["err_max"], tokens_compared=st["tokens_compared"],
+        attribution=st["attribution"],
+        scorer_executables_built_lazily=len(audit_builds))
+    judged = st["audits"]["clean"] + st["audits"]["diverged"]
+    check(judged == n_req and not st["audits"]["failed"] and not st["skips"],
+          f"not every served request was audited: {st['audits']} {st['skips']}")
+    check(st["err_max"] <= AUDIT_TOL,
+          f"shadow audit err_max {st['err_max']} over tolerance {AUDIT_TOL}")
+
+    # finite logits, read straight from the exact scorer
+    ids = [config.model.bos_token_id] + llm_tok.encode(QUERIES[0])
+    out = engine.generate([ids], max_new_tokens=8)[0]
+    score = engine.score_exact(ids, out)
+    check(np.isfinite(score["max_logit"]).all() and np.isfinite(score["chosen_logit"]).all(),
+          "non-finite logits")
+
+    # the Pallas path was taken, not a reference: kernels in the programs
+    S = max(config.engine.prompt_buckets)
+    programs = {}
+    if sched_engine is engine:
+        key = (1, S, engine._clamp_max_new(S, NEW_TOKENS), "spec")
+        programs["one-shot prefill-4096 + decode loop"] = engine._compiled[key]
+        check(service.metrics.snapshot().get("query_single_fetch", 0) >= n_solo,
+              "solo requests did not take the single-fetch path")
+    else:
+        programs["paged decode step"] = sched_engine._compiled[("step_paged", 1, 1)]
+        programs["paged prefill-4096"] = sched_engine._compiled[("prefill_paged", S, 1)]
+        programs["paged verify"] = sched_engine._compiled[
+            ("verify_paged", sched_engine.spec_K, 1)]
+        programs["mixed window"] = sched_engine._compiled[
+            ("mixed_step", sched_engine.chunk_tokens, 1)]
+    for what, compiled in programs.items():
+        check("tpu_custom_call" in compiled.as_text(), f"{what}: no Pallas kernel in it")
+    emb, norms = service.store.device_snapshot()
+    tokens, mask = service.encoder.prepare_batch(enc_tok.encode(QUERIES[0]))
+    k_eff = min(config.retrieval.k, service.store.ntotal)
+    fused = service._fused_retrieve[(tokens.shape[1], emb.shape[0], k_eff, 1)]
+    text = fused.lower(service.encoder.params, tokens, mask, emb, norms).compile().as_text()
+    check(text.count("tpu_custom_call") >= 2,
+          "fused embed+kNN: encoder flash and kNN kernels not both present")
+    pallas_in = sorted(programs) + ["fused embed+kNN"]
+
+    extra = {}
+    if sched_engine is not engine:
+        stats, pool = sched_engine.stats, sched_engine.kv_pool
+        extra = dict(
+            spec_verify_steps=stats.spec_verify_steps,
+            spec_drafted=stats.spec_drafted_tokens,
+            spec_accepted=stats.spec_accepted_tokens,
+            mixed_windows=sched_engine.ledger.state()["kinds"].get("mixed", {}),
+            pool_blocks=pool.num_blocks, blocks_in_use=pool.blocks_in_use(),
+        )
+        check(stats.spec_verify_steps > 0, "the paged verify step never ran")
+        check(extra["mixed_windows"].get("busy_s", 0) > 0, "no mixed window ran")
+        check(pool.blocks_in_use() == 0, f"pool not drained: {pool.stats()}")
+    else:
+        extra = dict(spec_verify_steps=engine.stats.spec_verify_steps,
+                     spec_emitted=engine.stats.spec_emitted_tokens)
+    new_errors = errors.records[n_err0:]
+    say(name, event="checks", pallas_in=pallas_in, errors_logged=len(new_errors),
+        peak_hbm_gib=round(hbm(device)["peak_bytes_in_use"] / GIB, 2), **extra)
+    check(not new_errors, "the package logged errors:\n" + "\n".join(new_errors))
+
+    fused_params = engine.params
+    service.shutdown()
+    return fused_params
+
+
+def pool_blocks_from_free_hbm(device, model_cfg, block_size: int, max_batch: int,
+                              reserve_gib: float) -> int:
+    """Size the paged arena from what is actually free: int8 payload + fp32
+    scale planes per block, capped at dense parity (every slot full)."""
+    stats = hbm(device)
+    free = stats["bytes_limit"] - stats["bytes_in_use"]
+    L, Kh, hd = model_cfg.num_layers, model_cfg.num_kv_heads, model_cfg.head_dim
+    per_block = block_size * 2 * L * Kh * (hd + 4)
+    row = T_MAX // block_size
+    blocks = min(max_batch * row, int((free - reserve_gib * GIB) // per_block))
+    say("paged", event="pool_sizing", free_gib=round(free / GIB, 2),
+        reserve_gib=reserve_gib, block_mib=round(per_block / (1 << 20), 2),
+        dense_parity_blocks=max_batch * row, kv_pool_blocks=blocks)
+    check(blocks >= row, f"free HBM holds {blocks} blocks, one row needs {row}")
+    return blocks
+
+
+def app_config(work: str, shape: str, model, engine, tp: int):
+    """The served configuration: greedy at the reference budget (the exact
+    path has no reference for a sampled stream), every request audited,
+    everything the service writes kept under ``work``."""
+    from rag_llm_k8s_tpu.core.config import (
+        AppConfig, FlightConfig, MeshConfig, SamplingConfig, ServerConfig,
+        ShadowConfig,
+    )
+
+    return AppConfig(
+        mesh=MeshConfig(dp=1, sp=1, tp=tp), model=model, engine=engine,
+        sampling=SamplingConfig(do_sample=False, max_new_tokens=NEW_TOKENS),
+        server=ServerConfig(
+            host="127.0.0.1", model_path=work, embedder_path=work,
+            index_path=os.path.join(work, f"index_{shape}"),
+            pdf_dir=os.path.join(work, "pdfs"),
+        ),
+        flight=FlightConfig(spool_dir=os.path.join(work, "incidents")),
+        shadow=ShadowConfig(sample_rate=1.0),
+    )
+
+
+def run_one_chip(args, counter, errors) -> None:
+    import jax
+
+    from rag_llm_k8s_tpu.core.config import (
+        DTypePolicy, EncoderConfig, EngineConfig, LlamaConfig, MeshConfig,
+    )
+    from rag_llm_k8s_tpu.core.mesh import make_mesh
+    from rag_llm_k8s_tpu.utils.synth import synth_encoder_params, synth_llama_params
+
+    device = jax.devices()[0]
+    say("kernels", event="start")
+    phase_kernels(args.seed)
+    gc.collect()
+
+    tokenizers = load_tokenizers()
+    mesh = make_mesh(MeshConfig(dp=1, sp=1, tp=1), devices=[device])
+    dtypes = DTypePolicy()
+    model = dataclasses.replace(LlamaConfig.llama_3_1_8b(), num_layers=args.layers)
+    t0 = time.monotonic()
+    params = synth_llama_params(
+        model, dtypes, args.seed, quant="int8", mesh=mesh, recite_gain=5.0
+    )
+    enc_params = synth_encoder_params(EncoderConfig.bge_m3(), dtypes, args.seed + 1)
+    jax.block_until_ready((params, enc_params))
+    say("params", model="Llama-3.1-8B published widths", layers_served=model.num_layers,
+        weights="int8", kv="int8", encoder="bge-m3 published widths", seed=args.seed,
+        seconds=round(time.monotonic() - t0, 1),
+        hbm_in_use_gib=round(hbm(device)["bytes_in_use"] / GIB, 2))
+
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as work:
+        # phase 3: the shape deploy/llm/deploy.yaml ships (coalesce,
+        # single-fetch RAG, speculation auto), int8 for one chip
+        one_chip = EngineConfig(weight_quant="int8", kv_quant="int8", attn_impl=ATTN_IMPL)
+        fused = serve_and_check(
+            "serve_default", app_config(work, "default", model, one_chip, 1), mesh, params,
+            tokenizers, enc_params, counter, errors, args.seed,
+        )
+        del params
+        gc.collect()
+
+        # phase 4: paged continuous, same weight buffers. Reserve what the
+        # compiler says the largest concurrent transients need (admission
+        # prefill of a 4-group at 4096, the exact scorer, the fused
+        # retrieve) — it counts one program at a time, the pool must not
+        bs = 32  # the int8 arena's Mosaic tile
+        blocks = pool_blocks_from_free_hbm(
+            device, model, bs, one_chip.max_batch_size, reserve_gib=4.5)
+        paged = dataclasses.replace(
+            one_chip, batching="continuous", kv_paged=True, kv_block_size=bs,
+            kv_pool_blocks=blocks, interleave_prefill=True, spec_paged=True,
+        )
+        serve_and_check(
+            "serve_paged", app_config(work, "paged", model, paged, 1), mesh, fused,
+            tokenizers, enc_params, counter, errors, args.seed,
+        )
+
+
+# ---------------------------------------------------------------------------
+# --chips 4: the sharded path and what it is compared with
+# ---------------------------------------------------------------------------
+
+
+def run_four_chips(args, counter, errors) -> None:
+    import jax
+    import numpy as np
+
+    from rag_llm_k8s_tpu.core.config import (
+        DTypePolicy, EncoderConfig, EngineConfig, LlamaConfig, MeshConfig,
+        SamplingConfig, ShadowConfig,
+    )
+    from rag_llm_k8s_tpu.core.mesh import make_mesh
+    from rag_llm_k8s_tpu.engine.continuous import ContinuousEngine, ContinuousScheduler
+    from rag_llm_k8s_tpu.engine.engine import InferenceEngine
+    from rag_llm_k8s_tpu.obs.shadow import ShadowAuditor
+    from rag_llm_k8s_tpu.utils.synth import synth_encoder_params, synth_llama_params
+
+    devices = jax.devices()
+    check(len(devices) == 4, f"--chips 4 needs four devices, JAX sees {len(devices)}")
+    mesh4 = make_mesh(MeshConfig(dp=1, sp=1, tp=4))
+    mesh1 = make_mesh(MeshConfig(dp=1, sp=1, tp=1), devices=[devices[0]])
+    dtypes = DTypePolicy()
+    full = LlamaConfig.llama_3_1_8b()
+    cut = dataclasses.replace(full, num_layers=args.cut_layers)
+    check(cut.num_kv_heads % 4 == 0 and cut.num_heads % 4 == 0,
+          "head counts do not tile tp=4: attention would fall to the XLA path")
+    sampling = SamplingConfig(do_sample=False, max_new_tokens=64)
+    rs = np.random.RandomState(args.seed)
+    prompts = [
+        [full.bos_token_id] + [int(t) for t in rs.randint(3, full.vocab_size - 300, n)]
+        for n in (23, 180, 97, 240)
+    ]
+
+    def in_use():
+        return [hbm(d)["bytes_in_use"] for d in devices]
+
+    def engines(params, mesh):
+        ec = EngineConfig(
+            attn_impl=ATTN_IMPL, prompt_buckets=(256,), max_batch_size=4,
+            max_seq_len=512, speculative="off",
+        )
+        one = InferenceEngine(cut, params, sampling=sampling, engine_config=ec,
+                              dtypes=dtypes, mesh=mesh)
+        paged = ContinuousEngine(
+            cut, one.params, sampling=sampling, dtypes=dtypes, mesh=mesh,
+            engine_config=dataclasses.replace(
+                ec, batching="continuous", kv_paged=True, kv_block_size=16),
+        )
+        return one, paged
+
+    def serve(one, paged):
+        streams = {"one-shot": one.generate(prompts)}
+        sched = ContinuousScheduler(paged)
+        out = [None] * len(prompts)
+
+        def submit(i):
+            out[i] = sched.submit(prompts[i])
+
+        threads = [threading.Thread(target=submit, args=(i,)) for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=600)
+            check(not t.is_alive(), "a paged submit did not return")
+        sched.shutdown()
+        check(paged.kv_pool.blocks_in_use() == 0, f"pool not drained: {paged.kv_pool.stats()}")
+        streams["paged"] = out
+        for kind, ss in streams.items():
+            check(all(len(s) == sampling.max_new_tokens for s in ss),
+                  f"{kind}: wrong stream lengths {[len(s) for s in ss]}")
+        return streams
+
+    # ---- depth cut so the same params also fit device 0 alone ------------
+    t0 = time.monotonic()
+    params4 = synth_llama_params(cut, dtypes, args.seed, mesh=mesh4, recite_gain=5.0)
+    jax.block_until_ready(params4)
+    n_sharded = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params4):
+        if all(a is None for a in leaf.sharding.spec):
+            continue  # norms: replicated
+        n_sharded += 1
+        shards = leaf.addressable_shards
+        check(len({s.device for s in shards}) == 4, f"{path}: not on four devices")
+        check(all(s.data.nbytes * 4 == leaf.nbytes for s in shards),
+              f"{path}: shards are not 1/4 of the bytes each")
+    one4, paged4 = engines(params4, mesh4)
+    plane = paged4._cache[0]
+    check(plane.addressable_shards[0].data.shape[2] == cut.num_kv_heads // 4,
+          f"arena plane not head-sharded: {plane.addressable_shards[0].data.shape}")
+    streams4 = serve(one4, paged4)
+    per_device = in_use()
+    say("tp4", event="served_cut", layers=cut.num_layers, sharded_leaves=n_sharded,
+        seconds=round(time.monotonic() - t0, 1),
+        bytes_in_use_gib=[round(b / GIB, 2) for b in per_device])
+    check(max(per_device) <= 1.25 * min(per_device),
+          f"HBM piled on one device: {per_device}")
+    text = paged4._compiled[("step_paged", 1, 1)].as_text()
+    check("all-reduce" in text, "tp=4 decode step has no all-reduce")
+    check("tpu_custom_call" in text, "tp=4 decode step has no Pallas kernel")
+    check("tpu_custom_call" in next(iter(one4._compiled.values())).as_text(),
+          "tp=4 one-shot program has no Pallas kernel")
+
+    # the same numbers on device 0 alone
+    params1 = jax.device_put(params4, mesh1.replicated)
+    one1, paged1 = engines(params1, mesh1)
+    del params1
+    streams1 = serve(one1, paged1)
+    auditor = ShadowAuditor(ShadowConfig(sample_rate=1.0, backlog=32),
+                            score_fn=one1.score_exact)
+    agree = {}
+    for kind, streams in (("tp4 one-shot", streams4["one-shot"]),
+                          ("tp4 paged", streams4["paged"]),
+                          ("tp1 paged", streams1["paged"]),
+                          ("tp1 one-shot", streams1["one-shot"])):
+        agree[kind] = sum(a == b for a, b in zip(streams, streams1["one-shot"]))
+        for prompt, stream in zip(prompts, streams):
+            check(auditor.observe(stream, prompt_ids=prompt, force=True),
+                  "audit not enqueued")
+    check(auditor.drain(timeout=600.0), "audits did not finish")
+    st = auditor.state()
+    auditor.shutdown()
+    say("tp4", event="parity_vs_tp1", identical_streams_of_4=agree,
+        audits=st["audits"], err_max=st["err_max"])
+    check(st["audits"]["clean"] + st["audits"]["diverged"] == 16
+          and not st["audits"]["failed"], f"audits incomplete: {st['audits']}")
+    check(st["err_max"] <= AUDIT_TOL, f"tp=4 vs tp=1 err_max {st['err_max']}")
+    # engines built outside a RagService stay referenced by the process-wide
+    # metrics registry's callback gauges, so dropping the names frees
+    # nothing: release the cut-depth buffers themselves before 16 GB more
+    # are born next to them
+    for leaf in jax.tree.leaves(
+        (params4, one1.params, paged4._cache, paged1._cache)
+    ):
+        leaf.delete()
+    del one4, paged4, one1, paged1, params4, auditor
+    gc.collect()
+
+    # ---- full depth: 16 GB of bf16 weights exist only across chips -------
+    tokenizers = load_tokenizers()
+    t0 = time.monotonic()
+    params = synth_llama_params(full, dtypes, args.seed, mesh=mesh4, recite_gain=5.0)
+    jax.block_until_ready(params)
+    per_device = in_use()
+    say("tp4", event="params_full_depth", layers=full.num_layers,
+        seconds=round(time.monotonic() - t0, 1),
+        bytes_in_use_gib=[round(b / GIB, 2) for b in per_device])
+    check(max(per_device) <= 1.05 * min(per_device),
+          f"full-depth weights piled on one device: {per_device}")
+    # the encoder and the index are single-device programs: they sit on
+    # device 0 by design, on top of its quarter of the decoder
+    enc_params = synth_encoder_params(EncoderConfig.bge_m3(), dtypes, args.seed + 1)
+    jax.block_until_ready(enc_params)
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as work:
+        # deploy.yaml's shape as it ships for a slice: bf16 weights and KV
+        config = app_config(work, "tp4", full, EngineConfig(attn_impl=ATTN_IMPL), 4)
+        serve_and_check("serve_tp4", config, mesh4, params, tokenizers, enc_params,
+                        counter, errors, args.seed, n_solo=2, n_burst=0)
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the tensor-parallel phase (builder-run)")
+    ap.add_argument("--layers", type=int, default=32,
+                    help="decoder depth served on one chip (widths are never cut)")
+    ap.add_argument("--cut-layers", type=int, default=8,
+                    help="--chips 4: depth of the tp=4 vs tp=1 comparison")
+    args = ap.parse_args()
+
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(f"chip_smoke: no TPU (JAX sees {devices[0].platform}); "
+              "there is no CPU fallback", file=sys.stderr)
+        sys.exit(2)
+
+    from rag_llm_k8s_tpu.core.compile_cache import (
+        CACHE_ENV, cache_entry_count, ensure_compile_cache,
+    )
+
+    cache_dir = ensure_compile_cache()
+    entries0 = cache_entry_count(cache_dir)
+    say("device", platform=devices[0].platform, kind=devices[0].device_kind,
+        count=len(devices), jax=jax.__version__,
+        compile_cache=cache_dir, placed_by_env=bool(os.environ.get(CACHE_ENV)),
+        cache_entries_before=entries0, cache_warm=entries0 > 0)
+    hbm(devices[0])
+
+    counter = CompileCounter()
+    errors = ErrorLog()
+    logging.basicConfig(level=logging.WARNING, stream=sys.stderr)
+    logging.getLogger("rag_llm_k8s_tpu").addHandler(errors)
+
+    if args.chips == 4:
+        run_four_chips(args, counter, errors)
+    else:
+        run_one_chip(args, counter, errors)
+
+    say("done", seconds=round(time.monotonic() - T_START, 1),
+        executables_built=len(counter.builds),
+        compile_seconds=round(sum(s for _, s in counter.builds), 1),
+        cache_hits=counter.cache_hits, cache_misses=counter.cache_misses,
+        cache_entries_before=entries0, cache_entries_after=cache_entry_count(cache_dir),
+        peak_hbm_gib=[round(d.memory_stats()["peak_bytes_in_use"] / GIB, 2)
+                      for d in devices])
+    print(json.dumps({"ok": True, "device": {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices)}}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

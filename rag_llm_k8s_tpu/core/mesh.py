@@ -77,23 +77,18 @@ def make_mesh(
     config = config or MeshConfig()
     devices = list(devices if devices is not None else jax.devices())
     dp, sp, tp = config.resolved(len(devices))
-    # Force Auto axis types on every path: jax>=0.9's jax.make_mesh defaults to
-    # Explicit sharding mode, under which plain indexing of sharded arrays
-    # raises ShardingTypeError — this framework uses the Auto (NamedSharding
-    # annotation) model throughout. Feature-detected: on jax versions that
-    # predate AxisType (< 0.6), Auto is the ONLY sharding model, so omitting
-    # the argument is semantically identical — without the detection, every
-    # mesh construction (and the whole tp/sp test surface) dies on import
-    # against an older installed jax.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    type_kw = {} if axis_type is None else {"axis_types": (axis_type.Auto,) * 3}
-    if devices == list(jax.devices()) and hasattr(jax, "make_mesh"):
+    # Force Auto axis types on every path: jax.make_mesh defaults to Explicit
+    # sharding mode, under which plain indexing of sharded arrays raises
+    # ShardingTypeError — this framework uses the Auto (NamedSharding
+    # annotation) model throughout.
+    axis_types = (jax.sharding.AxisType.Auto,) * 3
+    if devices == list(jax.devices()):
         mesh = jax.make_mesh(
-            (dp, sp, tp), config.axis_names, devices=devices, **type_kw
+            (dp, sp, tp), config.axis_names, devices=devices, axis_types=axis_types
         )
     else:
         arr = np.asarray(devices).reshape(dp, sp, tp)
-        mesh = Mesh(arr, config.axis_names, **type_kw)
+        mesh = Mesh(arr, config.axis_names, axis_types=axis_types)
     return MeshContext(mesh=mesh)
 
 
@@ -101,3 +96,10 @@ def single_device_mesh(device: Optional[jax.Device] = None) -> MeshContext:
     """1×1×1 mesh — lets all sharded code paths run unchanged on one chip."""
     device = device or jax.devices()[0]
     return make_mesh(MeshConfig(dp=1, sp=1, tp=1), devices=[device])
+
+
+def serving_device_kind(mesh: Optional[MeshContext] = None) -> str:
+    """``device_kind`` of the devices an engine serves on (its mesh's, or
+    the default backend's) — what roofline peaks are keyed by."""
+    device = mesh.mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    return device.device_kind

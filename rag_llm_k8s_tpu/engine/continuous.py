@@ -51,7 +51,7 @@ from rag_llm_k8s_tpu.core.config import (
     LlamaConfig,
     SamplingConfig,
 )
-from rag_llm_k8s_tpu.core.mesh import MeshContext
+from rag_llm_k8s_tpu.core.mesh import MeshContext, serving_device_kind
 from rag_llm_k8s_tpu.engine.engine import (
     EngineStats,
     _isin,
@@ -353,7 +353,9 @@ class ContinuousEngine:
         # goodput_window flight event so flightview --goodput reconstructs
         # the same report offline. Host-side dict math only; the
         # goodput_overhead bench leg holds it to <= 2% of decode steps/s.
-        self.ledger = obs_goodput.ledger_for(config, engine_config)
+        self.ledger = obs_goodput.ledger_for(
+            config, engine_config, device_kind=serving_device_kind(mesh)
+        )
         # request ids whose NEXT admission re-feeds tokens already computed
         # once (preemption / reset resubmission) — that admission's real
         # token lanes are attributed preempt_rework, exactly once (the
@@ -532,6 +534,17 @@ class ContinuousEngine:
             # the mixed decode+chunk window — the first interleaved
             # admission must not pay a compile either
             self._get("mixed_step", self.chunk_tokens)
+        self._warm_state_ops()
+
+    def _warm_state_ops(self):
+        """Admission and retirement touch the slot state with a few
+        op-by-op device calls (row-key split and store, active-mask clear),
+        and each of those compiles on first use — inside the first request
+        unless it happens here. Results are thrown away: ``_rng`` does not
+        advance, so streams are what they were."""
+        _, row_key = jax.random.split(self._rng)
+        self._rng_keys.at[0].set(self._put(row_key))
+        self._active & self._put(jnp.asarray(np.ones((self.B,), bool)))
 
     def _put(self, x, sharding=None):
         """Place a host/device value to match a lowered aval's sharding;

@@ -38,6 +38,7 @@ class EncoderRunner:
         ),
         max_batch: int = 32,
         eos_id: Optional[int] = None,
+        attn_impl: str = "auto",  # BgeM3Encoder.attn_impl
     ):
         self.config = config
         self.params = params
@@ -50,7 +51,7 @@ class EncoderRunner:
             b for b in length_buckets if b <= config.max_encode_len
         ) or (config.max_encode_len,)
         self.max_batch = max_batch
-        self.model = BgeM3Encoder(config, dtypes)
+        self.model = BgeM3Encoder(config, dtypes, attn_impl)
         self._jit = jax.jit(
             lambda params, tokens, mask: self.model.apply(
                 {"params": params}, tokens, mask

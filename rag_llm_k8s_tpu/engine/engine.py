@@ -42,7 +42,7 @@ from rag_llm_k8s_tpu.core.config import (
     LlamaConfig,
     SamplingConfig,
 )
-from rag_llm_k8s_tpu.core.mesh import MeshContext
+from rag_llm_k8s_tpu.core.mesh import MeshContext, serving_device_kind
 from rag_llm_k8s_tpu.engine.sampling import NEG_INF, _prepared_logits, sample_token
 from rag_llm_k8s_tpu.models.llama import (
     KVCache,
@@ -231,7 +231,9 @@ class InferenceEngine:
         # call's measured duration into prefill/decode shares analytically
         # ("oneshot" windows; the continuous engine measures its windows
         # exactly). Journals a goodput_window flight event per call.
-        self.ledger = obs_goodput.ledger_for(config, engine_config)
+        self.ledger = obs_goodput.ledger_for(
+            config, engine_config, device_kind=serving_device_kind(mesh)
+        )
         # observability handles (obs/metrics.py): standalone engines report
         # into the process default registry; RagService rebinds to its own
         self.bind_metrics(obs_metrics.default_registry())

@@ -2,9 +2,9 @@
 
 The dense slot layout allocates one ``(L, B, K, T, hd)`` cache with
 ``T = max_seq_len`` for EVERY slot, so a 64-slot batch pays full-window HBM
-and decode bandwidth for rows holding a 300-token prompt — BENCH_r05 shows
-device decode steps/s collapsing 250 → 90 from B=8 to B=64 on exactly that
-waste. PagedAttention (vLLM; Kwon et al. 2023) and JetStream's TPU serving
+and decode bandwidth for rows holding a 300-token prompt — the round-5
+capture (before PR 1, in git history) had device decode steps/s collapsing
+by almost two thirds from B=8 to B=64 on exactly that waste. PagedAttention (vLLM; Kwon et al. 2023) and JetStream's TPU serving
 design both make the same move: carve the KV arena into fixed-size physical
 **blocks**, give every row an int32 *block table* mapping its logical token
 positions onto pool blocks, and allocate blocks only as a row's frontier

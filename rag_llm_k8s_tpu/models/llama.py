@@ -187,7 +187,7 @@ def replicate_undividable_heads(t: jax.Array, mesh: Optional[Mesh]) -> jax.Array
     over ``tp`` whenever the byte count divides (parallel/sharding.py), so a
     head count that doesn't tile the axis leaves the reshaped ``[B, S,
     heads, hd]`` array sharded at SUB-HEAD granularity. That layout is not
-    just slow — on this container's jax 0.4.x, GSPMD miscompiles the
+    just slow — GSPMD (first seen on jax 0.4.x) miscompiles the
     slice+concat composite RoPE's rotate-by-halves builds over it whenever a
     second mesh axis (``dp``) is also populated: the jitted forward returns
     wrong VALUES (~0.3 absolute on tiny-config logits; eager is exact).
@@ -427,12 +427,10 @@ class Attention(nn.Module):
         def shard(kernel, specs_mode, q8):
             if not heads_shardable:
                 return kernel
-            from jax.experimental.shard_map import shard_map
-
             in_specs, out_spec = paged_partition_specs(specs_mode, q8=q8)
-            return shard_map(
+            return jax.shard_map(
                 kernel, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
-                check_rep=False,
+                check_vma=False,
             )
 
         if mode == "decode":
@@ -588,27 +586,25 @@ class Attention(nn.Module):
         if heads_shardable:
             # heads are independent: shard the kernel over the tp axis, one
             # per-device Pallas call each on its local heads — no collectives
-            from jax.experimental.shard_map import shard_map
-
             hspec = P(None, None, "tp", None)
             if cache_kv:
                 kvspec = P(None, None, "tp", None, None)
                 scspec = (P(None, None, "tp", None),) * 2 if scales is not None else ()
                 scalars = (P(None),) * (3 if mode == "chunk" else 2)
-                kernel = shard_map(
+                kernel = jax.shard_map(
                     kernel,
                     mesh=mesh,
                     in_specs=(hspec, kvspec, kvspec) + scspec + (P(None),) + scalars,
                     out_specs=hspec,
-                    check_rep=False,
+                    check_vma=False,
                 )
             else:
-                kernel = shard_map(
+                kernel = jax.shard_map(
                     kernel,
                     mesh=mesh,
                     in_specs=(hspec, hspec, hspec, P(None), P(None)),
                     out_specs=hspec,
-                    check_rep=False,
+                    check_vma=False,
                 )
         if mode == "decode":
             lay1 = jnp.asarray(layer, jnp.int32).reshape(1)
@@ -626,8 +622,6 @@ class Attention(nn.Module):
     def _attend_ring(self, q, k, v, kv_start, kv_len, sp: int, tp: int) -> jax.Array:
         """Sequence-parallel prefill attention: shard_map over ``sp`` (and
         ``tp`` when head counts divide it), ring K/V rotation inside."""
-        from jax.experimental.shard_map import shard_map
-
         from rag_llm_k8s_tpu.parallel.ring_attention import ring_attention
 
         mesh = self.mesh
@@ -640,14 +634,14 @@ class Attention(nn.Module):
         valid = (t[None, :] >= kv_start[:, None]) & (t[None, :] < kv_len[:, None])
 
         hspec = P(dp_axis, "sp", tp_axis, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q_, k_, v_, val_: ring_attention(
                 q_, k_, v_, axis_name="sp", causal=True, kv_valid=val_
             ),
             mesh=mesh,
             in_specs=(hspec, hspec, hspec, P(dp_axis, "sp")),
             out_specs=hspec,
-            check_rep=False,
+            check_vma=False,
         )
         return fn(q, k, v, valid).astype(q.dtype)
 
@@ -763,11 +757,11 @@ class Attention(nn.Module):
             # continuous batching: write_index is [B] — each row's token
             # lands at that row's own frontier. NOT a gather-scatter
             # (.at[layer, b, :, wi_b].set): that lowers to an XLA scatter
-            # which re-materializes the cache and measured 2.6x (B=8) to
-            # 12x (B=64) step time vs the one-shot loop (BENCH_r05
-            # continuous_device_steps_per_s, round-5 isolation). A masked
+            # which re-materializes the cache (the round-5 capture, before
+            # PR 1 and in git history, had it several times the one-shot
+            # loop's step time, worse at B=64 than at B=8). A masked
             # full-plane write streams the layer's [B, K, T, hd] planes
-            # exactly once (~0.7 ms at B=8 on v5e) and stays aliased under
+            # exactly once and stays aliased under
             # the scan carry via the scalar-indexed .at[layer].set.
             T_len = k_cache.shape[3]
             wi_b = write_index.reshape(B, 1, 1, 1)

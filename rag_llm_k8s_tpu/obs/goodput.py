@@ -67,6 +67,7 @@ __all__ = [
     "RooflineModel",
     "ledger_for",
     "merge_states",
+    "peaks_for_device",
     "render_report",
     "roofline_for_llama",
     "state_from_events",
@@ -89,13 +90,33 @@ WINDOW_CATEGORIES = CATEGORIES[:-1]
 #: Executable kinds the ledger aggregates roofline figures per.
 KINDS = ("prefill", "prefill_px", "decode", "verify", "oneshot", "mixed")
 
-#: Generic single-chip peaks used when the config does not pin them
-#: (TPU_RAG_GOODPUT_PEAK_TFLOPS / TPU_RAG_GOODPUT_HBM_GBS): a TPU-v4-class
-#: 275 bf16 TFLOP/s and 1.2 TB/s HBM. On CPU hosts the absolute MFU is
-#: meaningless-small but every RELATIVE read (category split, bubble
-#: fraction, per-request attribution, regression direction) still holds.
-DEFAULT_PEAK_TFLOPS = 275.0
-DEFAULT_HBM_GBS = 1200.0
+#: Single-chip roofline peaks by jax ``device_kind``: (bf16 TFLOP/s, HBM
+#: GB/s), used when the config does not pin them
+#: (TPU_RAG_GOODPUT_PEAK_TFLOPS / TPU_RAG_GOODPUT_HBM_GBS). A kind that is
+#: not in the table is an error, never a default — pricing one chip with
+#: another's peaks makes every MFU and roofline share silently wrong.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    "TPU v5 lite": (197.0, 819.0),
+    # the host platform the tests run on has no roofline worth the name:
+    # nominal figures keep every RELATIVE read (category split, bubble
+    # fraction, per-request attribution, regression direction) defined;
+    # absolute MFU on a CPU host is meaningless-small by construction
+    "cpu": (275.0, 1200.0),
+}
+
+
+def peaks_for_device(device_kind: str) -> Tuple[float, float]:
+    """(peak bf16 TFLOP/s, HBM GB/s) for one ``device_kind``; raises on a
+    kind the table does not hold."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks known for device kind {device_kind!r}: add "
+            "it to obs/goodput.py DEVICE_PEAKS with its source, or pin "
+            "TPU_RAG_GOODPUT_PEAK_TFLOPS and TPU_RAG_GOODPUT_HBM_GBS"
+        ) from None
 
 
 class RooflineModel:
@@ -114,18 +135,20 @@ class RooflineModel:
         kv_bytes_per_token: float,
         peak_tflops: float = 0.0,
         hbm_gbs: float = 0.0,
+        device_kind: str = "cpu",
     ):
         if flops_per_token <= 0 or weight_bytes <= 0 or kv_bytes_per_token <= 0:
             raise ValueError("roofline figures must be positive")
         self.flops_per_token = float(flops_per_token)
         self.weight_bytes = float(weight_bytes)
         self.kv_bytes_per_token = float(kv_bytes_per_token)
-        self.peak_flops = (
-            float(peak_tflops) if peak_tflops > 0 else DEFAULT_PEAK_TFLOPS
-        ) * 1e12
-        self.peak_bytes = (
-            float(hbm_gbs) if hbm_gbs > 0 else DEFAULT_HBM_GBS
-        ) * 1e9
+        if not (peak_tflops > 0 and hbm_gbs > 0):
+            # an unpinned peak resolves from the device the engine runs on
+            kind_tflops, kind_gbs = peaks_for_device(device_kind)
+            peak_tflops = peak_tflops if peak_tflops > 0 else kind_tflops
+            hbm_gbs = hbm_gbs if hbm_gbs > 0 else kind_gbs
+        self.peak_flops = float(peak_tflops) * 1e12
+        self.peak_bytes = float(hbm_gbs) * 1e9
 
     # -- derived ---------------------------------------------------------
     @property
@@ -177,6 +200,7 @@ def roofline_for_llama(
     kv_quant: str = "bf16",
     peak_tflops: float = 0.0,
     hbm_gbs: float = 0.0,
+    device_kind: str = "cpu",
 ) -> RooflineModel:
     """The serving stack's roofline from a LlamaConfig's fields.
 
@@ -210,10 +234,13 @@ def roofline_for_llama(
         kv_bytes_per_token=float(kv_bytes),
         peak_tflops=peak_tflops,
         hbm_gbs=hbm_gbs,
+        device_kind=device_kind,
     )
 
 
-def ledger_for(model_config, engine_config) -> "GoodputLedger":
+def ledger_for(
+    model_config, engine_config, device_kind: str = "cpu"
+) -> "GoodputLedger":
     """THE ledger constructor both serving engines share (duck-typed over
     the config dataclasses — still no package imports). One site means the
     two engines' rooflines cannot drift: ``merge_states`` sums their
@@ -233,6 +260,7 @@ def ledger_for(model_config, engine_config) -> "GoodputLedger":
             kv_quant=getattr(engine_config, "kv_quant", "bf16"),
             peak_tflops=getattr(gp, "peak_tflops", 0.0) or 0.0,
             hbm_gbs=getattr(gp, "hbm_gbs", 0.0) or 0.0,
+            device_kind=device_kind,
         ),
         enabled=getattr(gp, "enabled", True),
         chip_hour_usd=getattr(gp, "chip_hour_usd", 0.0) or 0.0,

@@ -625,11 +625,15 @@ def rope_rerotate(k: jax.Array, delta: jax.Array, inv_freqs: jax.Array) -> jax.A
     half = k.shape[-1] // 2
     phase = delta.astype(jnp.float32) * inv_freqs  # [hd/2]
     c, s = jnp.cos(phase), jnp.sin(phase)
-    x1 = k[..., :half].astype(jnp.float32)
-    x2 = k[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1
-    ).astype(k.dtype)
+    # halves as a [..., 2, hd/2] view, re-joined by stack + reshape: the
+    # same arithmetic per element as slicing and concatenating the halves,
+    # but the TPU compiler aborts (a fusion-emitter check on the
+    # lane-unaligned concatenate) on that spelling for fp32 K at hd >= 64
+    xr = k.astype(jnp.float32).reshape(*k.shape[:-1], 2, half)
+    x1, x2 = xr[..., 0, :], xr[..., 1, :]
+    return jnp.stack(
+        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-2
+    ).reshape(k.shape).astype(k.dtype)
 
 
 @jax.jit

@@ -142,7 +142,16 @@ def knn_topk(
     k: int = 5,
     block_n: int = 512,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Backend dispatch: Pallas on TPU, XLA elsewhere."""
-    if jax.default_backend() == "tpu" and embeddings.shape[0] % block_n == 0:
-        return knn_topk_pallas(queries, embeddings, sq_norms, k=k, block_n=block_n)
-    return knn_topk_xla(queries, embeddings, sq_norms, k=k)
+    """Backend dispatch: Pallas on TPU, XLA elsewhere. On TPU a row count
+    that does not tile ``block_n`` is an error, not a quiet fall to the XLA
+    path: the store pads its device snapshot to >= 512-row buckets
+    (index/store.py), so a misaligned count means a caller bypassed it."""
+    if jax.default_backend() != "tpu":
+        return knn_topk_xla(queries, embeddings, sq_norms, k=k)
+    if embeddings.shape[0] % block_n:
+        raise ValueError(
+            f"knn_topk on TPU needs a row count that is a multiple of "
+            f"block_n={block_n}, got {embeddings.shape[0]}: pad the index "
+            "snapshot (VectorStore.device_snapshot does)"
+        )
+    return knn_topk_pallas(queries, embeddings, sq_norms, k=k, block_n=block_n)

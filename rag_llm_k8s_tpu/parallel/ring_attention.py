@@ -60,13 +60,7 @@ def ring_attention(
     B, Sq, H, hd = q.shape
     K = k.shape[2]
     G = H // K
-    # lax.axis_size is jax>=0.6; psum(1, axis) is the portable spelling and
-    # constant-folds to the same static int inside a shard_map trace
-    n = (
-        jax.lax.axis_size(axis_name)
-        if hasattr(jax.lax, "axis_size")
-        else jax.lax.psum(1, axis_name)
-    )
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     scale = hd ** -0.5
 
@@ -129,15 +123,13 @@ def ring_attention_sharded(
     kv_valid: Optional[jax.Array] = None,
 ) -> jax.Array:
     """shard_map wrapper: shards sequences over ``sp``, runs the ring."""
-    from jax.experimental.shard_map import shard_map
-
     if kv_valid is None:
         kv_valid = jnp.ones(k.shape[:2], dtype=bool)
 
     def body(q, k, v, valid):
         return ring_attention(q, k, v, axis_name="sp", causal=causal, kv_valid=valid)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=(
@@ -147,6 +139,6 @@ def ring_attention_sharded(
             P(None, "sp"),
         ),
         out_specs=P(None, "sp", None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v, kv_valid)

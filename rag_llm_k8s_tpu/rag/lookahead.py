@@ -2,8 +2,9 @@
 
 Per-request serving used to be strictly sequential — retrieve → assemble →
 prefill → decode — so every query paid the embed+KNN stage on its critical
-path even while the device was busy decoding *other* requests (BENCH_r05
-measured that stage at ~118-132 ms under load). TeleRAG shows lookahead
+path even while the device was busy decoding *other* requests (the round-5
+capture, before PR 1 and in git history, had that stage at over a hundred
+milliseconds under load). TeleRAG shows lookahead
 retrieval hides this latency entirely under sustained load; SIFT motivates
 having the retrieved chunks' KV already resident before admission. This
 module is the pipeline that does both:

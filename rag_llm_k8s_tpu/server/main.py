@@ -36,16 +36,12 @@ logger = logging.getLogger(__name__)
 def build_service():
     from rag_llm_k8s_tpu.core.config import AppConfig
     from rag_llm_k8s_tpu.core.mesh import make_mesh
-    from rag_llm_k8s_tpu.engine.encoder import EncoderRunner
-    from rag_llm_k8s_tpu.engine.engine import InferenceEngine
-    from rag_llm_k8s_tpu.index.store import VectorStore
     from rag_llm_k8s_tpu.models.loader import (
         config_from_hf_json,
         load_encoder_safetensors,
         load_safetensors_params,
     )
     from rag_llm_k8s_tpu.parallel.sharding import make_streaming_put
-    from rag_llm_k8s_tpu.server.app import RagService
     from rag_llm_k8s_tpu.tokenizer import load_tokenizer
 
     config = AppConfig.from_env()
@@ -117,6 +113,26 @@ def build_service():
     )
     enc_tokenizer = load_tokenizer(config.server.embedder_path)
 
+    return assemble_service(
+        config, mesh, model_cfg, params, llm_tokenizer, enc_params, enc_tokenizer
+    )
+
+
+def assemble_service(
+    config, mesh, model_cfg, params, llm_tokenizer, enc_params, enc_tokenizer
+):
+    """Everything ``build_service`` does AFTER parameter loading: engines,
+    encoder runner, embedder fingerprint, index, scheduler choice,
+    ``RagService``. Takes params and tokenizers from the caller so an entry
+    point that makes its weights from a seed (``chip_smoke.py``) serves
+    through the same assembly a deployment runs, not a copy of it."""
+    import hashlib
+
+    from rag_llm_k8s_tpu.engine.encoder import EncoderRunner
+    from rag_llm_k8s_tpu.engine.engine import InferenceEngine
+    from rag_llm_k8s_tpu.index.store import VectorStore
+    from rag_llm_k8s_tpu.server.app import RagService
+
     engine = InferenceEngine(
         model_cfg,
         params,
@@ -125,15 +141,16 @@ def build_service():
         dtypes=config.dtypes,
         mesh=mesh,
     )
+    # one attention-backend knob for both models: the encoder spells the
+    # decoder's "pallas[_interpret]" as "flash[_interpret]"
     encoder = EncoderRunner(
         config.encoder, enc_params, config.dtypes, mesh=mesh,
         eos_id=getattr(enc_tokenizer, "eos_id", None),
+        attn_impl=config.engine.attn_impl.replace("pallas", "flash"),
     )
 
     # fingerprint the embedder with a probe embedding so a persisted index
     # built by different encoder weights is detected and rebuilt
-    import hashlib
-
     probe = encoder.encode([enc_tokenizer.encode("__embedder_fingerprint__")])[0]
     fingerprint = hashlib.sha256(probe.tobytes()).hexdigest()[:16]
     store = VectorStore.open_or_create(
@@ -189,9 +206,11 @@ def build_service():
 def main():
     import signal
 
+    from rag_llm_k8s_tpu.core.compile_cache import ensure_compile_cache
     from rag_llm_k8s_tpu.resilience import faults
     from rag_llm_k8s_tpu.server.app import create_app
 
+    logger.info("compile cache: %s", ensure_compile_cache())
     service = build_service()
     service.ingest_directory()
     if service.store.ntotal == 0:

@@ -100,7 +100,7 @@ def main():
     params, alpha, top1 = bench.make_params_8b_behavioral(cfg_8b, dtypes, llm_tok)
 
     out = {"alpha": alpha, "top1": top1, "bucket": args.bucket,
-           "tunnel_ms": round(bench.measure_tunnel_fetch_ms(), 1)}
+           "device_fetch_ms": round(bench.measure_device_fetch_ms(), 1)}
 
     def fresh_store():
         s = VectorStore(dim=enc_cfg.embed_dim)

@@ -44,8 +44,8 @@ from rag_llm_k8s_tpu.obs import regression  # noqa: E402
 # lacks them while the baseline has them — unless the current run was
 # budget-truncated before that leg (truncation is already reported).
 # b64_sync16 is tracked higher-is-better by regression.classify; the paged
-# keys are the BENCH_r05 rc-124 casualties (ROADMAP BENCH_r06 housekeeping)
-# — a judged run that silently drops the paged leg must fail, not pass.
+# keys are what the round-5 capture (before PR 1, in git history) lost to
+# its rc 124 — a judged run that silently drops the paged leg must fail, not pass.
 REQUIRED_KEYS = (
     "continuous_device_steps_per_s.b64_sync16",
     "paged_decode_steps_per_s.b64_paged",

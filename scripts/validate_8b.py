@@ -37,6 +37,10 @@ def main():
     import jax.numpy as jnp
     import psutil
 
+    from rag_llm_k8s_tpu.core.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
+
     from rag_llm_k8s_tpu.core.config import DTypePolicy, LlamaConfig, MeshConfig
     from rag_llm_k8s_tpu.core.mesh import make_mesh
     from rag_llm_k8s_tpu.models.loader import load_safetensors_params

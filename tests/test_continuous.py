@@ -41,6 +41,19 @@ def make_engine(cfg, params):
 
 
 class TestContinuousEngine:
+    def test_warmup_primes_state_ops_without_touching_state(self, setup):
+        """warmup() runs the admission path's op-by-op device calls once
+        (so no first request compiles them — chip_smoke.py counts) and
+        leaves the rng stream and the slot state exactly where they were."""
+        cfg, params, _ = setup
+        eng = make_engine(cfg, params)
+        rng, keys, active = (np.asarray(x) for x in
+                             (eng._rng, eng._rng_keys, eng._active))
+        eng.warmup(batch_sizes=(1,), buckets=eng.buckets[:1])
+        assert np.array_equal(np.asarray(eng._rng), rng)
+        assert np.array_equal(np.asarray(eng._rng_keys), keys)
+        assert np.array_equal(np.asarray(eng._active), active)
+
     def test_greedy_parity_with_oneshot(self, setup):
         cfg, params, oracle = setup
         eng = make_engine(cfg, params)

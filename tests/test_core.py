@@ -209,3 +209,46 @@ class TestMesh:
         ctx = single_device_mesh()
         assert ctx.n_devices == 1
         assert ctx.tp == 1
+
+
+class TestCompileCache:
+    """core/compile_cache.py: the cache can be placed from outside, and
+    otherwise sits at ONE fixed path inside the checkout."""
+
+    def test_env_wins_and_nothing_is_set_in_code(self, monkeypatch, tmp_path):
+        from rag_llm_k8s_tpu.core import compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path))
+        assert compile_cache.ensure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_the_fixed_path_in_the_checkout(self, monkeypatch):
+        import os
+
+        from rag_llm_k8s_tpu.core import compile_cache
+
+        monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            path = compile_cache.ensure_compile_cache()
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert path == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert compile_cache.ensure_compile_cache() == path  # never moves
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_entry_count(self, tmp_path):
+        from rag_llm_k8s_tpu.core.compile_cache import cache_entry_count
+
+        assert cache_entry_count(str(tmp_path / "absent")) == 0
+        for name in ("jit_f-abc-cache", "jit_f-abc-atime", "jit_g-def-cache"):
+            (tmp_path / name).write_bytes(b"x")
+        assert cache_entry_count(str(tmp_path)) == 2
+
+
+def test_serving_device_kind_reads_the_mesh(mesh8):
+    from rag_llm_k8s_tpu.core.mesh import serving_device_kind
+
+    assert serving_device_kind(mesh8) == serving_device_kind() == "cpu"

@@ -5,8 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import set_mesh
-
 from rag_llm_k8s_tpu.ops.attention import attention_xla, flash_attention
 
 
@@ -289,7 +287,7 @@ class TestModelPallasPath:
         tokens = jax.random.randint(jax.random.PRNGKey(4), (B, S), 3, cfg.vocab_size)
         pad_mask = jnp.ones((B, S), jnp.int32).at[0, :9].set(0)
         p_ref, d_ref = self._run_prefill_decode(oracle, cfg, params, mkc, tokens, pad_mask, T)
-        with set_mesh(mesh8.mesh):
+        with jax.set_mesh(mesh8.mesh):
             p_got, d_got = self._run_prefill_decode(pallas, cfg, params, mkc, tokens, pad_mask, T)
         valid = pad_mask.astype(bool)[:, :, None]
         np.testing.assert_allclose(

@@ -99,6 +99,30 @@ def _roofline():
 # roofline arithmetic
 # ---------------------------------------------------------------------------
 class TestRoofline:
+    def test_unpinned_peaks_come_from_the_device_kind(self):
+        """ROADMAP C8: a v5e is priced as a v5e, pins win over the table,
+        and a kind the table does not hold is an error, not a default."""
+        v5e = goodput.RooflineModel(1.0, 1.0, 1.0, device_kind="TPU v5 lite")
+        assert (v5e.peak_flops, v5e.peak_bytes) == (197e12, 819e9)
+        assert goodput.peaks_for_device("TPU v5 lite") == (197.0, 819.0)
+        pinned = goodput.RooflineModel(
+            1.0, 1.0, 1.0, peak_tflops=100.0, hbm_gbs=500.0, device_kind="TPU v9"
+        )
+        assert (pinned.peak_flops, pinned.peak_bytes) == (100e12, 500e9)
+        half = goodput.RooflineModel(1.0, 1.0, 1.0, peak_tflops=100.0,
+                                     device_kind="TPU v5 lite")
+        assert (half.peak_flops, half.peak_bytes) == (100e12, 819e9)
+        with pytest.raises(ValueError, match="TPU v9"):
+            goodput.RooflineModel(1.0, 1.0, 1.0, device_kind="TPU v9")
+        with pytest.raises(ValueError, match="DEVICE_PEAKS"):
+            goodput.ledger_for(
+                LlamaConfig.tiny(), EngineConfig(), device_kind="TPU v9"
+            )
+        ledger = goodput.ledger_for(
+            LlamaConfig.tiny(), EngineConfig(), device_kind="TPU v5 lite"
+        )
+        assert ledger.roofline.peak_bytes == 819e9
+
     def test_figures_and_ridge(self):
         rf = _roofline()
         assert rf.flops_per_token > 0 and rf.weight_bytes > 0

@@ -44,6 +44,20 @@ class TestKnnKernel:
         oracle_idx = np.argsort(d, axis=1)[:, :5]
         np.testing.assert_array_equal(np.asarray(ei), oracle_idx)
 
+    def test_dispatch_refuses_a_misaligned_index_on_tpu(self, monkeypatch):
+        """On the chip a row count that does not tile block_n is an error,
+        never a quiet fall to the XLA path; off the chip XLA serves."""
+        from rag_llm_k8s_tpu.ops import knn
+
+        q, e, norms, _ = _random_problem(3, N=700)
+        args = (jnp.asarray(q), jnp.asarray(e), jnp.asarray(norms)[None, :])
+        _, want = knn_topk_xla(*args, k=5)
+        _, got = knn.knn_topk(*args, k=5)  # CPU: any row count
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        monkeypatch.setattr(knn.jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="multiple of block_n=512"):
+            knn.knn_topk(*args, k=5)
+
     def test_single_query_single_block(self):
         q, e, norms, _ = _random_problem(2, N=256, Q=1)
         pv, pi = knn_topk_pallas(

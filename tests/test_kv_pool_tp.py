@@ -262,8 +262,6 @@ class TestShardedPagedKernelParity:
         return tables
 
     def test_sharded_paged_decode_matches_oracle(self):
-        from jax.experimental.shard_map import shard_map
-
         from rag_llm_k8s_tpu.ops.attention import (
             paged_decode_attention,
             paged_decode_attention_xla,
@@ -279,12 +277,12 @@ class TestShardedPagedKernelParity:
         tables = self._tables(B, MB, bs, kv_len)
         q = jnp.asarray(rng.standard_normal((B, 1, H, hd)).astype(np.float32))
         in_specs, out_spec = paged_partition_specs("decode")
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q_, k_, v_, t_, l_, lay_: paged_decode_attention(
                 q_, k_, v_, t_, l_, lay_, interpret=True
             ),
             mesh=self._mesh(), in_specs=in_specs, out_specs=out_spec,
-            check_rep=False,
+            check_vma=False,
         )
         for lay in range(L):
             lay1 = jnp.asarray(lay, jnp.int32).reshape(1)
@@ -297,8 +295,6 @@ class TestShardedPagedKernelParity:
             )
 
     def test_sharded_paged_chunk_matches_oracle(self):
-        from jax.experimental.shard_map import shard_map
-
         from rag_llm_k8s_tpu.ops.attention import (
             paged_chunk_attention,
             paged_chunk_attention_xla,
@@ -315,12 +311,12 @@ class TestShardedPagedKernelParity:
         tables = self._tables(B, MB, bs, kv_len)
         q = jnp.asarray(rng.standard_normal((B, S, H, hd)).astype(np.float32))
         in_specs, out_spec = paged_partition_specs("chunk")
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q_, k_, v_, t_, l_, lay_, wi_: paged_chunk_attention(
                 q_, k_, v_, t_, l_, lay_, wi_, bq=4, interpret=True
             ),
             mesh=self._mesh(), in_specs=in_specs, out_specs=out_spec,
-            check_rep=False,
+            check_vma=False,
         )
         lay1 = jnp.asarray(1, jnp.int32).reshape(1)
         got = fn(
@@ -334,8 +330,6 @@ class TestShardedPagedKernelParity:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
     def test_sharded_paged_q8_decode_matches_oracle(self):
-        from jax.experimental.shard_map import shard_map
-
         from rag_llm_k8s_tpu.ops.attention import (
             paged_decode_attention_q8,
             paged_decode_attention_xla_q8,
@@ -353,12 +347,12 @@ class TestShardedPagedKernelParity:
         tables = self._tables(B, MB, bs, kv_len)
         q = jnp.asarray(rng.standard_normal((B, 1, H, hd)).astype(np.float32))
         in_specs, out_spec = paged_partition_specs("decode", q8=True)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q_, k_, v_, ks_, vs_, t_, l_, lay_: paged_decode_attention_q8(
                 q_, k_, v_, ks_, vs_, t_, l_, lay_, interpret=True
             ),
             mesh=self._mesh(), in_specs=in_specs, out_specs=out_spec,
-            check_rep=False,
+            check_vma=False,
         )
         lay1 = jnp.asarray(0, jnp.int32).reshape(1)
         args = (
@@ -373,8 +367,6 @@ class TestShardedPagedKernelParity:
         """The fused q8 paged chunk kernel (it replaced PR 5's gather
         oracle) under the SERVING partition specs — warm-tier chunked
         prefill is shard-aware like every other paged path."""
-        from jax.experimental.shard_map import shard_map
-
         from rag_llm_k8s_tpu.ops.attention import (
             paged_chunk_attention_q8,
             paged_chunk_attention_xla_q8,
@@ -393,7 +385,7 @@ class TestShardedPagedKernelParity:
         tables = self._tables(B, MB, bs, kv_len)
         q = jnp.asarray(rng.standard_normal((B, S, H, hd)).astype(np.float32))
         in_specs, out_spec = paged_partition_specs("chunk", q8=True)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q_, k_, v_, ks_, vs_, t_, l_, lay_, wi_: (
                 paged_chunk_attention_q8(
                     q_, k_, v_, ks_, vs_, t_, l_, lay_, wi_, bq=4,
@@ -401,7 +393,7 @@ class TestShardedPagedKernelParity:
                 )
             ),
             mesh=self._mesh(), in_specs=in_specs, out_specs=out_spec,
-            check_rep=False,
+            check_vma=False,
         )
         lay1 = jnp.asarray(1, jnp.int32).reshape(1)
         args = (

@@ -528,7 +528,7 @@ class TestQuantTP:
         PR 6): tiny()'s K=2 kv heads do not tile tp=4, so the flat k/v
         projection output — column-sharded over tp by the param specs —
         reshapes to a SUB-head-sharded ``[B, S, K, hd]`` layout, and with
-        ``dp`` also populated this container's jax 0.4.x GSPMD miscompiles
+        ``dp`` also populated GSPMD (first seen on jax 0.4.x) miscompiles
         the slice+concat rotate-by-halves RoPE over it: the jitted forward
         returns wrong VALUES (~0.3 absolute on these logits) while eager is
         exact. ``replicate_undividable_heads`` (models/llama.py) degrades

@@ -5,8 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import set_mesh
-
 from rag_llm_k8s_tpu.core.config import MeshConfig
 from rag_llm_k8s_tpu.core.mesh import make_mesh
 from rag_llm_k8s_tpu.parallel.ring_attention import ring_attention_sharded
@@ -119,7 +117,7 @@ class TestModelSequenceParallel:
 
         ring_model = LlamaModel(cfg, FP32, attn_impl="xla", mesh=sp_mix_mesh.mesh)
         cache = make_kv_cache(cfg, B, S, jnp.float32)
-        with set_mesh(sp_mix_mesh.mesh):
+        with jax.set_mesh(sp_mix_mesh.mesh):
             got, _ = jax.jit(
                 lambda p, t: ring_model.apply(
                     {"params": p}, t, pos, cache, *window, jnp.int32(0)
@@ -153,7 +151,7 @@ class TestModelSequenceParallel:
         _, _, loss1 = jax.jit(step_sp1)(params, init_opt(params), tokens, mask)
 
         init_opt2, step_ring = make_train_step(cfg, FP32, mesh=sp_mix_mesh.mesh)
-        with set_mesh(sp_mix_mesh.mesh):
+        with jax.set_mesh(sp_mix_mesh.mesh):
             p2, _, loss2 = jax.jit(step_ring)(params, init_opt2(params), tokens, mask)
         np.testing.assert_allclose(float(loss1), float(loss2), rtol=1e-5)
         # updated params must match too (gradients flowed through the ring)

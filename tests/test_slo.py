@@ -568,7 +568,7 @@ BASE_BENCH = {
     "query_qps_load": 4.5,
     "coalesce_tok_per_s": 1700.0,
     "query_stage_ms": {"generate": 770.0, "embed_retrieve": 6.0},
-    "tunnel_fetch_ms": 100.0,
+    "device_fetch_ms": 100.0,
     "query_n": 20,
     "spec_8b_identical": True,
 }
@@ -610,7 +610,7 @@ class TestRegressionGate:
 
     def test_ignored_keys_never_flag(self):
         cur = dict(
-            BASE_BENCH, tunnel_fetch_ms=900.0, query_n=3, spec_8b_identical=False
+            BASE_BENCH, device_fetch_ms=900.0, query_n=3, spec_8b_identical=False
         )
         out = regression.compare(cur, BASE_BENCH)
         assert out["regression"] == []
@@ -727,7 +727,7 @@ class TestBenchGateCli:
 
 
 # ---------------------------------------------------------------------------
-# bench budget truncation (satellite: BENCH_r05 rc-124 data loss)
+# bench budget truncation (the round-5 capture lost its data to rc 124)
 # ---------------------------------------------------------------------------
 
 

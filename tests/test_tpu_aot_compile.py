@@ -1,0 +1,183 @@
+"""The main path's Pallas kernels compile for a TPU v5e at Llama-3.1-8B widths.
+
+No chip is attached: the TPU compiler installed here compiles for a
+DESCRIBED ``v5e:2x2`` topology, which refuses what Mosaic would refuse on the
+machine — a slice not aligned to the tiling, a kernel over its VMEM or SMEM
+budget, a kernel that cannot be partitioned. Interpret mode shows none of
+that. Nothing runs, so these say nothing about results or times
+(``chip_smoke.py`` phase 2 is where the same kernels meet their oracles).
+
+Everything that touches the topology happens inside fixtures and tests,
+never at import: only one process may hold libtpu, and every xdist worker
+imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+from rag_llm_k8s_tpu.ops import attention as A
+from rag_llm_k8s_tpu.ops.knn import knn_topk_pallas
+
+# Llama-3.1-8B: 32 query heads over 8 KV heads of 128, 32 layers; slots of
+# 4352 tokens (the 4096 bucket + 256), so 272 blocks of 16 / 136 of 32 a row
+H, K, HD, L, T = 32, 8, 128, 32, 4352
+BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from describing it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def uncached():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip (the next one warns and compiles
+    again): keep the cache out of these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _arena(q8: bool, n_blocks: int, bs: int):
+    payload = ((L, n_blocks, K, bs, HD), I8 if q8 else BF16)
+    if not q8:
+        return [payload, payload]
+    scale = ((L, n_blocks, K, bs), F32)
+    return [payload, payload, scale, scale]
+
+
+def _paged(kernel, q8: bool, B: int, chunk: int = 0):
+    """(fn, [(shape, dtype), ...]) for one paged kernel. The arena is what
+    one chip's HBM leaves for it next to 8B weights (eight full rows plus
+    the null block), whatever the batch: rows share the pool."""
+    bs = 32 if q8 else 16
+    mb = T // bs
+    args = [((B, chunk or 1, H, HD), BF16), *_arena(q8, 8 * mb + 1, bs),
+            ((B, mb), I32), ((B,), I32), ((), I32)]
+    if chunk:
+        args.append(((B,), I32))  # per-row write_index
+    return kernel, args
+
+
+def _dense_cache(q8: bool, B: int):
+    payload = ((L, B, K, T, HD), I8 if q8 else BF16)
+    if not q8:
+        return [payload, payload]
+    scale = ((L, B, K, T), F32)
+    return [payload, payload, scale, scale]
+
+
+def _flash(S: int, heads: int, kv_heads: int, hd: int, B: int, causal: bool):
+    def fn(q, k, v, kv_len):
+        return A.flash_attention(q, k, v, kv_len=kv_len, causal=causal)
+
+    qkv = [((B, S, h, hd), BF16) for h in (heads, kv_heads, kv_heads)]
+    return fn, [*qkv, ((B,), I32)]
+
+
+def _rerotate(dtype):
+    """``rope_rerotate`` over one cached segment's K plane. fp32 is here on
+    purpose: slicing and concatenating the halves aborted the TPU compiler
+    for fp32 at hd >= 64 (found on the chip, PR 21)."""
+    inv = ((HD // 2,), F32)
+    return A.rope_rerotate, [((L, 1, K, 256, HD), dtype), ((), I32), inv]
+
+
+CASES = {
+    "rope_rerotate[bf16]": _rerotate(BF16),
+    "rope_rerotate[fp32]": _rerotate(F32),
+    "rope_rerotate_q8": (
+        A.rope_rerotate_q8,
+        [((L, 1, K, 256, HD), I8), ((L, 1, K, 256), F32), ((), I32), ((HD // 2,), F32)],
+    ),
+    **{
+        f"{name}[B={B}]": _paged(getattr(A, name), "q8" in name, B, chunk)
+        for name, chunk in (
+            ("paged_decode_attention", 0),
+            ("paged_decode_attention_q8", 0),
+            ("paged_chunk_attention", 256),
+            ("paged_chunk_attention_q8", 256),
+        )
+        for B in (8, 64)
+    },
+    "flash_attention[4096]": _flash(4096, H, K, HD, 1, True),
+    "flash_attention[encoder hd=64]": _flash(1536, 16, 16, 64, 8, False),
+    "decode_attention": (
+        A.decode_attention,
+        [((8, 1, H, HD), BF16), *_dense_cache(False, 8), ((8,), I32), ((8,), I32), ((), I32)],
+    ),
+    "decode_attention_q8": (
+        A.decode_attention_q8,
+        [((8, 1, H, HD), BF16), *_dense_cache(True, 8), ((8,), I32), ((8,), I32), ((), I32)],
+    ),
+    "chunk_prefill_attention": (
+        A.chunk_prefill_attention,
+        [((1, 512, H, HD), BF16), *_dense_cache(False, 1),
+         ((1,), I32), ((1,), I32), ((), I32), ((), I32)],
+    ),
+    "chunk_prefill_attention_q8": (
+        A.chunk_prefill_attention_q8,
+        [((1, 512, H, HD), BF16), *_dense_cache(True, 1),
+         ((1,), I32), ((1,), I32), ((), I32), ((), I32)],
+    ),
+    "knn_topk_pallas": (
+        lambda q, emb, norms: knn_topk_pallas(q, emb, norms, k=5),
+        [((8, 1024), F32), ((131072, 1024), F32), ((1, 131072), F32)],
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip, uncached):
+    fn, args = CASES[name]
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
+    compiled = jax.jit(fn).lower(*avals).compile()
+    if not name.startswith("rope_"):  # plain XLA ops, no Pallas
+        assert "tpu_custom_call" in compiled.as_text(), f"{name}: no Mosaic kernel"
+
+
+def test_sharded_paged_decode_compiles_for_four_chips(topo, uncached):
+    """The serving layout at tp=4: the paged decode kernel ``shard_map``'d
+    over a 4-device mesh with ``paged_partition_specs`` — each device runs
+    the kernel on its 8 query / 2 KV heads of every block."""
+    mesh = Mesh(list(topo.devices), ("tp",))
+    in_specs, out_spec = A.paged_partition_specs("decode", q8=False)
+    fn = jax.shard_map(
+        A.paged_decode_attention, mesh=mesh, in_specs=in_specs,
+        out_specs=out_spec, check_vma=False,
+    )
+    _, args = _paged(A.paged_decode_attention, False, 8)
+    args[-1] = ((1,), I32)  # the model hands the layer index as a [1] vector
+    avals = [
+        jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, spec))
+        for (s, d), spec in zip(args, in_specs)
+    ]
+    compiled = jax.jit(fn).lower(*avals).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # heads are independent: no collective belongs in this program
+    assert "all-reduce" not in text and "all-gather" not in text
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    arena_bytes = 2 * L * (8 * 272 + 1) * K * 16 * HD * 2
+    assert per_device < arena_bytes / 4 * 1.05, (per_device, arena_bytes)

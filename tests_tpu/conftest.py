@@ -7,12 +7,19 @@ repeatable artifact, not a commit-message claim. Run via ``make tpu-test``
 or ``python -m pytest tests_tpu/ -q`` (skips itself entirely off-TPU).
 """
 
-import jax
 import pytest
 
 
-def pytest_collection_modifyitems(config, items):
+@pytest.fixture(scope="session", autouse=True)
+def tpu_backend():
+    """Every test of this lane needs the chip. Decided HERE, when the first
+    test runs — not while pytest collects: touching the backend at import or
+    in a collection hook takes the chip in whatever process merely lists
+    the tests."""
+    import jax
+
+    from rag_llm_k8s_tpu.core.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     if jax.default_backend() != "tpu":
-        skip = pytest.mark.skip(reason=f"needs TPU (backend={jax.default_backend()})")
-        for item in items:
-            item.add_marker(skip)
+        pytest.skip(f"needs TPU (backend={jax.default_backend()})")
