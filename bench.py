@@ -2292,8 +2292,7 @@ def make_params_8b_behavioral(llama_cfg, dtypes, llm_tok):
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
     def gen_leaf(path, s, key):
-        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        kind = synth_leaf_kind(name, s.dtype, s.ndim)
+        kind = synth_leaf_kind(tuple(p.key for p in path), s.dtype)
         if kind == "kernel_q":
             # int8 directly: an int32 intermediate on the [32,4096,14336]
             # leaves would transiently cost ~7.5 GiB of the 16 GiB chip.

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Bring-up proof: the retrieve-then-generate path on a TPU v5e, end to end.
 
-    python chip_smoke.py [--seed N] [--layers N]      # one chip, one process
+    python chip_smoke.py [--seed N]                   # one chip, one process
     python chip_smoke.py --chips 4 [--seed N]         # the sharded path only
 
 The default run drives the system's main path once through the entry points
@@ -20,8 +20,11 @@ device from ``--seed``:
 4. the paged continuous shape (block-pool KV sized from free HBM, mixed
    prefill/decode windows, paged draft-and-verify) on the same params;
 5. checks on 3 and 4: token counts, finite logits, the shadow auditor at
-   sample rate 1.0 inside its pinned tolerance, no executable built while
-   /generate requests were in flight, the Pallas path present in the
+   sample rate 1.0 inside its pinned tolerance, no executable built on any
+   thread between the return of ``warmup()`` and the last audit — except
+   inside the ``/upload_pdf`` request, whose builds are counted and printed
+   (ingest into an empty index pays for the shapes its data brings, by
+   design: no /generate ever does) — the Pallas path present in the
    compiled programs, the block pool drained.
 
 Any failed check raises: the exit code is non-zero and no result line is
@@ -50,6 +53,11 @@ T_START = time.monotonic()
 NEW_TOKENS = 150  # the reference budget (rag.py:172; deploy.yaml)
 AUDIT_TOL = 0.15  # SloConfig.quality_logit_err — the auditor's pinned bound
 ATTN_IMPL = "pallas"  # explicit, never "auto": no backend sniffing on this path
+# Depth served. A cold one-chip run at full depth takes ~11 of the 20 minutes
+# allowed; if that ever stops fitting, cut LAYERS here — never a width — and
+# the "params" line prints the depth served.
+LAYERS = 32
+CUT_LAYERS = 8  # --chips 4: depth at which the same params also fit one chip
 GIB = float(1 << 30)
 
 QUERIES = (
@@ -450,7 +458,9 @@ def serve_and_check(name, config, mesh, params, tokenizers, enc_params,
     device = mesh.mesh.devices.flat[0]
     n_err0 = len(errors.records)
     service = assemble_service(
-        config, mesh, config.model, params, llm_tok, enc_params, enc_tok
+        config, mesh, config.model, params, llm_tok, enc_params, enc_tok,
+        # the encoder's spelling of the decoder's explicit backend
+        encoder_attn_impl=config.engine.attn_impl.replace("pallas", "flash"),
     )
     engine = service.engine
     sched_engine = getattr(service.scheduler, "engine", engine)
@@ -464,9 +474,10 @@ def serve_and_check(name, config, mesh, params, tokenizers, enc_params,
     hits0, miss0 = counter.cache_hits, counter.cache_misses
     t0 = time.monotonic()
     service.warmup()
-    service.restore_from_wal()  # what server/main runs after warmup
     warm_s = time.monotonic() - t0
     built = counter.since(mark)
+    mark_ready = counter.mark()  # every build from here on is after warmup()
+    service.restore_from_wal()  # what server/main runs after warmup
     say(name, event="warmup", seconds=round(warm_s, 1), executables=len(built),
         compile_seconds=round(sum(s for _, s in built), 1),
         cache_hits=counter.cache_hits - hits0,
@@ -493,13 +504,19 @@ def serve_and_check(name, config, mesh, params, tokenizers, enc_params,
         status, info = http("GET", base + "/index_info")
         check(status == 200 and info.get("total_vectors", 0) >= 5,
               f"/index_info: {status} {str(info)[:200]}")
-        # the first ingest into an empty index builds the executables its
-        # new shapes need (encoder batch, fused retrieve, assembly) inside
-        # the upload — by design, and reported, not hidden
+        mark_ingested = counter.mark()
+        # FINDING, printed on every run: the first ingest into an empty
+        # index builds the executables its data's shapes need (encoder at
+        # the chunk batch, fused retrieve, prompt assembly) inside the
+        # upload request, after warmup() returned — ingest pays for index
+        # growth so that no /generate does. These are the only builds the
+        # after-warmup check below lets through.
+        by_upload = counter.builds[mark_ingest:mark_ingested]
         say(name, event="upload_pdf", seconds=round(time.monotonic() - t0, 1),
             message=body.get("message"), total_vectors=info["total_vectors"],
             dimension=info["dimension"],
-            executables_built_by_ingest=len(counter.since(mark_ingest)))
+            executables_built_inside_upload=len(by_upload),
+            compile_seconds_inside_upload=round(sum(s for _, s in by_upload), 1))
 
         def generate(query, out):
             t = time.monotonic()
@@ -509,7 +526,6 @@ def serve_and_check(name, config, mesh, params, tokenizers, enc_params,
         def tokens_served():
             return int(service.metrics.snapshot().get("query_decode_tokens", 0))
 
-        mark_serve = counter.mark()
         responses = []
         tok0 = tokens_served()
         for q in QUERIES[:n_solo]:
@@ -533,13 +549,9 @@ def serve_and_check(name, config, mesh, params, tokenizers, enc_params,
         served = tokens_served() - tok0
         check(served == n_req * NEW_TOKENS,
               f"{served} tokens served, expected {n_req * NEW_TOKENS}")
-        in_flight = [b for b in counter.since(mark_serve) if b[0] != "shadow-audit"]
         say(name, event="generate", requests=n_req, tokens=served,
             latencies_s=[round(s, 2) for _, _, s in responses],
-            timings_ms=[r[1]["timings"] for r in responses[:1]],
-            executables_built_in_flight=in_flight)
-        check(not in_flight,
-              f"executables were built while requests were in flight: {in_flight}")
+            timings_ms=[r[1]["timings"] for r in responses[:1]])
 
         status, metrics = http("GET", base + "/metrics")
         check(status == 200 and "rag_request_duration_seconds_bucket" in metrics,
@@ -553,16 +565,21 @@ def serve_and_check(name, config, mesh, params, tokenizers, enc_params,
     # exact path says, inside the pinned tolerance
     check(service.shadow.drain(timeout=600.0), "shadow audits did not finish")
     st = service.shadow.state()
-    audit_builds = [b for b in counter.since(mark_serve) if b[0] == "shadow-audit"]
     say(name, event="shadow", audits=st["audits"], skips=st["skips"],
         err_max=st["err_max"], tokens_compared=st["tokens_compared"],
-        attribution=st["attribution"],
-        scorer_executables_built_lazily=len(audit_builds))
+        attribution=st["attribution"])
     judged = st["audits"]["clean"] + st["audits"]["diverged"]
     check(judged == n_req and not st["audits"]["failed"] and not st["skips"],
           f"not every served request was audited: {st['audits']} {st['skips']}")
     check(st["err_max"] <= AUDIT_TOL,
           f"shadow audit err_max {st['err_max']} over tolerance {AUDIT_TOL}")
+
+    # nothing was built after warmup() returned — on the request threads,
+    # the scheduler's or the auditor's — but what the upload built
+    late = counter.builds[mark_ready:mark_ingest] + counter.since(mark_ingested)
+    say(name, event="built_after_warmup", outside_upload=late,
+        inside_upload=len(by_upload))
+    check(not late, f"executables were built after warmup() returned: {late}")
 
     # finite logits, read straight from the exact scorer
     ids = [config.model.bos_token_id] + llm_tok.encode(QUERIES[0])
@@ -679,7 +696,7 @@ def run_one_chip(args, counter, errors) -> None:
     tokenizers = load_tokenizers()
     mesh = make_mesh(MeshConfig(dp=1, sp=1, tp=1), devices=[device])
     dtypes = DTypePolicy()
-    model = dataclasses.replace(LlamaConfig.llama_3_1_8b(), num_layers=args.layers)
+    model = dataclasses.replace(LlamaConfig.llama_3_1_8b(), num_layers=LAYERS)
     t0 = time.monotonic()
     params = synth_llama_params(
         model, dtypes, args.seed, quant="int8", mesh=mesh, recite_gain=5.0
@@ -744,7 +761,7 @@ def run_four_chips(args, counter, errors) -> None:
     mesh1 = make_mesh(MeshConfig(dp=1, sp=1, tp=1), devices=[devices[0]])
     dtypes = DTypePolicy()
     full = LlamaConfig.llama_3_1_8b()
-    cut = dataclasses.replace(full, num_layers=args.cut_layers)
+    cut = dataclasses.replace(full, num_layers=CUT_LAYERS)
     check(cut.num_kv_heads % 4 == 0 and cut.num_heads % 4 == 0,
           "head counts do not tile tp=4: attention would fall to the XLA path")
     sampling = SamplingConfig(do_sample=False, max_new_tokens=64)
@@ -885,10 +902,6 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the tensor-parallel phase (builder-run)")
-    ap.add_argument("--layers", type=int, default=32,
-                    help="decoder depth served on one chip (widths are never cut)")
-    ap.add_argument("--cut-layers", type=int, default=8,
-                    help="--chips 4: depth of the tp=4 vs tp=1 comparison")
     args = ap.parse_args()
 
     import jax

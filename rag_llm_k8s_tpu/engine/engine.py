@@ -1143,6 +1143,23 @@ class InferenceEngine:
             "chosen_logit": stats[sl, 2].astype(np.float64),
         }
 
+    def warm_score_exact(self, buckets: Sequence[int]) -> None:
+        """AOT-compile the exact scorer for every padded length a prompt
+        landing in one of the prompt ``buckets`` plus its token budget can
+        reach — one executable per multiple of the score chunk.
+        Without this the first audit at each length compiles on the audit
+        thread while requests are being served."""
+        ladder = sorted(self.engine_config.prompt_buckets)
+        chunk = min(self._SCORE_CHUNK, max(ladder))
+        for b in buckets:
+            below = [x for x in ladder if x < b]
+            # shortest prompt the bucket takes + one emitted token ... a
+            # full bucket + the whole budget
+            lo = (below[-1] + 1 if below else 1) + 1
+            hi = b + self._clamp_max_new(b, self.sampling.max_new_tokens)
+            for S in range(-(-lo // chunk) * chunk, -(-hi // chunk) * chunk + 1, chunk):
+                self._get_score_exact(S, chunk)
+
     def _get_score_exact(self, S: int, chunk: int):
         key = (1, S, 0, ("shadow", chunk))
         with self._lock:

@@ -1162,24 +1162,28 @@ def quantize_llama_params(params: dict, donate: bool = False) -> dict:
     return quant
 
 
-def synth_leaf_kind(name: str, dtype, ndim: int) -> str:
-    """Classify a QUANTIZED-Llama param leaf for the synthetic weight
-    builders (bench.py's behavioral 8B tree, __graft_entry__'s tp-sharded
-    serving dry-run): ``"kernel_q"`` (int8 kernels), ``"quant_scale"``
-    (per-channel dequant scales), ``"norm"`` (RMSNorm weights — MUST stay
-    ~1), or ``"embedding"`` (the bf16 table). Quant scales match by EXACT
-    name: RMSNorm weights are ALSO called "scale" in the Flax tree, and a
+def synth_leaf_kind(path, dtype) -> str:
+    """Classify a Llama param leaf, given its ``path`` of string keys, for
+    the synthetic weight builders (``utils/synth.synth_llama_params``,
+    bench.py's behavioral 8B tree): ``"kernel_q"`` (int8 kernels),
+    ``"quant_scale"`` (per-channel dequant scales), ``"norm"`` (RMSNorm
+    weights — MUST stay ~1), ``"embedding"`` (the token table) or
+    ``"kernel"`` (bf16 projection kernels and output head). Norms go by
+    their module's name, not by rank: the layers are stacked, so a norm
+    weight is an ``[L, D]`` leaf. Quant scales match by EXACT leaf name:
+    RMSNorm weights are ALSO called "scale" in the Flax tree, and a
     substring match once flattened every norm to ~1e-4 and collapsed the
     network to flat logits."""
     import numpy as np
 
+    name = path[-1]
     if np.dtype(dtype) == np.int8:
         return "kernel_q"
     if name in ("qscale", "lm_head_scale", "embedding_scale"):
         return "quant_scale"
-    if ndim == 1 or "norm" in name:
+    if any("norm" in part for part in path):
         return "norm"
-    return "embedding"
+    return "embedding" if name == "embedding" else "kernel"
 
 
 def init_llama_params(

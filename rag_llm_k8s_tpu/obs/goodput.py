@@ -94,15 +94,12 @@ KINDS = ("prefill", "prefill_px", "decode", "verify", "oneshot", "mixed")
 #: GB/s), used when the config does not pin them
 #: (TPU_RAG_GOODPUT_PEAK_TFLOPS / TPU_RAG_GOODPUT_HBM_GBS). A kind that is
 #: not in the table is an error, never a default — pricing one chip with
-#: another's peaks makes every MFU and roofline share silently wrong.
+#: another's peaks makes every MFU and roofline share silently wrong. The
+#: host CPU has no row: an enabled ledger there needs both peaks pinned
+#: (tests/conftest.py pins nominal ones for the suite).
 DEVICE_PEAKS = {
     # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
     "TPU v5 lite": (197.0, 819.0),
-    # the host platform the tests run on has no roofline worth the name:
-    # nominal figures keep every RELATIVE read (category split, bubble
-    # fraction, per-request attribution, regression direction) defined;
-    # absolute MFU on a CPU host is meaningless-small by construction
-    "cpu": (275.0, 1200.0),
 }
 
 
@@ -114,8 +111,9 @@ def peaks_for_device(device_kind: str) -> Tuple[float, float]:
     except KeyError:
         raise ValueError(
             f"no roofline peaks known for device kind {device_kind!r}: add "
-            "it to obs/goodput.py DEVICE_PEAKS with its source, or pin "
-            "TPU_RAG_GOODPUT_PEAK_TFLOPS and TPU_RAG_GOODPUT_HBM_GBS"
+            "it to obs/goodput.py DEVICE_PEAKS with its source, pin "
+            "TPU_RAG_GOODPUT_PEAK_TFLOPS and TPU_RAG_GOODPUT_HBM_GBS, or "
+            "switch the ledger off (TPU_RAG_GOODPUT=0)"
         ) from None
 
 
@@ -133,20 +131,16 @@ class RooflineModel:
         flops_per_token: float,
         weight_bytes: float,
         kv_bytes_per_token: float,
-        peak_tflops: float = 0.0,
-        hbm_gbs: float = 0.0,
-        device_kind: str = "cpu",
+        peak_tflops: float,
+        hbm_gbs: float,
     ):
         if flops_per_token <= 0 or weight_bytes <= 0 or kv_bytes_per_token <= 0:
             raise ValueError("roofline figures must be positive")
+        if peak_tflops <= 0 or hbm_gbs <= 0:
+            raise ValueError("roofline peaks must be positive")
         self.flops_per_token = float(flops_per_token)
         self.weight_bytes = float(weight_bytes)
         self.kv_bytes_per_token = float(kv_bytes_per_token)
-        if not (peak_tflops > 0 and hbm_gbs > 0):
-            # an unpinned peak resolves from the device the engine runs on
-            kind_tflops, kind_gbs = peaks_for_device(device_kind)
-            peak_tflops = peak_tflops if peak_tflops > 0 else kind_tflops
-            hbm_gbs = hbm_gbs if hbm_gbs > 0 else kind_gbs
         self.peak_flops = float(peak_tflops) * 1e12
         self.peak_bytes = float(hbm_gbs) * 1e9
 
@@ -198,9 +192,9 @@ def roofline_for_llama(
     vocab_size: int,
     weight_bytes_per_param: float = 2.0,
     kv_quant: str = "bf16",
-    peak_tflops: float = 0.0,
-    hbm_gbs: float = 0.0,
-    device_kind: str = "cpu",
+    *,
+    peak_tflops: float,
+    hbm_gbs: float,
 ) -> RooflineModel:
     """The serving stack's roofline from a LlamaConfig's fields.
 
@@ -234,21 +228,29 @@ def roofline_for_llama(
         kv_bytes_per_token=float(kv_bytes),
         peak_tflops=peak_tflops,
         hbm_gbs=hbm_gbs,
-        device_kind=device_kind,
     )
 
 
-def ledger_for(
-    model_config, engine_config, device_kind: str = "cpu"
-) -> "GoodputLedger":
+def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger":
     """THE ledger constructor both serving engines share (duck-typed over
     the config dataclasses — still no package imports). One site means the
     two engines' rooflines cannot drift: ``merge_states`` sums their
     states into one report, which is only meaningful when both were
-    derived from the same arithmetic."""
+    derived from the same arithmetic. A peak the config leaves unpinned
+    resolves from ``device_kind`` (``peaks_for_device``: an unknown kind
+    raises) — only for an enabled ledger; a disabled one prices nothing
+    and holds no roofline."""
     gp = getattr(engine_config, "goodput", None)
-    return GoodputLedger(
-        roofline_for_llama(
+    enabled = getattr(gp, "enabled", True)
+    roofline = None
+    if enabled:
+        peak_tflops = getattr(gp, "peak_tflops", 0.0) or 0.0
+        hbm_gbs = getattr(gp, "hbm_gbs", 0.0) or 0.0
+        if not (peak_tflops > 0 and hbm_gbs > 0):
+            kind_tflops, kind_gbs = peaks_for_device(device_kind)
+            peak_tflops = peak_tflops if peak_tflops > 0 else kind_tflops
+            hbm_gbs = hbm_gbs if hbm_gbs > 0 else kind_gbs
+        roofline = roofline_for_llama(
             model_config.num_layers, model_config.hidden_size,
             model_config.num_heads, model_config.num_kv_heads,
             model_config.head_dim, model_config.intermediate_size,
@@ -258,11 +260,12 @@ def ledger_for(
                 else 2.0
             ),
             kv_quant=getattr(engine_config, "kv_quant", "bf16"),
-            peak_tflops=getattr(gp, "peak_tflops", 0.0) or 0.0,
-            hbm_gbs=getattr(gp, "hbm_gbs", 0.0) or 0.0,
-            device_kind=device_kind,
-        ),
-        enabled=getattr(gp, "enabled", True),
+            peak_tflops=peak_tflops,
+            hbm_gbs=hbm_gbs,
+        )
+    return GoodputLedger(
+        roofline,
+        enabled=enabled,
         chip_hour_usd=getattr(gp, "chip_hour_usd", 0.0) or 0.0,
     )
 
@@ -296,10 +299,12 @@ class GoodputLedger:
 
     def __init__(
         self,
-        roofline: RooflineModel,
+        roofline: Optional[RooflineModel],
         enabled: bool = True,
         chip_hour_usd: float = 0.0,
     ):
+        if enabled and roofline is None:
+            raise ValueError("an enabled GoodputLedger needs a roofline")
         self.roofline = roofline
         self.enabled = bool(enabled)
         self.chip_hour_usd = max(0.0, float(chip_hour_usd))

@@ -2718,6 +2718,17 @@ class RagService:
             mn = max(1, min(self.engine.sampling.max_new_tokens,
                             ec.max_seq_len - largest))
             self.engine._get_compiled(1, 2 * largest, mn, largest)
+        if self.shadow is not None:
+            # the auditor's exact scorer: one executable per padded length.
+            # Same coverage rule as the batch ladder above — the largest
+            # bucket (where full-context RAG prompts land) by default,
+            # every bucket under warm_full_ladder — so sampled audits of
+            # the traffic warmup prepares for never compile after ready
+            ec = self.engine.engine_config
+            self.engine.warm_score_exact(
+                ec.prompt_buckets if ec.warm_full_ladder
+                else (max(ec.prompt_buckets),)
+            )
         self.embed_texts(["warmup"])
         # compile the fused embed+kNN executable and upload the index
         # snapshot (no-op while the index is empty; ingest re-warms)

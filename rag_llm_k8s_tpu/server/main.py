@@ -119,13 +119,16 @@ def build_service():
 
 
 def assemble_service(
-    config, mesh, model_cfg, params, llm_tokenizer, enc_params, enc_tokenizer
+    config, mesh, model_cfg, params, llm_tokenizer, enc_params, enc_tokenizer,
+    encoder_attn_impl: str = "auto",
 ):
     """Everything ``build_service`` does AFTER parameter loading: engines,
     encoder runner, embedder fingerprint, index, scheduler choice,
     ``RagService``. Takes params and tokenizers from the caller so an entry
     point that makes its weights from a seed (``chip_smoke.py``) serves
-    through the same assembly a deployment runs, not a copy of it."""
+    through the same assembly a deployment runs, not a copy of it. A
+    deployment leaves the encoder's attention backend on ``"auto"`` (no
+    config field governs it); ``chip_smoke.py`` names it explicitly."""
     import hashlib
 
     from rag_llm_k8s_tpu.engine.encoder import EncoderRunner
@@ -141,12 +144,10 @@ def assemble_service(
         dtypes=config.dtypes,
         mesh=mesh,
     )
-    # one attention-backend knob for both models: the encoder spells the
-    # decoder's "pallas[_interpret]" as "flash[_interpret]"
     encoder = EncoderRunner(
         config.encoder, enc_params, config.dtypes, mesh=mesh,
         eos_id=getattr(enc_tokenizer, "eos_id", None),
-        attn_impl=config.engine.attn_impl.replace("pallas", "flash"),
+        attn_impl=encoder_attn_impl,
     )
 
     # fingerprint the embedder with a probe embedding so a persisted index

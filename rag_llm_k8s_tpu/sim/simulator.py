@@ -65,11 +65,12 @@ class PoolExhausted(RuntimeError):
     cannot share an exception class without a package import)."""
 
 
-def llama8b_roofline(
-    peak_tflops: float = 0.0, hbm_gbs: float = 0.0
-) -> "object":
-    """A Llama-3-8B-shaped ``RooflineModel`` — the default chip/model
-    arithmetic when the caller plans capacity without a config in hand."""
+def llama8b_roofline() -> "object":
+    """A Llama-3-8B-shaped ``RooflineModel`` at the peaks of the chip
+    deploy/llm/deploy.yaml targets (a v5e) — the arithmetic when the caller
+    plans capacity without a config in hand. For another chip, build the
+    roofline with its peaks and pass it as ``SimEngine(roofline=...)``."""
+    peak_tflops, hbm_gbs = _goodput.peaks_for_device("TPU v5 lite")
     return _goodput.roofline_for_llama(
         num_layers=32, hidden_size=4096, num_heads=32, num_kv_heads=8,
         head_dim=128, intermediate_size=14336, vocab_size=128256,

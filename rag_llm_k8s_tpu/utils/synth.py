@@ -106,6 +106,8 @@ def write_synth_checkpoint(
 # dequantized std of an int8 kernel drawn uniformly from [-126, 126], per
 # unit of scale: 126 / sqrt(3)
 _INT8_UNIFORM_STD = 72.75
+# projection kernels' std as a multiple of 1/sqrt(fan_in) (synth_llama_params)
+_LAYER_GAIN = 0.25
 # cycle length of the optional "reciting" output head (synth_llama_params)
 _RECITE_PERIOD = 8
 
@@ -116,7 +118,6 @@ def synth_llama_params(
     seed: int,
     quant: str = "bf16",
     mesh=None,
-    layer_gain: float = 0.25,
     recite_gain: float = 0.0,
 ):
     """Seeded random Llama params in the ``LlamaModel`` layout, generated on
@@ -131,7 +132,7 @@ def synth_llama_params(
 
     Shapes the numerics, not the timing (decode cost is shape/dtype-bound):
     RMSNorm weights are 1; projection kernels have std
-    ``layer_gain / sqrt(fan_in)`` (0.25x init: layers perturb the residual
+    ``_LAYER_GAIN / sqrt(fan_in)`` (0.25x init: layers perturb the residual
     stream instead of randomizing it, which keeps logit noise between two
     exact-in-theory paths far below the auditor's tolerance); the embedding
     has unit std and the untied output head gives logits of ~unit std, so
@@ -181,13 +182,7 @@ def synth_llama_params(
         return NamedSharding(mesh.mesh, specs[path]) if mesh is not None else None
 
     def draw(path, s, key):
-        name = path[-1]
-        # stacked RMSNorm weights are [L, D] leaves named "scale": only the
-        # path says they are norms
-        kind = (
-            "norm" if any("norm" in part for part in path)
-            else synth_leaf_kind(name, s.dtype, s.ndim)
-        )
+        kind = synth_leaf_kind(path, s.dtype)
         if kind == "norm":
             return jnp.ones(s.shape, s.dtype)
         # the CONTRACTED dim: intermediate for the MLP down-projection,
@@ -195,7 +190,7 @@ def synth_llama_params(
         fan_in = config.intermediate_size if "w_down" in path else D
         if kind == "quant_scale":
             return jnp.full(
-                s.shape, layer_gain / (_INT8_UNIFORM_STD * math.sqrt(fan_in)),
+                s.shape, _LAYER_GAIN / (_INT8_UNIFORM_STD * math.sqrt(fan_in)),
                 s.dtype,
             )
 
@@ -205,7 +200,7 @@ def synth_llama_params(
                 # leaves would cost ~7.5 GiB); maxval 127, not 128 — the
                 # bound is cast to int8 and 128 would wrap to -128
                 return jax.random.randint(k, shape, -126, 127, jnp.int8)
-            std = 1.0 if name == "embedding" else layer_gain / math.sqrt(fan_in)
+            std = 1.0 if kind == "embedding" else _LAYER_GAIN / math.sqrt(fan_in)
             return (jax.random.normal(k, shape, jnp.float32) * std).astype(s.dtype)
 
         if s.ndim == 3:  # stacked [L, in, out]: one layer per loop step

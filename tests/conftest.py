@@ -21,6 +21,16 @@ os.environ.setdefault("JAX_ENABLE_X64", "0")
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
+from rag_llm_k8s_tpu.obs import goodput  # noqa: E402
+
+# The goodput ledger prices chip time against roofline peaks keyed by
+# device_kind and refuses a kind it does not know (obs/goodput.py). The CPU
+# this suite runs on has no roofline worth the name, so the suite pins
+# nominal peaks for it here, once: every RELATIVE read (category split,
+# bubble fraction, per-request attribution) stays defined, and absolute MFU
+# on a CPU host is meaningless by construction.
+goodput.DEVICE_PEAKS["cpu"] = (275.0, 1200.0)
+
 
 @pytest.fixture(scope="session")
 def devices8():

@@ -780,7 +780,7 @@ _CHAOS_COMMON = """
 import sys, time, threading
 import jax
 from rag_llm_k8s_tpu.core.config import (
-    DTypePolicy, EngineConfig, LlamaConfig, SamplingConfig,
+    DTypePolicy, EngineConfig, GoodputConfig, LlamaConfig, SamplingConfig,
 )
 from rag_llm_k8s_tpu.engine.continuous import (
     ContinuousEngine, ContinuousScheduler,
@@ -790,8 +790,11 @@ from rag_llm_k8s_tpu.obs import flight
 
 FP32 = DTypePolicy.fp32()
 CFG = LlamaConfig.tiny()
+# the CPU has no DEVICE_PEAKS row: pin nominal roofline peaks (conftest.py
+# does it for the suite; this script runs in a process of its own)
 ENG_CFG = EngineConfig(prompt_buckets=(16, 32), max_batch_size=4,
-                       max_seq_len=64)
+                       max_seq_len=64,
+                       goodput=GoodputConfig(peak_tflops=275.0, hbm_gbs=1200.0))
 SAMP = SamplingConfig(do_sample=False, max_new_tokens=40)
 PROMPTS = ([5, 6, 7, 8], [9, 10, 11, 12])
 

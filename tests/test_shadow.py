@@ -388,6 +388,32 @@ class TestScoreExact:
         assert [int(t) for t in score["argmax"]] == out
 
 
+    def test_warmed_scorer_covers_every_length_a_bucket_reaches(self, tiny):
+        """warm_score_exact builds the scorer for each padded length a
+        prompt of the given buckets plus its budget can have — after it, an
+        audit at any such length finds its executable (RagService.warmup
+        calls it, so the first audits never compile after ready)."""
+        cfg, params = tiny
+        ec = EngineConfig(
+            prompt_buckets=(16, 48), max_batch_size=2, max_seq_len=64,
+            speculative="off",
+        )
+        eng = InferenceEngine(
+            cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32
+        )
+
+        def scorers():
+            return {k[1] for k in eng._compiled if k[3] == ("shadow", 48)}
+
+        eng.warm_score_exact((48,))  # prompts of 17..48 tokens, 1..10 emitted
+        assert scorers() == {48, 96}  # multiples of the chunk: 18..58 tokens
+        for n_prompt, n_out in ((17, 1), (40, 8), (41, 8), (48, 10)):
+            eng.score_exact([cfg.bos_token_id] + [5] * (n_prompt - 1), [7] * n_out)
+        assert scorers() == {48, 96}  # nothing new was built
+        eng.warm_score_exact((16, 48))  # the small bucket adds nothing below one chunk
+        assert scorers() == {48, 96}
+
+
 # ---------------------------------------------------------------------------
 # approximation fingerprints
 # ---------------------------------------------------------------------------
@@ -793,5 +819,60 @@ class TestShadowSmoke:
             judged = (rep["report"]["audits"]["clean"]
                       + rep["report"]["audits"]["skipped"])
             assert judged >= 1
+        finally:
+            svc.shutdown()
+
+    def test_warmup_leaves_the_first_audited_query_nothing_to_build(
+        self, tiny, tmp_path
+    ):
+        """RagService.warmup() with the auditor on also warms the exact
+        scorer: a query served after ready, and its audit, add no
+        executable to the engine (the chip found the first audit at each
+        padded length compiling on the audit thread, PR 21)."""
+        cfg, params = tiny
+        from rag_llm_k8s_tpu.core.config import EncoderConfig
+        from rag_llm_k8s_tpu.engine.encoder import EncoderRunner
+        from rag_llm_k8s_tpu.index.store import VectorStore
+        from rag_llm_k8s_tpu.models.bge_m3 import init_encoder_params
+
+        enc_cfg = EncoderConfig.tiny(vocab_size=300)
+        app_cfg = AppConfig(
+            model=cfg, encoder=enc_cfg,
+            flight=FlightConfig(spool_dir=str(tmp_path / "spool")),
+            shadow=ShadowConfig(sample_rate=1.0),
+            system_message="Use the context.",
+        )
+        engine = InferenceEngine(  # a bucket the byte-level prompt fits
+            cfg, params, sampling=GREEDY, dtypes=FP32,
+            engine_config=EngineConfig(
+                prompt_buckets=(128,), max_batch_size=2, max_seq_len=256,
+                speculative="off",
+            ),
+        )
+        encoder = EncoderRunner(
+            enc_cfg, init_encoder_params(jax.random.PRNGKey(1), enc_cfg, FP32),
+            dtypes=FP32, length_buckets=(32, 64), max_batch=4,
+        )
+        store = VectorStore(dim=enc_cfg.hidden_size)
+        texts = ["alpha beta gamma", "delta epsilon zeta"]
+        vecs = encoder.encode([ByteTokenizer().encode(t) for t in texts])
+        store.add(list(vecs), [
+            {"filename": "f", "chunk_id": i, "text": t}
+            for i, t in enumerate(texts)
+        ])
+        svc = RagService(
+            app_cfg, engine, ByteTokenizer(), encoder, ByteTokenizer(), store,
+        )
+        try:
+            svc.warmup()
+            assert svc.ready
+            warmed = set(engine._compiled)
+            assert any(k[3] == ("shadow", 128) for k in warmed)
+            r = create_app(svc).test_client().post("/query", json={"prompt": "alpha"})
+            assert r.status_code == 200
+            _drain_shadow(svc)
+            audits = svc.shadow.state()["audits"]
+            assert audits["clean"] == 1 and not audits["failed"]
+            assert set(engine._compiled) == warmed
         finally:
             svc.shutdown()

@@ -91,3 +91,29 @@ def test_synth_pdf_is_seeded_multipage_and_extractable():
     text = extract_text(synth_pdf(3, n_pages=3, words_per_page=100))
     assert all(f"Section {i} of the seeded corpus 3." in text for i in (1, 2, 3))
     assert 300 < len(text.split()) < 400
+
+
+def test_leaf_kinds_go_by_path_not_rank():
+    """Layers are stacked, so an RMSNorm weight is an [L, D] leaf named
+    "scale": only its module's name says it is a norm (it was once drawn as
+    embedding noise). Every leaf of both layouts has one of five kinds."""
+    import jax.numpy as jnp
+
+    from rag_llm_k8s_tpu.models.llama import synth_leaf_kind
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert synth_leaf_kind(("layers", "input_norm", "scale"), bf16) == "norm"
+    assert synth_leaf_kind(("layers", "post_attn_norm", "scale"), bf16) == "norm"
+    assert synth_leaf_kind(("final_norm", "scale"), bf16) == "norm"
+    assert synth_leaf_kind(("layers", "attn", "wq", "qscale"), f32) == "quant_scale"
+    assert synth_leaf_kind(("lm_head_scale",), f32) == "quant_scale"
+    assert synth_leaf_kind(("layers", "mlp", "w_up", "kernel_q"), jnp.int8) == "kernel_q"
+    assert synth_leaf_kind(("lm_head_q",), jnp.int8) == "kernel_q"
+    assert synth_leaf_kind(("layers", "attn", "wo", "kernel"), bf16) == "kernel"
+    assert synth_leaf_kind(("lm_head",), bf16) == "kernel"
+    assert synth_leaf_kind(("embedding",), bf16) == "embedding"
+    # and the builder follows it: stacked norms come out as ones
+    params = synth_llama_params(CFG, DTypePolicy(), 0, quant="int8")
+    for name in ("input_norm", "post_attn_norm"):
+        scale = np.asarray(params["layers"][name]["scale"], np.float32)
+        assert scale.ndim == 2 and (scale == 1.0).all()
