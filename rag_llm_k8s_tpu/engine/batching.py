@@ -24,6 +24,7 @@ from dataclasses import dataclass, field
 from typing import List, Optional
 
 from rag_llm_k8s_tpu.engine.engine import InferenceEngine
+from rag_llm_k8s_tpu.obs import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -53,6 +54,8 @@ class _Pending:
     result: Optional[List[int]] = None
     error: Optional[BaseException] = None
     t_enqueue: float = field(default_factory=time.monotonic)  # wait anchor
+    rows: int = 0  # size of the dispatch this request rode (set by the worker)
+    wait_s: float = 0.0  # enqueue -> dispatch
 
 
 @dataclass
@@ -229,6 +232,15 @@ class BatchScheduler:
         self.wait_histogram = None
         # optional obs Counter — shutdown join timeouts (see _join_worker)
         self.join_timeout_counter = None
+        # optional obs counter FAMILIES (settable after construction):
+        # rag_generate_dispatch_rows_total{path="batched", rows} counts the
+        # answers of each dispatch by its size, and
+        # rag_generate_dispatch_reason_total{reason} why the drain loop
+        # stopped — "full", "hint" (every in-flight request aboard),
+        # "deadline" (the window ran out), "incompatible" (the next request
+        # needs another executable and leads the next round)
+        self.dispatch_counter = None
+        self.reason_counter = None
         # size of the batch currently inside engine.generate (0 between
         # dispatches) — the rag_batch_occupancy gauge reads this; plain
         # int assignment, so no lock needed for the scrape-time read
@@ -274,6 +286,9 @@ class BatchScheduler:
             raise TimeoutError("generation timed out")
         if item.error is not None:
             raise item.error
+        if info is not None:
+            info["dispatch_rows"] = item.rows
+            info["queue_wait_ms"] = item.wait_s * 1e3
         return item.result
 
     def shutdown(self):
@@ -322,11 +337,13 @@ class BatchScheduler:
             # ABSOLUTE deadline (a per-get timeout resets on every arrival:
             # worst case (cap-1) x window under trickle load)
             deadline = time.monotonic() + self.max_wait_ms / 1e3
+            reason = "full"  # why the drain stops, unless a break says otherwise
             while len(batch) < cap:
                 hint = self.pending_hint
                 if hint is not None and len(batch) >= hint():
                     # every in-flight request is already aboard (solo query:
                     # immediately) — don't burn the window waiting for nobody
+                    reason = "hint"
                     break
                 remaining = deadline - time.monotonic()
                 try:
@@ -337,8 +354,10 @@ class BatchScheduler:
                         if remaining > 0 else self._queue.get_nowait()
                     )
                 except queue.Empty:
+                    reason = "deadline"
                     break
                 if nxt is None:
+                    reason = None  # the shutdown wake-up: not a decision
                     break
                 if nxt.max_new == first.max_new and nxt.seed == first.seed:
                     batch.append(nxt)
@@ -347,19 +366,27 @@ class BatchScheduler:
                     # re-queue would reorder it behind later arrivals and
                     # could starve it under sustained mixed load)
                     carry = nxt
+                    reason = "incompatible"
                     break
+            rows = len(batch)
             hist = self.wait_histogram
-            if hist is not None:
-                now = time.monotonic()
-                for b in batch:
-                    hist.observe(now - b.t_enqueue)
-            self.in_flight = len(batch)
+            now = time.monotonic()
+            for b in batch:
+                b.rows, b.wait_s = rows, now - b.t_enqueue
+                if hist is not None:
+                    hist.observe(b.wait_s)
+            if self.dispatch_counter is not None:
+                self.dispatch_counter.labels(path="batched", rows=str(rows)).inc(rows)
+            if self.reason_counter is not None and reason is not None:
+                self.reason_counter.labels(reason=reason).inc()
+            self.in_flight = rows
             try:
-                outs = self.engine.generate(
-                    [b.prompt for b in batch],
-                    max_new_tokens=first.max_new,
-                    seed=first.seed,
-                )
+                with tracing.span("dispatch", rows=rows):
+                    outs = self.engine.generate(
+                        [b.prompt for b in batch],
+                        max_new_tokens=first.max_new,
+                        seed=first.seed,
+                    )
                 for b, out in zip(batch, outs):
                     b.result = out
             except BaseException as e:  # noqa: BLE001 — deliver to all waiters

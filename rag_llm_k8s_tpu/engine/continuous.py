@@ -79,6 +79,7 @@ from rag_llm_k8s_tpu.models.llama import (
 from rag_llm_k8s_tpu.obs import flight
 from rag_llm_k8s_tpu.obs import goodput as obs_goodput
 from rag_llm_k8s_tpu.obs import metrics as obs_metrics
+from rag_llm_k8s_tpu.obs.tracing import phase_scope
 from rag_llm_k8s_tpu.resilience import faults
 from rag_llm_k8s_tpu.resilience.deadline import Deadline, DeadlineExceeded
 # the scheduler's decision core lives behind the sim seam (ISSUE 17):
@@ -730,6 +731,7 @@ class ContinuousEngine:
         model = self.model
         kv_quant = self.kv_quant
 
+        @phase_scope("prefill")
         def prefill(params, tokens, pad_mask, rngs):
             cache = make_kv_cache(cfg, n, S, dt.compute_dtype, quant=kv_quant)
             kv_start, _ = mask_window(pad_mask)
@@ -784,6 +786,7 @@ class ContinuousEngine:
         i32 = jnp.int32
         from rag_llm_k8s_tpu.models.llama import KVCache
 
+        @phase_scope("prefill")
         def prefill(params, suffix_tokens, suffix_len, ctx, prefix_len, rngs):
             cache = make_kv_cache(cfg, 1, T_build, dt.compute_dtype, quant=kv_quant)
             planes = (
@@ -1286,6 +1289,7 @@ class ContinuousEngine:
         into arbitrary slots in ONE device call (the admission group's
         counterpart to the batched prefill)."""
 
+        @phase_scope("prefill")
         def insert(cache, row_cache, kv_start, kv_len, last_tok, active,
                    rng_keys, rows, row_starts, tok0s, row_keys):
             # each row's prompt KV occupies slots [0, S); frontiers are
@@ -1373,6 +1377,7 @@ class ContinuousEngine:
             )
             return out, kv_len, tok, hit_eos, active
 
+        @phase_scope("decode")
         def step(params, cache_t, kv_start, kv_len, last_tok, active, rng_keys):
             if k == 1:
                 cache_t, kv_len, tok, hit_eos, active = one(
@@ -1441,6 +1446,7 @@ class ContinuousEngine:
         kv_quant = self.kv_quant
         i32 = jnp.int32
 
+        @phase_scope("prefill")
         def prefill(params, tokens, lens, rngs):
             cache = make_kv_cache(cfg, n, S, dt.compute_dtype, quant=kv_quant)
             positions = jnp.broadcast_to(jnp.arange(S, dtype=i32)[None, :], (n, S))
@@ -1480,6 +1486,7 @@ class ContinuousEngine:
         bs = self.block_size
         nb = S // bs
 
+        @phase_scope("prefill")
         def insert(arena, row_cache, kv_len, last_tok, active, rng_keys,
                    rows, block_ids, lens, tok0s, row_keys):
             # ONE scatter per plane over the block axis: reshape each row's
@@ -1575,6 +1582,7 @@ class ContinuousEngine:
             )
             return out, kv_len, tok, hit_eos, active
 
+        @phase_scope("decode")
         def step(params, cache_t, tables, kv_len, last_tok, active, rng_keys):
             if k == 1:
                 cache_t, kv_len, tok, hit_eos, active = one(
@@ -1651,6 +1659,7 @@ class ContinuousEngine:
         i32 = jnp.int32
         from rag_llm_k8s_tpu.models.llama import KVCache
 
+        @phase_scope("verify")
         def verify(params, cache_t, tables, kv_len, last_tok, active,
                    rng_keys, drafts, n_drafts):
             wi = jnp.where(active, kv_len, 0)  # inactive rows park at 0
@@ -1759,6 +1768,7 @@ class ContinuousEngine:
         i32 = jnp.int32
         from rag_llm_k8s_tpu.models.llama import KVCache
 
+        @phase_scope("mixed")
         def mixed(params, cache_t, tables, kv_len, last_tok, active,
                   rng_keys, fed, n_fed, chunk_base, final):
             is_chunk = n_fed > 0
@@ -1853,6 +1863,7 @@ class ContinuousEngine:
         bs = self.block_size
         nbp = P // bs  # admit_prefixed validates P % block_size == 0
 
+        @phase_scope("prefill")
         def scatter(arena, planes, ids):
             # ONE scatter per plane (same shape discipline as insert_paged:
             # an unrolled loop here emitted P/bs slice/update pairs per
@@ -1902,6 +1913,7 @@ class ContinuousEngine:
         i32 = jnp.int32
         from rag_llm_k8s_tpu.models.llama import KVCache
 
+        @phase_scope("prefill")
         def px(params, arena, row_table, suffix_tokens, slen, plen, rngs):
             positions = (plen + jnp.arange(C, dtype=i32))[None, :]
             total = (plen + slen).astype(i32)
@@ -1953,6 +1965,7 @@ class ContinuousEngine:
         kv_quant = self.kv_quant
         i32 = jnp.int32
 
+        @phase_scope("prefill")
         def splice(arena, src, dst, delta):
             k, v = arena[0], arena[1]
             ks = jnp.take(k, src, axis=1)  # [L, nb, K, bs, hd]
@@ -2083,6 +2096,7 @@ class ContinuousEngine:
         i32 = jnp.int32
         from rag_llm_k8s_tpu.models.llama import KVCache
 
+        @phase_scope("prefill")
         def bfix(params, arena, row_table, toks, woff):
             positions = (woff + jnp.arange(W, dtype=i32))[None, :]
             kv_len = jnp.broadcast_to(woff + W, (1,)).astype(i32)

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from rag_llm_k8s_tpu.core.config import DTypePolicy, EncoderConfig
+from rag_llm_k8s_tpu.obs.tracing import phase_scope
 
 NEG_INF = -1e9
 
@@ -135,6 +136,7 @@ class BgeM3Encoder(nn.Module):
         return self.attn_impl
 
     @nn.compact
+    @phase_scope("retrieve/embed")  # whoever traces the encoder: a query or an ingest batch
     def __call__(self, tokens: jax.Array, mask: jax.Array) -> jax.Array:
         c, dt = self.config, self.dtypes
         word = self.param(

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from rag_llm_k8s_tpu.core.config import DTypePolicy, LlamaConfig
+from rag_llm_k8s_tpu.obs.tracing import phase_scope
 from rag_llm_k8s_tpu.ops.attention import (
     attention_xla,
     chunk_attention_xla,
@@ -885,20 +886,23 @@ class Block(nn.Module):
     def __call__(self, carry, kv_start, kv_len, cos, sin, write_index,
                  block_tables):
         h, kv, layer = carry
-        attn_out, kv = Attention(
-            self.config, self.dtypes, self.attn_impl, self.mesh, self.chunked,
-            self.row_frontier, self.fused_qkv, self.quantized, self.kv_quant,
-            self.paged, name="attn",
-        )(
-            RMSNorm(self.config.rms_norm_eps, self.dtypes, name="input_norm")(h),
-            kv, layer, kv_start, kv_len, cos, sin, write_index, block_tables,
-        )
-        h = h + attn_out
-        h = h + MLP(
-            self.config, self.dtypes, self.fused_qkv, self.quantized, name="mlp"
-        )(
-            RMSNorm(self.config.rms_norm_eps, self.dtypes, name="post_attn_norm")(h)
-        )
+        # sub-scopes of whichever phase traces the block (obs/tracing.py):
+        # the rotation itself is applied inside ``attn``
+        with phase_scope("norm_rope"):
+            x = RMSNorm(self.config.rms_norm_eps, self.dtypes, name="input_norm")(h)
+        with phase_scope("attn"):
+            attn_out, kv = Attention(
+                self.config, self.dtypes, self.attn_impl, self.mesh, self.chunked,
+                self.row_frontier, self.fused_qkv, self.quantized, self.kv_quant,
+                self.paged, name="attn",
+            )(x, kv, layer, kv_start, kv_len, cos, sin, write_index, block_tables)
+            h = h + attn_out
+        with phase_scope("norm_rope"):
+            x = RMSNorm(self.config.rms_norm_eps, self.dtypes, name="post_attn_norm")(h)
+        with phase_scope("mlp"):
+            h = h + MLP(
+                self.config, self.dtypes, self.fused_qkv, self.quantized, name="mlp"
+            )(x)
         return (h, kv, layer + 1), None
 
 
@@ -943,33 +947,35 @@ class LlamaModel(nn.Module):
         block_tables: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, KVCache]:
         c, dt = self.config, self.dtypes
-        if self.quantized and c.tie_word_embeddings:
-            # tied head: the [V, D] table is re-read IN FULL by every decode
-            # step's logit matmul, so it gets the int8 treatment too (per-row
-            # scales serve both the gather and the logits epilogue below)
-            embedding = self.param(
-                "embedding_q", nn.initializers.zeros,
-                (c.vocab_size, c.hidden_size), jnp.int8,
-            )
-            emb_scale = self.param(
-                "embedding_scale", nn.initializers.ones, (c.vocab_size,), jnp.float32
-            )
-            h = (
-                jnp.take(embedding, tokens, axis=0).astype(dt.compute_dtype)
-                * jnp.take(emb_scale, tokens, axis=0)[..., None].astype(dt.compute_dtype)
-            )
-        else:
-            # untied (or unquantized): the embedding is only ever GATHERED
-            # ([B, S] rows per step), so int8 would save no bandwidth
-            embedding = self.param(
-                "embedding",
-                nn.initializers.normal(stddev=0.02),
-                (c.vocab_size, c.hidden_size),
-                dt.param_dtype,
-            )
-            h = jnp.take(embedding, tokens, axis=0).astype(dt.compute_dtype)
+        with phase_scope("embed"):
+            if self.quantized and c.tie_word_embeddings:
+                # tied head: the [V, D] table is re-read IN FULL by every decode
+                # step's logit matmul, so it gets the int8 treatment too (per-row
+                # scales serve both the gather and the logits epilogue below)
+                embedding = self.param(
+                    "embedding_q", nn.initializers.zeros,
+                    (c.vocab_size, c.hidden_size), jnp.int8,
+                )
+                emb_scale = self.param(
+                    "embedding_scale", nn.initializers.ones, (c.vocab_size,), jnp.float32
+                )
+                h = (
+                    jnp.take(embedding, tokens, axis=0).astype(dt.compute_dtype)
+                    * jnp.take(emb_scale, tokens, axis=0)[..., None].astype(dt.compute_dtype)
+                )
+            else:
+                # untied (or unquantized): the embedding is only ever GATHERED
+                # ([B, S] rows per step), so int8 would save no bandwidth
+                embedding = self.param(
+                    "embedding",
+                    nn.initializers.normal(stddev=0.02),
+                    (c.vocab_size, c.hidden_size),
+                    dt.param_dtype,
+                )
+                h = jnp.take(embedding, tokens, axis=0).astype(dt.compute_dtype)
 
-        cos, sin = rope_cos_sin(positions, rope_frequencies(c))
+        with phase_scope("norm_rope"):
+            cos, sin = rope_cos_sin(positions, rope_frequencies(c))
 
         ScanBlocks = nn.scan(
             Block,
@@ -998,57 +1004,59 @@ class LlamaModel(nn.Module):
         )
         new_cache = KVCache(*new_kv)
 
-        h = RMSNorm(c.rms_norm_eps, dt, name="final_norm")(h)
-        if logit_index is not None:
-            # right-padded prefill (prefix-cache suffix chunks; the paged
-            # engine's whole-prompt prefill): the LAST REAL token sits at a
-            # dynamic position, not -1 — slice just it before the head
-            # projection (same [B, S, V] avoidance as last_logit_only, but
-            # at a traced index). A VECTOR index gathers per row — paged
-            # admission groups rows of different real lengths in one bucket.
-            B = h.shape[0]
-            idx = jnp.clip(jnp.asarray(logit_index, jnp.int32), 0, h.shape[1] - 1)
-            if idx.ndim == 0:
-                h = jax.lax.dynamic_slice(h, (0, idx, 0), (B, 1, h.shape[2]))
+        with phase_scope("norm_rope"):
+            h = RMSNorm(c.rms_norm_eps, dt, name="final_norm")(h)
+        with phase_scope("lm_head"):
+            if logit_index is not None:
+                # right-padded prefill (prefix-cache suffix chunks; the paged
+                # engine's whole-prompt prefill): the LAST REAL token sits at a
+                # dynamic position, not -1 — slice just it before the head
+                # projection (same [B, S, V] avoidance as last_logit_only, but
+                # at a traced index). A VECTOR index gathers per row — paged
+                # admission groups rows of different real lengths in one bucket.
+                B = h.shape[0]
+                idx = jnp.clip(jnp.asarray(logit_index, jnp.int32), 0, h.shape[1] - 1)
+                if idx.ndim == 0:
+                    h = jax.lax.dynamic_slice(h, (0, idx, 0), (B, 1, h.shape[2]))
+                else:
+                    h = jnp.take_along_axis(h, idx.reshape(B, 1, 1), axis=1)
+            elif last_logit_only:
+                # prefill only consumes the final position — projecting just it
+                # avoids a [B, S, V] fp32 intermediate (S x the FLOPs and HBM)
+                h = h[:, -1:, :]
+            if c.tie_word_embeddings:
+                logits = jnp.einsum(
+                    "bsd,vd->bsv", h, embedding.astype(dt.compute_dtype),
+                    preferred_element_type=jnp.float32,
+                )
+                if self.quantized:
+                    logits = logits * emb_scale[None, None, :]
+            elif self.quantized:
+                head = self.param(
+                    "lm_head_q", nn.initializers.zeros,
+                    (c.hidden_size, c.vocab_size), jnp.int8,
+                )
+                head_scale = self.param(
+                    "lm_head_scale", nn.initializers.ones, (c.vocab_size,), jnp.float32
+                )
+                logits = (
+                    jnp.einsum(
+                        "bsd,dv->bsv", h, head.astype(dt.compute_dtype),
+                        preferred_element_type=jnp.float32,
+                    )
+                    * head_scale[None, None, :]
+                )
             else:
-                h = jnp.take_along_axis(h, idx.reshape(B, 1, 1), axis=1)
-        elif last_logit_only:
-            # prefill only consumes the final position — projecting just it
-            # avoids a [B, S, V] fp32 intermediate (S x the FLOPs and HBM)
-            h = h[:, -1:, :]
-        if c.tie_word_embeddings:
-            logits = jnp.einsum(
-                "bsd,vd->bsv", h, embedding.astype(dt.compute_dtype),
-                preferred_element_type=jnp.float32,
-            )
-            if self.quantized:
-                logits = logits * emb_scale[None, None, :]
-        elif self.quantized:
-            head = self.param(
-                "lm_head_q", nn.initializers.zeros,
-                (c.hidden_size, c.vocab_size), jnp.int8,
-            )
-            head_scale = self.param(
-                "lm_head_scale", nn.initializers.ones, (c.vocab_size,), jnp.float32
-            )
-            logits = (
-                jnp.einsum(
+                head = self.param(
+                    "lm_head",
+                    nn.initializers.normal(stddev=0.02),
+                    (c.hidden_size, c.vocab_size),
+                    dt.param_dtype,
+                )
+                logits = jnp.einsum(
                     "bsd,dv->bsv", h, head.astype(dt.compute_dtype),
                     preferred_element_type=jnp.float32,
                 )
-                * head_scale[None, None, :]
-            )
-        else:
-            head = self.param(
-                "lm_head",
-                nn.initializers.normal(stddev=0.02),
-                (c.hidden_size, c.vocab_size),
-                dt.param_dtype,
-            )
-            logits = jnp.einsum(
-                "bsd,dv->bsv", h, head.astype(dt.compute_dtype),
-                preferred_element_type=jnp.float32,
-            )
         return logits.astype(dt.logits_dtype), new_cache
 
 

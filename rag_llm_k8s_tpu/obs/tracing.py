@@ -9,16 +9,28 @@ contract: top-level spans sum to within 5% of ``timings.total_ms``).
 
 Where a stage runs as ONE fused device program (the whole generate loop is
 a single executable — by design, see engine/engine.py), the host cannot
-observe finer structure wall-clock; those stages appear as one span and
-their interior is visible two other ways instead:
+observe finer structure wall-clock. ``generate`` therefore holds one
+``dispatch`` span per device program launched for the request, with two
+children — ``launch`` (host preparation up to the enqueue) and ``fetch``
+(the blocking device→host read of the output tokens) — and the interior of
+the program is named on the DEVICE's clock instead:
 
 - every span body is wrapped in ``jax.profiler.TraceAnnotation``, so an
-  xprof capture (``/profile``) shows the named stages on the device
-  timeline;
-- the per-token view (TTFT / inter-token) comes from the metrics
-  histograms the engines feed (``rag_time_to_first_token_seconds``,
-  ``rag_decode_inter_token_seconds``) — distribution over all traffic
-  rather than one request's timeline.
+  xprof capture (``/profile``) shows the named stages on the host timeline
+  next to the device's;
+- every operation of a compiled program carries a phase from ``PHASES``
+  (and a sub-scope from ``SUB_SCOPES``) in its ``op_name``: ``phase_scope``
+  below is opened where the programs are traced (engine/engine.py,
+  models/llama.py, models/bge_m3.py, ops/knn.py, engine/continuous.py), so
+  the capture's operations read ``…/decode/attn/…`` rather than
+  ``fusion.335``. A scope is op metadata: it adds no operation and costs
+  nothing at run time. The persistent compile cache does not key on
+  metadata, so a change to scopes alone needs a fresh cache directory to
+  show (docs/OBSERVABILITY.md).
+
+(``rag_time_to_first_token_seconds`` / ``rag_decode_inter_token_seconds``
+are fed by the continuous engine only; in the one-shot shape they stay
+empty — no first token is visible to the host there.)
 
 Finished traces are emitted as structured JSON logs (logger
 ``rag_llm_k8s_tpu.trace``, DEBUG) and kept in an in-memory ring buffer
@@ -42,9 +54,38 @@ from typing import Dict, List, Optional
 logger = logging.getLogger("rag_llm_k8s_tpu.trace")
 
 try:  # device-timeline names for xprof captures; absent off-JAX is fine
+    import jax
     from jax.profiler import TraceAnnotation as _TraceAnnotation
 except Exception:  # noqa: BLE001 — tracing must work without jax
+    jax = None
     _TraceAnnotation = None
+
+# The one vocabulary of scopes inside compiled programs. A phase is what a
+# device program is doing for a request; a sub-scope is which part of the
+# model does it. ``score`` is the exact-path audit scorer (never serving
+# work); ``mixed`` is the continuous engine's window, whose prefill and
+# decode lanes share operations and cannot be told apart inside one.
+PHASES = ("retrieve", "prefill", "decode", "verify", "sample", "score", "mixed")
+SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
+SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES)
+
+
+def phase_scope(path: str, rows: Optional[int] = None):
+    """``jax.named_scope(path)`` for a name of the vocabulary, or several
+    joined by ``/`` (``retrieve/embed``); any other name raises, so a trace
+    never grows a scope its readers do not know. Usable as a context manager
+    or as a decorator of the function whose operations it names.
+
+    ``rows`` (a generate program's ``prefill``) adds the component
+    ``rows<N>``: the batch the executable was built for, stated where every
+    operation of it carries it, so a capture that cuts a program anywhere
+    still says how many prompts its prefill served."""
+    unknown = [n for n in path.split("/") if n not in SCOPE_NAMES]
+    if unknown:
+        raise ValueError(
+            f"scope {unknown[0]!r} is not in the vocabulary {sorted(SCOPE_NAMES)}"
+        )
+    return jax.named_scope(path if rows is None else f"{path}/rows{int(rows)}")
 
 
 @dataclass

@@ -186,6 +186,7 @@ def flash_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(kv_start.astype(jnp.int32), kv_len.astype(jnp.int32), qt, kt, vt)
 
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
@@ -337,6 +338,7 @@ def decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         kv_start.astype(jnp.int32),
@@ -486,6 +488,7 @@ def chunk_prefill_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
         interpret=interpret,
+        name="chunk_prefill_attention",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.asarray(write_index, jnp.int32).reshape(1),
@@ -849,6 +852,7 @@ def decode_attention_q8(
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
+        name="decode_attention_q8",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         kv_start.astype(jnp.int32),
@@ -1013,6 +1017,7 @@ def chunk_prefill_attention_q8(
         ),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
         interpret=interpret,
+        name="chunk_prefill_attention_q8",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.asarray(write_index, jnp.int32).reshape(1),
@@ -1185,6 +1190,7 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         block_tables.astype(jnp.int32).reshape(-1),
@@ -1321,6 +1327,7 @@ def paged_decode_attention_q8(
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention_q8",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         block_tables.astype(jnp.int32).reshape(-1),
@@ -1487,6 +1494,7 @@ def paged_chunk_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
         interpret=interpret,
+        name="paged_chunk_attention",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.broadcast_to(jnp.asarray(write_index, jnp.int32), (B,)),
@@ -1659,6 +1667,7 @@ def paged_chunk_attention_q8(
         ),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
         interpret=interpret,
+        name="paged_chunk_attention_q8",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.broadcast_to(jnp.asarray(write_index, jnp.int32), (B,)),

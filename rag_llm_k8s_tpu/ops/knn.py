@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from rag_llm_k8s_tpu.obs.tracing import phase_scope
+
 BIG = 3.4e38  # +inf stand-in that survives arithmetic (python float: not traced)
 
 
@@ -84,6 +86,7 @@ def _knn_kernel(q_ref, e_ref, en_ref, vals_ref, idx_ref, top_v, top_i, *, block_
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
+@phase_scope("retrieve/knn")
 def knn_topk_pallas(
     queries: jax.Array,  # [Q, D] fp32
     embeddings: jax.Array,  # [N_pad, D] fp32, rows >= n_valid are arbitrary
@@ -118,10 +121,12 @@ def knn_topk_pallas(
             pltpu.VMEM((Q, k), jnp.int32),
         ],
         interpret=interpret,
+        name="knn_topk_pallas",  # the trace's row for this kernel, whatever the function is called
     )(queries, embeddings, sq_norms)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
+@phase_scope("retrieve/knn")
 def knn_topk_xla(
     queries: jax.Array,  # [Q, D]
     embeddings: jax.Array,  # [N_pad, D]

@@ -32,6 +32,7 @@ import os
 import threading
 import time
 from collections import OrderedDict
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -444,6 +445,22 @@ class RagService:
         )
         for s in ("retrieve", "embed", "generate"):
             self._m_coalesce_wait.labels(stage=s)
+        # every device program launched for /generate, by the path that
+        # launched it and the rows it carried (rows <= max_batch_size, so the
+        # label is bounded), incremented by those rows: answers by the size
+        # of the dispatch they rode. Which path a burst took — one batch, or
+        # one request alone on the fused path while the rest wait — is read
+        # from here, not guessed from latencies.
+        self._m_dispatch_rows = reg.labeled_counter(
+            "rag_generate_dispatch_rows_total",
+            "answers dispatched to the device, by path "
+            "(fused|prefixed|batched|direct) and rows of the dispatch",
+        )
+        self._m_dispatch_reason = reg.labeled_counter(
+            "rag_generate_dispatch_reason_total",
+            "why the batch scheduler's drain loop stopped "
+            "(full|hint|deadline|incompatible)",
+        )
         # present in every mode so dashboards stay uniform; only the
         # continuous engine's host loop can actually observe it (exact
         # submit→first-token), so it stays empty under coalesce serving
@@ -941,6 +958,9 @@ class RagService:
             self.scheduler.wait_histogram = (
                 self._m_coalesce_wait.labels(stage="generate")
             )
+        if self.scheduler is not None and hasattr(self.scheduler, "dispatch_counter"):
+            self.scheduler.dispatch_counter = self._m_dispatch_rows
+            self.scheduler.reason_counter = self._m_dispatch_reason
         # the decision layer: SLO specs evaluated over sliding windows of
         # the histograms/counters registered above; exports rag_slo_* gauges
         # into the same registry and backs GET /slo (obs/slo.py)
@@ -1709,6 +1729,7 @@ class RagService:
         if fn is None:
             model = self.encoder.model
 
+            @tracing.phase_scope("retrieve")
             def fused(params, tokens, mask, emb, norms):
                 vec = model.apply({"params": params}, tokens, mask)
                 d, i = knn_topk(vec.astype(jnp.float32), emb, norms, k=k_eff)
@@ -2171,7 +2192,7 @@ class RagService:
             t0 = time.monotonic()
             gen_info: Dict[str, float] = {}
             served_engine = self.engine  # shadow audit: whose sampling rules
-            with tracing.span("generate"):
+            with tracing.span("generate") as gen_span:
                 if self.scheduler is not None and len(prompt_ids) <= self._scheduler_prompt_cap():
                     served_engine = (
                         getattr(self.scheduler, "engine", None) or self.engine
@@ -2195,6 +2216,13 @@ class RagService:
                                 "generate", deadline.budget_ms
                             ) from None
                         raise
+                    # the dispatch itself is the scheduler worker's span; the
+                    # request's own tree says how many rows it rode with and
+                    # how long it queued (BatchScheduler fills both)
+                    gen_attrs = {}
+                    if "dispatch_rows" in gen_info:
+                        gen_attrs = {"rows": gen_info.pop("dispatch_rows"),
+                                     "queue_wait_ms": gen_info.pop("queue_wait_ms")}
                 else:
                     # prompts beyond the scheduler's capability need chunked
                     # prefill, which fixed-length continuous slots cannot do —
@@ -2206,9 +2234,13 @@ class RagService:
                     with self._inflight_lock:
                         self._inflight_generate -= 1
                     in_generate = False
-                    out_ids = self.engine.generate(
-                        [prompt_ids], info=gen_info
-                    )[0]
+                    gen_attrs = {"rows": 1, "queue_wait_ms": 0.0}
+                    with self._dispatch("direct"):
+                        out_ids = self.engine.generate(
+                            [prompt_ids], info=gen_info
+                        )[0]
+                if gen_span is not None:
+                    gen_span.attrs.update(gen_attrs)
             if in_generate:
                 with self._inflight_lock:
                     self._inflight_generate -= 1
@@ -2257,6 +2289,18 @@ class RagService:
             # what {"timeline": true} resolves inline)
             resp["request_id"] = int(gen_info["request_id"])
         return self._finish(resp, notes)
+
+    @contextmanager
+    def _dispatch(self, path: str):
+        """One batch-1 device program launched for ``/generate`` from the
+        request thread (the scheduler worker opens its own in
+        ``BatchScheduler``): its one row under ``path`` in
+        ``rag_generate_dispatch_rows_total``, counted at the launch whether
+        or not the program then succeeds (as the scheduler counts its
+        batches), and the ``dispatch`` span."""
+        self._m_dispatch_rows.labels(path=path, rows="1").inc()
+        with tracing.span("dispatch", rows=1):
+            yield
 
     def _prefix_enabled(self) -> bool:
         """KV prefix cache applicability (engine/prefix_cache.py)."""
@@ -2337,11 +2381,12 @@ class RagService:
         timings["prefix_resolve_ms"] = (time.monotonic() - t_r) * 1e3
         t0 = time.monotonic()
         gen_info: Dict[str, float] = {}
-        with tracing.span("generate"):
+        with tracing.span("generate", rows=1, queue_wait_ms=0.0):
             try:
-                out_ids = self.engine.generate_prefixed(
-                    b_ids, cp, info=gen_info
-                )
+                with self._dispatch("prefixed"):
+                    out_ids = self.engine.generate_prefixed(
+                        b_ids, cp, info=gen_info
+                    )
             except ValueError:
                 return None  # tail over the suffix ladder: cold path serves
         t_de = time.monotonic()
@@ -2443,7 +2488,8 @@ class RagService:
         th.start()
         t0 = time.monotonic()
         gen_info: Dict[str, float] = {}
-        with tracing.span("generate"):
+        with tracing.span("generate", rows=1, queue_wait_ms=0.0), \
+                self._dispatch("fused"):
             out_ids = self.engine.generate_rag(
                 a_ids, b_ids, packed_dev, toks_dev, lens_dev, n_chunks=n_ctx,
                 info=gen_info,

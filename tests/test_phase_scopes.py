@@ -1,0 +1,434 @@
+"""Phase scopes inside the compiled programs, and the record of every dispatch
+(ISSUE 24).
+
+The first half is the CPU twin of the benchmark's ``unscoped_device_time_share``:
+every executable the serving path runs is lowered at a toy size, and every
+operation the PROGRAM traced (an instruction of the optimized HLO whose
+``op_name`` is a path from its ``jit``) must name a phase of
+``obs/tracing.PHASES``. What the compiler writes itself carries no path and
+differs by backend, so it is the chip's to judge (the benchmark's reader and
+its metric); nothing under ``benchmark/`` is imported here. The second half
+drives the dispatch counters and spans.
+"""
+
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from rag_llm_k8s_tpu.core.config import (
+    AppConfig,
+    DTypePolicy,
+    EncoderConfig,
+    EngineConfig,
+    LlamaConfig,
+    PrefixCacheConfig,
+    SamplingConfig,
+)
+from rag_llm_k8s_tpu.engine.batching import BatchScheduler
+from rag_llm_k8s_tpu.engine.continuous import ContinuousEngine
+from rag_llm_k8s_tpu.engine.encoder import EncoderRunner
+from rag_llm_k8s_tpu.engine.engine import InferenceEngine
+from rag_llm_k8s_tpu.index.store import VectorStore
+from rag_llm_k8s_tpu.models.bge_m3 import init_encoder_params
+from rag_llm_k8s_tpu.models.llama import init_llama_params
+from rag_llm_k8s_tpu.obs import metrics as obs_metrics
+from rag_llm_k8s_tpu.obs import tracing
+from rag_llm_k8s_tpu.ops.knn import knn_topk_xla
+from rag_llm_k8s_tpu.server.app import RagService, create_app
+
+FP32 = DTypePolicy.fp32()
+GREEDY = SamplingConfig(do_sample=False, max_new_tokens=6)
+PC = PrefixCacheConfig(
+    enabled=True, max_prefix_tokens=64, segment_buckets=(16,),
+    suffix_buckets=(16,), hbm_budget_mb=64,
+)
+EC = EngineConfig(
+    prompt_buckets=(32, 64), max_batch_size=2, max_seq_len=128,
+    speculative="prompt_lookup", prefix_cache=PC,
+)
+# opcodes that only carry values around: no device time is theirs to file
+PLUMBING = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+            "while", "conditional", "call")
+
+
+class ByteTokenizer:
+    def encode(self, text):
+        return [b + 3 for b in text.encode("utf-8")]
+
+    def decode(self, ids, skip_special_tokens=True):
+        return bytes((i - 3) % 256 for i in ids if i >= 3).decode("utf-8", "replace")
+
+
+# ---------------------------------------------------------------------------
+# (a) every executable arrives scoped; (b) the helper's vocabulary is closed
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def llama():
+    cfg = LlamaConfig.tiny(vocab_size=300)
+    return cfg, init_llama_params(jax.random.PRNGKey(0), cfg, FP32)
+
+
+@pytest.fixture(scope="module")
+def engine(llama):
+    cfg, params = llama
+    return InferenceEngine(cfg, params, sampling=GREEDY, engine_config=EC, dtypes=FP32)
+
+
+@pytest.fixture(scope="module")
+def continuous(llama):
+    cfg, params = llama
+    import dataclasses
+
+    ec = dataclasses.replace(
+        EC, speculative="off", kv_paged=True, kv_block_size=16,
+        interleave_prefill=True, prefill_chunk_tokens=16, spec_paged=True,
+    )
+    return ContinuousEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+
+
+@pytest.fixture(scope="module")
+def encoder():
+    cfg = EncoderConfig.tiny(vocab_size=300)
+    return EncoderRunner(cfg, init_encoder_params(jax.random.PRNGKey(1), cfg, FP32),
+                         dtypes=FP32, length_buckets=(32,), max_batch=4)
+
+
+def _encoder_program(encoder):
+    i32 = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    return encoder._jit.lower(encoder.params, i32, i32).compile()
+
+
+def _knn_program(_):
+    f32 = jnp.float32
+    return knn_topk_xla.lower(
+        jax.ShapeDtypeStruct((2, 64), f32), jax.ShapeDtypeStruct((512, 64), f32),
+        jax.ShapeDtypeStruct((1, 512), f32), k=3).compile()
+
+
+ENGINE_PROGRAMS = {
+    "generate": lambda e: e._build_generate(2, 32, 6),
+    "generate_chunked": lambda e: e._build_generate(1, 128, 6, chunk=64),
+    "generate_spec": lambda e: e._build_generate_spec(32, 6),
+    "generate_rag": lambda e: e._build_generate_rag(64, 6, 16, 24, 8, 16, 2, 3, False),
+    "generate_rag_spec": lambda e: e._build_generate_rag(64, 6, 16, 24, 8, 16, 2, 3, True),
+    "generate_prefixed": lambda e: e._build_generate_prefixed(16, 6),
+    "segment_kv": lambda e: e._build_segment_kv(16),
+    "score_exact": lambda e: e._build_score_exact(64, 32),
+}
+CONTINUOUS_PROGRAMS = {
+    "continuous_prefill": lambda c: c._build_prefill_paged(32),
+    "continuous_insert": lambda c: c._build_insert_paged(32),
+    "continuous_step": lambda c: c._build_step_paged(1),
+    "continuous_verify": lambda c: c._build_verify_paged(3),
+    "continuous_mixed": lambda c: c._build_mixed_step(16),
+}
+RETRIEVE_PROGRAMS = {"encoder": _encoder_program, "knn": _knn_program}
+# the phase each program's operations must be filed under (any of them)
+EXPECTED = {
+    "generate": {"prefill", "decode"}, "generate_chunked": {"prefill", "decode"},
+    "generate_spec": {"prefill", "verify"},
+    "generate_rag": {"retrieve", "prefill", "decode"},
+    "generate_rag_spec": {"retrieve", "prefill", "verify"},
+    "generate_prefixed": {"prefill", "decode"}, "segment_kv": {"prefill"},
+    "score_exact": {"score"}, "continuous_prefill": {"prefill"},
+    "continuous_insert": {"prefill"}, "continuous_step": {"decode"},
+    "continuous_verify": {"verify"}, "continuous_mixed": {"mixed"},
+    "encoder": {"retrieve"}, "knn": {"retrieve"},
+}
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*op_name=\"(jit\([^\"]*)\"")
+
+
+def _scope(op_name):
+    """``(phase, sub-scope)`` of a traced path: the first component that is
+    a phase, and the first sub-scope (or sampler) after it; None outside."""
+    parts = op_name.split("/")
+    for i, part in enumerate(parts):
+        if part in tracing.PHASES:
+            subs = tracing.SUB_SCOPES + ("sample",)
+            return part, next((p for p in parts[i + 1:] if p in subs), "")
+    return None, ""
+
+
+def _traced(compiled):
+    """``[(opcode, op_name)]`` of the optimized program's instructions that
+    carry a path the program traced, plumbing apart."""
+    found = (_INSTRUCTION.match(line) for line in compiled.as_text().splitlines())
+    return [m.groups() for m in found if m and m.group(1) not in PLUMBING]
+
+
+def _assert_scoped(name, compiled):
+    rows = _traced(compiled)
+    assert len(rows) > 3, compiled.as_text()[:2000]
+    unscoped = [row for row in rows if _scope(row[1])[0] is None]
+    assert not unscoped, f"{name}: operations outside every phase scope: {unscoped}"
+    assert {_scope(path)[0] for _, path in rows} == EXPECTED[name], name
+
+
+@pytest.mark.parametrize("name", sorted(ENGINE_PROGRAMS))
+def test_engine_programs_arrive_scoped(engine, name):
+    _assert_scoped(name, ENGINE_PROGRAMS[name](engine))
+
+
+@pytest.mark.parametrize("name", sorted(CONTINUOUS_PROGRAMS))
+def test_continuous_programs_arrive_scoped(continuous, name):
+    _assert_scoped(name, CONTINUOUS_PROGRAMS[name](continuous))
+
+
+@pytest.mark.parametrize("name", sorted(RETRIEVE_PROGRAMS))
+def test_retrieve_programs_arrive_scoped(encoder, name):
+    _assert_scoped(name, RETRIEVE_PROGRAMS[name](encoder))
+
+
+def test_model_sub_scopes_are_named(engine):
+    """The decoder files its work under the sub-scopes, inside whichever phase
+    traced it: a decode step's attention is ``decode/attn``."""
+    found = {_scope(path) for _, path in _traced(ENGINE_PROGRAMS["generate"](engine))}
+    for sub in ("attn", "mlp", "lm_head", "norm_rope", "embed"):
+        assert ("prefill", sub) in found and ("decode", sub) in found, (sub, sorted(found))
+    assert ("decode", "sample") in found and ("prefill", "sample") in found
+
+
+def test_the_programs_state_what_the_benchmark_counts_by(engine):
+    """A step is counted by the operations traced in the body of the ``while``
+    that stands right under ``decode`` / ``verify``; a prefill's rows are the
+    ``rows<N>`` its scope states, by the layers' loop beneath it."""
+    for name, phase, rows in (("generate", "decode", 2), ("generate_spec", "verify", 1),
+                              ("generate_rag", "decode", 1), ("generate_rag_spec", "verify", 1),
+                              ("generate_prefixed", "decode", 1)):
+        paths = [path for _, path in _traced(ENGINE_PROGRAMS[name](engine))]
+        assert any(f"/{phase}/while/body/" in p for p in paths), (name, paths[:5])
+        layers = [p for p in paths if f"/prefill/rows{rows}/" in p and "/while/body/" in p]
+        assert layers, (name, [p for p in paths if "/prefill/" in p][:5])
+        assert not [p for p in paths if "/prefill/" in p and f"/prefill/rows{rows}/" not in p]
+
+
+def test_an_operation_outside_every_scope_shows():
+    def f(x):
+        y = jnp.tanh(x) @ x  # traced outside every scope
+        with tracing.phase_scope("decode"):
+            return jnp.sin(y) @ x
+
+    compiled = jax.jit(f).lower(jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
+    assert {_scope(path)[0] for _, path in _traced(compiled)} == {"decode", None}
+    with pytest.raises(AssertionError, match="outside every phase scope"):
+        _assert_scoped("generate", compiled)
+
+
+class TestVocabulary:
+    def test_refuses_a_name_outside_it(self):
+        with pytest.raises(ValueError, match="vocabulary"):
+            tracing.phase_scope("warmup")
+        with pytest.raises(ValueError, match="vocabulary"):
+            tracing.phase_scope("decode/attention")
+
+    @pytest.mark.parametrize("path", ["decode", "retrieve/embed", "verify/attn", "mixed"])
+    def test_a_scope_is_op_metadata(self, path):
+        def f(x):
+            with tracing.phase_scope(path):
+                return x * 2.0
+
+        text = jax.jit(f).lower(jnp.ones((4,))).compile().as_text()
+        assert f"/{path}/" in text
+
+    def test_rows_are_stated_in_the_path(self):
+        def f(x):
+            with tracing.phase_scope("prefill", rows=3):
+                return x * 2.0
+
+        text = jax.jit(f).lower(jnp.ones((4,))).compile().as_text()
+        assert "/prefill/rows3/" in text
+        with pytest.raises(ValueError, match="vocabulary"):
+            tracing.phase_scope("rows3")
+
+
+# ---------------------------------------------------------------------------
+# (c) the dispatch counters; (d) the dispatch spans
+# ---------------------------------------------------------------------------
+
+
+def _rows(reg):
+    fam = reg.get_family("rag_generate_dispatch_rows_total")
+    return {(dict(k)["path"], dict(k)["rows"]): c.value for k, c in fam.items()}
+
+
+def _reasons(reg):
+    fam = reg.get_family("rag_generate_dispatch_reason_total")
+    return {dict(k)["reason"]: c.value for k, c in fam.items()}
+
+
+class StubEngine:
+    """An engine whose generate is instant (or held), for the drain loop."""
+
+    def __init__(self, cap, hold=None):
+        self.engine_config = EngineConfig(max_batch_size=cap)
+        self.hold = hold
+        self.batches = []
+
+    def generate(self, prompts, max_new_tokens=None, seed=None):
+        if self.hold is not None:
+            self.hold.wait(5.0)
+        self.batches.append(len(prompts))
+        return [[1] for _ in prompts]
+
+
+def _scheduler(cap, max_wait_ms, hint=None, hold=None):
+    reg = obs_metrics.MetricsRegistry()
+    sched = BatchScheduler(StubEngine(cap, hold), max_wait_ms=max_wait_ms, pending_hint=hint)
+    sched.dispatch_counter = reg.labeled_counter("rag_generate_dispatch_rows_total")
+    sched.reason_counter = reg.labeled_counter("rag_generate_dispatch_reason_total")
+    return reg, sched
+
+
+def _submit_all(sched, requests):
+    infos = [{} for _ in requests]
+    threads = [threading.Thread(target=sched.submit, args=(p,), kwargs=dict(info=i, **kw))
+               for (p, kw), i in zip(requests, infos)]
+    for t in threads:
+        t.start()
+        time.sleep(0.01)  # arrival order is the order given
+    for t in threads:
+        t.join(10.0)
+    return infos
+
+
+class TestDispatchRecord:
+    def test_a_batch_of_three_counts_three_answers_under_rows_3(self):
+        n = [3]
+        reg, sched = _scheduler(cap=4, max_wait_ms=2000.0, hint=lambda: n[0])
+        try:
+            infos = _submit_all(sched, [([1, 2], {})] * 3)
+        finally:
+            sched.shutdown()
+        assert _rows(reg) == {("batched", "3"): 3.0}
+        assert _reasons(reg) == {"hint": 1.0}
+        assert [i["dispatch_rows"] for i in infos] == [3, 3, 3]
+        assert all(i["queue_wait_ms"] >= 0.0 for i in infos)
+
+    def test_full(self):
+        reg, sched = _scheduler(cap=2, max_wait_ms=2000.0)
+        try:
+            _submit_all(sched, [([1], {})] * 2)
+        finally:
+            sched.shutdown()
+        assert _reasons(reg) == {"full": 1.0}
+        assert _rows(reg) == {("batched", "2"): 2.0}
+
+    def test_deadline(self):
+        reg, sched = _scheduler(cap=4, max_wait_ms=20.0)
+        try:
+            _submit_all(sched, [([1], {})])
+        finally:
+            sched.shutdown()
+        assert _reasons(reg) == {"deadline": 1.0}
+        assert _rows(reg) == {("batched", "1"): 1.0}
+
+    def test_incompatible(self):
+        """A request that needs another executable ends the drain and leads
+        the next round: two dispatches of one row each."""
+        reg, sched = _scheduler(cap=4, max_wait_ms=300.0)
+        try:
+            _submit_all(sched, [([1], {"max_new_tokens": 4}), ([1], {"max_new_tokens": 8})])
+        finally:
+            sched.shutdown()
+        assert _reasons(reg)["incompatible"] == 1.0
+        assert _rows(reg) == {("batched", "1"): 2.0}
+
+    def test_the_shutdown_wake_up_is_not_a_decision(self):
+        reg, sched = _scheduler(cap=4, max_wait_ms=5000.0)
+        t = threading.Thread(target=lambda: sched.submit([1]))
+        t.start()
+        time.sleep(0.05)
+        sched.shutdown()
+        t.join(10.0)
+        assert _reasons(reg) == {}
+
+
+@pytest.fixture(scope="module")
+def served():
+    llama_cfg = LlamaConfig.tiny(vocab_size=300)
+    enc_cfg = EncoderConfig.tiny(vocab_size=300)
+    engine = InferenceEngine(
+        llama_cfg, init_llama_params(jax.random.PRNGKey(0), llama_cfg, FP32),
+        sampling=GREEDY, dtypes=FP32,
+        engine_config=EngineConfig(prompt_buckets=(128, 512), max_batch_size=4,
+                                   max_seq_len=640, rag_fused=True),
+    )
+    encoder = EncoderRunner(
+        enc_cfg, init_encoder_params(jax.random.PRNGKey(1), enc_cfg, FP32),
+        dtypes=FP32, length_buckets=(32,), max_batch=4,
+    )
+    store = VectorStore(dim=enc_cfg.hidden_size)
+    scheduler = BatchScheduler(engine, max_wait_ms=2000.0)
+    svc = RagService(AppConfig(model=llama_cfg, encoder=enc_cfg), engine, ByteTokenizer(),
+                     encoder, ByteTokenizer(), store, scheduler=scheduler)
+    svc.ready = True
+    vec = encoder.encode([ByteTokenizer().encode("tiny doc text")])[0]
+    store.add([vec], [{"filename": "f", "chunk_id": 0, "text": "kernels tile queries"}])
+    yield svc, create_app(svc).test_client()
+    scheduler.shutdown()
+
+
+def _find(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+class TestDispatchThroughTheService:
+    def test_one_fused_request_and_a_batch_of_three(self, served):
+        svc, client = served
+        before = _rows(svc.metrics)
+        r = client.post("/generate", json={"prompt": "what do kernels do?", "trace": True})
+        assert r.status_code == 200, r.get_json()
+        body = r.get_json()
+        after_one = _rows(svc.metrics)
+        # a solo request takes the fused single-fetch path: one row, its own dispatch
+        moved = {k: v - before.get(k, 0) for k, v in after_one.items() if v != before.get(k, 0)}
+        assert moved == {("fused", "1"): 1.0}
+        # (d) generate -> dispatch -> {launch, fetch}, rows set, and the
+        # top-level spans still sum to the request's total
+        tree = body["trace"]
+        gen = _find(tree["spans"], "generate")[0]
+        assert gen["attrs"]["rows"] == 1.0 and gen["attrs"]["queue_wait_ms"] == 0.0
+        dispatch = _find(gen["spans"], "dispatch")[0]
+        assert dispatch["attrs"]["rows"] == 1.0
+        assert [s["name"] for s in dispatch["spans"]] == ["launch", "fetch"]
+        stage_sum = sum(s["duration_ms"] for s in tree["spans"])
+        assert stage_sum == pytest.approx(body["timings"]["total_ms"], rel=0.05)
+
+        # three callers at once: one batch of three through the scheduler
+        results = []
+        threads = [threading.Thread(target=lambda i=i: results.append(
+            client.post("/generate", json={"prompt": f"question {i}?", "trace": True})))
+            for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60.0)
+        assert [x.status_code for x in results] == [200] * 3
+        moved = {k: v - after_one.get(k, 0) for k, v in _rows(svc.metrics).items()
+                 if v != after_one.get(k, 0)}
+        assert sum(moved.values()) == 3.0, moved
+        # whichever way the three were admitted (one batch, or the admission
+        # race's one-and-two), each request's tree says what it rode with
+        rode = sorted(_find(x.get_json()["trace"]["spans"], "generate")[0]["attrs"]["rows"]
+                      for x in results)
+        counted = sorted(float(rows) for (_, rows), n in moved.items()
+                         for _ in range(int(n)))
+        assert rode == counted, (rode, moved)
+
+    def test_a_dispatch_that_fails_was_still_dispatched(self, served):
+        """Counted at the launch, as the scheduler counts its batches, so the
+        family has one meaning on every path."""
+        svc, _ = served
+        before = _rows(svc.metrics).get(("direct", "1"), 0.0)
+        with pytest.raises(RuntimeError, match="device lost"):
+            with svc._dispatch("direct"):
+                raise RuntimeError("device lost")
+        assert _rows(svc.metrics)[("direct", "1")] == before + 1.0
