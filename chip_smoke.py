@@ -290,6 +290,33 @@ def kernel_cases(seed: int):
     yield "chunk_prefill_attention", lambda: chunk(False), 2e-4, 2e-5
     yield "chunk_prefill_attention_q8", lambda: chunk(True), 0.0, 0.03
 
+    def grouped(q8: bool):
+        """A speculative verify step of one row (spec_tokens + 1 = 16
+        positions, G*S = 64 query rows a KV head) against the oracle AND the
+        per-head kernel it stands in for: behind a left pad mid-generation,
+        from slot 0 across a block edge, and on the cache's last slots."""
+        B, S = 1, 16
+        kc, vc = dense_cache(B)
+        q = normal(24, (B, S, H, HD))
+        cache = (kc, vc)
+        fn, per_head, ref = (
+            A.chunk_attention_grouped, A.chunk_prefill_attention, A.chunk_attention_xla)
+        if q8:
+            (kq, ksc), (vq, vsc) = A.quantize_kv(kc), A.quantize_kv(vc)
+            cache = (kq, vq, ksc, vsc)
+            fn, per_head, ref = (
+                A.chunk_attention_grouped_q8, A.chunk_prefill_attention_q8,
+                A.chunk_attention_xla_q8)
+        pairs = []
+        for kv_start, wi in ((900, T_MAX - 215), (0, 250), (T_MAX // 2, T_MAX - S)):
+            args = (q, *cache, i32([kv_start]), i32([wi + S]), i32(1), i32(wi))
+            got = fn(*args)
+            pairs += [(got, ref(*args)), (got, per_head(*args))]
+        return pairs
+
+    yield "chunk_attention_grouped", lambda: grouped(False), 2e-4, 2e-5
+    yield "chunk_attention_grouped_q8", lambda: grouped(True), 0.0, 0.03
+
     # -- RoPE re-rotation of cached K ----------------------------------------
     inv = np.asarray(rope_frequencies(LlamaConfig.llama_3_1_8b()), np.float64)
 

@@ -27,6 +27,7 @@ via NamedSharding — XLA inserts the ICI collectives.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import flax.linen as nn
@@ -36,9 +37,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from rag_llm_k8s_tpu.core.config import DTypePolicy, LlamaConfig
-from rag_llm_k8s_tpu.obs.tracing import phase_scope
+from rag_llm_k8s_tpu.obs.tracing import count_kernel_build, phase_scope
 from rag_llm_k8s_tpu.ops.attention import (
     attention_xla,
+    chunk_attention_grouped,
+    chunk_attention_grouped_q8,
     chunk_attention_xla,
     chunk_attention_xla_q8,
     chunk_prefill_attention,
@@ -48,6 +51,7 @@ from rag_llm_k8s_tpu.ops.attention import (
     decode_attention_xla,
     decode_attention_xla_q8,
     flash_attention,
+    grouped_chunk_fits,
     paged_chunk_attention,
     paged_chunk_attention_q8,
     paged_chunk_attention_xla,
@@ -537,6 +541,7 @@ class Attention(nn.Module):
             # sequence, K/V blocks rotate via ppermute on the ICI ring
             # (parallel/ring_attention.py). Differentiable (the training
             # path), composes with tp over heads.
+            count_kernel_build(mode, "ring_attention")
             return self._attend_ring(q, k, v, kv_start, kv_len, sp, tp)
         heads_shardable = tp > 1 and H % tp == 0 and K % tp == 0
         if impl != "xla" and tp > 1 and not heads_shardable:
@@ -545,6 +550,7 @@ class Attention(nn.Module):
             # gather — the sharding-transparent XLA path is strictly better
             impl = "xla"
         if impl == "xla":
+            count_kernel_build(mode, "xla")
             if mode == "decode":
                 if scales is not None:
                     return decode_attention_xla_q8(
@@ -562,27 +568,27 @@ class Attention(nn.Module):
                 )
             return attention_xla(q, k, v, kv_start=kv_start, kv_len=kv_len, causal=True)
 
-        interpret = impl == "pallas_interpret"
-        if mode == "decode" and scales is not None:
-            kernel = lambda q_, k_, v_, ks_, vs_, s_, l_, lay_: decode_attention_q8(  # noqa: E731
-                q_, k_, v_, ks_, vs_, s_, l_, lay_, interpret=interpret
-            )
-        elif mode == "decode":
-            kernel = lambda q_, k_, v_, s_, l_, lay_: decode_attention(  # noqa: E731
-                q_, k_, v_, s_, l_, lay_, interpret=interpret
-            )
-        elif mode == "chunk" and scales is not None:
-            kernel = lambda q_, k_, v_, ks_, vs_, s_, l_, lay_, wi_: chunk_prefill_attention_q8(  # noqa: E731
-                q_, k_, v_, ks_, vs_, s_, l_, lay_, wi_, interpret=interpret
-            )
+        # every fused kernel takes its arrays positionally, the cache kernels
+        # in one order (q, k, v[, k_scale, v_scale], kv_start, kv_len, layer
+        # [, write_index]): the choice below is of a function, by mode, by
+        # whether scales ride along, and for a chunk by its static shape
+        q8 = scales is not None
+        if mode == "decode":
+            fn = decode_attention_q8 if q8 else decode_attention
         elif mode == "chunk":
-            kernel = lambda q_, k_, v_, s_, l_, lay_, wi_: chunk_prefill_attention(  # noqa: E731
-                q_, k_, v_, s_, l_, lay_, wi_, interpret=interpret
-            )
+            # a small chunk (a speculative verify step's spec_tokens + 1
+            # positions) folds a kv head's G query heads and the S positions
+            # into one matmul's rows and streams the cache once a KV head;
+            # prompt chunks of hundreds of rows keep the per-head kernel
+            local_kv = K // tp if heads_shardable else K
+            if grouped_chunk_fits(H // K, q.shape[1], local_kv):
+                fn = chunk_attention_grouped_q8 if q8 else chunk_attention_grouped
+            else:
+                fn = chunk_prefill_attention_q8 if q8 else chunk_prefill_attention
         else:
-            kernel = lambda q_, k_, v_, s_, l_: flash_attention(  # noqa: E731
-                q_, k_, v_, s_, l_, causal=True, interpret=interpret
-            )
+            fn = flash_attention
+        count_kernel_build(mode, fn.__name__)
+        kernel = functools.partial(fn, interpret=impl == "pallas_interpret")
 
         if heads_shardable:
             # heads are independent: shard the kernel over the tp axis, one

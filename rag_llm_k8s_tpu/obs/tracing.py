@@ -49,7 +49,7 @@ import uuid
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 logger = logging.getLogger("rag_llm_k8s_tpu.trace")
 
@@ -86,6 +86,26 @@ def phase_scope(path: str, rows: Optional[int] = None):
             f"scope {unknown[0]!r} is not in the vocabulary {sorted(SCOPE_NAMES)}"
         )
     return jax.named_scope(path if rows is None else f"{path}/rows{int(rows)}")
+
+
+# Which attention kernel ``LlamaModel._attend`` built into a program, by the
+# mode it served (prefill | decode | chunk). The choice is made where a
+# program is TRACED — by static shapes, once a compiled program — so this
+# counts traces and costs a dispatch nothing. Process-wide, like the jit
+# caches the programs live in; ``/metrics`` serves it as
+# ``rag_attend_kernel_builds_total{mode, kernel}``.
+_kernel_builds: Dict[Tuple[str, str], int] = {}
+_kernel_builds_lock = threading.Lock()
+
+
+def count_kernel_build(mode: str, kernel: str) -> None:
+    with _kernel_builds_lock:
+        _kernel_builds[(mode, kernel)] = _kernel_builds.get((mode, kernel), 0) + 1
+
+
+def kernel_builds() -> Dict[Tuple[str, str], int]:
+    with _kernel_builds_lock:
+        return dict(_kernel_builds)
 
 
 @dataclass

@@ -972,7 +972,14 @@ def chunk_prefill_attention_q8(
     directly and dequantizes in the matmul EPILOGUES (score × k-scale,
     prob × v-scale) — the long-prompt int8 path never materializes a bf16
     layer slice, so chunked prefill keeps the bandwidth int8 bought.
-    (Round 3 dequantized ``[1, B, K, T, hd]`` bf16 per layer per chunk.)"""
+    (Round 3 dequantized ``[1, B, K, T, hd]`` bf16 per layer per chunk.)
+
+    Reached by chunks too long for one MXU pass a KV head (``G·S > 128``:
+    prompt chunks over the largest bucket, the prefix cache's segment
+    builder, dense chunked admission). A speculative verify step's
+    ``spec_tokens + 1`` positions go to ``chunk_attention_grouped_q8``
+    (``grouped_chunk_fits``): at S = 16 this grid is 544 steps of a 16-row
+    matmul a call, each KV head's blocks streamed G times."""
     B, S, H, hd = q.shape
     L, _, K, T, _ = k_cache.shape
     G = H // K
@@ -1031,6 +1038,252 @@ def chunk_prefill_attention_q8(
     )
 
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# GQA-grouped chunk attention (small chunks over the dense cache)
+# ---------------------------------------------------------------------------
+#
+# The per-head chunk kernels above were written for prompt chunks of hundreds
+# of rows: grid (B·H, S//bq, T//bk), one query head a cell. Speculative
+# verification hands the same path S = spec_tokens + 1 = 16 rows: every cell
+# is then a [16, hd] × [bk, hd] matmul (16 of the MXU's 128 rows), the cache
+# of a KV head is streamed once for each of its G query heads, and the grid
+# steps alone (544 a call at 8B widths) outweigh the work twenty times over.
+# The grouped kernel takes ``decode_attention``'s grid instead: (B, T//bk),
+# every KV head's block in one cell, and the G query heads of a KV head
+# folded with the S chunk positions into ONE matmul's G·S rows. The cache is
+# streamed once, in T//bk steps. Arithmetic per (query, head) — payload,
+# scales, fp32 accumulators, softmax recurrence, masks — is the per-head
+# kernel's. The model step picks it by shape alone (``grouped_chunk_fits``).
+
+GROUPED_CHUNK_ROWS = 128  # one pass of the MXU's rows
+GROUPED_CELL_ROWS = 1024  # query rows of ALL kv heads a grid cell holds in VMEM
+
+
+def grouped_chunk_fits(group: int, chunk: int, kv_heads: int) -> bool:
+    """The shape rule ``LlamaModel._attend`` applies: the grouped kernel when
+    a KV head's ``group · chunk`` query rows fit one MXU pass (and the cell's
+    ``kv_heads`` of them fit VMEM: queries, output and the three accumulators
+    stay resident), the per-head kernel for the long chunks it was written
+    for. ``kv_heads`` is what one device holds (K/tp under ``shard_map``)."""
+    rows = group * chunk
+    return rows <= GROUPED_CHUNK_ROWS and kv_heads * rows <= GROUPED_CELL_ROWS
+
+
+def _chunk_grouped_kernel(
+    layer_ref,  # SMEM [1] (consumed by the index maps)
+    wi_ref,  # SMEM [1]: write_index — global cache slot of query 0
+    kv_start_ref,  # SMEM [B]
+    kv_len_ref,  # SMEM [B]
+    q_ref,  # [1, K, G*S, hd] — row r of a kv head is (head r // S, query r % S)
+    k_ref,  # [1, 1, K, bk, hd] (int8 when quantized)
+    v_ref,  # [1, 1, K, bk, hd]
+    *rest,  # quantized: ks_ref, vs_ref [1, 1, K, bk] fp32; then o_ref + scratch
+    chunk: int,
+    group: int,
+    bk: int,
+    scale: float,
+    quantized: bool,
+):
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
+    b = pl.program_id(0)
+    kj = pl.program_id(1)
+    nk = pl.num_programs(1)
+    wi = wi_ref[0]
+
+    @pl.when(kj == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    # block skip, as in the per-head chunk kernel: inside the left pad, past
+    # the frontier, or strictly above the last query's slot
+    blk_lo = kj * bk
+    overlap = (blk_lo + bk > kv_start_ref[b]) & (blk_lo < kv_len_ref[b])
+    live = overlap & (blk_lo <= wi + chunk - 1)
+
+    @pl.when(live)
+    def _compute():
+        q = q_ref[0]  # [K, G*S, hd]
+        cpos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        cok = (cpos >= kv_start_ref[b]) & (cpos < kv_len_ref[b])  # [1, bk]
+        if quantized:
+            # int8 payloads need no validity masking (every bit pattern is
+            # finite); scales CAN be NaN past the frontier, so they are
+            # zeroed under the window mask, used whole: [K, bk], no row-select
+            k = k_ref[0, 0].astype(q.dtype)  # [K, bk, hd]
+            v = v_ref[0, 0].astype(q.dtype)
+            ks = jnp.where(cok, ks_ref[0, 0], 0.0)
+            vs = jnp.where(cok, vs_ref[0, 0], 0.0)
+        else:
+            # zero K/V rows outside the valid window BEFORE any matmul (cache
+            # slots past the frontier may be uninitialized; 0 * NaN = NaN)
+            rpos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, (1, bk, 1), 1)
+            rok = (rpos >= kv_start_ref[b]) & (rpos < kv_len_ref[b])
+            k = jnp.where(rok, k_ref[0, 0], 0)
+            v = jnp.where(rok, v_ref[0, 0], 0)
+        # one batched dot over the kv heads: [K, G*S, hd] x [K, bk, hd]
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        ) * scale  # [K, G*S, bk]
+        if quantized:
+            s = s * ks[:, None, :]  # dequantization rides the epilogues
+
+        # row r is query r % S: peeled off by G - 1 selects on one column
+        # (no vector division); that query sits at cache slot wi + r % S
+        r = jax.lax.broadcasted_iota(jnp.int32, (group * chunk, 1), 0)
+        t = r
+        for g in range(1, group):
+            t = jnp.where(r >= g * chunk, r - g * chunk, t)
+        ok = (cok & (cpos <= wi + t))[None]  # [1, G*S, bk], one mask for all K
+        s = jnp.where(ok, s, NEG_INF)
+
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        if quantized:
+            p = p * vs[:, None, :]  # V scale folded into the prob matrix
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = m_new
+
+    @pl.when(kj == nk - 1)
+    def _emit():
+        l = jnp.maximum(l_scr[:], 1e-30)
+        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+
+
+def _chunk_grouped(q, k_cache, v_cache, scales, kv_start, kv_len, layer,
+                   write_index, bk, interpret):
+    """Both grouped kernels: ``scales`` is ``(k_scale, v_scale)`` over an
+    int8 cache, ``None`` over a bf16 one."""
+    B, S, H, hd = q.shape
+    L, _, K, T, _ = k_cache.shape
+    G = H // K
+    rows = G * S
+    quantized = scales is not None
+    # what a cell holds in VMEM for each cache slot of its block: the fp32
+    # score and probability columns [K, G*S], the K and V rows twice (the
+    # pipeline's two buffers) and, over int8, their copies in q's dtype.
+    # Keep that near 6 MiB whatever the head count.
+    slot_bytes = K * (2 * rows * 4 + 4 * hd * k_cache.dtype.itemsize
+                      + (2 * hd * q.dtype.itemsize if quantized else 0))
+    while bk > 128 and slot_bytes * bk > 6 * 1024 * 1024:
+        bk //= 2
+    bk = _decode_block(T, bk)
+    if not interpret and (bk % (32 if quantized else 16)
+                          or (quantized and bk % 128 and bk != T)):
+        # int8 blocks need a 32-row second-to-minor tile (bf16: 16), and the
+        # scale block's minor dim is bk: lanes of 128, or the whole of T
+        raise ValueError(
+            f"cache length T={T} only tiles into blocks of {bk}: pad T to a "
+            "multiple of 128 — the engine rounds cache lengths for this"
+        )
+    nk = T // bk
+
+    # [B, S, K*G, hd] -> [B, K, G*S, hd]: head k*G + g, query s at row g*S + s
+    qh = q.reshape(B, S, K, G, hd).transpose(0, 2, 3, 1, 4).reshape(B, K, rows, hd)
+
+    def live_block(b, kj, wi_ref, kv_start_ref, kv_len_ref):
+        # dead blocks (left pad, past the frontier) name the nearest live
+        # one, so the pipeline does not fetch what the body will not read
+        lo = jnp.minimum(jax.lax.div(kv_start_ref[b], bk), nk - 1)
+        end = jnp.minimum(kv_len_ref[b], wi_ref[0] + S)
+        hi = jnp.minimum(jax.lax.div(jnp.maximum(end - 1, 0), bk), nk - 1)
+        return jnp.clip(kj, lo, jnp.maximum(hi, lo))
+
+    def kv_index(b, kj, layer_ref, *s_):
+        return (layer_ref[0], b, 0, live_block(b, kj, *s_), 0)
+
+    def sc_index(b, kj, layer_ref, *s_):
+        return (layer_ref[0], b, 0, live_block(b, kj, *s_))
+
+    def q_index(b, kj, *s_):
+        return (b, 0, 0, 0)
+
+    kv_spec = pl.BlockSpec((1, 1, K, bk, hd), kv_index)
+    sc_specs = [pl.BlockSpec((1, 1, K, bk), sc_index)] * 2 if quantized else []
+    out = pl.pallas_call(
+        functools.partial(
+            _chunk_grouped_kernel, chunk=S, group=G, bk=bk, scale=hd**-0.5,
+            quantized=quantized,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, nk),
+            in_specs=[pl.BlockSpec((1, K, rows, hd), q_index), kv_spec, kv_spec,
+                      *sc_specs],
+            out_specs=pl.BlockSpec((1, K, rows, hd), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((K, rows, 1), jnp.float32),
+                pltpu.VMEM((K, rows, 1), jnp.float32),
+                pltpu.VMEM((K, rows, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, K, rows, hd), q.dtype),
+        interpret=interpret,
+        name="chunk_attention_grouped_q8" if quantized else "chunk_attention_grouped",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.asarray(write_index, jnp.int32).reshape(1),
+        kv_start.astype(jnp.int32),
+        kv_len.astype(jnp.int32),
+        qh,
+        k_cache,
+        v_cache,
+        *(scales or ()),
+    )
+
+    return out.reshape(B, K, G, S, hd).transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
+
+
+@functools.partial(jax.jit, static_argnames=("bk", "interpret"))
+def chunk_attention_grouped(
+    q: jax.Array,  # [B, S, H, hd] — a small chunk: G*S <= GROUPED_CHUNK_ROWS
+    k_cache: jax.Array,  # [L, B, K, T, hd] — FULL stacked head-major cache
+    v_cache: jax.Array,  # [L, B, K, T, hd]
+    kv_start: jax.Array,  # [B] int32: first valid cache slot
+    kv_len: jax.Array,  # [B] int32: valid frontier (= write_index + S)
+    layer: jax.Array,  # [] or [1] int32
+    write_index: jax.Array,  # [] or [1] int32: cache slot of query 0
+    bk: int = 512,
+    interpret: bool = False,
+) -> jax.Array:
+    """``chunk_prefill_attention`` for SMALL chunks (see the note above):
+    same arguments, same offset causality, same result; the cache is read
+    once a KV head instead of once a query head."""
+    return _chunk_grouped(q, k_cache, v_cache, None, kv_start, kv_len, layer,
+                          write_index, bk, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("bk", "interpret"))
+def chunk_attention_grouped_q8(
+    q: jax.Array,  # [B, S, H, hd] — a small chunk: G*S <= GROUPED_CHUNK_ROWS
+    k_cache: jax.Array,  # [L, B, K, T, hd] int8
+    v_cache: jax.Array,  # [L, B, K, T, hd] int8
+    k_scale: jax.Array,  # [L, B, K, T] fp32
+    v_scale: jax.Array,  # [L, B, K, T] fp32
+    kv_start: jax.Array,  # [B] int32
+    kv_len: jax.Array,  # [B] int32
+    layer: jax.Array,  # [] or [1] int32
+    write_index: jax.Array,  # [] or [1] int32: cache slot of query 0
+    bk: int = 512,
+    interpret: bool = False,
+) -> jax.Array:
+    """``chunk_attention_grouped`` over an int8 KV cache: the speculative
+    verify step's attention (S = spec_tokens + 1). Dequantization rides the
+    two matmul epilogues as in ``chunk_prefill_attention_q8``."""
+    return _chunk_grouped(q, k_cache, v_cache, (k_scale, v_scale), kv_start,
+                          kv_len, layer, write_index, bk, interpret)
 
 
 # ---------------------------------------------------------------------------

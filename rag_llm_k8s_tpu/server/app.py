@@ -461,6 +461,15 @@ class RagService:
             "why the batch scheduler's drain loop stopped "
             "(full|hint|deadline|incompatible)",
         )
+        # which attention kernel each compiled program was built with
+        # (obs/tracing.count_kernel_build, where LlamaModel._attend chooses
+        # by static shape): children appear as programs are traced, synced
+        # from the process-wide tally at scrape time (_sync_kernel_builds)
+        self._m_kernel_builds = reg.labeled_counter(
+            "rag_attend_kernel_builds_total",
+            "attention dispatches traced into compiled programs, by mode "
+            "(prefill|decode|chunk) and the kernel chosen",
+        )
         # present in every mode so dashboards stay uniform; only the
         # continuous engine's host loop can actually observe it (exact
         # submit→first-token), so it stays empty under coalesce serving
@@ -979,6 +988,15 @@ class RagService:
         if sched_engine is not None:
             engines[id(sched_engine)] = sched_engine
         return engines
+
+    def _sync_kernel_builds(self) -> None:
+        """One callback child per (mode, kernel) the process has traced so
+        far; the label set is bounded by the kernels ``_attend`` can name."""
+        for mode, kernel in tracing.kernel_builds():
+            self._m_kernel_builds.labels_callback(
+                lambda key=(mode, kernel): tracing.kernel_builds().get(key, 0),
+                mode=mode, kernel=kernel,
+            )
 
     def _engine_stat(self, name: str) -> float:
         return float(sum(
@@ -3154,6 +3172,7 @@ class WsgiApp:
         the flat JSON snapshot stays available under Accept:
         application/json (same values — tests/test_obs.py pins it)."""
         reg = self.service.metrics
+        self.service._sync_kernel_builds()
         if "application/json" in (request.headers.get("Accept") or ""):
             return self._jsonify(reg.snapshot())
         return self._Response(

@@ -486,6 +486,9 @@ class TestGoodputMixed:
         eng = ContinuousEngine(
             cfg, params, sampling=GREEDY, engine_config=INTER, dtypes=FP32
         )
+        # the ring must cover the whole run: a file this worker ran before
+        # (tests/test_replay.py) can leave the process recorder at 64 events
+        flight.configure(enabled=True, capacity=8192)
         seq0 = flight.recorder().events_emitted
         drain(eng, [(i + 1, p, 10) for i, p in enumerate(PROMPTS)])
         st = eng.ledger.state()

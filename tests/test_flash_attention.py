@@ -436,3 +436,159 @@ class TestChunkPrefillAttentionQ8:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5
         )
+
+
+class TestChunkAttentionGrouped:
+    """The GQA-grouped chunk kernels (interpret mode): small chunks — a
+    speculative verify step's positions — with a KV head's G query heads and
+    the S positions as one matmul's rows, against the dense oracles and
+    against the per-head kernels they stand in for."""
+
+    T, BK, L = 256, 64, 3
+
+    def _problem(self, seed, B, S, G, K=2, hd=64):
+        from rag_llm_k8s_tpu.ops.attention import quantize_kv
+
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        q = jax.random.normal(ks[0], (B, S, K * G, hd), jnp.float32)
+        kc = jax.random.normal(ks[1], (self.L, B, K, self.T, hd), jnp.float32)
+        vc = jax.random.normal(ks[2], (self.L, B, K, self.T, hd), jnp.float32)
+        return q, kc, vc, quantize_kv(kc), quantize_kv(vc)
+
+    def _offsets(self, S):
+        # first slot; straddling a bk edge; the last S slots of the cache
+        return (0, self.BK - (S + 1) // 2, self.T - S)
+
+    @pytest.mark.parametrize("q8", [False, True], ids=["bf16", "q8"])
+    @pytest.mark.parametrize("B", [1, 2])
+    @pytest.mark.parametrize("G", [1, 4])
+    @pytest.mark.parametrize("S", [1, 5, 16, 32])
+    def test_matches_oracle_and_per_head_kernel(self, S, G, B, q8):
+        from rag_llm_k8s_tpu.ops import attention as A
+
+        q, kc, vc, (kq, ksc), (vq, vsc) = self._problem(S * 8 + G + B, B, S, G)
+        cache = (kq, vq, ksc, vsc) if q8 else (kc, vc)
+        grouped = A.chunk_attention_grouped_q8 if q8 else A.chunk_attention_grouped
+        per_head = A.chunk_prefill_attention_q8 if q8 else A.chunk_prefill_attention
+        oracle = A.chunk_attention_xla_q8 if q8 else A.chunk_attention_xla
+        kv_start = jnp.array([0, 23][:B], jnp.int32)  # row 1 is left-padded
+        for wi in self._offsets(S):
+            kv_len = jnp.full((B,), wi + S, jnp.int32)
+            for lay in range(self.L):
+                tail = (kv_start, kv_len, jnp.int32(lay), jnp.int32(wi))
+                got = grouped(q, *cache, *tail, bk=self.BK, interpret=True)
+                np.testing.assert_allclose(
+                    np.asarray(got), np.asarray(oracle(q, *cache, *tail)),
+                    rtol=2e-4, atol=2e-5,
+                )
+                # same blocks, same recurrence: the per-head kernel's result
+                np.testing.assert_allclose(
+                    np.asarray(got),
+                    np.asarray(per_head(q, *cache, *tail, bk=self.BK, interpret=True)),
+                    rtol=1e-6, atol=1e-6,
+                )
+
+    @pytest.mark.parametrize("S,G", [(16, 4), (5, 1)])
+    def test_nan_scales_outside_the_window_do_not_poison(self, S, G):
+        """Left pad below ``kv_start`` and slots past ``kv_len`` can hold NaN
+        scales (donated device memory): they are zeroed under the window
+        mask and must not reach the output."""
+        from rag_llm_k8s_tpu.ops.attention import (
+            chunk_attention_grouped_q8,
+            chunk_attention_xla_q8,
+        )
+
+        q, _, _, (kq, ksc), (vq, vsc) = self._problem(3, 2, S, G)
+        wi = self.BK - 3
+        kv_start = jnp.array([7, 40], jnp.int32)
+        kv_len = jnp.full((2,), wi + S, jnp.int32)
+        t = jnp.arange(self.T)[None, None, None, :]
+        outside = (t >= wi + S) | (t < kv_start[None, :, None, None])
+        ksc = jnp.where(outside, jnp.nan, ksc)
+        vsc = jnp.where(outside, jnp.nan, vsc)
+        tail = (kv_start, kv_len, jnp.int32(1), jnp.int32(wi))
+        got = chunk_attention_grouped_q8(
+            q, kq, vq, ksc, vsc, *tail, bk=self.BK, interpret=True
+        )
+        assert not bool(jnp.any(jnp.isnan(got))), "NaN scales leaked"
+        np.testing.assert_allclose(
+            np.asarray(got),
+            np.asarray(chunk_attention_xla_q8(q, kq, vq, ksc, vsc, *tail)),
+            rtol=2e-4, atol=2e-5,
+        )
+
+    def test_block_budget_shrinks_with_head_count(self):
+        """The K/V block is sized from what a cell holds in VMEM a cache slot:
+        at the benchmark's widths the preferred 512 stays; 32 KV heads of
+        bf16 halve it twice."""
+        from rag_llm_k8s_tpu.ops import attention as A
+
+        def bk_of(K, G, S, dtype):
+            q = jax.ShapeDtypeStruct((1, S, K * G, 128), jnp.bfloat16)
+            kv = jax.ShapeDtypeStruct((2, 1, K, 1024, 128), dtype)
+            i1 = jax.ShapeDtypeStruct((1,), jnp.int32)
+            i0 = jax.ShapeDtypeStruct((), jnp.int32)
+            jaxpr = jax.make_jaxpr(
+                lambda *a: A.chunk_attention_grouped(*a, interpret=True)
+            )(q, kv, kv, i1, i1, i0, i0)
+            (call,) = _pallas_calls(jaxpr)
+            return call.params["grid_mapping"].grid[1]
+
+        assert bk_of(8, 4, 16, jnp.bfloat16) == 1024 // 512
+        assert bk_of(32, 1, 32, jnp.bfloat16) == 1024 // 128
+
+    @pytest.mark.parametrize("kv_quant", ["bf16", "int8"])
+    @pytest.mark.parametrize("S,want", [
+        (16, "chunk_attention_grouped"),  # a verify step: G*S = 32
+        (64, "chunk_attention_grouped"),  # G*S = 128, the last that fits
+        (512, "chunk_prefill_attention"),  # a prompt chunk
+    ])
+    def test_attend_picks_by_shape(self, S, want, kv_quant):
+        """``LlamaModel._attend(mode="chunk")`` reads static shapes only:
+        the grouped kernel at ``G*S <= 128``, the per-head one for prompt
+        chunks — counted in the jaxpr by ``pallas_call`` name, and in the
+        tally ``/metrics`` serves."""
+        from rag_llm_k8s_tpu.core.config import DTypePolicy, LlamaConfig
+        from rag_llm_k8s_tpu.models.llama import (
+            LlamaModel,
+            init_llama_params,
+            make_kv_cache,
+        )
+        from rag_llm_k8s_tpu.obs import tracing
+
+        cfg = LlamaConfig.tiny()  # 4 query heads over 2 KV heads
+        fp32 = DTypePolicy.fp32()
+        model = LlamaModel(
+            cfg, fp32, attn_impl="pallas_interpret", chunked=True, kv_quant=kv_quant
+        )
+        params = jax.eval_shape(
+            lambda: init_llama_params(jax.random.PRNGKey(0), cfg, fp32)
+        )
+        cache = jax.eval_shape(lambda: make_kv_cache(cfg, 1, 1024, jnp.float32, kv_quant))
+        name = want + ("_q8" if kv_quant == "int8" else "")
+        before = tracing.kernel_builds().get(("chunk", name), 0)
+        jaxpr = jax.make_jaxpr(
+            lambda p, c: model.apply(
+                {"params": p}, jnp.zeros((1, S), jnp.int32),
+                jnp.zeros((1, S), jnp.int32), c, jnp.zeros((1,), jnp.int32),
+                jnp.full((1,), 256 + S, jnp.int32), jnp.int32(256),
+            )
+        )(params, cache)
+        names = {c.params["name"] for c in _pallas_calls(jaxpr)}
+        assert names == {name}, names
+        assert tracing.kernel_builds()[("chunk", name)] > before
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, sub-jaxprs included."""
+    found = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found

@@ -326,6 +326,24 @@ class TestExposition:
         assert samples[("tpu_rag_engine_generate_calls", "")] >= 1
 
 
+    def test_attend_kernel_builds_follow_the_tally(self, served):
+        """``rag_attend_kernel_builds_total{mode, kernel}``: the fixture's
+        programs were traced with the XLA attention (CPU); a kernel traced
+        later (here: counted by hand, as ``LlamaModel._attend`` does where it
+        chooses) shows in the next scrape, in both renderings."""
+        _, client = served
+        fam = "rag_attend_kernel_builds_total"
+        samples = _parse_samples(client.get("/metrics").get_data(as_text=True))
+        assert samples[(fam, '{kernel="xla",mode="prefill"}')] >= 1
+        key = (fam, '{kernel="chunk_attention_grouped_q8",mode="chunk"}')
+        before = samples.get(key, 0)
+        tracing.count_kernel_build("chunk", "chunk_attention_grouped_q8")
+        samples = _parse_samples(client.get("/metrics").get_data(as_text=True))
+        assert samples[key] == before + 1
+        body = client.get("/metrics", headers={"Accept": "application/json"}).get_json()
+        assert body[fam] == sum(v for (n, _), v in samples.items() if n == fam)
+
+
 class TestTracedGenerate:
     def test_span_tree_matches_timings(self, served):
         _, client = served

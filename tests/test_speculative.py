@@ -162,6 +162,37 @@ class TestSpecWithQuantization:
         assert spec.stats.spec_verify_steps > 0
 
 
+    @pytest.mark.parametrize("spec_tokens,kernel", [
+        (15, "chunk_attention_grouped_q8"),  # G*S = 2*16: one MXU pass
+        (71, "chunk_prefill_attention_q8"),  # G*S = 2*72 > 128: per head
+    ])
+    def test_exact_vs_vanilla_through_the_fused_verify_kernels(self, spec_tokens, kernel):
+        """The verify program built on the Pallas path (interpret mode) over
+        the int8 cache: whichever chunk kernel the draft length selects, the
+        greedy stream is token-identical to the vanilla loop's."""
+        from rag_llm_k8s_tpu.obs import tracing
+
+        cfg = LlamaConfig.tiny()
+        params = init_llama_params(jax.random.PRNGKey(0), cfg, FP32)
+        ec = dataclasses.replace(
+            ENG, kv_quant="int8", attn_impl="pallas_interpret",
+            prompt_buckets=(32,), max_seq_len=256,
+        )
+        vanilla = InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+        spec = InferenceEngine(
+            cfg, params, sampling=GREEDY,
+            engine_config=dataclasses.replace(
+                ec, speculative="prompt_lookup", spec_tokens=spec_tokens
+            ),
+            dtypes=FP32,
+        )
+        before = tracing.kernel_builds().get(("chunk", kernel), 0)
+        for p in ([3, 17, 42, 7, 99], [5, 9, 2] * 5):
+            assert spec.generate([p])[0] == vanilla.generate([p])[0], p
+        assert spec.stats.spec_verify_steps > 0
+        assert tracing.kernel_builds().get(("chunk", kernel), 0) > before
+
+
 class TestSampledDistribution:
     """Rejection-sampling verification must preserve the SAMPLED output
     distribution exactly: accept proposal x w.p. p(x) under the filtered

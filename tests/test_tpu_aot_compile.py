@@ -141,6 +141,18 @@ CASES = {
         [((1, 512, H, HD), BF16), *_dense_cache(True, 1),
          ((1,), I32), ((1,), I32), ((), I32), ((), I32)],
     ),
+    # the speculative verify step of the benchmark's solo cell: 16 draft
+    # positions of one row, so G*S = 64 query rows a KV head ([K, 64, 1] scratch)
+    "chunk_attention_grouped[S=16]": (
+        A.chunk_attention_grouped,
+        [((1, 16, H, HD), BF16), *_dense_cache(False, 1),
+         ((1,), I32), ((1,), I32), ((), I32), ((), I32)],
+    ),
+    "chunk_attention_grouped_q8[S=16]": (
+        A.chunk_attention_grouped_q8,
+        [((1, 16, H, HD), BF16), *_dense_cache(True, 1),
+         ((1,), I32), ((1,), I32), ((), I32), ((), I32)],
+    ),
     "knn_topk_pallas": (
         lambda q, emb, norms: knn_topk_pallas(q, emb, norms, k=5),
         [((8, 1024), F32), ((131072, 1024), F32), ((1, 131072), F32)],
@@ -181,3 +193,28 @@ def test_sharded_paged_decode_compiles_for_four_chips(topo, uncached):
     per_device = compiled.memory_analysis().argument_size_in_bytes
     arena_bytes = 2 * L * (8 * 272 + 1) * K * 16 * HD * 2
     assert per_device < arena_bytes / 4 * 1.05, (per_device, arena_bytes)
+
+
+def test_sharded_grouped_chunk_compiles_for_four_chips(topo, uncached):
+    """A speculative verify step at tp=4 (a round the admission race left one
+    caller alone in): the grouped chunk kernel ``shard_map``'d over the tp
+    axis as ``LlamaModel._attend`` does it — each device its 8 query / 2 KV
+    heads of the dense bf16 cache, 64 query rows a KV head."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh = Mesh(list(topo.devices), ("tp",))
+    heads, cache = P(None, None, "tp", None), P(None, None, "tp", None, None)
+    in_specs = (heads, cache, cache, P(None), P(None), P(None), P(None))
+    fn = jax.shard_map(
+        A.chunk_attention_grouped, mesh=mesh, in_specs=in_specs,
+        out_specs=heads, check_vma=False,
+    )
+    args = [((1, 16, H, HD), BF16), *_dense_cache(False, 1),
+            ((1,), I32), ((1,), I32), ((1,), I32), ((1,), I32)]
+    avals = [
+        jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, spec))
+        for (s, d), spec in zip(args, in_specs)
+    ]
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" not in text and "all-gather" not in text
