@@ -40,8 +40,10 @@ branch): the median executions of those instructions in a program.
   does not move the count. (The sampler cannot be the mark: on the chip the
   compiler fuses a greedy sampler into the head's fusion, and no instruction
   is filed under ``sample``.)
-- Under ``prefill`` it is an operation of the layers' loop, and runs
-  ``num_hidden_layers`` times a pass, so a pass the slice cut counts by the
+- Under ``prefill`` it is an operation of the layers' loop, and runs once a
+  trip of that loop: ``layer_loop_trips`` times a pass, which the cell's
+  family states (``families/<model_type>.py``; the published depth where
+  every layer is in the one loop), so a pass the slice cut counts by the
   layers of it that ran. The **rows** of a pass are in the scope path too:
   ``engine/engine.py`` opens a generate program's prefill as
   ``prefill/rows<N>``, the batch the executable was built for. (A prefill
@@ -340,7 +342,7 @@ def attribute_gaps(busy: list, spans: list, t0: float, t1: float) -> dict:
     return {k: v / 1e9 for k, v in out.items()}
 
 
-def reduce_phases(data: dict, num_layers: int, top: int = 10) -> dict:
+def reduce_phases(data: dict, layer_loop_trips: int, top: int = 10) -> dict:
     """Self time of every leaf operation filed under its scope, and what the
     programs themselves say to divide it by (the module docstring)."""
     runs = sorted(data["modules"], key=lambda m: m[1])
@@ -385,7 +387,7 @@ def reduce_phases(data: dict, num_layers: int, top: int = 10) -> dict:
         if around:
             steps[phase] = steps.get(phase, 0.0) + n
         elif phase == "prefill" and rows:
-            prefill_rows += rows * n / num_layers
+            prefill_rows += rows * n / layer_loop_trips
     spans = data["host"]
     busy = trace.union([op[1], op[1] + op[2]] for op in data["ops"])
     everything = data["ops"] + spans
@@ -439,7 +441,7 @@ def of(ctx):
 
         t0 = time.monotonic()
         path = trace.find_xplane(os.path.join(serve.STATE_DIR, "trace"))
-        reduced = reduce_phases(load(path), int(ctx["config"]["num_hidden_layers"]))
+        reduced = reduce_phases(load(path), int(ctx["layer_loop_trips"]))
         reduced["seconds_to_reduce"] = round(time.monotonic() - t0, 1)
         print(json.dumps({"event": "phases", **reduced}), flush=True)
         ctx["phases"] = reduced

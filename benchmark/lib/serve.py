@@ -4,16 +4,21 @@ The scaffold ``chip_smoke.py`` proved on the chip (PR 21), copied so that the
 yardstick does not move when the smoke does: seeded weights made on the
 device, ``server.main.assemble_service``, ``warmup()``, a real werkzeug
 server over a socket, one PDF through ``/upload_pdf``. From the
-program it takes the service, its parameter layout and its counters.
+program it takes the service and its counters. What belongs to one decoder
+family (its published keys, the program's model configuration, the parameter
+layout its weights are drawn in) is ``families/<model_type>.py``, found by
+the configuration's ``model_type``; what every family shares is here.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import logging
 import math
 import os
+import re
 import threading
 import time
 import urllib.error
@@ -27,33 +32,23 @@ STATE_DIR = os.path.join(REPO, ".bench_state")
 AUDIT_TOL = 0.15  # SloConfig.quality_logit_err, the auditor's pinned bound
 ATTN_IMPL = "pallas"  # explicit, never "auto": no backend sniffing here
 
-# published config.json key -> LlamaConfig field
-HF_TO_LLAMA = {
-    "vocab_size": "vocab_size",
-    "hidden_size": "hidden_size",
-    "intermediate_size": "intermediate_size",
-    "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "rms_norm_eps": "rms_norm_eps",
-    "rope_theta": "rope_theta",
-    "max_position_embeddings": "max_seq_len",
-    "tie_word_embeddings": "tie_word_embeddings",
-    "bos_token_id": "bos_token_id",
-}
-# published keys that select nothing in this decoder but must hold these
-# values for it to be the published block
-HF_FIXED = {"hidden_act": "silu", "sliding_window": None, "rope_scaling": None,
-            "attention_bias": False, "mlp_bias": False, "model_type": "mistral"}
+# what every configuration file has, whatever its family; the published keys a
+# family reads and the values it insists on are its own (families/<model_type>.py)
+COMMON_KEYS = {"model_type", "source", "eos_token_id", "serving", "assumed", "reduced", "deployment"}
+# ``serving``: these, any further key the family names (``SERVING_KEYS``), and
+# sections: a dict under the name of a dataclass field of the program's
+# ``AppConfig`` (``engine``, ``sampling``, ``shadow``, ...) overrides it by field name
 SERVING_KEYS = {"tp", "weight_quant", "kv_quant", "encoder", "recite_gain", "weights_seed",
-                "tokenizer_vocab", "engine", "sampling", "shadow"}
-CONFIG_KEYS = set(HF_TO_LLAMA) | set(HF_FIXED) | {
-    "source", "eos_token_id", "serving", "assumed", "reduced", "deployment"}
+                "tokenizer_vocab"}
+# what ``families/<model_type>.py`` and ``references/<model_type>.py`` must define
+FAMILY_CONTRACT = ("PUBLISHED_KEYS", "FIXED", "REHEARSAL_MODEL", "model_config", "make_params",
+                   "layer_loop_trips")
+REFERENCE_CONTRACT = ("score", "HALF_GAP_TOL", "LOGIT_TOL")
 
-_INT8_UNIFORM_STD = 72.75  # 126 / sqrt(3): std of uniform int8 per unit scale
-_LAYER_GAIN = 0.25  # projection std as a multiple of 1/sqrt(fan_in)
-_RECITE_PERIOD = 8  # cycle length of the reciting output head
+# the statistics of every family's seeded weights (utils/synth.py, PR 21)
+INT8_UNIFORM_STD = 72.75  # 126 / sqrt(3): std of uniform int8 per unit scale
+LAYER_GAIN = 0.25  # projection std as a multiple of 1/sqrt(fan_in)
+RECITE_PERIOD = 8  # cycle length of the reciting output head
 
 
 class CompileCounter:
@@ -95,37 +90,70 @@ class ErrorLog(logging.Handler):
         self.records.append(self.format(record))
 
 
-def load_config(path: str) -> dict:
-    """One configuration file; unknown keys are an error."""
+def _load_by_model_type(kind: str, model_type: str, contract, root: str):
+    """``<root>/<kind>/<model_type>.py`` as a module, held to ``contract``.
+    The directory is the list: no registry, and a file that is missing or
+    short of the contract is an error that names it. Imports no JAX (the
+    files import it inside their functions)."""
+    if not re.fullmatch(r"[A-Za-z0-9_-]+", str(model_type)):
+        raise ValueError(f"model_type {model_type!r} is not a plain name")
+    path = os.path.join(root, kind, model_type + ".py")
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"model_type {model_type!r}: no {path} "
+                                "(benchmark/README.md, 'A decoder family')")
+    spec = importlib.util.spec_from_file_location(f"benchmark_{kind}_{model_type}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [name for name in contract if not hasattr(mod, name)]
+    if missing:
+        raise AttributeError(f"{path}: lacks {missing} of the contract {list(contract)}")
+    return mod
+
+
+def load_family(model_type: str, root: str = BENCH_DIR):
+    """``families/<model_type>.py``: where a file becomes a model and its weights."""
+    return _load_by_model_type("families", model_type, FAMILY_CONTRACT, root)
+
+
+def load_reference(model_type: str, root: str = BENCH_DIR):
+    """``references/<model_type>.py``: the family's plain reference and its tolerances."""
+    return _load_by_model_type("references", model_type, REFERENCE_CONTRACT, root)
+
+
+def load_config(path: str, root: str = BENCH_DIR):
+    """One configuration file and the family its ``model_type`` names, as
+    ``(cfg, family)``; unknown keys are an error."""
     with open(path, encoding="utf-8") as f:
         cfg = json.load(f)
-    unknown = set(cfg) - CONFIG_KEYS
+    if "model_type" not in cfg:
+        raise ValueError(f"{path}: no model_type, so no family to read it by")
+    family = load_family(cfg["model_type"], root)
+    unknown = set(cfg) - COMMON_KEYS - set(family.PUBLISHED_KEYS) - set(family.FIXED)
     if unknown:
         raise ValueError(f"{path}: unknown keys {sorted(unknown)}")
-    for key, want in HF_FIXED.items():
+    for key, want in family.FIXED.items():
         if key in cfg and cfg[key] != want:
             raise ValueError(f"{path}: {key}={cfg[key]!r}; this decoder runs {want!r} only")
-    unknown = set(cfg.get("serving", {})) - SERVING_KEYS
+    known = SERVING_KEYS | set(getattr(family, "SERVING_KEYS", ()))
+    unknown = {k for k, v in cfg.get("serving", {}).items() if k not in known and not isinstance(v, dict)}
     if unknown:
         raise ValueError(f"{path}: unknown serving keys {sorted(unknown)}")
-    return cfg
-
-
-def llama_config(cfg: dict):
-    from rag_llm_k8s_tpu.core.config import LlamaConfig
-
-    fields = {dst: cfg[src] for src, dst in HF_TO_LLAMA.items() if src in cfg}
-    return LlamaConfig(rope_scaling=None, eos_token_ids=(int(cfg["eos_token_id"]),), **fields)
+    return cfg, family
 
 
 def _replace(obj, overrides: dict, where: str):
     """Dataclass overrides by field name; a name the program does not have is
-    an error, so a renamed field cannot be dropped silently."""
+    an error, so a renamed field cannot be dropped silently. A dict for a
+    field that is itself a dataclass is overrides of that dataclass."""
     names = {f.name for f in dataclasses.fields(obj)}
     unknown = set(overrides) - names
     if unknown:
         raise ValueError(f"{where}: no such field {sorted(unknown)}")
-    fixed = {k: tuple(v) if isinstance(v, list) else v for k, v in overrides.items()}
+    fixed = {}
+    for k, v in overrides.items():
+        if isinstance(v, dict) and dataclasses.is_dataclass(getattr(obj, k)):
+            v = _replace(getattr(obj, k), v, f"{where}.{k}")
+        fixed[k] = tuple(v) if isinstance(v, list) else v
     return dataclasses.replace(obj, **fixed)
 
 
@@ -133,26 +161,22 @@ def app_config(cfg: dict, model, work: str, max_new_tokens: int, attn_impl: str 
     """The served configuration: the file's overrides on the program's
     defaults, everything the service writes kept under ``work``."""
     from rag_llm_k8s_tpu.core.config import (
-        AppConfig, EngineConfig, FlightConfig, MeshConfig, SamplingConfig,
-        ServerConfig, ShadowConfig,
+        AppConfig, EngineConfig, FlightConfig, MeshConfig, SamplingConfig, ServerConfig,
     )
 
     s = cfg["serving"]
-    engine = _replace(
-        EngineConfig(weight_quant=s["weight_quant"], kv_quant=s["kv_quant"],
-                     attn_impl=attn_impl),
-        s.get("engine", {}), "serving.engine")
-    sampling = _replace(SamplingConfig(max_new_tokens=max_new_tokens),
-                        s.get("sampling", {}), "serving.sampling")
-    return AppConfig(
-        mesh=MeshConfig(dp=1, sp=1, tp=int(s["tp"])), model=model, engine=engine,
-        sampling=sampling,
+    config = AppConfig(
+        mesh=MeshConfig(dp=1, sp=1, tp=int(s["tp"])), model=model,
+        engine=EngineConfig(weight_quant=s["weight_quant"], kv_quant=s["kv_quant"],
+                            attn_impl=attn_impl),
+        sampling=SamplingConfig(max_new_tokens=max_new_tokens),
         server=ServerConfig(
             host="127.0.0.1", model_path=work, embedder_path=work,
             index_path=os.path.join(work, "index"), pdf_dir=os.path.join(work, "pdfs")),
         flight=FlightConfig(spool_dir=os.path.join(work, "incidents")),
-        shadow=_replace(ShadowConfig(), s.get("shadow", {}), "serving.shadow"),
     )
+    sections = {k: v for k, v in s.items() if isinstance(v, dict)}
+    return _replace(config, sections, "serving")
 
 
 # ---------------------------------------------------------------------------
@@ -261,78 +285,30 @@ def ensure_tokenizers(llm_vocab: int, enc_pieces: int = 250000, corpus_mb: float
 # ---------------------------------------------------------------------------
 
 
-def make_llama_params(config, dtypes, seed: int, quant: str, mesh, recite_gain: float):
-    """Seeded random params in the program's ``LlamaModel`` layout, every
-    leaf born on its device(s) in its serving dtype and sharding, in ONE
-    jitted call. The statistics are those of ``utils/synth.py
-    synth_llama_params`` (PR 21): RMSNorm weights 1; projection kernels of
-    std ``0.25/sqrt(fan_in)``; a unit-std embedding; an untied head giving
-    unit-std logits with zeroed EOS columns (every stream runs its whole
-    budget) and ``recite_gain/D`` of each token's cycle predecessor's
+def draw_head(key, embedding, eos_ids, recite_gain: float, dtype):
+    """The untied output head of every family, traced inside the family's one
+    jitted call: unit-std logits, zeroed EOS columns (every stream runs its
+    whole budget) and ``recite_gain/D`` of each token's cycle predecessor's
     embedding (answers that partly repeat their history, which is what
-    prompt-lookup speculation drafts from)."""
+    prompt-lookup speculation drafts from, so every speculation number rests
+    on it). ``embedding`` is ``[V, D]``; ``dtype`` is the type of the head's
+    first leaf in the program's layout: int8 gives ``(lm_head_q, lm_head_scale)``,
+    any other ``(lm_head [D, V],)`` in it."""
     import jax
     import jax.numpy as jnp
-    from flax import traverse_util
-    from jax.sharding import NamedSharding
 
-    from rag_llm_k8s_tpu.models.llama import (
-        init_llama_params, quantize_llama_params, synth_leaf_kind,
-    )
-    from rag_llm_k8s_tpu.parallel.sharding import llama_param_specs
-
-    shapes = jax.eval_shape(lambda: init_llama_params(jax.random.PRNGKey(0), config, dtypes))
-    if quant == "int8":
-        shapes = jax.eval_shape(quantize_llama_params, shapes)
-    elif quant != "bf16":
-        raise ValueError(f"weight_quant={quant!r}: expected 'bf16' or 'int8'")
-    flat = traverse_util.flatten_dict(shapes)
-    specs = traverse_util.flatten_dict(llama_param_specs(shapes, mesh))
-    D, V = config.hidden_size, config.vocab_size
-    head_paths = [p for p in (("lm_head",), ("lm_head_q",), ("lm_head_scale",)) if p in flat]
-    body = sorted(p for p in flat if p not in head_paths)
-
-    def draw(path, s, key):
-        kind = synth_leaf_kind(path, s.dtype)
-        if kind == "norm":
-            return jnp.ones(s.shape, s.dtype)
-        fan_in = config.intermediate_size if "w_down" in path else D
-        if kind == "quant_scale":
-            return jnp.full(s.shape, _LAYER_GAIN / (_INT8_UNIFORM_STD * math.sqrt(fan_in)), s.dtype)
-
-        def block(k, shape):
-            if kind == "kernel_q":
-                return jax.random.randint(k, shape, -126, 127, jnp.int8)
-            std = 1.0 if kind == "embedding" else _LAYER_GAIN / math.sqrt(fan_in)
-            return (jax.random.normal(k, shape, jnp.float32) * std).astype(s.dtype)
-
-        if s.ndim == 3:  # stacked [L, in, out]: one layer per loop step
-            return jax.lax.map(lambda k: block(k, s.shape[1:]), jax.random.split(key, s.shape[0]))
-        return block(key, s.shape)
-
-    def draw_head(key, embedding):
-        w = jax.random.normal(key, (D, V), jnp.float32) / math.sqrt(D)
-        if recite_gain:
-            v = jnp.arange(V)
-            base = v - v % _RECITE_PERIOD
-            pred = jnp.minimum(base + (v - base - 1) % _RECITE_PERIOD, V - 1)
-            w = w + (recite_gain / D) * embedding[pred].T.astype(jnp.float32)
-        w = w.at[:, jnp.asarray(config.eos_token_ids)].set(0.0)
-        if quant == "bf16":
-            return (w.astype(flat[("lm_head",)].dtype),)
-        scale = jnp.maximum(jnp.max(jnp.abs(w), axis=0) / 127.0, 1e-8)
-        return jnp.round(w / scale[None, :]).astype(jnp.int8), scale
-
-    def make(root):
-        out = {p: draw(p, flat[p], jax.random.fold_in(root, i)) for i, p in enumerate(body)}
-        if head_paths:
-            leaves = draw_head(jax.random.fold_in(root, len(flat)), out[("embedding",)])
-            out.update(zip(head_paths, leaves))
-        return out
-
-    shardings = {p: NamedSharding(mesh.mesh, specs[p]) for p in flat}
-    root = prng_key(seed, 0)
-    return traverse_util.unflatten_dict(jax.jit(make, out_shardings=shardings)(root))
+    V, D = embedding.shape
+    w = jax.random.normal(key, (D, V), jnp.float32) / math.sqrt(D)
+    if recite_gain:
+        v = jnp.arange(V)
+        base = v - v % RECITE_PERIOD
+        pred = jnp.minimum(base + (v - base - 1) % RECITE_PERIOD, V - 1)
+        w = w + (recite_gain / D) * embedding[pred].T.astype(jnp.float32)
+    w = w.at[:, jnp.asarray(eos_ids)].set(0.0)
+    if dtype != jnp.int8:
+        return (w.astype(dtype),)
+    scale = jnp.maximum(jnp.max(jnp.abs(w), axis=0) / 127.0, 1e-8)
+    return jnp.round(w / scale[None, :]).astype(jnp.int8), scale
 
 
 def prng_key(seed: int, salt: int):

@@ -13,6 +13,10 @@ pass over ``prompt + delivered`` gives, for each delivered token, the
 reference's greedy choice, its logit and the delivered token's logit: the
 shape of the program's ``score_exact`` result, so the two can be set side by
 side.
+
+This is the reference of ``model_type: "mistral"``: ``lib/serve.py
+load_reference`` finds a family's by that name and holds it to ``score``,
+``HALF_GAP_TOL`` and ``LOGIT_TOL``.
 """
 
 from __future__ import annotations

@@ -10,8 +10,11 @@ few unmeasured requests) ends when the window opens; the window lasts
 ``--seconds`` (a closed loop whose callers are through their plans sooner
 ends there); what was due in it is drained, then a seeded sample of what was
 served is scored against the program's exact path and against the plain
-float32 reference. Every stdout line but the last is information; the last
-is the result object.
+float32 reference. The configuration's published ``model_type`` names the
+decoder family: ``families/<model_type>.py`` makes the file a model and its
+seeded weights, ``references/<model_type>.py`` is the reference its answers
+are judged by; this file knows neither. Every stdout line but the last is
+information; the last is the result object.
 """
 
 from __future__ import annotations
@@ -45,11 +48,9 @@ N_AUDITS = 4
 OPEN_WORKERS = 48  # sender threads of an open loop: more than it ever has in flight
 
 # --allow-cpu-rehearsal: the same control flow at a size the CPU finishes in
-# a minute. Its result line says ``correct: false`` and its numbers mean nothing.
+# a minute (the toy model is the family's ``REHEARSAL_MODEL``). Its result line
+# says ``correct: false`` and its numbers mean nothing.
 REHEARSAL = {
-    "model": dict(vocab_size=512, hidden_size=64, intermediate_size=128,
-                  num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
-                  head_dim=16, max_position_embeddings=1024),
     "engine": dict(prompt_buckets=[512], max_seq_len=544, max_batch_size=4,
                    max_chunked_prompt=2048),
     "traffic": dict(corpus_pages=3, words_per_page=60, max_new_tokens=8, question_pool=8),
@@ -192,13 +193,14 @@ def main() -> int:
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + f" --xla_force_host_platform_device_count={chips}")
 
-    from benchmark.lib import reference, serve, stats, trace, traffic
+    from benchmark.lib import serve, stats, trace, traffic
 
-    cfg = serve.load_config(os.path.join(REPO, cfg_entry["file"]))
+    cfg, family = serve.load_config(os.path.join(REPO, cfg_entry["file"]))
+    reference = serve.load_reference(cfg["model_type"])
     mix = traffic.load_traffic(os.path.join(BENCH_DIR, "traffic", cell["traffic"] + ".json"))
     serving = cfg["serving"]
     if rehearsal:
-        cfg.update(REHEARSAL["model"])
+        cfg.update(family.REHEARSAL_MODEL)
         serving = dict(serving, engine=dict(serving.get("engine", {}), **REHEARSAL["engine"]),
                        tokenizer_vocab=REHEARSAL["tokenizer_vocab"])
         cfg["serving"] = serving
@@ -252,17 +254,18 @@ def main() -> int:
     t0 = time.monotonic()
     mesh = make_mesh(MeshConfig(dp=1, sp=1, tp=int(serving["tp"])), devices=devices)
     dtypes = DTypePolicy()
-    model = serve.llama_config(cfg)
+    model = family.model_config(cfg)
     # the weights are the configuration's, not the run's: with speculation an
     # answer's cost follows them, and a deployment serves one model
     weights_seed = int(serving["weights_seed"])
-    params = serve.make_llama_params(
+    params = family.make_params(
         model, dtypes, weights_seed, serving["weight_quant"], mesh, float(serving["recite_gain"]))
     enc_cfg = EncoderConfig.tiny(vocab_size=REHEARSAL["encoder_pieces"]) if rehearsal \
         else getattr(EncoderConfig, serving["encoder"])()
     enc_params = serve.make_encoder_params(enc_cfg, dtypes, weights_seed)
     jax.block_until_ready((params, enc_params))
-    say("params", layers=model.num_layers, hidden=model.hidden_size, vocab=model.vocab_size,
+    say("params", family=os.path.relpath(family.__file__, BENCH_DIR),
+        model={k: cfg.get(k) for k in family.PUBLISHED_KEYS},
         weights=serving["weight_quant"], kv=serving["kv_quant"], tp=serving["tp"],
         seconds=round(time.monotonic() - t0, 1),
         hbm_in_use_gib=[round((d.memory_stats() or {}).get("bytes_in_use", 0) / GIB, 2)
@@ -429,7 +432,7 @@ def main() -> int:
             errors=[r["error"] for r in records if r["error"]][:3])
 
         # ---- correct: a seeded sample of what was served, scored by the program's
-        # exact path and by the plain float32 reference (lib/reference.py) ------
+        # exact path and by the family's plain float32 reference (references/) ---
         device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
                   "count": len(devices),  # the peak of serving: the reference below is not the system
                   "memory_peak_bytes": max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
@@ -444,7 +447,8 @@ def main() -> int:
         ref_gaps = [stats.half_gap_max(r) for r in refs]
         ref_errs = [stats.logit_err_max(x, r) for x, r in zip(exact, refs)]
         new_errors = errors.records[n_err0:]
-        say("audit", audits=len(audits), err=[round(a, 5) for a in audits], tolerance=serve.AUDIT_TOL,
+        say("audit", reference=os.path.relpath(reference.__file__, BENCH_DIR), audits=len(audits),
+            err=[round(a, 5) for a in audits], tolerance=serve.AUDIT_TOL,
             reference_half_gap=[round(g, 5) for g in ref_gaps], half_gap_tolerance=reference.HALF_GAP_TOL,
             reference_logit_err=[round(e, 5) for e in ref_errs], logit_tolerance=reference.LOGIT_TOL,
             seconds=round(time.monotonic() - t0, 1), errors_logged=new_errors[:3])
@@ -461,6 +465,7 @@ def main() -> int:
             "new_tokens": new_tokens, "setup_s": setup_s, "before": before, "after": after,
             "config": cfg, "traffic": mix, "chips": chips, "peaks": peaks, "trace": None,
             "stats": stats, "prompt_tokens": None,
+            "layer_loop_trips": family.layer_loop_trips(cfg),
         }
         result = {"correct": correct, "attempted": len(records), "failed": failed}
         if args.trace:

@@ -210,10 +210,12 @@ def test_recorded_trace_reduces():
 @pytest.mark.parametrize("cell", BENCHMARK["workloads"], ids=lambda c: c["name"])
 def test_every_cell_resolves_to_files_that_parse(cell):
     entry = {c["name"]: c for c in BENCHMARK["configs"]}[cell["config"]]
-    cfg = serve.load_config(os.path.join(REPO, entry["file"]))
+    cfg, family = serve.load_config(os.path.join(REPO, entry["file"]))
     assert cfg["source"] == entry["source"] and cfg["reduced"] == entry["reduced"]
     assert cfg["serving"]["tp"] == cell["chips"]
-    model = serve.llama_config(cfg)
+    assert os.path.exists(os.path.join(BENCH, "references", cfg["model_type"] + ".py"))
+    model = family.model_config(cfg)
+    assert family.layer_loop_trips(cfg) == model.num_layers == cfg["num_hidden_layers"]
     assert model.num_heads % cell["chips"] == 0 and model.num_kv_heads % cell["chips"] == 0
     m = mix(cell["traffic"])
     assert m["loop"] in ("closed", "open")
@@ -244,12 +246,15 @@ def test_unknown_config_key_is_an_error(tmp_path):
 
 
 @pytest.mark.parametrize("trace_flag", ["0", "1"])
-def test_rehearsal_prints_the_contracts_last_line(trace_flag):
+def test_rehearsal_prints_the_contracts_last_line(trace_flag, tmp_path):
     cell = "mistral-7b-int8.closed8"
+    # a compile cache of its own: a program the CPU loads from a persistent
+    # cache carries no scopes, and the phase readers then find nothing to read
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
     p = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell, "--seed",
          str(2**31 + 5), "--seconds", "6", "--trace", trace_flag, "--allow-cpu-rehearsal"],
-        cwd=REPO, capture_output=True, text=True, timeout=600)
+        cwd=REPO, capture_output=True, text=True, timeout=600, env=env)
     assert p.returncode == 0, p.stderr[-2000:]
     last = json.loads(p.stdout.strip().splitlines()[-1])
     keys = {"correct", "attempted", "failed", "metrics", "device"}
@@ -328,8 +333,7 @@ def test_reference_is_the_block_written_out():
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark.lib import reference
-
+    reference = serve.load_reference("mistral")
     rs = np.random.RandomState(0)
     D, F, H, KV, hd, V, S = 16, 24, 4, 2, 4, 32, 7
     cfg = dict(num_attention_heads=H, num_key_value_heads=KV, head_dim=hd, rms_norm_eps=1e-5,

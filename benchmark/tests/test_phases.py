@@ -164,7 +164,7 @@ def test_load_walks_a_cpu_capture(cpu_capture):
     data = phases.load(path)
     assert data["ops"] and data["scopes"] and all(len(op) == 4 for op in data["ops"])
     assert {sp[0] for sp in data["host"]} == {"dispatch", "launch", "fetch"}
-    r = phases.reduce_phases(data, num_layers=4)
+    r = phases.reduce_phases(data, layer_loop_trips=4)
     assert r["seconds"].get("decode", 0) > 0 and r["seconds"].get("prefill", 0) > 0
     assert 0 < r["unscoped_share"] < 1  # the sine and its matmul
     # no kernel to count by on the CPU, and none needed: two runs of a program
@@ -273,6 +273,22 @@ def test_the_phase_metrics_read_the_recorded_numbers(reduced, monkeypatch):
         assert reader(name).read(off) is None
 
 
+def test_the_layers_loops_trips_are_handed_in_by_the_cells_family(recorded, reduced, monkeypatch):
+    """``of`` reads no depth from the configuration: the family states how
+    many trips of the layers' loop a prefill pass makes, and with Mistral's
+    (its depth) the recorded slice reads what it read."""
+    monkeypatch.setattr(phases.trace, "find_xplane", lambda where: "recorded")
+    monkeypatch.setattr(phases, "load", lambda path: recorded)
+    got = phases.of({"trace": {}, "layer_loop_trips": LAYERS})  # no "config" in it
+    got.pop("seconds_to_reduce")
+    assert got == reduced
+    assert got["prefill_rows"] == pytest.approx(8.0) and got["steps"] == reduced["steps"]
+    # a family with one layer of its depth outside the loop: a pass is fewer trips
+    fewer = phases.of({"trace": {}, "layer_loop_trips": LAYERS - 1})
+    assert fewer["prefill_rows"] == pytest.approx(8.0 * LAYERS / (LAYERS - 1))
+    assert fewer["seconds"] == reduced["seconds"] and fewer["steps"] == reduced["steps"]
+
+
 def test_a_program_without_scopes_reads_as_unscoped(recorded):
     """The parent's programs: every operation is there, none names a phase."""
     bare = dict(recorded, scopes={})
@@ -312,7 +328,7 @@ def test_full_batch_answer_share():
 # ---- end to end, tiny, on the CPU ---------------------------------------------
 
 
-def test_the_rehearsal_walks_the_phase_readers():
+def test_the_rehearsal_walks_the_phase_readers(tmp_path):
     """``--allow-cpu-rehearsal --trace 1`` reduces its own capture with
     ``lib/phases.py`` and reads every new metric of the cell: steps and rows
     come from the program's own statements, which the CPU makes too."""
@@ -322,7 +338,9 @@ def test_the_rehearsal_walks_the_phase_readers():
     p = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell, "--seed",
          str(2**31 + 5), "--seconds", "6", "--trace", "1", "--allow-cpu-rehearsal"],
-        cwd=REPO, capture_output=True, text=True, timeout=600)
+        cwd=REPO, capture_output=True, text=True, timeout=600,
+        # a cache of its own: what the CPU loads from a persistent cache carries no scopes
+        env=dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path)))
     assert p.returncode == 0, p.stderr[-2000:]
     lines = [json.loads(x) for x in p.stdout.strip().splitlines() if x.startswith("{")]
     line = next(x for x in lines if x.get("event") == "phases")
