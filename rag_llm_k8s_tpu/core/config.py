@@ -187,6 +187,123 @@ class LlamaConfig:
 
 
 @dataclass(frozen=True)
+class YarnScalingConfig:
+    """YaRN RoPE scaling as the latent-attention family publishes it
+    (``rope_scaling.type == "yarn"``): per-dimension blend of ``theta_i`` and
+    ``theta_i / factor`` by a linear ramp between the dimensions that turn
+    ``beta_fast`` and ``beta_slow`` times in the original context, and a
+    softmax-scale correction from ``mscale_all_dim``."""
+
+    factor: float = 40.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+    original_max_position_embeddings: int = 4096
+
+
+@dataclass(frozen=True)
+class LatentMoEConfig:
+    """The latent-attention, sparse-expert decoder family (``models/latent_moe.py``):
+    multi-head latent attention (a per-token latent ``c_kv`` and one shared
+    rotated key slice are all the cache holds), ``first_k_dense`` leading
+    dense layers, then layers whose FFN is a sigmoid-routed, group-limited
+    mixture of ``n_routed_experts`` SwiGLU experts beside ``n_shared_experts``
+    shared ones.
+
+    ``ep_size``/``ep_rank`` state this chip's SHARE of an expert-parallel
+    deployment: the router keeps its published width, weights are normalised
+    over all selected experts, and only the experts ``[ep_rank * held,
+    (ep_rank + 1) * held)`` are held and computed here; what absent experts
+    would add is left out (no exchange on one chip).
+
+    Defaults are the published widths of the 672B-A37B decoder the benchmark
+    cell serves a share of."""
+
+    vocab_size: int = 129280
+    hidden_size: int = 7168
+    intermediate_size: int = 18432  # the dense layers' SwiGLU width
+    moe_intermediate_size: int = 2048  # every expert's SwiGLU width
+    num_layers: int = 61
+    first_k_dense: int = 3
+    num_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 256
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    ep_size: int = 1
+    ep_rank: int = 0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScalingConfig] = field(default_factory=YarnScalingConfig)
+    max_seq_len: int = 163840
+    tie_word_embeddings: bool = False
+    bos_token_id: int = 0
+    eos_token_ids: Tuple[int, ...] = (1,)
+
+    def __post_init__(self):
+        if self.n_routed_experts % self.ep_size or not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(
+                f"ep_size={self.ep_size}, ep_rank={self.ep_rank}: the "
+                f"{self.n_routed_experts} routed experts must divide evenly "
+                "over the ranks and the rank must be one of them"
+            )
+        if self.n_routed_experts % self.n_group or not 0 < self.topk_group <= self.n_group:
+            raise ValueError("n_group must divide n_routed_experts; topk_group <= n_group")
+        if not 0 <= self.first_k_dense <= self.num_layers:
+            raise ValueError("first_k_dense must lie in [0, num_layers]")
+        if self.tie_word_embeddings:
+            raise ValueError("the latent-MoE family serves an untied head only")
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_routed_experts // self.ep_size
+
+    @property
+    def first_held(self) -> int:
+        return self.ep_rank * self.experts_held
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def num_kv_heads(self) -> int:
+        """As published (``num_key_value_heads`` = ``num_attention_heads``):
+        every query head has keys and values of its own, rebuilt from the one
+        latent; what divides over chips like a KV head count does."""
+        return self.num_heads
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 256, **overrides) -> "LatentMoEConfig":
+        """Miniature config for CPU tests: one leading dense layer, two MoE
+        layers, 16 experts in 4 groups of which rank 1 of 2 holds 8."""
+        base = dict(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=128,
+            moe_intermediate_size=32, num_layers=3, first_k_dense=1, num_heads=4,
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=16,
+            num_experts_per_tok=4, n_group=4, topk_group=2, ep_size=2, ep_rank=1,
+            rope_scaling=YarnScalingConfig(factor=4.0, original_max_position_embeddings=64),
+            max_seq_len=256, bos_token_id=1, eos_token_ids=(2,),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
 class EncoderConfig:
     """Bidirectional encoder config for the embedding model.
 
@@ -1243,6 +1360,8 @@ SYSTEM_MESSAGE = (
 class AppConfig:
     mesh: MeshConfig = field(default_factory=MeshConfig)
     dtypes: DTypePolicy = field(default_factory=DTypePolicy)
+    # a LlamaConfig or a LatentMoEConfig: the engine builds the model and its
+    # cache from the configuration's type (models/families.py)
     model: LlamaConfig = field(default_factory=LlamaConfig.llama_3_1_8b)
     encoder: EncoderConfig = field(default_factory=EncoderConfig.bge_m3)
     retrieval: RetrievalConfig = field(default_factory=RetrievalConfig)

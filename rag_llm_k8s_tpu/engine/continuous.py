@@ -161,6 +161,10 @@ class ContinuousEngine:
         mesh: Optional[MeshContext] = None,
         pad_id: int = 0,
     ):
+        from rag_llm_k8s_tpu.models import families
+
+        # the slot engine and its paged pool hold per-head K/V planes only
+        families.refuse_unsupported(config, engine_config, mesh, engine="continuous")
         self.config = config
         self.sampling = sampling
         self.engine_config = engine_config

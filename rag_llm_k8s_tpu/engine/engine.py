@@ -29,7 +29,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -44,9 +44,9 @@ from rag_llm_k8s_tpu.core.config import (
 )
 from rag_llm_k8s_tpu.core.mesh import MeshContext, serving_device_kind
 from rag_llm_k8s_tpu.engine.sampling import NEG_INF, _prepared_logits, sample_token
+from rag_llm_k8s_tpu.models import families
 from rag_llm_k8s_tpu.models.llama import (
     KVCache,
-    LlamaModel,
     make_kv_cache,
     mask_window,
 )
@@ -173,6 +173,10 @@ class EngineStats:
     # KV was spliced from a cached block (prefill_tokens counts only tokens
     # actually computed — the two sum to the logical prompt-token total)
     prefill_tokens_skipped: int = 0
+    # what the family's cache counted on the device (models/families.py
+    # ``Family.counter_names``; none for per-head K/V), folded in with every
+    # fetched answer and exported at /metrics as ``engine_<name>``
+    family_counters: Dict[str, int] = field(default_factory=dict)
 
 
 class InferenceEngine:
@@ -208,17 +212,19 @@ class InferenceEngine:
         # the verify overhead, re-probing every _SPEC_REPROBE-th call
         self._spec_ema: Optional[float] = None
         self._spec_skips = 0
+        # the model and its cache come from the configuration's TYPE
+        # (models/families.py); what a family cannot be served with refuses
+        # here, by mechanism
+        families.refuse_unsupported(config, engine_config, mesh)
+        self.family = families.of(config)
         self.params, fused = maybe_fuse_params(params, engine_config, mesh)
         self.params, quantized = maybe_quantize_params(self.params, engine_config)
-        self.model = LlamaModel(
-            config,
-            dtypes,
-            attn_impl=engine_config.attn_impl,
-            mesh=(mesh.mesh if mesh is not None and mesh.tp > 1 else None),
-            fused_qkv=fused,
-            quantized=quantized,
-            kv_quant=engine_config.kv_quant,
+        self.model = self.family.build_model(
+            config, dtypes, engine_config, mesh, fused=fused, quantized=quantized
         )
+        # int32 counters the family's cache carries (0 for per-head K/V): the
+        # generate programs append them to the one array the host fetches
+        self._n_counters = self.family.counters_width
         # same params, STATIC chunked=True: prompts longer than the largest
         # bucket prefill through the cache chunk by chunk (offset-causal
         # chunk_prefill_attention) instead of being silently truncated
@@ -228,7 +234,7 @@ class InferenceEngine:
         self._sidecar_placed: Dict[Tuple[int, int], tuple] = {}
         self._lock = threading.Lock()
         self._rng_counter = 0
-        self.stats = EngineStats()
+        self.stats = EngineStats(family_counters=dict.fromkeys(self.family.counter_names, 0))
         # goodput ledger (obs/goodput.py; ISSUE 14): the one-shot engine's
         # generate is ONE device program, so the roofline model splits each
         # call's measured duration into prefill/decode shares analytically
@@ -408,7 +414,7 @@ class InferenceEngine:
 
         def gen(params, tokens, pad_mask, rng):
             with phase_scope("prefill", rows=B):
-                cache = make_kv_cache(
+                cache = families.make_cache(
                     cfg, B, T, cache_dtype, quant=self.engine_config.kv_quant
                 )
                 kv_start, _ = mask_window(pad_mask)  # left-pad: [S - real_len, S)
@@ -454,10 +460,29 @@ class InferenceEngine:
             # loop's condition and carried copies are decode work too
             with phase_scope("decode"):
                 init = (jnp.int32(1), cache, tok0, done0, out0, rng)
-                _, _, _, _, out, _ = jax.lax.while_loop(cond, body, init)
-            return out
+                _, cache, _, _, out, _ = jax.lax.while_loop(cond, body, init)
+                return self._with_counters(out, cache)
 
         return gen
+
+    def _with_counters(self, out, cache):
+        """Append the cache's counters (a family that carries any) to row 0
+        of the output, as further columns: they come back in the ONE fetch."""
+        if not self._n_counters:
+            return out
+        tail = jnp.zeros((out.shape[0], self._n_counters), out.dtype).at[0].set(
+            cache.counters.astype(out.dtype))
+        return jnp.concatenate([out, tail], axis=1)
+
+    def _split_counters(self, out: np.ndarray) -> np.ndarray:
+        """Host side of ``_with_counters``: fold the counters into the
+        stats, return the output without them."""
+        if not self._n_counters:
+            return out
+        with self._lock:
+            for name, n in self.family.fold_counters(out[0, -self._n_counters:]).items():
+                self.stats.family_counters[name] += n
+        return out[:, :-self._n_counters]
 
     def _build_generate_spec(self, S: int, max_new: int):
         """AOT-compile the SPECULATIVE batch-1 generate executable
@@ -519,7 +544,7 @@ class InferenceEngine:
 
         def gen(params, tokens, pad_mask, rng):
             with phase_scope("prefill", rows=1):
-                cache = make_kv_cache(
+                cache = families.make_cache(
                     cfg, 1, T, cache_dtype, quant=self.engine_config.kv_quant
                 )
                 kv_start, _ = mask_window(pad_mask)
@@ -637,13 +662,14 @@ class InferenceEngine:
 
             with phase_scope("verify"):
                 init = (i32(1), cache, hist0, done0, out0, rng, i32(0))
-                _, _, _, _, out, _, iters = jax.lax.while_loop(cond, body, init)
+                _, cache, _, _, out, _, iters = jax.lax.while_loop(cond, body, init)
                 # iters = verify forwards run; the emitted-token count over it
                 # is the measured acceptance rate (EngineStats.spec_verify_steps).
                 # Packed into the out buffer's first slack slot (never an
                 # emission target): returning it as a second array would cost a
                 # SECOND device->host round trip per generate on a slow link.
-                return out[:, :max_new + 1].at[:, max_new].set(iters)
+                return self._with_counters(
+                    out[:, :max_new + 1].at[:, max_new].set(iters), cache)
 
         return gen
 
@@ -793,7 +819,7 @@ class InferenceEngine:
                 rng_j,
             )
         with tracing.span("fetch"):
-            out = np.asarray(out_dev)  # the ONE per-query fetch
+            out = self._split_counters(np.asarray(out_dev))  # the ONE per-query fetch
         call_s = time.perf_counter() - t_call
         iters = 0
         if spec:
@@ -1203,7 +1229,7 @@ class InferenceEngine:
 
         @phase_scope("score")  # audit work, never filed under serving
         def score(params, tokens, pad_mask, next_tokens):
-            cache = make_kv_cache(cfg, 1, T, dt.compute_dtype, quant=kvq)
+            cache = families.make_cache(cfg, 1, T, dt.compute_dtype, quant=kvq)
             kv_start, _ = mask_window(pad_mask)
             positions = jnp.clip(jnp.cumsum(pad_mask, axis=-1) - 1, 0)
             n_chunks = S // chunk
@@ -1629,7 +1655,7 @@ class InferenceEngine:
             t_call = time.perf_counter()
             out_dev = fn(self.params, tokens_j, mask_j, rng_j)
         with tracing.span("fetch"):
-            out = np.asarray(out_dev)  # ONE fetch
+            out = self._split_counters(np.asarray(out_dev))  # ONE fetch
         if spec:
             iters = int(out[0, max_new])  # packed in the slack slot
             out = out[:, :max_new]

@@ -1,0 +1,140 @@
+"""The seam between a model configuration's TYPE and what serves it.
+
+One table: a configuration type -> its ``Family`` (model, cache, parameter
+shardings, the counters its cache carries, what it cannot be served with
+yet). ``InferenceEngine`` asks ``of(config)`` and names no family itself; a
+new decoder is a row here and a module beside ``models/llama.py``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, Dict, Optional, Tuple
+
+import jax.numpy as jnp
+
+from rag_llm_k8s_tpu.core.config import LatentMoEConfig, LlamaConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """What serves one type of model configuration."""
+
+    name: str  # as a refusal names it
+    build_model: Callable  # (config, dtypes, engine_config, mesh, fused=, quantized=) -> nn.Module
+    make_cache: Callable  # (config, batch, max_seq_len, dtype, quant) -> a fresh cache
+    param_specs: Callable  # (params, mesh) -> PartitionSpec pytree
+    # int32 counters the cache carries (``cache.counters``): the generate
+    # programs append them to their one fetched array, and ``fold_counters``
+    # turns a fetched row into {name: increment} for ``/metrics``
+    counters_width: int = 0
+    counter_names: Tuple[str, ...] = ()
+    fold_counters: Optional[Callable] = None
+    # (engine_config, mesh, engine) -> (mechanism, why) of the first thing
+    # this family cannot be served with yet, or None
+    unsupported: Callable = lambda engine_config, mesh, engine: None
+    # why server.main.build_service cannot load it from safetensors (None: it can)
+    checkpoint_loader_refusal: Optional[str] = None
+
+
+def _llama_model(config, dtypes, engine_config, mesh, *, fused: bool, quantized: bool):
+    from rag_llm_k8s_tpu.models.llama import LlamaModel
+
+    return LlamaModel(
+        config,
+        dtypes,
+        attn_impl=engine_config.attn_impl,
+        mesh=(mesh.mesh if mesh is not None and mesh.tp > 1 else None),
+        fused_qkv=fused,
+        quantized=quantized,
+        kv_quant=engine_config.kv_quant,
+    )
+
+
+def _llama_cache(config, batch_size, max_seq_len, dtype, quant):
+    from rag_llm_k8s_tpu.models.llama import make_kv_cache
+
+    return make_kv_cache(config, batch_size, max_seq_len, dtype, quant=quant)
+
+
+def _llama_specs(params, mesh):
+    from rag_llm_k8s_tpu.parallel.sharding import llama_param_specs
+
+    return llama_param_specs(params, mesh)
+
+
+def _latent_moe() -> Family:
+    from rag_llm_k8s_tpu.models import latent_moe as lm
+    from rag_llm_k8s_tpu.parallel.sharding import latent_moe_param_specs
+
+    def unsupported(engine_config, mesh, engine):
+        if engine == "continuous":
+            return ("the continuous engine (batching='continuous') or its paged KV pool",
+                    "per-row frontiers and block tables are written for per-head K/V planes, "
+                    "not the latent cache; use batching='coalesce'")
+        if getattr(engine_config, "batching", "coalesce") == "continuous":
+            return "batching='continuous'", "the slot engine has no latent cache; use 'coalesce'"
+        pc = getattr(engine_config, "prefix_cache", None)
+        if pc is not None and pc.enabled:
+            return ("the KV prefix cache (prefix_cache.enabled)",
+                    "splicing a latent row needs only its rope slice re-rotated, which "
+                    "rerotate_prefix_planes does not do")
+        if engine_config.kv_quant != "bf16":
+            return f"kv_quant={engine_config.kv_quant!r}", "the latent cache has no int8 planes"
+        if engine_config.weight_quant != "bf16":
+            return (f"weight_quant={engine_config.weight_quant!r}",
+                    "quantize_llama_params does not know this tree (stacked experts, the router)")
+        if mesh is not None and (mesh.tp > 1 or getattr(mesh, "sp", 1) > 1):
+            return (f"tp={mesh.tp}, sp={getattr(mesh, 'sp', 1)}",
+                    "the latent projections and the expert stack have no partition rules; "
+                    "experts across chips need the all-to-all")
+        return None
+
+    return Family(
+        name="the latent-attention sparse-expert family (LatentMoEConfig)",
+        build_model=lambda config, dtypes, engine_config, mesh, *, fused, quantized: lm.LatentMoEModel(
+            config, dtypes, attn_impl=engine_config.attn_impl),
+        make_cache=lambda config, batch_size, max_seq_len, dtype, quant: lm.make_latent_cache(
+            config, batch_size, max_seq_len, dtype),
+        param_specs=latent_moe_param_specs,
+        counters_width=lm.N_COUNTERS,
+        counter_names=tuple(lm.COUNTER_STATS),
+        fold_counters=lm.fold_counters,
+        unsupported=unsupported,
+        checkpoint_loader_refusal=(
+            "the checkpoint loader has no name map for the latent-attention "
+            "sparse-expert family's tensors; serve it through assemble_service "
+            "with a parameter tree of your own"),
+    )
+
+
+# configuration type -> its family (a thunk where building it imports the model)
+_TABLE: Tuple[Tuple[type, Callable[[], Family]], ...] = (
+    (LatentMoEConfig, _latent_moe),
+    (LlamaConfig, lambda: Family("the Llama family (LlamaConfig)", _llama_model, _llama_cache, _llama_specs)),
+)
+_BUILT: Dict[type, Family] = {}
+
+
+def of(config) -> Family:
+    """The family that serves ``config``, by its type."""
+    for kind, build in _TABLE:
+        if isinstance(config, kind):
+            if kind not in _BUILT:
+                _BUILT[kind] = build()
+            return _BUILT[kind]
+    raise TypeError(f"no decoder family serves a {type(config).__name__}")
+
+
+def refuse_unsupported(config, engine_config, mesh, *, engine: str = "one-shot") -> None:
+    """Raise ``NotImplementedError`` naming the first mechanism ``config``'s
+    family cannot be served with (ROADMAP.md, "What the system cannot run yet")."""
+    family = of(config)
+    found = family.unsupported(engine_config, mesh, engine)
+    if found:
+        raise NotImplementedError(f"{family.name} cannot be served with {found[0]} yet: {found[1]}")
+
+
+def make_cache(config, batch_size: int, max_seq_len: int, dtype=jnp.bfloat16, quant: str = "bf16"):
+    """A fresh cache of the family's kind (per-head K/V planes, or latents)."""
+    return of(config).make_cache(config, batch_size, max_seq_len, dtype, quant)

@@ -231,6 +231,42 @@ def roofline_for_llama(
     )
 
 
+def roofline_for_latent_moe(cfg, *, peak_tflops: float, hbm_gbs: float) -> RooflineModel:
+    """The roofline of the latent-attention sparse-expert family, from a
+    ``LatentMoEConfig``'s fields (duck-typed, like ``roofline_for_llama``).
+
+    ``flops_per_token`` counts the parameters a token is multiplied by: the
+    latent attention's projections, the dense layers' FFN, and a MoE layer's
+    router, shared expert and ``num_experts_per_tok / ep_size`` routed
+    experts (what a balanced router sends to the experts HELD here).
+    ``weight_bytes`` is what a decode step streams at batch 1: attention,
+    router and shared expert whole, but only the held experts a token's
+    choices hit, never all held (a batch hits more; bf16, 2 bytes).
+    ``kv_bytes_per_token`` is one position's latent row over all layers."""
+    d, H = int(cfg.hidden_size), int(cfg.num_heads)
+    attn = (
+        d * cfg.q_lora_rank + cfg.q_lora_rank * H * cfg.qk_head_dim
+        + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+        + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+        + H * cfg.v_head_dim * d
+    )
+    expert = 3 * d * cfg.moe_intermediate_size
+    routed_here = cfg.num_experts_per_tok / cfg.ep_size
+    moe_layer = attn + d * cfg.n_routed_experts + (cfg.n_shared_experts + routed_here) * expert
+    dense_layer = attn + 3 * d * cfg.intermediate_size
+    active = (
+        cfg.first_k_dense * dense_layer + cfg.num_moe_layers * moe_layer
+        + cfg.vocab_size * d
+    )
+    return RooflineModel(
+        flops_per_token=2.0 * active,
+        weight_bytes=2.0 * active,
+        kv_bytes_per_token=2.0 * cfg.num_layers * (cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+        peak_tflops=peak_tflops,
+        hbm_gbs=hbm_gbs,
+    )
+
+
 def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger":
     """THE ledger constructor both serving engines share (duck-typed over
     the config dataclasses — still no package imports). One site means the
@@ -250,7 +286,11 @@ def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger"
             kind_tflops, kind_gbs = peaks_for_device(device_kind)
             peak_tflops = peak_tflops if peak_tflops > 0 else kind_tflops
             hbm_gbs = hbm_gbs if hbm_gbs > 0 else kind_gbs
-        roofline = roofline_for_llama(
+        # the family is told by what the configuration HAS (no package
+        # imports here): a latent cache's rank, or per-head K/V
+        roofline = roofline_for_latent_moe(
+            model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
+        ) if hasattr(model_config, "kv_lora_rank") else roofline_for_llama(
             model_config.num_layers, model_config.hidden_size,
             model_config.num_heads, model_config.num_kv_heads,
             model_config.head_dim, model_config.intermediate_size,

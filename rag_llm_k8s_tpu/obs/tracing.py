@@ -67,7 +67,14 @@ except Exception:  # noqa: BLE001 — tracing must work without jax
 # decode lanes share operations and cannot be told apart inside one.
 PHASES = ("retrieve", "prefill", "decode", "verify", "sample", "score", "mixed")
 SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
-SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES)
+# opened BENEATH a sub-scope by the latent-attention sparse-expert family
+# (models/latent_moe.py): ``attn/latent`` (the attention over the latent
+# cache itself, its absorbing matmuls included), ``mlp/router``,
+# ``mlp/experts`` (gather, grouped matmuls, scatter), ``mlp/shared``. A
+# reader that files an operation under the first sub-scope it knows keeps
+# reading ``attn`` and ``mlp``; one that knows these sees the finer split.
+FINE_SCOPES = ("latent", "router", "experts", "shared")
+SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES + FINE_SCOPES)
 
 
 def phase_scope(path: str, rows: Optional[int] = None):

@@ -110,6 +110,20 @@ def llama_param_specs(params, ctx: MeshContext):
     return traverse_util.unflatten_dict(specs)
 
 
+def latent_moe_param_specs(params, ctx: MeshContext):
+    """PartitionSpec pytree for the ``LatentMoEModel`` layout: every leaf
+    replicated. The family is served at tp = 1 (``models/families.py``
+    refuses more by name): its latent projections and its expert stack have
+    no partition rules yet, and experts across chips need the all-to-all."""
+    if ctx.tp > 1:
+        raise NotImplementedError(
+            f"tp={ctx.tp}: the latent-attention sparse-expert tree has no "
+            "tensor-parallel partition rules (tp must be 1)"
+        )
+    flat = traverse_util.flatten_dict(params)
+    return traverse_util.unflatten_dict({p: P(*(None,) * leaf.ndim) for p, leaf in flat.items()})
+
+
 def shard_params(params, specs, ctx: MeshContext):
     """Place a param pytree on the mesh per its spec tree.
 

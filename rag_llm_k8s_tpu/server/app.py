@@ -505,6 +505,12 @@ class RagService:
                     fn=lambda: self._engine_stat("spec_verify_steps"))
         reg.counter("engine_spec_emitted_tokens",
                     fn=lambda: self._engine_stat("spec_emitted_tokens"))
+        # what the serving engines' family counts on the device (models/
+        # families.py ``Family.counter_names``: a sparse-expert family's routed
+        # and computed assignments; nothing for a dense one)
+        for name in sorted({n for e in self._engines().values()
+                            for n in getattr(getattr(e, "stats", None), "family_counters", ())}):
+            reg.counter("engine_" + name, fn=lambda n=name: self._family_counter(n))
         # paged continuous draft-and-verify (TPU_RAG_SPEC_PAGED,
         # docs/SPECULATIVE.md): draft-token outcomes summed over the
         # serving engines — families exist in every mode (zeros while
@@ -1002,6 +1008,12 @@ class RagService:
         return float(sum(
             getattr(e.stats, name, 0) for e in self._engines().values()
             if getattr(e, "stats", None) is not None
+        ))
+
+    def _family_counter(self, name: str) -> float:
+        return float(sum(
+            getattr(getattr(e, "stats", None), "family_counters", {}).get(name, 0)
+            for e in self._engines().values()
         ))
 
     def _pcache_stat(self, name: str) -> float:

@@ -52,6 +52,11 @@ def build_service():
     model_cfg = config.model
     if os.path.exists(os.path.join(model_dir, "config.json")):
         model_cfg = config_from_hf_json(model_dir)
+    from rag_llm_k8s_tpu.models import families
+
+    refusal = families.of(model_cfg).checkpoint_loader_refusal
+    if refusal:  # a family the safetensors loader has no name map for
+        raise NotImplementedError(refusal)
     logger.info("loading Llama weights from %s", model_dir)
 
     # TPU_RAG_WEIGHT_QUANT=int8 streams the weight-only int8 layout straight

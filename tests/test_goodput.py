@@ -385,8 +385,17 @@ class TestSmoke:
             total_chip_s = sum(
                 i["goodput"]["chip_ms"] / 1e3 for i in infos
             )
-            busy = sched.busy_seconds()
-            assert abs(total_chip_s - busy) / busy < 0.05
+            # conservation, on ONE clock: the requests' shares add up to the
+            # time of the windows the ledger measured (chip_ms is rounded to
+            # 1e-4 ms a request). The scheduler's own stopwatch runs AROUND
+            # those windows on another reading of the clock, so under a
+            # loaded host (six test workers) it is only an upper bound: the
+            # 5% two-sided comparison with it failed the driver's run of
+            # PR 26's tree by a descheduled dispatcher, not by the ledger
+            ledger_busy = st["busy_s"]
+            assert abs(total_chip_s - ledger_busy) <= 1e-6 * ledger_busy + 1e-6 * len(infos), (
+                f"attributed {total_chip_s:.6f}s vs the ledger's windows {ledger_busy:.6f}s")
+            assert ledger_busy <= sched.busy_seconds() * (1 + 1e-6)
             # never double-counted: rework cannot exceed the whole of
             # admission-window time
             kinds = st["kinds"]

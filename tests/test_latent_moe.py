@@ -1,0 +1,406 @@
+"""The latent-attention sparse-expert family (models/latent_moe.py, ops/mla.py,
+ops/moe.py) against its plain reference (tests/latent_moe_reference.py), at a
+toy size on the CPU with seeded weights under the fp32 policy.
+
+Tolerances. The program and the reference compute the same float32 numbers in
+different orders (the absorbed form folds W_UK into the query and takes W_UV
+after the sum; the experts run as grouped matmuls over gathered rows; the
+cache round-trips nothing in fp32), so logits of magnitude ~1-3 agree to a
+few 1e-5; ``ATOL`` is 3e-4, ten times that, and a wrong scale, a missed
+rotation or a dropped expert moves them by 1e-2 or more. The share test (e)
+adds the same float32 terms in another order: 2e-5.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import latent_moe_reference as ref
+from rag_llm_k8s_tpu.core.config import (
+    DTypePolicy,
+    EngineConfig,
+    LatentMoEConfig,
+    MeshConfig,
+    PrefixCacheConfig,
+    SamplingConfig,
+)
+from rag_llm_k8s_tpu.core.mesh import make_mesh
+from rag_llm_k8s_tpu.engine.engine import InferenceEngine
+from rag_llm_k8s_tpu.models import latent_moe as lm
+from rag_llm_k8s_tpu.ops import mla, moe
+
+FP32 = DTypePolicy.fp32()
+ATOL = 3e-4
+CFG = LatentMoEConfig.tiny(vocab_size=300)
+GREEDY = SamplingConfig(do_sample=False, max_new_tokens=8)
+
+
+def seeded_params(cfg, seed=0):
+    """Weights with statistics that make every part matter: kernels of std
+    1/sqrt(fan_in), norm weights near 1, a router bias that moves choices."""
+    shapes = jax.eval_shape(lambda: lm.init_latent_moe_params(jax.random.PRNGKey(0), cfg, FP32))
+    flat = jax.tree_util.tree_flatten_with_path(shapes)[0]
+    rng = np.random.default_rng(seed)
+    out = {}
+    for path, leaf in flat:
+        names = tuple(k.key for k in path)
+        if any("norm" in n for n in names):
+            value = 1.0 + 0.1 * rng.standard_normal(leaf.shape)
+        elif names[-1] == "router_bias":
+            value = 0.1 * rng.standard_normal(leaf.shape)
+        elif names[-1] == "embedding":
+            value = rng.standard_normal(leaf.shape)
+        else:
+            value = rng.standard_normal(leaf.shape) / np.sqrt(leaf.shape[-2])
+        node = out
+        for n in names[:-1]:
+            node = node.setdefault(n, {})
+        node[names[-1]] = jnp.asarray(value, jnp.float32)
+    return out
+
+
+@pytest.fixture(scope="module")
+def params():
+    return seeded_params(CFG)
+
+
+def engine_for(params, cfg=CFG, **kw):
+    ec = EngineConfig(**{**dict(prompt_buckets=(32, 64), max_batch_size=4, max_seq_len=160,
+                                speculative="off", attn_impl="xla", max_chunked_prompt=256), **kw})
+    return InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+
+
+def greedy_reference(params, cfg, prompt, n):
+    tokens = list(prompt)
+    for _ in range(n):
+        tokens.append(int(np.argmax(ref.forward(params, cfg, tokens)[-1])))
+    return tokens[len(prompt):]
+
+
+def prompt_of(n, seed):
+    return [int(t) for t in np.random.default_rng(seed).integers(3, 300, size=n)]
+
+
+# ---- (a) prefill logits, then decode through the latent cache step by step ----
+
+
+def test_prefill_then_decode_matches_reference(params):
+    tokens = prompt_of(24, 1)
+    want = ref.forward(params, CFG, tokens)
+    model = lm.LatentMoEModel(CFG, FP32, attn_impl="xla")
+    S, T = 16, 32
+    cache = lm.make_latent_cache(CFG, 1, T, jnp.float32)
+    zero, i32 = jnp.zeros((1,), jnp.int32), jnp.int32
+    logits, cache = model.apply(
+        {"params": params}, jnp.asarray([tokens[:S]]), jnp.arange(S)[None], cache, zero,
+        jnp.full((1,), S, i32), i32(0))
+    np.testing.assert_allclose(np.asarray(logits[0]), want[:S], atol=ATOL)
+    for t in range(S, len(tokens)):  # one token at a time, against the full forward
+        logits, cache = model.apply(
+            {"params": params}, jnp.asarray([[tokens[t]]]), jnp.asarray([[t]]), cache, zero,
+            jnp.full((1,), t + 1, i32), i32(t))
+        np.testing.assert_allclose(np.asarray(logits[0, 0]), want[t], atol=ATOL)
+    counters = np.asarray(cache.counters).reshape(len(lm.COUNTER_MODES), -1)
+    assert counters[0, 0] == S * CFG.num_moe_layers and counters[1, 4] == 8 * CFG.num_moe_layers
+    assert (counters[:, 1] == counters[:, 2]).all()  # routed to held == computed
+
+
+# ---- (b) every one-shot program of the engine ----
+
+
+def test_batched_rows_of_unequal_length(params):
+    prompts = [prompt_of(n, 10 + n) for n in (20, 31, 7)]
+    got = engine_for(params).generate(prompts)
+    assert got == [greedy_reference(params, CFG, p, 8) for p in prompts]
+
+
+def test_verify_16_drafts_is_the_vanilla_stream(params):
+    base = prompt_of(6, 3)
+    prompt = (base * 5)[:28]  # repeats: prompt lookup has something to draft
+    e = engine_for(params, speculative="prompt_lookup", spec_tokens=16)
+    assert e.generate([prompt]) == [greedy_reference(params, CFG, prompt, 8)]
+    counted = e.stats.family_counters
+    assert e.stats.spec_verify_steps > 0 and counted["moe_chunk_assignments_held"] > 0
+    assert counted["moe_chunk_assignments_held"] == counted["moe_chunk_assignments_computed"]
+
+
+def test_chunked_prefill_past_the_largest_bucket(params):
+    prompt = prompt_of(100, 4)  # > 64: two chunks of 64 through the cache
+    assert engine_for(params).generate([prompt]) == [greedy_reference(params, CFG, prompt, 8)]
+
+
+def test_score_exact_matches_reference_logits(params):
+    prompt, emitted = prompt_of(20, 5), prompt_of(6, 6)
+    got = engine_for(params).score_exact(prompt, emitted)
+    logits = ref.forward(params, CFG, prompt + emitted)[len(prompt) - 1:-1]
+    assert list(got["argmax"]) == list(np.argmax(logits, -1))
+    np.testing.assert_allclose(got["max_logit"], logits.max(-1), atol=ATOL)
+    np.testing.assert_allclose(got["chosen_logit"], logits[np.arange(6), emitted], atol=ATOL)
+
+
+def test_fused_single_fetch_path(params):
+    e = engine_for(params)
+    a_ids, b_ids = np.asarray(prompt_of(5, 7), np.int32), np.asarray(prompt_of(4, 8), np.int32)
+    store = np.zeros((8, 12), np.int32)
+    lens = np.asarray([12, 9, 12, 5, 12, 12, 12, 12], np.int32)
+    for i in range(8):
+        store[i, :lens[i]] = prompt_of(int(lens[i]), 20 + i)
+    packed = jnp.asarray([[0.1, 0.2, 0.3, 3.0, 1.0, 6.0]], jnp.float32)  # dists | ids
+    got = e.generate_rag(a_ids, b_ids, packed, jnp.asarray(store), jnp.asarray(lens), n_chunks=2)
+    prompt = list(a_ids) + list(store[3, :5]) + list(store[1, :9]) + list(b_ids)
+    assert got == greedy_reference(params, CFG, [int(t) for t in prompt], 8)
+    counted = e.stats.family_counters
+    assert counted["moe_prefill_assignments_held"] == counted["moe_prefill_assignments_computed"] > 0
+
+
+@pytest.mark.parametrize("impl", ["pallas_interpret"])
+def test_pallas_path_is_the_xla_path(params, impl):
+    prompts = [prompt_of(n, 30 + n) for n in (20, 9)]
+    assert engine_for(params, attn_impl=impl).generate(prompts) == engine_for(params).generate(prompts)
+
+
+# ---- (c) absorbed against expanded attention ----
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_absorbed_equals_expanded(impl):
+    rng = np.random.default_rng(0)
+    B, S, H, C, R, dn, dv = 2, 128, 4, 16, 8, 16, 16
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    q_nope, q_rope, c_kv, k_rope, w = f(B, S, H, dn), f(B, S, H, R), f(B, S, C), f(B, S, R), f(C, H, dn + dv)
+    kv_start, kv_len = jnp.asarray([0, 5], jnp.int32), jnp.asarray([S, S - 3], jnp.int32)
+    kv = jnp.einsum("bsc,chd->bshd", c_kv, w)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope[:, :, None], (B, S, H, R))], -1)
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    expanded = mla.mla_prefill_attention_xla(q, k, kv[..., dn:], kv_start, kv_len, scale=0.2)
+    if impl != "xla":
+        flash = mla.mla_flash_attention(q, k, kv[..., dn:], kv_start, kv_len, scale=0.2,
+                                        bq=64, bk=64, interpret=True)
+        np.testing.assert_allclose(np.asarray(flash), np.asarray(expanded), atol=2e-5)
+    q_lat = jnp.einsum("bshn,chn->bshc", q_nope, w[..., :dn])
+    o_lat = mla.latent_attention_xla(q_lat, q_rope, c_kv[None], k_rope[None], kv_start, kv_len,
+                                     jnp.int32(0), jnp.int32(0), scale=0.2)
+    absorbed = jnp.einsum("bshc,chv->bshv", o_lat, w[..., dn:])
+    np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded), atol=2e-5)
+    if impl != "xla":  # the decode kernel: the last valid query of each row
+        last = S - 4
+        one = mla.mla_decode_attention(
+            q_lat[:, last:last + 1], q_rope[:, last:last + 1], c_kv[None], k_rope[None], kv_start,
+            jnp.full((B,), last + 1, jnp.int32), jnp.int32(0), scale=0.2, bk=32, interpret=True)
+        np.testing.assert_allclose(np.asarray(one[:, 0]), np.asarray(o_lat[:, last]), atol=2e-5)
+
+
+# ---- (d) routing ----
+
+ROUTE = dict(top_k=2, n_group=4, topk_group=2, scaling=2.5)
+
+
+def test_route_group_limiting_bias_normalisation_scaling_and_ties():
+    logits = jnp.full((1, 16), -3.0).at[0, [0, 1]].set(2.0).at[0, [4, 5]].set(1.0).at[0, 8].set(2.5)
+    # group scores (top-2 sums): g0 = 2 s(2), g1 = 2 s(1), g2 = s(2.5) + s(-3): g2 is the lowest of
+    # the three, so expert 8, the single best, is NOT eligible; ties in g0 go to the lower index
+    experts, weights = moe.route(logits, jnp.zeros(16), **ROUTE)
+    assert experts.tolist() == [[0, 1]]
+    np.testing.assert_allclose(np.asarray(weights), [[1.25, 1.25]], rtol=1e-6)
+    # the bias moves the CHOICE (expert 5 over 1) and never the WEIGHT (sigmoid(1) and sigmoid(2))
+    bias = jnp.zeros(16).at[5].set(0.5).at[4].set(0.5)
+    experts, weights = moe.route(logits, bias, **ROUTE)
+    assert sorted(experts[0].tolist()) == [4, 5]
+    np.testing.assert_allclose(float(weights.sum()), 2.5, rtol=1e-6)
+    experts, weights = moe.route(logits, bias.at[4].set(0.0).at[0].set(0.3), **ROUTE)
+    s = jax.nn.sigmoid(jnp.asarray([2.0, 1.0]))
+    got = dict(zip(experts[0].tolist(), np.asarray(weights[0])))
+    np.testing.assert_allclose([got[0], got[5]], np.asarray(s / s.sum() * 2.5), rtol=1e-6)
+    assert np.allclose(moe.route(logits, bias, **ROUTE, normalize=False)[1].sum(),
+                       2.5 * 2 * float(jax.nn.sigmoid(1.0)), rtol=1e-6)
+
+
+def test_route_matches_reference_on_random_scores():
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((64, 32)), jnp.float32)
+    w_g = jnp.asarray(rng.standard_normal((32, 16)), jnp.float32)
+    bias = jnp.asarray(0.2 * rng.standard_normal(16), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = ref.route(x, w_g, bias, CFG)
+        experts, weights = moe.route(x @ w_g, bias, top_k=4, n_group=4, topk_group=2, scaling=2.5)
+    got = np.zeros((64, 16))
+    np.put_along_axis(got, np.asarray(experts), np.asarray(weights), axis=1)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_imbalance_loses_nothing(impl):
+    """Every token sends all its choices to held experts, half of them to ONE:
+    four times the row buffer, so the layer takes several passes."""
+    rng = np.random.default_rng(2)
+    N, D, F, held, top_k = 256, 32, 16, 4, 2
+    x = jnp.asarray(rng.standard_normal((N, D)), jnp.float32)
+    w = [jnp.asarray(rng.standard_normal(s) / 4, jnp.float32)
+         for s in ((1, held, D, F), (1, held, D, F), (1, held, F, D))]
+    experts = jnp.stack([jnp.full((N,), 9), 8 + jnp.asarray(rng.integers(0, 4, N))], 1).astype(jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, (N, top_k)), jnp.float32)
+    assert moe.rows_per_pass(N, top_k, 64, held) < N * top_k
+    with jax.default_matmul_precision("highest"):
+        y, counts = moe.held_expert_ffn(x, experts, weights, *w, jnp.int32(0), 8, 64, impl=impl)
+        want = sum(jnp.where(experts[:, j:j + 1] == 8 + e, weights[:, j:j + 1], 0.0)
+                   * ref._swiglu(x, w[0][0, e], w[1][0, e], w[2][0, e])
+                   for e in range(held) for j in range(top_k))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-4)
+    assert int(counts.routed) == int(counts.computed) == N * top_k and int(counts.experts_hit) >= 1
+
+
+# ---- (e) the share ties to the model ----
+
+
+def test_shares_add_up_to_the_uncut_layer(params):
+    whole = dataclasses.replace(CFG, ep_size=1, ep_rank=0)
+    p_whole = seeded_params(whole, seed=3)
+    layer = jax.tree.map(lambda a: a[0], p_whole["layers"]["mlp"])
+    x = jnp.asarray(np.random.default_rng(4).standard_normal((2, 24, CFG.hidden_size)), jnp.float32)
+
+    def run(cfg, stack):
+        y, _ = lm.SparseMLP(cfg, FP32, "xla").apply({"params": layer}, x, stack, jnp.int32(1))
+        return np.asarray(y, np.float64)
+
+    stack = tuple(p_whole["experts"][n] for n in ("w_gate", "w_up", "w_down"))
+    with jax.default_matmul_precision("highest"):
+        uncut = run(whole, stack)
+        held = CFG.n_routed_experts // 2
+        shares = [run(dataclasses.replace(CFG, ep_size=2, ep_rank=r),
+                      tuple(w[:, r * held:(r + 1) * held] for w in stack)) for r in range(2)]
+        sh = layer["shared"]
+        shared = np.asarray(ref._swiglu(x, sh["w_gate"]["kernel"], sh["w_up"]["kernel"],
+                                        sh["w_down"]["kernel"]), np.float64)
+    # every share counts the shared expert; the uncut layer counts it once
+    np.testing.assert_allclose(shares[0] + shares[1] - shared, uncut, atol=2e-5)
+    assert np.abs(shares[0] - shared).max() > 1e-2 and np.abs(shares[1] - shared).max() > 1e-2
+
+
+# ---- (f) YaRN ----
+
+
+def test_yarn_frequencies_and_softmax_scale_are_the_closed_form():
+    big = LatentMoEConfig()  # the published block
+    inv = np.asarray(lm.yarn_frequencies(big.qk_rope_head_dim, big.rope_theta, big.rope_scaling))
+    np.testing.assert_allclose(inv, ref.yarn_inv_freq(big), rtol=1e-6)
+    base = 10000.0 ** (-np.arange(0, 64, 2) / 64)
+    assert np.allclose(inv[:10], base[:10], rtol=1e-6) and np.allclose(inv[-8:], base[-8:] / 40, rtol=1e-6)
+    m = 0.1 * np.log(40.0) + 1.0
+    assert abs(m - 1.369) < 1e-3
+    np.testing.assert_allclose(lm.softmax_scale(big), 192 ** -0.5 * m * m, rtol=1e-9)
+    assert lm.rope_amplitude(big) == 1.0
+    assert lm.softmax_scale(dataclasses.replace(big, rope_scaling=None)) == 192 ** -0.5
+    np.testing.assert_allclose(lm.softmax_scale(CFG), ref.softmax_scale(CFG), rtol=1e-9)
+
+
+# ---- (g) what the family cannot be served with refuses by name ----
+
+
+@pytest.mark.parametrize("overrides,mechanism", [
+    (dict(kv_quant="int8"), "kv_quant"),
+    (dict(weight_quant="int8"), "weight_quant"),
+    (dict(prefix_cache=PrefixCacheConfig(enabled=True)), "prefix cache"),
+    (dict(batching="continuous"), "continuous"),
+])
+def test_refusals_name_the_mechanism(params, overrides, mechanism):
+    with pytest.raises(NotImplementedError, match=mechanism):
+        engine_for(params, **overrides)
+
+
+def test_continuous_engine_and_tp_refuse(params):
+    from rag_llm_k8s_tpu.engine.continuous import ContinuousEngine
+
+    with pytest.raises(NotImplementedError, match="continuous engine"):
+        ContinuousEngine(CFG, params, sampling=GREEDY, engine_config=EngineConfig(), dtypes=FP32)
+    mesh = make_mesh(MeshConfig(dp=1, sp=1, tp=2), devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="tp=2"):
+        InferenceEngine(CFG, params, sampling=GREEDY, engine_config=EngineConfig(), dtypes=FP32, mesh=mesh)
+
+
+def test_llama_tree_is_untouched_by_the_seam():
+    from rag_llm_k8s_tpu.core.config import LlamaConfig
+    from rag_llm_k8s_tpu.models import families
+    from rag_llm_k8s_tpu.models.llama import KVCache, LlamaModel
+
+    cfg = LlamaConfig.tiny()
+    llama = families.of(cfg)
+    assert isinstance(llama.build_model(cfg, FP32, EngineConfig(), None, fused=False, quantized=False),
+                      LlamaModel)
+    assert isinstance(families.make_cache(cfg, 1, 128, jnp.float32), KVCache)
+    assert llama.counters_width == 0 and not llama.counter_names and llama.checkpoint_loader_refusal is None
+    assert families.of(CFG).counters_width == lm.N_COUNTERS
+    assert set(families.of(CFG).counter_names) == set(lm.fold_counters(np.zeros(lm.N_COUNTERS)))
+    with pytest.raises(TypeError, match="no decoder family"):
+        families.of(object())
+
+
+def test_build_service_refuses_the_family_s_checkpoint_by_name(monkeypatch, tmp_path):
+    """A deployment's entry point loads safetensors through a name map this
+    family does not have: it says so instead of misreading a tree."""
+    import dataclasses as dc
+
+    from rag_llm_k8s_tpu.core.config import AppConfig
+    from rag_llm_k8s_tpu.server import main as server_main
+
+    base = AppConfig.from_env()
+    app = dc.replace(base, model=CFG, server=dc.replace(base.server, model_path=str(tmp_path)))
+    monkeypatch.setattr(AppConfig, "from_env", classmethod(lambda cls: app))
+    with pytest.raises(NotImplementedError, match="name map"):
+        server_main.build_service()
+
+
+# ---- the grouped kernel counts the rows it stores ----
+
+
+@pytest.mark.parametrize("sizes", [(0, 0, 0, 0), (5, 0, 130, 1), (128, 128, 0, 0), (300, 3, 3, 206)])
+def test_grouped_matmul_reports_the_rows_it_stored(sizes):
+    rng = np.random.default_rng(5)
+    m, k, n = 512, 128, 256
+    lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((2, 4, k, n)) / 8, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        out, stored = moe.grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32), jnp.int32(1), interpret=True)
+        assert int(stored) == sum(sizes)
+        lo = 0
+        for g, size in enumerate(sizes):
+            np.testing.assert_allclose(np.asarray(out[lo:lo + size]), np.asarray(lhs[lo:lo + size] @ rhs[1, g]),
+                                       atol=1e-4)
+            lo += size
+
+
+def test_a_tile_the_grid_never_reaches_shows_in_the_count(monkeypatch):
+    """The count is the kernel's own: cut the grid one (row tile, group) pair
+    short, as a wrong bound would, and fewer rows are reported than routed."""
+    import importlib
+
+    gmm = importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm")
+    real = gmm.make_group_metadata
+
+    def short(**kw):
+        meta, tiles = real(**kw)
+        return meta, tiles - 1
+
+    monkeypatch.setattr(gmm, "make_group_metadata", short)
+    lhs = jnp.ones((256, 128), jnp.float32)
+    rhs = jnp.ones((1, 2, 128, 128), jnp.float32)
+    sizes = jnp.asarray([128, 100], jnp.int32)
+    _, stored = moe.grouped_matmul.__wrapped__(lhs, rhs, sizes, jnp.int32(0), interpret=True)
+    assert int(stored) == 128 < int(sizes.sum())
+
+
+# ---- a large batch prefills a row at a time, by shape ----
+
+
+def test_rowwise_is_decided_by_shape_and_changes_nothing(params, monkeypatch):
+    big = LatentMoEConfig()  # the published block: a row of a 4096 bucket expands to 0.5 GiB
+    assert not lm.rowwise(big, 1, 4096, jnp.bfloat16) and lm.rowwise(big, 2, 4096, jnp.bfloat16)
+    assert not lm.rowwise(big, 2, 2048, jnp.bfloat16) and lm.rowwise(big, 8, 2048, jnp.bfloat16)
+    assert not lm.rowwise(CFG, 8, 64, jnp.float32)
+    prompts = [prompt_of(n, 40 + n) for n in (20, 9, 14)]
+    want = engine_for(params).generate(prompts)
+    monkeypatch.setattr(lm, "ROWWISE_BYTES", 1)
+    assert lm.rowwise(CFG, 3, 32, jnp.float32)
+    assert engine_for(params).generate(prompts) == want

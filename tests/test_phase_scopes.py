@@ -210,6 +210,50 @@ def test_the_programs_state_what_the_benchmark_counts_by(engine):
         assert not [p for p in paths if "/prefill/" in p and f"/prefill/rows{rows}/" not in p]
 
 
+LATENT_PROGRAMS = {k: ENGINE_PROGRAMS[k] for k in (
+    "generate", "generate_chunked", "generate_spec", "generate_rag", "generate_rag_spec", "score_exact")}
+
+
+@pytest.fixture(scope="module")
+def latent_engine():
+    """The latent-attention sparse-expert family through the same programs
+    (no prefix cache: the family refuses it)."""
+    import dataclasses
+
+    from rag_llm_k8s_tpu.core.config import LatentMoEConfig
+    from rag_llm_k8s_tpu.models.latent_moe import init_latent_moe_params
+
+    cfg = LatentMoEConfig.tiny(vocab_size=300)
+    params = init_latent_moe_params(jax.random.PRNGKey(0), cfg, FP32)
+    ec = dataclasses.replace(EC, prefix_cache=PrefixCacheConfig(enabled=False), attn_impl="xla")
+    return InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_PROGRAMS))
+def test_latent_moe_programs_arrive_scoped(latent_engine, name):
+    """Every operation of the second decoder family carries a phase."""
+    _assert_scoped(name, LATENT_PROGRAMS[name](latent_engine))
+
+
+def test_latent_moe_fine_scopes_sit_beneath_attn_and_mlp(latent_engine):
+    """``attn/latent``, ``mlp/router``, ``mlp/experts``, ``mlp/shared`` are in
+    the vocabulary and BENEATH the sub-scopes a reader already knows, so an
+    operation of them still files under ``decode/attn`` or ``decode/mlp``; the
+    leading dense layer stands outside the layers' loop and the MoE layers in
+    it, which is what a prefill's rows are counted by."""
+    assert set(tracing.FINE_SCOPES) == {"latent", "router", "experts", "shared"}
+    assert set(tracing.FINE_SCOPES) <= tracing.SCOPE_NAMES
+    paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](latent_engine))]
+    for phase in ("prefill", "decode"):
+        for sub, fine in (("attn", "latent"), ("mlp", "router"), ("mlp", "experts"), ("mlp", "shared")):
+            hits = [p for p in paths if f"/{phase}/" in p and f"/{sub}/{fine}/" in p]
+            assert hits and all(_scope(p) == (phase, sub) for p in hits), (phase, sub, fine)
+    dense = [p for p in paths if "/prefill/rows2/" in p and "/dense_0/" in p]
+    assert dense and not [p for p in dense if "/while/" in p.split("/dense_0/")[0]]
+    looped = [p for p in paths if "/prefill/rows2/" in p and "/while/body/" in p and "/layers/" in p]
+    assert looped and not [p for p in looped if "/dense_0/" in p]
+
+
 def test_an_operation_outside_every_scope_shows():
     def f(x):
         y = jnp.tanh(x) @ x  # traced outside every scope

@@ -160,6 +160,39 @@ CASES = {
 }
 
 
+def _latent(fn_name: str, args, **static):
+    import functools
+
+    from rag_llm_k8s_tpu.ops import mla, moe
+
+    fn = getattr(mla, fn_name, None) or getattr(moe, fn_name)
+    return functools.partial(fn, **static), args
+
+
+# the latent-attention sparse-expert family at its published widths: 128
+# heads of 128 nope + 64 rope keys against 128-wide values, a latent cache of
+# rank 512 + 64, 16 held experts of 7168 x 2048 stacked over 4 layers
+CASES.update({
+    "mla_flash_attention[4096]": _latent(
+        "mla_flash_attention",
+        [((1, 4096, 128, 192), BF16), ((1, 4096, 128, 192), BF16), ((1, 4096, 128, 128), BF16),
+         ((1,), I32), ((1,), I32)], scale=0.1),
+    "mla_decode_attention[B=8]": _latent(
+        "mla_decode_attention",
+        [((8, 1, 128, 512), BF16), ((8, 1, 128, 64), BF16), ((5, 8, T, 512), BF16),
+         ((5, 8, T, 64), BF16), ((8,), I32), ((8,), I32), ((), I32)], scale=0.1),
+    "grouped_matmul[prefill up]": _latent(
+        "grouped_matmul",
+        [((32768, 7168), BF16), ((4, 16, 7168, 2048), BF16), ((16,), I32), ((), I32)]),
+    "grouped_matmul[prefill down]": _latent(
+        "grouped_matmul",
+        [((32768, 2048), BF16), ((4, 16, 2048, 7168), BF16), ((16,), I32), ((), I32)]),
+    "grouped_matmul[decode up]": _latent(
+        "grouped_matmul",
+        [((128, 7168), BF16), ((4, 16, 7168, 2048), BF16), ((16,), I32), ((), I32)]),
+})
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, uncached):
     fn, args = CASES[name]
@@ -218,3 +251,33 @@ def test_sharded_grouped_chunk_compiles_for_four_chips(topo, uncached):
     text = jax.jit(fn).lower(*avals).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" not in text and "all-gather" not in text
+
+
+def test_latent_moe_generate_program_compiles_with_its_kernels(one_chip, uncached):
+    """The second decoder family's batched generate program, at toy widths,
+    through the Pallas path: the flash prefill over expanded latents, the
+    absorbed decode kernel over the latent cache, and the grouped expert
+    matmul all lower for the chip inside one program."""
+    from rag_llm_k8s_tpu.core.config import (
+        DTypePolicy, EngineConfig, GoodputConfig, LatentMoEConfig, SamplingConfig,
+    )
+    from rag_llm_k8s_tpu.engine import engine as engine_mod
+    from rag_llm_k8s_tpu.models.latent_moe import init_latent_moe_params
+    cfg = LatentMoEConfig.tiny(
+        vocab_size=512, hidden_size=256, intermediate_size=512, moe_intermediate_size=128,
+        num_heads=4, q_lora_rank=128, kv_lora_rank=128, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, max_seq_len=1024)
+    dt = DTypePolicy()
+    shapes = jax.eval_shape(lambda: init_latent_moe_params(jax.random.PRNGKey(0), cfg, dt))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    ec = EngineConfig(prompt_buckets=(256,), max_seq_len=512, attn_impl="pallas", speculative="off",
+                      goodput=GoodputConfig(enabled=False))
+    eng = engine_mod.InferenceEngine(
+        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=8),
+        engine_config=ec, dtypes=dt)
+    fn = eng._make_gen(2, 256, 8)
+    tok = jax.ShapeDtypeStruct((2, 256), I32, sharding=one_chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(fn).lower(params, tok, tok, rng).compile().as_text()
+    for kernel in ("mla_flash_attention", "mla_decode_attention", "grouped_matmul"):
+        assert kernel in text, f"{kernel}: not in the compiled program"
