@@ -24,9 +24,10 @@ class Family:
     build_model: Callable  # (config, dtypes, engine_config, mesh, fused=, quantized=) -> nn.Module
     make_cache: Callable  # (config, batch, max_seq_len, dtype, quant) -> a fresh cache
     param_specs: Callable  # (params, mesh) -> PartitionSpec pytree
-    # int32 counters the cache carries (``cache.counters``): the generate
-    # programs append them to their one fetched array, and ``fold_counters``
-    # turns a fetched row into {name: increment} for ``/metrics``
+    # int32 counters the cache carries (``cache.counters``: a sparse-expert
+    # family's assignments, a dense one's prefill token rows computed): the
+    # generate programs append them to their one fetched array, and
+    # ``fold_counters`` turns a fetched row into {name: increment} for ``/metrics``
     counters_width: int = 0
     counter_names: Tuple[str, ...] = ()
     fold_counters: Optional[Callable] = None
@@ -54,13 +55,27 @@ def _llama_model(config, dtypes, engine_config, mesh, *, fused: bool, quantized:
 def _llama_cache(config, batch_size, max_seq_len, dtype, quant):
     from rag_llm_k8s_tpu.models.llama import make_kv_cache
 
-    return make_kv_cache(config, batch_size, max_seq_len, dtype, quant=quant)
+    return make_kv_cache(config, batch_size, max_seq_len, dtype, quant=quant, counters=True)
 
 
 def _llama_specs(params, mesh):
     from rag_llm_k8s_tpu.parallel.sharding import llama_param_specs
 
     return llama_param_specs(params, mesh)
+
+
+def _llama() -> Family:
+    from rag_llm_k8s_tpu.models import llama
+
+    return Family(
+        name="the Llama family (LlamaConfig)",
+        build_model=_llama_model,
+        make_cache=_llama_cache,
+        param_specs=_llama_specs,
+        counters_width=len(llama.COUNTER_NAMES),
+        counter_names=llama.COUNTER_NAMES,
+        fold_counters=llama.fold_counters,
+    )
 
 
 def _latent_moe() -> Family:
@@ -111,7 +126,7 @@ def _latent_moe() -> Family:
 # configuration type -> its family (a thunk where building it imports the model)
 _TABLE: Tuple[Tuple[type, Callable[[], Family]], ...] = (
     (LatentMoEConfig, _latent_moe),
-    (LlamaConfig, lambda: Family("the Llama family (LlamaConfig)", _llama_model, _llama_cache, _llama_specs)),
+    (LlamaConfig, _llama),
 )
 _BUILT: Dict[type, Family] = {}
 

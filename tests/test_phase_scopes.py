@@ -7,7 +7,7 @@ operation the PROGRAM traced (an instruction of the optimized HLO whose
 ``op_name`` is a path from its ``jit``) must name a phase of
 ``obs/tracing.PHASES``. What the compiler writes itself carries no path and
 differs by backend, so it is the chip's to judge (the benchmark's reader and
-its metric); nothing under ``benchmark/`` is imported here. The second half
+its metric); of ``benchmark/`` only its phase reader is imported, by one test. The second half
 drives the dispatch counters and spans.
 """
 
@@ -208,6 +208,29 @@ def test_the_programs_state_what_the_benchmark_counts_by(engine):
         layers = [p for p in paths if f"/prefill/rows{rows}/" in p and "/while/body/" in p]
         assert layers, (name, [p for p in paths if "/prefill/" in p][:5])
         assert not [p for p in paths if "/prefill/" in p and f"/prefill/rows{rows}/" not in p]
+
+
+def test_a_live_suffix_prefill_still_counts_its_rows(llama, tmp_path):
+    """A bucket with rungs (``models/llama.py live_offsets``) puts the layers'
+    matmuls in branches. The benchmark counts a prefill's rows from the
+    operations of the layers' loop that stand in NO branch, so the loop has
+    to keep some: its own reader (``benchmark/lib/phases.py``, the one piece
+    of the benchmark imported here) reduces a CPU capture of the program."""
+    from benchmark.lib import phases, trace
+
+    cfg, params = llama
+    ec = EngineConfig(prompt_buckets=(1280,), max_batch_size=2, max_seq_len=1408)
+    eng = InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+    prompts = [list(range(5, 905)), list(range(7, 807))]
+    eng.generate(prompts)  # compiled outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    for _ in range(2):
+        eng.generate(prompts)
+    jax.profiler.stop_trace()
+    reduced = phases.reduce_phases(phases.load(trace.find_xplane(str(tmp_path))), cfg.num_layers)
+    assert reduced["prefill_rows"] == 2 * 2  # the batch, twice
+    assert reduced["steps"].get("decode", 0) > 0
+    assert eng.stats.family_counters["prefill_tokens_computed"] == 3 * 2 * (1280 - 320)
 
 
 LATENT_PROGRAMS = {k: ENGINE_PROGRAMS[k] for k in (

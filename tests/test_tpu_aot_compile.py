@@ -281,3 +281,44 @@ def test_latent_moe_generate_program_compiles_with_its_kernels(one_chip, uncache
     text = jax.jit(fn).lower(params, tok, tok, rng).compile().as_text()
     for kernel in ("mla_flash_attention", "mla_decode_attention", "grouped_matmul"):
         assert kernel in text, f"{kernel}: not in the compiled program"
+
+
+@pytest.mark.parametrize("batch", [1, 8])
+def test_live_suffix_branches_copy_no_stacked_weight(batch, one_chip, uncached):
+    """The prefill of a bucket with rungs (``models/llama.py live_offsets``)
+    at Mistral-7B's widths, int8, four layers: its branches read the MLP's
+    weights from the STACKED tree at their layer. Asked the same of
+    attention's projections the compiler re-laid the whole stack inside a
+    branch, every layer (``s8[L,4096,4096] copy`` in the full branch: PR 28);
+    they keep the scan's own slices. A copy of a stacked kernel anywhere but
+    the entry computation (once a call, as before) fails here."""
+    import re
+
+    from rag_llm_k8s_tpu.core.config import (
+        DTypePolicy, EngineConfig, GoodputConfig, LlamaConfig, SamplingConfig,
+    )
+    from rag_llm_k8s_tpu.engine import engine as engine_mod
+    from rag_llm_k8s_tpu.models.llama import init_llama_params, quantize_llama_params
+
+    layers = 4
+    cfg = LlamaConfig(vocab_size=32768, hidden_size=4096, intermediate_size=14336, num_layers=layers,
+                      num_heads=32, num_kv_heads=8, head_dim=128, max_seq_len=32768, rope_theta=1e6,
+                      rope_scaling=None, tie_word_embeddings=False)
+    dt = DTypePolicy()
+    shapes = jax.eval_shape(
+        lambda: quantize_llama_params(init_llama_params(jax.random.PRNGKey(0), cfg, dt)))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    ec = EngineConfig(prompt_buckets=(4096,), max_seq_len=4352, attn_impl="pallas", speculative="off",
+                      weight_quant="int8", kv_quant="int8", goodput=GoodputConfig(enabled=False))
+    eng = engine_mod.InferenceEngine(
+        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=2),
+        engine_config=ec, dtypes=dt)
+    tok = jax.ShapeDtypeStruct((batch, 4096), I32, sharding=one_chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(eng._make_gen(batch, 4096, 2)).lower(params, tok, tok, rng).compile().as_text()
+    assert "flash_attention" in text and " conditional(" in text
+    stacked_copy = re.compile(rf"= s8\[{layers},\d+,\d+\]\S* copy\(")
+    for computation in text.split("\n\n"):
+        if not computation.lstrip().startswith("ENTRY"):
+            found = [line.strip()[:160] for line in computation.splitlines() if stacked_copy.search(line)]
+            assert not found, found
