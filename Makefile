@@ -3,7 +3,7 @@
 #                    sharding coverage without hardware)
 #   make tpu-test  - hardware lane on the real TPU chip (kernels vs oracles,
 #                    engine end-to-end); skips itself when no TPU is present
-#   make bench     - headline benchmark JSON line (real chip)
+#   (speed)        - `python3 benchmark/run.py` as BENCHMARK.json gives it, on a chip
 #   make lint      - ruff (when available) + metrics↔OBSERVABILITY.md gate
 #   make check     - THE pre-snapshot gate: everything the driver measures.
 #                    Run before every snapshot commit; nothing ships red.
@@ -25,9 +25,6 @@ tier1:
 # command per call: `python -m pytest tests_tpu/ -q` after `chip_smoke.py`.)
 tpu-test:
 	python -m pytest tests_tpu/ -q
-
-bench:
-	python bench.py
 
 # Chaos lane (ISSUE 4 + ISSUE 5): the fault-injection suite with
 # TPU_RAG_FAULTS armed (enables the harness end-to-end, including the
@@ -199,23 +196,6 @@ restart-smoke:
 disagg-smoke:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_router.py::TestSmoke -q -p no:cacheprovider
 
-# Perf regression gate (scripts/bench_gate.py): compare a fresh bench JSON
-# against a committed baseline with per-metric tolerance bands, direction
-# aware (latency up = bad, tok/s down = bad). Defaults to comparing the
-# baseline against itself (a self-comparison smoke that must pass); for a
-# real judgment use a round artifact as the baseline (its {"parsed": ...}
-# envelope is unwrapped) and a fresh capture as current:
-#   make bench-gate BENCH_BASELINE=BENCH_r03.json BENCH_CURRENT=<fresh capture>.json
-# Disjoint schemas (zero shared comparable metrics) exit 2, never "OK".
-# REQUIRED_KEYS in the script (continuous_device_steps_per_s.b64_sync16,
-# tracked higher-is-better) may never silently vanish from a judged run —
-# a dropped leg fails the gate instead of reading as a pass, so the B=64
-# continuous regression can never return unjudged.
-BENCH_BASELINE ?= BENCH_BASELINE.json
-BENCH_CURRENT ?= $(BENCH_BASELINE)
-bench-gate:
-	python scripts/bench_gate.py --baseline $(BENCH_BASELINE) --current $(BENCH_CURRENT)
-
 # Static checks: ruff (when the environment provides it — this container
 # does not bake it in, and the no-new-deps rule forbids installing it
 # here; its rule selection is PINNED in pyproject.toml [tool.ruff] so a
@@ -224,7 +204,7 @@ bench-gate:
 # rule (stdlib-only so it runs everywhere tier1 runs).
 lint:
 	@if command -v ruff >/dev/null 2>&1; then \
-		ruff check rag_llm_k8s_tpu tests bench.py scripts; \
+		ruff check rag_llm_k8s_tpu tests scripts; \
 	else \
 		echo "lint: ruff not installed in this environment; skipping style pass"; \
 	fi
@@ -250,17 +230,15 @@ validate-8b:
 validate-70b:
 	python -m pytest tests/test_loader_70b.py -q
 
-check: test tpu-test bench
+check: test tpu-test
 	python -c "from __graft_entry__ import entry; import jax; fn, a = entry(); jax.jit(fn).lower(*a).compile(); print('entry: compile OK')"
 	XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
 		python -c "from __graft_entry__ import dryrun_multichip; dryrun_multichip(8); print('dryrun_multichip(8): OK')"
 
 # The no-hardware CI lane: the tier-1 gate verbatim, the chaos (fault
-# injection) suite, static checks, and a fast bench-gate schema pass
-# (validates the baseline + gate plumbing without running the bench — the
-# TPU-judged comparison is `make bench` followed by
-# `make bench-gate BENCH_CURRENT=...`).
+# injection) suite and the static checks. Speed is not judged here: the
+# driver runs the benchmark on the chip and holds a PR to BENCHMARK.json's
+# bounds.
 ci: tier1 chaos tp2-smoke lookahead-smoke tiering-smoke splice-smoke spec-smoke interleave-smoke flight-smoke goodput-smoke shadow-smoke replay-smoke tenants-smoke drain-smoke restart-smoke disagg-smoke lint analyze
-	python scripts/bench_gate.py --baseline $(BENCH_BASELINE) --dry-run
 
-.PHONY: test tier1 tpu-test bench bench-gate chaos tp2-smoke lookahead-smoke tiering-smoke splice-smoke spec-smoke interleave-smoke flight-smoke goodput-smoke shadow-smoke replay-smoke tenants-smoke drain-smoke restart-smoke disagg-smoke ci lint analyze check validate-8b validate-70b
+.PHONY: test tier1 tpu-test chaos tp2-smoke lookahead-smoke tiering-smoke splice-smoke spec-smoke interleave-smoke flight-smoke goodput-smoke shadow-smoke replay-smoke tenants-smoke drain-smoke restart-smoke disagg-smoke ci lint analyze check validate-8b validate-70b

@@ -1,7 +1,7 @@
 """Placement of JAX's persistent compilation cache.
 
 Every entry point that compiles for a device (``server/main.main``,
-``chip_smoke.py``, ``bench.py``, ``scripts/validate_8b.py``, the hardware
+``chip_smoke.py``, ``benchmark/run.py``, ``scripts/validate_8b.py``, the hardware
 test lane's fixture) calls :func:`ensure_compile_cache` FIRST. The directory
 is part of a cache entry's key, so it must never move between runs:
 

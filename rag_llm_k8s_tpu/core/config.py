@@ -525,9 +525,9 @@ class GoodputConfig:
     accounting, and cost-per-query (obs/goodput.py, docs/GOODPUT.md).
 
     ON BY DEFAULT: the ledger is pure host-side dict math per device sync
-    window (no device work, no I/O), held to ≤ 2% of B=8 decode steps/s
-    by the ``goodput_overhead`` bench gate — the same contract as the
-    flight recorder it journals through.
+    window (no device work, no I/O). What that costs a decode step has
+    not been measured on the chip (PERF.md §7, ``audits-on``) — as for
+    the flight recorder it journals through.
     """
 
     # master switch for the step ledger (env TPU_RAG_GOODPUT)
@@ -1031,8 +1031,8 @@ class FlightConfig:
     """Engine flight recorder + incident bundles (obs/flight.py).
 
     The recorder is ON BY DEFAULT: it is the post-mortem signal, and its
-    measured cost is a bounded ring append per scheduler decision (the
-    ``flight_overhead`` bench leg pins it at ≤ 2% of B=8 decode steps/s).
+    cost is a bounded ring append per scheduler decision (not measured on
+    the chip: PERF.md §7, ``audits-on``).
     """
 
     # master switch for the in-process event journal (env TPU_RAG_FLIGHT)
@@ -1161,8 +1161,8 @@ class ShadowConfig:
     the serving path (int8 warm tier, chunk splice/re-rotation, boundary
     correction, speculative verify). ON BY DEFAULT: the audit is one
     headroom-gated chunked forward per sampled request on the one-shot
-    engine (never the serving pool), and the ``shadow_overhead`` bench
-    leg pins its cost at ≤ 2% of B=8 decode steps/s.
+    engine (never the serving pool); its cost to live traffic has not
+    been measured on the chip (PERF.md §7, ``audits-on``).
     """
 
     # master switch (env TPU_RAG_SHADOW)
@@ -1235,8 +1235,8 @@ class TenantConfig:
     or event attr — ``rag_tenant_*`` families can never hold more than
     ``top_k``+1 tenant children (the +1 is the ``__other__`` overflow
     bucket), no matter the traffic. ON BY DEFAULT: attribution is a dict
-    update per request edge/completion and the ``tenant_overhead`` bench
-    leg pins its cost at ≤ 2% of B=8 decode steps/s.
+    update per request edge/completion; its cost to a decode step has
+    not been measured on the chip (PERF.md §7).
     """
 
     # master switch (env TPU_RAG_TENANTS)

@@ -26,7 +26,7 @@ Anatomy (all AOT-compiled, static shapes):
 
 Trade-off vs the fused one-shot path (engine.py): per-window host sync and a
 scatter cache write, in exchange for no head-of-line blocking. The one-shot
-path remains the fastest way to run a KNOWN batch (bench.py uses it).
+path remains the fastest way to run a KNOWN batch (the benchmark's cells do).
 """
 
 from __future__ import annotations
@@ -94,7 +94,7 @@ logger = logging.getLogger(__name__)
 
 # request ids are PROCESS-global (not per-scheduler): the flight journal
 # (obs/flight.py) keys every lifecycle event on this id, and two schedulers
-# in one process (bench legs, tests) must never alias each other's
+# in one process (tests, a prefill/decode pair) must never alias each other's
 # timelines. itertools.count is atomic under CPython — no lock needed.
 _REQUEST_IDS = itertools.count(1)
 
@@ -357,7 +357,7 @@ class ContinuousEngine:
         # reads the rolling totals, and each window journals ONE
         # goodput_window flight event so flightview --goodput reconstructs
         # the same report offline. Host-side dict math only; the
-        # goodput_overhead bench leg holds it to <= 2% of decode steps/s.
+        # its cost to a decode step is not measured on the chip (PERF.md §7).
         self.ledger = obs_goodput.ledger_for(
             config, engine_config, device_kind=serving_device_kind(mesh)
         )
@@ -2313,7 +2313,7 @@ class ContinuousEngine:
         if not self.paged:
             return
         if len(self._blocks_at_retire) > 8192:
-            # raw-engine callers (tests, benches) never pop; don't let the
+            # raw-engine callers (tests, scripts) never pop; don't let the
             # footprint map grow without bound under them
             self._blocks_at_retire.clear()
         for r in rows:

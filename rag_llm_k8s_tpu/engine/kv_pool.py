@@ -91,7 +91,7 @@ class KVBlockPool:
         # deterministic id sequences either way
         self._free: deque = deque(range(1, self.num_blocks))
         self._refs: Dict[int, int] = {}
-        # cumulative counters (engine stats / bench)
+        # cumulative counters (engine stats)
         self.total_allocs = 0
         self.total_exhaustions = 0
         # per-tier occupancy of REGISTERED prefix blocks (hotness tiering,

@@ -260,7 +260,7 @@ class PrefixCache:
         self._pinned_keys: set = set()
         self.entry_bytes = 0
         self.assembled_bytes = 0
-        # counters (read by /metrics and bench.py)
+        # counters (read by /metrics)
         self.hits = 0
         self.misses = 0
         self.tokens_reused = 0
@@ -872,7 +872,7 @@ class PrefixCache:
 
     def force_demote(self, tier: str, seg_key: Optional[str] = None) -> int:
         """Demote entries (all, or just ``seg_key``'s) to ``tier``
-        regardless of hotness — the bench's forced-demotion lever and the
+        regardless of hotness — the forced-demotion lever, which is the
         quality-tolerance tests' setup hook. Pinned entries still never
         demote. Returns the number of entries moved."""
         if tier not in ("warm", "cold"):
@@ -1057,7 +1057,7 @@ class PrefixCache:
 
     def tier_stats(self) -> Dict[str, float]:
         """Per-tier residency + transition counters — the source of the
-        ``rag_kv_tier_*`` families (obs) and the bench's capacity math."""
+        ``rag_kv_tier_*`` families (obs) and of the lookahead's hide rate."""
         out: Dict[str, float] = {
             "tier_hot_entries": 0, "tier_warm_entries": 0,
             "tier_cold_entries": 0, "tier_hot_bytes": 0,

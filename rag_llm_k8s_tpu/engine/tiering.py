@@ -133,7 +133,7 @@ class HostSpillStore:
         self._data: "Dict[object, Tuple[Tuple[np.ndarray, ...], dict, int]]" = {}
         self._order: list = []  # insertion order (oldest first)
         self.bytes = 0
-        # cumulative counters (tier stats / bench)
+        # cumulative counters (tier stats)
         self.spills = 0
         self.evictions = 0
 

@@ -510,8 +510,8 @@ class VectorStore:
         reads these for prefill accounting and context rendering."""
         with self._lock:
             out = []
-            for i in idxs:
-                row = self._chunk_tokens[int(i)] if int(i) < len(self._chunk_tokens) else None
+            for i in map(int, idxs):  # a negative id is out of range too, not the last row
+                row = self._chunk_tokens[i] if 0 <= i < len(self._chunk_tokens) else None
                 out.append(0 if row is None else int(row.shape[0]))
             return out
 

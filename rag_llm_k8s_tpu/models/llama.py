@@ -1383,7 +1383,7 @@ def quantize_llama_params(params: dict, donate: bool = False) -> dict:
 def synth_leaf_kind(path, dtype) -> str:
     """Classify a Llama param leaf, given its ``path`` of string keys, for
     the synthetic weight builders (``utils/synth.synth_llama_params``,
-    bench.py's behavioral 8B tree): ``"kernel_q"`` (int8 kernels),
+    the benchmark's seeded trees): ``"kernel_q"`` (int8 kernels),
     ``"quant_scale"`` (per-channel dequant scales), ``"norm"`` (RMSNorm
     weights — MUST stay ~1), ``"embedding"`` (the token table) or
     ``"kernel"`` (bf16 projection kernels and output head). Norms go by

@@ -21,9 +21,9 @@ The decision layer on top (ISSUE 3):
   (``GET /slo`` + ``rag_slo_*`` gauges);
 - ``obs.logging`` — W3C ``traceparent`` parse/emit and trace-correlated
   structured JSON logs;
-- ``obs.devices`` — per-device HBM / prefix-cache residency gauges;
-- ``obs.regression`` — the direction-aware bench regression comparator
-  behind ``make bench-gate``.
+- ``obs.devices`` — per-device HBM / prefix-cache residency gauges.
+  (Whether a change made the program slower is not judged in here: the
+  driver runs ``benchmark/`` on the chip against ``BENCHMARK.json``'s bounds.)
 
 The causal layer (ISSUE 11):
 

@@ -14,8 +14,8 @@ in aggregate. This module is the journal those decisions write to:
   EVENT-REGISTRY rule pins emit sites ↔ catalog ↔ docs three ways.
 - :class:`FlightRecorder` — a fixed-size ring of monotonic-stamped events.
   One append under one tiny lock, never any device work; the hot decode
-  path pays ~a microsecond per sync window (the ``flight_overhead`` bench
-  leg holds the recorder to ≤ 2% of B=8 decode steps/s). On by default.
+  path pays one such append per sync window (its cost to a decode step
+  has not been measured on the chip: PERF.md §7). On by default.
 - **timeline reconstruction** — events carry the scheduler request id, so
   ``timeline(rid)`` returns one request's ordered event chain with
   inter-event deltas (``GET /debug/timeline/<id>``; ``{"timeline": true}``
@@ -357,7 +357,7 @@ def configure(enabled: Optional[bool] = None,
               arrival_ids: Optional[bool] = None,
               wal=_UNSET) -> FlightRecorder:
     """Apply ``FlightConfig`` to the process recorder (the service calls
-    this at construction; bench legs toggle ``enabled`` directly). A
+    this at construction; tests toggle ``enabled`` directly). A
     capacity change rebuilds the ring (journal starts fresh); an
     enabled-only change keeps it. ``wal`` attaches (a :class:`FlightWAL`)
     or detaches (None) the durable tee; omitted, the current tee stays."""
@@ -496,8 +496,8 @@ class FlightWAL:
       SIGKILL costs at most the one event being written.
 
     Appends take one lock and one fsync — this is the durability tax the
-    warm-restart contract pays, measured by the bench ``restart_warmth``
-    leg's WAL-on throughput column. A failed append logs and drops the
+    warm-restart contract pays (not measured on the chip: no cell turns
+    the WAL on; PERF.md §7). A failed append logs and drops the
     event rather than taking the serving path down.
     """
 

@@ -325,11 +325,11 @@ class GoodputLedger:
     thread only; readers (``state`` / ``totals``, the /metrics callbacks
     and ``/debug/goodput``) come from scrape threads — a single tiny lock
     over plain dict math covers both, and no record ever touches device
-    state (the ``goodput_overhead`` bench leg holds the whole ledger to
-    ≤ 2% of B=8 decode steps/s).
+    state (what the ledger costs a decode step has not been measured on
+    the chip: PERF.md §7, ``audits-on``).
     """
 
-    MAX_REQUESTS = 8192  # raw-engine callers (tests, benches) never pop
+    MAX_REQUESTS = 8192  # raw-engine callers (tests, scripts) never pop
     COST_RING = 512      # completed-request chip_s ring (percentiles)
     # distinct per-tenant rollup rows (interned names churn slowly through
     # the top-K tracker; when even that overflows, the coldest row folds

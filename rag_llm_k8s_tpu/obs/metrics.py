@@ -7,8 +7,8 @@ the one registry everything reports into:
 - **Counter / Gauge / Histogram** primitives, each optionally *labeled*
   (``histogram.labels(stage="prefill")`` returns a per-label child).
   Histograms use FIXED log-spaced buckets so p50/p95 can be read off any
-  scrape (and so ``bench.py`` and a production Prometheus read the *same*
-  numbers from the same structure).
+  scrape (and so the benchmark's ``/metrics`` deltas and a production
+  Prometheus read the *same* numbers from the same structure).
 - **Lock-cheap hot path**: one uncontended per-child lock acquisition per
   observation — no global registry lock is ever taken to observe, only to
   register (which is rare and idempotent).
@@ -18,7 +18,7 @@ the one registry everything reports into:
   write on their hot paths.
 - **Two renderings** of the same state: Prometheus text exposition
   (``render_prometheus``) and a flat JSON snapshot (``snapshot``) for the
-  pre-existing JSON consumers (tests, bench) — content negotiation in the
+  pre-existing JSON consumers (tests) — content negotiation in the
   server picks one; the values are identical by construction
   (tests/test_obs.py pins the equivalence).
 
@@ -70,7 +70,7 @@ def log_buckets(lo: float, hi: float, factor: float) -> Tuple[float, ...]:
 
 # coarse general-purpose latency ladder: 0.5 ms .. ~65 s, x2 per bucket
 LATENCY_BUCKETS = log_buckets(0.0005, 64.0, 2.0)
-# fine end-to-end request ladder (the p50/p95 the bench and dashboards
+# fine end-to-end request ladder (the p50/p95 that dashboards
 # read off the histogram): ~12% relative resolution, 5 ms .. ~90 s
 REQUEST_BUCKETS = log_buckets(0.005, 90.0, 1.12)
 # per-token ladder (TTFT / inter-token): 0.2 ms .. ~2.2 s
@@ -219,7 +219,7 @@ class Histogram(_Child):
     def snapshot(self) -> Tuple[Tuple[int, ...], float, int]:
         """Consistent ``(per_bucket_counts, sum, count)`` — subtractable, so
         a caller can diff two snapshots and take quantiles of the window
-        in between (bench.py's per-pass p50/p95)."""
+        in between (obs/slo.py's windows are such diffs)."""
         with self._lock:
             return tuple(self._counts), self._sum, self._count
 
@@ -324,7 +324,7 @@ class MetricsRegistry:
 
     The legacy facade (``inc``/``observe``/``snapshot``) preserves the
     seed's ``_Metrics`` API byte-for-byte so every pre-existing consumer
-    (bench.py's ``query_single_fetch`` reads, the JSON ``/metrics`` tests)
+    (the ``query_single_fetch`` reads, the JSON ``/metrics`` tests)
     keeps working; ``observe(name, v)`` maintains the old ``{name}_sum`` /
     ``{name}_count`` counter pair.
     """
@@ -569,5 +569,5 @@ _DEFAULT = MetricsRegistry()
 def default_registry() -> MetricsRegistry:
     """Process-wide fallback registry: engines constructed standalone (unit
     tests, scripts) report here; ``RagService`` rebinds its engines to its
-    own instance so concurrent services (bench legs) never cross-count."""
+    own instance so concurrent services in a process never cross-count."""
     return _DEFAULT

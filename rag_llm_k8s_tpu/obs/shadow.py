@@ -5,7 +5,7 @@ The serving stack runs four lossy-by-contract approximations in
 production — int8 warm-tier KV, chunk-granular splice with boundary
 correction, speculative verify windows, and prefix reuse generally — but
 their quality contracts (warm logit tolerance 0.15, splice logit_max_err
-<= 0.15, spec byte-identity) were pinned only in tests and bench legs,
+<= 0.15, spec byte-identity) were pinned only in tests at a tiny size,
 never observed on live traffic. This module is that observation:
 
 - :class:`ShadowAuditor` re-runs a sampled fraction of completed live

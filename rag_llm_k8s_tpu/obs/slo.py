@@ -14,8 +14,8 @@ computable object):
   family the server maintains);
 - the engine samples the CUMULATIVE (good, total) pair per SLI into a
   time-indexed ring and evaluates windowed SLI values by differencing the
-  ring — the same trick bench.py uses to take per-pass quantiles from
-  cumulative histograms, applied over wall-clock windows;
+  ring — ``obs/metrics.py``'s snapshot diffing, which takes a window's
+  quantiles from cumulative histograms, applied over wall-clock windows;
 - **burn rate** per window = (bad fraction) / (1 - objective): burn 1.0
   spends exactly the error budget by the end of the SLO period, 14.4 spends
   a 30-day budget in 2 days. The alert signal pairs a long window with a

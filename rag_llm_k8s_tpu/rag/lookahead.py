@@ -116,7 +116,7 @@ class LookaheadExecutor:
       prefetch trigger (``PrefixCache.stage(trigger="lookahead")`` performs
       any host→HBM swap-in on the worker thread, overlapped with the
       previous request's decode), and ``stats()`` folds those counters into
-      the swap-in HIDE RATE the bench leg reports.
+      the swap-in HIDE RATE that ``stats()`` reports.
     """
 
     def __init__(
@@ -456,7 +456,7 @@ class LookaheadExecutor:
         return len(expired)
 
     def stats(self) -> Dict[str, float]:
-        """Live hit/waste accounting for bench legs and tests."""
+        """Live hit/waste accounting for ``/metrics`` and tests."""
         hit = self._m_joins["hit"].value
         late = self._m_joins["late"].value
         miss = self._m_joins["miss"].value

@@ -98,7 +98,7 @@ def _engine_mode(scheduler) -> str:
 def make_segment_source(llm_tokenizer, max_bucket: int):
     """The chunk→prompt-segment token source handed to the store's sidecar.
 
-    A standalone closure ON PURPOSE: the store outlives services (the bench
+    A standalone closure ON PURPOSE: the store outlives services (a script
     reuses one store across engine configurations; production swaps services
     on reload), and attaching a BOUND METHOD would make the store retain the
     whole service → engine → params graph after teardown — measured as a
@@ -400,7 +400,7 @@ class RagService:
                 headroom_fn=self._lookahead_headroom,
                 index_gen_fn=lambda: self.store.ntotal,
                 # KV tiering: stats() folds the cache's swap-in counters
-                # into the swap-in hide rate the bench leg reports —
+                # into the swap-in hide rate that stats() reports —
                 # the FRESH reader, not the scrape memo (stats() callers
                 # expect current counters)
                 tier_stats_fn=self._pcache_tier_stats_fresh,

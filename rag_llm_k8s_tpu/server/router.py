@@ -73,7 +73,7 @@ class Replica:
     ``role``, ``healthy``, ``load``) so an HTTP handle can implement it
     without the router changing. ``breaker`` and ``admission`` are the
     replica's OWN resilience objects when it runs inside a service —
-    optional here so raw engine pairs (tests, benches) route too.
+    optional here so raw engine pairs (tests) route too.
     """
 
     def __init__(self, name: str, scheduler, breaker=None, admission=None):
@@ -141,7 +141,7 @@ class Router:
         self.config = config
         self.replicas: List[Replica] = list(replicas)
         # the tier-wide gate (PR 4): fair-share shedding for the whole
-        # fleet — None keeps the router standalone (tests, benches)
+        # fleet — None keeps the router standalone (tests)
         self.admission = admission
         self._lock = threading.Lock()
         # per-replica hot-chunk LRU: chunk key -> None, newest last;

@@ -20,8 +20,8 @@ The simulator emits a synthetic flight-schema journal — ``admit``,
 durations), ``complete`` — with virtual timestamps, so the existing
 renderers (``flightview --summary/--goodput``, ``goodput.render_report``)
 consume it unchanged. ``simulate()`` wraps trace → driver → report and
-measures the virtual-over-wall speedup (the ≥100× figure the
-``replay_fidelity`` bench leg pins).
+measures the virtual-over-wall speedup (≥100× is what
+``tests/test_replay.py`` holds it to).
 
 What the simulator models: the paged one-shot admission path (bucketed
 grouped prefill), fixed-horizon decode sync windows, block growth,
@@ -126,8 +126,8 @@ class CalibratedStepModel:
     ``goodput_window`` events: for each kind, a least-squares line
     ``dur_ms = a + b * tokens`` (collapsing to the kind's mean when the
     recording has no token spread). Simulating the recorded deployment
-    back through its own fit is the ``replay_fidelity`` bench leg's
-    steps/s check; changing the load against the same fit is the
+    back through its own fit is the fidelity check of
+    ``tests/test_replay.py``; changing the load against the same fit is the
     capacity-planning walkthrough in docs/REPLAY.md."""
 
     DEFAULT_MS = 1.0

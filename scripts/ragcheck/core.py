@@ -2,7 +2,7 @@
 
 Three layers, all stdlib:
 
-- :class:`Repo` parses the scan roots (the package + bench.py) once and
+- :class:`Repo` parses the scan roots (the package) once and
   hands every rule the same ASTs; cross-file rules can lazily pull any
   other repo file (tests/, docs/, deploy manifests) through the same cache.
 - Rules are objects with a stable ``id`` and a ``run(repo)`` generator of
@@ -95,7 +95,7 @@ class Repo:
     else a cross-file rule wants (tests, docs, manifests)."""
 
     #: default scan roots, repo-relative (directories walk ``**/*.py``)
-    SCAN_ROOTS: Tuple[str, ...] = ("rag_llm_k8s_tpu", "bench.py")
+    SCAN_ROOTS: Tuple[str, ...] = ("rag_llm_k8s_tpu",)
 
     def __init__(self, root: str, scan_roots: Optional[Sequence[str]] = None):
         self.root = os.path.abspath(root)

@@ -64,7 +64,7 @@ GREEDY = SamplingConfig(do_sample=False, max_new_tokens=6)
 # invariance is a property of trained attention (retrieved chunks attend
 # mostly within themselves), and a random-init model is its worst case
 # (measured 0.10–0.27 max-abs across seeds at boundary_tokens=4). The pin
-# bounds REGRESSION drift; the bench leg's fixed stream pins 0.15.
+# bounds REGRESSION drift; the shadow auditor's live tolerance is 0.15.
 LOGIT_TOL = 0.35
 
 CHUNK_PC = PrefixCacheConfig(

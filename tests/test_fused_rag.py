@@ -8,7 +8,9 @@ generation over either is identical; budget overflow drops trailing chunks
 sides; and the serving path pays ONE device→host fetch per solo query.
 """
 
+import sys
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -156,6 +158,79 @@ class TestGenerateRagMatchesHostAssembly:
             packed, toks_dev, lens_dev, n_chunks=1,
         )
         assert got == want
+
+
+class TestRagCompileOnce:
+    """``_get_rag_compiled`` after a cap-growing ingest: every thread that
+    misses a key together used to compile the same executable inside its
+    request and keep one. Builds are faked (a slow stand-in that counts):
+    what is under test is the guard around the build, not the compiler."""
+
+    KEY = dict(S=256, max_new=8, cap=64, Lc=32, LA=8, LB=16, n=3, kk=3, spec=False)
+
+    def _racing(self, engine, n_threads):
+        """n_threads ask for KEY at once; returns (results, errors)."""
+        results, errors = [], []
+        barrier = threading.Barrier(n_threads)
+
+        def ask():
+            barrier.wait(timeout=30)
+            try:
+                results.append(engine._get_rag_compiled(**self.KEY))
+            except RuntimeError as e:
+                errors.append(e)
+
+        threads = [threading.Thread(target=ask) for _ in range(n_threads)]
+        was = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(was)
+        assert not any(t.is_alive() for t in threads)
+        return results, errors
+
+    def test_concurrent_misses_build_each_key_once(self, monkeypatch):
+        _, engine = make_engine(speculative="auto")  # two variants a key
+        built = []
+
+        def slow_build(S, max_new, cap, Lc, LA, LB, n, kk, v):
+            built.append(v)
+            time.sleep(0.05)  # long enough for every other thread to miss
+            return ("executable", v, len(built))
+
+        monkeypatch.setattr(engine, "_build_generate_rag", slow_build)
+        compiles = []
+        monkeypatch.setattr(engine, "_record_compile", compiles.append)
+        results, errors = self._racing(engine, n_threads=16)
+        assert not errors
+        assert sorted(built) == [False, True]  # one build a variant, not 16
+        assert len(compiles) == 2
+        assert len(results) == 16 and len(set(results)) == 1 and results[0][1] is False
+        # and a later hit builds nothing
+        assert engine._get_rag_compiled(**self.KEY) == results[0] and len(built) == 2
+
+    def test_failed_build_releases_the_key(self, monkeypatch):
+        _, engine = make_engine()
+        calls = []
+
+        def flaky_build(S, max_new, cap, Lc, LA, LB, n, kk, v):
+            calls.append(v)
+            time.sleep(0.05)
+            if len(calls) == 1:
+                raise RuntimeError("compile failed")
+            return ("executable", len(calls))
+
+        monkeypatch.setattr(engine, "_build_generate_rag", flaky_build)
+        results, errors = self._racing(engine, n_threads=8)
+        # the builder's caller sees the failure; a waiter takes the key over,
+        # builds it once, and everyone else is served that build
+        assert len(errors) == 1 and len(calls) == 2
+        assert len(results) == 7 and set(results) == {("executable", 2)}
+        assert engine._get_rag_compiled(**self.KEY) == ("executable", 2) and len(calls) == 2
 
 
 class TestFusedService:

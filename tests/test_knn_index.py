@@ -216,6 +216,16 @@ class TestVectorStore:
         assert info["dimension"] == 8
         assert len(info["sample_chunks"]) == 5
 
+    @pytest.mark.parametrize("row, want", [(-1, 0), (10, 0), (3, 4)], ids=["negative", "len", "valid"])
+    def test_token_lengths_out_of_range_row_is_zero(self, row, want):
+        """A row id outside [0, len) has no tokens: -1 must not read the last
+        row's length (Python's negative index), as one past the end does not."""
+        store, _, _ = self._mk()
+        store.attach_token_source(lambda md: list(range(1 + int(md["chunk_id"]))))
+        store.token_snapshot()  # tokenizes every row: row i holds i + 1 tokens
+        assert store.token_lengths([9]) == [10]  # the last row is there to be misread
+        assert store.token_lengths([row]) == [want]
+
     def test_concurrent_adds_no_loss(self):
         """The race the reference has at rag.py:68-86: concurrent ingest must
         not lose vectors."""

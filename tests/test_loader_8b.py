@@ -11,12 +11,11 @@ full-depth run lives in scripts/validate_8b.py, results in docs/8B.md).
 
 import dataclasses
 import os
-import resource
 
 import jax
 import jax.numpy as jnp
+import loader_probe
 import numpy as np
-import psutil
 import pytest
 
 from rag_llm_k8s_tpu.core.config import DTypePolicy, LlamaConfig
@@ -37,7 +36,7 @@ def synth_dir(tmp_path_factory):
 
 
 class TestStreaming8B:
-    def test_tp_streamed_load_shapes_shardings_and_memory(self, synth_dir, mesh_tp8):
+    def test_tp_streamed_load_shapes_shardings_and_memory(self, synth_dir):
         """Stream the 4-shard checkpoint onto the 8-device mesh: every tensor
         must arrive TP-sharded at true 8B shapes in bf16, with transient host
         overhead bounded by a couple of single tensors — NOT the checkpoint
@@ -48,43 +47,31 @@ class TestStreaming8B:
         )
         assert ckpt_bytes > 2 * GB  # true-shape sanity: L=2 slice is ~3 GB
 
-        proc = psutil.Process()
-        # ru_maxrss is a process-LIFETIME high-water mark: snapshot it before
-        # the load so the assertion measures this load's transient, not
-        # whatever earlier tests in the same process peaked at
-        peak_before = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
-        put = make_streaming_put(mesh_tp8, dtype=jnp.bfloat16)
-        params = load_safetensors_params(
-            synth_dir, CFG_8B_L2, DTypePolicy(), put=put
-        )
-        rss_after = proc.memory_info().rss
-        peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+        # the load runs in a process of its own (tests/loader_probe.py says
+        # why): what it loaded, and what its host memory did, come back as facts
+        got = loader_probe.probe(synth_dir, "llama_3_1_8b", 2)
+        leaves = got["leaves"]
 
         # ---- geometry: stacked [L, ...] at true 8B shapes, bf16 ----------
         c = CFG_8B_L2
-        lay = params["layers"]
-        assert params["embedding"].shape == (c.vocab_size, c.hidden_size)
-        assert lay["attn"]["wq"]["kernel"].shape == (
+        assert leaves["embedding"]["shape"] == [c.vocab_size, c.hidden_size]
+        assert leaves["layers/attn/wq/kernel"]["shape"] == [
             2, c.hidden_size, c.num_heads * c.head_dim
-        )
-        assert lay["attn"]["wk"]["kernel"].shape == (
+        ]
+        assert leaves["layers/attn/wk/kernel"]["shape"] == [
             2, c.hidden_size, c.num_kv_heads * c.head_dim
-        )
-        assert lay["mlp"]["w_gate"]["kernel"].shape == (
+        ]
+        assert leaves["layers/mlp/w_gate/kernel"]["shape"] == [
             2, c.hidden_size, c.intermediate_size
-        )
-        assert params["lm_head"].shape == (c.hidden_size, c.vocab_size)
-        assert params["embedding"].dtype == jnp.bfloat16
-        assert lay["mlp"]["w_gate"]["kernel"].dtype == jnp.bfloat16
+        ]
+        assert leaves["lm_head"]["shape"] == [c.hidden_size, c.vocab_size]
+        assert leaves["embedding"]["dtype"] == "bfloat16"
+        assert leaves["layers/mlp/w_gate/kernel"]["dtype"] == "bfloat16"
 
         # ---- sharding: the big matmuls actually split over tp=8 ----------
-        for leaf in (
-            lay["attn"]["wq"]["kernel"],
-            lay["mlp"]["w_gate"]["kernel"],
-            params["lm_head"],
-        ):
-            shard_bytes = leaf.addressable_shards[0].data.nbytes
-            assert shard_bytes * 8 == leaf.nbytes, leaf.sharding
+        for name in ("layers/attn/wq/kernel", "layers/mlp/w_gate/kernel", "lm_head"):
+            leaf = leaves[name]
+            assert leaf["shard0_nbytes"] * 8 == leaf["nbytes"], (name, leaf["spec"])
 
         # ---- memory: transient overhead, not checkpoint-sized ------------
         # on the CPU mesh the PLACED params necessarily stay resident in
@@ -93,7 +80,8 @@ class TestStreaming8B:
         # a couple of vocab-sized tensors (embed read + lm_head transpose),
         # never the multi-GB whole-checkpoint spike from_pretrained makes.
         embed_bytes = c.vocab_size * c.hidden_size * 2
-        transient = peak - max(rss_after, peak_before)
+        transient = got["peak"] - max(got["rss_after"], got["peak_before"])
+        assert got["peak"] > got["peak_before"]  # the load is what set the high-water mark
         assert transient < 3 * embed_bytes + 512 * (1 << 20), (
             f"transient host overhead {transient / GB:.2f} GB suggests the "
             f"loader materialized more than a streamed group"

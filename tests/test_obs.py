@@ -93,7 +93,7 @@ class TestPrimitives:
         assert reg.histogram("rag_empty_seconds").quantile(0.5) is None
 
     def test_histogram_snapshot_diff_quantile(self):
-        """bench.py's per-pass windowing: quantile over a snapshot diff."""
+        """Windowing by subtraction (obs/slo.py): quantile over a snapshot diff."""
         reg = obs_metrics.MetricsRegistry()
         h = reg.histogram("rag_win_seconds", buckets=(1.0, 2.0, 4.0))
         h.observe(0.5)

@@ -290,7 +290,7 @@ class TestShardingContract:
         assert fs == []
 
     def test_token_returning_executables_are_exempt(self, tmp_path):
-        # regression (bench.py fwd): a value DERIVED from cache through a
+        # regression (a benchmark's fwd): a value DERIVED from cache through a
         # call is logits, not state — call results don't taint the return,
         # whether bound to a temp or returned inline
         fs = run_rule(tmp_path, ShardingContractRule, {
@@ -979,6 +979,12 @@ class TestWholeRepo:
             f.render() for f in new
         )
         assert stale == [], f"stale baseline entries (delete them): {stale}"
+
+    @pytest.mark.parametrize("scan_root", core.Repo.SCAN_ROOTS)
+    def test_every_scan_root_exists(self, scan_root):
+        """A scan root that is gone is skipped in silence (``Repo.__init__``
+        walks what it finds), so a deleted file would stay listed for ever."""
+        assert (REPO_ROOT / scan_root).exists()
 
     def test_grown_baseline_fails(self):
         _, findings = core.run_analysis(str(REPO_ROOT))

@@ -385,7 +385,7 @@ class TestReplaySmoke:
     def test_simulated_goodput_lands_in_band(self, params):
         """Simulate the recorded trace through a step model CALIBRATED
         on the recording: the simulator's busy chip-time must land
-        within ±25% of the recording's (the bench leg's fidelity band,
+        within ±25% of the recording's (the fidelity band of docs/REPLAY.md,
         measured here on the CPU engine's own journal)."""
         j1, _ = record(params, TRACE)
         trace = replay.extract_trace(j1)
@@ -448,9 +448,9 @@ class TestSimulator:
         assert rep["cost"]["chip_hour_usd"] == 3.2
 
     def test_faster_than_real_time(self):
-        """The acceptance floor: ≥100× virtual-over-wall speedup (the
-        bench leg reports the real figure; roofline-modeled TPU windows
-        against host dict math clears 100× with a wide margin)."""
+        """The acceptance floor: ≥100× virtual-over-wall speedup
+        (roofline-modeled TPU windows against host dict math clear 100×
+        with a wide margin)."""
         res = self._run(tracegen.generate(300, seed=31))
         assert not res["errors"]
         assert res["speedup_x"] >= 100, res["speedup_x"]

@@ -1,16 +1,10 @@
 """ISSUE 3 decision-layer tests: W3C traceparent propagation round-trips,
 burn-rate math against hand-computed fixtures, the fast-burn/slow-burn
-window split, the /slo endpoint, the bench regression gate, and bench.py's
-budget-truncation contract."""
+window split, the /slo endpoint and the device telemetry gauges."""
 
 import json
 import logging
-import os
 import re
-import signal
-import subprocess
-import sys
-import threading
 
 import jax
 import pytest
@@ -30,11 +24,9 @@ from rag_llm_k8s_tpu.models.bge_m3 import init_encoder_params
 from rag_llm_k8s_tpu.models.llama import init_llama_params
 from rag_llm_k8s_tpu.obs import logging as obs_logging
 from rag_llm_k8s_tpu.obs import metrics as obs_metrics
-from rag_llm_k8s_tpu.obs import regression
 from rag_llm_k8s_tpu.obs import slo as obs_slo
 from rag_llm_k8s_tpu.server.app import RagService, create_app
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FP32 = DTypePolicy.fp32()
 
 
@@ -551,271 +543,6 @@ class TestSloEndpoint:
         assert s["fast_burn"] is True
         assert s["burn_rate"]["6h"] < 6.0  # slow window still calm
         assert s["slow_burn"] is False
-
-
-# ---------------------------------------------------------------------------
-# bench regression gate
-# ---------------------------------------------------------------------------
-
-
-BASE_BENCH = {
-    "metric": "llama_1b_decode_throughput",
-    "value": 4000.0,
-    "unit": "tokens/sec/chip",
-    "vs_baseline": 1500.0,
-    "query_p50_ms": 800.0,
-    "query_p50_8b_ms": 1830.0,
-    "query_qps_load": 4.5,
-    "coalesce_tok_per_s": 1700.0,
-    "query_stage_ms": {"generate": 770.0, "embed_retrieve": 6.0},
-    "device_fetch_ms": 100.0,
-    "query_n": 20,
-    "spec_8b_identical": True,
-}
-
-
-class TestRegressionGate:
-    def test_self_comparison_is_clean(self):
-        out = regression.compare(BASE_BENCH, BASE_BENCH)
-        assert out["regression"] == [] and out["missing"] == []
-
-    def test_latency_up_flags(self):
-        cur = dict(BASE_BENCH, query_p50_ms=1200.0)  # +50% > 25% band
-        out = regression.compare(cur, BASE_BENCH)
-        assert [f.key for f in out["regression"]] == ["query_p50_ms"]
-
-    def test_latency_down_is_improvement_not_regression(self):
-        cur = dict(BASE_BENCH, query_p50_ms=400.0)
-        out = regression.compare(cur, BASE_BENCH)
-        assert out["regression"] == []
-        assert any(f.key == "query_p50_ms" for f in out["improvement"])
-
-    def test_throughput_down_flags_direction_aware(self):
-        cur = dict(BASE_BENCH, coalesce_tok_per_s=1000.0, query_qps_load=2.0)
-        keys = {f.key for f in regression.compare(cur, BASE_BENCH)["regression"]}
-        assert keys == {"coalesce_tok_per_s", "query_qps_load"}
-
-    def test_nested_stage_regression(self):
-        cur = json.loads(json.dumps(BASE_BENCH))
-        cur["query_stage_ms"]["generate"] = 2000.0
-        keys = {f.key for f in regression.compare(cur, BASE_BENCH)["regression"]}
-        assert keys == {"query_stage_ms.generate"}
-
-    def test_within_tolerance_passes(self):
-        cur = dict(BASE_BENCH, query_p50_ms=900.0)  # +12.5% < 25%
-        assert regression.compare(cur, BASE_BENCH)["regression"] == []
-        # but a tightened band catches it
-        out = regression.compare(cur, BASE_BENCH, tolerance=0.10)
-        assert [f.key for f in out["regression"]] == ["query_p50_ms"]
-
-    def test_ignored_keys_never_flag(self):
-        cur = dict(
-            BASE_BENCH, device_fetch_ms=900.0, query_n=3, spec_8b_identical=False
-        )
-        out = regression.compare(cur, BASE_BENCH)
-        assert out["regression"] == []
-
-    def test_missing_keys_reported_not_failed(self):
-        cur = {k: v for k, v in BASE_BENCH.items() if k != "query_p50_ms"}
-        out = regression.compare(cur, BASE_BENCH)
-        assert out["regression"] == []
-        assert [f.key for f in out["missing"]] == ["query_p50_ms"]
-
-    def test_schema_check(self):
-        assert regression.schema_check(BASE_BENCH) == []
-        assert regression.schema_check({"note": "strings only"})
-        assert regression.schema_check([1, 2])  # type: ignore[arg-type]
-
-    def test_headline_value_is_gated(self):
-        """'value' is the headline decode tok/s — a change that halves it
-        must fail the gate (it is NOT a config echo)."""
-        assert regression.classify("value") == "higher"
-        cur = dict(BASE_BENCH, value=2000.0)
-        keys = {f.key for f in regression.compare(cur, BASE_BENCH)["regression"]}
-        assert "value" in keys
-
-    def test_zero_overlap_is_detectable(self):
-        """Disjoint schemas share nothing comparable — the CLI treats that
-        as an error (rc 2), never a vacuous pass."""
-        assert regression.comparable_overlap(
-            {"alpha_ms": 1.0}, {"beta_ms": 2.0}
-        ) == []
-        assert "query_p50_ms" in regression.comparable_overlap(
-            BASE_BENCH, BASE_BENCH
-        )
-
-    def test_load_json_unwraps_driver_envelope(self, tmp_path):
-        """BENCH_r*.json artifacts wrap the bench line in {"parsed": ...};
-        load_json unwraps it so any committed round can be the baseline."""
-        p = tmp_path / "round.json"
-        p.write_text(json.dumps({"n": 3, "rc": 0, "parsed": BASE_BENCH}))
-        assert regression.load_json(str(p)) == BASE_BENCH
-        # a null parsed (the rc-124 artifacts) stays a wrapper — the CLI's
-        # zero-overlap guard then fails it loudly
-        p.write_text(json.dumps({"n": 5, "rc": 124, "parsed": None}))
-        assert regression.load_json(str(p))["rc"] == 124
-
-    def test_classify_real_bench_keys(self):
-        assert regression.classify("query_p50_load_adj_ms") == "lower"
-        assert regression.classify("knn_ms_100k") == "lower"
-        assert regression.classify("snapshot_save_s") == "lower"
-        assert regression.classify("decode_int8_tok_per_s.64") == "higher"
-        assert regression.classify("continuous_steps_per_s_sync16") == "higher"
-        assert regression.classify("prefill_mfu_b8") == "higher"
-        assert regression.classify("prefix_prefill_reduction") == "higher"
-        assert regression.classify("query_p50_target_ms") == "ignore"
-        assert regression.classify("query_8b_spec_verify_steps") == "ignore"
-        assert regression.classify("query_load_quant") == "ignore"
-
-    def test_fidelity_band_is_absolute(self):
-        """ISSUE 17 (docs/REPLAY.md): the replay simulator's fidelity
-        ratios are judged against the absolute 1.0 ± tolerance band —
-        drifting HIGH is exactly as wrong as drifting low, so the _per_s
-        higher-is-better rule must not swallow steps_per_s_ratio."""
-        assert regression.classify("replay_fidelity.steps_per_s_ratio") == "band"
-        assert regression.classify("replay_fidelity.cost_ratio") == "band"
-        base = dict(BASE_BENCH, replay_fidelity={"steps_per_s_ratio": 1.0})
-        for r in (0.8, 1.0, 1.2):  # inside the band: clean
-            cur = dict(BASE_BENCH, replay_fidelity={"steps_per_s_ratio": r})
-            assert regression.compare(cur, base)["regression"] == []
-        for r in (0.7, 1.4):  # outside: flagged in BOTH directions
-            cur = dict(BASE_BENCH, replay_fidelity={"steps_per_s_ratio": r})
-            keys = {f.key for f in regression.compare(cur, base)["regression"]}
-            assert keys == {"replay_fidelity.steps_per_s_ratio"}, r
-        # the band is absolute: an out-of-band baseline does not grant an
-        # out-of-band current a self-comparison pass
-        drifted = dict(BASE_BENCH, replay_fidelity={"steps_per_s_ratio": 1.4})
-        assert regression.compare(drifted, drifted)["regression"]
-
-
-class TestBenchGateCli:
-    def _run(self, *args):
-        return subprocess.run(
-            [sys.executable, os.path.join(REPO, "scripts", "bench_gate.py"), *args],
-            capture_output=True, text=True, timeout=120,
-        )
-
-    def test_baseline_vs_itself_exits_zero(self):
-        p = self._run()
-        assert p.returncode == 0, p.stderr
-
-    def test_injected_regression_exits_nonzero(self):
-        p = self._run(
-            "--current",
-            os.path.join(REPO, "tests", "fixtures", "bench_regression.json"),
-        )
-        assert p.returncode == 1, (p.stdout, p.stderr)
-        assert "REGRESSION" in p.stderr
-
-    def test_dry_run_schema_check(self):
-        p = self._run("--dry-run")
-        assert p.returncode == 0, p.stderr
-        assert "dry-run OK" in p.stdout
-
-    def test_unreadable_input_exits_two(self):
-        p = self._run("--current", "/nonexistent/bench.json")
-        assert p.returncode == 2
-
-    def test_disjoint_schemas_exit_two_not_ok(self, tmp_path):
-        """A current document sharing NO comparable keys with the baseline
-        must error (the gate would otherwise judge nothing and 'pass')."""
-        p = tmp_path / "other.json"
-        p.write_text(json.dumps({"totally_different_ms": 1.0}))
-        r = self._run("--current", str(p))
-        assert r.returncode == 2, (r.stdout, r.stderr)
-        assert "no comparable metrics" in r.stderr
-
-
-# ---------------------------------------------------------------------------
-# bench budget truncation (the round-5 capture lost its data to rc 124)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchBudget:
-    def test_truncated_run_emits_valid_partial_json(self, monkeypatch, capsys):
-        import bench
-
-        def fake_legs(line):
-            def ok():
-                line["query_p50_ms"] = 123.0
-
-            def boom():
-                raise bench.BenchBudgetExceeded("SIGTERM")
-
-            return [("fast", ok), ("slow", boom), ("never", lambda: None)]
-
-        monkeypatch.setattr(bench, "bench_legs", fake_legs)
-        old_term = signal.getsignal(signal.SIGTERM)
-        old_alrm = signal.getsignal(signal.SIGALRM)
-        try:
-            bench.main()
-        finally:
-            signal.signal(signal.SIGTERM, old_term)
-            signal.signal(signal.SIGALRM, old_alrm)
-            signal.alarm(0)
-        out = capsys.readouterr().out.strip().splitlines()[-1]
-        doc = json.loads(out)  # ALWAYS valid JSON — the contract
-        assert doc["truncated"] is True
-        assert doc["query_p50_ms"] == 123.0  # completed legs' data survives
-        assert doc["legs_completed"] == ["fast"]
-        assert doc["legs_skipped"] == ["slow", "never"]
-
-    def test_untruncated_run_has_no_marker(self, monkeypatch, capsys):
-        import bench
-
-        monkeypatch.setattr(
-            bench, "bench_legs",
-            lambda line: [("only", lambda: line.update({"x_ms": 1.0}))],
-        )
-        old_term = signal.getsignal(signal.SIGTERM)
-        old_alrm = signal.getsignal(signal.SIGALRM)
-        try:
-            bench.main()
-        finally:
-            # main() leaves TERM/ALRM ignored (emit protection) — restore
-            signal.signal(signal.SIGTERM, old_term)
-            signal.signal(signal.SIGALRM, old_alrm)
-            signal.alarm(0)
-        doc = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert "truncated" not in doc and doc["x_ms"] == 1.0
-
-    def test_budget_alarm_delivers_between_bytecodes(self):
-        """TPU_RAG_BENCH_BUDGET_S arms SIGALRM -> BenchBudgetExceeded in the
-        main thread; a compute loop is interrupted and the partial-emit
-        path runs. Subprocess: the alarm must not leak into pytest."""
-        code = (
-            "import os, time, json\n"
-            "os.environ['TPU_RAG_BENCH_BUDGET_S'] = '1'\n"
-            "import bench\n"
-            "assert bench.install_budget_guard() == '1'\n"
-            "try:\n"
-            "    t0 = time.monotonic()\n"
-            "    while time.monotonic() - t0 < 30:\n"
-            "        sum(range(1000))\n"
-            "    print(json.dumps({'interrupted': False}))\n"
-            "except bench.BenchBudgetExceeded as e:\n"
-            "    print(json.dumps({'interrupted': True, 'sig': str(e)}))\n"
-        )
-        p = subprocess.run(
-            [sys.executable, "-c", code], capture_output=True, text=True,
-            timeout=60, cwd=REPO,
-        )
-        assert p.returncode == 0, p.stderr
-        doc = json.loads(p.stdout.strip().splitlines()[-1])
-        assert doc == {"interrupted": True, "sig": "SIGALRM"}
-
-    def test_guard_is_noop_off_main_thread(self):
-        import bench
-
-        result = {}
-
-        def run():
-            result["guard"] = bench.install_budget_guard()
-
-        t = threading.Thread(target=run)
-        t.start()
-        t.join()
-        assert result["guard"] is None
 
 
 # ---------------------------------------------------------------------------

@@ -62,8 +62,8 @@ class TestExactness:
                 vanilla.generate([p], max_new_tokens=mn)[0], mn
 
     def test_zero_slack_cache_shape_stays_exact(self, setup):
-        """S + max_new an exact 128-multiple (the round-4 bench's own
-        shapes): without k slack slots, the last verify forwards' KV writes
+        """S + max_new an exact 128-multiple (the shapes of the round-4
+        capture): without k slack slots, the last verify forwards' KV writes
         would clamp-shift onto valid accepted KV and diverge near the
         budget. Repeat-heavy prompt drives acceptance right to the edge."""
         _, _, vanilla, spec = setup
