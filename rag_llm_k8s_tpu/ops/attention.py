@@ -2,18 +2,23 @@
 
 The torch path this replaces materializes full [S, S] attention matrices on
 CPU inside ``model.generate`` (/root/reference/llm/rag.py:172). Here the
-prefill attention runs blockwise: per (head, q-block), K/V blocks stream
-through VMEM while a running (max, sum, accumulator) softmax keeps memory at
-O(block²) — the flash-attention recurrence, written for the MXU/VPU split
+prefill attention runs blockwise: a KV head's K/V strip stays in VMEM (where
+it fits; its blocks are streamed where not) while the query blocks of its G
+query heads pass over it, each with a running (max, sum, accumulator)
+softmax — the flash-attention recurrence, written for the MXU/VPU split
 (matmuls on the MXU via ``jax.lax.dot_general`` with fp32 accumulation,
 renormalization on the VPU).
 
 Masking model matches the serving engine's left-padded batches: causal over
 global positions plus a per-row valid window ``[kv_start, kv_len)`` delivered
-through scalar prefetch (SMEM) — no [S, S] bias array ever exists.
+through scalar prefetch (SMEM) — no [S, S] bias array ever exists. The work
+follows the live causal triangle of each row (``flash_block_plan``): a query
+block visits the key blocks that hold a live pair and no others, and only
+the blocks a mask can touch pay for one.
 
-GQA is handled by index mapping: query head h reads K/V head ``h // G``
-directly from HBM; K/V are never repeated in memory.
+GQA is a fold: the G query heads of a KV head are G·bq rows of ONE matmul
+against that head's K/V block; K/V are read once a KV head and never
+repeated in memory.
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_NO_KEY = 2**31 - 1  # the position of a key no query may see
 
 
 def _fit_block(n: int, pref: int) -> int:
@@ -38,82 +44,307 @@ def _fit_block(n: int, pref: int) -> int:
     return b
 
 
+def flash_block_plan(qi, kv_start, kv_len, S: int, bq: int, bk: int, causal: bool):
+    """Which key blocks query block ``qi`` of a row visits, and which of them
+    no mask can touch: ``(lo, hi, int_lo, int_hi)``, every bound inclusive.
+
+    The live pairs of a row are ``kv_start <= k < kv_len`` and, under
+    ``causal``, ``k <= q``. Key blocks ``lo..hi`` are exactly those that hold
+    a live pair with a query of the block (none when ``hi < lo``: a query
+    block wholly in the left pad, or an empty window). Of those, blocks
+    ``int_lo..int_hi`` are INTERIOR: wholly inside the window and wholly
+    under the block's diagonal, so every pair in them is live; the others
+    (on the diagonal, or straddling ``kv_start`` or ``kv_len``) are EDGE
+    blocks. Integer arithmetic only, so it serves Python ints, numpy arrays
+    and the kernel's traced scalars alike: the kernel's loop bounds and the
+    tests read this one rule. ``S`` is the key length (bounds stay inside it)."""
+    nk = S // bk
+    q_lo, q_hi = qi * bq, qi * bq + bq - 1  # the block's first and last query
+    lo = jnp.maximum(kv_start // bk, 0)
+    hi = jnp.minimum((kv_len - 1) // bk, nk - 1)
+    int_lo = (kv_start + bk - 1) // bk
+    int_hi = kv_len // bk - 1
+    empty = kv_len <= kv_start
+    if causal:
+        hi = jnp.minimum(hi, q_hi // bk)
+        int_hi = jnp.minimum(int_hi, (q_lo + 1) // bk - 1)
+        empty = empty | (q_hi < kv_start)
+    hi = jnp.where(empty, lo - 1, hi)
+    return lo, hi, int_lo, int_hi
+
+
 def _flash_kernel(
     kv_start_ref,  # SMEM [B]
     kv_len_ref,  # SMEM [B]
-    q_ref,  # [1, bq, hd]
-    k_ref,  # [1, bk, hd]
-    v_ref,  # [1, bk, hd]
-    o_ref,  # [1, bq, hd]
-    m_scr,  # VMEM [bq, 1]
-    l_scr,  # VMEM [bq, 1]
-    acc_scr,  # VMEM [bq, hd]
+    q_ref,  # [G, bq, dq]: the G query heads of one KV head
+    k_ref,  # [1, Sk, dq]: that KV head's whole strip, resident across q blocks
+    v_ref,  # [1, Sk, dv]   (streamed: [1, bk, .], the block the grid step names)
+    o_ref,  # [G, bq, dv]
+    m_scr,  # VMEM [G*bq, 1]
+    l_scr,  # VMEM [G*bq, 128]: the sum a LANE, folded once at the end
+    acc_scr,  # VMEM [G*bq, dv]
     *,
+    sk: int,
     bq: int,
     bk: int,
+    wide: int,
     scale: float,
     causal: bool,
-    num_heads: int,
+    kv_heads: int,
+    resident: bool,
 ):
-    bh = pl.program_id(0)
+    G = q_ref.shape[0]
+    rows = G * bq
+    b = pl.program_id(0) // kv_heads
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
-    nk = pl.num_programs(2)
-    b = bh // num_heads
+    start, end = kv_start_ref[b], kv_len_ref[b]
+    lo, hi, int_lo, int_hi = flash_block_plan(qi, start, end, sk, bq, bk, causal)
 
-    @pl.when(kj == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    # the G heads fold into one matmul's rows: row r is (head r // bq, query
+    # qi*bq + r % bq) against ONE K/V block
+    q = q_ref[:].reshape(rows, q_ref.shape[2])
+    lanes = l_scr.shape[1]
+    if causal:
+        # row r is query r % bq: peeled off by G - 1 selects on one column
+        # (no vector division)
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        t = r
+        for g in range(1, G):
+            t = jnp.where(r >= g * bq, r - g * bq, t)
+        q_pos = qi * bq + t  # [rows, 1]
+    else:
+        # every live key is allowed: the mask stays a ROW of keys, and never
+        # becomes a [rows, width] compare (the encoder's hd 64 is VPU-bound)
+        q_pos = _NO_KEY - 1
 
-    # block skip: fully-masked K blocks do no work — strictly above the
-    # causal diagonal (halves causal prefill), entirely inside the left-pad
-    # region (< kv_start), or entirely past the valid frontier (>= kv_len)
-    overlap = (kj * bk + bk > kv_start_ref[b]) & (kj * bk < kv_len_ref[b])
-    live = (overlap & (kj * bk <= qi * bq + bq - 1)) if causal else overlap
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        # zero K/V rows outside the valid window BEFORE any matmul: cache
-        # slots past the frontier may be uninitialized device memory, and a
-        # NaN there survives even a zero-weight product (0 * NaN = NaN)
-        cpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-        cok = (cpos >= kv_start_ref[b]) & (cpos < kv_len_ref[b])
-        k = jnp.where(cok, k, 0)
-        v = jnp.where(cok, v, 0)
+    def block(kj, n: int, masked: bool, first: bool = False):
+        """Key blocks ``kj .. kj + n - 1`` into the running softmax, as one
+        step. ``first``: the state is still empty, so nothing is rescaled."""
+        width = n * bk
+        off = pl.multiple_of(kj * bk, bk)
+        at = off if resident else 0  # streamed: the block IS the ref
+        k = k_ref[0, pl.ds(at, width), :]
+        v = v_ref[0, pl.ds(at, width), :]
+        if masked:
+            # zero K/V rows outside the valid window BEFORE any matmul: cache
+            # slots past the frontier may be uninitialized device memory, and a
+            # NaN there survives even a zero-weight product (0 * NaN = NaN)
+            cpos = off + jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
+            cok = (cpos >= start) & (cpos < end)
+            k = jnp.where(cok, k, 0)
+            v = jnp.where(cok, v, 0)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk]
+        ) * scale  # [rows, width]
+        if masked:
+            # a key outside the window sits past every query: the window and
+            # the diagonal are then ONE compare over the block, of a row of
+            # key positions against a column of query positions
+            k_pos = off + jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+            k_pos = jnp.where((k_pos >= start) & (k_pos < end), k_pos, _NO_KEY)
+            ok = k_pos <= q_pos  # [rows, width] under ``causal``, else [1, width]
+            s = jnp.where(ok, s, NEG_INF)
 
-        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        k_pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        ok = (k_pos >= kv_start_ref[b]) & (k_pos < kv_len_ref[b])
-        if causal:
-            ok = ok & (k_pos <= q_pos)
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev = m_scr[:]  # [bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # explicit zero for masked entries: when a whole row is masked both s
-        # and m_new sit at NEG_INF and exp(s - m_new) would be 1, polluting
-        # l/acc with mean(V); the mask multiply makes such rows emit zeros
-        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)  # [bq, bk]
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        m_new = jnp.max(s, axis=1, keepdims=True)  # [rows, 1]
+        if not first:
+            m_prev = m_scr[:]
+            m_new = jnp.maximum(m_prev, m_new)
+            alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)  # [rows, width]
+        if masked:
+            # explicit zero for masked entries: when a whole row is masked both
+            # s and m_new sit at NEG_INF and exp(s - m_new) would be 1,
+            # polluting l/acc with mean(V); such rows emit zeros
+            p = jnp.where(ok, p, 0.0)
+        # the row sum stays a sum a lane (whole-vreg adds) until the end: one
+        # cross-lane reduction a query block, not one a key block
+        if width % lanes == 0:
+            part = p[:, :lanes]
+            for c in range(1, width // lanes):
+                part = part + p[:, c * lanes:(c + 1) * lanes]
+        else:  # blocks under a vreg's lanes (tests, tiny shapes): lane 0 holds it
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+            part = jnp.where(col == 0, jnp.sum(p, axis=1, keepdims=True), 0.0)
+        pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        l_scr[:] = part if first else l_scr[:] * alpha + part
+        acc_scr[:] = pv if first else acc_scr[:] * alpha + pv
         m_scr[:] = m_new
 
-    @pl.when(kj == nk - 1)
-    def _emit():
-        l = jnp.maximum(l_scr[:], 1e-30)  # fully-masked rows -> 0, not NaN
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+    def emit():
+        @pl.when(hi < lo)
+        def _dead():  # wholly in the left pad (or an empty window): zeros
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        @pl.when(hi >= lo)
+        def _live():
+            l = jnp.sum(l_scr[:], axis=1, keepdims=True)
+            l = jnp.maximum(l, 1e-30)  # fully-masked rows -> 0, not NaN
+            out = (acc_scr[:] / l).astype(o_ref.dtype)
+            for g in range(G):
+                o_ref[g] = out[g * bq:(g + 1) * bq]
+
+    # which body a block takes is a matter of scalars. The first block visited
+    # finds the state empty and goes under the mask (it holds ``kv_start``
+    # behind a left pad, and where it holds no masked pair the mask changes
+    # nothing). Of the rest, interior blocks hold no masked pair: no iota, no
+    # compare, no select; edge blocks keep the mask.
+    last_int = jnp.minimum(int_hi, hi)
+    first_int = jnp.maximum(int_lo, lo + 1)
+    n_int = jnp.maximum(last_int - first_int + 1, 0)
+
+    if not resident:
+        # one key block a grid step: step j holds block lo + j, and the steps
+        # past ``hi`` re-name the block already there (``_flash_call``'s index
+        # map), so they fetch nothing and do nothing
+        j = pl.program_id(2)
+        kj = lo + j
+        interior = (kj >= first_int) & (kj <= last_int)
+        pl.when((j == 0) & (hi >= lo))(lambda: block(kj, 1, True, first=True))
+        pl.when((j > 0) & (kj <= hi) & interior)(lambda: block(kj, 1, False))
+        pl.when((j > 0) & (kj <= hi) & ~interior)(lambda: block(kj, 1, True))
+        pl.when(j == pl.num_programs(2) - 1)(emit)
+        return
+
+    # the strip is resident: an inner loop with the plan's bounds. ``wide``
+    # interior blocks in a row go as ONE step, because what a step costs is
+    # mostly its bookkeeping a row (max, rescale), not its keys; the odd ones
+    # left go one by one, then the edge blocks behind them (the diagonal, the
+    # block that straddles ``kv_len``).
+    odd = first_int + n_int // wide * wide  # the interior blocks no wide step takes
+
+    def step(at, n: int, masked: bool):
+        def body(t, carry):
+            block(at + t * n, n, masked)
+            return carry
+
+        return body
+
+    @pl.when(hi >= lo)
+    def _visit():
+        block(lo, 1, True, first=True)
+        # (an edge block between ``lo`` and the interior cannot be: ``int_lo``
+        # is ``lo`` or ``lo + 1``)
+        jax.lax.fori_loop(0, n_int // wide, step(first_int, wide, False), 0)
+        if wide > 1:
+            jax.lax.fori_loop(0, first_int + n_int - odd, step(odd, 1, False), 0)
+        jax.lax.fori_loop(0, hi + 1 - (first_int + n_int), step(first_int + n_int, 1, True), 0)
+
+    emit()
+
+
+FLASH_WIDE = 2  # interior key blocks a step of the causal flash prefill kernels
+_FLASH_VMEM = 31 * 2**19  # what a kernel's blocks may hold of the 16 MiB scoped limit
+
+
+def _flash_fits(S: int, rows: int, bk: int, wide: int, dq: int, dv: int, itemsize: int) -> bool:
+    """Whether a KV head's K/V strips (twice: the pipeline's buffers) fit
+    VMEM beside a query block of ``rows`` = G·bq rows. A row holds its q, o
+    and accumulators (≈ 1.25 KB) and a step's scores and probabilities: 12
+    bytes a key of a masked block, 7.25 of an unmasked step of ``wide``
+    blocks (as compiled for a v5e: 1024 rows of 1024 masked keys fit beside
+    strips of 2 MiB and not of 3)."""
+    pad = lambda d: -(-d // 128) * 128  # noqa: E731 — lanes of a VMEM tile
+    row = 1280 + bk * max(12, 7.25 * wide)
+    return 2 * itemsize * S * (pad(dq) + pad(dv)) + row * rows <= _FLASH_VMEM
+
+
+def flash_blocks(S: int, G: int, dq: int, dv: int, causal: bool = True, itemsize: int = 2) -> Tuple[int, int]:
+    """The default ``(bq, bk)`` of the flash prefill kernels, from the shape
+    alone (no option, no model's name).
+
+    Swept on a v5e (PR 30; PERF.md §6 has the tables). What a step of the key
+    loop costs is mostly its bookkeeping a ROW (running max, rescaling sum
+    and accumulator through VMEM: ≈ 1.2 µs a step at 1024 rows, whatever the
+    keys), so a step takes 1024 keys and the G-fold keeps G·bq = 1024 rows a
+    step (bq 256 at G = 4). Under ``causal`` the 1024 keys are ``FLASH_WIDE``
+    interior blocks of ``bk`` = 512, so that the blocks a mask can touch are
+    512 wide, and bq stops at 512: a taller query block only widens the
+    diagonal's waste (MLA, G = 1: 1024 × 512 lost 11% to 512 × 512). Finer
+    blocks LOSE: 256 × 256 by 12%, bq 128 by 8%, one block a step by 8%; the
+    1024 × 1024 this replaces (a per-head grid that masked every block) was
+    60% slower at the serving prefill. Without a diagonal (the encoder) there
+    is nothing to cut finer: ONE block of 1024 keys a step, which is also the
+    order the kernel before PR 30 summed in, so an index's embeddings stay
+    what they were (7 in a million outputs differ, in bf16's last place).
+
+    The K/V strips of a KV head are resident, so a long sequence or a wide
+    head leaves less for the query block: bq halves while that lets the strips
+    fit. Where they do not fit beside 256 rows (S ≥ 16384 at hd 128) the
+    K/V blocks are streamed a grid step (``_flash_call``) and bq stays."""
+    bk = _fit_block(S, 512 if causal else 1024)
+    wide = FLASH_WIDE if causal else 1
+    full = min(512, 1024 // G) if causal else 1024
+    bq = full
+    while G * bq > 256 and not _flash_fits(S, G * bq, bk, wide, dq, dv, itemsize):
+        bq //= 2
+    if not _flash_fits(S, G * bq, bk, wide, dq, dv, itemsize):
+        bq = full
+    return _fit_block(S, bq), bk
+
+
+def _flash_call(qt, kt, vt, kv_start, kv_len, *, scale, causal, bq, bk, interpret, name, resident=None):
+    """The one flash prefill ``pallas_call``: ``qt [B*H, Sq, dq]`` against
+    ``kt [B*K, Sk, dq]`` / ``vt [B*K, Sk, dv]`` (the G = H // K query heads of
+    a KV head are consecutive rows of ``qt``); returns ``[B*H, Sq, dv]``.
+    ``bq`` / ``bk`` left ``None`` come from ``flash_blocks``. ``resident``
+    (``None``: where they fit) keeps a KV head's K/V strips in VMEM across
+    its query blocks; otherwise the grid gains a key-block axis whose index
+    map is clamped into the plan's ``lo..hi``, so any length runs."""
+    BH, Sq, dq = qt.shape
+    BK, Sk, dv = vt.shape
+    G = BH // BK
+    kv_heads = BK // kv_start.shape[0]
+    wide = FLASH_WIDE if causal else 1
+    rule_bq, rule_bk = flash_blocks(max(Sq, Sk), G, dq, dv, causal, kt.dtype.itemsize)
+    bq = _fit_block(Sq, bq or rule_bq)
+    if resident is None:
+        resident = _flash_fits(Sk, G * bq, min(bk or rule_bk, Sk), wide, dq, dv, kt.dtype.itemsize)
+    # streamed, a step is one block: it takes a wide step's keys
+    bk = _fit_block(Sk, bk or (rule_bk if resident else wide * rule_bk))
+
+    if resident:
+        grid, kv_block = (BK, Sq // bq), Sk
+
+        def kv_index(h, qi, *s_):
+            return (h, 0, 0)
+    else:
+        grid, kv_block = (BK, Sq // bq, Sk // bk), bk
+
+        def kv_index(h, qi, j, start_ref, len_ref):
+            b = h // kv_heads
+            lo, hi, _, _ = flash_block_plan(qi, start_ref[b], len_ref[b], Sk, bq, bk, causal)
+            return (h, jnp.minimum(lo + j, jnp.clip(hi, lo, Sk // bk - 1)), 0)
+
+    def q_index(h, qi, *s_):
+        return (h, qi, 0)
+
+    return pl.pallas_call(
+        functools.partial(
+            _flash_kernel, sk=Sk, bq=bq, bk=bk, wide=min(wide, Sk // bk),
+            scale=scale, causal=causal, kv_heads=kv_heads, resident=resident,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((G, bq, dq), q_index),
+                pl.BlockSpec((1, kv_block, dq), kv_index),
+                pl.BlockSpec((1, kv_block, dv), kv_index),
+            ],
+            out_specs=pl.BlockSpec((G, bq, dv), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((G * bq, 1), jnp.float32),
+                pltpu.VMEM((G * bq, 128), jnp.float32),
+                pltpu.VMEM((G * bq, dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((BH, Sq, dv), qt.dtype),
+        interpret=interpret,
+        name=name,
+    )(kv_start.astype(jnp.int32), kv_len.astype(jnp.int32), qt, kt, vt)
 
 
 @functools.partial(
@@ -126,25 +357,17 @@ def flash_attention(
     kv_start: Optional[jax.Array] = None,  # [B] int32 (left-pad offset)
     kv_len: Optional[jax.Array] = None,  # [B] int32 (valid frontier)
     causal: bool = True,
-    bq: int = 1024,
-    bk: int = 1024,
+    bq: Optional[int] = None,
+    bk: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Blockwise fused attention; returns ``[B, Sq, H, hd]`` in q's dtype.
-
-    Default blocks are deliberately coarse (1024×1024): the TPU grid runs
-    sequentially, so per-step overhead is amortized by doing more MXU work
-    per step. Swept on v5e at the 4096-token serving prefill: 1024×1024
-    beats the earlier 256×512 by 36-40% (the [bq, bk] fp32 score/prob
-    temporaries dominate VMEM at ~4 MB each — 2048-wide blocks overflow the
-    16 MB scoped limit and fail to compile). Blocks shrink (halving) until
-    they tile the sequence exactly, so any power-of-two length works."""
+    ``bq`` / ``bk`` default to ``flash_blocks``' rule on the shape; blocks
+    shrink (halving) until they tile the sequence exactly, and a sequence
+    whose K/V strips outgrow VMEM has its key blocks streamed
+    (``_flash_call``), so any power-of-two length works."""
     B, Sq, H, hd = q.shape
     _, Sk, K, _ = k.shape
-    G = H // K
-    bq = _fit_block(Sq, bq)
-    bk = _fit_block(Sk, bk)
-    assert Sq % bq == 0 and Sk % bk == 0, (Sq, bq, Sk, bk)
     if kv_start is None:
         kv_start = jnp.zeros((B,), jnp.int32)
     if kv_len is None:
@@ -154,41 +377,10 @@ def flash_attention(
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, hd)
     kt = k.transpose(0, 2, 1, 3).reshape(B * K, Sk, hd)
     vt = v.transpose(0, 2, 1, 3).reshape(B * K, Sk, hd)
-
-    grid = (B * H, Sq // bq, Sk // bk)
-
-    def kv_index(bh, qi, kj, *scalar_refs):
-        return ((bh // H) * K + (bh % H) // G, kj, 0)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _flash_kernel,
-            bq=bq,
-            bk=bk,
-            scale=hd**-0.5,
-            causal=causal,
-            num_heads=H,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bq, hd), lambda bh, qi, kj, *s_: (bh, qi, 0)),
-                pl.BlockSpec((1, bk, hd), kv_index),
-                pl.BlockSpec((1, bk, hd), kv_index),
-            ],
-            out_specs=pl.BlockSpec((1, bq, hd), lambda bh, qi, kj, *s_: (bh, qi, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
-        interpret=interpret,
-        name="flash_attention",
-    )(kv_start.astype(jnp.int32), kv_len.astype(jnp.int32), qt, kt, vt)
-
+    out = _flash_call(
+        qt, kt, vt, kv_start, kv_len, scale=hd**-0.5, causal=causal,
+        bq=bq, bk=bk, interpret=interpret, name="flash_attention",
+    )
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
 
 

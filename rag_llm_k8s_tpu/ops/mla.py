@@ -26,13 +26,14 @@ valid cache slots, causal over slots on top.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from rag_llm_k8s_tpu.ops.attention import NEG_INF, _decode_block, _fit_block, _flash_kernel
+from rag_llm_k8s_tpu.ops.attention import NEG_INF, _decode_block, _flash_call
 
 # a dense score plane is [B, H, S, T] fp32: queries beyond this many go
 # through it a block at a time
@@ -53,41 +54,24 @@ def mla_flash_attention(
     kv_len: jax.Array,  # [B] int32
     *,
     scale: float,
-    bq: int = 1024,
-    bk: int = 1024,
+    bq: Optional[int] = None,
+    bk: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Causal flash attention with a key width that differs from the value
     width, and a caller-given softmax scale (YaRN's correction rides in it).
-    Returns ``[B, S, H, dv]``. The kernel body is ``ops/attention.py``'s
-    ``_flash_kernel``: it takes its widths from the blocks it is handed."""
+    Returns ``[B, S, H, dv]``. The kernel is ``ops/attention.py``'s
+    ``_flash_kernel`` with one head a group: it takes its widths from the
+    blocks it is handed, and its default blocks from the same rule."""
     B, S, H, dq = q.shape
     dv = v.shape[-1]
-    bq, bk = _fit_block(S, bq), _fit_block(S, bk)
     qt = q.transpose(0, 2, 1, 3).reshape(B * H, S, dq)
     kt = k.transpose(0, 2, 1, 3).reshape(B * H, S, dq)
     vt = v.transpose(0, 2, 1, 3).reshape(B * H, S, dv)
-    out = pl.pallas_call(
-        functools.partial(_flash_kernel, bq=bq, bk=bk, scale=scale, causal=True, num_heads=H),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B * H, S // bq, S // bk),
-            in_specs=[
-                pl.BlockSpec((1, bq, dq), lambda bh, qi, kj, *s_: (bh, qi, 0)),
-                pl.BlockSpec((1, bk, dq), lambda bh, qi, kj, *s_: (bh, kj, 0)),
-                pl.BlockSpec((1, bk, dv), lambda bh, qi, kj, *s_: (bh, kj, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bq, dv), lambda bh, qi, kj, *s_: (bh, qi, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, dv), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, dv), q.dtype),
-        interpret=interpret,
-        name="mla_flash_attention",
-    )(kv_start.astype(jnp.int32), kv_len.astype(jnp.int32), qt, kt, vt)
+    out = _flash_call(
+        qt, kt, vt, kv_start, kv_len, scale=scale, causal=True,
+        bq=bq, bk=bk, interpret=interpret, name="mla_flash_attention",
+    )
     return out.reshape(B, H, S, dv).transpose(0, 2, 1, 3)
 
 

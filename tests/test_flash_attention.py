@@ -5,7 +5,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from rag_llm_k8s_tpu.ops.attention import attention_xla, flash_attention
+from rag_llm_k8s_tpu.ops.attention import (
+    attention_xla,
+    flash_attention,
+    _flash_call,
+    _flash_fits,
+    flash_block_plan,
+    flash_blocks,
+)
 
 
 def _problem(seed, B=2, S=256, H=4, K=2, hd=64, dtype=jnp.float32):
@@ -57,6 +64,170 @@ class TestFlashAttention:
         got = flash_attention(q, k, v, causal=True, bq=32, bk=128, interpret=True)
         want = attention_xla(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5)
+
+
+def _flash_streamed(q, k, v, kv_start, kv_len, causal, bq, bk, interpret):
+    """``flash_attention`` with the K/V blocks streamed a grid step, as a
+    sequence too long for resident strips gets them."""
+    B, S, H, hd = q.shape
+    K = k.shape[2]
+    lay = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, S, hd)  # noqa: E731
+    out = _flash_call(lay(q), lay(k), lay(v), kv_start, kv_len, scale=hd**-0.5, causal=causal,
+                      bq=bq, bk=bk, interpret=interpret, name="flash_attention", resident=False)
+    return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
+
+
+def _valid_rows(q, kv_start, causal):
+    """Query rows the engine reads: behind the left pad (under ``causal`` a
+    pad row has no live key and both sides emit what they emit)."""
+    S = q.shape[1]
+    if not causal:
+        return jnp.ones((q.shape[0], S, 1, 1), bool)
+    return (jnp.arange(S)[None, :] >= kv_start[:, None])[:, :, None, None]
+
+
+class TestFlashBlockPlan:
+    """``flash_block_plan`` against the dense mask: the kernel's loop bounds
+    are this function's, so what it skips must be dead and what it calls
+    interior must need no mask."""
+
+    S = 1024
+
+    @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+    @pytest.mark.parametrize("bq,bk", [(128, 256), (256, 128)])
+    @pytest.mark.parametrize("frontier", ["one", "mid", "S"])
+    @pytest.mark.parametrize("start", ["0", "1", "bk-1", "bk", "402", "950", "S-1"])
+    def test_plan_matches_dense_mask(self, start, frontier, bq, bk, causal):
+        S = self.S
+        kv_start = {"0": 0, "1": 1, "bk-1": bk - 1, "bk": bk, "402": 402, "950": 950, "S-1": S - 1}[start]
+        kv_len = {"one": kv_start + 1, "mid": min(S, kv_start + (S - kv_start) // 2 + 37), "S": S}[frontier]
+        kv_len = max(kv_len, kv_start + 1)
+        pos = np.arange(S)
+        live = (pos[None, :] >= kv_start) & (pos[None, :] < kv_len)
+        live = np.broadcast_to(live, (S, S)).copy()
+        if causal:
+            live &= pos[None, :] <= pos[:, None]
+        for qi in range(S // bq):
+            lo, hi, int_lo, int_hi = (int(x) for x in flash_block_plan(qi, kv_start, kv_len, S, bq, bk, causal))
+            for kj in range(S // bk):
+                tile = live[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+                visited = lo <= kj <= hi
+                assert visited == bool(tile.any()), (qi, kj, lo, hi)
+                if visited and int_lo <= kj <= int_hi:
+                    assert tile.all(), (qi, kj, "interior block with a masked pair")
+                if visited and tile.all() and kj != lo:
+                    # nothing forces it, but a fully live block called edge is wasted masking
+                    assert int_lo <= kj <= int_hi, (qi, kj, int_lo, int_hi)
+
+    def test_empty_window_visits_nothing(self):
+        for causal in (True, False):
+            lo, hi, _, _ = flash_block_plan(1, 300, 300, 1024, 256, 256, causal)
+            assert int(hi) < int(lo)
+
+    def test_default_blocks_by_shape(self):
+        """The rule reads shapes only: 1024 folded rows and 1024 keys a step,
+        the keys cut in two blocks and bq capped at 512 under a diagonal, and
+        a smaller query block when the resident K/V strips are large."""
+        assert flash_blocks(4096, 4, 128, 128) == (256, 512)  # Mistral, Llama-3.1-8B
+        assert flash_blocks(4096, 1, 192, 128) == (512, 512)  # the MLA expanded form
+        assert flash_blocks(1536, 1, 64, 64, causal=False) == (512, 512)  # the encoder's snug bucket
+        assert flash_blocks(2048, 1, 64, 64, causal=False) == (1024, 1024)  # no diagonal: 1024 keys ONE block
+        assert flash_blocks(3072, 1, 64, 64, causal=False) == (512, 1024)  # strips of 3 MiB: half the rows
+        assert flash_blocks(8192, 4, 128, 128)[0] == 128  # strips of 8 MiB: half the rows
+        assert flash_blocks(64, 2, 16, 16) == (64, 64)  # blocks never exceed the sequence
+
+    @pytest.mark.parametrize("S,G,dq,dv,fits", [
+        (4096, 4, 128, 128, True), (8192, 4, 128, 128, True), (8192, 1, 192, 128, True),
+        (16384, 4, 128, 128, False), (16384, 1, 192, 128, False), (131072, 4, 128, 128, False),
+    ])
+    def test_long_sequences_stream_their_keys(self, S, G, dq, dv, fits):
+        """Where a KV head's strips leave no room in VMEM the rule keeps its
+        full query block and ``_flash_call`` streams the key blocks."""
+        bq, bk = flash_blocks(S, G, dq, dv)
+        assert _flash_fits(S, G * bq, bk, 2, dq, dv, 2) == fits
+        if not fits:
+            assert (bq, bk) == (min(512, 1024 // G), 512)
+
+
+class TestFlashLiveTriangle:
+    """The kernel's visits follow the plan: parity with the dense oracle where
+    rows of one batch disagree about which blocks are edge blocks."""
+
+    def _check(self, q, k, v, kv_start, kv_len, causal, streamed=False, **blocks):
+        attend = _flash_streamed if streamed else flash_attention
+        got = attend(q, k, v, kv_start=kv_start, kv_len=kv_len, causal=causal, interpret=True, **blocks)
+        want = attention_xla(q, k, v, kv_start=kv_start, kv_len=kv_len, causal=causal)
+        assert not bool(jnp.any(jnp.isnan(got)))
+        m = _valid_rows(q, kv_start, causal)
+        np.testing.assert_allclose(
+            np.asarray(jnp.where(m, got, 0)), np.asarray(jnp.where(m, want, 0)),
+            rtol=2e-4, atol=2e-5,
+        )
+        return got
+
+    @pytest.mark.parametrize("streamed", [False, True], ids=["resident", "streamed"])
+    @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+    @pytest.mark.parametrize("G", [1, 4])
+    def test_rows_with_different_windows(self, G, causal, streamed):
+        """Row 0 is unpadded (block 1 is interior for it), row 1's window
+        starts inside block 1 (an edge block there), row 2 is all pad but one
+        slot, row 3's frontier cuts a block; wide steps and single ones, over
+        a resident strip and over key blocks streamed a grid step."""
+        q, k, v = _problem(5 + G, B=4, S=512, H=2 * G, K=2, hd=32)
+        kv_start = jnp.array([0, 100, 300, 64], jnp.int32)
+        kv_len = jnp.array([512, 512, 301, 333], jnp.int32)
+        self._check(q, k, v, kv_start, kv_len, causal, streamed, bq=64, bk=64)
+        self._check(q, k, v, kv_start, kv_len, causal, streamed, bq=128, bk=32)
+
+    @pytest.mark.parametrize("streamed", [False, True], ids=["resident", "streamed"])
+    @pytest.mark.parametrize("G", [1, 4])
+    def test_single_valid_slot(self, G, streamed):
+        """A row that is all pad but its last slot: one live pair."""
+        q, k, v = _problem(9, B=2, S=256, H=2 * G, K=2, hd=32)
+        kv_start = jnp.array([255, 17], jnp.int32)
+        got = self._check(q, k, v, kv_start, jnp.array([256, 256], jnp.int32), True, streamed, bq=64, bk=64)
+        # the one live query attends its own slot alone: v of that slot
+        np.testing.assert_allclose(
+            np.asarray(got[0, 255].reshape(2, G, 32)), np.asarray(jnp.broadcast_to(v[0, 255][:, None], (2, G, 32))),
+            rtol=2e-4, atol=2e-5,
+        )
+
+    @pytest.mark.parametrize("streamed", [False, True], ids=["resident", "streamed"])
+    @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+    @pytest.mark.parametrize("G", [1, 4])
+    def test_nan_outside_the_window_does_not_poison(self, G, causal, streamed):
+        """K/V rows in the left pad and past the frontier can hold anything
+        (uninitialized device memory): a NaN there must not reach a live row,
+        in an edge block (zeroed under the mask) or by a skipped block."""
+        q, k, v = _problem(11, B=2, S=256, H=2 * G, K=2, hd=32)
+        kv_start = jnp.array([70, 0], jnp.int32)
+        kv_len = jnp.array([256, 150], jnp.int32)
+        t = jnp.arange(256)[None, :, None, None]
+        outside = (t < kv_start[:, None, None, None]) | (t >= kv_len[:, None, None, None])
+        attend = _flash_streamed if streamed else flash_attention
+        got = attend(q, jnp.where(outside, jnp.nan, k), jnp.where(outside, jnp.nan, v),
+                     kv_start=kv_start, kv_len=kv_len, causal=causal, bq=64, bk=64, interpret=True)
+        assert not bool(jnp.any(jnp.isnan(got))), "NaN leaked from outside the window"
+        want = attention_xla(q, k, v, kv_start=kv_start, kv_len=kv_len, causal=causal)
+        m = _valid_rows(q, kv_start, causal)
+        np.testing.assert_allclose(
+            np.asarray(jnp.where(m, got, 0)), np.asarray(jnp.where(m, want, 0)), rtol=2e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("streamed", [False, True], ids=["resident", "streamed"])
+    def test_pad_query_blocks_are_zero(self, streamed):
+        """A query block wholly in the left pad visits nothing and writes zeros."""
+        q, k, v = _problem(13, B=1, S=256, H=4, K=2, hd=32)
+        attend = _flash_streamed if streamed else flash_attention
+        got = attend(q, k, v, kv_start=jnp.array([130], jnp.int32), kv_len=jnp.array([256], jnp.int32),
+                     causal=True, bq=64, bk=64, interpret=True)
+        assert float(jnp.max(jnp.abs(got[0, :130]))) == 0.0
+
+    @pytest.mark.parametrize("S,kv_len", [(1536, 1536), (1536, 1100), (2048, 1999), (192, 64)])
+    def test_default_blocks_at_an_encoder_row(self, S, kv_len):
+        """Not causal: the rule's blocks of 1024 keys (512 at 1536), one a
+        step, on rows padded on the right."""
+        q, k, v = _problem(17, B=2, S=S, H=2, K=2, hd=16)
+        self._check(q, k, v, jnp.zeros((2,), jnp.int32), jnp.array([kv_len, S], jnp.int32), False)
 
 
 class TestDecodeAttention:

@@ -193,6 +193,24 @@ def test_absorbed_equals_expanded(impl):
         np.testing.assert_allclose(np.asarray(one[:, 0]), np.asarray(o_lat[:, last]), atol=2e-5)
 
 
+@pytest.mark.parametrize("kv_start", [(0, 37), (402, 100), (255, 256)], ids=["first-block", "402", "block-edge"])
+def test_mla_flash_attention_at_the_published_head_widths(kv_start):
+    """The flash prefill at key width 192 (128 nope + 64 rope) against value
+    width 128, one head a group, windows that start inside the first block, at
+    a block's edge and beyond it: the kernel's visits follow each row's own
+    triangle, and the caller's scale (YaRN's correction) is applied in float32."""
+    rng = np.random.default_rng(3)
+    B, S, H, dq, dv = 2, 512, 2, 192, 128
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    q, k, v = f(B, S, H, dq), f(B, S, H, dq), f(B, S, H, dv)
+    kv_start, kv_len = jnp.asarray(kv_start, jnp.int32), jnp.asarray([S, S - 3], jnp.int32)
+    scale = 0.1147 * 1.37  # not a power of two: a q pre-scaled in bf16 would round
+    want = mla.mla_prefill_attention_xla(q, k, v, kv_start, kv_len, scale=scale)
+    got = mla.mla_flash_attention(q, k, v, kv_start, kv_len, scale=scale, bq=128, bk=256, interpret=True)
+    live = (jnp.arange(S)[None, :] >= kv_start[:, None])[:, :, None, None]
+    np.testing.assert_allclose(np.asarray(jnp.where(live, got, 0)), np.asarray(jnp.where(live, want, 0)), atol=2e-5)
+
+
 # ---- (d) routing ----
 
 ROUTE = dict(top_k=2, n_group=4, topk_group=2, scaling=2.5)
