@@ -207,18 +207,37 @@ class LatentMoEConfig:
     """The latent-attention, sparse-expert decoder family (``models/latent_moe.py``):
     multi-head latent attention (a per-token latent ``c_kv`` and one shared
     rotated key slice are all the cache holds), ``first_k_dense`` leading
-    dense layers, then layers whose FFN is a sigmoid-routed, group-limited
-    mixture of ``n_routed_experts`` SwiGLU experts beside ``n_shared_experts``
-    shared ones.
+    dense layers, then layers with a routed mixture of ``n_routed_experts``
+    SwiGLU experts. Two published blocks are spanned, selected by the fields
+    below; the defaults are the first:
+
+    - ``sublayers_per_layer`` 1: norm, attention, norm, expert layer. Scores
+      are ``sigmoid`` (``scoring_func``), choice is group-limited
+      (``n_group`` / ``topk_group``), weights are normalised over the chosen
+      (``norm_topk_prob``), ``n_shared_experts`` shared experts beside them.
+    - ``sublayers_per_layer`` 2 (shortcut-connected): two attention-plus-
+      dense-FFN sublayers a layer (``intermediate_size`` wide); the ONE
+      expert layer branches off sublayer 0's normed stream and joins the
+      residual after the last sublayer's FFN. With ``scoring_func``
+      ``softmax`` the router scores ``n_routed_experts + zero_expert_num``
+      outputs; the last ``zero_expert_num`` are zero-computation experts
+      (identity: ``w * x``), replicated on every chip. ``n_group`` 1 is the
+      no-groups case; ``n_shared_experts`` 0 builds no shared expert.
+      ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` multiply the queries by
+      ``(hidden / q_lora_rank) ** 0.5`` and the normed latent by ``(hidden /
+      kv_lora_rank) ** 0.5``.
+
+    ``num_layers`` counts LAYERS; the latent cache holds one plane an
+    attention sublayer (``num_cache_planes``).
 
     ``ep_size``/``ep_rank`` state this chip's SHARE of an expert-parallel
-    deployment: the router keeps its published width, weights are normalised
-    over all selected experts, and only the experts ``[ep_rank * held,
-    (ep_rank + 1) * held)`` are held and computed here; what absent experts
-    would add is left out (no exchange on one chip).
+    deployment: the router keeps its published width, and only the routed
+    experts ``[ep_rank * held, (ep_rank + 1) * held)`` are held and computed
+    here; what absent experts would add is left out (no exchange on one chip).
 
-    Defaults are the published widths of the 672B-A37B decoder the benchmark
-    cell serves a share of."""
+    Defaults are the published widths of the 672B-A37B decoder the
+    ``dots-vlm1-ep16.closed8`` cell serves a share of; the shortcut block's
+    are in ``benchmark/configs/longcat-flash-bf16-ep32-share.json``."""
 
     vocab_size: int = 129280
     hidden_size: int = 7168
@@ -239,6 +258,11 @@ class LatentMoEConfig:
     topk_group: int = 4
     routed_scaling_factor: float = 2.5
     norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"  # "sigmoid" | "softmax" (over routed + zero outputs)
+    zero_expert_num: int = 0  # router outputs past the routed experts: identity experts, ``w * x``
+    sublayers_per_layer: int = 1  # attention + FFN sublayers a layer (2: shortcut-connected)
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     ep_size: int = 1
     ep_rank: int = 0
     rms_norm_eps: float = 1e-6
@@ -260,6 +284,17 @@ class LatentMoEConfig:
             raise ValueError("n_group must divide n_routed_experts; topk_group <= n_group")
         if not 0 <= self.first_k_dense <= self.num_layers:
             raise ValueError("first_k_dense must lie in [0, num_layers]")
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring_func={self.scoring_func!r}: 'sigmoid' or 'softmax'")
+        if self.zero_expert_num < 0:
+            raise ValueError(f"zero_expert_num={self.zero_expert_num}: 0 or more")
+        if self.zero_expert_num and self.n_group != 1:
+            raise ValueError("zero-computation experts are routed without groups (n_group 1)")
+        if self.sublayers_per_layer not in (1, 2):
+            raise ValueError(f"sublayers_per_layer={self.sublayers_per_layer}: 1 or 2")
+        if self.sublayers_per_layer == 2 and self.first_k_dense:
+            raise ValueError("a shortcut-connected block (sublayers_per_layer 2) has no "
+                             "leading dense layers: first_k_dense must be 0")
         if self.tie_word_embeddings:
             raise ValueError("the latent-MoE family serves an untied head only")
 
@@ -274,6 +309,16 @@ class LatentMoEConfig:
     @property
     def num_moe_layers(self) -> int:
         return self.num_layers - self.first_k_dense
+
+    @property
+    def num_cache_planes(self) -> int:
+        """One latent plane an attention sublayer."""
+        return self.num_layers * self.sublayers_per_layer
+
+    @property
+    def router_width(self) -> int:
+        """The router's outputs: the routed experts, then the zero ones."""
+        return self.n_routed_experts + self.zero_expert_num
 
     @property
     def qk_head_dim(self) -> int:

@@ -20,13 +20,24 @@ What differs from the Llama block:
   RoPE pairs dimension ``i`` with ``i + R/2`` (by halves, as the rest of this
   package; the publisher's interleaved pairing is a permutation of ``W_UQ``'s
   and ``W_DKV``'s rope columns). YaRN scales the frequencies and the softmax.
+  Where the configuration says so, the queries and the normed latent carry
+  the publisher's LoRA scales (``mla_scale_q_lora`` / ``mla_scale_kv_lora``);
+  the latent is cached scaled, so both forms read it alike.
 - **Layers.** ``first_k_dense`` leading dense layers OUTSIDE the layers' loop
   (``dense_<i>`` in the tree), then the MoE layers as ONE ``lax.scan`` over a
-  stacked tree (``layers``).
-- **Sparse experts** (``ops/moe.py``): sigmoid group-limited routing over the
-  published experts, this chip's held range computed by grouped matmuls over
-  gathered assignments with no capacity limit, a shared expert beside them.
-  ``y = sum_{i selected and held} w_i E_i(x) + E_shared(x)``.
+  stacked tree (``layers``). A layer is ``sublayers_per_layer`` attention
+  sublayers (``Block``): with one, norm, attention, norm, expert layer; with
+  two (shortcut-connected), each sublayer has a dense SwiGLU of its own, the
+  ONE expert layer branches off sublayer 0's normed stream and joins the
+  residual after the last sublayer's FFN. The cache holds a plane an
+  attention sublayer (``num_cache_planes``).
+- **Sparse experts** (``ops/moe.py``): the router scores all its outputs
+  (sigmoid with group-limited choice, or softmax over routed and
+  zero-computation experts), this chip's held range is computed by grouped
+  matmuls over gathered assignments with no capacity limit; a shared expert
+  and the zero-computation (identity) experts' term where the configuration
+  has them. ``y = sum_{i selected and held} w_i E_i(x) [+ E_shared(x)]
+  [+ (sum_{i selected and zero} w_i) x]``.
 
 The cache carries the family's counters (``LatentCache.counters``), so they
 ride every program the engine builds and come back with the answer.
@@ -52,12 +63,13 @@ from rag_llm_k8s_tpu.ops import mla, moe
 # cache: verify, chunked prefill, the scorer); ``what`` is ops.moe.ExpertCounts
 # plus the layer-calls the mode made.
 COUNTER_MODES = ("prefill", "decode", "chunk")
-COUNTER_FIELDS = ("tokens", "routed", "computed", "experts_hit", "layer_calls")
+COUNTER_FIELDS = ("tokens", "routed", "computed", "experts_hit", "layer_calls", "zero")
 N_COUNTERS = len(COUNTER_MODES) * len(COUNTER_FIELDS)
 # what ``/metrics`` calls them (``engine_<name>``) -> (mode, field) of the
 # block; the first sums its field over the modes. Assignments to experts HELD
 # here and assignment rows the grouped kernel stored, by how the model was
-# called; held experts hit, summed over decode layer-steps, and those steps
+# called; held experts hit, summed over decode layer-steps, and those steps;
+# assignments to zero-computation experts (none where a model has none)
 COUNTER_STATS = {
     "moe_tokens_routed": (None, "tokens"),
     "moe_prefill_assignments_held": ("prefill", "routed"),
@@ -69,6 +81,9 @@ COUNTER_STATS = {
     "moe_decode_experts_hit": ("decode", "experts_hit"),
     "moe_decode_layer_steps": ("decode", "layer_calls"),
     "moe_prefill_layer_calls": ("prefill", "layer_calls"),
+    "moe_prefill_assignments_zero": ("prefill", "zero"),
+    "moe_decode_assignments_zero": ("decode", "zero"),
+    "moe_chunk_assignments_zero": ("chunk", "zero"),
 }
 
 
@@ -85,7 +100,8 @@ def fold_counters(row) -> dict:
 @flax.struct.dataclass
 class LatentCache:
     """``c_kv [L, B, T, C]`` normed latents and ``k_rope [L, B, T, R]`` rotated
-    shared key slices, written at a shared index like ``KVCache``;
+    shared key slices (``L``: a plane an attention sublayer,
+    ``num_cache_planes``), written at a shared index like ``KVCache``;
     ``counters [N_COUNTERS]`` int32 accumulate what the expert layers did."""
 
     c_kv: jax.Array
@@ -95,7 +111,7 @@ class LatentCache:
 
 def make_latent_cache(config: LatentMoEConfig, batch_size: int, max_seq_len: int,
                       dtype: jnp.dtype = jnp.bfloat16) -> LatentCache:
-    lead = (config.num_layers, batch_size, max_seq_len)
+    lead = (config.num_cache_planes, batch_size, max_seq_len)
     return LatentCache(
         c_kv=jnp.zeros(lead + (config.kv_lora_rank,), dtype),
         k_rope=jnp.zeros(lead + (config.qk_rope_head_dim,), dtype),
@@ -197,6 +213,9 @@ class LatentAttention(nn.Module):
         wq_b, wo = dense(H * (dn + R), "wq_b"), dense(c.hidden_size, "wo")
         latent = dense(C + R, "wkv_a")(x)
         c_kv = RMSNorm(c.rms_norm_eps, dt, name="kv_norm")(latent[..., :C])
+        if c.mla_scale_kv_lora:  # the latent is cached scaled: both forms read it alike
+            c_kv = c_kv * jnp.asarray((c.hidden_size / C) ** 0.5, c_kv.dtype)
+        q_scale = (c.hidden_size / c.q_lora_rank) ** 0.5 if c.mla_scale_q_lora else None
         k_rope = apply_rope(latent[..., None, C:], cos, sin)[:, :, 0]  # [B, S, R]
         w_ukv = Kernel((C, H * (dn + dv)), dt, name="wkv_b")().astype(dt.compute_dtype)
 
@@ -207,7 +226,10 @@ class LatentAttention(nn.Module):
             r_cache, k_rope.astype(r_cache.dtype)[None], (layer, 0, write_index, 0))
 
         def queries(c_q, cos, sin):
-            q = wq_b(c_q).reshape(*c_q.shape[:2], H, dn + R)
+            q = wq_b(c_q)
+            if q_scale is not None:  # nope and rope slices alike, before the rotation
+                q = q * jnp.asarray(q_scale, q.dtype)
+            q = q.reshape(*c_q.shape[:2], H, dn + R)
             return q[..., :dn], apply_rope(q[..., dn:], cos, sin)
 
         if S > 1 and not self.chunked:
@@ -326,8 +348,8 @@ class SparseMLP(nn.Module):
         flat = x.reshape(B * S, D)
         impl = resolve_impl(self.attn_impl)
         with phase_scope("router"):
-            w_g = Kernel((D, c.n_routed_experts), dt, name="router")()
-            bias = self.param("router_bias", nn.initializers.zeros, (c.n_routed_experts,), jnp.float32)
+            w_g = Kernel((D, c.router_width), dt, name="router")()
+            bias = self.param("router_bias", nn.initializers.zeros, (c.router_width,), jnp.float32)
             # float32 scores. bf16 inputs multiply exactly into the float32
             # accumulator in one pass; float32 inputs (the fp32 policy) need
             # the highest precision said, or a TPU rounds them to bf16
@@ -337,19 +359,25 @@ class SparseMLP(nn.Module):
             experts, weights = moe.route(
                 logits, bias, top_k=c.num_experts_per_tok, n_group=c.n_group,
                 topk_group=c.topk_group, scaling=c.routed_scaling_factor,
-                normalize=c.norm_topk_prob)
+                normalize=c.norm_topk_prob, scoring=c.scoring_func)
         with phase_scope("experts"):
             y, counts = moe.held_expert_ffn(
                 flat, experts, weights, *experts_stack, moe_layer, c.first_held,
-                c.n_routed_experts, impl=impl)
-        with phase_scope("shared"):
-            y = y + SwiGLU(c.moe_intermediate_size * c.n_shared_experts, D, dt, name="shared")(flat)
+                c.router_width, impl=impl)
+        if c.zero_expert_num:
+            with phase_scope("zero"):
+                term, n_zero = moe.zero_expert_term(flat, experts, weights, c.n_routed_experts)
+                y, counts = y + term, counts._replace(zero=n_zero)
+        if c.n_shared_experts:
+            with phase_scope("shared"):
+                y = y + SwiGLU(c.moe_intermediate_size * c.n_shared_experts, D, dt, name="shared")(flat)
         return y.reshape(B, S, D), counts
 
 
 def _count(counters, mode: str, counts: moe.ExpertCounts):
     base = COUNTER_MODES.index(mode) * len(COUNTER_FIELDS)
-    add = jnp.stack([counts.tokens, counts.routed, counts.computed, counts.experts_hit, jnp.int32(1)])
+    add = jnp.stack([counts.tokens, counts.routed, counts.computed, counts.experts_hit, jnp.int32(1),
+                     jnp.asarray(counts.zero, jnp.int32)])
     return jax.lax.dynamic_update_slice(
         counters, jax.lax.dynamic_slice(counters, (base,), (len(COUNTER_FIELDS),)) + add, (base,))
 
@@ -357,7 +385,14 @@ def _count(counters, mode: str, counts: moe.ExpertCounts):
 class Block(nn.Module):
     """One decoder layer: the scan body of the MoE layers (``sparse``) and,
     called directly, a leading dense layer. The carry threads ``(h, cache
-    planes, counters, layer)`` like ``models/llama.py``'s."""
+    planes, counters, plane)`` like ``models/llama.py``'s; ``plane`` is the
+    cache plane the layer's first attention writes.
+
+    A sparse layer is ``sublayers_per_layer`` attention sublayers. With one,
+    the expert layer IS the sublayer's FFN. With two (shortcut-connected)
+    every sublayer has a dense SwiGLU, and the expert layer reads sublayer
+    0's normed stream and joins the residual after the last sublayer's FFN:
+    its value stays live through an attention and a dense FFN."""
 
     config: LatentMoEConfig
     dtypes: DTypePolicy
@@ -368,27 +403,34 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, carry, kv_start, kv_len, cos, sin, write_index, experts_stack=None):
         c, dt = self.config, self.dtypes
-        h, planes, counters, layer = carry
-        with phase_scope("norm_rope"):
-            x = RMSNorm(c.rms_norm_eps, dt, name="input_norm")(h)
-        with phase_scope("attn"):
-            attn_out, planes = LatentAttention(c, dt, self.attn_impl, self.chunked, name="attn")(
-                x, planes, layer, kv_start, kv_len, cos, sin, write_index)
-            h = h + attn_out
-        with phase_scope("norm_rope"):
-            x = RMSNorm(c.rms_norm_eps, dt, name="post_attn_norm")(h)
-        with phase_scope("mlp"):
-            if self.sparse:
-                y, counts = SparseMLP(c, dt, self.attn_impl, name="mlp")(
-                    x, experts_stack, layer - c.first_k_dense)
-                mode = "decode" if x.shape[1] == 1 else "chunk" if self.chunked else "prefill"
-                counters = _count(counters, mode, counts)
-            else:
-                mlp = SwiGLU(c.intermediate_size, c.hidden_size, dt, name="mlp")
-                big = not self.chunked and rowwise(c, x.shape[0], x.shape[1], dt.compute_dtype)
-                y = by_rows(mlp, x) if big else mlp(x)
-            h = h + y
-        return (h, planes, counters, layer + 1), None
+        h, planes, counters, plane = carry
+        n = c.sublayers_per_layer if self.sparse else 1
+        branch = None
+        for i in range(n):
+            sub = f"_{i}" if n > 1 else ""
+            with phase_scope("norm_rope"):
+                x = RMSNorm(c.rms_norm_eps, dt, name="input_norm" + sub)(h)
+            with phase_scope("attn"):
+                attn_out, planes = LatentAttention(c, dt, self.attn_impl, self.chunked, name="attn" + sub)(
+                    x, planes, plane + i, kv_start, kv_len, cos, sin, write_index)
+                h = h + attn_out
+            with phase_scope("norm_rope"):
+                x = RMSNorm(c.rms_norm_eps, dt, name="post_attn_norm" + sub)(h)
+            with phase_scope("mlp"):
+                if self.sparse and i == 0:
+                    branch, counts = SparseMLP(c, dt, self.attn_impl, name="mlp")(
+                        x, experts_stack, (plane - c.first_k_dense) // n)
+                    mode = "decode" if x.shape[1] == 1 else "chunk" if self.chunked else "prefill"
+                    counters = _count(counters, mode, counts)
+                if not self.sparse or n > 1:
+                    with phase_scope("dense"):
+                        mlp = SwiGLU(c.intermediate_size, c.hidden_size, dt,
+                                     name="ffn" + sub if self.sparse else "mlp")
+                        big = not self.chunked and rowwise(c, x.shape[0], x.shape[1], dt.compute_dtype)
+                        h = h + (by_rows(mlp, x) if big else mlp(x))
+                if branch is not None and i == n - 1:
+                    h = h + branch
+        return (h, planes, counters, plane + n), None
 
 
 class LatentMoEModel(nn.Module):

@@ -237,13 +237,17 @@ def roofline_for_latent_moe(cfg, *, peak_tflops: float, hbm_gbs: float) -> Roofl
 
     ``flops_per_token`` counts the parameters a token is multiplied by: the
     latent attention's projections, the dense layers' FFN, and a MoE layer's
-    router, shared expert and ``num_experts_per_tok / ep_size`` routed
-    experts (what a balanced router sends to the experts HELD here).
-    ``weight_bytes`` is what a decode step streams at batch 1: attention,
-    router and shared expert whole, but only the held experts a token's
-    choices hit, never all held (a batch hits more; bf16, 2 bytes).
-    ``kv_bytes_per_token`` is one position's latent row over all layers."""
+    router, shared expert and the routed experts a balanced router sends to
+    those HELD here (``num_experts_per_tok * held`` over the router's
+    outputs, zero-computation ones included: those multiply nothing). A
+    shortcut-connected layer (``sublayers_per_layer`` 2) has two attentions
+    and a dense FFN beside each. ``weight_bytes`` is what a decode step
+    streams at batch 1: attention, router, dense FFNs and shared expert
+    whole, but only the held experts a token's choices hit, never all held
+    (a batch hits more; bf16, 2 bytes). ``kv_bytes_per_token`` is one
+    position's latent row over all cache planes."""
     d, H = int(cfg.hidden_size), int(cfg.num_heads)
+    sub = cfg.sublayers_per_layer
     attn = (
         d * cfg.q_lora_rank + cfg.q_lora_rank * H * cfg.qk_head_dim
         + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
@@ -251,9 +255,12 @@ def roofline_for_latent_moe(cfg, *, peak_tflops: float, hbm_gbs: float) -> Roofl
         + H * cfg.v_head_dim * d
     )
     expert = 3 * d * cfg.moe_intermediate_size
-    routed_here = cfg.num_experts_per_tok / cfg.ep_size
-    moe_layer = attn + d * cfg.n_routed_experts + (cfg.n_shared_experts + routed_here) * expert
-    dense_layer = attn + 3 * d * cfg.intermediate_size
+    dense_ffn = 3 * d * cfg.intermediate_size
+    routed_here = cfg.num_experts_per_tok * cfg.experts_held / cfg.router_width
+    moe_layer = sub * attn + d * cfg.router_width + (cfg.n_shared_experts + routed_here) * expert
+    if sub > 1:
+        moe_layer += sub * dense_ffn
+    dense_layer = attn + dense_ffn
     active = (
         cfg.first_k_dense * dense_layer + cfg.num_moe_layers * moe_layer
         + cfg.vocab_size * d
@@ -261,7 +268,7 @@ def roofline_for_latent_moe(cfg, *, peak_tflops: float, hbm_gbs: float) -> Roofl
     return RooflineModel(
         flops_per_token=2.0 * active,
         weight_bytes=2.0 * active,
-        kv_bytes_per_token=2.0 * cfg.num_layers * (cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+        kv_bytes_per_token=2.0 * cfg.num_cache_planes * (cfg.kv_lora_rank + cfg.qk_rope_head_dim),
         peak_tflops=peak_tflops,
         hbm_gbs=hbm_gbs,
     )

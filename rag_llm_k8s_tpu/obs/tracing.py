@@ -70,10 +70,12 @@ SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
 # opened BENEATH a sub-scope by the latent-attention sparse-expert family
 # (models/latent_moe.py): ``attn/latent`` (the attention over the latent
 # cache itself, its absorbing matmuls included), ``mlp/router``,
-# ``mlp/experts`` (gather, grouped matmuls, scatter), ``mlp/shared``. A
+# ``mlp/experts`` (gather, grouped matmuls, scatter), ``mlp/shared``,
+# ``mlp/zero`` (the zero-computation experts' term) and ``mlp/dense`` (a
+# dense SwiGLU: a leading dense layer's, a shortcut-connected layer's two). A
 # reader that files an operation under the first sub-scope it knows keeps
 # reading ``attn`` and ``mlp``; one that knows these sees the finer split.
-FINE_SCOPES = ("latent", "router", "experts", "shared")
+FINE_SCOPES = ("latent", "router", "experts", "shared", "zero", "dense")
 SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES + FINE_SCOPES)
 
 

@@ -1,8 +1,13 @@
-"""Sparse experts: sigmoid group-limited routing, and this chip's share of
-the routed experts as grouped matmuls over the assignments it holds.
+"""Sparse experts: routing by the two published rules this family spans, and
+this chip's share of the routed experts as grouped matmuls over the
+assignments it holds.
 
-``route`` is the published ``noaux_tc`` rule over ALL routed experts.
-``held_expert_ffn`` computes ``sum_i w_i * E_i(x)`` over the selected experts
+``route`` scores ALL of the router's outputs (sigmoid, or softmax over the
+routed experts and the zero-computation ones after them), chooses by score
+plus a correction bias (group-limited where there are groups), and weighs by
+the score itself. ``zero_expert_term`` is what the chosen zero-computation
+experts (identity) add, whole on every chip. ``held_expert_ffn`` computes
+``sum_i w_i * E_i(x)`` over the selected experts
 THIS chip holds (a contiguous range of the published experts): assignments
 to held experts are gathered into rows sorted by expert, with **no capacity
 limit**, and run through three grouped matmuls (gate, up, down) whose work
@@ -38,27 +43,48 @@ def route(
     topk_group: int,
     scaling: float,
     normalize: bool = True,
+    scoring: str = "sigmoid",
 ) -> Tuple[jax.Array, jax.Array]:
     """``(experts [N, top_k] int32, weights [N, top_k] float32)``.
 
-    ``s = sigmoid(logits)``; the CHOICE is by ``s + bias``: a group's score
-    is the sum of its top 2, the best ``topk_group`` groups stay, the
-    ``top_k`` best experts inside them are chosen (ties to the lower index).
-    The WEIGHT is ``s`` itself (never ``s + bias``) at the chosen experts,
-    divided by their sum over all ``top_k``, times ``scaling``."""
+    ``s = sigmoid(logits)``, or ``softmax`` over all ``E`` outputs (routed and
+    zero-computation experts alike); the CHOICE is by ``s + bias``: a group's
+    score is the sum of its top 2, the best ``topk_group`` groups stay, the
+    ``top_k`` best experts inside them are chosen (ties to the lower index;
+    ``n_group`` 1: the ``top_k`` best of all). The WEIGHT is ``s`` itself
+    (never ``s + bias``) at the chosen experts, divided by their sum over all
+    ``top_k`` where ``normalize``, times ``scaling``."""
     N, E = logits.shape
-    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    logits = logits.astype(jnp.float32)
+    s = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
     choice = s + bias.astype(jnp.float32)[None, :]
-    per = E // n_group
-    group_score = jnp.sum(jax.lax.top_k(choice.reshape(N, n_group, per), 2)[0], axis=-1)
-    _, keep = jax.lax.top_k(group_score, topk_group)  # [N, topk_group]
-    kept = jnp.zeros((N, n_group), bool).at[jnp.arange(N)[:, None], keep].set(True)
-    masked = jnp.where(jnp.repeat(kept, per, axis=1), choice, -jnp.inf)
-    _, experts = jax.lax.top_k(masked, top_k)
+    if n_group > 1:
+        per = E // n_group
+        group_score = jnp.sum(jax.lax.top_k(choice.reshape(N, n_group, per), 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(group_score, topk_group)  # [N, topk_group]
+        kept = jnp.zeros((N, n_group), bool).at[jnp.arange(N)[:, None], keep].set(True)
+        choice = jnp.where(jnp.repeat(kept, per, axis=1), choice, -jnp.inf)
+    _, experts = jax.lax.top_k(choice, top_k)
     weights = jnp.take_along_axis(s, experts, axis=1)
     if normalize:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), weights * scaling
+
+
+def zero_expert_term(
+    x: jax.Array,  # [N, D]
+    experts: jax.Array,  # [N, top_k] int32, over the router's outputs
+    weights: jax.Array,  # [N, top_k] float32
+    n_routed: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """``(sum_{chosen e >= n_routed} w_e) * x`` for every token, ``[N, D]`` in
+    ``x``'s dtype (float32 product, cast like the held experts' scatter-add),
+    and the number of assignments to zero-computation experts. An identity
+    expert gathers nothing, holds no weight and takes no row of the grouped
+    buffer; every chip computes the term for its own tokens."""
+    zero = experts >= n_routed
+    w = jnp.sum(jnp.where(zero, weights, 0.0), axis=-1, keepdims=True)
+    return (w * x.astype(jnp.float32)).astype(x.dtype), jnp.sum(zero).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -190,6 +216,7 @@ class ExpertCounts(NamedTuple):
     routed: jax.Array  # assignments to experts held here
     computed: jax.Array  # assignment rows the down projection's kernel stored, by its own count
     experts_hit: jax.Array  # held experts with at least one assignment
+    zero: jax.Array = 0  # assignments to zero-computation experts (``zero_expert_term``)
 
 
 def rows_per_pass(n_tokens: int, top_k: int, n_experts: int, held: int) -> int:
@@ -204,14 +231,14 @@ def rows_per_pass(n_tokens: int, top_k: int, n_experts: int, held: int) -> int:
 
 def held_expert_ffn(
     x: jax.Array,  # [N, D]
-    experts: jax.Array,  # [N, top_k] int32, over ALL routed experts
+    experts: jax.Array,  # [N, top_k] int32, over ALL the router's outputs
     weights: jax.Array,  # [N, top_k] float32
     w_gate: jax.Array,  # [L, held, D, F]: every MoE layer's held experts
     w_up: jax.Array,  # [L, held, D, F]
     w_down: jax.Array,  # [L, held, F, D]
     layer: jax.Array,  # [] int32: which of the L
     first_held: int,
-    n_experts: int,
+    n_experts: int,  # the router's outputs (zero-computation ones too): the balanced load's divisor
     *,
     impl: str = "xla",  # "xla" (ragged_dot) | "pallas" | "pallas_interpret"
 ) -> Tuple[jax.Array, ExpertCounts]:

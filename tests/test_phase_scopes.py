@@ -264,7 +264,7 @@ def test_latent_moe_fine_scopes_sit_beneath_attn_and_mlp(latent_engine):
     operation of them still files under ``decode/attn`` or ``decode/mlp``; the
     leading dense layer stands outside the layers' loop and the MoE layers in
     it, which is what a prefill's rows are counted by."""
-    assert set(tracing.FINE_SCOPES) == {"latent", "router", "experts", "shared"}
+    assert set(tracing.FINE_SCOPES) == {"latent", "router", "experts", "shared", "zero", "dense"}
     assert set(tracing.FINE_SCOPES) <= tracing.SCOPE_NAMES
     paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](latent_engine))]
     for phase in ("prefill", "decode"):
@@ -273,8 +273,41 @@ def test_latent_moe_fine_scopes_sit_beneath_attn_and_mlp(latent_engine):
             assert hits and all(_scope(p) == (phase, sub) for p in hits), (phase, sub, fine)
     dense = [p for p in paths if "/prefill/rows2/" in p and "/dense_0/" in p]
     assert dense and not [p for p in dense if "/while/" in p.split("/dense_0/")[0]]
+    assert [p for p in dense if "/mlp/dense/" in p] and not [p for p in paths if "/mlp/zero/" in p]
     looped = [p for p in paths if "/prefill/rows2/" in p and "/while/body/" in p and "/layers/" in p]
     assert looped and not [p for p in looped if "/dense_0/" in p]
+
+
+def test_shortcut_block_arrives_scoped_with_its_own_fine_scopes():
+    """The shortcut-connected block through the same generate program: every
+    operation carries a phase; the two dense FFNs are ``mlp/dense``, the
+    identity experts' term ``mlp/zero``, and no ``mlp/shared`` is opened where
+    there is no shared expert."""
+    import dataclasses
+
+    from longcat_flash_reference import tiny_config
+    from rag_llm_k8s_tpu.models.latent_moe import init_latent_moe_params
+
+    cfg = tiny_config(vocab_size=300)
+    params = init_latent_moe_params(jax.random.PRNGKey(0), cfg, FP32)
+    ec = dataclasses.replace(EC, prefix_cache=PrefixCacheConfig(enabled=False), attn_impl="xla")
+    engine = InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+    traced = LATENT_PROGRAMS["generate"](engine)
+    _assert_scoped("generate", traced)
+    paths = [path for _, path in _traced(traced)]
+    for phase in ("prefill", "decode"):
+        for fine in ("router", "experts", "zero", "dense"):
+            hits = [p for p in paths if f"/{phase}/" in p and f"/mlp/{fine}/" in p]
+            assert hits and all(_scope(p) == (phase, "mlp") for p in hits), (phase, fine)
+        assert {"ffn_0", "ffn_1"} <= {part for p in paths if f"/{phase}/" in p and "/mlp/dense/" in p
+                                      for part in p.split("/")}
+    assert not [p for p in paths if "/mlp/shared/" in p]
+    # the whole block (both sublayers and the branch) runs in the layers'
+    # loop at the rows2 prefill: a prefill's rows are counted by that
+    looped = [p for p in paths if "/prefill/rows2/" in p and "/while/body/" in p and "/layers/" in p]
+    for name in ("attn_0", "attn_1", "ffn_0", "ffn_1", "/mlp/experts/"):
+        assert [p for p in looped if name in p], name
+    assert not [p for p in paths if "/prefill/rows2/" in p and "/ffn_" in p and "/while/body/" not in p]
 
 
 def test_an_operation_outside_every_scope_shows():

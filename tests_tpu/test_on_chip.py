@@ -442,3 +442,52 @@ class TestSingleFetchOnChip:
             packed, toks_dev, lens_dev, n_chunks=3,
         )
         assert got == want
+
+
+class TestShortcutBlockKernelsOnChip:
+    """The latent-attention sparse-expert family's kernels at the widths of
+    its shortcut-connected configuration (64 heads of 128 nope + 64 rope
+    against value 128; experts 6144 <-> 2048), against their XLA forms."""
+
+    def test_grouped_matmul_at_6144_and_2048(self):
+        from rag_llm_k8s_tpu.ops import moe
+
+        rng = np.random.default_rng(0)
+        sizes = jnp.asarray([300, 0, 5, 130, 0, 0, 77, 0, 0, 0, 0, 0, 0, 0, 0, 0], jnp.int32)  # 16 held
+        for k, n in ((6144, 2048), (2048, 6144)):  # gate / up, then down
+            lhs = jnp.asarray(rng.standard_normal((512, k)), jnp.bfloat16)
+            rhs = jnp.asarray(rng.standard_normal((2, 16, k, n)) / np.sqrt(k), jnp.bfloat16)
+            got, stored = moe.grouped_matmul(lhs, rhs, sizes, jnp.int32(1))
+            want, _ = moe._grouped_xla(lhs, rhs, sizes, jnp.int32(1))
+            rows = int(sizes.sum())
+            assert int(stored) == rows
+            np.testing.assert_allclose(np.asarray(got[:rows], np.float32), np.asarray(want[:rows], np.float32),
+                                       rtol=2e-2, atol=2e-2)  # both accumulate in float32: bf16's last place
+
+    def test_mla_kernels_at_64_heads(self):
+        from rag_llm_k8s_tpu.ops import mla
+
+        rng = np.random.default_rng(1)
+        B, S, H, C, R, dn, dv = 2, 1024, 64, 512, 64, 128, 128
+        f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+        q_nope, q_rope, c_kv, k_rope = f(B, S, H, dn), f(B, S, H, R), f(B, S, C), f(B, S, R)
+        w = f(C, H, dn + dv) / np.sqrt(C)
+        kv_start, kv_len = jnp.asarray([0, 402], jnp.int32), jnp.asarray([S, S - 3], jnp.int32)
+        scale = 192 ** -0.5
+        with jax.default_matmul_precision("highest"):
+            kv = jnp.einsum("bsc,chd->bshd", c_kv, w)
+            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope[:, :, None], (B, S, H, R))], -1)
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            want = mla.mla_prefill_attention_xla(q, k, kv[..., dn:], kv_start, kv_len, scale=scale)
+            got = mla.mla_flash_attention(q, k, kv[..., dn:], kv_start, kv_len, scale=scale)
+            live = (jnp.arange(S)[None, :] >= kv_start[:, None])[:, :, None, None]
+            np.testing.assert_allclose(np.asarray(jnp.where(live, got, 0)), np.asarray(jnp.where(live, want, 0)),
+                                       rtol=2e-4, atol=2e-4)
+            q_lat = jnp.einsum("bshn,chn->bshc", q_nope, w[..., :dn])
+            o_lat = mla.latent_attention_xla(q_lat, q_rope, c_kv[None], k_rope[None], kv_start, kv_len,
+                                             jnp.int32(0), jnp.int32(0), scale=scale)
+            last = S - 4
+            one = mla.mla_decode_attention(
+                q_lat[:, last:last + 1], q_rope[:, last:last + 1], c_kv[None], k_rope[None], kv_start,
+                jnp.full((B,), last + 1, jnp.int32), jnp.int32(0), scale=scale)
+        np.testing.assert_allclose(np.asarray(one[:, 0]), np.asarray(o_lat[:, last]), rtol=2e-4, atol=2e-4)
