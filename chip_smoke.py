@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import json
 import logging
@@ -144,6 +145,16 @@ H, K, HD = 32, 8, 128  # Llama-3.1-8B attention heads
 T_MAX = 4352  # EngineConfig.max_seq_len: the 4096 bucket + 256
 ENC_S = 1536  # the encoder's snug bucket for reference-size chunks
 KNN_ROWS = 131072  # a 128k-vector index snapshot
+
+
+# the single-token decode kernels at the benchmark's four serving shapes:
+# name -> (kind, rows, query heads, the rows' left pads)
+SERVING_DECODE = {
+    "decode_attention_q8[8,8,4,128]": ("q8", 8, 32, [903, 917, 951, 966, 978, 990, 1001, 940]),
+    "decode_attention[4,2,4,128]": ("bf16", 4, 8, [1290, 1305, 1320, 1296]),  # Nemo's local heads at tp=4
+    "mla_decode_attention[8,128,512]": ("latent", 8, 128, [402, 431, 470, 498, 512, 530, 547, 455]),
+    "mla_decode_attention[8,64,512]": ("latent", 8, 64, [402, 431, 470, 498, 512, 530, 547, 455]),
+}
 
 
 def _block_tables(kv_len, bs: int, mb: int):
@@ -279,6 +290,38 @@ def kernel_cases(seed: int):
 
     yield "decode_attention", lambda: decode(False), 2e-4, 2e-5
     yield "decode_attention_q8", lambda: decode(True), 0.0, 0.03
+
+    def serving_decode(kind, B, heads, starts):
+        """A single-token decode kernel at one of the benchmark's four
+        serving shapes, in the dtypes served (bf16; int8 KV): a step of the
+        middle of an answer (75 of 150 tokens behind the 4096 bucket) with
+        the cell's left pads, so every row's walk starts inside the cache
+        and its last step is fetched from ``T - step``."""
+        from rag_llm_k8s_tpu.ops import mla as M
+
+        bf = lambda i, shape: normal(i, shape).astype(jnp.bfloat16)  # noqa: E731
+        kv_start = i32([s * T_MAX // 4352 for s in starts])  # (a rehearsal shrinks T_MAX)
+        kv_len, lay = i32([T_MAX - 256 + 75] * B), i32(1)
+        if kind == "latent":
+            C, R = 512, 64
+            args = (bf(50, (B, 1, heads, C)), bf(51, (B, 1, heads, R)), bf(52, (L, B, T_MAX, C)),
+                    bf(53, (L, B, T_MAX, R)), kv_start, kv_len, lay)
+            return (M.mla_decode_attention(*args, scale=0.05),
+                    M.latent_attention_xla(*args, kv_len[0] - 1, scale=0.05))
+        kv_heads = heads // 4
+        q = bf(54, (B, 1, heads, HD))
+        kc, vc = normal(55, (L, B, kv_heads, T_MAX, HD)), normal(56, (L, B, kv_heads, T_MAX, HD))
+        if kind == "q8":
+            (kq, ksc), (vq, vsc) = A.quantize_kv(kc), A.quantize_kv(vc)
+            args = (q, kq, vq, ksc, vsc, kv_start, kv_len, lay)
+            return A.decode_attention_q8(*args), A.decode_attention_xla_q8(*args)
+        args = (q, kc.astype(jnp.bfloat16), vc.astype(jnp.bfloat16), kv_start, kv_len, lay)
+        return A.decode_attention(*args), A.decode_attention_xla(*args)
+
+    # [rows, KV heads, group, head] / [rows, heads, rank] as the trace names them;
+    # bf16 outputs and probabilities against the oracle's: an output's last place, and 2e-3
+    for name, case in SERVING_DECODE.items():
+        yield name, functools.partial(serving_decode, *case), 1e-2, 2e-3
 
     def chunk(q8: bool):
         B, S = 2, 512

@@ -55,15 +55,20 @@ import jax.numpy as jnp
 
 from rag_llm_k8s_tpu.core.config import DTypePolicy, LatentMoEConfig, YarnScalingConfig
 from rag_llm_k8s_tpu.models.llama import RMSNorm, apply_rope, rope_cos_sin
+from rag_llm_k8s_tpu.models.llama import resolve_attn_impl as resolve_impl
 from rag_llm_k8s_tpu.obs.tracing import count_kernel_build, phase_scope
 from rag_llm_k8s_tpu.ops import mla, moe
+from rag_llm_k8s_tpu.ops.attention import decode_slots_streamed
 
 # LatentCache.counters: [mode, what] int32, flattened. ``mode`` is how the
 # model was called (single-shot prefill | one-token decode | a chunk over the
 # cache: verify, chunked prefill, the scorer); ``what`` is ops.moe.ExpertCounts
-# plus the layer-calls the mode made.
+# plus the layer-calls the mode made, and last the cache slots a step through
+# the decode kernel fetched over the rows and rows x the slots allocated
+# (``ops/attention.py decode_slots_streamed``; decode only).
 COUNTER_MODES = ("prefill", "decode", "chunk")
-COUNTER_FIELDS = ("tokens", "routed", "computed", "experts_hit", "layer_calls", "zero")
+COUNTER_FIELDS = ("tokens", "routed", "computed", "experts_hit", "layer_calls", "zero",
+                  "slots_streamed", "slots_allocated")
 N_COUNTERS = len(COUNTER_MODES) * len(COUNTER_FIELDS)
 # what ``/metrics`` calls them (``engine_<name>``) -> (mode, field) of the
 # block; the first sums its field over the modes. Assignments to experts HELD
@@ -84,6 +89,8 @@ COUNTER_STATS = {
     "moe_prefill_assignments_zero": ("prefill", "zero"),
     "moe_decode_assignments_zero": ("decode", "zero"),
     "moe_chunk_assignments_zero": ("chunk", "zero"),
+    "decode_slots_streamed": ("decode", "slots_streamed"),
+    "decode_slots_allocated": ("decode", "slots_allocated"),
 }
 
 
@@ -182,15 +189,6 @@ def _dense(module: nn.Module, dt: DTypePolicy):
     return lambda feats, name: nn.Dense(
         feats, use_bias=False, dtype=dt.compute_dtype, param_dtype=dt.param_dtype,
         parent=module, name=name)
-
-
-def resolve_impl(attn_impl: str) -> str:
-    if attn_impl not in ("auto", "pallas", "pallas_interpret", "xla"):
-        raise ValueError(f"attn_impl={attn_impl!r}: expected one of "
-                         "'auto', 'pallas', 'pallas_interpret', 'xla'")
-    if attn_impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    return attn_impl
 
 
 class LatentAttention(nn.Module):
@@ -379,7 +377,7 @@ def _count(counters, mode: str, counts: moe.ExpertCounts):
     add = jnp.stack([counts.tokens, counts.routed, counts.computed, counts.experts_hit, jnp.int32(1),
                      jnp.asarray(counts.zero, jnp.int32)])
     return jax.lax.dynamic_update_slice(
-        counters, jax.lax.dynamic_slice(counters, (base,), (len(COUNTER_FIELDS),)) + add, (base,))
+        counters, jax.lax.dynamic_slice(counters, (base,), (add.shape[0],)) + add, (base,))
 
 
 class Block(nn.Module):
@@ -463,7 +461,16 @@ class LatentMoEModel(nn.Module):
             if amp != 1.0:
                 cos, sin = cos * amp, sin * amp
 
-        carry = (h, (cache.c_kv, cache.k_rope), cache.counters, jnp.int32(0))
+        counters = cache.counters
+        if tokens.shape[1] == 1 and resolve_impl(self.attn_impl) != "xla":
+            # a step through ``mla_decode_attention``: what its walk fetches of
+            # a plane (every plane's call fetches the same, so a step counts once)
+            B, T = cache.c_kv.shape[1:3]
+            step = mla.latent_decode_step(T, c.num_heads, c.kv_lora_rank, cache.c_kv.dtype)
+            at = COUNTER_MODES.index("decode") * len(COUNTER_FIELDS) + COUNTER_FIELDS.index("slots_streamed")
+            counters = counters.at[at:at + 2].add(jnp.stack(
+                [decode_slots_streamed(kv_start, kv_len, T, step), B * T]).astype(counters.dtype))
+        carry = (h, (cache.c_kv, cache.k_rope), counters, jnp.int32(0))
         window = (kv_start, kv_len, cos, sin, write_index)
         for i in range(c.first_k_dense):  # outside the layers' loop
             carry, _ = Block(c, dt, self.attn_impl, self.chunked, sparse=False,

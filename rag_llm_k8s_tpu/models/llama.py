@@ -50,7 +50,9 @@ from rag_llm_k8s_tpu.ops.attention import (
     decode_attention_q8,
     decode_attention_xla,
     decode_attention_xla_q8,
+    decode_slots_streamed,
     flash_attention,
+    gqa_decode_step,
     grouped_chunk_fits,
     paged_chunk_attention,
     paged_chunk_attention_q8,
@@ -87,8 +89,9 @@ class KVCache:
 
     ``counters`` (the one-shot engine's caches, ``models/families.py``):
     ``[len(COUNTER_NAMES)]`` int32 the model adds to on the device, where
-    it decides how much of a prompt's bucket to compute; they ride the
-    generate programs' one fetch. ``None`` everywhere else.
+    it decides how much of a prompt's bucket to compute and what a decode
+    step's kernel fetches of the cache; they ride the generate programs'
+    one fetch. ``None`` everywhere else.
     """
 
     k: jax.Array
@@ -100,8 +103,13 @@ class KVCache:
 
 # what ``KVCache.counters`` counts, a fresh multi-token call at a time: token
 # rows the layers' matmuls ran on, and token rows of the padded batch
-# ("bucketed", not "bucket": a sample named ``*_bucket`` is a histogram's)
-COUNTER_NAMES = ("prefill_tokens_computed", "prefill_tokens_bucketed")
+# ("bucketed", not "bucket": a sample named ``*_bucket`` is a histogram's);
+# and, a single-token step through the decode kernel at a time: the cache
+# slots a layer's call fetches over the rows (``ops/attention.py
+# decode_slots_streamed``: the kernel's own plan) and rows x the slots
+# allocated (every layer of the step fetches the same, so a step counts once)
+COUNTER_NAMES = ("prefill_tokens_computed", "prefill_tokens_bucketed",
+                 "decode_slots_streamed", "decode_slots_allocated")
 
 
 def fold_counters(row) -> dict:
@@ -405,6 +413,29 @@ def _set_rows_from(x: jax.Array, off: int, rows: jax.Array) -> jax.Array:
     return jax.lax.dynamic_update_slice_in_dim(x, rows, off, axis=1)
 
 
+def resolve_attn_impl(attn_impl: str) -> str:
+    if attn_impl not in ("auto", "pallas", "pallas_interpret", "xla"):
+        raise ValueError(
+            f"attn_impl={attn_impl!r}: expected one of "
+            "'auto', 'pallas', 'pallas_interpret', 'xla'"
+        )
+    if attn_impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    return attn_impl
+
+
+def decode_walk_step(config: LlamaConfig, attn_impl: str, mesh, cache: "KVCache") -> Optional[int]:
+    """The step a single-token call's decode kernel walks ``cache`` in on
+    each device, or None where ``Attention._attend`` takes the XLA path (its
+    rule: under tp the kernel runs on local heads when both head counts
+    divide the axis, and not at all when they do not)."""
+    tp = mesh.shape["tp"] if mesh is not None and "tp" in mesh.axis_names else 1
+    H, K = config.num_heads, config.num_kv_heads
+    if resolve_attn_impl(attn_impl) == "xla" or (tp > 1 and (H % tp or K % tp)):
+        return None
+    return gqa_decode_step(cache.k.shape[3], K // tp, H // K, config.head_dim, cache.k.dtype)
+
+
 class Attention(nn.Module):
     """GQA attention with two fused TPU paths and one differentiable oracle.
 
@@ -467,14 +498,7 @@ class Attention(nn.Module):
     paged: bool = False
 
     def _resolved_impl(self) -> str:
-        if self.attn_impl not in ("auto", "pallas", "pallas_interpret", "xla"):
-            raise ValueError(
-                f"attn_impl={self.attn_impl!r}: expected one of "
-                "'auto', 'pallas', 'pallas_interpret', 'xla'"
-            )
-        if self.attn_impl == "auto":
-            return "pallas" if jax.default_backend() == "tpu" else "xla"
-        return self.attn_impl
+        return resolve_attn_impl(self.attn_impl)
 
     def _attend_paged(
         self, q, k, v, kv_len, layer, *, mode: str, block_tables,
@@ -1181,7 +1205,13 @@ class LlamaModel(nn.Module):
             bufs = tuple(jnp.zeros((B, S, w * hd), dt.compute_dtype) for w in widths)
         counters = cache.counters
         if fresh and counters is not None:
-            counters = counters + jnp.stack([B * (S - skipped), B * S]).astype(counters.dtype)
+            counters = counters.at[:2].add(jnp.stack([B * (S - skipped), B * S]).astype(counters.dtype))
+        if S == 1 and not self.paged and counters is not None:
+            step = decode_walk_step(c, self.attn_impl, self.mesh, cache)
+            if step is not None:  # a step through the decode kernel: what its walk fetches
+                T = cache.k.shape[3]
+                counters = counters.at[2:].add(jnp.stack(
+                    [decode_slots_streamed(kv_start, kv_len, T, step), B * T]).astype(counters.dtype))
 
         ScanBlocks = nn.scan(
             Block,

@@ -24,6 +24,7 @@ repeated in memory.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -384,79 +385,243 @@ def flash_attention(
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
 
 
+# ---------------------------------------------------------------------------
+# single-token decode over the dense cache: a row's live window, in a few
+# large steps (decode_attention, decode_attention_q8, ops/mla.py's
+# mla_decode_attention)
+# ---------------------------------------------------------------------------
+
+# a step starts on a multiple of this many slots: the lane tile of the int8
+# cache's scale planes ([.., K, T] float32), and a whole number of sublane
+# tiles of every payload (32 rows of int8, 16 of bf16)
+DECODE_ALIGN = 128
+# what one step of the walk moves at least (bytes), how many times the rows'
+# float32 accumulator, and what a step may take of VMEM (its two buffers, the
+# unpacked or masked copy the matmuls read, 12 bytes a score): the sweep
+# behind all three is in PERF.md §6, PR 32
+_DECODE_STEP_BYTES = 1 << 20
+_DECODE_ACC_STEPS = 16
+_DECODE_VMEM = 12 << 20
+
+
+def decode_step(T: int, slot_bytes: int, rows: int, width: int) -> int:
+    """Slots a step of the decode kernels' walk over a cache of ``T`` slots
+    fetches for a row and folds into its softmax at once, from the shape
+    alone (no option, no model's name).
+
+    ``slot_bytes`` is what a slot costs to read (every local KV head's K and
+    V and their scales; or a latent), ``rows`` the query rows that attend to
+    it (K·G; or H) and ``width`` the accumulator's columns a row (``hd``; or
+    the latent rank). Swept on a v5e (PR 32): a step costs ≈ 0.25 µs whatever
+    it moves, so it moves at least 1 MiB; and folding a step into the running
+    max, sum and accumulator costs by the ROW, not by the slot (PR 30 found
+    the same law in the prefill kernel), so a step also moves 16 times the
+    accumulator's bytes: 128 heads over a rank-512 latent want steps of 2048
+    slots (69.6 µs a call against 96.3 at 512), 32 rows of 128 want 512
+    (90.0 against 97.6 at 1024: a longer step only fetches more dead slots
+    behind the window's end). A power-of-two count of ``DECODE_ALIGN`` slots,
+    at most half the cache's; it need not divide ``T`` (the last step of a
+    row is fetched from ``T - step``: ``decode_step_bounds``). Cutting a
+    fetched step into pieces for the matmuls never won (it is how the latent
+    kernel lost a factor of two), so there are none."""
+    if T % DECODE_ALIGN or T <= DECODE_ALIGN:
+        return T  # one step: the whole (short, or oddly sized) cache
+    want = max(_DECODE_STEP_BYTES, _DECODE_ACC_STEPS * rows * width * 4)
+    step = DECODE_ALIGN
+    while (4 * step <= T and step * slot_bytes < want
+           and 2 * step * (4 * slot_bytes + 12 * rows) <= _DECODE_VMEM):
+        step *= 2
+    return step
+
+
+def decode_block_plan(kv_start, kv_len, T: int, step: int):
+    """Where a row's decode walk starts and how many steps it takes:
+    ``(origin, n)``. Step ``j < n`` covers the slots ``[origin + j·step,
+    origin + (j + 1)·step)``; together they are exactly the steps from
+    ``origin`` that intersect the live window ``[kv_start, kv_len)``, and
+    nothing else of ``[0, T)`` is fetched. ``origin`` is ``kv_start`` rounded
+    down to ``DECODE_ALIGN`` (to the step, where that is finer). An empty
+    window still takes one step (everything in it is masked; the output is
+    zeros), so a walk always has a first fetch. Integer arithmetic only: the
+    kernels' traced scalars, the model's counters and the tests' numpy arrays
+    read this one rule."""
+    align = math.gcd(step, DECODE_ALIGN)
+    origin = jnp.clip(kv_start, 0, T - 1) // align * align
+    n = jnp.maximum(-(-(jnp.minimum(kv_len, T) - origin) // step), 1)
+    return origin, n
+
+
+def decode_step_bounds(origin, j, T: int, step: int):
+    """``(first, nominal)`` of step ``j``: it covers the slots from
+    ``nominal`` on and is fetched from ``first``. They differ only where a
+    step would pass the end of the cache (``T`` need not be a multiple of the
+    step): that step is fetched from ``T - step`` and the slots in front of
+    ``nominal``, which the step before it covered, are masked."""
+    nominal = origin + j * step
+    return jnp.minimum(nominal, T - step), nominal
+
+
+def decode_slots_streamed(kv_start, kv_len, T: int, step: int):
+    """Cache slots the walk fetches for the rows ``kv_start`` / ``kv_len``
+    describe, summed over them (one layer's call): what the model's
+    ``decode_slots_streamed`` counter adds a step, next to rows × ``T``."""
+    _, n = decode_block_plan(kv_start, kv_len, T, step)
+    return jnp.sum(n) * step
+
+
+def _decode_walk(kv_start_ref, kv_len_ref, turn_ref, m_scr, l_scr, acc_scr, *, T: int, step: int, copies, consume):
+    """One grid cell of a decode kernel: batch row ``b``'s window, fetched a
+    step at a time into one of two buffers while the step before it is
+    consumed; returns the row's attention output (float32, the
+    accumulator's shape). ``copies(row, first, buf)`` names the async copies
+    of the step that starts at slot ``first`` of ``row`` into buffer ``buf``;
+    ``consume(buf, first, lo, hi)`` folds the slots ``[lo, hi)`` of it into
+    the row's running softmax (``_softmax_fold`` over ``m_scr``, ``l_scr``,
+    ``acc_scr``). The first step of the NEXT row is started under this row's
+    last, so a row does not begin by waiting on HBM; ``turn_ref`` (SMEM)
+    carries which buffer that is across grid cells."""
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    b, nb = pl.program_id(0), pl.num_programs(0)
+    hint = math.gcd(math.gcd(step, DECODE_ALIGN), T - step)
+
+    def fetch(row, j, buf):
+        origin, _ = decode_block_plan(kv_start_ref[row], kv_len_ref[row], T, step)
+        first, _ = decode_step_bounds(origin, j, T, step)
+        return copies(row, pl.multiple_of(first, hint), buf)
+
+    @pl.when(b == 0)
+    def _first_row():
+        turn_ref[0] = 0
+        for c in fetch(0, 0, 0):
+            c.start()
+
+    turn = turn_ref[0]
+    start, end = kv_start_ref[b], kv_len_ref[b]
+    origin, n = decode_block_plan(start, end, T, step)
+
+    def one_step(j, carry):
+        buf = (turn + j) % 2
+        more = j + 1 < n  # else the next row's first step, if there is a next row
+
+        @pl.when(more | (b + 1 < nb))
+        def _prefetch():
+            row = jnp.where(more, b, jnp.minimum(b + 1, nb - 1))
+            for c in fetch(row, jnp.where(more, j + 1, 0), 1 - buf):
+                c.start()
+
+        for c in fetch(b, j, buf):
+            c.wait()
+        first, nominal = decode_step_bounds(origin, j, T, step)
+        consume(buf, pl.multiple_of(first, hint), jnp.maximum(start, nominal), end)
+        return carry
+
+    jax.lax.fori_loop(0, n, one_step, 0)
+    turn_ref[0] = (turn + n) % 2
+    return acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
+
+
+def _softmax_fold(s, ok, m_scr, l_scr, acc_scr, weigh):
+    """Fold one step's scores ``s`` (float32, slots last; ``ok`` its live
+    slots) into the running max / sum / accumulator; ``weigh(p)`` is the
+    step's probability-weighted sum of values."""
+    s = jnp.where(ok, s, NEG_INF)
+    m_prev = m_scr[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+    l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[:] = acc_scr[:] * alpha + weigh(p)
+    m_scr[:] = m_new
+
+
 def _decode_kernel(
     layer_ref,  # SMEM [1]
     kv_start_ref,  # SMEM [B]
     kv_len_ref,  # SMEM [B]
     q_ref,  # [1, K, G, hd]
-    k_ref,  # [1, 1, K, bk, hd]
-    v_ref,  # [1, 1, K, bk, hd]
+    k_hbm,  # [L, B, K, T, hd], left in HBM
+    v_hbm,  # [L, B, K, T, hd]
     o_ref,  # [1, K, G, hd]
+    k_buf,  # VMEM [2, K, step, hd]
+    v_buf,  # VMEM [2, K, step, hd]
+    sem,  # DMA [2, 2]
+    turn_ref,  # SMEM [1]
     m_scr,  # VMEM [K, G, 1]
     l_scr,  # VMEM [K, G, 1]
     acc_scr,  # VMEM [K, G, hd]
     *,
-    bk: int,
+    T: int,
+    step: int,
     scale: float,
 ):
-    b = pl.program_id(0)
-    kj = pl.program_id(1)
-    nk = pl.num_programs(1)
+    def copies(row, first, buf):
+        def src(ref):
+            return ref.at[layer_ref[0], row, :, pl.ds(first, step), :]
 
-    @pl.when(kj == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        return (pltpu.make_async_copy(src(k_hbm), k_buf.at[buf], sem.at[buf, 0]),
+                pltpu.make_async_copy(src(v_hbm), v_buf.at[buf], sem.at[buf, 1]))
 
-    # skip blocks entirely outside the row's valid [kv_start, kv_len) window
-    blk_lo = kj * bk
-    live = (blk_lo < kv_len_ref[b]) & (blk_lo + bk > kv_start_ref[b])
-
-    @pl.when(live)
-    def _compute():
+    def consume(buf, first, lo, hi):
         q = q_ref[0]  # [K, G, hd]
-        k = k_ref[0, 0]  # [K, bk, hd]
-        v = v_ref[0, 0]
-        # zero K/V rows outside the valid window BEFORE any matmul: cache
-        # slots past the frontier may be uninitialized device memory, and a
-        # NaN there survives even a zero-weight product (0 * NaN = NaN)
-        rpos = blk_lo + jax.lax.broadcasted_iota(
-            jnp.int32, (k.shape[0], k.shape[1], 1), 1
-        )
-        rok = (rpos >= kv_start_ref[b]) & (rpos < kv_len_ref[b])
+        k = k_buf[buf]  # [K, step, hd]
+        v = v_buf[buf]
+        # zero K/V rows outside the live slots BEFORE any matmul: cache
+        # slots past the frontier may be uninitialized device memory, and
+        # a NaN there survives even a zero-weight product (0 * NaN = NaN)
+        rpos = first + jax.lax.broadcasted_iota(jnp.int32, (k.shape[0], step, 1), 1)
+        rok = (rpos >= lo) & (rpos < hi)
         k = jnp.where(rok, k, 0)
         v = jnp.where(rok, v, 0)
-        # one batched dot over all kv heads: [K, G, hd] x [K, bk, hd] -> [K, G, bk]
+        # one batched dot over all kv heads: [K, G, hd] x [K, step, hd] -> [K, G, step]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
         ) * scale
-
-        k_pos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        ok = (k_pos >= kv_start_ref[b]) & (k_pos < kv_len_ref[b])
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=2, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+        k_pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        _softmax_fold(
+            s, (k_pos >= lo) & (k_pos < hi), m_scr, l_scr, acc_scr,
+            lambda p: jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32),
         )
-        m_scr[:] = m_new
 
-    @pl.when(kj == nk - 1)
-    def _emit():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+    o_ref[0] = _decode_walk(
+        kv_start_ref, kv_len_ref, turn_ref, m_scr, l_scr, acc_scr,
+        T=T, step=step, copies=copies, consume=consume,
+    ).astype(o_ref.dtype)
+
+
+def gqa_decode_step(T: int, kv_heads: int, group: int, head_dim: int, dtype) -> int:
+    """``decode_step`` for ``decode_attention`` (a bf16 / float32 cache) and
+    ``decode_attention_q8`` (int8, with two float32 scales a head-vector) on
+    the shapes ONE device's kernel sees (``kv_heads`` local under tp): the
+    wrappers and the model's counters (``models/llama.py``) ask this one
+    function."""
+    dtype = jnp.dtype(dtype)
+    width = head_dim + 4 if dtype == jnp.int8 else head_dim * dtype.itemsize
+    return decode_step(T, 2 * kv_heads * width, kv_heads * group, head_dim)
+
+
+def _decode_step_or(bk: Optional[int], rule: int, T: int, tile: int, interpret: bool) -> int:
+    """The rule's step, or a caller's (``bk``: tests, sweeps); on hardware a
+    step is whole sublane tiles (``tile`` rows) of the payload."""
+    step = rule if bk is None else min(bk, T)
+    if not interpret and (step % tile or (T - step) % tile):
+        raise ValueError(
+            f"cache length T={T} walked in steps of {step}: both must be multiples of "
+            f"{tile} slots — the engine rounds cache lengths to {DECODE_ALIGN} for this"
+        )
+    return step
 
 
 def _decode_block(T: int, bk: int) -> int:
-    """Largest K/V block ≤ ``bk`` that tiles ``T`` exactly: prefer the coarse
-    candidates (more MXU work per sequential grid step), else the largest
-    divisor of ``T`` that fits — any caller-supplied ``bk`` works."""
+    """The chunk kernels' K/V block (``chunk_prefill_attention[_q8]``,
+    ``_chunk_grouped``): the largest ≤ ``bk`` that tiles ``T`` exactly —
+    prefer the coarse candidates (more MXU work per sequential grid step),
+    else the largest divisor of ``T`` that fits, so any caller-supplied
+    ``bk`` works. The single-token decode kernels left this rule in PR 32
+    (``decode_step``: their steps need not divide ``T``)."""
     if T <= bk:
         return T
     for cand in (512, 384, 256, 128):
@@ -473,56 +638,51 @@ def decode_attention(
     kv_start: jax.Array,  # [B] int32: first valid cache slot (left-pad offset)
     kv_len: jax.Array,  # [B] int32: valid frontier (exclusive)
     layer: jax.Array,  # [] or [1] int32: which layer's cache to attend over
-    bk: int = 512,
+    bk: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused single-token decode attention over the KV cache.
 
     Replaces the reference's per-step torch attention inside ``model.generate``
-    (/root/reference/llm/rag.py:172). The kernel reads ITS OWN layer straight
-    out of the full stacked cache — ``layer`` rides scalar prefetch into the
-    block index map, so no per-layer slice of the multi-GB cache is ever
-    materialized. One grid cell per batch row: all K kv heads' blocks stream
-    together (one batched MXU dot per block — the grid stays coarse so
-    per-step kernel overhead never dominates the bandwidth-bound cache scan),
-    with the flash recurrence across blocks; blocks outside
-    ``[kv_start, kv_len)`` are compute-skipped. The ``[.., K, T, hd]`` layout
-    makes every block K contiguous ``(bk, hd)`` slabs — tiled exactly for the
-    VPU/MXU, no transposition of cache memory ever happens.
+    (/root/reference/llm/rag.py:172). The cache stays in HBM and the kernel
+    copies what it reads itself: ``layer`` and the row's window
+    ``[kv_start, kv_len)`` ride scalar prefetch into the copies' addresses,
+    so no per-layer slice of the multi-GB cache is ever materialized and no
+    slot outside the steps that window touches is ever FETCHED
+    (``decode_block_plan``; bandwidth scales with the live tokens, not with
+    the allocation). One grid cell per batch row: all K kv heads' slots of a
+    step arrive in one double-buffered copy (``decode_step`` sizes it, ≈ 1 MiB;
+    ``bk`` overrides the step), the next step — or the next row's first — in
+    flight while this one is consumed, one batched MXU dot a step, with the
+    flash recurrence across steps. The ``[.., K, T, hd]`` layout
+    makes every step K contiguous ``(step, hd)`` slabs — tiled exactly for
+    the VPU/MXU, no transposition of cache memory ever happens.
     """
     B, S, H, hd = q.shape
     assert S == 1, f"decode_attention is single-token (got S={S})"
     L, _, K, T, _ = k_cache.shape
     G = H // K
-    req_bk = bk
-    bk = _decode_block(T, bk)
-    assert T % bk == 0, (T, bk)
-    if not interpret and bk % 16:
-        # a (bk, hd) block's second-to-minor dim must meet Mosaic's 16-row
-        # bf16 tile on real hardware; fail actionably instead of opaquely
-        raise ValueError(
-            f"cache length T={T} only tiles into blocks of {bk} ≤ bk={req_bk}: "
-            "pad T to a multiple of 128 — the engine rounds cache lengths for this"
-        )
+    step = _decode_step_or(bk, gqa_decode_step(T, K, G, hd, k_cache.dtype), T, 16, interpret)
 
-    qh = q.reshape(B, K, G, hd)
-    grid = (B, T // bk)
-
-    def kv_index(b, kj, layer_ref, *s_):
-        return (layer_ref[0], b, 0, kj, 0)
+    def row_block(b, *s_):
+        return (b, 0, 0, 0)
 
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, bk=bk, scale=hd**-0.5),
+        functools.partial(_decode_kernel, T=T, step=step, scale=hd**-0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
+            grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, K, G, hd), lambda b, kj, *s_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, 1, K, bk, hd), kv_index),
-                pl.BlockSpec((1, 1, K, bk, hd), kv_index),
+                pl.BlockSpec((1, K, G, hd), row_block),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, K, G, hd), lambda b, kj, *s_: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, K, G, hd), row_block),
             scratch_shapes=[
+                pltpu.VMEM((2, K, step, hd), k_cache.dtype),
+                pltpu.VMEM((2, K, step, hd), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((K, G, 1), jnp.float32),
                 pltpu.VMEM((K, G, 1), jnp.float32),
                 pltpu.VMEM((K, G, hd), jnp.float32),
@@ -535,7 +695,7 @@ def decode_attention(
         jnp.asarray(layer, jnp.int32).reshape(1),
         kv_start.astype(jnp.int32),
         kv_len.astype(jnp.int32),
-        qh,
+        q.reshape(B, K, G, hd),
         k_cache,
         v_cache,
     )
@@ -906,80 +1066,69 @@ def dequantize_layer_slice(
 
 
 def _decode_kernel_q8(
-    layer_ref,  # SMEM [1] (consumed by the index maps)
+    layer_ref,  # SMEM [1]
     kv_start_ref,  # SMEM [B]
     kv_len_ref,  # SMEM [B]
     q_ref,  # [1, K, G, hd]
-    k_ref,  # [1, 1, K, bk, hd] int8
-    v_ref,  # [1, 1, K, bk, hd] int8
-    ks_ref,  # [1, 1, K, bk] fp32
-    vs_ref,  # [1, 1, K, bk] fp32
+    k_hbm,  # [L, B, K, T, hd] int8, left in HBM
+    v_hbm,  # [L, B, K, T, hd] int8
+    ks_hbm,  # [L, B, K, T] fp32
+    vs_hbm,  # [L, B, K, T] fp32
     o_ref,  # [1, K, G, hd]
+    k_buf,  # VMEM [2, K, step, hd] int8
+    v_buf,  # VMEM [2, K, step, hd] int8
+    ks_buf,  # VMEM [2, K, step] fp32
+    vs_buf,  # VMEM [2, K, step] fp32
+    sem,  # DMA [2, 4]
+    turn_ref,  # SMEM [1]
     m_scr,  # VMEM [K, G, 1]
     l_scr,  # VMEM [K, G, 1]
     acc_scr,  # VMEM [K, G, hd]
     *,
-    bk: int,
+    T: int,
+    step: int,
     scale: float,
 ):
-    b = pl.program_id(0)
-    kj = pl.program_id(1)
-    nk = pl.num_programs(1)
+    def copies(row, first, buf):
+        lay, win = layer_ref[0], pl.ds(first, step)
+        return (pltpu.make_async_copy(k_hbm.at[lay, row, :, win, :], k_buf.at[buf], sem.at[buf, 0]),
+                pltpu.make_async_copy(v_hbm.at[lay, row, :, win, :], v_buf.at[buf], sem.at[buf, 1]),
+                pltpu.make_async_copy(ks_hbm.at[lay, row, :, win], ks_buf.at[buf], sem.at[buf, 2]),
+                pltpu.make_async_copy(vs_hbm.at[lay, row, :, win], vs_buf.at[buf], sem.at[buf, 3]))
 
-    @pl.when(kj == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    blk_lo = kj * bk
-    live = (blk_lo < kv_len_ref[b]) & (blk_lo + bk > kv_start_ref[b])
-
-    @pl.when(live)
-    def _compute():
+    def consume(buf, first, lo, hi):
         q = q_ref[0]  # [K, G, hd]
         # int8 payloads need NO validity masking: unlike bf16 (where an
         # uninitialized slot can hold NaN that survives 0-weighting), every
-        # int8 bit pattern is a finite value, and invalid columns are
+        # int8 bit pattern is a finite value, and dead columns are
         # eliminated by the score mask + zeroed scales below. The convert
         # to the matmul dtype is the only per-element op on the payload.
-        k = k_ref[0, 0].astype(q.dtype)  # [K, bk, hd]
-        rpos = blk_lo + jax.lax.broadcasted_iota(
-            jnp.int32, (k.shape[0], bk), 1
-        )
-        rok = (rpos >= kv_start_ref[b]) & (rpos < kv_len_ref[b])
+        k = k_buf[buf].astype(q.dtype)  # [K, step, hd]
+        rpos = first + jax.lax.broadcasted_iota(jnp.int32, (k.shape[0], step), 1)
+        rok = (rpos >= lo) & (rpos < hi)
         # scales CAN be NaN past the frontier (uninitialized fp32 memory):
-        # zero them under the window mask — [K, bk] work, not [K, bk, hd]
-        ks = jnp.where(rok, ks_ref[0, 0], 0.0)
-        vs = jnp.where(rok, vs_ref[0, 0], 0.0)
+        # zero them under the window mask — [K, step] work, not [K, step, hd]
+        ks = jnp.where(rok, ks_buf[buf], 0.0)
+        vs = jnp.where(rok, vs_buf[buf], 0.0)
         # dequantization rides the EPILOGUES: scores scale per key column,
-        # probabilities fold the V scale — O(K*G*bk) multiplies instead of
-        # O(K*bk*hd) on the payload (the whole point: the int8 win is
+        # probabilities fold the V scale — O(K*G*step) multiplies instead
+        # of O(K*step*hd) on the payload (the whole point: the int8 win is
         # bandwidth, so the kernel must not spend it back in VPU flops)
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
         ) * scale * ks[:, None, :]
-
-        k_pos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        ok = (k_pos >= kv_start_ref[b]) & (k_pos < kv_len_ref[b])
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=2, keepdims=True)
-        pv = (p * vs[:, None, :]).astype(q.dtype)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            pv, v_ref[0, 0].astype(q.dtype), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+        k_pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        _softmax_fold(
+            s, (k_pos >= lo) & (k_pos < hi), m_scr, l_scr, acc_scr,
+            lambda p: jax.lax.dot_general(
+                (p * vs[:, None, :]).astype(q.dtype), v_buf[buf].astype(q.dtype),
+                (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32),
         )
-        m_scr[:] = m_new
 
-    @pl.when(kj == nk - 1)
-    def _emit():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+    o_ref[0] = _decode_walk(
+        kv_start_ref, kv_len_ref, turn_ref, m_scr, l_scr, acc_scr,
+        T=T, step=step, copies=copies, consume=consume,
+    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -992,51 +1141,39 @@ def decode_attention_q8(
     kv_start: jax.Array,  # [B] int32
     kv_len: jax.Array,  # [B] int32
     layer: jax.Array,  # [] or [1] int32
-    bk: int = 512,
+    bk: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """``decode_attention`` over an int8 KV cache (see module note above).
 
-    Same grid, masking, and streaming layout as the bf16 kernel; the only
-    addition is the two per-(token, head) scale planes riding alongside the
-    int8 payload blocks."""
+    Same walk over the row's live window, masking, and streaming layout as
+    the bf16 kernel; the only addition is the two per-(token, head) scale
+    planes, whose slots of a step ride their own copies beside the int8
+    payload's."""
     B, S, H, hd = q.shape
     assert S == 1, f"decode_attention_q8 is single-token (got S={S})"
     L, _, K, T, _ = k_cache.shape
     G = H // K
-    req_bk = bk
-    bk = _decode_block(T, bk)
-    assert T % bk == 0, (T, bk)
-    if not interpret and bk % 32:
-        # int8 blocks need a 32-row second-to-minor tile on real hardware
-        raise ValueError(
-            f"cache length T={T} only tiles into blocks of {bk} ≤ bk={req_bk}: "
-            "pad T to a multiple of 128 — the engine rounds cache lengths for this"
-        )
+    # int8 rows come in sublane tiles of 32; the scale planes' lanes in 128
+    step = _decode_step_or(bk, gqa_decode_step(T, K, G, hd, k_cache.dtype), T, 32, interpret)
 
-    qh = q.reshape(B, K, G, hd)
-    grid = (B, T // bk)
-
-    def kv_index(b, kj, layer_ref, *s_):
-        return (layer_ref[0], b, 0, kj, 0)
-
-    def sc_index(b, kj, layer_ref, *s_):
-        return (layer_ref[0], b, 0, kj)
+    def row_block(b, *s_):
+        return (b, 0, 0, 0)
 
     out = pl.pallas_call(
-        functools.partial(_decode_kernel_q8, bk=bk, scale=hd**-0.5),
+        functools.partial(_decode_kernel_q8, T=T, step=step, scale=hd**-0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, K, G, hd), lambda b, kj, *s_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, 1, K, bk, hd), kv_index),
-                pl.BlockSpec((1, 1, K, bk, hd), kv_index),
-                pl.BlockSpec((1, 1, K, bk), sc_index),
-                pl.BlockSpec((1, 1, K, bk), sc_index),
-            ],
-            out_specs=pl.BlockSpec((1, K, G, hd), lambda b, kj, *s_: (b, 0, 0, 0)),
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, K, G, hd), row_block)] + [pl.BlockSpec(memory_space=pl.ANY)] * 4,
+            out_specs=pl.BlockSpec((1, K, G, hd), row_block),
             scratch_shapes=[
+                pltpu.VMEM((2, K, step, hd), jnp.int8),
+                pltpu.VMEM((2, K, step, hd), jnp.int8),
+                pltpu.VMEM((2, K, step), jnp.float32),
+                pltpu.VMEM((2, K, step), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 4)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((K, G, 1), jnp.float32),
                 pltpu.VMEM((K, G, 1), jnp.float32),
                 pltpu.VMEM((K, G, hd), jnp.float32),
@@ -1049,7 +1186,7 @@ def decode_attention_q8(
         jnp.asarray(layer, jnp.int32).reshape(1),
         kv_start.astype(jnp.int32),
         kv_len.astype(jnp.int32),
-        qh,
+        q.reshape(B, K, G, hd),
         k_cache,
         v_cache,
         k_scale,
@@ -1057,6 +1194,7 @@ def decode_attention_q8(
     )
 
     return out.reshape(B, 1, H, hd)
+
 
 
 def _chunk_kernel_q8(
@@ -1482,17 +1620,18 @@ def chunk_attention_grouped_q8(
 # paged KV cache (block-pool arena + per-row block tables)
 # ---------------------------------------------------------------------------
 #
-# The dense decode kernels above stream a [L, B, K, T, hd] cache whose T is
-# the engine's FULL window for every row — at B=64 that is mostly pad (a
-# 300-token prompt in a 4352-slot row), and the bandwidth-bound decode step
-# pays for every byte of it. The paged layout replaces the per-row T axis
-# with a POOL of fixed-size blocks, [L, N, K, bs, hd], plus a per-row int32
-# block table mapping logical block j of row b to a physical pool block.
-# The kernels below are the dense kernels with ONE change: the K/V block
-# index map reads the table (scalar prefetch, SMEM) instead of computing
-# kj directly — the flash recurrence, masking, and out-of-window block skip
-# are identical, and only a row's LIVE blocks are ever streamed, so decode
-# bandwidth scales with real tokens, not the window.
+# The dense kernels above read a [L, B, K, T, hd] cache whose T is the
+# engine's FULL window for every row — at B=64 that is mostly pad (a
+# 300-token prompt in a 4352-slot row): HBM ALLOCATED for every slot of it
+# (the single-token kernels fetch only a row's live window since PR 32; the
+# chunk kernels still fetch every block). The paged layout replaces the
+# per-row T axis with a POOL of fixed-size blocks, [L, N, K, bs, hd], plus a
+# per-row int32 block table mapping logical block j of row b to a physical
+# pool block. The kernels below keep the BlockSpec pipeline: the K/V block
+# index map reads the table (scalar prefetch, SMEM) — the flash recurrence,
+# masking, and out-of-window block skip are the dense chunk kernels', and
+# only a row's LIVE blocks are ever allocated or streamed, so decode
+# bandwidth and footprint scale with real tokens, not the window.
 #
 # Geometry: paged rows are RIGHT-padded — logical positions start at 0, the
 # valid window is [0, kv_len), and kv_start does not exist (this is also

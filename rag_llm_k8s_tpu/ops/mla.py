@@ -33,7 +33,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from rag_llm_k8s_tpu.ops.attention import NEG_INF, _decode_block, _flash_call
+from rag_llm_k8s_tpu.ops.attention import (
+    NEG_INF, _decode_step_or, _decode_walk, _flash_call, _softmax_fold, decode_step,
+)
 
 # a dense score plane is [B, H, S, T] fp32: queries beyond this many go
 # through it a block at a time
@@ -146,63 +148,61 @@ def latent_attention_xla(
     return jnp.moveaxis(out, 0, 1).reshape(q_lat.shape)
 
 
+def latent_decode_step(T: int, heads: int, rank: int, dtype):
+    """``ops/attention.py decode_step`` on the latent cache's shapes: a slot
+    is one latent of ``rank`` values (the rotated key slices ride whole a
+    row), attended by ``heads`` query rows. The kernel's wrapper and the
+    model's counters (``models/latent_moe.py``) ask this one function."""
+    return decode_step(T, rank * jnp.dtype(dtype).itemsize, heads, rank)
+
+
 def _mla_decode_kernel(
     layer_ref,  # SMEM [1]
     kv_start_ref,  # SMEM [B]
     kv_len_ref,  # SMEM [B]
     ql_ref,  # [1, H, C]
     qr_ref,  # [1, H, R]
-    c_ref,  # [1, 1, bk, C]
-    r_ref,  # [1, 1, bk, R]
+    c_hbm,  # [L, B, T, C], left in HBM
+    r_ref,  # [1, 1, T, R]: the row's whole plane of rotated key slices
     o_ref,  # [1, H, C]
+    c_buf,  # VMEM [2, step, C]
+    sem,  # DMA [2, 1]
+    turn_ref,  # SMEM [1]
     m_scr,  # VMEM [H, 1]
     l_scr,  # VMEM [H, 1]
     acc_scr,  # VMEM [H, C]
     *,
-    bk: int,
+    T: int,
+    step: int,
     scale: float,
 ):
-    b = pl.program_id(0)
-    kj = pl.program_id(1)
-    nk = pl.num_programs(1)
+    def copies(row, first, buf):
+        src = c_hbm.at[layer_ref[0], row, pl.ds(first, step), :]
+        return (pltpu.make_async_copy(src, c_buf.at[buf], sem.at[buf, 0]),)
 
-    @pl.when(kj == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    blk_lo = kj * bk
-    live = (blk_lo < kv_len_ref[b]) & (blk_lo + bk > kv_start_ref[b])
-
-    @pl.when(live)
-    def _compute():
-        c = c_ref[0, 0]  # [bk, C]
-        r = r_ref[0, 0]  # [bk, R]
-        # zero rows outside the window BEFORE any matmul (see _decode_kernel)
-        rpos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-        rok = (rpos >= kv_start_ref[b]) & (rpos < kv_len_ref[b])
+    def consume(buf, first, lo, hi):
+        c = c_buf[buf]  # [step, C]
+        r = r_ref[0, 0, pl.ds(first, step), :]  # [step, R]
+        # zero rows outside the live slots BEFORE any matmul (see _decode_kernel)
+        rpos = first + jax.lax.broadcasted_iota(jnp.int32, (step, 1), 0)
+        rok = (rpos >= lo) & (rpos < hi)
         c = jnp.where(rok, c, 0)
         r = jnp.where(rok, r, 0)
         dims = (((1,), (1,)), ((), ()))
         s = jax.lax.dot_general(ql_ref[0], c, dims, preferred_element_type=jnp.float32)
         s = s + jax.lax.dot_general(qr_ref[0], r, dims, preferred_element_type=jnp.float32)
-        s = s * scale  # [H, bk]
-        k_pos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = (k_pos >= kv_start_ref[b]) & (k_pos < kv_len_ref[b])
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(c.dtype), c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+        s = s * scale  # [H, step]
+        k_pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _softmax_fold(
+            s, (k_pos >= lo) & (k_pos < hi), m_scr, l_scr, acc_scr,
+            lambda p: jax.lax.dot_general(
+                p.astype(c.dtype), c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32),
+        )
 
-    @pl.when(kj == nk - 1)
-    def _emit():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+    o_ref[0] = _decode_walk(
+        kv_start_ref, kv_len_ref, turn_ref, m_scr, l_scr, acc_scr,
+        T=T, step=step, copies=copies, consume=consume,
+    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "bk", "interpret"))
@@ -216,37 +216,46 @@ def mla_decode_attention(
     layer: jax.Array,  # [] or [1] int32
     *,
     scale: float,
-    bk: int = 512,
+    bk: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Single-token absorbed attention over the latent cache: one grid cell a
-    (row, cache block), all H query heads against the row's one latent "KV
-    head" as the rows of one matmul, so the cache streams once a row. The
-    layer rides scalar prefetch into the block index (no per-layer slice of
-    the cache is materialized). Returns ``[B, 1, H, C]``."""
+    row, all H query heads against the row's one latent "KV head" as the rows
+    of one matmul, so the cache streams once a row. The walk is
+    ``ops/attention.py``'s (``decode_block_plan``, ``_decode_walk``): the
+    latents stay in HBM, the layer and the row's window ride scalar prefetch
+    into the copies' addresses, and only the steps the window
+    ``[kv_start, kv_len)`` touches are fetched (no per-layer slice of the
+    cache is materialized, no dead step of latents is read). The rotated key
+    slices (a ninth of a slot's bytes) ride whole a row, a block of the
+    pipeline's: a plane ``R`` = 64 wide is padded to the 128-lane tile in
+    HBM, and Mosaic refuses to slice such a plane for a copy of the kernel's
+    own (compiled for a v5e, PR 32). Returns ``[B, 1, H, C]``."""
     B, S, H, C = q_lat.shape
     assert S == 1, f"mla_decode_attention is single-token (got S={S})"
     R = q_rope.shape[-1]
     T = c_cache.shape[2]
-    bk = _decode_block(T, bk)
-    assert T % bk == 0, (T, bk)
+    step = _decode_step_or(bk, latent_decode_step(T, H, C, c_cache.dtype), T, 16, interpret)
 
-    def cache_index(b, kj, layer_ref, *s_):
-        return (layer_ref[0], b, kj, 0)
+    def row_block(b, *s_):
+        return (b, 0, 0)
 
     out = pl.pallas_call(
-        functools.partial(_mla_decode_kernel, bk=bk, scale=scale),
+        functools.partial(_mla_decode_kernel, T=T, step=step, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, T // bk),
+            grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, H, C), lambda b, kj, *s_: (b, 0, 0)),
-                pl.BlockSpec((1, H, R), lambda b, kj, *s_: (b, 0, 0)),
-                pl.BlockSpec((1, 1, bk, C), cache_index),
-                pl.BlockSpec((1, 1, bk, R), cache_index),
+                pl.BlockSpec((1, H, C), row_block),
+                pl.BlockSpec((1, H, R), row_block),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, 1, T, R), lambda b, layer_ref, *s_: (layer_ref[0], b, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, H, C), lambda b, kj, *s_: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, C), row_block),
             scratch_shapes=[
+                pltpu.VMEM((2, step, C), c_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 1)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, C), jnp.float32),

@@ -348,9 +348,11 @@ def test_llama_tree_is_untouched_by_the_seam():
     assert isinstance(llama.build_model(cfg, FP32, EngineConfig(), None, fused=False, quantized=False),
                       LlamaModel)
     assert isinstance(families.make_cache(cfg, 1, 128, jnp.float32), KVCache)
-    # its cache counts the token rows its prefills computed (PR 28), nothing of a router's
-    assert llama.counter_names == ("prefill_tokens_computed", "prefill_tokens_bucketed")
-    assert llama.counters_width == 2 and llama.checkpoint_loader_refusal is None
+    # its cache counts the token rows its prefills computed (PR 28) and the cache
+    # slots its decode kernel fetched (PR 32, appended), nothing of a router's
+    assert llama.counter_names == ("prefill_tokens_computed", "prefill_tokens_bucketed",
+                                   "decode_slots_streamed", "decode_slots_allocated")
+    assert llama.counters_width == 4 and llama.checkpoint_loader_refusal is None
     assert families.of(CFG).counters_width == lm.N_COUNTERS
     assert set(families.of(CFG).counter_names) == set(lm.fold_counters(np.zeros(lm.N_COUNTERS)))
     with pytest.raises(TypeError, match="no decoder family"):

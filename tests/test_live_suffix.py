@@ -118,8 +118,8 @@ def test_suffix_prefill_equals_full_prefill(params, lens, fused, kv_quant):
     off = _rung_offset(kv_start)
     assert off == {(900,): 320, (700, 1000, 1279): 0}[lens]
     B = len(lens)
-    assert cache.counters.tolist() == [B * (S - off), B * S]
-    assert ref.counters.tolist() == [B * S, B * S]  # every position's logits leave: no branch
+    assert cache.counters.tolist()[:2] == [B * (S - off), B * S]
+    assert ref.counters.tolist()[:2] == [B * S, B * S]  # every position's logits leave: no branch
 
 
 def test_suffix_prefill_with_int8_weights(params):
@@ -130,7 +130,7 @@ def test_suffix_prefill_with_int8_weights(params):
     want, ref, _ = _prefill(model, tree, tokens, mask, last=False)
     np.testing.assert_allclose(np.asarray(got[:, -1]), np.asarray(want[:, -1]), atol=2e-5)
     _assert_same_on_live_slots(cache, ref, kv_start)
-    assert cache.counters.tolist() == [2 * (S - 480), 2 * S]
+    assert cache.counters.tolist()[:2] == [2 * (S - 480), 2 * S]
 
 
 @pytest.mark.parametrize("lens, off", [
@@ -145,7 +145,7 @@ def test_which_rung_a_batch_takes(params, lens, off):
     got, cache, kv_start = _prefill(model, params, tokens, mask, last=True)
     want, ref, _ = _prefill(model, params, tokens, mask, last=False)
     assert _rung_offset(kv_start) == off
-    assert cache.counters.tolist() == [len(lens) * (S - off), len(lens) * S]
+    assert cache.counters.tolist()[:2] == [len(lens) * (S - off), len(lens) * S]
     live = [i for i, n in enumerate(lens) if n]  # an empty row's logits are nobody's
     np.testing.assert_allclose(np.asarray(got[live, -1]), np.asarray(want[live, -1]), atol=2e-5)
     _assert_same_on_live_slots(cache, ref, [kv_start[i] if i in live else S for i in range(len(lens))])
@@ -182,6 +182,12 @@ def _engine(tree, mesh=None, **kw):
     return InferenceEngine(CFG, tree, sampling=GREEDY, engine_config=ec, dtypes=FP32, mesh=mesh)
 
 
+def _prefill_counters(eng):
+    """The counters of this file (the decode kernel's, PR 32, ride the same
+    row and stay 0 on the XLA path these engines take)."""
+    return {k: v for k, v in eng.stats.family_counters.items() if k.startswith("prefill_")}
+
+
 def _no_rungs(monkeypatch):
     """The parent's program, for a reference: steered here, in the test; the
     program has no option for it."""
@@ -196,15 +202,15 @@ def test_engine_counts_what_the_branch_did(params, monkeypatch):
     S - 1) does not move the minimum, 1280 - 900 = 380 -> the rung at 320."""
     eng = _engine(params)
     got = eng.generate(PROMPTS)
-    assert eng.stats.family_counters == {
+    assert _prefill_counters(eng) == {
         "prefill_tokens_computed": 4 * (S - 320), "prefill_tokens_bucketed": 4 * S}
     got1 = eng.generate(PROMPTS[:1])  # 1280 - 700 = 580 -> the last rung, 480
-    assert eng.stats.family_counters == {
+    assert _prefill_counters(eng) == {
         "prefill_tokens_computed": 4 * (S - 320) + (S - 480), "prefill_tokens_bucketed": 5 * S}
     _no_rungs(monkeypatch)
     ref = _engine(params)
     assert ref.generate(PROMPTS) == got and ref.generate(PROMPTS[:1]) == got1
-    assert ref.stats.family_counters == {
+    assert _prefill_counters(ref) == {
         "prefill_tokens_computed": 5 * S, "prefill_tokens_bucketed": 5 * S}
 
 
@@ -213,7 +219,7 @@ def test_the_speculative_program_counts_too(params, monkeypatch):
     eng = _engine(params, speculative="prompt_lookup", kv_quant="int8")
     got = eng.generate(prompt)
     assert eng.stats.spec_verify_steps > 0
-    assert eng.stats.family_counters == {
+    assert _prefill_counters(eng) == {
         "prefill_tokens_computed": S - 480, "prefill_tokens_bucketed": S}
     _no_rungs(monkeypatch)
     assert _engine(params, speculative="prompt_lookup", kv_quant="int8").generate(prompt) == got
@@ -224,5 +230,5 @@ def test_under_a_tp_mesh(params):
     sharded = _engine(shard_llama_params(params, ctx), mesh=ctx)
     single = _engine(params)
     assert sharded.generate(PROMPTS) == single.generate(PROMPTS)
-    assert sharded.stats.family_counters == single.stats.family_counters == {
+    assert _prefill_counters(sharded) == _prefill_counters(single) == {
         "prefill_tokens_computed": 4 * (S - 320), "prefill_tokens_bucketed": 4 * S}

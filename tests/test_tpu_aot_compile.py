@@ -138,6 +138,13 @@ CASES = {
         A.decode_attention_q8,
         [((8, 1, H, HD), BF16), *_dense_cache(True, 8), ((8,), I32), ((8,), I32), ((), I32)],
     ),
+    # Mistral-Nemo under tp=4: what ONE device's kernel sees inside the
+    # shard_map (2 of 8 KV heads, 4 rows, 40 layers): a step of 1024 slots
+    "decode_attention[tp4 local heads]": (
+        A.decode_attention,
+        [((4, 1, 8, HD), BF16), ((40, 4, 2, T, HD), BF16), ((40, 4, 2, T, HD), BF16),
+         ((4,), I32), ((4,), I32), ((), I32)],
+    ),
     "chunk_prefill_attention": (
         A.chunk_prefill_attention,
         [((1, 512, H, HD), BF16), *_dense_cache(False, 1),
@@ -188,6 +195,11 @@ CASES.update({
         "mla_decode_attention",
         [((8, 1, 128, 512), BF16), ((8, 1, 128, 64), BF16), ((5, 8, T, 512), BF16),
          ((5, 8, T, 64), BF16), ((8,), I32), ((8,), I32), ((), I32)], scale=0.1),
+    # the shortcut-connected configuration: 64 heads over 8 cache planes
+    "mla_decode_attention[B=8, 64 heads]": _latent(
+        "mla_decode_attention",
+        [((8, 1, 64, 512), BF16), ((8, 1, 64, 64), BF16), ((8, 8, T, 512), BF16),
+         ((8, 8, T, 64), BF16), ((8,), I32), ((8,), I32), ((), I32)], scale=0.1),
     "grouped_matmul[prefill up]": _latent(
         "grouped_matmul",
         [((32768, 7168), BF16), ((4, 16, 7168, 2048), BF16), ((16,), I32), ((), I32)]),
@@ -218,6 +230,17 @@ def test_kernel_compiles_for_v5e(name, one_chip, uncached):
                            "flash_attention[encoder S=2048]": (32 * 16, 2048, 64),
                            "flash_attention[16384 streamed]": (H, 16384, HD)}[name]
         assert re.search(rf"%{kernel}(\.\d+)? = bf16\[{heads},{S},{width}\]\S* custom-call\(", text), name
+        assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
+    if re.match(r"(mla_)?decode_attention", name):
+        # the benchmark finds the decode kernels by name and by result shape
+        # (``mla_decode_attention_roofline``: [rows, heads, rank]; the phases'
+        # ``decode_attention_q8 [8,8,4,128]``): a walk of the kernel's own
+        # copies must leave both as they were, inside the default scoped VMEM
+        shape = {"decode_attention": "8,8,4,128", "decode_attention_q8": "8,8,4,128",
+                 "decode_attention[tp4 local heads]": "4,2,4,128",
+                 "mla_decode_attention[B=8]": "8,128,512",
+                 "mla_decode_attention[B=8, 64 heads]": "8,64,512"}[name]
+        assert re.search(rf"%{name.split('[')[0]}(\.\d+)? = bf16\[{shape}\]\S* custom-call\(", text), name
         assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
 
 

@@ -10,6 +10,7 @@ to bf16 and the two implementations differ by rounding noise, not bugs).
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from rag_llm_k8s_tpu.core.config import (
     DTypePolicy,
@@ -74,6 +75,20 @@ class TestAttentionKernels:
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
             )
+
+    @pytest.mark.parametrize("name", [
+        "decode_attention_q8[8,8,4,128]", "decode_attention[4,2,4,128]",
+        "mla_decode_attention[8,128,512]", "mla_decode_attention[8,64,512]"])
+    def test_decode_kernels_at_the_serving_shapes(self, name):
+        """The benchmark's four batched cells' decode calls (bf16 / int8 KV,
+        T = 4352, the cells' left pads): ``chip_smoke.py``'s cases, one each."""
+        import chip_smoke
+
+        assert set(chip_smoke.SERVING_DECODE) >= {name}
+        (run, rtol, atol), = [(r, rt, at) for n, r, rt, at in chip_smoke.kernel_cases(0) if n == name]
+        got, want = run()
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                                   rtol=rtol, atol=atol)
 
     def test_decode_q8_matches_oracle_on_mosaic(self):
         """int8-KV decode kernel on real Mosaic vs its XLA oracle — the
