@@ -349,6 +349,202 @@ class LatentMoEConfig:
 
 
 @dataclass(frozen=True)
+class RopeParameters:
+    """One layer type's rotary table as ``rope_parameters`` publishes it:
+    ``rope_type`` ``default`` (``theta`` alone) or ``yarn`` (the frequencies
+    blended between ``theta_i`` and ``theta_i / factor`` by ``beta_fast`` /
+    ``beta_slow`` turns in ``original_max_position_embeddings``, cos and sin
+    times ``attention_factor``). ``partial_rotary_factor`` is the share of a
+    head's dimensions that rotate (the first ones); the rest pass through."""
+
+    rope_theta: float = 10000.0
+    rope_type: str = "default"
+    partial_rotary_factor: float = 1.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if self.rope_type not in ("default", "yarn"):
+            raise ValueError(f"rope_type={self.rope_type!r}: 'default' or 'yarn'")
+        if not 0.0 < self.partial_rotary_factor <= 1.0:
+            raise ValueError(f"partial_rotary_factor={self.partial_rotary_factor}: in (0, 1]")
+
+
+@dataclass(frozen=True)
+class WindowedMoEConfig:
+    """The windowed-attention, sparse-expert decoder family
+    (``models/windowed_moe.py``): GQA over per-head K/V planes whose layers
+    differ in KIND (``layer_types``: ``full_attention``, or
+    ``sliding_attention`` over the last ``sliding_window`` tokens), in query
+    heads (``num_attention_heads_per_layer``) and in rotary table
+    (``rope_parameters``, one a layer type), with a per-head gate on
+    attention's output; ``mlp_layer_types`` says which layers' FFN is a dense
+    SwiGLU (``intermediate_size``) and which a routed mixture of
+    ``num_experts`` SwiGLU experts (``moe_intermediate_size``, sigmoid scores,
+    top ``num_experts_per_tok``) beside a shared expert. Field names are the
+    published ``config.json``'s.
+
+    The layers' loop runs a PERIOD a trip, because parameter shapes differ by
+    layer type: a leading run of dense layers sits outside it (``num_lead``),
+    and what follows must be whole periods, each the same pattern of (layer
+    type, heads) that ends with its full layer (``period``). ``ep_size`` / ``ep_rank``: this chip's share of the routed
+    experts, as ``LatentMoEConfig``.
+
+    Defaults are the published widths of the 118B decoder the
+    ``laguna-s-ep16.closed8`` cell serves a share of, at the cell's depth."""
+
+    vocab_size: int = 100352
+    hidden_size: int = 3072
+    intermediate_size: int = 12288
+    moe_intermediate_size: int = 1024
+    shared_expert_intermediate_size: int = 1024
+    num_kv_heads: int = 8
+    head_dim: int = 128
+    sliding_window: int = 512
+    layer_types: Tuple[str, ...] = ("full_attention",) + ("sliding_attention",) * 3 + ("full_attention",)
+    num_attention_heads_per_layer: Tuple[int, ...] = (48, 72, 72, 72, 48)
+    mlp_layer_types: Tuple[str, ...] = ("dense",) + ("sparse",) * 4
+    rope_parameters: Tuple[Tuple[str, RopeParameters], ...] = (
+        ("full_attention", RopeParameters(
+            rope_theta=500000.0, rope_type="yarn", partial_rotary_factor=0.5, factor=128.0,
+            original_max_position_embeddings=8192, beta_fast=32.0, beta_slow=1.0,
+            attention_factor=1.4852030263919618)),
+        ("sliding_attention", RopeParameters(rope_theta=10000.0)),
+    )
+    num_experts: int = 256
+    num_experts_per_tok: int = 10
+    moe_routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    moe_router_logit_softcapping: float = 0.0
+    moe_apply_router_weight_on_input: bool = False
+    ep_size: int = 1
+    ep_rank: int = 0
+    rms_norm_eps: float = 1e-6
+    max_seq_len: int = 1048576
+    tie_word_embeddings: bool = False
+    bos_token_id: int = 0
+    eos_token_ids: Tuple[int, ...] = (1,)
+
+    KINDS = ("full_attention", "sliding_attention")
+
+    def __post_init__(self):
+        L = len(self.layer_types)
+        if not L or len(self.num_attention_heads_per_layer) != L or len(self.mlp_layer_types) != L:
+            raise ValueError("layer_types, num_attention_heads_per_layer and mlp_layer_types "
+                             "name every layer once")
+        if set(self.layer_types) - set(self.KINDS) or set(self.mlp_layer_types) - {"dense", "sparse"}:
+            raise ValueError(f"layer_types are {self.KINDS}; mlp_layer_types 'dense' or 'sparse'")
+        if any(h % self.num_kv_heads for h in self.num_attention_heads_per_layer):
+            raise ValueError("every layer's query heads are a whole number a KV head")
+        lead = self.num_lead
+        if "dense" in self.mlp_layer_types[lead:]:
+            raise ValueError("mlp_layer_types: dense layers are a leading run (the layers' loop "
+                             "is over sparse layers)")
+        rest = tuple(zip(self.layer_types, self.num_attention_heads_per_layer))[lead:]
+        if rest and (len(rest) % self.period or rest != rest[:self.period] * (len(rest) // self.period)):
+            raise ValueError(
+                "the layers behind the leading dense ones must repeat one pattern of (layer "
+                f"type, heads), a period that ends with its full layer, a whole number of times; "
+                f"{len(rest)} layers in periods of {self.period} do not")
+        missing = set(self.layer_types) - {k for k, _ in self.rope_parameters}
+        if missing:
+            raise ValueError(f"rope_parameters has no table for {sorted(missing)}")
+        if self.moe_router_logit_softcapping:
+            raise ValueError("moe_router_logit_softcapping: the router's logits are not capped here (0)")
+        if self.moe_apply_router_weight_on_input:
+            raise ValueError("moe_apply_router_weight_on_input: the weights multiply the experts' OUTPUT")
+        if self.num_experts % self.ep_size or not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(
+                f"ep_size={self.ep_size}, ep_rank={self.ep_rank}: the {self.num_experts} routed "
+                "experts must divide evenly over the ranks and the rank must be one of them")
+        if self.shared_expert_intermediate_size % self.moe_intermediate_size:
+            raise ValueError("the shared expert is a whole number of routed experts wide")
+        if self.sliding_window < 1:
+            raise ValueError(f"sliding_window={self.sliding_window}: at least 1")
+        if self.tie_word_embeddings:
+            raise ValueError("this family serves an untied head only")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def num_lead(self) -> int:
+        """The leading run of dense layers, outside the layers' loop."""
+        return next((i for i, t in enumerate(self.mlp_layer_types) if t != "dense"), self.num_layers)
+
+    @property
+    def period(self) -> int:
+        """Layers a trip of the loop: a period runs up to and with its full
+        layer (``sliding ... sliding full``); 1 where the layers behind the
+        leading ones hold no full layer."""
+        kinds = self.layer_types[self.num_lead:]
+        return kinds.index("full_attention") + 1 if "full_attention" in kinds else 1
+
+    @property
+    def num_periods(self) -> int:
+        return (self.num_layers - self.num_lead) // self.period
+
+    @property
+    def num_sliding_layers(self) -> int:
+        return sum(t == "sliding_attention" for t in self.layer_types)
+
+    def rope_of(self, kind: str) -> RopeParameters:
+        return dict(self.rope_parameters)[kind]
+
+    # what ``models/latent_moe.py``'s sparse FFN (SparseMLP, Experts) reads of
+    # a configuration, under the names it reads them by
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.num_lead
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts // self.ep_size
+
+    @property
+    def first_held(self) -> int:
+        return self.ep_rank * self.experts_held
+
+    n_routed_experts = property(lambda self: self.num_experts)
+    router_width = property(lambda self: self.num_experts)
+    n_shared_experts = property(
+        lambda self: self.shared_expert_intermediate_size // self.moe_intermediate_size)
+    routed_scaling_factor = property(lambda self: self.moe_routed_scaling_factor)
+    scoring_func = property(lambda self: "sigmoid")
+    n_group = property(lambda self: 1)
+    topk_group = property(lambda self: 1)
+    zero_expert_num = property(lambda self: 0)
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 256, **overrides) -> "WindowedMoEConfig":
+        """Miniature config for CPU tests: a dense full layer, then two
+        periods of (two sliding layers of 9 heads a KV head, one full of 6);
+        window 8; 16 experts of which rank 1 of 2 holds 8."""
+        kinds = ("full_attention",) + ("sliding_attention", "sliding_attention", "full_attention") * 2
+        base = dict(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+            shared_expert_intermediate_size=32, num_kv_heads=2, head_dim=16, sliding_window=8,
+            layer_types=kinds,
+            num_attention_heads_per_layer=tuple(18 if k == "sliding_attention" else 12 for k in kinds),
+            mlp_layer_types=("dense",) + ("sparse",) * 6,
+            rope_parameters=(
+                ("full_attention", RopeParameters(
+                    rope_theta=500000.0, rope_type="yarn", partial_rotary_factor=0.5, factor=4.0,
+                    original_max_position_embeddings=32, attention_factor=1.1386)),
+                ("sliding_attention", RopeParameters(rope_theta=10000.0)),
+            ),
+            num_experts=16, num_experts_per_tok=4, ep_size=2, ep_rank=1,
+            max_seq_len=256, bos_token_id=1, eos_token_ids=(2,),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
 class EncoderConfig:
     """Bidirectional encoder config for the embedding model.
 

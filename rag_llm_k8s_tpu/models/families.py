@@ -13,7 +13,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
-from rag_llm_k8s_tpu.core.config import LatentMoEConfig, LlamaConfig
+from rag_llm_k8s_tpu.core.config import LatentMoEConfig, LlamaConfig, WindowedMoEConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,8 +123,54 @@ def _latent_moe() -> Family:
     )
 
 
+def _windowed_moe() -> Family:
+    from rag_llm_k8s_tpu.models import windowed_moe as wm
+    from rag_llm_k8s_tpu.parallel.sharding import windowed_moe_param_specs
+
+    def unsupported(engine_config, mesh, engine):
+        if engine == "continuous" or getattr(engine_config, "batching", "coalesce") == "continuous":
+            return ("the continuous engine (batching='continuous') or its paged KV pool",
+                    "the block pool has one table kind and every plane its full length: sliding "
+                    "layers want a ring of window slots and a table of their own; use 'coalesce'")
+        pc = getattr(engine_config, "prefix_cache", None)
+        if pc is not None and pc.enabled:
+            return ("the KV prefix cache (prefix_cache.enabled)",
+                    "a spliced segment's sliding layers saw another window than the prompt's, "
+                    "and rerotate_prefix_planes knows one rotary table, not one a layer kind")
+        if engine_config.kv_quant != "bf16":
+            return (f"kv_quant={engine_config.kv_quant!r}",
+                    "the windowed prefill and the chunk form read bf16 planes only")
+        if engine_config.weight_quant != "bf16":
+            return (f"weight_quant={engine_config.weight_quant!r}",
+                    "quantize_llama_params does not know this tree (projections that differ "
+                    "in shape by layer kind, stacked experts, the router)")
+        if mesh is not None and (mesh.tp > 1 or getattr(mesh, "sp", 1) > 1):
+            return (f"tp={mesh.tp}, sp={getattr(mesh, 'sp', 1)}",
+                    "this tree has no partition rules (72 and 48 query heads over 8 KV heads "
+                    "split differently), and experts across chips need the all-to-all")
+        return None
+
+    return Family(
+        name="the windowed-attention sparse-expert family (WindowedMoEConfig)",
+        build_model=lambda config, dtypes, engine_config, mesh, *, fused, quantized: wm.WindowedMoEModel(
+            config, dtypes, attn_impl=engine_config.attn_impl),
+        make_cache=lambda config, batch_size, max_seq_len, dtype, quant: wm.make_windowed_cache(
+            config, batch_size, max_seq_len, dtype),
+        param_specs=windowed_moe_param_specs,
+        counters_width=wm.N_COUNTERS,
+        counter_names=wm.COUNTER_NAMES,
+        fold_counters=wm.fold_counters,
+        unsupported=unsupported,
+        checkpoint_loader_refusal=(
+            "the checkpoint loader has no name map for the windowed-attention "
+            "sparse-expert family's tensors; serve it through assemble_service "
+            "with a parameter tree of your own"),
+    )
+
+
 # configuration type -> its family (a thunk where building it imports the model)
 _TABLE: Tuple[Tuple[type, Callable[[], Family]], ...] = (
+    (WindowedMoEConfig, _windowed_moe),
     (LatentMoEConfig, _latent_moe),
     (LlamaConfig, _llama),
 )

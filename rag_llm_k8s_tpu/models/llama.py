@@ -436,6 +436,148 @@ def decode_walk_step(config: LlamaConfig, attn_impl: str, mesh, cache: "KVCache"
     return gqa_decode_step(cache.k.shape[3], K // tp, H // K, config.head_dim, cache.k.dtype)
 
 
+def attend(
+    q, k, v, kv_start, kv_len, layer, *, mode: str, impl: str, mesh: Optional[Mesh] = None,
+    write_index=None, scales=None, window: Optional[int] = None, ring=None,
+) -> jax.Array:
+    """The attention seam of every family that keeps per-head K/V planes:
+    dispatch to the right backend (``impl``: a resolved ``attn_impl``). ``mode``:
+
+    - ``"prefill"``: fresh ``k``/``v`` ``[B, S, K, hd]``, causal within S;
+    - ``"decode"`` / ``"chunk"``: ``k``/``v`` are the FULL stacked
+      head-major cache ``[L, B, K, T, hd]`` read at ``layer`` (no
+      per-layer slice is ever materialized); ``chunk`` additionally takes
+      ``write_index`` — query ``t`` sits at cache slot ``write_index + t``
+      (offset causality over the populated prefix).
+
+    ``scales`` (int8-KV only): ``(k_scale, v_scale) [L, B, K, T]`` fp32
+    riding alongside an int8 cache. Decode and chunk both stream them
+    through their q8 kernels (dequantization rides the matmul epilogues
+    — no bf16 layer slice is ever materialized; the XLA oracle path
+    dequantizes a slice, but it is the oracle, not the serving path).
+
+    ``window`` (a sliding layer; bf16 planes only): a query sees its last
+    ``window`` slots, itself among them. Prefill takes the flash kernel with
+    the bound in its plan (``flash_attention_window``); a decode step hands
+    the walk ``max(kv_start, kv_len - window)``, so the kernel fetches the
+    window's steps and no others; a chunk over the cache takes the XLA form
+    with the bound in its mask (the chunk kernels have none).
+
+    ``ring`` (``Attention._attend_ring``): the sequence-parallel prefill.
+    """
+    assert window is None or scales is None, "a windowed layer's planes are not quantized"
+    cache_kv = mode in ("decode", "chunk")
+    # kv heads sit at dim 2 in both layouts ([L,B,K,T,hd] / [B,S,K,hd])
+    H, K = q.shape[2], k.shape[2]
+    tp = (
+        mesh.shape["tp"]
+        if mesh is not None and "tp" in mesh.axis_names
+        else 1
+    )
+    sp = (
+        mesh.shape["sp"]
+        if mesh is not None and "sp" in mesh.axis_names
+        else 1
+    )
+    if mode == "prefill" and sp > 1 and q.shape[1] % sp == 0 and ring is not None:
+        # sequence parallelism: prefill/training attention runs as RING
+        # attention over the sp axis — each device holds S/sp of the
+        # sequence, K/V blocks rotate via ppermute on the ICI ring
+        # (parallel/ring_attention.py). Differentiable (the training
+        # path), composes with tp over heads.
+        count_kernel_build(mode, "ring_attention")
+        return ring(q, k, v, kv_start, kv_len, sp, tp)
+    heads_shardable = tp > 1 and H % tp == 0 and K % tp == 0
+    if impl != "xla" and tp > 1 and not heads_shardable:
+        # head counts don't tile the tp axis: an unsharded Pallas call
+        # inside the mesh program would force a per-layer full-cache
+        # gather — the sharding-transparent XLA path is strictly better
+        impl = "xla"
+    if window is not None and mode == "chunk" and impl != "xla":
+        count_kernel_build(mode, "chunk_attention_xla")
+        return chunk_attention_xla(q, k, v, kv_start, kv_len, layer, write_index, window=window)
+    if impl == "xla":
+        count_kernel_build(mode, "xla")
+        if mode == "decode":
+            if scales is not None:
+                return decode_attention_xla_q8(
+                    q, k, v, scales[0], scales[1], kv_start, kv_len, layer
+                )
+            return decode_attention_xla(q, k, v, kv_start, kv_len, layer, window=window)
+        if mode == "chunk":
+            if scales is not None:
+                return chunk_attention_xla_q8(
+                    q, k, v, scales[0], scales[1], kv_start, kv_len,
+                    layer, write_index,
+                )
+            return chunk_attention_xla(
+                q, k, v, kv_start, kv_len, layer, write_index, window=window
+            )
+        return attention_xla(q, k, v, kv_start=kv_start, kv_len=kv_len, causal=True, window=window)
+
+    # every fused kernel takes its arrays positionally, the cache kernels
+    # in one order (q, k, v[, k_scale, v_scale], kv_start, kv_len, layer
+    # [, write_index]): the choice below is of a function, by mode, by
+    # whether scales ride along, and for a chunk by its static shape
+    q8 = scales is not None
+    if mode == "decode":
+        fn = decode_attention_q8 if q8 else decode_attention
+    elif mode == "chunk":
+        # a small chunk (a speculative verify step's spec_tokens + 1
+        # positions) folds a kv head's G query heads and the S positions
+        # into one matmul's rows and streams the cache once a KV head;
+        # prompt chunks of hundreds of rows keep the per-head kernel
+        local_kv = K // tp if heads_shardable else K
+        if grouped_chunk_fits(H // K, q.shape[1], local_kv):
+            fn = chunk_attention_grouped_q8 if q8 else chunk_attention_grouped
+        else:
+            fn = chunk_prefill_attention_q8 if q8 else chunk_prefill_attention
+    else:
+        fn = flash_attention
+    windowed = window is not None and mode == "prefill"
+    count_kernel_build(mode, fn.__name__ + ("_window" if windowed else ""))
+    kernel = functools.partial(fn, interpret=impl == "pallas_interpret",
+                               **({"window": window} if windowed else {}))
+    if window is not None and mode == "decode":
+        kv_start = jnp.maximum(kv_start, kv_len - window)
+
+    if heads_shardable:
+        # heads are independent: shard the kernel over the tp axis, one
+        # per-device Pallas call each on its local heads — no collectives
+        hspec = P(None, None, "tp", None)
+        if cache_kv:
+            kvspec = P(None, None, "tp", None, None)
+            scspec = (P(None, None, "tp", None),) * 2 if scales is not None else ()
+            scalars = (P(None),) * (3 if mode == "chunk" else 2)
+            kernel = jax.shard_map(
+                kernel,
+                mesh=mesh,
+                in_specs=(hspec, kvspec, kvspec) + scspec + (P(None),) + scalars,
+                out_specs=hspec,
+                check_vma=False,
+            )
+        else:
+            kernel = jax.shard_map(
+                kernel,
+                mesh=mesh,
+                in_specs=(hspec, hspec, hspec, P(None), P(None)),
+                out_specs=hspec,
+                check_vma=False,
+            )
+    if mode == "decode":
+        lay1 = jnp.asarray(layer, jnp.int32).reshape(1)
+        if scales is not None:
+            return kernel(q, k, v, scales[0], scales[1], kv_start, kv_len, lay1)
+        return kernel(q, k, v, kv_start, kv_len, lay1)
+    if mode == "chunk":
+        lay1 = jnp.asarray(layer, jnp.int32).reshape(1)
+        wi1 = jnp.asarray(write_index, jnp.int32).reshape(1)
+        if scales is not None:
+            return kernel(q, k, v, scales[0], scales[1], kv_start, kv_len, lay1, wi1)
+        return kernel(q, k, v, kv_start, kv_len, lay1, wi1)
+    return kernel(q, k, v, kv_start, kv_len)
+
+
 class Attention(nn.Module):
     """GQA attention with two fused TPU paths and one differentiable oracle.
 
@@ -618,126 +760,11 @@ class Attention(nn.Module):
         self, q, k, v, kv_start, kv_len, layer, *, mode: str, write_index=None,
         scales=None,
     ) -> jax.Array:
-        """Dispatch to the right backend. ``mode``:
-
-        - ``"prefill"``: fresh ``k``/``v`` ``[B, S, K, hd]``, causal within S;
-        - ``"decode"`` / ``"chunk"``: ``k``/``v`` are the FULL stacked
-          head-major cache ``[L, B, K, T, hd]`` read at ``layer`` (no
-          per-layer slice is ever materialized); ``chunk`` additionally takes
-          ``write_index`` — query ``t`` sits at cache slot ``write_index + t``
-          (offset causality over the populated prefix).
-
-        ``scales`` (int8-KV only): ``(k_scale, v_scale) [L, B, K, T]`` fp32
-        riding alongside an int8 cache. Decode and chunk both stream them
-        through their q8 kernels (dequantization rides the matmul epilogues
-        — no bf16 layer slice is ever materialized; the XLA oracle path
-        dequantizes a slice, but it is the oracle, not the serving path).
-        """
-        impl = self._resolved_impl()
-        mesh = self.mesh
-        cache_kv = mode in ("decode", "chunk")
-        # kv heads sit at dim 2 in both layouts ([L,B,K,T,hd] / [B,S,K,hd])
-        H, K = q.shape[2], k.shape[2]
-        tp = (
-            mesh.shape["tp"]
-            if mesh is not None and "tp" in mesh.axis_names
-            else 1
+        """``attend`` with this module's backend and mesh."""
+        return attend(
+            q, k, v, kv_start, kv_len, layer, mode=mode, impl=self._resolved_impl(),
+            mesh=self.mesh, write_index=write_index, scales=scales, ring=self._attend_ring,
         )
-        sp = (
-            mesh.shape["sp"]
-            if mesh is not None and "sp" in mesh.axis_names
-            else 1
-        )
-        if mode == "prefill" and sp > 1 and q.shape[1] % sp == 0:
-            # sequence parallelism: prefill/training attention runs as RING
-            # attention over the sp axis — each device holds S/sp of the
-            # sequence, K/V blocks rotate via ppermute on the ICI ring
-            # (parallel/ring_attention.py). Differentiable (the training
-            # path), composes with tp over heads.
-            count_kernel_build(mode, "ring_attention")
-            return self._attend_ring(q, k, v, kv_start, kv_len, sp, tp)
-        heads_shardable = tp > 1 and H % tp == 0 and K % tp == 0
-        if impl != "xla" and tp > 1 and not heads_shardable:
-            # head counts don't tile the tp axis: an unsharded Pallas call
-            # inside the mesh program would force a per-layer full-cache
-            # gather — the sharding-transparent XLA path is strictly better
-            impl = "xla"
-        if impl == "xla":
-            count_kernel_build(mode, "xla")
-            if mode == "decode":
-                if scales is not None:
-                    return decode_attention_xla_q8(
-                        q, k, v, scales[0], scales[1], kv_start, kv_len, layer
-                    )
-                return decode_attention_xla(q, k, v, kv_start, kv_len, layer)
-            if mode == "chunk":
-                if scales is not None:
-                    return chunk_attention_xla_q8(
-                        q, k, v, scales[0], scales[1], kv_start, kv_len,
-                        layer, write_index,
-                    )
-                return chunk_attention_xla(
-                    q, k, v, kv_start, kv_len, layer, write_index
-                )
-            return attention_xla(q, k, v, kv_start=kv_start, kv_len=kv_len, causal=True)
-
-        # every fused kernel takes its arrays positionally, the cache kernels
-        # in one order (q, k, v[, k_scale, v_scale], kv_start, kv_len, layer
-        # [, write_index]): the choice below is of a function, by mode, by
-        # whether scales ride along, and for a chunk by its static shape
-        q8 = scales is not None
-        if mode == "decode":
-            fn = decode_attention_q8 if q8 else decode_attention
-        elif mode == "chunk":
-            # a small chunk (a speculative verify step's spec_tokens + 1
-            # positions) folds a kv head's G query heads and the S positions
-            # into one matmul's rows and streams the cache once a KV head;
-            # prompt chunks of hundreds of rows keep the per-head kernel
-            local_kv = K // tp if heads_shardable else K
-            if grouped_chunk_fits(H // K, q.shape[1], local_kv):
-                fn = chunk_attention_grouped_q8 if q8 else chunk_attention_grouped
-            else:
-                fn = chunk_prefill_attention_q8 if q8 else chunk_prefill_attention
-        else:
-            fn = flash_attention
-        count_kernel_build(mode, fn.__name__)
-        kernel = functools.partial(fn, interpret=impl == "pallas_interpret")
-
-        if heads_shardable:
-            # heads are independent: shard the kernel over the tp axis, one
-            # per-device Pallas call each on its local heads — no collectives
-            hspec = P(None, None, "tp", None)
-            if cache_kv:
-                kvspec = P(None, None, "tp", None, None)
-                scspec = (P(None, None, "tp", None),) * 2 if scales is not None else ()
-                scalars = (P(None),) * (3 if mode == "chunk" else 2)
-                kernel = jax.shard_map(
-                    kernel,
-                    mesh=mesh,
-                    in_specs=(hspec, kvspec, kvspec) + scspec + (P(None),) + scalars,
-                    out_specs=hspec,
-                    check_vma=False,
-                )
-            else:
-                kernel = jax.shard_map(
-                    kernel,
-                    mesh=mesh,
-                    in_specs=(hspec, hspec, hspec, P(None), P(None)),
-                    out_specs=hspec,
-                    check_vma=False,
-                )
-        if mode == "decode":
-            lay1 = jnp.asarray(layer, jnp.int32).reshape(1)
-            if scales is not None:
-                return kernel(q, k, v, scales[0], scales[1], kv_start, kv_len, lay1)
-            return kernel(q, k, v, kv_start, kv_len, lay1)
-        if mode == "chunk":
-            lay1 = jnp.asarray(layer, jnp.int32).reshape(1)
-            wi1 = jnp.asarray(write_index, jnp.int32).reshape(1)
-            if scales is not None:
-                return kernel(q, k, v, scales[0], scales[1], kv_start, kv_len, lay1, wi1)
-            return kernel(q, k, v, kv_start, kv_len, lay1, wi1)
-        return kernel(q, k, v, kv_start, kv_len)
 
     def _attend_ring(self, q, k, v, kv_start, kv_len, sp: int, tp: int) -> jax.Array:
         """Sequence-parallel prefill attention: shard_map over ``sp`` (and

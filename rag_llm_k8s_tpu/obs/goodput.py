@@ -274,6 +274,31 @@ def roofline_for_latent_moe(cfg, *, peak_tflops: float, hbm_gbs: float) -> Roofl
     )
 
 
+def roofline_for_windowed_moe(cfg, *, peak_tflops: float, hbm_gbs: float) -> RooflineModel:
+    """The roofline of the windowed-attention sparse-expert family, from a
+    ``WindowedMoEConfig``'s fields (duck-typed): every layer's attention at
+    ITS head count (q, k, v, o and the per-head gate), a dense layer's FFN,
+    a sparse layer's router, shared expert and the routed experts a balanced
+    router sends to those held here (as ``roofline_for_latent_moe``).
+    ``kv_bytes_per_token`` is one position's K and V over all planes: every
+    plane keeps every position, a sliding layer's too."""
+    d, K, hd = int(cfg.hidden_size), int(cfg.num_kv_heads), int(cfg.head_dim)
+    expert = 3 * d * cfg.moe_intermediate_size
+    routed_here = cfg.num_experts_per_tok * cfg.experts_held / cfg.num_experts
+    sparse_ffn = d * cfg.num_experts + expert * routed_here + 3 * d * cfg.shared_expert_intermediate_size
+    active = cfg.vocab_size * d
+    for heads, ffn in zip(cfg.num_attention_heads_per_layer, cfg.mlp_layer_types):
+        active += 2 * d * heads * hd + 2 * d * K * hd + d * heads
+        active += 3 * d * cfg.intermediate_size if ffn == "dense" else sparse_ffn
+    return RooflineModel(
+        flops_per_token=2.0 * active,
+        weight_bytes=2.0 * active,
+        kv_bytes_per_token=2.0 * 2 * cfg.num_layers * K * hd,
+        peak_tflops=peak_tflops,
+        hbm_gbs=hbm_gbs,
+    )
+
+
 def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger":
     """THE ledger constructor both serving engines share (duck-typed over
     the config dataclasses — still no package imports). One site means the
@@ -294,10 +319,13 @@ def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger"
             peak_tflops = peak_tflops if peak_tflops > 0 else kind_tflops
             hbm_gbs = hbm_gbs if hbm_gbs > 0 else kind_gbs
         # the family is told by what the configuration HAS (no package
-        # imports here): a latent cache's rank, or per-head K/V
+        # imports here): a latent cache's rank, layers of several kinds, or
+        # per-head K/V alike in every layer
         roofline = roofline_for_latent_moe(
             model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
-        ) if hasattr(model_config, "kv_lora_rank") else roofline_for_llama(
+        ) if hasattr(model_config, "kv_lora_rank") else roofline_for_windowed_moe(
+            model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
+        ) if hasattr(model_config, "layer_types") else roofline_for_llama(
             model_config.num_layers, model_config.hidden_size,
             model_config.num_heads, model_config.num_kv_heads,
             model_config.head_dim, model_config.intermediate_size,

@@ -72,10 +72,14 @@ SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
 # cache itself, its absorbing matmuls included), ``mlp/router``,
 # ``mlp/experts`` (gather, grouped matmuls, scatter), ``mlp/shared``,
 # ``mlp/zero`` (the zero-computation experts' term) and ``mlp/dense`` (a
-# dense SwiGLU: a leading dense layer's, a shortcut-connected layer's two). A
+# dense SwiGLU: a leading dense layer's, a shortcut-connected layer's two).
+# The windowed-attention family (models/windowed_moe.py) opens ``attn/window``
+# and ``attn/global`` around the attention of a sliding and of a full layer
+# (kernel or XLA form, nothing else), ``attn/gate`` around the per-head output
+# gate, and the ``mlp/*`` scopes above. A
 # reader that files an operation under the first sub-scope it knows keeps
 # reading ``attn`` and ``mlp``; one that knows these sees the finer split.
-FINE_SCOPES = ("latent", "router", "experts", "shared", "zero", "dense")
+FINE_SCOPES = ("latent", "router", "experts", "shared", "zero", "dense", "window", "global", "gate")
 SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES + FINE_SCOPES)
 
 
@@ -97,7 +101,7 @@ def phase_scope(path: str, rows: Optional[int] = None):
     return jax.named_scope(path if rows is None else f"{path}/rows{int(rows)}")
 
 
-# Which attention kernel ``LlamaModel._attend`` built into a program, by the
+# Which attention kernel ``models/llama.py attend`` built into a program, by the
 # mode it served (prefill | decode | chunk). The choice is made where a
 # program is TRACED — by static shapes, once a compiled program — so this
 # counts traces and costs a dispatch nothing. Process-wide, like the jit

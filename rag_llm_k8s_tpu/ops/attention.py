@@ -45,31 +45,44 @@ def _fit_block(n: int, pref: int) -> int:
     return b
 
 
-def flash_block_plan(qi, kv_start, kv_len, S: int, bq: int, bk: int, causal: bool):
+def flash_block_plan(qi, kv_start, kv_len, S: int, bq: int, bk: int, causal: bool,
+                     window: Optional[int] = None):
     """Which key blocks query block ``qi`` of a row visits, and which of them
     no mask can touch: ``(lo, hi, int_lo, int_hi)``, every bound inclusive.
 
     The live pairs of a row are ``kv_start <= k < kv_len`` and, under
-    ``causal``, ``k <= q``. Key blocks ``lo..hi`` are exactly those that hold
+    ``causal``, ``k <= q``; under ``window`` (causal only) also ``k > q -
+    window``. Key blocks ``lo..hi`` are exactly those that hold
     a live pair with a query of the block (none when ``hi < lo``: a query
     block wholly in the left pad, or an empty window). Of those, blocks
     ``int_lo..int_hi`` are INTERIOR: wholly inside the window and wholly
-    under the block's diagonal, so every pair in them is live; the others
-    (on the diagonal, or straddling ``kv_start`` or ``kv_len``) are EDGE
+    under the block's diagonal (and wholly behind its window edge), so every
+    pair in them is live; the others (on the diagonal, on the window's edge,
+    or straddling ``kv_start`` or ``kv_len``) are EDGE
     blocks. Integer arithmetic only, so it serves Python ints, numpy arrays
     and the kernel's traced scalars alike: the kernel's loop bounds and the
-    tests read this one rule. ``S`` is the key length (bounds stay inside it)."""
+    tests read this one rule. ``S`` is the key length (bounds stay inside it).
+    ``window=None`` is the plan without one, bit for bit."""
     nk = S // bk
     q_lo, q_hi = qi * bq, qi * bq + bq - 1  # the block's first and last query
-    lo = jnp.maximum(kv_start // bk, 0)
+    if window is None:
+        lo = jnp.maximum(kv_start // bk, 0)
+        int_lo = (kv_start + bk - 1) // bk
+    else:
+        assert causal, "a window bound is causal"
+        # the oldest key the block's FIRST query sees; every key of an
+        # interior block is seen by its LAST query too
+        lo = jnp.maximum(jnp.maximum(kv_start, q_lo - window + 1) // bk, 0)
+        int_lo = (jnp.maximum(kv_start, q_hi - window + 1) + bk - 1) // bk
     hi = jnp.minimum((kv_len - 1) // bk, nk - 1)
-    int_lo = (kv_start + bk - 1) // bk
     int_hi = kv_len // bk - 1
     empty = kv_len <= kv_start
     if causal:
         hi = jnp.minimum(hi, q_hi // bk)
         int_hi = jnp.minimum(int_hi, (q_lo + 1) // bk - 1)
         empty = empty | (q_hi < kv_start)
+    if window is not None:
+        empty = empty | (kv_len <= q_lo - window + 1)
     hi = jnp.where(empty, lo - 1, hi)
     return lo, hi, int_lo, int_hi
 
@@ -93,13 +106,14 @@ def _flash_kernel(
     causal: bool,
     kv_heads: int,
     resident: bool,
+    window: Optional[int] = None,
 ):
     G = q_ref.shape[0]
     rows = G * bq
     b = pl.program_id(0) // kv_heads
     qi = pl.program_id(1)
     start, end = kv_start_ref[b], kv_len_ref[b]
-    lo, hi, int_lo, int_hi = flash_block_plan(qi, start, end, sk, bq, bk, causal)
+    lo, hi, int_lo, int_hi = flash_block_plan(qi, start, end, sk, bq, bk, causal, window)
 
     # the G heads fold into one matmul's rows: row r is (head r // bq, query
     # qi*bq + r % bq) against ONE K/V block
@@ -144,6 +158,8 @@ def _flash_kernel(
             k_pos = off + jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
             k_pos = jnp.where((k_pos >= start) & (k_pos < end), k_pos, _NO_KEY)
             ok = k_pos <= q_pos  # [rows, width] under ``causal``, else [1, width]
+            if window is not None:  # a key outside the live slots is past every query already
+                ok = ok & (k_pos > q_pos - window)
             s = jnp.where(ok, s, NEG_INF)
 
         m_new = jnp.max(s, axis=1, keepdims=True)  # [rows, 1]
@@ -226,8 +242,11 @@ def _flash_kernel(
     @pl.when(hi >= lo)
     def _visit():
         block(lo, 1, True, first=True)
-        # (an edge block between ``lo`` and the interior cannot be: ``int_lo``
-        # is ``lo`` or ``lo + 1``)
+        # (without a window an edge block between ``lo`` and the interior
+        # cannot be: ``int_lo`` is ``lo`` or ``lo + 1``; a window's edge is a
+        # second diagonal, as many blocks as a query block spans)
+        if window is not None:
+            jax.lax.fori_loop(0, jnp.minimum(first_int, hi + 1) - (lo + 1), step(lo + 1, 1, True), 0)
         jax.lax.fori_loop(0, n_int // wide, step(first_int, wide, False), 0)
         if wide > 1:
             jax.lax.fori_loop(0, first_int + n_int - odd, step(odd, 1, False), 0)
@@ -260,7 +279,11 @@ def flash_blocks(S: int, G: int, dq: int, dv: int, causal: bool = True, itemsize
     loop costs is mostly its bookkeeping a ROW (running max, rescaling sum
     and accumulator through VMEM: ≈ 1.2 µs a step at 1024 rows, whatever the
     keys), so a step takes 1024 keys and the G-fold keeps G·bq = 1024 rows a
-    step (bq 256 at G = 4). Under ``causal`` the 1024 keys are ``FLASH_WIDE``
+    step (bq 256 at G = 4; where G is no power of two, the power of two
+    nearest ``1024 / G``, since plain halving of 113 or 170 queries ends at a
+    block of one or two: G = 9 takes 128 queries, 1152 rows, 7-8% faster than
+    64 with or without a window; G = 6 takes 128, 768 rows, its best: swept at
+    72 / 48 heads in PR 33, PERF.md §6). Under ``causal`` the 1024 keys are ``FLASH_WIDE``
     interior blocks of ``bk`` = 512, so that the blocks a mask can touch are
     512 wide, and bq stops at 512: a taller query block only widens the
     diagonal's waste (MLA, G = 1: 1024 × 512 lost 11% to 512 × 512). Finer
@@ -277,7 +300,10 @@ def flash_blocks(S: int, G: int, dq: int, dv: int, causal: bool = True, itemsize
     K/V blocks are streamed a grid step (``_flash_call``) and bq stays."""
     bk = _fit_block(S, 512 if causal else 1024)
     wide = FLASH_WIDE if causal else 1
-    full = min(512, 1024 // G) if causal else 1024
+    # 1024 rows a step, as the nearest power of two of queries so that the
+    # block still tiles a bucket at a group size that is none (G = 9: 128
+    # queries, 1152 rows; G = 6: 128, 768; at a power of two nothing changes)
+    full = min(512, 1 << round(math.log2(1024 / G))) if causal else 1024
     bq = full
     while G * bq > 256 and not _flash_fits(S, G * bq, bk, wide, dq, dv, itemsize):
         bq //= 2
@@ -286,14 +312,16 @@ def flash_blocks(S: int, G: int, dq: int, dv: int, causal: bool = True, itemsize
     return _fit_block(S, bq), bk
 
 
-def _flash_call(qt, kt, vt, kv_start, kv_len, *, scale, causal, bq, bk, interpret, name, resident=None):
+def _flash_call(qt, kt, vt, kv_start, kv_len, *, scale, causal, bq, bk, interpret, name, resident=None,
+                window=None):
     """The one flash prefill ``pallas_call``: ``qt [B*H, Sq, dq]`` against
     ``kt [B*K, Sk, dq]`` / ``vt [B*K, Sk, dv]`` (the G = H // K query heads of
     a KV head are consecutive rows of ``qt``); returns ``[B*H, Sq, dv]``.
     ``bq`` / ``bk`` left ``None`` come from ``flash_blocks``. ``resident``
     (``None``: where they fit) keeps a KV head's K/V strips in VMEM across
     its query blocks; otherwise the grid gains a key-block axis whose index
-    map is clamped into the plan's ``lo..hi``, so any length runs."""
+    map is clamped into the plan's ``lo..hi``, so any length runs. ``window``
+    (causal only) bounds every query to its last ``window`` keys."""
     BH, Sq, dq = qt.shape
     BK, Sk, dv = vt.shape
     G = BH // BK
@@ -316,7 +344,7 @@ def _flash_call(qt, kt, vt, kv_start, kv_len, *, scale, causal, bq, bk, interpre
 
         def kv_index(h, qi, j, start_ref, len_ref):
             b = h // kv_heads
-            lo, hi, _, _ = flash_block_plan(qi, start_ref[b], len_ref[b], Sk, bq, bk, causal)
+            lo, hi, _, _ = flash_block_plan(qi, start_ref[b], len_ref[b], Sk, bq, bk, causal, window)
             return (h, jnp.minimum(lo + j, jnp.clip(hi, lo, Sk // bk - 1)), 0)
 
     def q_index(h, qi, *s_):
@@ -326,6 +354,7 @@ def _flash_call(qt, kt, vt, kv_start, kv_len, *, scale, causal, bq, bk, interpre
         functools.partial(
             _flash_kernel, sk=Sk, bq=bq, bk=bk, wide=min(wide, Sk // bk),
             scale=scale, causal=causal, kv_heads=kv_heads, resident=resident,
+            window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -349,7 +378,7 @@ def _flash_call(qt, kt, vt, kv_start, kv_len, *, scale, causal, bq, bk, interpre
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "bq", "bk", "interpret")
+    jax.jit, static_argnames=("causal", "bq", "bk", "interpret", "window")
 )
 def flash_attention(
     q: jax.Array,  # [B, Sq, H, hd]
@@ -361,12 +390,16 @@ def flash_attention(
     bq: Optional[int] = None,
     bk: Optional[int] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Blockwise fused attention; returns ``[B, Sq, H, hd]`` in q's dtype.
     ``bq`` / ``bk`` default to ``flash_blocks``' rule on the shape; blocks
     shrink (halving) until they tile the sequence exactly, and a sequence
     whose K/V strips outgrow VMEM has its key blocks streamed
-    (``_flash_call``), so any power-of-two length works."""
+    (``_flash_call``), so any power-of-two length works. ``window`` (causal
+    only): a query sees its last ``window`` keys, itself among them; that
+    call is built under the name ``flash_attention_window``, so a trace
+    tells the two apart."""
     B, Sq, H, hd = q.shape
     _, Sk, K, _ = k.shape
     if kv_start is None:
@@ -380,7 +413,8 @@ def flash_attention(
     vt = v.transpose(0, 2, 1, 3).reshape(B * K, Sk, hd)
     out = _flash_call(
         qt, kt, vt, kv_start, kv_len, scale=hd**-0.5, causal=causal,
-        bq=bq, bk=bk, interpret=interpret, name="flash_attention",
+        bq=bq, bk=bk, interpret=interpret,
+        name="flash_attention" if window is None else "flash_attention_window", window=window,
     )
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
 
@@ -862,9 +896,11 @@ def chunk_attention_xla(
     kv_len: jax.Array,  # [B]
     layer: jax.Array,  # [] or [1] int32
     write_index: jax.Array,  # [] int32
+    window: Optional[int] = None,  # a query sees its last ``window`` slots
 ) -> jax.Array:
     """Dense XLA reference for ``chunk_prefill_attention`` (oracle; fallback
-    off-TPU)."""
+    off-TPU; the served form of a windowed layer's chunk calls, for which the
+    chunk kernels have no bound)."""
     B, S, H, hd = q.shape
     _, _, K, T, _ = k_cache.shape
     G = H // K
@@ -880,6 +916,8 @@ def chunk_attention_xla(
         t_pos[None, None, :] < kv_len[:, None, None]
     )
     ok = ok & (t_pos[None, None, :] <= q_pos[None, :, None])  # [B, S, T]
+    if window is not None:
+        ok = ok & (t_pos[None, None, :] > q_pos[None, :, None] - window)
     s = jnp.where(ok[:, None, None, :, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(ok[:, None, None, :, :], p, 0.0)
@@ -897,8 +935,11 @@ def decode_attention_xla(
     kv_start: jax.Array,  # [B]
     kv_len: jax.Array,  # [B]
     layer: jax.Array,  # [] or [1] int32
+    window: Optional[int] = None,  # the query (slot kv_len - 1) sees its last ``window`` slots
 ) -> jax.Array:
     """Dense XLA reference for ``decode_attention`` (oracle; fallback off-TPU)."""
+    if window is not None:
+        kv_start = jnp.maximum(kv_start, kv_len - window)
     B, S, H, hd = q.shape
     _, _, K, T, _ = k_cache.shape
     G = H // K
@@ -928,6 +969,7 @@ def attention_xla(
     kv_start: Optional[jax.Array] = None,
     kv_len: Optional[jax.Array] = None,
     causal: bool = True,
+    window: Optional[int] = None,  # causal only: a query sees its last ``window`` keys
 ) -> jax.Array:
     """Dense XLA reference (oracle for the kernel; fallback off-TPU)."""
     B, Sq, H, hd = q.shape
@@ -945,6 +987,9 @@ def attention_xla(
         ok = ok & (k_pos[None, None, :] < kv_len[:, None, None])
     if causal:
         ok = ok & (k_pos[None, None, :] <= q_pos[None, :, None])
+    if window is not None:
+        assert causal, "a window bound is causal"
+        ok = ok & (k_pos[None, None, :] > q_pos[None, :, None] - window)
     s = jnp.where(ok[:, None, None, :, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     # rows with no valid key: softmax of all-NEG_INF is uniform — zero it so

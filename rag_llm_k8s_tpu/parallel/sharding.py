@@ -110,18 +110,28 @@ def llama_param_specs(params, ctx: MeshContext):
     return traverse_util.unflatten_dict(specs)
 
 
+def _replicated_specs(params, ctx: MeshContext, tree: str):
+    if ctx.tp > 1:
+        raise NotImplementedError(
+            f"tp={ctx.tp}: the {tree} tree has no "
+            "tensor-parallel partition rules (tp must be 1)"
+        )
+    flat = traverse_util.flatten_dict(params)
+    return traverse_util.unflatten_dict({p: P(*(None,) * leaf.ndim) for p, leaf in flat.items()})
+
+
 def latent_moe_param_specs(params, ctx: MeshContext):
     """PartitionSpec pytree for the ``LatentMoEModel`` layout: every leaf
     replicated. The family is served at tp = 1 (``models/families.py``
     refuses more by name): its latent projections and its expert stack have
     no partition rules yet, and experts across chips need the all-to-all."""
-    if ctx.tp > 1:
-        raise NotImplementedError(
-            f"tp={ctx.tp}: the latent-attention sparse-expert tree has no "
-            "tensor-parallel partition rules (tp must be 1)"
-        )
-    flat = traverse_util.flatten_dict(params)
-    return traverse_util.unflatten_dict({p: P(*(None,) * leaf.ndim) for p, leaf in flat.items()})
+    return _replicated_specs(params, ctx, "latent-attention sparse-expert")
+
+
+def windowed_moe_param_specs(params, ctx: MeshContext):
+    """The same for the ``WindowedMoEModel`` layout: its projections differ
+    in shape by layer kind and its expert stack has no partition rules yet."""
+    return _replicated_specs(params, ctx, "windowed-attention sparse-expert")
 
 
 def shard_params(params, specs, ctx: MeshContext):

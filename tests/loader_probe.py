@@ -56,12 +56,23 @@ def main(argv) -> None:
     cfg = dataclasses.replace(getattr(LlamaConfig, preset)(), num_layers=int(layers))
     mesh = make_mesh(MeshConfig(dp=1, sp=1, tp=8), devices=jax.devices()[:8])
 
+    def high_water() -> int:
+        """This interpreter's own peak RSS. ``VmHWM`` belongs to the address
+        space exec made; ``ru_maxrss`` does not do: it starts at the SPAWNING
+        process's peak (Linux keeps it across fork and exec), so after a
+        pytest worker that once held more than the load does, it never moved."""
+        with open("/proc/self/status", encoding="ascii") as f:
+            for line in f:
+                if line.startswith("VmHWM:"):
+                    return int(line.split()[1]) * 1024
+        return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+
     proc = psutil.Process()
-    peak_before = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+    peak_before = high_water()
     put = make_streaming_put(mesh, dtype=jnp.bfloat16)
     params = load_safetensors_params(synth_dir, cfg, DTypePolicy(), put=put, quant=quant)
     rss_after = proc.memory_info().rss
-    peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+    peak = high_water()
 
     leaves = {}
     for path, x in jax.tree_util.tree_flatten_with_path(params)[0]:

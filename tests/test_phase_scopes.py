@@ -264,7 +264,8 @@ def test_latent_moe_fine_scopes_sit_beneath_attn_and_mlp(latent_engine):
     operation of them still files under ``decode/attn`` or ``decode/mlp``; the
     leading dense layer stands outside the layers' loop and the MoE layers in
     it, which is what a prefill's rows are counted by."""
-    assert set(tracing.FINE_SCOPES) == {"latent", "router", "experts", "shared", "zero", "dense"}
+    assert set(tracing.FINE_SCOPES) == {"latent", "router", "experts", "shared", "zero", "dense",
+                                        "window", "global", "gate"}
     assert set(tracing.FINE_SCOPES) <= tracing.SCOPE_NAMES
     paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](latent_engine))]
     for phase in ("prefill", "decode"):
@@ -276,6 +277,48 @@ def test_latent_moe_fine_scopes_sit_beneath_attn_and_mlp(latent_engine):
     assert [p for p in dense if "/mlp/dense/" in p] and not [p for p in paths if "/mlp/zero/" in p]
     looped = [p for p in paths if "/prefill/rows2/" in p and "/while/body/" in p and "/layers/" in p]
     assert looped and not [p for p in looped if "/dense_0/" in p]
+
+
+@pytest.fixture(scope="module")
+def windowed_engine():
+    """The windowed-attention sparse-expert family through the same programs."""
+    import dataclasses
+
+    from rag_llm_k8s_tpu.core.config import WindowedMoEConfig
+    from rag_llm_k8s_tpu.models.windowed_moe import init_windowed_moe_params
+
+    cfg = WindowedMoEConfig.tiny(vocab_size=300)
+    params = init_windowed_moe_params(jax.random.PRNGKey(0), cfg, FP32)
+    ec = dataclasses.replace(EC, prefix_cache=PrefixCacheConfig(enabled=False), attn_impl="xla")
+    return InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_PROGRAMS))
+def test_windowed_moe_programs_arrive_scoped(windowed_engine, name):
+    """Every operation of the third decoder family carries a phase."""
+    _assert_scoped(name, LATENT_PROGRAMS[name](windowed_engine))
+
+
+def test_windowed_moe_fine_scopes_sit_beneath_attn_and_mlp(windowed_engine):
+    """``attn/window``, ``attn/global`` and ``attn/gate`` are BENEATH ``attn``
+    (a reader that knows no finer scope still files them under
+    ``decode/attn``); a period's layers sit in the layers' loop (``periods``)
+    each under its own name, the leading dense layer outside it."""
+    paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](windowed_engine))]
+    for phase in ("prefill", "decode"):
+        for sub, fine in (("attn", "window"), ("attn", "global"), ("attn", "gate"), ("mlp", "router"),
+                          ("mlp", "experts"), ("mlp", "shared"), ("mlp", "dense")):
+            hits = [p for p in paths if f"/{phase}/" in p and f"/{sub}/{fine}/" in p]
+            assert hits and all(_scope(p) == (phase, sub) for p in hits), (phase, sub, fine)
+    lead = [p for p in paths if "/prefill/rows2/" in p and "/lead_0/" in p]
+    assert lead and not [p for p in lead if "/while/" in p.split("/lead_0/")[0]]
+    assert [p for p in lead if "/attn/global/" in p] and not [p for p in lead if "/attn/window/" in p]
+    looped = [p for p in paths if "/prefill/rows2/" in p and "/periods/" in p and "/while/body/" in p]
+    assert looped and not [p for p in looped if "/lead_0/" in p]
+    for sub, fine in (("l0", "window"), ("l1", "window"), ("l2", "global")):  # the toy period: two sliding, one full
+        assert [p for p in looped if f"/periods/{sub}/" in p and f"/attn/{fine}/" in p], (sub, fine)
+    assert not [p for p in looped if ("/periods/l2/" in p and "/attn/window/" in p)
+                or ("/periods/l0/" in p and "/attn/global/" in p)]
 
 
 def test_shortcut_block_arrives_scoped_with_its_own_fine_scopes():

@@ -212,6 +212,37 @@ CASES.update({
 })
 
 
+def _flash_window(heads: int):
+    def fn(q, k, v, kv_start, kv_len):
+        return A.flash_attention(q, k, v, kv_start, kv_len, window=512)
+
+    qkv = [((1, 4096, h, HD), BF16) for h in (heads, K, K)]
+    return fn, [*qkv, ((1,), I32), ((1,), I32)]
+
+
+# the windowed-attention sparse-expert family at its published widths: 72
+# query heads (sliding layers, window 512) and 48 (full layers) over 8 KV heads
+# of 128, group sizes 9 and 6 (no power of two: 576 / 768 folded rows a step,
+# 9 / 6 query rows a KV head in the decode kernel's [K, G, hd] block), 17
+# planes; 16 held experts of 3072 x 1024 stacked over 16 layers
+CASES.update({
+    "flash_attention_window[72 heads]": _flash_window(72),
+    "flash_attention_window[48 heads]": _flash_window(48),
+    "flash_attention[72 heads]": _flash(4096, 72, K, HD, 1, True),
+    "flash_attention[48 heads]": _flash(4096, 48, K, HD, 1, True),
+    **{f"decode_attention[{heads} heads]": (
+        A.decode_attention,
+        [((8, 1, heads, HD), BF16), ((17, 8, K, T, HD), BF16), ((17, 8, K, T, HD), BF16),
+         ((8,), I32), ((8,), I32), ((), I32)]) for heads in (72, 48)},
+    "grouped_matmul[small experts, prefill up]": _latent(
+        "grouped_matmul",
+        [((40960, 3072), BF16), ((16, 16, 3072, 1024), BF16), ((16,), I32), ((), I32)]),
+    "grouped_matmul[small experts, decode down]": _latent(
+        "grouped_matmul",
+        [((128, 1024), BF16), ((16, 16, 1024, 3072), BF16), ((16,), I32), ((), I32)]),
+})
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, uncached):
     fn, args = CASES[name]
@@ -228,7 +259,11 @@ def test_kernel_compiles_for_v5e(name, one_chip, uncached):
                            "flash_attention[encoder hd=64]": (8 * 16, 1536, 64),
                            "flash_attention[encoder S=1024]": (32 * 16, 1024, 64),
                            "flash_attention[encoder S=2048]": (32 * 16, 2048, 64),
-                           "flash_attention[16384 streamed]": (H, 16384, HD)}[name]
+                           "flash_attention[16384 streamed]": (H, 16384, HD),
+                           "flash_attention_window[72 heads]": (72, 4096, HD),
+                           "flash_attention_window[48 heads]": (48, 4096, HD),
+                           "flash_attention[72 heads]": (72, 4096, HD),
+                           "flash_attention[48 heads]": (48, 4096, HD)}[name]
         assert re.search(rf"%{kernel}(\.\d+)? = bf16\[{heads},{S},{width}\]\S* custom-call\(", text), name
         assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
     if re.match(r"(mla_)?decode_attention", name):
@@ -239,7 +274,8 @@ def test_kernel_compiles_for_v5e(name, one_chip, uncached):
         shape = {"decode_attention": "8,8,4,128", "decode_attention_q8": "8,8,4,128",
                  "decode_attention[tp4 local heads]": "4,2,4,128",
                  "mla_decode_attention[B=8]": "8,128,512",
-                 "mla_decode_attention[B=8, 64 heads]": "8,64,512"}[name]
+                 "mla_decode_attention[B=8, 64 heads]": "8,64,512",
+                 "decode_attention[72 heads]": "8,8,9,128", "decode_attention[48 heads]": "8,8,6,128"}[name]
         assert re.search(rf"%{name.split('[')[0]}(\.\d+)? = bf16\[{shape}\]\S* custom-call\(", text), name
         assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
 
@@ -322,6 +358,39 @@ def test_latent_moe_generate_program_compiles_with_its_kernels(one_chip, uncache
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
     text = jax.jit(fn).lower(params, tok, tok, rng).compile().as_text()
     for kernel in ("mla_flash_attention", "mla_decode_attention", "grouped_matmul"):
+        assert kernel in text, f"{kernel}: not in the compiled program"
+
+
+def test_windowed_moe_generate_program_compiles_with_its_kernels(one_chip, uncached):
+    """The third decoder family's batched generate program, at toy widths but
+    the published head geometry (9 and 6 query heads a KV head of 128, window
+    512 under a 1024 bucket), through the Pallas path: the windowed and the
+    full flash prefill, the decode kernel on both layer kinds and the grouped
+    expert matmul all lower for the chip inside one program."""
+    from rag_llm_k8s_tpu.core.config import (
+        DTypePolicy, EngineConfig, GoodputConfig, SamplingConfig, WindowedMoEConfig,
+    )
+    from rag_llm_k8s_tpu.engine import engine as engine_mod
+    from rag_llm_k8s_tpu.models.windowed_moe import init_windowed_moe_params
+    kinds = ("full_attention", "sliding_attention", "sliding_attention", "full_attention")
+    cfg = WindowedMoEConfig.tiny(
+        vocab_size=512, hidden_size=256, intermediate_size=512, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128, num_kv_heads=2, head_dim=128, sliding_window=512,
+        layer_types=kinds, num_attention_heads_per_layer=(12, 18, 18, 12),
+        mlp_layer_types=("dense", "sparse", "sparse", "sparse"), max_seq_len=2048)
+    dt = DTypePolicy()
+    shapes = jax.eval_shape(lambda: init_windowed_moe_params(jax.random.PRNGKey(0), cfg, dt))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    ec = EngineConfig(prompt_buckets=(1024,), max_seq_len=1280, attn_impl="pallas", speculative="off",
+                      goodput=GoodputConfig(enabled=False))
+    eng = engine_mod.InferenceEngine(
+        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=8),
+        engine_config=ec, dtypes=dt)
+    fn = eng._make_gen(2, 1024, 8)
+    tok = jax.ShapeDtypeStruct((2, 1024), I32, sharding=one_chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(fn).lower(params, tok, tok, rng).compile().as_text()
+    for kernel in ("%flash_attention_window", "%flash_attention.", "%decode_attention", "%grouped_matmul"):
         assert kernel in text, f"{kernel}: not in the compiled program"
 
 
