@@ -11,7 +11,8 @@ device from ``--seed``:
 
 1. device gate — no accelerator, no run (there is no CPU fallback);
 2. every main-path Pallas kernel, compiled by Mosaic, against its XLA oracle
-   at 8B head geometry;
+   at 8B head geometry; the sparse experts' combine against the XLA
+   scatter-add at the three served prefill shapes;
 3. the default deployment shape (coalesce batching, single-fetch RAG,
    speculation auto) assembled by ``server.main.assemble_service``, warmed,
    served by a real werkzeug server over sockets: /healthz, /upload_pdf,
@@ -154,6 +155,15 @@ SERVING_DECODE = {
     "decode_attention[4,2,4,128]": ("bf16", 4, 8, [1290, 1305, 1320, 1296]),  # Nemo's local heads at tp=4
     "mla_decode_attention[8,128,512]": ("latent", 8, 128, [402, 431, 470, 498, 512, 530, 547, 455]),
     "mla_decode_attention[8,64,512]": ("latent", 8, 64, [402, 431, 470, 498, 512, 530, 547, 455]),
+}
+
+# the held experts' combine at the three sparse-expert cells' prefill shapes
+# (batch 8 of a 4096 bucket): name -> (tokens, a pass's rows, width,
+# assignments a token that fall on the 16 held experts)
+SERVING_COMBINE = {
+    "expert_combine[32768,32768,7168]": (32768, 32768, 7168, 0.55),
+    "expert_combine[32768,16384,6144]": (32768, 16384, 6144, 0.25),
+    "expert_combine[32768,40960,3072]": (32768, 40960, 3072, 0.64),
 }
 
 
@@ -450,6 +460,60 @@ def phase_kernels(seed: int) -> None:
     check(err <= bound, f"rope_rerotate_q8 drift {err} over bound {bound}")
     say("kernel", name="rope_rerotate[delta=0] + rope_rerotate_q8",
         max_abs_err=err, bound=bound)
+
+
+def phase_combine(seed: int, cases=None, interpret: bool = False) -> None:
+    """``ops/moe.py expert_combine`` against the XLA scatter-add it replaced,
+    on a pass as the experts leave it (rows run by run, an expert each, tokens
+    rising inside a run, the tail behind the routed rows unwritten: NaN here):
+    values, the kernel's own count of what it combined, and microseconds a
+    call of both (eight calls chained in one program, the best of three)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from rag_llm_k8s_tpu.ops import moe
+
+    def scatter(acc, y, weight, token, tail):
+        # as the parent did: the tail's rows are zeros added to real tokens
+        rows = jnp.where((token < acc.shape[0])[:, None], y.astype(jnp.float32) * weight[:, None], 0.0)
+        return acc.at[jnp.where(token < acc.shape[0], token, tail)].add(rows.astype(acc.dtype))
+
+    def us_a_call(fn, acc, *rest):
+        many = jax.jit(lambda a, *r: jax.lax.fori_loop(0, 8, lambda i, x: fn(x, *r), a))
+        times = []
+        for _ in range(4):
+            t0 = time.perf_counter()
+            many(acc, *rest).block_until_ready()
+            times.append(time.perf_counter() - t0)
+        return round(min(times[1:]) / 8 * 1e6, 1)
+
+    for name, (N, C, D, load) in (cases or SERVING_COMBINE).items():
+        rng = np.random.default_rng(seed)
+        runs = [np.flatnonzero(rng.random(N) < load / 16) for _ in range(16)]
+        held = np.concatenate(runs)[:C]
+        expert = np.repeat(np.arange(16), [len(r) for r in runs])[:C]
+        token = jnp.asarray(np.concatenate([held, np.full(C - len(held), N)]), jnp.int32)
+        group = jnp.asarray(np.concatenate([expert, np.full(C - len(held), 16)]), jnp.int32)
+        tail = jnp.asarray(np.sort(rng.integers(0, N, C)), jnp.int32)
+        key = jax.random.PRNGKey(seed)
+        y = jnp.where((token < N)[:, None], jax.random.normal(key, (C, D), jnp.bfloat16), jnp.nan)
+        weight = jax.random.uniform(jax.random.fold_in(key, 1), (C,), jnp.float32, 0.05, 0.5)
+        acc = jax.random.normal(jax.random.fold_in(key, 2), (N, D), jnp.bfloat16)
+        blocks = moe.combine_blocks(N, C, D, 2)
+        check(blocks is not None, f"{name}: the rule sends a served prefill shape to the dense dot")
+        combine = functools.partial(moe.expert_combine, groups=16, blocks=blocks, interpret=interpret)
+        got, hot = combine(acc, y, weight, token, group)
+        want = np.asarray(scatter(acc, y, weight, token, tail), np.float32)
+        got = np.asarray(got, np.float32)
+        check(np.isfinite(got).all(), f"{name}: non-finite kernel output")
+        check(int(hot) == len(held), f"{name}: combined {int(hot)} of {len(held)} rows")
+        # one rounding to bf16 against one after every row: a few of the sum's last places
+        np.testing.assert_allclose(got, want, rtol=2e-2, atol=6e-2, err_msg=name)
+        say("kernel", name=name, blocks=blocks, rows_combined=int(hot),
+            max_abs_err=float(np.max(np.abs(got - want))),
+            us_a_call=us_a_call(lambda a, *r: combine(a, *r)[0], acc, y, weight, token, group),
+            xla_scatter_add_us_a_call=us_a_call(scatter, acc, y, weight, token, tail))
 
 
 # ---------------------------------------------------------------------------
@@ -767,6 +831,7 @@ def run_one_chip(args, counter, errors) -> None:
     device = jax.devices()[0]
     say("kernels", event="start")
     phase_kernels(args.seed)
+    phase_combine(args.seed)
     gc.collect()
 
     tokenizers = load_tokenizers()

@@ -65,14 +65,17 @@ from rag_llm_k8s_tpu.ops.attention import decode_slots_streamed
 # cache: verify, chunked prefill, the scorer); ``what`` is ops.moe.ExpertCounts
 # plus the layer-calls the mode made, and last the cache slots a step through
 # the decode kernel fetched over the rows and rows x the slots allocated
-# (``ops/attention.py decode_slots_streamed``; decode only).
+# (``ops/attention.py decode_slots_streamed``; decode only), then the one-hot
+# entries the experts' combine set (appended last: the older fields keep
+# their places).
 COUNTER_MODES = ("prefill", "decode", "chunk")
 COUNTER_FIELDS = ("tokens", "routed", "computed", "experts_hit", "layer_calls", "zero",
-                  "slots_streamed", "slots_allocated")
+                  "slots_streamed", "slots_allocated", "combined")
 N_COUNTERS = len(COUNTER_MODES) * len(COUNTER_FIELDS)
 # what ``/metrics`` calls them (``engine_<name>``) -> (mode, field) of the
 # block; the first sums its field over the modes. Assignments to experts HELD
-# here and assignment rows the grouped kernel stored, by how the model was
+# here, assignment rows the grouped kernel stored and (last) one-hot entries
+# the combine set, each by the kernel's own count, by how the model was
 # called; held experts hit, summed over decode layer-steps, and those steps;
 # assignments to zero-computation experts (none where a model has none)
 COUNTER_STATS = {
@@ -91,6 +94,9 @@ COUNTER_STATS = {
     "moe_chunk_assignments_zero": ("chunk", "zero"),
     "decode_slots_streamed": ("decode", "slots_streamed"),
     "decode_slots_allocated": ("decode", "slots_allocated"),
+    "moe_prefill_assignments_combined": ("prefill", "combined"),
+    "moe_decode_assignments_combined": ("decode", "combined"),
+    "moe_chunk_assignments_combined": ("chunk", "combined"),
 }
 
 
@@ -374,8 +380,8 @@ class SparseMLP(nn.Module):
 
 def _count(counters, mode: str, counts: moe.ExpertCounts):
     base = COUNTER_MODES.index(mode) * len(COUNTER_FIELDS)
-    add = jnp.stack([counts.tokens, counts.routed, counts.computed, counts.experts_hit, jnp.int32(1),
-                     jnp.asarray(counts.zero, jnp.int32)])
+    add = {**counts._asdict(), "layer_calls": 1}  # the slots are the decode kernel's, counted a step
+    add = jnp.stack([jnp.asarray(add.get(f, 0), jnp.int32) for f in COUNTER_FIELDS])
     return jax.lax.dynamic_update_slice(
         counters, jax.lax.dynamic_slice(counters, (base,), (add.shape[0],)) + add, (base,))
 

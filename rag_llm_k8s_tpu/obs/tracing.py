@@ -70,7 +70,7 @@ SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
 # opened BENEATH a sub-scope by the latent-attention sparse-expert family
 # (models/latent_moe.py): ``attn/latent`` (the attention over the latent
 # cache itself, its absorbing matmuls included), ``mlp/router``,
-# ``mlp/experts`` (gather, grouped matmuls, scatter), ``mlp/shared``,
+# ``mlp/experts`` (gather, grouped matmuls, combine), ``mlp/shared``,
 # ``mlp/zero`` (the zero-computation experts' term) and ``mlp/dense`` (a
 # dense SwiGLU: a leading dense layer's, a shortcut-connected layer's two).
 # The windowed-attention family (models/windowed_moe.py) opens ``attn/window``

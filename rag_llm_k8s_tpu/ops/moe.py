@@ -12,14 +12,17 @@ THIS chip holds (a contiguous range of the published experts): assignments
 to held experts are gathered into rows sorted by expert, with **no capacity
 limit**, and run through three grouped matmuls (gate, up, down) whose work
 follows the rows that exist: an expert no token chose is not read, and any
-imbalance only adds passes over a fixed-size row buffer. What experts held
-elsewhere would add is left out; nothing stands in for their chips.
+imbalance only adds passes over a fixed-size row buffer. A pass's rows go
+back into their tokens as a one-hot matmul (``combine``): one dense dot where
+rows x tokens is small, the kernel ``expert_combine`` over token tiles where
+it is large; no program scatters. What experts held elsewhere would add is
+left out; nothing stands in for their chips.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -78,7 +81,7 @@ def zero_expert_term(
     n_routed: int,
 ) -> Tuple[jax.Array, jax.Array]:
     """``(sum_{chosen e >= n_routed} w_e) * x`` for every token, ``[N, D]`` in
-    ``x``'s dtype (float32 product, cast like the held experts' scatter-add),
+    ``x``'s dtype (float32 product, cast once like the held experts' combine),
     and the number of assignments to zero-computation experts. An identity
     expert gathers nothing, holds no weight and takes no row of the grouped
     buffer; every chip computes the term for its own tokens."""
@@ -205,6 +208,255 @@ def _grouped_xla(lhs, rhs, group_sizes, layer):
 
 
 # ---------------------------------------------------------------------------
+# the combine: a pass's rows summed back into their tokens (one-hot matmul)
+# ---------------------------------------------------------------------------
+
+# rows x tokens up to which the one-hot sum is ONE dense dot in XLA, and what
+# a grid cell of the kernel may take of VMEM: the sweep behind both is in
+# PERF.md section 6, PR 34
+COMBINE_DENSE = 1 << 19
+_COMBINE_VMEM = 12 << 20
+
+
+def combine_blocks(N: int, C: int, D: int, itemsize: int) -> Optional[Tuple[int, int, int]]:
+    """How ``C`` rows are summed into ``N`` tokens of width ``D``, from the
+    shape alone (no option, no model's name): None for the dense dot, else
+    ``expert_combine``'s ``(tn, R, dblk)``: a grid cell sums into ``tn``
+    tokens by ``dblk`` columns, from rows fetched ``R`` at a time.
+
+    Swept on a v5e at 7168 columns (PR 34): the dot wins up to 1024 tokens x
+    512 rows (142 us against the kernel's 159) and loses from 1024 x 1024 on
+    (188 against 161; 1536 against 405 at 4096 x 4096), so decode (8 x 128),
+    a verify chunk (16 x 128) and a 512-token prefill chunk take the dot and
+    every prefill of a bucket the kernel. ``tn`` is the largest halving of
+    256 that tiles ``N``; ``R`` is the row tile (``ROW_ALIGN``: a tile's
+    range starts anywhere, so a longer step fetches more rows of its
+    neighbours; 256 lost 1-6%, 512 10-24%); ``dblk`` the widest
+    multiple of 128 dividing ``D`` whose cell (the tile in and out, double
+    buffered, its float32 sum, two row buffers) fits ``_COMBINE_VMEM``: a
+    narrower block only repeats the walk (896 columns +5% on 1792). All of
+    it moves a call by 3-6%: the call is its bytes."""
+    if N * C <= COMBINE_DENSE:
+        return None
+    tn, R = _fit_block(N, 256), ROW_ALIGN
+    if tn % 16 or C % R:
+        return None  # no tile of whole sublanes: a shape no program serves at this size
+    cols = [b for b in range(128, D + 1, 128) if D % b == 0] or [D]
+    fit = [b for b in cols if b * (tn * (4 * itemsize + 4) + 2 * R * itemsize) <= _COMBINE_VMEM]
+    return tn, R, max(fit or cols[:1])
+
+
+def _expert_combine_kernel(
+    first_ref,  # SMEM [tiles + 1]: the row where each token tile's rows start
+    tok_ref,  # VMEM [C / R, R] int32: the rows' tokens, row by row (N: no token)
+    acc_ref,  # VMEM [tn, dblk]: what the tile's tokens hold so far
+    rows_hbm,  # [C, D] in the order of their token tiles, left in HBM
+    out_ref,  # [tn, dblk] (the same memory as ``acc_ref``'s array)
+    count_ref,  # SMEM [1]: one-hot entries set
+    buf,  # VMEM [2, R, dblk]
+    sem,  # DMA [2]
+    turn_ref,  # SMEM [1]: which buffer the cell's first step is in
+    sum_scr,  # VMEM [tn, dblk] float32
+    *,
+    tn: int,
+    R: int,
+    dblk: int,
+    blocks: int,
+    precision,
+):
+    """One grid cell: token tile ``t``, column block ``d``. The tile's rows
+    are ONE range ``[first[t], first[t + 1])`` of the tile-ordered rows; the
+    cell copies the ``R``-row blocks that range touches itself, one a step
+    into one of two buffers while the step before is summed (``ops/attention
+    .py _decode_walk``'s pattern), the next cell's first block in flight
+    under this cell's last. A step's one-hot block is (row's token = tile's
+    token): rows of other tiles in a fetched block, and rows of no token,
+    match none, so no bound is compared. A tile with no rows still takes one
+    step (its block matches nothing): every cell has a first fetch."""
+    t, d = pl.program_id(0), pl.program_id(1)
+    nt, nd = pl.num_programs(0), pl.num_programs(1)
+
+    def first_block(tile):  # (whole numbers, none negative: ``lax.div``, not ``//`` and its signs)
+        return jnp.minimum(jax.lax.div(first_ref[tile], R), blocks - 1)
+
+    def copy(block, col, slot):
+        src = rows_hbm.at[pl.ds(pl.multiple_of(block * R, R), R), pl.ds(pl.multiple_of(col * dblk, dblk), dblk)]
+        return pltpu.make_async_copy(src, buf.at[slot], sem.at[slot])
+
+    @pl.when((t == 0) & (d == 0))
+    def _first_cell():
+        turn_ref[0] = 0
+        count_ref[0] = 0
+        copy(first_block(0), 0, 0).start()
+
+    turn = turn_ref[0]
+    b0 = first_block(t)
+    n = jnp.maximum(jax.lax.div(first_ref[t + 1] + (R - 1), R) - b0, 1)
+    sum_scr[...] = acc_ref[...].astype(jnp.float32)
+    tile_tokens = t * tn + jax.lax.broadcasted_iota(jnp.int32, (tn, R), 0)
+    wrap = d + 1 == nd  # the next cell is the next tile's first column block
+
+    def one_step(j, carry):
+        slot = (turn + j) & 1
+        more = j + 1 < n
+
+        @pl.when(more | ~(wrap & (t + 1 == nt)))
+        def _prefetch():
+            ahead = first_block(jnp.where(wrap, jnp.minimum(t + 1, nt - 1), t))
+            col = jnp.where(more, d, jnp.where(wrap, 0, d + 1))
+            copy(jnp.where(more, b0 + j + 1, ahead), col, 1 - slot).start()
+
+        copy(b0 + j, d, slot).wait()
+        hot = tok_ref[pl.ds(b0 + j, 1), :] == tile_tokens  # [tn, R]
+        sum_scr[...] += jax.lax.dot_general(
+            hot.astype(buf.dtype), buf[slot], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+
+        @pl.when(d == 0)
+        def _count():
+            count_ref[0] += jnp.sum(hot.astype(jnp.int32))
+
+        return carry
+
+    jax.lax.fori_loop(0, n, one_step, 0)
+    turn_ref[0] = (turn + n) & 1
+    out_ref[...] = sum_scr[...].astype(out_ref.dtype)
+
+
+def _one_hot_precision(dtype):
+    """A 0/1 matrix times bf16 rows is exact in one pass; float32 rows (the
+    fp32 policy) need the highest precision said, or a TPU rounds them."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _weighted_rows(y, weight, has_token, dtype):
+    """``weight_i * y_i`` formed in float32, zero for a row of no token (what
+    the grouped kernel left unwritten there may be anything, NaN included)."""
+    return jnp.where(has_token[:, None], y.astype(jnp.float32) * weight.astype(jnp.float32)[:, None],
+                     0.0).astype(dtype)
+
+
+def rows_by_token_tile(token, group, groups: int, N: int, tn: int):
+    """Where a pass's rows go when they are put in the order of their token
+    TILES: ``(src [C], first [N / tn + 1])``: row ``r`` of the new order is row
+    ``src[r]`` of the pass, and tile ``t``'s rows are ``[first[t], first[t +
+    1])`` of the new order (rows of no token stay behind them all).
+
+    No sort (a one-dimensional sort of 32768 keys takes the TPU compiler 18 s
+    a program, and 0.3 ms a call): a pass is ``groups`` runs, one an expert,
+    inside each of which tokens only rise (``held_expert_ffn``'s stable
+    argsort), so the rows of (run, tile) are a block of the pass whose bounds
+    are counts: how many rows lie under ``(run, tile's first token)``. The
+    new order is the blocks tile by tile, a row's source its own place plus
+    what the blocks in front of it were moved by; both are compare-and-sum
+    fusions over rows x blocks (2064 blocks at 16 experts and 128 tiles)."""
+    C = token.shape[0]
+    tiles, span = N // tn, N + 1
+    assert (groups + 1) * span < 2**31, "the (run, token) key is an int32"
+    key = jnp.minimum(group, groups).astype(jnp.int32) * span + jnp.minimum(token, N).astype(jnp.int32)
+    bounds = (jnp.arange(groups, dtype=jnp.int32)[:, None] * span
+              + jnp.arange(tiles + 1, dtype=jnp.int32)[None, :] * tn)  # [run, tile]
+    under = jnp.sum(key[None, None, :] < bounds[:, :, None], axis=-1).astype(jnp.int32)  # rows in front of the block
+    start = under[:, :-1].T.reshape(-1)  # blocks tile by tile: where each starts in the pass
+    size = (under[:, 1:] - under[:, :-1]).T.reshape(-1)
+    end = jnp.cumsum(size)  # ... and where each ends, and starts, in the new order
+    new_start = end - size
+    moved = start - new_start
+    r = jnp.arange(C, dtype=jnp.int32)
+    step = jnp.diff(moved, append=moved[-1:])
+    src = r + moved[0] + jnp.sum(jnp.where(end[None, :] <= r[:, None], step[None, :], 0), axis=1)
+    return jnp.where(r < end[-1], src, r), jnp.concatenate([new_start[::groups], end[-1:]])
+
+
+@functools.partial(jax.jit, static_argnames=("groups", "blocks", "interpret"))
+def expert_combine(
+    acc: jax.Array,  # [N, D]
+    y: jax.Array,  # [C, D]: a pass's rows, run by run, tokens rising inside a run
+    weight: jax.Array,  # [C] float32
+    token: jax.Array,  # [C] int32: the row's token; N for a row of no token
+    group: jax.Array,  # [C] int32: the row's run (its expert); ``groups`` for a row of no token
+    *,
+    groups: int,
+    blocks: Tuple[int, int, int],  # ``combine_blocks``'s (tn, R, dblk)
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """``acc[n] + sum_i [token_i = n] * (weight_i * y_i)``, ``[N, D]`` in
+    ``acc``'s dtype, summed in float32 and cast once; and the number of
+    one-hot entries the kernel SET, summed by the kernel itself, so a row it
+    never reached shows as fewer than the rows that have a token.
+
+    The rows are put in the order of their token tiles first
+    (``rows_by_token_tile``, and one row gather, the size of the dispatch
+    gather; ``weight_i * y_i`` is formed on the way: ``_weighted_rows``).
+    Then every token tile's rows are one contiguous range whose
+    bounds ride scalar prefetch, and the kernel adds ``one_hot @ rows`` a
+    tile on the MXU where a scatter-add walks its updates one at a time
+    (0.9 us a row at 7168 columns: PERF.md section 6, PR 34). ``acc`` is read
+    and written a tile at a time in place."""
+    N, D = acc.shape
+    C = y.shape[0]
+    tn, R, dblk = blocks
+    src, first = rows_by_token_tile(token, group, groups, N, tn)
+    key = jnp.take(token, src).astype(jnp.int32)
+    rows = _weighted_rows(jnp.take(y, src, axis=0), jnp.take(weight, src), key < N, acc.dtype)
+
+    def tile_block(t, d, first):
+        return (t, d)
+
+    out, count = pl.pallas_call(
+        functools.partial(_expert_combine_kernel, tn=tn, R=R, dblk=dblk, blocks=C // R,
+                          precision=_one_hot_precision(acc.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N // tn, D // dblk),
+            in_specs=[
+                pl.BlockSpec((C // R, R), lambda t, d, first: (0, 0)),
+                pl.BlockSpec((tn, dblk), tile_block),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec((tn, dblk), tile_block),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((2, R, dblk), acc.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((tn, dblk), jnp.float32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((N, D), acc.dtype), jax.ShapeDtypeStruct((1,), jnp.int32)],
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="expert_combine",
+    )(first, key.reshape(C // R, R), acc, rows)
+    return out, count[0]
+
+
+def _combine_dense(acc, y, weight, token):
+    """``expert_combine`` as one dense dot of the ``[N, C]`` one-hot matrix:
+    rows x tokens x columns FLOPs, so the form of small shapes (15 MFLOP a
+    decode step's layer) and of the XLA path."""
+    N = acc.shape[0]
+    hot = token[None, :] == jnp.arange(N, dtype=token.dtype)[:, None]
+    out = jnp.dot(hot.astype(acc.dtype), _weighted_rows(y, weight, token < N, acc.dtype),
+                  preferred_element_type=jnp.float32, precision=_one_hot_precision(acc.dtype))
+    return (acc.astype(jnp.float32) + out).astype(acc.dtype), jnp.sum(hot).astype(jnp.int32)
+
+
+def combine(acc, y, weight, token, group, groups: int, *, impl: str = "xla"):
+    """A pass's rows into their tokens, and the one-hot entries set: the
+    dense dot or the kernel by ``combine_blocks`` (``impl`` "xla": always the
+    dot, as ``ragged_dot`` stands in for ``grouped_matmul``)."""
+    blocks = None if impl == "xla" else combine_blocks(acc.shape[0], y.shape[0], acc.shape[1], acc.dtype.itemsize)
+    if blocks is None:
+        return _combine_dense(acc, y, weight, token)
+    return expert_combine(acc, y, weight, token, group, groups=groups, blocks=blocks,
+                          interpret=impl == "pallas_interpret")
+
+
+# ---------------------------------------------------------------------------
 # this chip's share of the routed experts
 # ---------------------------------------------------------------------------
 
@@ -217,6 +469,7 @@ class ExpertCounts(NamedTuple):
     computed: jax.Array  # assignment rows the down projection's kernel stored, by its own count
     experts_hit: jax.Array  # held experts with at least one assignment
     zero: jax.Array = 0  # assignments to zero-computation experts (``zero_expert_term``)
+    combined: jax.Array = 0  # one-hot entries the combine set, by its own count (== routed)
 
 
 def rows_per_pass(n_tokens: int, top_k: int, n_experts: int, held: int) -> int:
@@ -243,7 +496,11 @@ def held_expert_ffn(
     impl: str = "xla",  # "xla" (ragged_dot) | "pallas" | "pallas_interpret"
 ) -> Tuple[jax.Array, ExpertCounts]:
     """``sum_i w_i * E_i(x)`` over the chosen experts in ``[first_held,
-    first_held + held)``, every ``E`` a SwiGLU; ``[N, D]`` in ``x``'s dtype."""
+    first_held + held)``, every ``E`` a SwiGLU; ``[N, D]`` in ``x``'s dtype.
+    A pass gathers its rows, runs the three grouped matmuls and sums the
+    weighted rows back into their tokens (``combine``); ``counts.computed``
+    and ``counts.combined`` are the down projection's and the combine's own
+    counts of what they did, both equal to ``counts.routed``."""
     N, D = x.shape
     top_k = experts.shape[1]
     held = w_gate.shape[1]
@@ -267,7 +524,7 @@ def held_expert_ffn(
         grouped = functools.partial(grouped_matmul, interpret=impl == "pallas_interpret")
 
     def one_pass(carry):
-        p, acc, computed = carry
+        p, acc, computed, combined = carry
         lo = p * C
         a = jax.lax.dynamic_slice(order, (lo,), (C,))
         valid = lo + jnp.arange(C, dtype=jnp.int32) < total
@@ -276,16 +533,15 @@ def held_expert_ffn(
         sizes_here = jnp.clip(ends - lo, 0, C) - jnp.clip(starts - lo, 0, C)
         h = jax.nn.silu(grouped(rows, w_gate, sizes_here, layer)[0]) * grouped(rows, w_up, sizes_here, layer)[0]
         y, stored = grouped(h, w_down, sizes_here, layer)
-        wa = jnp.take(flat_w, a).astype(jnp.float32)
-        y = jnp.where(valid[:, None], y.astype(jnp.float32) * wa[:, None], 0.0)
-        acc = acc.at[token].add(y.astype(acc.dtype))
-        return p + 1, acc, computed + stored
+        acc, hot = combine(acc, y, jnp.take(flat_w, a), jnp.where(valid, token, N),
+                           jnp.where(valid, jnp.take(key, a), held), held, impl=impl)
+        return p + 1, acc, computed + stored, combined + hot
 
     n_pass = (total + C - 1) // C
-    _, y, computed = jax.lax.while_loop(
+    _, y, computed, combined = jax.lax.while_loop(
         lambda c: c[0] < n_pass, one_pass,
-        (jnp.int32(0), jnp.zeros((N, D), x.dtype), jnp.int32(0)))
+        (jnp.int32(0), jnp.zeros((N, D), x.dtype), jnp.int32(0), jnp.int32(0)))
     counts = ExpertCounts(
         tokens=jnp.int32(N), routed=total.astype(jnp.int32), computed=computed,
-        experts_hit=jnp.sum(sizes > 0).astype(jnp.int32))
+        experts_hit=jnp.sum(sizes > 0).astype(jnp.int32), combined=combined)
     return y, counts

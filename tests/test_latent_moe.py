@@ -12,6 +12,8 @@ adds the same float32 terms in another order: 2e-5.
 """
 
 import dataclasses
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -426,3 +428,141 @@ def test_rowwise_is_decided_by_shape_and_changes_nothing(params, monkeypatch):
     monkeypatch.setattr(lm, "ROWWISE_BYTES", 1)
     assert lm.rowwise(CFG, 3, 32, jnp.float32)
     assert engine_for(params).generate(prompts) == want
+
+
+# ---- the combine: rows back into their tokens as a one-hot matmul ----
+
+
+RUNS = 4  # a pass is so many runs (an expert each) of rows whose tokens rise
+
+
+def _combine_case(N, C, D, n_valid, spread, seed=7):
+    """``C`` rows of which the first ``n_valid`` have a token among the first
+    ``spread`` of ``N``, run by run as a pass holds them (the rest none: NaN
+    where the grouped kernel wrote nothing), and the scatter-add as oracle."""
+    rng = np.random.default_rng(seed)
+    token, group = np.full(C, N, np.int32), np.full(C, RUNS, np.int32)
+    t, g = rng.integers(0, spread, n_valid), np.sort(rng.integers(0, RUNS, n_valid))
+    by = np.lexsort((t, g))
+    token[:n_valid], group[:n_valid] = t[by], g[by]
+    y = rng.standard_normal((C, D)).astype(np.float32)
+    y[n_valid:] = np.nan
+    weight = rng.uniform(0.1, 1.0, C).astype(np.float32)
+    acc = rng.standard_normal((N, D)).astype(np.float32)
+    want = acc.copy()
+    np.add.at(want, token[:n_valid], weight[:n_valid, None] * y[:n_valid])
+    return tuple(map(jnp.asarray, (acc, y, weight, token, group))), want
+
+
+@pytest.mark.parametrize("N,C,D,n_valid,spread,blocks", [
+    (64, 256, 128, 200, 4, (16, 128, 128)),  # a token with fifty rows; twelve tiles' worth of tokens with none
+    (64, 256, 128, 40, 64, (16, 128, 128)),  # an invalid tail five times the valid rows
+    (64, 512, 128, 512, 16, (16, 128, 128)),  # one tile's range crosses three steps of R
+    (64, 256, 128, 0, 64, (16, 128, 128)),  # no row at all: every tile writes what it held
+    (8, 128, 256, 5, 8, (8, 128, 128)),  # the decode shape, through the kernel too
+    (32, 256, 3072, 90, 32, (16, 128, 3072)),  # the served widths' column blocks
+    (32, 256, 6144, 90, 32, (16, 128, 3072)),
+    (32, 256, 7168, 90, 32, (16, 128, 1792)),
+], ids=["many-a-token", "long-tail", "range-crosses-steps", "no-rows", "decode-8x128", "D3072", "D6144", "D7168"])
+def test_combine_is_the_scatter_add(N, C, D, n_valid, spread, blocks):
+    args, want = _combine_case(N, C, D, n_valid, spread)
+    for got, hot in (moe._combine_dense(*args[:4]),
+                     moe.expert_combine(*args, groups=RUNS, blocks=blocks, interpret=True)):
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+        assert int(hot) == n_valid
+    if blocks[2] > 128:  # the rule's column block at this width, under the cell's VMEM
+        assert moe.combine_blocks(32768, 32768, D, 2)[2] == blocks[2]
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_two_passes_combine_every_assignment(impl, monkeypatch):
+    """``total > C``: the second pass adds into what the first left, through
+    the kernel where the shape asks for it (the boundary lowered to force it)."""
+    monkeypatch.setattr(moe, "COMBINE_DENSE", 1 << 10)
+    rng = np.random.default_rng(3)
+    N, D, F, held, top_k = 256, 128, 16, 4, 2
+    x = jnp.asarray(rng.standard_normal((N, D)), jnp.float32)
+    w = [jnp.asarray(rng.standard_normal(s) / 4, jnp.float32)
+         for s in ((1, held, D, F), (1, held, D, F), (1, held, F, D))]
+    experts = jnp.stack([jnp.full((N,), 9), 8 + jnp.asarray(rng.integers(0, 4, N))], 1).astype(jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, (N, top_k)), jnp.float32)
+    C = moe.rows_per_pass(N, top_k, 64, held)
+    assert C < N * top_k and moe.combine_blocks(N, C, D, 4) == (256, 128, 128)  # impl "xla" keeps the dot
+    with jax.default_matmul_precision("highest"):
+        y, counts = moe.held_expert_ffn(x, experts, weights, *w, jnp.int32(0), 8, 64, impl=impl)
+        want = sum(jnp.where(experts[:, j:j + 1] == 8 + e, weights[:, j:j + 1], 0.0)
+                   * ref._swiglu(x, w[0][0, e], w[1][0, e], w[2][0, e])
+                   for e in range(held) for j in range(top_k))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-4)
+    assert int(counts.combined) == int(counts.routed) == int(counts.computed) == N * top_k
+
+
+def test_held_expert_ffn_holds_no_scatter():
+    """Neither the prefill shape (the kernel) nor the decode shape (the dense
+    dot) adds by scatter: the jaxpr of the whole function, sub-jaxprs included."""
+    held, D, F = 16, 256, 128
+    w = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in ((1, held, D, F), (1, held, D, F), (1, held, F, D))]
+    for N, tiled in ((8192, True), (8, False)):
+        C = moe.rows_per_pass(N, 8, 256, held)
+        assert (moe.combine_blocks(N, C, D, 2) is not None) == tiled
+        text = str(jax.make_jaxpr(functools.partial(
+            moe.held_expert_ffn, first_held=16, n_experts=256, impl="pallas_interpret"))(
+            jax.ShapeDtypeStruct((N, D), jnp.bfloat16), jax.ShapeDtypeStruct((N, 8), jnp.int32),
+            jax.ShapeDtypeStruct((N, 8), jnp.float32), *w, jax.ShapeDtypeStruct((), jnp.int32)))
+        assert ("expert_combine" in text) == tiled
+        # what is left adds into vectors of a few entries (the grouped kernel's
+        # metadata: histograms over experts and row tiles), never into rows
+        adds = re.findall(r"\w+\[([\d,]*)\] = scatter[-_]add", text)
+        assert adds and all("," not in shape and int(shape) <= 4 * held for shape in adds), adds
+
+
+def test_combine_rule_for_the_served_shapes():
+    """The three cells' prefill (batch 8 of a 4096 bucket) takes the kernel at
+    these tiles; decode, a verify chunk and a 512-token prefill chunk the dot."""
+    served = {"dots": (8, 256, 7168), "longcat": (12, 768, 6144), "laguna": (10, 256, 3072)}
+    rows = {k: moe.rows_per_pass(32768, top_k, E, 16) for k, (top_k, E, _) in served.items()}
+    assert rows == {"dots": 32768, "longcat": 16384, "laguna": 40960}
+    assert {k: moe.combine_blocks(32768, rows[k], D, 2) for k, (_, _, D) in served.items()} == {
+        "dots": (256, 128, 1792), "longcat": (256, 128, 3072), "laguna": (256, 128, 3072)}
+    for top_k, E, D in served.values():
+        for N in (8, 16, 512):
+            assert moe.combine_blocks(N, moe.rows_per_pass(N, top_k, E, 16), D, 2) is None
+    assert moe.combine_blocks(1024, 512, 7168, 2) is None and moe.combine_blocks(1024, 1024, 7168, 2) is not None
+
+
+# ---- the benchmark's reader of the held experts' prefill time ----
+
+
+@pytest.mark.parametrize("case,by,rows,want", [
+    ("a_slice_with_the_scope", {"prefill": {"experts": 0.24, "router": 0.1}, "decode": {"experts": 9.0}}, 24.0, 10.0),
+    ("a_program_without_the_scope", {"prefill": {"dense": 1.0}}, 24.0, None),
+    ("no_prefill_row_in_the_slice", {"prefill": {"experts": 0.24}}, 0.0, None),
+    ("no_trace", None, 24.0, None),
+])
+def test_reader_of_held_experts_prefill_ms_per_row(case, by, rows, want):
+    """``benchmark/layer_metrics/held_experts_prefill_ms_per_row.py`` divides
+    ``prefill/.../mlp/experts`` by the slice's prefill rows, returns None (and
+    does not raise) where either is missing, and ``BENCHMARK.json`` lists it
+    for the three sparse-expert cells."""
+    import importlib.util
+    import json
+    import os
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, repo)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "held_experts_reader", os.path.join(repo, "benchmark/layer_metrics/held_experts_prefill_ms_per_row.py"))
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.remove(repo)
+    ctx = {"trace": None} if by is None else {"trace": {}, "fine_scopes": by, "phases": {"prefill_rows": rows, "steps": {}}}
+    got = reader.read(ctx)
+    assert got is None if want is None else got == pytest.approx(want)
+    with open(os.path.join(repo, "BENCHMARK.json")) as f:
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}["held_experts_prefill_ms_per_row"]
+    assert entry == {"name": "held_experts_prefill_ms_per_row", "unit": "ms", "better": "lower",
+                     "source": "device_trace", "layer": "model step", "moves": "latency_p50_ms",
+                     "workloads": ["dots-vlm1-ep16.closed8", "longcat-flash-ep32.closed8", "laguna-s-ep16.closed8"]}

@@ -243,6 +243,26 @@ CASES.update({
 })
 
 
+def _combine(N: int, C: int, D: int):
+    from rag_llm_k8s_tpu.ops import moe
+
+    def fn(acc, y, weight, token, group):
+        return moe.expert_combine(acc, y, weight, token, group, groups=16,
+                                  blocks=moe.combine_blocks(N, C, D, 2))
+
+    return fn, [((N, D), BF16), ((C, D), BF16), ((C,), F32), ((C,), I32), ((C,), I32)]
+
+
+# the held experts' combine at the three sparse-expert cells' prefill shapes
+# (batch 8 of a 4096 bucket; a pass's rows by ``rows_per_pass``), at the
+# rule's tiles: 256 tokens x 1792 / 3072 / 3072 columns a grid cell
+CASES.update({
+    "expert_combine[32768 rows, 7168]": _combine(32768, 32768, 7168),
+    "expert_combine[16384 rows, 6144]": _combine(32768, 16384, 6144),
+    "expert_combine[40960 rows, 3072]": _combine(32768, 40960, 3072),
+})
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, uncached):
     fn, args = CASES[name]
@@ -266,6 +286,12 @@ def test_kernel_compiles_for_v5e(name, one_chip, uncached):
                            "flash_attention[48 heads]": (48, 4096, HD)}[name]
         assert re.search(rf"%{kernel}(\.\d+)? = bf16\[{heads},{S},{width}\]\S* custom-call\(", text), name
         assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
+    if name.startswith("expert_combine"):
+        # in place (the tile read and written is the accumulator's), inside
+        # the default scoped VMEM, and no sort in front of it (a sort of a
+        # pass's keys takes this compiler 18 s a program)
+        assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
+        assert "output_to_operand_aliasing" in text and not re.search(r" sort\(", text), name
     if re.match(r"(mla_)?decode_attention", name):
         # the benchmark finds the decode kernels by name and by result shape
         # (``mla_decode_attention_roofline``: [rows, heads, rank]; the phases'
