@@ -745,11 +745,10 @@ def serve_and_check(name, config, mesh, params, tokenizers, enc_params,
             ("mixed_step", sched_engine.chunk_tokens, 1)]
     for what, compiled in programs.items():
         check("tpu_custom_call" in compiled.as_text(), f"{what}: no Pallas kernel in it")
-    emb, norms = service.store.device_snapshot()
-    tokens, mask = service.encoder.prepare_batch(enc_tok.encode(QUERIES[0]))
+    emb, _ = service.store.device_snapshot()
+    tokens, _ = service.encoder.prepare_batch(enc_tok.encode(QUERIES[0]))
     k_eff = min(config.retrieval.k, service.store.ntotal)
-    fused = service._fused_retrieve[(tokens.shape[1], emb.shape[0], k_eff, 1)]
-    text = fused.lower(service.encoder.params, tokens, mask, emb, norms).compile().as_text()
+    text = service._fused_retrieve[(tokens.shape[1], emb.shape[0], k_eff, 1)].as_text()
     check(text.count("tpu_custom_call") >= 2,
           "fused embed+kNN: encoder flash and kNN kernels not both present")
     pallas_in = sorted(programs) + ["fused embed+kNN"]

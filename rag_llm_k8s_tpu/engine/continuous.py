@@ -79,6 +79,7 @@ from rag_llm_k8s_tpu.models.llama import (
 from rag_llm_k8s_tpu.obs import flight
 from rag_llm_k8s_tpu.obs import goodput as obs_goodput
 from rag_llm_k8s_tpu.obs import metrics as obs_metrics
+from rag_llm_k8s_tpu.obs import tracing
 from rag_llm_k8s_tpu.obs.tracing import phase_scope
 from rag_llm_k8s_tpu.resilience import faults
 from rag_llm_k8s_tpu.resilience.deadline import Deadline, DeadlineExceeded
@@ -423,12 +424,6 @@ class ContinuousEngine:
         and per-window boundaries, so TTFT and inter-token latency here are
         measured EXACTLY (admission → first token; step window / k)."""
         self._obs = registry
-        self._m_compile_events = registry.counter(
-            "rag_compile_events_total", "AOT lowering/compile events"
-        )
-        self._m_compile_seconds = registry.counter(
-            "rag_compile_seconds_total", "seconds spent in AOT lowering/compile"
-        )
         self._m_ttft = registry.histogram(
             "rag_time_to_first_token_seconds",
             "submit-to-first-token (queue + coalesce + prefill + fetch)",
@@ -636,39 +631,24 @@ class ContinuousEngine:
         key = (kind, S, n)
         fn = self._compiled.get(key)
         if fn is None:
-            t0 = time.perf_counter()
-            if kind == "step":
-                fn = self._build_step(S)  # S carries the sync window here
-            elif kind == "step_paged":
-                fn = self._build_step_paged(S)
-            elif kind == "prefill":
-                fn = self._build_prefill(S, n)
-            elif kind == "prefill_paged":
-                fn = self._build_prefill_paged(S, n)
-            elif kind == "insert_paged":
-                fn = self._build_insert_paged(S, n)
-            elif kind == "prefill_px":
-                fn = self._build_prefill_prefixed(S, n)  # n carries the suffix bucket
-            elif kind == "prefill_px_paged":
-                fn = self._build_prefill_px_paged(S)  # S carries the suffix bucket
-            elif kind == "prefix_scatter":
-                fn = self._build_prefix_scatter(S)  # S carries the buffer width
-            elif kind == "chunk_splice":
-                fn = self._build_chunk_splice(S)  # S carries the block count
-            elif kind == "migrate_out":
-                fn = self._build_migrate_out(S)  # S carries the block count
-            elif kind == "migrate_in":
-                fn = self._build_migrate_in(S)  # S carries the block count
-            elif kind == "boundary_px":
-                fn = self._build_boundary_px_paged(S)  # S carries the window
-            elif kind == "verify_paged":
-                fn = self._build_verify_paged(S)  # S carries the draft count K
-            elif kind == "mixed_step":
-                fn = self._build_mixed_step(S)  # S carries the chunk width
-            else:
-                fn = self._build_insert(S, n)
-            self._m_compile_events.inc()
-            self._m_compile_seconds.inc(time.perf_counter() - t0)
+            build = {
+                "step": lambda: self._build_step(S),  # S carries the sync window here
+                "step_paged": lambda: self._build_step_paged(S),
+                "prefill": lambda: self._build_prefill(S, n),
+                "prefill_paged": lambda: self._build_prefill_paged(S, n),
+                "insert": lambda: self._build_insert(S, n),
+                "insert_paged": lambda: self._build_insert_paged(S, n),
+                "prefill_px": lambda: self._build_prefill_prefixed(S, n),  # n: the suffix bucket
+                "prefill_px_paged": lambda: self._build_prefill_px_paged(S),  # S: the suffix bucket
+                "prefix_scatter": lambda: self._build_prefix_scatter(S),  # S: the buffer width
+                "chunk_splice": lambda: self._build_chunk_splice(S),  # S: the block count
+                "migrate_out": lambda: self._build_migrate_out(S),  # S: the block count
+                "migrate_in": lambda: self._build_migrate_in(S),  # S: the block count
+                "boundary_px": lambda: self._build_boundary_px_paged(S),  # S: the window
+                "verify_paged": lambda: self._build_verify_paged(S),  # S: the draft count K
+                "mixed_step": lambda: self._build_mixed_step(S),  # S: the chunk width
+            }[kind]
+            fn = tracing.build_span("continuous", key, build, rows=n, bucket=S)
             self._compiled[key] = fn
         return fn
 
@@ -759,12 +739,12 @@ class ContinuousEngine:
         out_shardings = (
             (self._cache_shardings(), rep, rep) if self.mesh is not None else None
         )
-        return jax.jit(prefill, out_shardings=out_shardings).lower(
+        return jax.jit(prefill, out_shardings=out_shardings), (
             param_avals(self.params),
             jax.ShapeDtypeStruct((n, S), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((n, S), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((n, 2), jnp.uint32, sharding=rep),
-        ).compile()
+        )
 
     def _build_prefill_prefixed(self, S: int, C: int):
         """Batch-1 PREFIXED admission (KV prefix cache): splice a
@@ -833,14 +813,14 @@ class ContinuousEngine:
         out_shardings = (
             (self._cache_shardings(), rep, rep) if self.mesh is not None else None
         )
-        return jax.jit(prefill, out_shardings=out_shardings).lower(
+        return jax.jit(prefill, out_shardings=out_shardings), (
             param_avals(self.params),
             jax.ShapeDtypeStruct((1, C), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
             ctx_avals,
             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((1, 2), jnp.uint32, sharding=rep),
-        ).compile()
+        )
 
     def _prefix_plane_shapes(self, length: int):
         """(shape, dtype) per prefix-buffer plane — mirrors the one-shot
@@ -1329,7 +1309,7 @@ class ContinuousEngine:
         )
         # row_cache is not donated: an [L,n,...] block cannot alias into the
         # [L,B,...] cache, so donation would only emit a warning
-        return jax.jit(insert, donate_argnums=(0, 2, 3, 6), out_shardings=out_shardings).lower(
+        return jax.jit(insert, donate_argnums=(0, 2, 3, 6), out_shardings=out_shardings), (
             self._cache_avals(self.B, self.T),
             self._cache_avals(n, S),
             jax.ShapeDtypeStruct((self.B,), i32, sharding=rep),
@@ -1341,7 +1321,7 @@ class ContinuousEngine:
             jax.ShapeDtypeStruct((n,), i32, sharding=rep),
             jax.ShapeDtypeStruct((n,), i32, sharding=rep),
             jax.ShapeDtypeStruct((n, 2), jnp.uint32, sharding=rep),
-        ).compile()
+        )
 
     def _build_step(self, k: int = 1):
         """The decode executable: ``k`` decode steps for all ``B`` slots as
@@ -1409,7 +1389,7 @@ class ContinuousEngine:
         )
         # kv_start (2) and rng_keys (6) are NOT donated: neither is among the
         # outputs, and the host keeps using their buffers across steps
-        return jax.jit(step, donate_argnums=(1, 3, 4, 5), out_shardings=out_shardings).lower(
+        return jax.jit(step, donate_argnums=(1, 3, 4, 5), out_shardings=out_shardings), (
             param_avals(self.params),
             self._cache_avals(B, T),
             jax.ShapeDtypeStruct((B,), i32, sharding=rep),
@@ -1417,7 +1397,7 @@ class ContinuousEngine:
             jax.ShapeDtypeStruct((B,), i32, sharding=rep),
             jax.ShapeDtypeStruct((B,), bool, sharding=rep),
             jax.ShapeDtypeStruct((B, 2), jnp.uint32, sharding=rep),
-        ).compile()
+        )
 
 
     # ------------------------------------------------------------------
@@ -1473,12 +1453,12 @@ class ContinuousEngine:
         out_shardings = (
             (self._cache_shardings(), rep) if self.mesh is not None else None
         )
-        return jax.jit(prefill, out_shardings=out_shardings).lower(
+        return jax.jit(prefill, out_shardings=out_shardings), (
             param_avals(self.params),
             jax.ShapeDtypeStruct((n, S), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((n, 2), jnp.uint32, sharding=rep),
-        ).compile()
+        )
 
     def _build_insert_paged(self, S: int, n: int = 1):
         """Scatter ``n`` freshly prefilled rows into their pool blocks (ONE
@@ -1532,7 +1512,7 @@ class ContinuousEngine:
         )
         return jax.jit(
             insert, donate_argnums=(0, 2, 3, 5), out_shardings=out_shardings
-        ).lower(
+        ), (
             self._arena_avals(),
             row_avals,
             jax.ShapeDtypeStruct((self.B,), i32, sharding=rep),
@@ -1544,7 +1524,7 @@ class ContinuousEngine:
             jax.ShapeDtypeStruct((n,), i32, sharding=rep),
             jax.ShapeDtypeStruct((n,), i32, sharding=rep),
             jax.ShapeDtypeStruct((n, 2), jnp.uint32, sharding=rep),
-        ).compile()
+        )
 
     def _build_step_paged(self, k: int = 1):
         """The paged decode executable: identical control flow to
@@ -1614,7 +1594,7 @@ class ContinuousEngine:
         )
         return jax.jit(
             step, donate_argnums=(1, 3, 4, 5), out_shardings=out_shardings
-        ).lower(
+        ), (
             param_avals(self.params),
             self._arena_avals(),
             jax.ShapeDtypeStruct((B, self.MB), i32, sharding=rep),
@@ -1622,7 +1602,7 @@ class ContinuousEngine:
             jax.ShapeDtypeStruct((B,), i32, sharding=rep),
             jax.ShapeDtypeStruct((B,), bool, sharding=rep),
             jax.ShapeDtypeStruct((B, 2), jnp.uint32, sharding=rep),
-        ).compile()
+        )
 
     def _build_verify_paged(self, K: int):
         """The speculative VERIFY executable (ISSUE 13): one device call
@@ -1723,7 +1703,7 @@ class ContinuousEngine:
         # tables/rng_keys/drafts are host-fed per window, never donated
         return jax.jit(
             verify, donate_argnums=(1, 3, 4, 5), out_shardings=out_shardings
-        ).lower(
+        ), (
             param_avals(self.params),
             self._arena_avals(),
             jax.ShapeDtypeStruct((B, self.MB), i32, sharding=rep),
@@ -1733,7 +1713,7 @@ class ContinuousEngine:
             jax.ShapeDtypeStruct((B, 2), jnp.uint32, sharding=rep),
             jax.ShapeDtypeStruct((B, K), i32, sharding=rep),
             jax.ShapeDtypeStruct((B,), i32, sharding=rep),
-        ).compile()
+        )
 
     def _build_mixed_step(self, C: int):
         """The MIXED decode+chunk window executable (ISSUE 16): one device
@@ -1843,7 +1823,7 @@ class ContinuousEngine:
         # window, never donated
         return jax.jit(
             mixed, donate_argnums=(1, 3, 4, 5), out_shardings=out_shardings
-        ).lower(
+        ), (
             param_avals(self.params),
             self._arena_avals(),
             jax.ShapeDtypeStruct((B, self.MB), i32, sharding=rep),
@@ -1855,7 +1835,7 @@ class ContinuousEngine:
             jax.ShapeDtypeStruct((B,), i32, sharding=rep),
             jax.ShapeDtypeStruct((B,), i32, sharding=rep),
             jax.ShapeDtypeStruct((B,), bool, sharding=rep),
-        ).compile()
+        )
 
     def _build_prefix_scatter(self, P: int):
         """Scatter a ``CachedPrefix``'s splice-buffer planes into pool
@@ -1898,11 +1878,11 @@ class ContinuousEngine:
         )
         return jax.jit(
             scatter, donate_argnums=(0,), out_shardings=out_shardings
-        ).lower(
+        ), (
             self._arena_avals(),
             plane_avals,
             jax.ShapeDtypeStruct((nbp,), i32, sharding=rep),
-        ).compile()
+        )
 
     def _build_prefill_px_paged(self, C: int):
         """Paged PREFIXED admission, batch 1: the prefix KV already sits in
@@ -1941,7 +1921,7 @@ class ContinuousEngine:
         )
         return jax.jit(
             px, donate_argnums=(1,), out_shardings=out_shardings
-        ).lower(
+        ), (
             param_avals(self.params),
             self._arena_avals(),
             jax.ShapeDtypeStruct((1, self.MB), i32, sharding=rep),
@@ -1949,7 +1929,7 @@ class ContinuousEngine:
             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((1, 2), jnp.uint32, sharding=rep),
-        ).compile()
+        )
 
     def _build_chunk_splice(self, nb: int):
         """Per-chunk paged splice (chunk-granular prefix reuse): copy ``nb``
@@ -1993,12 +1973,12 @@ class ContinuousEngine:
         )
         return jax.jit(
             splice, donate_argnums=(0,), out_shardings=out_shardings
-        ).lower(
+        ), (
             self._arena_avals(),
             jax.ShapeDtypeStruct((nb,), i32, sharding=rep),
             jax.ShapeDtypeStruct((nb,), i32, sharding=rep),
             jax.ShapeDtypeStruct((), i32, sharding=rep),
-        ).compile()
+        )
 
     def _packet_avals(self, nb: int):
         """The migration packet's plane avals: the arena tuple with its
@@ -2034,10 +2014,10 @@ class ContinuousEngine:
             )
         else:
             out_shardings = None
-        return jax.jit(gather, out_shardings=out_shardings).lower(
+        return jax.jit(gather, out_shardings=out_shardings), (
             self._arena_avals(),
             jax.ShapeDtypeStruct((nb,), jnp.int32, sharding=rep),
-        ).compile()
+        )
 
     def _build_migrate_in(self, nb: int):
         """Scatter a migrated packet's planes into freshly allocated
@@ -2071,7 +2051,7 @@ class ContinuousEngine:
         )
         return jax.jit(
             splice, donate_argnums=(0, 2, 3, 5), out_shardings=out_shardings
-        ).lower(
+        ), (
             self._arena_avals(),
             self._packet_avals(nb),
             jax.ShapeDtypeStruct((self.B,), i32, sharding=rep),
@@ -2083,7 +2063,7 @@ class ContinuousEngine:
             jax.ShapeDtypeStruct((), i32, sharding=rep),
             jax.ShapeDtypeStruct((), i32, sharding=rep),
             jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
-        ).compile()
+        )
 
     def _build_boundary_px_paged(self, W: int):
         """Boundary-correction re-prefill straight into pool blocks: the
@@ -2121,13 +2101,13 @@ class ContinuousEngine:
         )
         return jax.jit(
             bfix, donate_argnums=(1,), out_shardings=out_shardings
-        ).lower(
+        ), (
             param_avals(self.params),
             self._arena_avals(),
             jax.ShapeDtypeStruct((1, self.MB), i32, sharding=rep),
             jax.ShapeDtypeStruct((1, W), jnp.int32, sharding=rep),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),
-        ).compile()
+        )
 
     # ------------------------------------------------------------------
     # chunk-granular registrations (reuse="chunk"; scheduler thread only)

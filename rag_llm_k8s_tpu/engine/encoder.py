@@ -17,7 +17,9 @@ import numpy as np
 
 from rag_llm_k8s_tpu.core.config import DTypePolicy, EncoderConfig
 from rag_llm_k8s_tpu.core.mesh import MeshContext
+from rag_llm_k8s_tpu.engine.engine import param_avals
 from rag_llm_k8s_tpu.models.bge_m3 import BgeM3Encoder
+from rag_llm_k8s_tpu.obs import tracing
 from rag_llm_k8s_tpu.resilience import faults
 from rag_llm_k8s_tpu.utils.buckets import bucket_len, next_pow2
 from rag_llm_k8s_tpu.utils.tokens import truncate_keep_eos
@@ -57,6 +59,16 @@ class EncoderRunner:
                 {"params": params}, tokens, mask
             )
         )
+        self._compiled = {}  # (batch, length bucket) -> executable
+
+    def _get(self, B: int, S: int):
+        fn = self._compiled.get((B, S))
+        if fn is None:
+            i32 = jax.ShapeDtypeStruct((B, S), jnp.int32)
+            fn = self._compiled[(B, S)] = tracing.build_span(
+                "encode", (B, S), lambda: (self._jit, (param_avals(self.params), i32, i32)),
+                rows=B, bucket=S)
+        return fn
 
     def prepare_batch(self, ids: Sequence[int]):
         """One bucketed, padded, EOS-preserving ``[1, S]`` (tokens, mask)
@@ -100,7 +112,7 @@ class EncoderRunner:
                 tokens[row, : len(ids)] = ids
                 mask[row, : len(ids)] = 1
             pending.append(
-                (group, self._jit(self.params, jnp.asarray(tokens), jnp.asarray(mask)))
+                (group, self._get(B, S)(self.params, jnp.asarray(tokens), jnp.asarray(mask)))
             )
         # device-side concat → ONE host fetch for the whole call (group
         # batch dims differ, but the hidden dim is shared)

@@ -16,9 +16,9 @@ Replaces the reference's per-request ``model.generate`` on CPU torch
   tracking all live on device; the host sees only final token ids. With
   params placed via NamedSharding, XLA propagates TP shardings through the
   loop and inserts ICI collectives.
-- **AOT compilation**: executables are built with ``jit(...).lower().compile()``
-  from abstract shapes, so ``warmup()`` pays compile time only — no throwaway
-  generations (readiness gating for the server).
+- **AOT compilation**: executables are traced, lowered and compiled from
+  abstract shapes (``obs/tracing.py build_span``), so ``warmup()`` pays
+  compile time only — no throwaway generations (readiness gating).
 - **Early exit**: the while_loop stops when every row has emitted EOS —
   short answers don't pay for ``max_new_tokens`` steps (the reference always
   runs the full HF sequential loop per request).
@@ -87,7 +87,7 @@ def _isin(tokens: jax.Array, ids: Tuple[int, ...]) -> jax.Array:
 
 
 def param_avals(params):
-    """Abstract (shape, dtype, sharding) tree for AOT ``.lower()`` calls —
+    """Abstract (shape, dtype, sharding) tree for AOT builds —
     shared by the one-shot and continuous engines."""
     return jax.tree.map(
         lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=leaf.sharding)
@@ -233,7 +233,7 @@ class InferenceEngine:
         # mesh-replicated chunk-token sidecar copies (see _placed_sidecar)
         self._sidecar_placed: Dict[Tuple[int, int], tuple] = {}
         self._lock = threading.Lock()
-        self._rag_gates: Dict[tuple, threading.Lock] = {}  # _get_rag_compiled: a build lock a key
+        self._gates: Dict[tuple, threading.Lock] = {}  # _get_or_build: a build lock a key
         self._rng_counter = 0
         self.stats = EngineStats(family_counters=dict.fromkeys(self.family.counter_names, 0))
         # goodput ledger (obs/goodput.py; ISSUE 14): generate is ONE device
@@ -264,14 +264,9 @@ class InferenceEngine:
         """Point this engine's metric handles at ``registry`` — called at
         construction with the process default and again by RagService with
         the service's own registry, so one scrape carries the engine's
-        compile events and generate/inter-token histograms."""
+        generate/inter-token histograms (what it builds is counted process-
+        wide: obs/tracing.py ``build_span``)."""
         self._obs = registry
-        self._m_compile_events = registry.counter(
-            "rag_compile_events_total", "AOT lowering/compile events"
-        )
-        self._m_compile_seconds = registry.counter(
-            "rag_compile_seconds_total", "seconds spent in AOT lowering/compile"
-        )
         self._m_generate = registry.histogram(
             "rag_generate_duration_seconds",
             "one generate call: prefill + decode + output fetch",
@@ -287,12 +282,6 @@ class InferenceEngine:
             "duration over decode steps; continuous is exact per window)",
             buckets=obs_metrics.TOKEN_LATENCY_BUCKETS,
         ).labels(mode="oneshot_est")
-
-    def _record_compile(self, seconds: float) -> None:
-        """Attribute one AOT lowering/compile to the dashboard ('first
-        request is slow' becomes a visible compile event, not a mystery)."""
-        self._m_compile_events.inc()
-        self._m_compile_seconds.inc(seconds)
 
     def _observe_generate(self, seconds: float, decode_steps: int) -> None:
         self._m_generate.observe(seconds)
@@ -344,23 +333,20 @@ class InferenceEngine:
     # compiled generate graph (one per (B, S, max_new))
     # ------------------------------------------------------------------
     def _build_generate(self, B: int, S: int, max_new: int, chunk: Optional[int] = None):
-        """AOT-compile one generate executable.
+        """One generate program and its abstract arguments (``_get_or_build``
+        builds it).
 
         ``chunk=None``: single-shot prefill at bucket ``S``. ``chunk=C``:
         ``S`` is a multiple of ``C`` and the prompt prefills through the
         cache in ``C``-sized chunks (long prompts — no silent truncation).
         """
         gen = self._make_gen(B, S, max_new, chunk)
-        # AOT-compile from abstract shapes (no execution)
+        # abstract shapes: the build executes nothing
         avals = param_avals(self.params)
         data_sharding = self.mesh.replicated if self.mesh is not None else None
         tok_aval = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=data_sharding)
         rng_aval = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=data_sharding)
-        return (
-            jax.jit(gen)
-            .lower(avals, tok_aval, tok_aval, rng_aval)
-            .compile()
-        )
+        return jax.jit(gen), (avals, tok_aval, tok_aval, rng_aval)
 
     def _make_gen(self, B: int, S: int, max_new: int, chunk: Optional[int] = None):
         """The generate graph body ``gen(params, tokens, pad_mask, rng)`` —
@@ -485,7 +471,7 @@ class InferenceEngine:
         return out[:, :-self._n_counters]
 
     def _build_generate_spec(self, S: int, max_new: int):
-        """AOT-compile the SPECULATIVE batch-1 generate executable
+        """The SPECULATIVE batch-1 generate program (and its abstract arguments)
         (``EngineConfig.speculative`` = "prompt_lookup"/"auto").
 
         Each loop iteration feeds ``k+1`` tokens — the pending last token
@@ -519,7 +505,7 @@ class InferenceEngine:
         data_sharding = self.mesh.replicated if self.mesh is not None else None
         tok_aval = jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=data_sharding)
         rng_aval = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=data_sharding)
-        return jax.jit(gen).lower(avals, tok_aval, tok_aval, rng_aval).compile()
+        return jax.jit(gen), (avals, tok_aval, tok_aval, rng_aval)
 
     def _make_gen_spec(self, S: int, max_new: int):
         """The speculative batch-1 graph body (see ``_build_generate_spec``)
@@ -677,7 +663,7 @@ class InferenceEngine:
         self, S: int, max_new: int, cap: int, Lc: int, LA: int, LB: int,
         n: int, kk: int, spec: bool,
     ):
-        """AOT-compile the SINGLE-FETCH RAG executable: device-side prompt
+        """The SINGLE-FETCH RAG program (and its abstract arguments): device-side prompt
         assembly fused in front of the (vanilla or speculative) batch-1
         generate body.
 
@@ -741,19 +727,15 @@ class InferenceEngine:
         avals = param_avals(self.params)
         ds = self.mesh.replicated if self.mesh is not None else None
         mk = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=ds)  # noqa: E731
-        return (
-            jax.jit(gen_rag)
-            .lower(
-                avals,
-                mk((LA,), jnp.int32),
-                mk((LB,), jnp.int32),
-                mk((), jnp.int32),
-                mk((1, 2 * kk), jnp.float32),
-                mk((cap, Lc), jnp.int32),
-                mk((cap,), jnp.int32),
-                mk((2,), jnp.uint32),
-            )
-            .compile()
+        return jax.jit(gen_rag), (
+            avals,
+            mk((LA,), jnp.int32),
+            mk((LB,), jnp.int32),
+            mk((), jnp.int32),
+            mk((1, 2 * kk), jnp.float32),
+            mk((cap, Lc), jnp.int32),
+            mk((cap,), jnp.int32),
+            mk((2,), jnp.uint32),
         )
 
     def generate_rag(
@@ -869,27 +851,16 @@ class InferenceEngine:
         self, S: int, max_new: int, cap: int, Lc: int, LA: int, LB: int,
         n: int, kk: int, spec: bool,
     ):
-        """Get-or-build the single-fetch RAG executable, each key ONCE however
-        many threads miss it together. Under ``speculative="auto"`` BOTH the
+        """Get-or-build the single-fetch RAG executable. Under ``speculative="auto"`` BOTH the
         spec and vanilla variants build (the EMA can flip between them
         mid-serving — a flip must never compile inside a timed request)."""
         fns = []  # the variant asked for first
         for v in ([spec, not spec] if self.engine_config.speculative == "auto" else [spec]):
             key = (1, S, max_new, ("rag", cap, Lc, LA, LB, n, kk, v))
-            with self._lock:
-                built = self._compiled.get(key)
-                gate = built is None and self._rag_gates.setdefault(key, threading.Lock())
-            if built is None:
-                with gate:  # one builder a key; a build that raises frees it for the next
-                    with self._lock:
-                        built = self._compiled.get(key)
-                    if built is None:
-                        t0 = time.perf_counter()
-                        built = self._build_generate_rag(S, max_new, cap, Lc, LA, LB, n, kk, v)
-                        self._record_compile(time.perf_counter() - t0)
-                        with self._lock:
-                            self._compiled[key] = built
-            fns.append(built)
+            fns.append(self._get_or_build(
+                key, "generate_rag",
+                lambda v=v: self._build_generate_rag(S, max_new, cap, Lc, LA, LB, n, kk, v),
+            ))
         return fns[0]
 
     def warm_rag(
@@ -1046,19 +1017,10 @@ class InferenceEngine:
 
     def _get_segment_kv(self, Sb: int):
         key = (1, Sb, 0, ("segkv", self._prefix_capacity()))
-        with self._lock:
-            fn = self._compiled.get(key)
-        if fn is None:
-            t0 = time.perf_counter()
-            fn = self._build_segment_kv(Sb)
-            self._record_compile(time.perf_counter() - t0)
-            with self._lock:
-                self._compiled.setdefault(key, fn)
-                fn = self._compiled[key]
-        return fn
+        return self._get_or_build(key, "segment_kv", lambda: self._build_segment_kv(Sb))
 
     def _build_segment_kv(self, Sb: int):
-        """AOT-compile the segment-KV builder: chunked prefill of up to
+        """The segment-KV builder's program: chunked prefill of up to
         ``Sb`` fresh tokens at a dynamic offset over a spliced context
         prefix, returning the fresh slots' KV block. One executable per
         segment bucket — never per (segment, offset) pair (both the offset
@@ -1108,16 +1070,12 @@ class InferenceEngine:
             tuple(ds for _ in self._prefix_plane_shapes(Sb))
             if self.mesh is not None else None
         )
-        return (
-            jax.jit(seg, out_shardings=out_shardings)
-            .lower(
-                param_avals(self.params),
-                jax.ShapeDtypeStruct((1, Sb), jnp.int32, sharding=ds),
-                jax.ShapeDtypeStruct((), jnp.int32, sharding=ds),
-                self._prefix_plane_avals(P),
-                jax.ShapeDtypeStruct((), jnp.int32, sharding=ds),
-            )
-            .compile()
+        return jax.jit(seg, out_shardings=out_shardings), (
+            param_avals(self.params),
+            jax.ShapeDtypeStruct((1, Sb), jnp.int32, sharding=ds),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=ds),
+            self._prefix_plane_avals(P),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=ds),
         )
 
     # ------------------------------------------------------------------
@@ -1204,19 +1162,10 @@ class InferenceEngine:
 
     def _get_score_exact(self, S: int, chunk: int):
         key = (1, S, 0, ("shadow", chunk))
-        with self._lock:
-            fn = self._compiled.get(key)
-        if fn is None:
-            t0 = time.perf_counter()
-            fn = self._build_score_exact(S, chunk)
-            self._record_compile(time.perf_counter() - t0)
-            with self._lock:
-                self._compiled.setdefault(key, fn)
-                fn = self._compiled[key]
-        return fn
+        return self._get_or_build(key, "score_exact", lambda: self._build_score_exact(S, chunk))
 
     def _build_score_exact(self, S: int, chunk: int):
-        """AOT-compile the teacher-forced scorer: left-padded chunked
+        """The teacher-forced scorer's program: left-padded chunked
         prefill over the full sequence, reducing each chunk's [1, C, V]
         logit plane on device to per-position (argmax, max logit, logit of
         the next delivered token) — the host fetches one [S, 3] array,
@@ -1267,15 +1216,11 @@ class InferenceEngine:
             return stats
 
         ds = self.mesh.replicated if self.mesh is not None else None
-        return (
-            jax.jit(score, out_shardings=ds)
-            .lower(
-                param_avals(self.params),
-                jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=ds),
-                jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=ds),
-                jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=ds),
-            )
-            .compile()
+        return jax.jit(score, out_shardings=ds), (
+            param_avals(self.params),
+            jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=ds),
+            jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=ds),
+            jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=ds),
         )
 
     def _make_gen_prefixed(self, S_suf: int, max_new: int):
@@ -1355,18 +1300,19 @@ class InferenceEngine:
 
     def _build_generate_prefixed(self, S_suf: int, max_new: int):
         ds = self.mesh.replicated if self.mesh is not None else None
-        return (
-            jax.jit(self._make_gen_prefixed(S_suf, max_new))
-            .lower(
-                param_avals(self.params),
-                self._prefix_plane_avals(self._prefix_capacity()),
-                jax.ShapeDtypeStruct((), jnp.int32, sharding=ds),
-                jax.ShapeDtypeStruct((1, S_suf), jnp.int32, sharding=ds),
-                jax.ShapeDtypeStruct((), jnp.int32, sharding=ds),
-                jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=ds),
-            )
-            .compile()
+        return jax.jit(self._make_gen_prefixed(S_suf, max_new)), (
+            param_avals(self.params),
+            self._prefix_plane_avals(self._prefix_capacity()),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=ds),
+            jax.ShapeDtypeStruct((1, S_suf), jnp.int32, sharding=ds),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=ds),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=ds),
         )
+
+    def _get_prefixed(self, S_suf: int, max_new: int):
+        key = (1, S_suf, max_new, ("prefix", self._prefix_capacity()))
+        return self._get_or_build(
+            key, "generate_prefixed", lambda: self._build_generate_prefixed(S_suf, max_new))
 
     def generate_prefixed(
         self,
@@ -1400,16 +1346,7 @@ class InferenceEngine:
             1, min(max_new, self.engine_config.max_seq_len
                    - max(self.engine_config.prompt_buckets))
         )
-        key = (1, S_suf, max_new, ("prefix", self._prefix_capacity()))
-        with self._lock:
-            fn = self._compiled.get(key)
-        if fn is None:
-            t0 = time.perf_counter()
-            fn = self._build_generate_prefixed(S_suf, max_new)
-            self._record_compile(time.perf_counter() - t0)
-            with self._lock:
-                self._compiled.setdefault(key, fn)
-                fn = self._compiled[key]
+        fn = self._get_prefixed(S_suf, max_new)
         with tracing.span("launch"):
             toks = np.full((1, S_suf), self.pad_id, np.int32)
             toks[0, : len(suffix_ids)] = list(suffix_ids)
@@ -1471,32 +1408,33 @@ class InferenceEngine:
             for n in (suffix_lens or (self.RAG_TAIL_BUCKET,))
         }
         for S_suf in sorted(buckets):
-            key = (1, S_suf, max_new, ("prefix", self._prefix_capacity()))
-            with self._lock:
-                built = key in self._compiled
-            if not built:
-                t0 = time.perf_counter()
-                fn = self._build_generate_prefixed(S_suf, max_new)
-                self._record_compile(time.perf_counter() - t0)
-                with self._lock:
-                    self._compiled.setdefault(key, fn)
+            self._get_prefixed(S_suf, max_new)
 
     def _get_compiled(
         self, B: int, S: int, max_new: int, chunk: Optional[int] = None
     ) -> jax.stages.Compiled:
-        key = (B, S, max_new, chunk)
+        if chunk == "spec":
+            return self._get_or_build(
+                (B, S, max_new, chunk), "generate_spec", lambda: self._build_generate_spec(S, max_new))
+        return self._get_or_build(
+            (B, S, max_new, chunk), "generate", lambda: self._build_generate(B, S, max_new, chunk))
+
+    def _get_or_build(self, key, program: str, build):
+        """The executable cached under ``key``, built ONCE however many threads
+        miss it together: ``build()`` gives ``(jitted, avals)``, and
+        ``tracing.build_span`` traces, lowers, compiles and counts it."""
         with self._lock:
             fn = self._compiled.get(key)
+            gate = fn is None and self._gates.setdefault(key, threading.Lock())
         if fn is None:
-            t0 = time.perf_counter()
-            if chunk == "spec":
-                fn = self._build_generate_spec(S, max_new)
-            else:
-                fn = self._build_generate(B, S, max_new, chunk)
-            self._record_compile(time.perf_counter() - t0)
-            with self._lock:
-                self._compiled.setdefault(key, fn)
-                fn = self._compiled[key]
+            with gate:  # one builder a key; a build that raises frees it for the next
+                with self._lock:
+                    fn = self._compiled.get(key)
+                if fn is None:
+                    fn = tracing.build_span(
+                        program, key, build, rows=key[0], bucket=key[1], max_new=key[2])
+                    with self._lock:
+                        self._compiled[key] = fn
         return fn
 
     _SPEC_EMA_DECAY = 0.7

@@ -43,6 +43,7 @@ from __future__ import annotations
 import contextvars
 import json
 import logging
+import os
 import threading
 import time
 import uuid
@@ -119,6 +120,136 @@ def count_kernel_build(mode: str, kernel: str) -> None:
 def kernel_builds() -> Dict[Tuple[str, str], int]:
     with _kernel_builds_lock:
         return dict(_kernel_builds)
+
+
+_IMPORTED_AT = time.time()
+
+
+def process_start_time() -> float:
+    """Epoch seconds at which this process started: its age by /proc (Linux),
+    elsewhere this module's import, which is later."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            ticks = float(f.read().rsplit(")", 1)[1].split()[19])  # starttime
+        with open("/proc/uptime", encoding="ascii") as f:
+            up = float(f.read().split()[0])
+        return time.time() - (up - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):
+        return _IMPORTED_AT
+
+
+# Every executable the program builds, by what it is for. One name a builder
+# (engine/engine.py ``_build_*``, server/app.py's fused embed+kNN, the
+# encoder's jit; the continuous engine's programs share one); ``undeclared``
+# is what the listener below files a build under that no site declared.
+BUILD_PROGRAMS = (
+    "generate", "generate_spec", "generate_rag", "generate_prefixed",
+    "segment_kv", "score_exact", "retrieve", "encode", "continuous",
+    "undeclared",
+)
+BUILD_STAGES = ("trace", "lower", "compile", "other")
+
+# The census of builds: seconds by (program, stage), executables by (program,
+# cache outcome: hit | miss (compiled AND written) | off: not asked, or too
+# quick to keep). Process-wide as ``_kernel_builds`` is, so the weights' jits
+# count before any service exists; ``/metrics`` serves it as
+# ``rag_compile_seconds_total{program, stage}`` and
+# ``rag_compile_events_total{program, cache}``.
+_compile_seconds: Dict[Tuple[str, str], float] = {}
+_compile_events: Dict[Tuple[str, str], int] = {}
+_census_lock = threading.Lock()
+# this thread's open build: ``stage`` is the stage ``build_span`` is in (None
+# outside a build), ``cache`` what the persistent cache last answered here
+_building = threading.local()
+
+
+def compile_census() -> Tuple[Dict[Tuple[str, str], float], Dict[Tuple[str, str], int]]:
+    """``(seconds by (program, stage), executables by (program, cache))``."""
+    with _census_lock:
+        return dict(_compile_seconds), dict(_compile_events)
+
+
+def _count_build(program: str, seconds: Dict[str, float], cache=False) -> None:
+    """Add ``seconds`` by stage, and one executable unless ``cache`` is False
+    (``hit`` | ``miss`` | None: the persistent cache said neither)."""
+    with _census_lock:
+        for stage, s in seconds.items():
+            _compile_seconds[(program, stage)] = _compile_seconds.get((program, stage), 0.0) + s
+        if cache is not False:
+            key = (program, cache or "off")
+            _compile_events[key] = _compile_events.get(key, 0) + 1
+
+
+def build_span(program: str, key, make, **ints):
+    """Build one executable where every site that builds one does: ``make()``
+    gives ``(jitted, avals)``, and ``jitted.trace(*avals).lower().compile()``
+    runs under a span ``build/<program>`` with each stage on the host's
+    monotonic clock. The span (on the current trace if there is one, and a
+    ``TraceAnnotation`` either way, so a build inside a capture sits on its
+    host timeline) carries ``trace_s``, ``lower_s``, ``compile_s``, ``other_s``
+    (its wall time less the three: ``make()``), ``cache_hit``
+    (1 | 0 | -1 where the persistent cache said neither) and ``ints`` (the
+    key's ``rows``, ``bucket``, ``max_new``); the census counts the same.
+    ``program`` is a name of ``BUILD_PROGRAMS``; any other raises."""
+    if program not in BUILD_PROGRAMS or program == "undeclared":
+        raise ValueError(f"build program {program!r} is not in the vocabulary {BUILD_PROGRAMS}")
+    outer = getattr(_building, "stage", None)
+    with span(f"build/{program}", **ints) as sp:
+        t = [time.monotonic()]
+        try:
+            staged, avals = make()
+            for stage in ("trace", "lower", "compile"):
+                t.append(time.monotonic())
+                _building.stage, _building.cache = stage, None
+                staged = staged.trace(*avals) if stage == "trace" else getattr(staged, stage)()
+            cache = _building.cache
+        finally:
+            _building.stage = outer
+        t.append(time.monotonic())
+        secs = {"trace": t[2] - t[1], "lower": t[3] - t[2], "compile": t[4] - t[3]}
+        secs["other"] = t[4] - t[0] - sum(secs.values())
+        _count_build(program, secs, cache)
+        if sp is not None:
+            sp.attrs.update({f"{k}_s": v for k, v in secs.items()})
+            sp.attrs["cache_hit"] = {"hit": 1.0, "miss": 0.0}.get(cache, -1.0)
+    logger.debug("build %s %r: %s cache=%s", program, key, secs, cache)
+    return staged
+
+
+_CACHE_ANSWERS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+def _on_cache_answer(event: str, **_) -> None:
+    answer = _CACHE_ANSWERS.get(event)
+    if answer is not None:  # the compile that asked ends on this thread
+        _building.cache = answer
+
+
+def _on_stage_seconds(event: str, seconds: float, fun_name: str = "", **_) -> None:
+    """A lowering or a backend compile (or cache read) that ``build_span`` is
+    not clocking on this thread is a lazy ``jax.jit`` nobody declared: the
+    census files it under ``undeclared``. Its ``fun_name`` goes to the log and
+    not into a label, so the families stay bounded. (``jaxpr_trace_duration``
+    events nest, an outer trace holding its inner jits', and are not summed.)"""
+    stage = _STAGE_EVENTS.get(event)
+    if stage is None or getattr(_building, "stage", None) == stage:
+        return
+    _count_build("undeclared", {stage: seconds},
+                 getattr(_building, "cache", None) if stage == "compile" else False)
+    _building.cache = None
+    logger.debug("undeclared %s of %s: %.3f s", stage, fun_name, seconds)
+
+
+if jax is not None:  # once a process, where the program is first imported
+    jax.monitoring.register_event_listener(_on_cache_answer)
+    jax.monitoring.register_event_duration_secs_listener(_on_stage_seconds)
 
 
 @dataclass

@@ -156,6 +156,10 @@ class RagService:
         self.metrics = obs_metrics.MetricsRegistry()
         self.traces = tracing.TraceBuffer(128)
         self.started_at = time.monotonic()
+        # warmup()'s own span tree, kept here (the ring of 128 would evict
+        # it) and served by GET /debug/traces under "boot"
+        self.boot_trace: Optional[Dict] = None
+        self._ready_seconds = 0.0
         # resilience layer (ISSUE 4): the readiness breaker over engine
         # resets, and the bounded admission gate in front of BOTH engine
         # modes — constructed before observability so the gauges can read
@@ -469,6 +473,29 @@ class RagService:
             "rag_attend_kernel_builds_total",
             "attention dispatches traced into compiled programs, by mode "
             "(prefill|decode|chunk) and the kernel chosen",
+        )
+        # every executable the process has built (obs/tracing.build_span and
+        # its listener): synced from the process-wide census the same way
+        self._m_compile_events = reg.labeled_counter(
+            "rag_compile_events_total",
+            "executables built, by program (obs/tracing.BUILD_PROGRAMS) and "
+            "what the persistent cache said (hit|miss|off: neither)",
+        )
+        self._m_compile_seconds = reg.labeled_counter(
+            "rag_compile_seconds_total",
+            "seconds building executables, by program and stage "
+            "(trace|lower|compile|other; compile is the backend's compile "
+            "or the persistent cache's read)",
+        )
+        self._m_ingest_stage = reg.labeled_histogram(
+            "rag_ingest_stage_seconds",
+            "one ingest's stages (extract|chunk|embed|index|warm)",
+            buckets=obs_metrics.REQUEST_BUCKETS,
+        )
+        reg.gauge(
+            "rag_ready_seconds",
+            "process start to ready (0 until warmup() has returned)",
+            fn=lambda: self._ready_seconds,
         )
         # present in every mode so dashboards stay uniform; only the
         # continuous engine's host loop can actually observe it (exact
@@ -997,11 +1024,24 @@ class RagService:
 
     def _sync_kernel_builds(self) -> None:
         """One callback child per (mode, kernel) the process has traced so
-        far; the label set is bounded by the kernels ``_attend`` can name."""
+        far, and per (program, stage) / (program, cache) it has built; the
+        label sets are bounded by the kernels ``_attend`` can name and by
+        ``tracing.BUILD_PROGRAMS``."""
         for mode, kernel in tracing.kernel_builds():
             self._m_kernel_builds.labels_callback(
                 lambda key=(mode, kernel): tracing.kernel_builds().get(key, 0),
                 mode=mode, kernel=kernel,
+            )
+        seconds, events = tracing.compile_census()
+        for program, stage in seconds:
+            self._m_compile_seconds.labels_callback(
+                lambda key=(program, stage): tracing.compile_census()[0].get(key, 0.0),
+                program=program, stage=stage,
+            )
+        for program, cache in events:
+            self._m_compile_events.labels_callback(
+                lambda key=(program, cache): tracing.compile_census()[1].get(key, 0),
+                program=program, cache=cache,
             )
 
     def _engine_stat(self, name: str) -> float:
@@ -1604,52 +1644,69 @@ class RagService:
         return self.encoder.encode(token_lists)
 
     # -- ingest ---------------------------------------------------------
+    @contextmanager
+    def _ingest_stage(self, stage: str):
+        """One stage of an ingest: a span on the upload's trace and a sample
+        of ``rag_ingest_stage_seconds{stage}``."""
+        t0 = time.monotonic()
+        with tracing.span(stage):
+            yield
+        self._m_ingest_stage.labels(stage=stage).observe(time.monotonic() - t0)
+
     def ingest_pdf_bytes(self, data: bytes, filename: str) -> int:
         """Extract → chunk → batch-embed → index. Returns chunk count."""
         t0 = time.monotonic()
-        text = extract_text(data)
-        chunks = split_text(
-            text, self.config.retrieval.chunk_size, self.config.retrieval.chunk_overlap
-        )
+        with self._ingest_stage("extract"):
+            text = extract_text(data)
+        with self._ingest_stage("chunk"):
+            chunks = split_text(
+                text, self.config.retrieval.chunk_size, self.config.retrieval.chunk_overlap
+            )
         if not chunks:
             return 0
-        vectors = self.embed_texts(chunks)
-        metadata = [
-            {"filename": filename, "chunk_id": i, "text": c} for i, c in enumerate(chunks)
-        ]
-        added = self.store.add(list(vectors), metadata)
-        if added and self.store.path:
-            self.store.save()
+        with self._ingest_stage("embed"):
+            vectors = self.embed_texts(chunks)
+        with self._ingest_stage("index"):
+            metadata = [
+                {"filename": filename, "chunk_id": i, "text": c} for i, c in enumerate(chunks)
+            ]
+            added = self.store.add(list(vectors), metadata)
+            if added and self.store.path:
+                self.store.save()
         if added and self.ready:
-            # pre-warm the fused retrieval executable, but ONLY when the
-            # index snapshot outgrew its padded bucket (a new executable is
-            # needed O(log N) times ever — bulk ingest must not pay a device
-            # call per document)
-            try:
-                cap = self.store.device_snapshot()[0].shape[0]
-                k_eff = min(self.config.retrieval.k, self.store.ntotal)
-                grew = not any(
-                    k[1] == cap and k[2] == k_eff for k in self._fused_retrieve
-                )
-                if grew:
-                    self._retrieve("warmup")
-                    if self.retrieve_coalescer is not None:
-                        self._retrieve_many(["warmup"] * self._retrieve_cap)
-                # single-fetch serving: sync the token sidecar EVERY ingest
-                # (an O(batch) splice — token_snapshot; a full rebuild only
-                # when the (cap, Lc) bucket outgrew) and get-or-build the
-                # assembly executables, so neither the sidecar rebuild nor
-                # an Lc-growth compile ever lands inside a user's query
-                self._warm_rag_executables(k_eff)
-                # KV prefix cache: compile this corpus's segment-KV builder
-                # bucket now, not inside the first query that misses
-                self._warm_prefix_segments()
-            except Exception:  # noqa: BLE001 — warmup must not fail ingest
-                logger.exception("post-ingest retrieval warmup failed")
+            with self._ingest_stage("warm"):
+                self._warm_after_ingest()
         self.metrics.observe("ingest_seconds", time.monotonic() - t0)
         self.metrics.inc("ingested_chunks", added)
         logger.info("ingested %s: %d chunks (%d new)", filename, len(chunks), added)
         return len(chunks)
+
+    def _warm_after_ingest(self) -> None:
+        """Pre-warm the fused retrieval executable, but ONLY when the index
+        snapshot outgrew its padded bucket (a new executable is needed
+        O(log N) times ever — bulk ingest must not pay a device call per
+        document), then what serves on top of it."""
+        try:
+            cap = self.store.device_snapshot()[0].shape[0]
+            k_eff = min(self.config.retrieval.k, self.store.ntotal)
+            grew = not any(
+                k[1] == cap and k[2] == k_eff for k in self._fused_retrieve
+            )
+            if grew:
+                self._retrieve("warmup")
+                if self.retrieve_coalescer is not None:
+                    self._retrieve_many(["warmup"] * self._retrieve_cap)
+            # single-fetch serving: sync the token sidecar EVERY ingest
+            # (an O(batch) splice — token_snapshot; a full rebuild only
+            # when the (cap, Lc) bucket outgrew) and get-or-build the
+            # assembly executables, so neither the sidecar rebuild nor
+            # an Lc-growth compile ever lands inside a user's query
+            self._warm_rag_executables(k_eff)
+            # KV prefix cache: compile this corpus's segment-KV builder
+            # bucket now, not inside the first query that misses
+            self._warm_prefix_segments()
+        except Exception:  # noqa: BLE001 — warmup must not fail ingest
+            logger.exception("post-ingest retrieval warmup failed")
 
     def ingest_directory(self, pdf_dir: Optional[str] = None) -> int:
         """Boot-time ingest parity (rag.py:88-112) — but idempotent."""
@@ -1746,15 +1803,17 @@ class RagService:
         kernel (survey §7 hard part (e)) and halves dispatch overhead."""
         return self._retrieve_many([text])[0]
 
-    def _fused_retrieve_fn(self, S: int, cap: int, k_eff: int, B_pad: int):
+    def _fused_retrieve_fn(self, S: int, k_eff: int, B_pad: int, emb, norms):
         """Get-or-build the compiled fused embed+kNN executable for one
-        (bucket, index capacity, k, padded batch) shape."""
+        (bucket, index capacity, k, padded batch) shape; ``emb`` / ``norms``
+        are the index snapshot it will be called with."""
         import jax
         import jax.numpy as jnp
 
+        from rag_llm_k8s_tpu.engine.engine import param_avals
         from rag_llm_k8s_tpu.ops.knn import knn_topk
 
-        key = (S, cap, k_eff, B_pad)
+        key = (S, emb.shape[0], k_eff, B_pad)
         fn = self._fused_retrieve.get(key)
         if fn is None:
             model = self.encoder.model
@@ -1769,8 +1828,12 @@ class RagService:
                 # up to 2^24 (16M vectors).
                 return jnp.concatenate([d, i.astype(jnp.float32)], axis=1)
 
-            fn = jax.jit(fused)
-            self._fused_retrieve[key] = fn
+            i32 = jax.ShapeDtypeStruct((B_pad, S), jnp.int32)
+            fn = self._fused_retrieve[key] = tracing.build_span(
+                "retrieve", key,
+                lambda: (jax.jit(fused), (param_avals(self.encoder.params), i32, i32,
+                                          *param_avals((emb, norms)))),
+                rows=B_pad, bucket=S)
         return fn
 
     def _retrieve_many(self, texts: List[str], allow_device: bool = False):
@@ -1809,7 +1872,7 @@ class RagService:
 
         if allow_device and len(texts) == 1 and self._fused_ok():
             tokens, mask, tok_ms = prepped[0]
-            fn = self._fused_retrieve_fn(tokens.shape[1], emb.shape[0], k_eff, 1)
+            fn = self._fused_retrieve_fn(tokens.shape[1], k_eff, 1, emb, norms)
             packed_dev = fn(
                 self.encoder.params, jnp.asarray(tokens), jnp.asarray(mask),
                 emb, norms,
@@ -1829,7 +1892,7 @@ class RagService:
                 for row, i in enumerate(group):
                     tokens[row], mask[row] = prepped[i][0][0], prepped[i][1][0]
 
-                fn = self._fused_retrieve_fn(S, emb.shape[0], k_eff, B_pad)
+                fn = self._fused_retrieve_fn(S, k_eff, B_pad, emb, norms)
                 packed = np.asarray(fn(
                     self.encoder.params, jnp.asarray(tokens), jnp.asarray(mask), emb, norms
                 ))  # ONE fetch
@@ -2740,6 +2803,20 @@ class RagService:
         buckets warm — RAG prompts with a full 3-chunk context land in the
         largest bucket, so warming only small buckets would leave the very
         first production query paying the big compile."""
+        # the way to ready is a span tree of its own: one span a stage below,
+        # the ``build`` spans under them (obs/tracing.build_span)
+        tr = tracing.start_trace()
+        tr.attrs["kind"] = "boot"
+        try:
+            self._warm_stages()
+        finally:  # a boot that failed keeps its tree too
+            tr.attrs["process_started_at"] = tracing.process_start_time()
+            tr.attrs["ready_at"] = time.time()
+            self.boot_trace = tracing.finish_trace(tr)
+        self._ready_seconds = tr.attrs["ready_at"] - tr.attrs["process_started_at"]
+        self.ready = True
+
+    def _warm_stages(self) -> None:
         # warm the engine that actually serves: the scheduler's (continuous
         # slots or coalescing wrapper around self.engine); self.engine alone
         # only when no scheduler exists
@@ -2754,9 +2831,10 @@ class RagService:
             (serving_engine.B,)
             if isinstance(serving_engine, ContinuousEngine) else (1,)
         )
-        serving_engine.warmup(
-            batch_sizes=warm_bs, buckets=serving_engine.engine_config.prompt_buckets
-        )
+        with tracing.span("warm_generate"):
+            serving_engine.warmup(
+                batch_sizes=warm_bs, buckets=serving_engine.engine_config.prompt_buckets
+            )
         from rag_llm_k8s_tpu.engine.batching import BatchScheduler
 
         if isinstance(self.scheduler, BatchScheduler):
@@ -2784,7 +2862,8 @@ class RagService:
                     warm_buckets = tuple(ec.prompt_buckets)
                 else:
                     warm_buckets = (max(ec.prompt_buckets),)
-                serving_engine.warmup(batch_sizes=tuple(sizes), buckets=warm_buckets)
+                with tracing.span("warm_ladder"):
+                    serving_engine.warmup(batch_sizes=tuple(sizes), buckets=warm_buckets)
         if serving_engine is not self.engine:
             # over-bucket prompts bypass the scheduler into the one-shot
             # engine's chunked prefill — warm one representative overflow
@@ -2793,7 +2872,8 @@ class RagService:
             largest = max(ec.prompt_buckets)
             mn = max(1, min(self.engine.sampling.max_new_tokens,
                             ec.max_seq_len - largest))
-            self.engine._get_compiled(1, 2 * largest, mn, largest)
+            with tracing.span("warm_overflow"):
+                self.engine._get_compiled(1, 2 * largest, mn, largest)
         if self.shadow is not None:
             # the auditor's exact scorer: one executable per padded length.
             # Same coverage rule as the batch ladder above — the largest
@@ -2801,35 +2881,38 @@ class RagService:
             # every bucket under warm_full_ladder — so sampled audits of
             # the traffic warmup prepares for never compile after ready
             ec = self.engine.engine_config
-            self.engine.warm_score_exact(
-                ec.prompt_buckets if ec.warm_full_ladder
-                else (max(ec.prompt_buckets),)
-            )
-        self.embed_texts(["warmup"])
-        # compile the fused embed+kNN executable and upload the index
-        # snapshot (no-op while the index is empty; ingest re-warms)
-        self._retrieve("warmup")
-        if self.retrieve_coalescer is not None and self.store.ntotal:
-            # one extra executable: the padded concurrent-retrieval batch
-            self._retrieve_many(["warmup"] * self._retrieve_cap)
+            with tracing.span("warm_score"):
+                self.engine.warm_score_exact(
+                    ec.prompt_buckets if ec.warm_full_ladder
+                    else (max(ec.prompt_buckets),)
+                )
+        with tracing.span("warm_retrieve"):
+            self.embed_texts(["warmup"])
+            # compile the fused embed+kNN executable and upload the index
+            # snapshot (no-op while the index is empty; ingest re-warms)
+            self._retrieve("warmup")
+            if self.retrieve_coalescer is not None and self.store.ntotal:
+                # one extra executable: the padded concurrent-retrieval batch
+                self._retrieve_many(["warmup"] * self._retrieve_cap)
         if self.store is not None and self.store.ntotal:
             # single-fetch serving: sidecar + generate_rag executables warm
             # here too — the first production solo query must not compile
-            self._warm_rag_executables(min(self.config.retrieval.k, self.store.ntotal))
+            with tracing.span("warm_rag"):
+                self._warm_rag_executables(min(self.config.retrieval.k, self.store.ntotal))
         if self._prefix_enabled():
             # KV prefix cache: compute + PIN the fixed head block (reused by
             # 100% of requests — it must never evict) and AOT-compile the
             # prefixed generate executables, so a cache hit never compiles
             # or prefills the head inside a user's request
             try:
-                head_key = f"head:{len(self._a_ids())}"
-                self.engine.prefix_cache.pin(head_key)
-                self.engine.prefix_cache.prefix_for([(head_key, self._a_ids())])
-                self.engine.warm_prefixed()
-                self._warm_prefix_segments()
+                with tracing.span("warm_prefix"):
+                    head_key = f"head:{len(self._a_ids())}"
+                    self.engine.prefix_cache.pin(head_key)
+                    self.engine.prefix_cache.prefix_for([(head_key, self._a_ids())])
+                    self.engine.warm_prefixed()
+                    self._warm_prefix_segments()
             except Exception:  # noqa: BLE001 — warmup must not fail boot
                 logger.exception("prefix-cache warmup failed")
-        self.ready = True
 
     def shutdown(self):
         """Stop the serving threads (coalescers/schedulers) and release the
@@ -2954,11 +3037,18 @@ class WsgiApp:
         if file.filename == "":
             return self._jsonify({"error": "No selected file"}, 400)
         if file and file.filename.endswith(".pdf"):
+            # traced into the ring as /generate is: the ingest's stages are
+            # the tree's top-level spans (service.ingest_pdf_bytes)
+            tr = tracing.start_trace()
+            tr.attrs["kind"] = "upload"
             try:
                 n = self.service.ingest_pdf_bytes(file.read(), file.filename)
             except Exception as e:  # noqa: BLE001 — parity: any failure → JSON error
                 logger.exception("upload_pdf failed")
+                tr.attrs["error"] = True
                 return self._jsonify({"error": str(e)}, 500)
+            finally:
+                tracing.finish_trace(tr, self.service.traces)
             return self._jsonify(
                 {"message": f"PDF processed and indexed successfully. {n} chunks created."}
             )
@@ -3213,13 +3303,15 @@ class WsgiApp:
             return self._jsonify({"error": str(e)}, 500)
 
     def ep_debug_traces(self, request):
-        """Recent request span trees from the in-memory ring buffer.
+        """Recent request span trees from the in-memory ring buffer, and
+        under ``boot`` the tree of ``warmup()`` (None until it has returned).
         Same 403-unless-armed contract as every ``/debug`` route."""
         if not self._debug_enabled():
             return self._debug_forbidden()
         try:
             limit = request.args.get("limit", type=int)
-            return self._jsonify({"traces": self.service.traces.list(limit)})
+            return self._jsonify({"traces": self.service.traces.list(limit),
+                                  "boot": self.service.boot_trace})
         except Exception as e:  # noqa: BLE001
             return self._jsonify({"error": str(e)}, 500)
 

@@ -31,6 +31,7 @@ from rag_llm_k8s_tpu.engine.engine import InferenceEngine
 from rag_llm_k8s_tpu.index.store import VectorStore
 from rag_llm_k8s_tpu.models.bge_m3 import init_encoder_params
 from rag_llm_k8s_tpu.models.llama import init_llama_params
+from rag_llm_k8s_tpu.obs import tracing
 from rag_llm_k8s_tpu.server.app import RagService
 
 FP32 = DTypePolicy.fp32()
@@ -193,6 +194,22 @@ class TestRagCompileOnce:
         assert not any(t.is_alive() for t in threads)
         return results, errors
 
+    class _Staged:
+        """Stands for a jitted function and every stage after it: what
+        ``tracing.build_span`` walks (``trace`` -> ``lower`` -> ``compile``)."""
+
+        def __init__(self, executable):
+            self.executable = executable
+
+        def trace(self, *avals):
+            return self
+
+        def lower(self):
+            return self
+
+        def compile(self):
+            return self.executable
+
     def test_concurrent_misses_build_each_key_once(self, monkeypatch):
         _, engine = make_engine(speculative="auto")  # two variants a key
         built = []
@@ -200,15 +217,16 @@ class TestRagCompileOnce:
         def slow_build(S, max_new, cap, Lc, LA, LB, n, kk, v):
             built.append(v)
             time.sleep(0.05)  # long enough for every other thread to miss
-            return ("executable", v, len(built))
+            return self._Staged(("executable", v, len(built))), ()
 
         monkeypatch.setattr(engine, "_build_generate_rag", slow_build)
-        compiles = []
-        monkeypatch.setattr(engine, "_record_compile", compiles.append)
+        counted = sum(n for (prog, _), n in tracing.compile_census()[1].items()
+                      if prog == "generate_rag")
         results, errors = self._racing(engine, n_threads=16)
         assert not errors
         assert sorted(built) == [False, True]  # one build a variant, not 16
-        assert len(compiles) == 2
+        assert sum(n for (prog, _), n in tracing.compile_census()[1].items()
+                   if prog == "generate_rag") == counted + 2
         assert len(results) == 16 and len(set(results)) == 1 and results[0][1] is False
         # and a later hit builds nothing
         assert engine._get_rag_compiled(**self.KEY) == results[0] and len(built) == 2
@@ -222,7 +240,7 @@ class TestRagCompileOnce:
             time.sleep(0.05)
             if len(calls) == 1:
                 raise RuntimeError("compile failed")
-            return ("executable", len(calls))
+            return self._Staged(("executable", len(calls))), ()
 
         monkeypatch.setattr(engine, "_build_generate_rag", flaky_build)
         results, errors = self._racing(engine, n_threads=8)

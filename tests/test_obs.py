@@ -2,10 +2,12 @@
 strict Prometheus exposition checking, span-tree tracing through a real
 ``/generate``, and JSON-snapshot ↔ exposition equivalence."""
 
+import collections
 import re
 import time
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from rag_llm_k8s_tpu.core.config import (
@@ -16,6 +18,7 @@ from rag_llm_k8s_tpu.core.config import (
     LlamaConfig,
     SamplingConfig,
 )
+from rag_llm_k8s_tpu.engine.batching import BatchScheduler
 from rag_llm_k8s_tpu.engine.encoder import EncoderRunner
 from rag_llm_k8s_tpu.engine.engine import InferenceEngine
 from rag_llm_k8s_tpu.index.store import VectorStore
@@ -163,6 +166,185 @@ class TestTracingUnit:
 
 
 # ---------------------------------------------------------------------------
+# builds: one helper, one census (obs/tracing.build_span and its listener)
+# ---------------------------------------------------------------------------
+
+
+def _census_delta(before):
+    """What the process-wide census gained since ``before``: (seconds, events)."""
+    seconds, events = tracing.compile_census()
+    return ({k: v - before[0].get(k, 0.0) for k, v in seconds.items() if v != before[0].get(k, 0.0)},
+            {k: n - before[1].get(k, 0) for k, n in events.items() if n != before[1].get(k, 0)})
+
+
+def _toy_program(scale):
+    """``make`` of a build: a fresh function every call, so no build finds the
+    last one's lowering in JAX's in-memory caches."""
+    aval = jax.ShapeDtypeStruct((16, 16), jnp.float32)
+    return lambda: (jax.jit(lambda x: jnp.sin(x) @ x * scale), (aval,))
+
+
+class _BackendCompiles:
+    """A listener of the test's own: every ``backend_compile_duration`` event
+    the process sees (what ``benchmark/lib/serve.py CompileCounter`` counts)."""
+
+    def __init__(self):
+        self.n = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, seconds, **_):
+        self.n += event == "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.fixture(scope="module")
+def backend_compiles():
+    return _BackendCompiles()
+
+
+class TestBuildSpan:
+    def test_one_span_whose_stages_sum_to_it_and_each_series_ticks_once(self):
+        before = tracing.compile_census()
+        tr = tracing.start_trace()
+        fn = tracing.build_span("generate", (2, 32, 6, None), _toy_program(2.0),
+                                rows=2, bucket=32, max_new=6)
+        tracing.finish_trace(tr)
+        seconds, events = _census_delta(before)
+        assert fn(jnp.ones((16, 16))).shape == (16, 16)  # an executable came back
+        (sp,) = [s for s in tr.spans if s.name.startswith("build")]
+        assert sp.name == "build/generate"
+        stages = [sp.attrs[f"{k}_s"] for k in tracing.BUILD_STAGES]
+        assert all(v >= 0 for v in stages) and sp.attrs["compile_s"] > 0
+        assert sum(stages) == pytest.approx(sp.duration_ms() / 1e3, abs=1e-3)
+        assert (sp.attrs["rows"], sp.attrs["bucket"], sp.attrs["max_new"]) == (2.0, 32.0, 6.0)
+        assert sp.attrs["cache_hit"] in (1.0, 0.0, -1.0)
+        assert set(seconds) == {("generate", k) for k in tracing.BUILD_STAGES}
+        for k in tracing.BUILD_STAGES:
+            assert seconds[("generate", k)] == pytest.approx(sp.attrs[f"{k}_s"], abs=1e-9)
+        assert sum(events.values()) == 1 and {p for p, _ in events} == {"generate"}
+
+    def test_persistent_cache_reads_miss_then_hit(self, tmp_path):
+        from jax._src import compilation_cache
+
+        was = {k: getattr(jax.config, k) for k in (
+            "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")}
+        try:
+            jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+            compilation_cache.reset_cache()
+            outcomes = []
+            for _ in range(2):  # the same program twice, a fresh function each
+                before = tracing.compile_census()
+                tr = tracing.start_trace()
+                tracing.build_span("score_exact", (1, 16, 0), _toy_program(3.0))
+                tracing.finish_trace(tr)
+                _, events = _census_delta(before)
+                ((_, cache),) = events
+                outcomes.append((cache, tr.spans[0].attrs["cache_hit"]))
+            assert outcomes == [("miss", 0.0), ("hit", 1.0)]
+        finally:
+            for k, v in was.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+
+    def test_unknown_program_raises(self):
+        for name in ("generate_fast", "undeclared", ""):
+            with pytest.raises(ValueError, match="vocabulary"):
+                tracing.build_span(name, (1,), _toy_program(1.0))
+
+    def test_lazy_jit_first_call_lands_under_undeclared(self, backend_compiles):
+        before, n0 = tracing.compile_census(), backend_compiles.n
+        jax.jit(lambda x: jnp.cos(x) * 5.0 + 7.0)(jnp.ones((3, 5))).block_until_ready()
+        seconds, events = _census_delta(before)
+        assert {p for p, _ in events} == {"undeclared"}
+        assert sum(events.values()) == backend_compiles.n - n0 >= 1
+        assert seconds[("undeclared", "compile")] > 0 and seconds[("undeclared", "lower")] > 0
+
+
+@pytest.fixture(scope="module")
+def warmed(backend_compiles):
+    """A tiny fused-RAG service taken to ready by ``warmup()`` itself, with
+    what the census and the test's own listener gained across it."""
+    llama_cfg = LlamaConfig.tiny(vocab_size=300)
+    enc_cfg = EncoderConfig.tiny(vocab_size=300)
+    cfg = AppConfig(model=llama_cfg, encoder=enc_cfg, system_message="SYS")
+    engine = InferenceEngine(
+        llama_cfg,
+        init_llama_params(jax.random.PRNGKey(0), llama_cfg, FP32),
+        sampling=SamplingConfig(do_sample=False, max_new_tokens=4),
+        engine_config=EngineConfig(prompt_buckets=(128, 256), max_batch_size=4,
+                                   rag_fused=True),
+        dtypes=FP32,
+    )
+    encoder = EncoderRunner(
+        enc_cfg, init_encoder_params(jax.random.PRNGKey(1), enc_cfg, FP32),
+        dtypes=FP32, length_buckets=(32,), max_batch=4,
+    )
+    store = VectorStore(dim=enc_cfg.hidden_size)
+    svc = RagService(cfg, engine, ByteTokenizer(), encoder, ByteTokenizer(), store,
+                     scheduler=BatchScheduler(engine, max_wait_ms=25.0))
+    texts = ["alpha beta gamma", "delta epsilon", "zeta eta theta"]
+    vecs = encoder.encode([ByteTokenizer().encode(t) for t in texts])
+    store.add(list(vecs), [{"filename": "f", "chunk_id": i, "text": t}
+                           for i, t in enumerate(texts)])
+    before, n0 = tracing.compile_census(), backend_compiles.n
+    svc.warmup()
+    gained = _census_delta(before)
+    yield svc, gained, backend_compiles.n - n0
+    svc.shutdown()
+
+
+def _spans(tree):
+    """Every span of a served tree, depth first."""
+    for sp in tree.get("spans", []):
+        yield sp
+        yield from _spans(sp)
+
+
+class TestBootTree:
+    def test_census_counts_every_executable_the_backend_built(self, warmed):
+        _, (_, events), backend_built = warmed
+        assert sum(events.values()) == backend_built > 0
+
+    def test_every_engine_key_has_one_build_span_under_a_stage(self, warmed, monkeypatch):
+        svc, (_, events), _ = warmed
+        assert svc.ready
+        monkeypatch.setenv("TPU_RAG_FAULTS", "1")
+        boot = create_app(svc).test_client().get("/debug/traces").get_json()["boot"]
+        assert boot["attrs"]["kind"] == "boot"
+        assert boot["attrs"]["process_started_at"] < boot["started_at"] < boot["attrs"]["ready_at"]
+        stages = [s["name"] for s in boot["spans"]]
+        assert stages == ["warm_generate", "warm_ladder", "warm_score", "warm_retrieve", "warm_rag"]
+        builds = [s for s in _spans(boot) if s["name"].startswith("build/")]
+        engine_programs = {"generate", "generate_spec", "generate_rag", "score_exact"}
+        by_key = collections.Counter(
+            (int(s["attrs"]["rows"]), int(s["attrs"]["bucket"]), int(s["attrs"]["max_new"]))
+            for s in builds if s["name"].split("/")[1] in engine_programs)
+        assert by_key == collections.Counter(k[:3] for k in svc.engine._compiled)
+        # and the declared programs' census is the tree's build spans
+        declared = collections.Counter()
+        for (program, _), n in events.items():
+            if program != "undeclared":
+                declared[program] += n
+        assert declared == collections.Counter(s["name"].split("/")[1] for s in builds)
+        assert declared["retrieve"] == 2 and declared["encode"] >= 1
+
+    def test_ready_seconds_and_labeled_families_in_the_scrape(self, warmed):
+        svc, _, _ = warmed
+        text = create_app(svc).test_client().get("/metrics").get_data(as_text=True)
+        samples = _parse_samples(text)
+        assert samples[("rag_ready_seconds", "")] > 0
+        assert samples[("rag_ready_seconds", "")] == pytest.approx(
+            svc.boot_trace["attrs"]["ready_at"] - svc.boot_trace["attrs"]["process_started_at"])
+        labels = {lab for (n, lab) in samples if n == "rag_compile_seconds_total"}
+        assert '{program="generate",stage="trace"}' in labels
+        assert all("program=" in lab and "stage=" in lab for lab in labels)
+        events = {lab for (n, lab) in samples if n == "rag_compile_events_total"}
+        assert all("program=" in lab and "cache=" in lab for lab in events)
+
+
+# ---------------------------------------------------------------------------
 # HTTP-level: exposition, traces, healthz (one tiny service for the module)
 # ---------------------------------------------------------------------------
 
@@ -252,7 +434,8 @@ class TestExposition:
         # the query actually landed in the request histogram and compile
         # time was attributed
         assert samples[("rag_request_duration_seconds_count", "")] >= 1
-        assert samples[("rag_compile_seconds_total", "")] > 0
+        assert sum(v for (n, _), v in samples.items()
+                   if n == "rag_compile_seconds_total") > 0
         # every serving stage observed — including assemble/detokenize,
         # which have no timings key and observe at their span sites
         for stage in ("retrieve", "assemble", "generate", "detokenize"):
@@ -448,5 +631,6 @@ class TestOneShotEngineInstrumentation:
         assert gen.count >= 1  # the fixture's query went through generate
         itl = reg.labeled_histogram("rag_decode_inter_token_seconds")
         assert itl.labels(mode="oneshot_est").count >= 1
-        events = reg.counter("rag_compile_events_total")
-        assert events.value >= 1
+        svc._sync_kernel_builds()  # what a scrape does: the census' children
+        events = reg.labeled_counter("rag_compile_events_total")
+        assert sum(child.value for _, child in events.items()) >= 1

@@ -111,22 +111,29 @@ def _knn_program(_):
         jax.ShapeDtypeStruct((1, 512), f32), k=3).compile()
 
 
+def _compiled(staged):
+    """A builder's ``(jitted, avals)`` through the stages ``build_span`` runs."""
+    jitted, avals = staged
+    return jitted.trace(*avals).lower().compile()
+
+
 ENGINE_PROGRAMS = {
-    "generate": lambda e: e._build_generate(2, 32, 6),
-    "generate_chunked": lambda e: e._build_generate(1, 128, 6, chunk=64),
-    "generate_spec": lambda e: e._build_generate_spec(32, 6),
-    "generate_rag": lambda e: e._build_generate_rag(64, 6, 16, 24, 8, 16, 2, 3, False),
-    "generate_rag_spec": lambda e: e._build_generate_rag(64, 6, 16, 24, 8, 16, 2, 3, True),
-    "generate_prefixed": lambda e: e._build_generate_prefixed(16, 6),
-    "segment_kv": lambda e: e._build_segment_kv(16),
-    "score_exact": lambda e: e._build_score_exact(64, 32),
+    "generate": lambda e: _compiled(e._build_generate(2, 32, 6)),
+    "generate_chunked": lambda e: _compiled(e._build_generate(1, 128, 6, chunk=64)),
+    "generate_spec": lambda e: _compiled(e._build_generate_spec(32, 6)),
+    "generate_rag": lambda e: _compiled(e._build_generate_rag(64, 6, 16, 24, 8, 16, 2, 3, False)),
+    "generate_rag_spec": lambda e: _compiled(
+        e._build_generate_rag(64, 6, 16, 24, 8, 16, 2, 3, True)),
+    "generate_prefixed": lambda e: _compiled(e._build_generate_prefixed(16, 6)),
+    "segment_kv": lambda e: _compiled(e._build_segment_kv(16)),
+    "score_exact": lambda e: _compiled(e._build_score_exact(64, 32)),
 }
 CONTINUOUS_PROGRAMS = {
-    "continuous_prefill": lambda c: c._build_prefill_paged(32),
-    "continuous_insert": lambda c: c._build_insert_paged(32),
-    "continuous_step": lambda c: c._build_step_paged(1),
-    "continuous_verify": lambda c: c._build_verify_paged(3),
-    "continuous_mixed": lambda c: c._build_mixed_step(16),
+    "continuous_prefill": lambda c: _compiled(c._build_prefill_paged(32)),
+    "continuous_insert": lambda c: _compiled(c._build_insert_paged(32)),
+    "continuous_step": lambda c: _compiled(c._build_step_paged(1)),
+    "continuous_verify": lambda c: _compiled(c._build_verify_paged(3)),
+    "continuous_mixed": lambda c: _compiled(c._build_mixed_step(16)),
 }
 RETRIEVE_PROGRAMS = {"encoder": _encoder_program, "knn": _knn_program}
 # the phase each program's operations must be filed under (any of them)

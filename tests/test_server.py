@@ -204,6 +204,53 @@ class TestRoutes:
         assert body["generated_text"] == "No relevant information found in the index."
 
 
+class TestSetUpTrees:
+    """Boot and ingest as span trees (``GET /debug/traces``) and as the
+    ``rag_ingest_stage_seconds`` / ``rag_ready_seconds`` families."""
+
+    STAGES = ["extract", "chunk", "embed", "index", "warm"]
+
+    def test_upload_stage_spans_cover_the_request(self, client, monkeypatch):
+        monkeypatch.setenv("TPU_RAG_FAULTS", "1")  # arms the /debug surface
+        pdf = make_pdf("span trees of an ingest name extract chunk embed index and warm " * 6)
+        r = client.post("/upload_pdf", data={"file": (io.BytesIO(pdf), "stages.pdf")},
+                        content_type="multipart/form-data")
+        assert r.status_code == 200, r.get_json()
+        tree = client.get("/debug/traces?limit=1").get_json()["traces"][0]
+        assert tree["attrs"]["kind"] == "upload"
+        assert [s["name"] for s in tree["spans"]] == self.STAGES
+        covered = sum(s["duration_ms"] for s in tree["spans"])
+        assert covered == pytest.approx(tree["total_ms"], rel=0.05)
+        # the post-ingest builds fall under ``warm`` (and the encoder's under ``embed``)
+        warm = tree["spans"][-1]
+        assert any(s["name"] == "build/retrieve" for s in warm.get("spans", []))
+        # and the same stages in the scrape, one sample an upload so far
+        text = client.get("/metrics").get_data(as_text=True)
+        for stage in self.STAGES:
+            count = [ln for ln in text.splitlines()
+                     if ln.startswith(f'rag_ingest_stage_seconds_count{{stage="{stage}"}}')]
+            assert count and float(count[0].rsplit(" ", 1)[1]) >= 1, stage
+
+    def test_boot_tree_is_kept_and_served(self, client, monkeypatch):
+        monkeypatch.setenv("TPU_RAG_FAULTS", "1")
+        service = client.application.service
+        if service.boot_trace is None:
+            assert client.get("/debug/traces").get_json()["boot"] is None
+            assert "rag_ready_seconds 0" in client.get("/metrics").get_data(as_text=True)
+            service.warmup()
+        for _ in range(3):  # requests roll the ring; the boot tree is not in it
+            client.post("/generate", json={"prompt": "roll the ring"})
+        body = client.get("/debug/traces?limit=1").get_json()
+        assert len(body["traces"]) == 1 and body["traces"][0].get("attrs", {}).get("kind") != "boot"
+        boot = body["boot"]
+        assert boot["attrs"]["kind"] == "boot"
+        assert [s["name"] for s in boot["spans"]][0] == "warm_generate"
+        assert "warm_retrieve" in [s["name"] for s in boot["spans"]]
+        ready = [ln for ln in client.get("/metrics").get_data(as_text=True).splitlines()
+                 if ln.startswith("rag_ready_seconds ")]
+        assert float(ready[0].split()[1]) > 0
+
+
 class TestEmbedTruncation:
     def test_truncation_preserves_eos(self):
         """Over-limit encoder inputs keep their trailing EOS (the bge-m3 CLS
