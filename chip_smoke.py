@@ -167,6 +167,18 @@ SERVING_COMBINE = {
 }
 
 
+# the router at the three sparse-expert cells' prefill shapes (batch 8 of a
+# 4096 bucket): name -> (tokens, the router's outputs, route's rule)
+SERVING_ROUTE = {
+    "route[32768,256] top-8 of 4 of 8 groups": (32768, 256, dict(
+        top_k=8, n_group=8, topk_group=4, scaling=2.5, normalize=True, scoring="sigmoid")),
+    "route[32768,768] top-12, softmax": (32768, 768, dict(
+        top_k=12, n_group=1, topk_group=1, scaling=6.0, normalize=False, scoring="softmax")),
+    "route[32768,256] top-10": (32768, 256, dict(
+        top_k=10, n_group=1, topk_group=1, scaling=2.5, normalize=True, scoring="sigmoid")),
+}
+
+
 def _block_tables(kv_len, bs: int, mb: int):
     """Distinct physical blocks (0 stays the null block) for each row's
     logical blocks, allocated interleaved so neighbours are not adjacent."""
@@ -462,6 +474,17 @@ def phase_kernels(seed: int) -> None:
         max_abs_err=err, bound=bound)
 
 
+def best_us_a_call(many, calls: int, *args) -> float:
+    """Microseconds a call of a program that chains ``calls`` of them: four
+    runs, the first (it compiles) left out, the best of the other three."""
+    times = []
+    for _ in range(4):
+        t0 = time.perf_counter()
+        many(*args).block_until_ready()
+        times.append(time.perf_counter() - t0)
+    return round(min(times[1:]) / calls * 1e6, 1)
+
+
 def phase_combine(seed: int, cases=None, interpret: bool = False) -> None:
     """``ops/moe.py expert_combine`` against the XLA scatter-add it replaced,
     on a pass as the experts leave it (rows run by run, an expert each, tokens
@@ -481,12 +504,7 @@ def phase_combine(seed: int, cases=None, interpret: bool = False) -> None:
 
     def us_a_call(fn, acc, *rest):
         many = jax.jit(lambda a, *r: jax.lax.fori_loop(0, 8, lambda i, x: fn(x, *r), a))
-        times = []
-        for _ in range(4):
-            t0 = time.perf_counter()
-            many(acc, *rest).block_until_ready()
-            times.append(time.perf_counter() - t0)
-        return round(min(times[1:]) / 8 * 1e6, 1)
+        return best_us_a_call(many, 8, acc, *rest)
 
     for name, (N, C, D, load) in (cases or SERVING_COMBINE).items():
         rng = np.random.default_rng(seed)
@@ -514,6 +532,72 @@ def phase_combine(seed: int, cases=None, interpret: bool = False) -> None:
             max_abs_err=float(np.max(np.abs(got - want))),
             us_a_call=us_a_call(lambda a, *r: combine(a, *r)[0], acc, y, weight, token, group),
             xla_scatter_add_us_a_call=us_a_call(scatter, acc, y, weight, token, tail))
+
+
+def route_us_a_call(fn, logits, bias, calls: int = 8) -> float:
+    """Microseconds a call of ``fn(logits, bias) -> (experts, weights)``,
+    ``calls`` of them chained in one program, the best of three. Each call's
+    logits hang on the last call's weights (by a branch never taken: no pass
+    over them is added), so no call is hoisted out of the loop or dropped."""
+    import jax
+    import jax.numpy as jnp
+
+    def chain(i, x):
+        return jax.lax.cond(jnp.sum(fn(x, bias)[1]) > 1e30, lambda x: x + 1.0, lambda x: x, x)
+
+    return best_us_a_call(jax.jit(lambda x: jax.lax.fori_loop(0, calls, chain, x)), calls, logits)
+
+
+def phase_route(seed: int, cases=None, interpret: bool = False) -> None:
+    """``ops/moe.py route`` under its kernel (``route_topk``) against its jnp
+    body (``lax.top_k`` and a gather), on random logits with a block of rows
+    all tied, a block of rounded logits (ties inside and across the cut) and,
+    a second time, a correction bias that leaves two finite scores a group of
+    32 (the ``-inf`` tail is chosen in the order of its index); and
+    microseconds a call of both. The kernel computes the scores itself, so a
+    score may differ from XLA's in its last place and with it a choice
+    between two experts that near: a row may differ from the oracle's only
+    where the oracle's own ``s + bias`` at the two choices agree to 1e-6."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from rag_llm_k8s_tpu.ops import moe
+
+    def choice_at(logits, bias, experts, scoring):
+        s = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
+        return np.asarray(jnp.take_along_axis(s + bias[None, :], experts, axis=1))
+
+    impl = "pallas_interpret" if interpret else "pallas"
+    for name, (N, E, rule) in (cases or SERVING_ROUTE).items():
+        key = jax.random.PRNGKey(seed)
+        logits = 2.0 * jax.random.normal(key, (N, E), jnp.float32)
+        logits = logits.at[:128].set(0.25).at[128:2 * 128].set(jnp.round(logits[128:2 * 128]))
+        std = 1.0 / E if rule["scoring"] == "softmax" else 0.05  # the families' own draws
+        bias = std * jax.random.normal(jax.random.fold_in(key, 1), (E,), jnp.float32)
+        check(moe.route_blocks(N, E, rule["n_group"]) is not None,
+              f"{name}: the rule sends a served prefill shape to the jnp body")
+        kernel = jax.jit(functools.partial(moe.route, **rule, impl=impl))
+        body = jax.jit(functools.partial(moe.route, **rule, impl="xla"))
+        report = {}
+        for what, b in (("served bias", bias),
+                        ("two finite scores a group of 32", jnp.where(jnp.arange(E) % 32 >= 2, -jnp.inf, bias))):
+            got, want = kernel(logits, b), body(logits, b)
+            same = np.all(np.asarray(got[0]) == np.asarray(want[0]), axis=1)
+            check(same.mean() >= 0.999, f"{name}, {what}: {int((~same).sum())} rows chose otherwise")
+            if not same.all():  # only between choices the oracle itself cannot tell apart
+                mine, its = (choice_at(logits, b, e[~same], rule["scoring"]) for e in (got[0], want[0]))
+                near = np.isclose(mine, its, rtol=1e-6, atol=0.0) | (mine == its)
+                check(near.all(), f"{name}, {what}: a row differs from the oracle's by more than a near tie")
+            np.testing.assert_allclose(np.asarray(got[1])[same], np.asarray(want[1])[same], rtol=1e-5,
+                                       err_msg=f"{name}, {what}")
+            check(np.isfinite(np.asarray(got[1])).all(), f"{name}, {what}: non-finite weights")
+            report[what] = {"rows_equal": int(same.sum()), "weights_bit_equal": bool(
+                np.array_equal(np.asarray(got[1]), np.asarray(want[1])))}
+        say("kernel", name=name, cols=moe.route_blocks(N, E, rule["n_group"]), **{
+            k.replace(" ", "_"): v for k, v in report.items()},
+            us_a_call=route_us_a_call(kernel, logits, bias),
+            jnp_body_us_a_call=route_us_a_call(body, logits, bias))
 
 
 # ---------------------------------------------------------------------------
@@ -831,6 +915,7 @@ def run_one_chip(args, counter, errors) -> None:
     say("kernels", event="start")
     phase_kernels(args.seed)
     phase_combine(args.seed)
+    phase_route(args.seed)
     gc.collect()
 
     tokenizers = load_tokenizers()

@@ -5,7 +5,12 @@ assignments it holds.
 ``route`` scores ALL of the router's outputs (sigmoid, or softmax over the
 routed experts and the zero-computation ones after them), chooses by score
 plus a correction bias (group-limited where there are groups), and weighs by
-the score itself. ``zero_expert_term`` is what the chosen zero-computation
+the score itself. From a prefill's size on all of it after the router's dot is
+ONE kernel over token tiles (``route_topk``: scores, the groups' mask,
+``top_k`` rounds of largest-and-mask that take the score in the same round,
+the normalising sum and the scaling; no sort of a row and no gather); a
+decode step's few tokens keep the ``lax.top_k`` form, which is also the
+``xla`` side and the oracle. ``zero_expert_term`` is what the chosen zero-computation
 experts (identity) add, whole on every chip. ``held_expert_ffn`` computes
 ``sum_i w_i * E_i(x)`` over the selected experts
 THIS chip holds (a contiguous range of the published experts): assignments
@@ -37,6 +42,183 @@ PASS_HEADROOM = 2
 ROW_ALIGN = 128  # the grouped kernel's row tile; a buffer is a multiple of it
 
 
+LANES = 128  # tokens a column of the router's kernel: one lane each
+# tokens from which ``route`` runs its kernel: the sweep behind it is in
+# PERF.md section 6, PR 37
+ROUTE_KERNEL_TOKENS = 1024
+_TAKEN = -(2**31)  # under every key ``_order_key`` makes of a float that is not a NaN
+
+
+def route_blocks(N: int, E: int, n_group: int) -> Optional[int]:
+    """Whether ``route_topk`` routes ``N`` tokens over ``E`` outputs, from the
+    shape alone (no option, no model's name): None for the ``lax.top_k`` form,
+    else the columns of ``LANES`` tokens a grid step takes.
+
+    The kernel from ``ROUTE_KERNEL_TOKENS`` tokens on: every prefill of a
+    bucket and the scorer's lengths. It wins from one column on (128 tokens:
+    4.2-7.7 us against 21-94, PR 37's sweep on a v5e), so the bound is
+    set-up's price and not speed's: a decode step (8 tokens, 8-13 us of
+    ``lax.top_k``), a verify chunk (16 a row) and a 512-token prefill chunk
+    keep the jnp body, and their programs do not trace and lower one more
+    kernel. Outputs fill whole 128-lane tiles (the kernel transposes them), a
+    group whole 8-sublane rows, the groups one such row: every served width
+    does (256 outputs in 8 groups of 32; 768 and 256 in one). Tokens that fill
+    no whole column are padded to one (no served shape: a bucket is whole
+    columns). A step takes up to 1 MiB of logits (8 columns at 256 outputs, 2
+    at 768, a ``[E, 128]`` float32 tile beside its keys in VMEM): 1 to 16
+    columns a step read alike, within 4% (the same sweep)."""
+    if N < ROUTE_KERNEL_TOKENS or E % LANES:
+        return None
+    if n_group > 1 and (n_group > 8 or E % n_group or (E // n_group) % 8):
+        return None
+    return _fit_block(-(-N // LANES), max(1, 2048 // E))
+
+
+def _order_key(x):
+    """float32 -> int32 that orders as the floats do (``-0.0`` made ``0.0``
+    first), so that ``_TAKEN`` lies under ``-inf``'s key: what a round took
+    can be told from what was ``-inf`` to begin with."""
+    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.int32)
+    return jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def _largest(key, place, size: int):
+    """``[rows, LANES]`` keys -> ``(place [1, LANES] of a lane's largest key,
+    the lowest among equals; the mask [rows, LANES] of that one row)``."""
+    best = jnp.max(key, axis=0, keepdims=True)
+    at = jnp.min(jnp.where(key == best, place, size), axis=0, keepdims=True)
+    return at, place == at
+
+
+def _route_kernel(
+    logits_ref,  # VMEM [cols, LANES, E] float32: a token a row
+    bias_ref,  # VMEM [E, 1] float32
+    experts_ref,  # VMEM [cols, top_k, LANES] int32
+    weights_ref,  # VMEM [cols, top_k, LANES] float32
+    key_scr,  # VMEM [E, LANES] int32: a column's choice keys, an expert a sublane row
+    s_scr,  # VMEM [E, LANES] float32: its scores, the same way
+    *,
+    n_group: int,
+    topk_group: int,
+    scaling: float,
+    normalize: bool,
+    scoring: str,
+):
+    """One grid step: ``cols`` columns of ``LANES`` tokens, one after another.
+    A column's logits are transposed so that experts lie along the sublanes
+    and tokens along the lanes: a reduction over experts is then elementwise
+    ACROSS the ``E / 8`` vector registers of a column and one fold inside the
+    last, and nothing crosses lanes. A round is the largest key with its
+    lowest index, then that index's score summed out of the scores and its
+    key set to ``_TAKEN``; the group-limited rule is the same
+    largest-and-mask in front (twice inside each group, ``topk_group`` times
+    over the groups' row). Every iota and mask is made here, inside the call."""
+    cols, _, E = logits_ref.shape
+    top_k = experts_ref.shape[1]
+    out_rows = -(-top_k // 8) * 8
+    out_row = jax.lax.broadcasted_iota(jnp.int32, (out_rows, LANES), 0)
+
+    def one_column(c, carry):
+        x = logits_ref[c].T  # [E, LANES]
+        if scoring == "softmax":
+            e = jnp.exp(x - jnp.max(x, axis=0, keepdims=True))
+            s = e / jnp.sum(e, axis=0, keepdims=True)
+        else:
+            s = jax.nn.sigmoid(x)
+        s_scr[...] = s
+        choice = s + bias_ref[...]
+        if n_group > 1:
+            per = E // n_group
+            sub = jax.lax.broadcasted_iota(jnp.int32, (8, LANES), 0)
+            place = jax.lax.broadcasted_iota(jnp.int32, (per, LANES), 0)
+            group_key = jnp.full((8, LANES), _TAKEN, jnp.int32)
+            for g in range(n_group):
+                own = choice[g * per:(g + 1) * per]
+                top = jnp.max(own, axis=0, keepdims=True)
+                first = jnp.min(jnp.where(own == top, place, per), axis=0, keepdims=True)
+                second = jnp.max(jnp.where(place == first, -jnp.inf, own), axis=0, keepdims=True)
+                group_key = jnp.where(sub == g, _order_key(top + second), group_key)
+            kept = jnp.zeros((8, LANES), jnp.bool_)
+            for _ in range(topk_group):
+                _, hit = _largest(group_key, sub, 8)
+                kept, group_key = kept | hit, jnp.where(hit, _TAKEN, group_key)
+            for g in range(n_group):
+                keep = jnp.any(kept & (sub == g), axis=0, keepdims=True)
+                key_scr[pl.ds(g * per, per), :] = _order_key(
+                    jnp.where(keep, choice[g * per:(g + 1) * per], -jnp.inf))
+        else:
+            key_scr[...] = _order_key(choice)
+        place = jax.lax.broadcasted_iota(jnp.int32, (E, LANES), 0)
+
+        def one_round(r, carry):
+            experts, weights, total = carry
+            at, hit = _largest(key_scr[...], place, E)
+            # zeros and the one score: exact, and no gather
+            score = jnp.sum(jnp.where(hit, s_scr[...], 0.0), axis=0, keepdims=True)
+            key_scr[...] = jnp.where(hit, _TAKEN, key_scr[...])
+            return (jnp.where(out_row == r, at, experts), jnp.where(out_row == r, score, weights), total + score)
+
+        experts, weights, total = jax.lax.fori_loop(0, top_k, one_round, (
+            jnp.zeros((out_rows, LANES), jnp.int32), jnp.zeros((out_rows, LANES), jnp.float32),
+            jnp.zeros((1, LANES), jnp.float32)))
+        if normalize:
+            weights = weights / (total + 1e-20)
+        experts_ref[c] = experts[:top_k]
+        weights_ref[c] = weights[:top_k] * scaling
+        return carry
+
+    jax.lax.fori_loop(0, cols, one_column, 0)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "top_k", "n_group", "topk_group", "scaling", "normalize", "scoring", "cols", "interpret"))
+def route_topk(
+    logits: jax.Array,  # [N, E] float32
+    bias: jax.Array,  # [E] float32
+    *,
+    top_k: int,
+    n_group: int,
+    topk_group: int,
+    scaling: float,
+    normalize: bool,
+    scoring: str,
+    cols: int,  # ``route_blocks``'s
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """``route`` as one kernel: the logits read once, ``experts`` and
+    ``weights`` written once, and between them nothing leaves VMEM. What
+    ``lax.top_k`` of ``s + bias`` (group-limited where ``n_group > 1``) and a
+    gather of ``s`` return: descending, ties to the lower index, what was
+    ``-inf`` behind every finite score in the order of its index; the
+    normalising sum adds the ``top_k`` scores in that order."""
+    tokens, E = logits.shape
+    N = -(-tokens // LANES) * LANES
+    if N != tokens:
+        logits = jnp.pad(logits, ((0, N - tokens), (0, 0)))
+    shape = (N // LANES, top_k, LANES)
+    experts, weights = pl.pallas_call(
+        functools.partial(_route_kernel, n_group=n_group, topk_group=topk_group, scaling=scaling,
+                          normalize=normalize, scoring=scoring),
+        grid=(N // LANES // cols,),
+        in_specs=[
+            pl.BlockSpec((cols, LANES, E), lambda i: (i, 0, 0)),
+            pl.BlockSpec((E, 1), lambda i: (0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((cols, top_k, LANES), lambda i: (i, 0, 0)),
+            pl.BlockSpec((cols, top_k, LANES), lambda i: (i, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((E, LANES), jnp.int32), pltpu.VMEM((E, LANES), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct(shape, jnp.int32), jax.ShapeDtypeStruct(shape, jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="route_topk",
+    )(logits.reshape(N // LANES, LANES, E), bias.reshape(E, 1))
+    # held_expert_ffn stands on the order of experts.reshape(N * top_k): a token's choices side by side
+    experts, weights = (x.transpose(0, 2, 1).reshape(N, top_k) for x in (experts, weights))
+    return (experts, weights) if N == tokens else (experts[:tokens], weights[:tokens])
+
+
 def route(
     logits: jax.Array,  # [N, E] float32: x . W_g
     bias: jax.Array,  # [E] float32: e_score_correction_bias
@@ -47,6 +229,7 @@ def route(
     scaling: float,
     normalize: bool = True,
     scoring: str = "sigmoid",
+    impl: str = "xla",  # "xla" (lax.top_k) | "pallas" | "pallas_interpret"
 ) -> Tuple[jax.Array, jax.Array]:
     """``(experts [N, top_k] int32, weights [N, top_k] float32)``.
 
@@ -56,9 +239,20 @@ def route(
     ``top_k`` best experts inside them are chosen (ties to the lower index;
     ``n_group`` 1: the ``top_k`` best of all). The WEIGHT is ``s`` itself
     (never ``s + bias``) at the chosen experts, divided by their sum over all
-    ``top_k`` where ``normalize``, times ``scaling``."""
+    ``top_k`` where ``normalize``, times ``scaling``.
+
+    Where ``route_blocks`` says so the kernel ``route_topk`` chooses and
+    weighs in one pass over a token tile; under ``impl`` "xla" and for fewer
+    tokens than ``ROUTE_KERNEL_TOKENS``, ``lax.top_k`` and a gather do (the
+    body below, also the kernel's oracle): the same experts in the same order."""
     N, E = logits.shape
     logits = logits.astype(jnp.float32)
+    cols = None if impl == "xla" else route_blocks(N, E, n_group)
+    if cols is not None:
+        return route_topk(
+            logits, bias.astype(jnp.float32), top_k=top_k, n_group=n_group, topk_group=topk_group,
+            scaling=float(scaling), normalize=bool(normalize), scoring=scoring, cols=cols,
+            interpret=impl == "pallas_interpret")
     s = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
     choice = s + bias.astype(jnp.float32)[None, :]
     if n_group > 1:

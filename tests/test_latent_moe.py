@@ -251,6 +251,151 @@ def test_route_matches_reference_on_random_scores():
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 
+# ---- (d') the router's kernel against the jnp body ----
+
+# the routers the three sparse-expert cells serve: outputs, then route's rule
+ROUTERS = {
+    "dots_sigmoid_top8_of_4_of_8_groups": (256, dict(
+        top_k=8, n_group=8, topk_group=4, scaling=2.5, normalize=True, scoring="sigmoid")),
+    "longcat_softmax_top12_of_768": (768, dict(
+        top_k=12, n_group=1, topk_group=1, scaling=6.0, normalize=False, scoring="softmax")),
+    "laguna_sigmoid_top10_of_256": (256, dict(
+        top_k=10, n_group=1, topk_group=1, scaling=2.5, normalize=True, scoring="sigmoid")),
+}
+ROUTER_CASES = {  # case -> tokens
+    "a_decode_step_of_8": 8, "one_tile": 128, "a_tile_and_3": 131, "several_tiles_in_steps_of_two": 1280,
+    "ties_inside_and_across_the_cut": 256, "a_bias_that_moves_the_choice": 128,
+    "minus_inf_beside_the_kept": 256, "logits_of_bf16_operands": 384,
+}
+
+
+def router_inputs(case: str, E: int, scoring: str):
+    """``(logits [N, E] float32, bias [E])`` of a case."""
+    N = ROUTER_CASES[case]
+    key = jax.random.PRNGKey(len(case) + E)
+    logits = 2.0 * jax.random.normal(key, (N, E), jnp.float32)
+    std = 1.0 / E if scoring == "softmax" else 0.05  # the families' own draws: it moves choices among near scores
+    bias = std * jax.random.normal(jax.random.fold_in(key, 1), (E,), jnp.float32)
+    if case == "ties_inside_and_across_the_cut":
+        # every score of a row equal; logits on a grid of whole numbers; two values a row; no bias to part them
+        logits = logits.at[:64].set(0.25).at[64:128].set(jnp.round(logits[64:128]))
+        logits = logits.at[128:192].set(jnp.where(logits[128:192] > 0, 1.0, -1.0))
+        bias = jnp.zeros(E)
+    if case == "a_bias_that_moves_the_choice":
+        bias = jnp.zeros(E).at[jnp.arange(5, E, 7)].set(2.0)  # above any difference of two scores
+    if case == "minus_inf_beside_the_kept":
+        # one finite score a group of 32, none at all in every fourth group and past 256: inside a
+        # kept group the finite score's neighbours are -inf, and the choice runs on into them by index
+        at = jnp.arange(E)
+        bias = jnp.where((at % 32 >= 1) | (at // 32 % 4 == 1) | (at >= 256), -jnp.inf, bias)
+    if case == "logits_of_bf16_operands":
+        x = jax.random.normal(key, (N, 64), jnp.bfloat16)
+        w = (jax.random.normal(jax.random.fold_in(key, 2), (64, E), jnp.float32) / 8).astype(jnp.bfloat16)
+        logits = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    return logits, bias
+
+
+@pytest.mark.parametrize("case", sorted(ROUTER_CASES))
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+def test_route_kernel_is_the_jnp_body(router, case, monkeypatch):
+    """``route_topk`` (interpret mode) against ``route``'s jnp body under the
+    three served rules: the same experts in the same order, element for
+    element, and the same weights to float32 rounding."""
+    E, rule = ROUTERS[router]
+    logits, bias = router_inputs(case, E, rule["scoring"])
+    monkeypatch.setattr(moe, "ROUTE_KERNEL_TOKENS", 128)  # the served rule's 1024 at sizes the interpreter walks
+    N = logits.shape[0]
+    want = jax.jit(functools.partial(moe.route, **rule, impl="xla"))(logits, bias)
+    kernel = functools.partial(moe.route, **rule, impl="pallas_interpret")
+    got = jax.jit(kernel)(logits, bias)
+    assert ("route_topk" in str(jax.make_jaxpr(kernel)(logits, bias))) == (N >= 128)
+    for mine, its, dtype in zip(got, want, (jnp.int32, jnp.float32)):
+        assert mine.dtype == its.dtype == dtype and mine.shape == its.shape == (N, rule["top_k"])
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]), rtol=4e-7, atol=0.0)
+    experts = np.asarray(got[0])
+    if case == "several_tiles_in_steps_of_two":
+        assert moe.route_blocks(N, E, rule["n_group"]) == 2  # ten columns: five grid steps
+    if case == "ties_inside_and_across_the_cut":
+        assert experts[0].tolist() == sorted(experts[0].tolist()) and experts[0, 0] == 0  # all equal: the lowest indices
+    if case == "a_bias_that_moves_the_choice":
+        plain = np.asarray(jax.jit(kernel)(logits, jnp.zeros(E))[0])
+        favoured = np.asarray(bias)[experts] > 0
+        assert favoured.all() and (plain != experts).any()  # the bias alone decided ...
+        s = np.asarray(jax.nn.softmax(logits) if rule["scoring"] == "softmax" else jax.nn.sigmoid(logits))
+        w = np.take_along_axis(s, experts, axis=1)  # ... and weighs nothing
+        w = w / (w.sum(axis=1, keepdims=True) + 1e-20) if rule["normalize"] else w
+        np.testing.assert_allclose(np.asarray(got[1]), w * rule["scaling"], rtol=2e-6)
+    if case == "minus_inf_beside_the_kept":
+        finite = np.isfinite(np.asarray(bias))[experts]
+        assert (~finite).any(axis=1).all() and finite[:, 0].all()  # every row runs past its finite scores
+        assert all((np.diff(row[~f]) > 0).all() for row, f in zip(experts, finite))  # the tail goes by index
+        assert np.isfinite(np.asarray(got[1])).all()
+
+
+def test_route_rule_for_the_served_shapes():
+    """Every prefill of a bucket and the scorer's lengths take the kernel, 8
+    columns of 128 tokens a step at 256 outputs and 2 at 768 where they tile;
+    a decode step, a verify chunk and a 512-token prefill chunk keep
+    ``lax.top_k``; so does a router whose outputs or groups fill no whole tile."""
+    for E, rule in ROUTERS.values():
+        g, most = rule["n_group"], 8 if E == 256 else 2
+        assert {N: moe.route_blocks(N, E, g) for N in (8, 16, 128, 512)} == {8: None, 16: None, 128: None, 512: None}
+        assert {N: moe.route_blocks(N, E, g) for N in (1024, 2048, 2304, 4352, 16384, 32768)} == {
+            1024: most, 2048: most, 2304: 2, 4352: 2, 16384: most, 32768: most}
+    assert moe.ROUTE_KERNEL_TOKENS == 1024
+    assert moe.route_blocks(4100, 256, 1) == 1  # 33 columns, the last one padded
+    assert moe.route_blocks(4096, 16, 4) is None and moe.route_blocks(4096, 192, 1) is None  # no 128-lane tile of outputs
+    assert moe.route_blocks(4096, 256, 16) is None and moe.route_blocks(4096, 128, 32) is None  # groups past one row / of 4
+
+
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+def test_a_prefill_router_holds_no_sort_gather_or_scatter(router):
+    """At the cells' prefill shape the jaxpr of ``route`` (the kernel's body
+    included) names the kernel and no ``sort``, ``top_k``, ``gather`` or
+    ``scatter``; at a decode step's shape it is the jnp body under either
+    ``impl``: ``top_k`` and no kernel."""
+    E, rule = ROUTERS[router]
+    args = lambda N: (jax.ShapeDtypeStruct((N, E), jnp.float32), jax.ShapeDtypeStruct((E,), jnp.float32))  # noqa: E731
+    text = str(jax.make_jaxpr(functools.partial(moe.route, **rule, impl="pallas_interpret"))(*args(32768)))
+    assert "route_topk" in text
+    assert not re.search(r"\b(sort|top_k|gather|scatter[-_\w]*)\b", text), re.findall(r"\b(?:sort|top_k|gather|scatter\S*)\b", text)
+    step = str(jax.make_jaxpr(functools.partial(moe.route, **rule, impl="pallas_interpret"))(*args(8)))
+    assert "route_topk" not in step and "top_k" in step
+    assert step == str(jax.make_jaxpr(functools.partial(moe.route, **rule, impl="xla"))(*args(8)))
+
+
+def test_the_model_routes_a_prefill_through_the_kernel(monkeypatch):
+    """``SparseMLP`` hands ``route`` its ``impl``: a prefill whose tokens reach
+    the rule runs the kernel in every routed layer, the decode step behind it
+    keeps ``lax.top_k``, the logits are the XLA path's and nothing is dropped."""
+    monkeypatch.setattr(moe, "ROUTE_KERNEL_TOKENS", 128)
+    cfg = LatentMoEConfig.tiny(n_routed_experts=128, n_group=8, topk_group=4, num_experts_per_tok=4,
+                               ep_size=8, ep_rank=1, max_seq_len=256)
+    params = lm.init_latent_moe_params(jax.random.PRNGKey(0), cfg, FP32)
+    S, i32 = 128, jnp.int32
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, S + 1), 3, cfg.vocab_size)
+    zero = jnp.zeros((1,), i32)
+    out = {}
+    for impl in ("xla", "pallas_interpret"):
+        model = lm.LatentMoEModel(cfg, FP32, attn_impl=impl)
+        prefill = functools.partial(model.apply, {"params": params}, tokens[:, :S], jnp.arange(S)[None])
+        decode = functools.partial(model.apply, {"params": params}, tokens[:, S:], jnp.asarray([[S]]))
+        cache = lm.make_latent_cache(cfg, 1, 256, jnp.float32)
+        first = (cache, zero, jnp.full((1,), S, i32), i32(0))
+        assert ("route_topk" in str(jax.make_jaxpr(prefill)(*first))) == (impl != "xla")
+        logits, cache = prefill(*first)
+        then = (cache, zero, jnp.full((1,), S + 1, i32), i32(S))
+        assert "route_topk" not in str(jax.make_jaxpr(decode)(*then))
+        step, cache = decode(*then)
+        out[impl] = (np.asarray(logits), np.asarray(step), lm.fold_counters(np.asarray(cache.counters)))
+    np.testing.assert_allclose(out["pallas_interpret"][0], out["xla"][0], atol=ATOL)
+    np.testing.assert_allclose(out["pallas_interpret"][1], out["xla"][1], atol=ATOL)
+    counted = out["pallas_interpret"][2]
+    assert counted["moe_prefill_assignments_held"] == counted["moe_prefill_assignments_computed"] > 0
+    assert counted["moe_prefill_assignments_held"] == out["xla"][2]["moe_prefill_assignments_held"]
+
+
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
 def test_imbalance_loses_nothing(impl):
     """Every token sends all its choices to held experts, half of them to ONE:
@@ -530,19 +675,23 @@ def test_combine_rule_for_the_served_shapes():
     assert moe.combine_blocks(1024, 512, 7168, 2) is None and moe.combine_blocks(1024, 1024, 7168, 2) is not None
 
 
-# ---- the benchmark's reader of the held experts' prefill time ----
+# ---- the benchmark's readers of the held experts' and the router's prefill time ----
 
 
 @pytest.mark.parametrize("case,by,rows,want", [
-    ("a_slice_with_the_scope", {"prefill": {"experts": 0.24, "router": 0.1}, "decode": {"experts": 9.0}}, 24.0, 10.0),
+    ("a_slice_with_the_scope", {"prefill": {"experts": 0.24, "router": 0.12}, "decode": {"experts": 9.0, "router": 7.0}},
+     24.0, {"experts": 10.0, "router": 5.0}),
     ("a_program_without_the_scope", {"prefill": {"dense": 1.0}}, 24.0, None),
-    ("no_prefill_row_in_the_slice", {"prefill": {"experts": 0.24}}, 0.0, None),
+    ("no_prefill_row_in_the_slice", {"prefill": {"experts": 0.24, "router": 0.12}}, 0.0, None),
     ("no_trace", None, 24.0, None),
 ])
-def test_reader_of_held_experts_prefill_ms_per_row(case, by, rows, want):
-    """``benchmark/layer_metrics/held_experts_prefill_ms_per_row.py`` divides
-    ``prefill/.../mlp/experts`` by the slice's prefill rows, returns None (and
-    does not raise) where either is missing, and ``BENCHMARK.json`` lists it
+@pytest.mark.parametrize("metric,scope", [("held_experts_prefill_ms_per_row", "experts"),
+                                          ("router_prefill_ms_per_row", "router")])
+def test_reader_of_a_fine_scope_s_prefill_ms_per_row(metric, scope, case, by, rows, want):
+    """``benchmark/layer_metrics/held_experts_prefill_ms_per_row.py`` and
+    ``router_prefill_ms_per_row.py`` divide ``prefill/.../mlp/experts`` and
+    ``prefill/.../mlp/router`` by the slice's prefill rows, return None (and
+    do not raise) where either is missing, and ``BENCHMARK.json`` lists each
     for the three sparse-expert cells."""
     import importlib.util
     import json
@@ -553,16 +702,16 @@ def test_reader_of_held_experts_prefill_ms_per_row(case, by, rows, want):
     sys.path.insert(0, repo)
     try:
         spec = importlib.util.spec_from_file_location(
-            "held_experts_reader", os.path.join(repo, "benchmark/layer_metrics/held_experts_prefill_ms_per_row.py"))
+            metric + "_reader", os.path.join(repo, f"benchmark/layer_metrics/{metric}.py"))
         reader = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(reader)
     finally:
         sys.path.remove(repo)
     ctx = {"trace": None} if by is None else {"trace": {}, "fine_scopes": by, "phases": {"prefill_rows": rows, "steps": {}}}
     got = reader.read(ctx)
-    assert got is None if want is None else got == pytest.approx(want)
+    assert got is None if want is None else got == pytest.approx(want[scope])
     with open(os.path.join(repo, "BENCHMARK.json")) as f:
-        entry = {m["name"]: m for m in json.load(f)["per_layer"]}["held_experts_prefill_ms_per_row"]
-    assert entry == {"name": "held_experts_prefill_ms_per_row", "unit": "ms", "better": "lower",
+        entry = {m["name"]: m for m in json.load(f)["per_layer"]}[metric]
+    assert entry == {"name": metric, "unit": "ms", "better": "lower",
                      "source": "device_trace", "layer": "model step", "moves": "latency_p50_ms",
                      "workloads": ["dots-vlm1-ep16.closed8", "longcat-flash-ep32.closed8", "laguna-s-ep16.closed8"]}

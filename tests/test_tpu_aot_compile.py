@@ -263,6 +263,27 @@ CASES.update({
 })
 
 
+def _route(E: int, N: int = 32768, **rule):
+    from rag_llm_k8s_tpu.ops import moe
+
+    def fn(logits, bias):
+        return moe.route(logits, bias, scaling=2.5, **rule, impl="pallas")
+
+    return fn, [((N, E), F32), ((E,), F32)]
+
+
+# the router at the three sparse-expert cells' prefill shape (batch 8 of a
+# 4096 bucket): group-limited sigmoid, softmax over 768 outputs, plain
+# sigmoid; and the smallest shape the rule gives the kernel (one row of a
+# 1024 bucket). A decode step's 8 tokens keep the jnp body: no kernel to lower
+CASES.update({
+    "route[256, top-8 of 4 of 8 groups]": _route(256, top_k=8, n_group=8, topk_group=4),
+    "route[768, top-12, softmax]": _route(768, top_k=12, n_group=1, topk_group=1, normalize=False, scoring="softmax"),
+    "route[256, top-10]": _route(256, top_k=10, n_group=1, topk_group=1),
+    "route[256, top-10, 1024 tokens]": _route(256, 1024, top_k=10, n_group=1, topk_group=1),
+})
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, uncached):
     fn, args = CASES[name]
@@ -286,6 +307,11 @@ def test_kernel_compiles_for_v5e(name, one_chip, uncached):
                            "flash_attention[48 heads]": (48, 4096, HD)}[name]
         assert re.search(rf"%{kernel}(\.\d+)? = bf16\[{heads},{S},{width}\]\S* custom-call\(", text), name
         assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
+    if name.startswith("route["):
+        # the kernel by its name, inside the default scoped VMEM, and neither a sort
+        # nor a gather left beside it
+        assert "%route_topk" in text and '"scoped_memory_configs":[]' in text, name
+        assert not re.search(r" (sort|gather)\(", text), name
     if name.startswith("expert_combine"):
         # in place (the tile read and written is the accumulator's), inside
         # the default scoped VMEM, and no sort in front of it (a sort of a

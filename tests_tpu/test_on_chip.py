@@ -506,3 +506,17 @@ class TestShortcutBlockKernelsOnChip:
                 q_lat[:, last:last + 1], q_rope[:, last:last + 1], c_kv[None], k_rope[None], kv_start,
                 jnp.full((B,), last + 1, jnp.int32), jnp.int32(0), scale=scale)
         np.testing.assert_allclose(np.asarray(one[:, 0]), np.asarray(o_lat[:, last]), rtol=2e-4, atol=2e-4)
+
+
+class TestRouterKernelOnChip:
+    @pytest.mark.parametrize("name", [
+        "route[32768,256] top-8 of 4 of 8 groups", "route[32768,768] top-12, softmax", "route[32768,256] top-10"])
+    def test_route_kernel_at_the_serving_shapes(self, name):
+        """``ops/moe.py route`` at the three sparse-expert cells' prefill
+        shapes: the kernel's experts are the jnp body's, tied rows and the
+        ``-inf`` tail included, its weights the same to float32 rounding
+        (``chip_smoke.py phase_route``'s checks, one case each; it raises
+        where they differ)."""
+        import chip_smoke
+
+        chip_smoke.phase_route(0, {name: chip_smoke.SERVING_ROUTE[name]})
