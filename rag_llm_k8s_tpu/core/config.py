@@ -545,6 +545,69 @@ class WindowedMoEConfig:
 
 
 @dataclass(frozen=True)
+class BlockWindowConfig:
+    """The block-window, pooled-summary decoder family
+    (``models/block_window.py``): multi-head attention whose query at
+    position ``t`` sees the exact keys of its own WINDOW (``t // window_size``,
+    causally) and, for every complete CHUNK of ``chunk_size`` positions in an
+    EARLIER window, one pooled key and one pooled value, all under one
+    softmax; a float32 residual stream (``fp32_skip_add``), unit-offset norm
+    scales (``norm_add_unit_offset``), float32 logits and ``num_pred_heads``
+    next-position heads of ``vocab_size`` columns each (the head is
+    ``[hidden, num_pred_heads * vocab_size]``, head-major; the served logits
+    are head 0's). Field names are the published ``config.json``'s.
+
+    Defaults are the published widths of the 6.5B byte-level decoder, at the
+    depth of one stage of a four-stage pipeline."""
+
+    vocab_size: int = 320
+    hidden_size: int = 4096
+    intermediate_size: int = 11008
+    num_hidden_layers: int = 8
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 32
+    window_size: int = 2048
+    chunk_size: int = 16
+    num_pred_heads: int = 8
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 100000.0
+    max_seq_len: int = 32768
+    tie_word_embeddings: bool = False
+    bos_token_id: int = 1
+    eos_token_ids: Tuple[int, ...] = (2,)
+
+    def __post_init__(self):
+        if self.num_key_value_heads != self.num_attention_heads:
+            raise ValueError("this family is multi-head: one K/V head a query head")
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size is a whole number of heads")
+        if self.chunk_size < 1 or self.window_size % self.chunk_size:
+            raise ValueError(
+                f"window_size={self.window_size} must be a whole number of chunks of {self.chunk_size}")
+        if self.tie_word_embeddings:
+            raise ValueError("this family serves an untied head only")
+
+    # the names the rest of the program reads a decoder's sizes by
+    num_layers = property(lambda self: self.num_hidden_layers)
+    num_heads = property(lambda self: self.num_attention_heads)
+    num_kv_heads = property(lambda self: self.num_key_value_heads)
+    head_dim = property(lambda self: self.hidden_size // self.num_attention_heads)
+    chunks_per_window = property(lambda self: self.window_size // self.chunk_size)
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 256, **overrides) -> "BlockWindowConfig":
+        """Miniature config for CPU tests: windows of 32 positions in chunks
+        of 4, 4 heads of 16, two layers, 8 next-position heads."""
+        base = dict(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+            num_attention_heads=4, num_key_value_heads=4, window_size=32, chunk_size=4,
+            num_pred_heads=8, max_seq_len=512, bos_token_id=1, eos_token_ids=(2,),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
 class EncoderConfig:
     """Bidirectional encoder config for the embedding model.
 

@@ -589,7 +589,11 @@ class InferenceEngine:
                 # choice, so garbage proposals just mean m = 0)
                 fed = jnp.concatenate([last_tok, props])[None, :]  # [1, k+1]
                 pos = (real_len[0] - 1 + e + jnp.arange(k + 1, dtype=i32))[None, :]
-                kv_len = jnp.full((1,), wi + k + 1, i32)
+                # how many of the fed positions may write the cache (a family
+                # whose cache cannot take a rejected write back bounds it)
+                span = self.family.verify_span
+                writes = k + 1 if span is None else span(cfg, pos[0, 0], k + 1)
+                kv_len = jnp.full((1,), wi + writes, i32)
                 logits, cache = mc.apply(
                     {"params": params}, fed, pos, cache, kv_start, kv_len, wi
                 )
@@ -633,6 +637,8 @@ class InferenceEngine:
                 is_eos = _isin(g, eos_ids)
                 eos_pos = jnp.min(jnp.where(is_eos & (j_idx <= m), j_idx, k + 1))
                 m_eff = jnp.minimum(jnp.minimum(m, eos_pos), max_new - e - 1)
+                if span is not None:  # nothing is kept past what was written
+                    m_eff = jnp.minimum(m_eff, writes - 1)
                 emit = j_idx <= m_eff
                 out_idx = e + j_idx  # unique lanes (slack-padded buffer)
                 out_row = out[0].at[out_idx].set(

@@ -13,7 +13,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
-from rag_llm_k8s_tpu.core.config import LatentMoEConfig, LlamaConfig, WindowedMoEConfig
+from rag_llm_k8s_tpu.core.config import BlockWindowConfig, LatentMoEConfig, LlamaConfig, WindowedMoEConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,6 +36,10 @@ class Family:
     unsupported: Callable = lambda engine_config, mesh, engine: None
     # why server.main.build_service cannot load it from safetensors (None: it can)
     checkpoint_loader_refusal: Optional[str] = None
+    # (config, position, fed) -> how many of a verify step's ``fed`` positions
+    # from ``position`` on may write the cache (None: all of them; a ring
+    # cannot take back a write past its window's end)
+    verify_span: Optional[Callable] = None
 
 
 def _llama_model(config, dtypes, engine_config, mesh, *, fused: bool, quantized: bool):
@@ -168,8 +172,51 @@ def _windowed_moe() -> Family:
     )
 
 
+def _block_window() -> Family:
+    from rag_llm_k8s_tpu.models import block_window as bwm
+    from rag_llm_k8s_tpu.parallel.sharding import block_window_param_specs
+
+    def unsupported(engine_config, mesh, engine):
+        if engine == "continuous" or getattr(engine_config, "batching", "coalesce") == "continuous":
+            return ("the continuous engine (batching='continuous') or its paged KV pool",
+                    "the block pool has one table kind of full-length planes: this cache is a ring "
+                    "of window slots and a plane of pooled summaries, a second table kind; use 'coalesce'")
+        pc = getattr(engine_config, "prefix_cache", None)
+        if pc is not None and pc.enabled:
+            return ("the KV prefix cache (prefix_cache.enabled)",
+                    "a pooled summary is position-free only up to its keys' rotation, and a spliced "
+                    "segment's windows and chunks fall elsewhere than the prompt's")
+        if engine_config.kv_quant != "bf16":
+            return f"kv_quant={engine_config.kv_quant!r}", "the ring and the summary plane have no int8 form"
+        if engine_config.weight_quant != "bf16":
+            return (f"weight_quant={engine_config.weight_quant!r}",
+                    "quantize_llama_params does not know this tree (stacked layers, the pooling vectors)")
+        if mesh is not None and (mesh.tp > 1 or getattr(mesh, "sp", 1) > 1):
+            return (f"tp={mesh.tp}, sp={getattr(mesh, 'sp', 1)}",
+                    "this tree has no partition rules, and a prompt row's windows are walked on one chip")
+        return None
+
+    return Family(
+        name="the block-window pooled-summary family (BlockWindowConfig)",
+        build_model=lambda config, dtypes, engine_config, mesh, *, fused, quantized: bwm.BlockWindowModel(
+            config, dtypes, attn_impl=engine_config.attn_impl),
+        make_cache=lambda config, batch_size, max_seq_len, dtype, quant: bwm.make_block_window_cache(
+            config, batch_size, max_seq_len, dtype),
+        param_specs=block_window_param_specs,
+        counters_width=bwm.N_COUNTERS,
+        counter_names=bwm.COUNTER_NAMES,
+        fold_counters=bwm.fold_counters,
+        unsupported=unsupported,
+        checkpoint_loader_refusal=(
+            "the checkpoint loader has no name map for the block-window pooled-summary "
+            "family's tensors; serve it through assemble_service with a parameter tree of your own"),
+        verify_span=bwm.verify_span,
+    )
+
+
 # configuration type -> its family (a thunk where building it imports the model)
 _TABLE: Tuple[Tuple[type, Callable[[], Family]], ...] = (
+    (BlockWindowConfig, _block_window),
     (WindowedMoEConfig, _windowed_moe),
     (LatentMoEConfig, _latent_moe),
     (LlamaConfig, _llama),

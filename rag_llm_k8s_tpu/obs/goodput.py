@@ -299,6 +299,23 @@ def roofline_for_windowed_moe(cfg, *, peak_tflops: float, hbm_gbs: float) -> Roo
     )
 
 
+def roofline_for_block_window(cfg, *, peak_tflops: float, hbm_gbs: float) -> RooflineModel:
+    """The roofline of the block-window pooled-summary family, from a
+    ``BlockWindowConfig``'s fields (duck-typed): the dense decoder's matmuls
+    with the head's ``num_pred_heads`` column blocks. ``kv_bytes_per_token``
+    is what one more position of context costs a decode step to read: a
+    pooled key and value every ``chunk_size`` positions (the ring of the
+    query's own window, at most ``window_size`` exact slots, does not grow
+    with the context and is left out of this linear term)."""
+    base = roofline_for_llama(
+        cfg.num_layers, cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+        cfg.intermediate_size, cfg.vocab_size * cfg.num_pred_heads, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs)
+    return RooflineModel(
+        flops_per_token=base.flops_per_token, weight_bytes=base.weight_bytes,
+        kv_bytes_per_token=base.kv_bytes_per_token / cfg.chunk_size,
+        peak_tflops=peak_tflops, hbm_gbs=hbm_gbs)
+
+
 def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger":
     """THE ledger constructor both serving engines share (duck-typed over
     the config dataclasses — still no package imports). One site means the
@@ -319,13 +336,15 @@ def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger"
             peak_tflops = peak_tflops if peak_tflops > 0 else kind_tflops
             hbm_gbs = hbm_gbs if hbm_gbs > 0 else kind_gbs
         # the family is told by what the configuration HAS (no package
-        # imports here): a latent cache's rank, layers of several kinds, or
-        # per-head K/V alike in every layer
+        # imports here): a latent cache's rank, layers of several kinds, a
+        # pooled summary every chunk, or per-head K/V alike in every layer
         roofline = roofline_for_latent_moe(
             model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
         ) if hasattr(model_config, "kv_lora_rank") else roofline_for_windowed_moe(
             model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
-        ) if hasattr(model_config, "layer_types") else roofline_for_llama(
+        ) if hasattr(model_config, "layer_types") else roofline_for_block_window(
+            model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
+        ) if hasattr(model_config, "chunk_size") else roofline_for_llama(
             model_config.num_layers, model_config.hidden_size,
             model_config.num_heads, model_config.num_kv_heads,
             model_config.head_dim, model_config.intermediate_size,

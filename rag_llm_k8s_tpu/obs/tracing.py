@@ -77,10 +77,15 @@ SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
 # The windowed-attention family (models/windowed_moe.py) opens ``attn/window``
 # and ``attn/global`` around the attention of a sliding and of a full layer
 # (kernel or XLA form, nothing else), ``attn/gate`` around the per-head output
-# gate, and the ``mlp/*`` scopes above. A
+# gate, and the ``mlp/*`` scopes above. The block-window family
+# (models/block_window.py) opens ``attn/ring`` around attention over a
+# window's exact keys and the pooled summaries of the windows before it (one
+# softmax, one call) and ``attn/pool`` around the pooling of chunks into
+# summaries. A
 # reader that files an operation under the first sub-scope it knows keeps
 # reading ``attn`` and ``mlp``; one that knows these sees the finer split.
-FINE_SCOPES = ("latent", "router", "experts", "shared", "zero", "dense", "window", "global", "gate")
+FINE_SCOPES = ("latent", "router", "experts", "shared", "zero", "dense", "window", "global", "gate",
+               "ring", "pool")
 SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES + FINE_SCOPES)
 
 

@@ -664,7 +664,7 @@ def _decode_block(T: int, bk: int) -> int:
     return max(d for d in range(1, min(bk, T) + 1) if T % d == 0)
 
 
-@functools.partial(jax.jit, static_argnames=("bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bk", "interpret", "name"))
 def decode_attention(
     q: jax.Array,  # [B, 1, H, hd] — the single fresh query token
     k_cache: jax.Array,  # [L, B, K, T, hd] — FULL stacked head-major cache
@@ -674,6 +674,7 @@ def decode_attention(
     layer: jax.Array,  # [] or [1] int32: which layer's cache to attend over
     bk: Optional[int] = None,
     interpret: bool = False,
+    name: str = "decode_attention",  # what a trace calls it (a family whose planes are of another kind)
 ) -> jax.Array:
     """Fused single-token decode attention over the KV cache.
 
@@ -724,7 +725,7 @@ def decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
-        name="decode_attention",
+        name=name,
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         kv_start.astype(jnp.int32),

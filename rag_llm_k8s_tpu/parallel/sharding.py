@@ -134,6 +134,11 @@ def windowed_moe_param_specs(params, ctx: MeshContext):
     return _replicated_specs(params, ctx, "windowed-attention sparse-expert")
 
 
+def block_window_param_specs(params, ctx: MeshContext):
+    """The same for the ``BlockWindowModel`` layout (layers stacked a leaf)."""
+    return _replicated_specs(params, ctx, "block-window pooled-summary")
+
+
 def shard_params(params, specs, ctx: MeshContext):
     """Place a param pytree on the mesh per its spec tree.
 

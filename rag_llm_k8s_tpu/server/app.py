@@ -302,9 +302,9 @@ class RagService:
             # whose (serial) generate then blocks the other N-1 for a whole
             # round — measured +1 s on the burst-8 p50. Sustained load would
             # batch naturally at window 0 (busy-worker accumulation), but the
-            # cold burst is the latency-defining case; a solo query pays this
-            # 25 ms plus the generate scheduler's 30 ms (server/main.py) —
-            # ~55 ms, ~5% of a /query p50 — as the price of burst robustness.
+            # cold burst is the latency-defining case; without the hint a solo
+            # query would pay this 25 ms plus the generate scheduler's window
+            # (server/main.py) as the price of burst robustness.
             self.retrieve_coalescer = Coalescer(
                 lambda items: self._retrieve_many(items, allow_device=True),
                 max_batch=self._retrieve_cap, max_wait_ms=25.0,

@@ -200,10 +200,16 @@ def assemble_service(
     else:
         from rag_llm_k8s_tpu.engine.batching import BatchScheduler
 
-        # 30 ms: long enough to catch a cold burst fanning out of ONE
-        # coalesced retrieval (results arrive within ~ms of each other),
-        # short enough to be invisible next to a full-context generate
-        scheduler = BatchScheduler(engine, max_wait_ms=30.0)
+        # how long the worker waits, from the first request aboard, for the
+        # others still in flight upstream (retrieval, prompt assembly). The
+        # wait ends at once when every in-flight request is aboard, so a round
+        # of callers pays it only when one of them is late, and a late one
+        # costs less than the second dispatch it would otherwise ride: a
+        # whole prefill and decode, and in a closed loop the round stays
+        # split. 120 ms covers the slowest prompt assembly served: a byte
+        # vocabulary's 20 k ids, tokenized twice by the budget rule, 15 ms a
+        # request where a BPE prompt takes 3
+        scheduler = BatchScheduler(engine, max_wait_ms=120.0)
     return RagService(
         config, engine, llm_tokenizer, encoder, enc_tokenizer, store, scheduler=scheduler
     )

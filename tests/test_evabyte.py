@@ -1,0 +1,367 @@
+"""The block-window pooled-summary family (models/block_window.py over
+ops/block_window.py and ops/attention.py's decode walk) against its plain
+reference (tests/evabyte_reference.py), at a toy size on the CPU with seeded
+weights under the fp32 policy: windows of 32 positions in chunks of 4, 4 heads
+of 16, two layers, 8 next-position heads of 40 columns. Every prompt here is
+longer than a window, so every query past position 31 reads summaries.
+
+Tolerances. The program and the reference compute the same float32 numbers in
+different orders (the cache round-trips nothing in fp32; the kernels' running
+softmax against one softmax over a masked row), so logits of magnitude ~1-4
+agree to a few 1e-6; ``ATOL`` is 1e-4, some thirty times that. The faults
+the comparison must see are far above it: window-only attention, the pooling
+vectors exchanged, a plain mean for the pooling and a summary of the query's
+own window each move a logit by 1e-2 or more past the first window.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import evabyte_reference as ref
+from rag_llm_k8s_tpu.core.config import (
+    BlockWindowConfig,
+    DTypePolicy,
+    EngineConfig,
+    MeshConfig,
+    PrefixCacheConfig,
+    SamplingConfig,
+)
+from rag_llm_k8s_tpu.core.mesh import make_mesh
+from rag_llm_k8s_tpu.engine.engine import InferenceEngine
+from rag_llm_k8s_tpu.models import block_window as bwm, families
+from rag_llm_k8s_tpu.ops import block_window as bw
+
+FP32 = DTypePolicy.fp32()
+ATOL = 1e-4
+V = 40
+CFG = BlockWindowConfig.tiny(vocab_size=V)
+W, C = CFG.window_size, CFG.chunk_size
+NEW = 6
+GREEDY = SamplingConfig(do_sample=False, max_new_tokens=NEW)
+
+
+def seeded_params(cfg, seed=0):
+    """Weights with statistics that make every part matter: kernels of std
+    1/sqrt(fan_in), norm offsets near 0, pooling vectors of unit std (so the
+    pooling weights are far from uniform)."""
+    shapes = jax.eval_shape(lambda: bwm.init_block_window_params(jax.random.PRNGKey(0), cfg, FP32))
+    rng = np.random.default_rng(seed)
+    out = {}
+    for name, leaf in shapes.items():
+        if "norm" in name:
+            value = 0.1 * rng.standard_normal(leaf.shape)
+        elif name == "embedding" or name.endswith(("mu", "phi")):
+            value = rng.standard_normal(leaf.shape)
+        else:
+            value = rng.standard_normal(leaf.shape) / np.sqrt(leaf.shape[-2])
+        out[name] = jnp.asarray(value, jnp.float32)
+    return out
+
+
+@pytest.fixture(scope="module")
+def params():
+    return seeded_params(CFG)
+
+
+def prompt_of(n, seed):
+    return [int(t) for t in np.random.default_rng(seed).integers(3, V, size=n)]
+
+
+def head0(logits):
+    return np.asarray(logits)[..., 0, :]
+
+
+_REF = {}
+
+
+def reference(params, tokens):
+    """The reference's logits ``[len, 8, V]``, once a sequence."""
+    key = tuple(tokens)
+    if key not in _REF:
+        _REF[key] = np.asarray(ref.forward(params, CFG, list(tokens)))
+    return _REF[key]
+
+
+def greedy_reference(params, prompt, n):
+    tokens = list(prompt)
+    for _ in range(n):
+        # a pad behind the sequence changes nothing in front of it (causal),
+        # but completes chunks: pad with whole windows only past the end
+        tokens.append(int(np.argmax(head0(ref.forward(params, CFG, tokens))[-1])))
+    return tokens[len(prompt):]
+
+
+def through_the_cache(params, rows, S, lengths, T=None, impl="xla"):
+    """All 8 heads' logits of ``rows`` (left-padded to ``S``, of which
+    ``lengths`` are prefilled at once and the rest decoded a token at a
+    time), and the cache."""
+    B = len(rows)
+    lens = np.asarray(lengths)
+    T = T or S + max(len(r) - n for r, n in zip(rows, lens))
+    model = bwm.BlockWindowModel(CFG, FP32, attn_impl=impl, all_heads=True)
+    call = jax.jit(lambda *a: model.apply({"params": params}, *a))
+    cache = bwm.make_block_window_cache(CFG, B, T, jnp.float32)
+    kv_start = jnp.asarray(S - lens, jnp.int32)
+    padded = np.zeros((B, S), np.int32)
+    for b, row in enumerate(rows):
+        padded[b, S - lens[b]:] = row[:lens[b]]
+    positions = jnp.maximum(jnp.arange(S)[None, :] - kv_start[:, None], 0)
+    logits, cache = call(jnp.asarray(padded), positions, cache, kv_start, jnp.full((B,), S, jnp.int32), jnp.int32(0))
+    out = [[np.asarray(logits[b, S - lens[b] + t]) for t in range(lens[b])] for b in range(B)]
+    for t in range(min(len(r) - n for r, n in zip(rows, lens))):
+        tok = jnp.asarray([[r[n + t]] for r, n in zip(rows, lens)], jnp.int32)
+        logits, cache = call(tok, jnp.asarray(lens + t)[:, None].astype(jnp.int32), cache, kv_start,
+                             jnp.full((B,), S + t + 1, jnp.int32), jnp.int32(S + t))
+        for b in range(B):
+            out[b].append(np.asarray(logits[b, 0]))
+    return [np.stack(o).reshape(-1, CFG.num_pred_heads, V) for o in out], cache
+
+
+# ---- (a) prefill, then decode through the ring and the summary plane ----
+
+
+@pytest.mark.parametrize("prompt_len,why", [
+    (70, "inside a chunk"), (72, "on a chunk's end"), (64, "on a window's end"), (95, "a step before a window's end"),
+])
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_prefill_then_decode_matches_reference_at_every_position(params, impl, prompt_len, why):
+    """All 8 heads' logits at every position: the bucket prefilled at once,
+    then 40 positions decoded, which crosses a window's end (and closes ten
+    chunks) whatever the prompt's length."""
+    tokens = prompt_of(prompt_len + 40, prompt_len)
+    (got,), cache = through_the_cache(params, [tokens], 96, [prompt_len], impl=impl)
+    np.testing.assert_allclose(got, reference(params, tokens), atol=ATOL)
+    counted = bwm.fold_counters(np.asarray(cache.counters))
+    assert counted["chunks_closed"] == len(tokens) // C and counted["windows_closed"] == len(tokens) // W
+    assert (counted["decode_ring_slots_fetched"] > 0) == (impl != "xla")
+    # the planes hold what the layout says: position p at ring slot p % W, chunk c at summary c
+    ring, pooled = bwm.ring_of(cache.k, CFG), bwm.summaries_of(cache.k, CFG)
+    assert ring.shape[-2] == W and pooled.shape[-2] == cache.k.shape[3] - W < len(tokens)  # no plane as long
+
+
+def test_two_rows_of_one_bucket_with_different_left_padding(params):
+    rows = [prompt_of(100, 2), prompt_of(77, 3), prompt_of(50, 4)]
+    lengths = [90, 41, 33]  # windows and chunks fall at different slots in every row
+    got, _ = through_the_cache(params, rows, 96, lengths)
+    for row, g in zip(rows, got):
+        np.testing.assert_allclose(g, reference(params, row)[:len(g)], atol=ATOL)
+
+
+def chunk_call(params, tokens, start, n, kv_start=0, kv_len=None, T=160, all_heads=True):
+    """``tokens[:start]`` prefilled and decoded into a cache, then ``n``
+    positions from ``start`` in ONE chunk call; returns its logits and cache."""
+    S = 32 * (-(-start // 32))
+    model = bwm.BlockWindowModel(CFG, FP32, attn_impl="xla", all_heads=all_heads)
+    mc = model.copy(chunked=True)
+    cache = bwm.make_block_window_cache(CFG, 1, T, jnp.float32)
+    pad = S - start
+    padded = np.zeros((1, S), np.int32)
+    padded[0, pad:] = tokens[:start]
+    ks = jnp.asarray([pad], jnp.int32)
+    positions = jnp.maximum(jnp.arange(S)[None] - pad, 0)
+    _, cache = model.apply({"params": params}, jnp.asarray(padded), positions, cache, ks,
+                           jnp.full((1,), S, jnp.int32), jnp.int32(0))
+    fed = jnp.asarray([tokens[start:start + n]], jnp.int32)
+    pos = (start + jnp.arange(n))[None]
+    end = S + n if kv_len is None else kv_len
+    return mc.apply({"params": params}, fed, pos, cache, ks, jnp.full((1,), end, jnp.int32), jnp.int32(S)), S
+
+
+@pytest.mark.parametrize("start,n,why", [
+    (57, 12, "straddles a window's end"), (40, 16, "inside a window, closes four chunks"),
+    (62, 2, "ends on a window's last position"), (64, 9, "starts a window"),
+])
+def test_a_chunk_over_the_cache_matches_reference(params, start, n, why):
+    """What a verify step and the scorer feed: positions over the cache whose
+    first ones are of one window and whose last of the next. The later
+    window's queries read the summary of the chunk this very call completes."""
+    tokens = prompt_of(120, 7)
+    (logits, cache), S = chunk_call(params, tokens, start, n)
+    want = reference(params, tokens[:start + n])[start:]
+    np.testing.assert_allclose(np.asarray(logits[0]).reshape(n, -1, V), want, atol=ATOL)
+    # and the cache it leaves serves the next positions exactly
+    model = bwm.BlockWindowModel(CFG, FP32, attn_impl="xla", all_heads=True)
+    at = start + n
+    step, _ = model.apply({"params": params}, jnp.asarray([[tokens[at]]], jnp.int32), jnp.asarray([[at]]), cache,
+                          jnp.asarray([S - start], jnp.int32), jnp.full((1,), S + n + 1, jnp.int32), jnp.int32(S + n))
+    np.testing.assert_allclose(np.asarray(step[0, 0]).reshape(-1, V), reference(params, tokens[:at + 1])[-1], atol=ATOL)
+
+
+def test_a_verify_step_keeps_the_ring_it_may_still_need(params):
+    """A verify step at position 57 feeding 12 proposals may write 7 (to the
+    window's end): ``verify_span`` says so and the engine hands it over as
+    ``kv_len``. The ring slots of the next window (slots 0..4: this window's
+    positions 32..36) keep their keys, so a rejected proposal costs nothing."""
+    tokens = prompt_of(120, 7)
+    assert int(bwm.verify_span(CFG, jnp.int32(57), 12)) == 7 and int(bwm.verify_span(CFG, jnp.int32(64), 12)) == 12
+    (_, before), S = chunk_call(params, tokens, 57, 1)
+    junk = tokens[:58] + prompt_of(11, 99)
+    (logits, after), _ = chunk_call(params, junk, 57, 12, kv_len=64 + 7)
+    ring = lambda cache: np.asarray(bwm.ring_of(cache.k, CFG))[:, 0, :, :57 % W]  # noqa: E731
+    np.testing.assert_array_equal(ring(after), ring(before))
+    np.testing.assert_allclose(np.asarray(logits[0, :7]).reshape(7, -1, V), reference(params, junk[:64])[57:], atol=ATOL)
+
+
+# ---- (b) the kernels against the XLA forms ----
+
+
+def test_prefill_kernel_is_the_dense_form():
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.standard_normal((4, 128, 16)), jnp.float32) for _ in range(3))
+    mu, phi = (jnp.asarray(rng.standard_normal((4, 16)), jnp.float32) for _ in range(2))
+    sk, sv = bw.pool_chunks(k, v, mu, phi, C)
+    want = bw.window_summary_attention_xla(q, k, v, sk, sv, window=W, chunk=C)
+    got = bw.window_summary_flash_attention(q, k, v, sk, sv, window=W, chunk=C, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    # a length that is no whole number of windows is padded inside
+    got = bw.window_summary_flash_attention(q[:, :80], k[:, :80], v[:, :80], sk[:, :20], sv[:, :20],
+                                            window=W, chunk=C, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want)[:, :80], atol=2e-6)
+
+
+def test_pooling_is_the_softmax_weighted_sum():
+    rng = np.random.default_rng(1)
+    k, v = (rng.standard_normal((2, 8, 16)) for _ in range(2))
+    mu, phi = rng.standard_normal((2, 16)), rng.standard_normal((2, 16))
+    sk, sv = bw.pool_chunks(jnp.asarray(k, jnp.float32), jnp.asarray(v, jnp.float32), jnp.asarray(mu, jnp.float32),
+                            jnp.asarray(phi, jnp.float32), 4)
+    for h in range(2):
+        for c in range(2):
+            kc, vc = k[h, 4 * c:4 * c + 4], v[h, 4 * c:4 * c + 4]
+            wk, wv = np.exp(kc @ mu[h]), np.exp(kc @ phi[h])
+            np.testing.assert_allclose(np.asarray(sk)[h, c], (wk / wk.sum()) @ kc, atol=1e-5)
+            np.testing.assert_allclose(np.asarray(sv)[h, c], (wv / wv.sum()) @ vc, atol=1e-5)
+
+
+def test_the_live_range_is_one_run_of_slots():
+    NS = 24
+    for t, first, end in ((0, 24, 25), (31, 24, 56), (32, 16, 25), (70, 8, 31), (95, 8, 56), (96, 0, 25)):
+        assert tuple(int(x) for x in bwm.live_range(jnp.int32(t), CFG, NS)) == (first, end)
+    assert bwm.summary_slots(CFG, 160) == 40 and bwm.summary_slots(BlockWindowConfig(), 20992) == 1408
+    cache = families.make_cache(CFG, 2, 160, jnp.float32)
+    assert cache.k.shape == (2, 2, 4, 40 + W, 16) and cache.counters.shape == (bwm.N_COUNTERS,)
+
+
+# ---- (c) the faults the comparison must see ----
+
+
+@pytest.mark.parametrize("fault", ["summaries", "swap_mu_phi", "mean_pool", "own_window_summaries"])
+def test_a_fault_fails_the_tolerance(params, fault):
+    tokens = prompt_of(110, 1)
+    sound = head0(reference(params, tokens))
+    bad = head0(ref.forward(params, CFG, tokens, **{fault: fault != "summaries"}))
+    first = C - 1 if fault == "own_window_summaries" else W  # where the fault can first show
+    np.testing.assert_allclose(bad[:first], sound[:first], atol=1e-5)
+    assert np.abs(bad[first:] - sound[first:]).max() > 100 * ATOL
+    (got,), _ = through_the_cache(params, [tokens], 96, [96])
+    assert np.abs(head0(got) - bad).max() > 100 * ATOL  # and the program is on the sound side
+
+
+# ---- (d) every one-shot program of the engine ----
+
+
+def engine_for(params, cfg=CFG, **kw):
+    ec = EngineConfig(**{**dict(prompt_buckets=(64, 96), max_batch_size=4, max_seq_len=224,
+                                speculative="off", attn_impl="xla", max_chunked_prompt=256), **kw})
+    return InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+
+
+def test_batched_rows_of_unequal_length(params):
+    prompts = [prompt_of(n, 10 + n) for n in (61, 90, 35)]  # 61 + 6 crosses a window's end while decoding
+    got = engine_for(params).generate(prompts)
+    assert got == [greedy_reference(params, p, NEW) for p in prompts]
+
+
+def test_verify_across_a_windows_end_is_the_vanilla_stream(params):
+    base = prompt_of(7, 3)
+    prompt = (base * 14)[:91]  # repeats: prompt lookup drafts; 91 + 6 crosses position 96
+    e = engine_for(params, speculative="prompt_lookup", spec_tokens=12)
+    assert e.generate([prompt]) == [greedy_reference(params, prompt, NEW)]
+    assert e.stats.spec_verify_steps > 0 and e.stats.family_counters["chunks_closed"] > 0
+
+
+def test_chunked_prefill_past_the_largest_bucket(params):
+    prompt = prompt_of(150, 4)  # > 96: two chunks of 96 through the ring, each in pieces of 24
+    assert engine_for(params).generate([prompt]) == [greedy_reference(params, prompt, NEW)]
+
+
+def test_score_exact_matches_reference_logits(params):
+    prompt, emitted = prompt_of(59, 5), prompt_of(8, 6)  # the scored positions straddle position 64
+    got = engine_for(params).score_exact(prompt, emitted)
+    logits = head0(reference(params, prompt + emitted))[len(prompt) - 1:-1]
+    assert list(got["argmax"]) == list(np.argmax(logits, -1))
+    np.testing.assert_allclose(got["max_logit"], logits.max(-1), atol=ATOL)
+    np.testing.assert_allclose(got["chosen_logit"], logits[np.arange(8), emitted], atol=ATOL)
+
+
+def test_fused_single_fetch_path(params):
+    e = engine_for(params)
+    a_ids, b_ids = np.asarray(prompt_of(5, 7), np.int32), np.asarray(prompt_of(4, 8), np.int32)
+    store = np.zeros((8, 30), np.int32)
+    lens = np.asarray([30, 22, 30, 27, 30, 30, 30, 30], np.int32)
+    for i in range(8):
+        store[i, :lens[i]] = prompt_of(int(lens[i]), 20 + i)
+    packed = jnp.asarray([[0.1, 0.2, 0.3, 3.0, 1.0, 6.0]], jnp.float32)  # dists | ids
+    got = e.generate_rag(a_ids, b_ids, packed, jnp.asarray(store), jnp.asarray(lens), n_chunks=2)
+    prompt = list(a_ids) + list(store[3, :27]) + list(store[1, :22]) + list(b_ids)
+    assert got == greedy_reference(params, [int(t) for t in prompt], NEW)
+
+
+def test_pallas_path_is_the_xla_path(params):
+    """The prefill kernel and the decode walk over the joined plane (both in
+    interpret mode) give the stream the XLA forms give, and the program
+    counts what the walk fetched."""
+    prompts = [prompt_of(n, 30 + n) for n in (93, 40)]
+    e = engine_for(params, attn_impl="pallas_interpret")
+    assert e.generate(prompts) == engine_for(params).generate(prompts)
+    counted = e.stats.family_counters
+    ring, pooled = counted["decode_ring_slots_fetched"], counted["decode_summary_slots_fetched"]
+    assert ring > 0 and pooled > 0
+    assert counted["decode_slots_attended_positions"] == ring + C * pooled
+
+
+# ---- (e) what the family cannot be served with refuses by name ----
+
+
+@pytest.mark.parametrize("overrides,mechanism", [
+    (dict(kv_quant="int8"), "kv_quant"),
+    (dict(weight_quant="int8"), "weight_quant"),
+    (dict(prefix_cache=PrefixCacheConfig(enabled=True)), "prefix cache"),
+    (dict(batching="continuous"), "continuous"),
+])
+def test_refusals_name_the_mechanism(params, overrides, mechanism):
+    with pytest.raises(NotImplementedError, match=mechanism):
+        engine_for(params, **overrides)
+
+
+def test_continuous_engine_and_tp_refuse(params):
+    from rag_llm_k8s_tpu.engine.continuous import ContinuousEngine
+
+    with pytest.raises(NotImplementedError, match="continuous engine"):
+        ContinuousEngine(CFG, params, sampling=GREEDY, engine_config=EngineConfig(), dtypes=FP32)
+    mesh = make_mesh(MeshConfig(dp=1, sp=1, tp=2), devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="tp=2"):
+        InferenceEngine(CFG, params, sampling=GREEDY, engine_config=EngineConfig(), dtypes=FP32, mesh=mesh)
+
+
+def test_the_family_row(params):
+    fam = families.of(CFG)
+    assert fam.counter_names == bwm.COUNTER_NAMES and fam.counters_width == 5
+    assert set(fam.counter_names) == set(bwm.fold_counters(np.zeros(5)))
+    assert fam.checkpoint_loader_refusal and "name map" in fam.checkpoint_loader_refusal
+    assert fam.verify_span is bwm.verify_span
+    from rag_llm_k8s_tpu.core.config import LlamaConfig
+
+    assert families.of(LlamaConfig.tiny()).verify_span is None
+    assert params["lm_head"].shape == (64, 8 * V) and params["layers_mu"].shape == (2, 4, 16)
+
+
+def test_config_refuses_what_the_layout_cannot_hold():
+    with pytest.raises(ValueError, match="whole number of chunks"):
+        BlockWindowConfig.tiny(window_size=30)
+    with pytest.raises(ValueError, match="multi-head"):
+        BlockWindowConfig.tiny(num_key_value_heads=2)
+    big = BlockWindowConfig()
+    assert (big.head_dim, big.chunks_per_window, big.num_pred_heads * big.vocab_size) == (128, 128, 2560)

@@ -272,7 +272,7 @@ def test_latent_moe_fine_scopes_sit_beneath_attn_and_mlp(latent_engine):
     leading dense layer stands outside the layers' loop and the MoE layers in
     it, which is what a prefill's rows are counted by."""
     assert set(tracing.FINE_SCOPES) == {"latent", "router", "experts", "shared", "zero", "dense",
-                                        "window", "global", "gate"}
+                                        "window", "global", "gate", "ring", "pool"}
     assert set(tracing.FINE_SCOPES) <= tracing.SCOPE_NAMES
     paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](latent_engine))]
     for phase in ("prefill", "decode"):
@@ -326,6 +326,40 @@ def test_windowed_moe_fine_scopes_sit_beneath_attn_and_mlp(windowed_engine):
         assert [p for p in looped if f"/periods/{sub}/" in p and f"/attn/{fine}/" in p], (sub, fine)
     assert not [p for p in looped if ("/periods/l2/" in p and "/attn/window/" in p)
                 or ("/periods/l0/" in p and "/attn/global/" in p)]
+
+
+@pytest.fixture(scope="module")
+def block_window_engine():
+    """The block-window pooled-summary family through the same programs."""
+    import dataclasses
+
+    from rag_llm_k8s_tpu.core.config import BlockWindowConfig
+    from rag_llm_k8s_tpu.models.block_window import init_block_window_params
+
+    cfg = BlockWindowConfig.tiny(vocab_size=300)
+    params = init_block_window_params(jax.random.PRNGKey(0), cfg, FP32)
+    ec = dataclasses.replace(EC, prefix_cache=PrefixCacheConfig(enabled=False), attn_impl="xla")
+    return InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_PROGRAMS))
+def test_block_window_programs_arrive_scoped(block_window_engine, name):
+    """Every operation of the fourth decoder family carries a phase."""
+    _assert_scoped(name, LATENT_PROGRAMS[name](block_window_engine))
+
+
+def test_block_window_fine_scopes_sit_beneath_attn(block_window_engine):
+    """``attn/ring`` and ``attn/pool`` are BENEATH ``attn`` in a prefill and in
+    a decode step; a prefill's ROWS are unrolled, each its own trip through
+    the layers' loop, so an operation of the loop is ONE loop deep in every
+    row (what ``benchmark/lib/phases.py`` counts a prefill's rows by)."""
+    paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](block_window_engine))]
+    for phase in ("prefill", "decode"):
+        for fine in ("ring", "pool"):
+            hits = [p for p in paths if f"/{phase}/" in p and f"/attn/{fine}/" in p]
+            assert hits and all(_scope(p) == (phase, "attn") for p in hits), (phase, fine)
+    looped = [p for p in paths if "/prefill/rows2/" in p and "/attn/ring/" in p]
+    assert looped and all(p.split("/prefill/rows2/")[1].split("/").count("while") == 1 for p in looped)
 
 
 def test_shortcut_block_arrives_scoped_with_its_own_fine_scopes():
