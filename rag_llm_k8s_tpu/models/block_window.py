@@ -206,7 +206,7 @@ class BlockWindowModel(nn.Module):
         with phase_scope("attn"):
             q, k, v = self._qkv(lp, x, jnp.arange(Sp, dtype=jnp.int32)[None])
             with phase_scope("pool"):
-                sk, sv = bw.pool_chunks(k[0], v[0], lp["mu"], lp["phi"], C)
+                sk, sv = bw.pool_chunks(k[0], v[0], lp["mu"], lp["phi"], C, impl)
             with phase_scope("ring"):  # the kernel's one softmax holds the summaries' part too
                 if impl == "xla":
                     o = bw.window_summary_attention_xla(q[0], k[0], v[0], sk, sv, window=W, chunk=C)
@@ -243,6 +243,9 @@ class BlockWindowModel(nn.Module):
             at = jnp.maximum(jnp.broadcast_to(at, (B,)) - kv_start, 0)  # slot -> position
         count_kernel_build(
             "prefill", "window_summary_attention_xla" if impl == "xla" else "window_summary_flash_attention")
+        row = (c.num_heads, Sp, c.head_dim)
+        count_kernel_build("prefill", "chunk_pool" if bw.pool_blocks(
+            row, c.chunk_size, self.dtypes.compute_dtype, impl) else "pool_chunks")
         outs = []
         for b in range(B):
             if b:  # this row starts when the one before it is in the planes
@@ -269,6 +272,9 @@ class BlockWindowModel(nn.Module):
         B = tokens.shape[0]
         NS = planes[0].shape[3] - W
         first, end = live_range(t, c, NS)
+        chunk_at, summary_at = NS + t % W // C * C, NS - 1 - t // C  # [B]: a row's chunk in the ring, its summary
+        count_kernel_build("decode", "chunk_pool_in_place" if bw.in_place_pool_serves(
+            planes[0].shape, C, planes[0].dtype, impl) else "pool_chunks")
         with phase_scope("embed"):
             h = jnp.take(params["embedding"], tokens, axis=0).astype(jnp.float32)
 
@@ -284,16 +290,9 @@ class BlockWindowModel(nn.Module):
                     k_plane = jax.lax.dynamic_update_slice(k_plane, k[b].astype(k_plane.dtype)[None, None], at)
                     v_plane = jax.lax.dynamic_update_slice(v_plane, v[b].astype(v_plane.dtype)[None, None], at)
 
-                with phase_scope("pool"):  # the chunk each row's position is of, from the ring
-                    size = (1, 1, c.num_heads, C, c.head_dim)
-                    for b in range(B):
-                        at = (li, b, 0, NS + t[b] % W // C * C, 0)
-                        sk, sv = bw.pool_chunks(jax.lax.dynamic_slice(k_plane, at, size)[0, 0],
-                                                jax.lax.dynamic_slice(v_plane, at, size)[0, 0],
-                                                lp["mu"], lp["phi"], C)
-                        at = (li, b, 0, NS - 1 - t[b] // C, 0)
-                        k_plane = jax.lax.dynamic_update_slice(k_plane, sk[None, None], at)
-                        v_plane = jax.lax.dynamic_update_slice(v_plane, sv[None, None], at)
+                with phase_scope("pool"):  # the chunk each row's position is of, from the ring: one call
+                    k_plane, v_plane = bw.pool_ring_chunks(
+                        k_plane, v_plane, lp["mu"], lp["phi"], li, chunk_at, summary_at, C, impl)
                 with phase_scope("ring"):  # one walk over the live summaries and the live ring
                     qd = q.transpose(0, 2, 1, 3)  # [B, 1, H, hd]
                     if impl == "xla":
@@ -308,7 +307,7 @@ class BlockWindowModel(nn.Module):
         return h, planes, jnp.stack([jnp.sum(t % C == C - 1), jnp.sum(t % W == W - 1)])
 
     # -- a chunk of positions a row over the planes ---------------------------
-    def _chunk(self, params, tokens, positions, t, live, planes):
+    def _chunk(self, params, tokens, positions, t, live, planes, impl):
         c, dt = self.config, self.dtypes
         W, C = c.window_size, c.chunk_size
         B, n = tokens.shape
@@ -321,6 +320,8 @@ class BlockWindowModel(nn.Module):
         fresh = (src >= 0) & (src < n) & jnp.take_along_axis(live, jnp.clip(src, 0, n - 1), axis=1)
         r0, lane, every = t0 % W, jnp.arange(n, dtype=jnp.int32), jnp.ones((nch,), bool)
         count_kernel_build("chunk", "ring_summary_chunk_attention_xla")
+        touched = (B, c.num_heads, nch * C, c.head_dim)
+        count_kernel_build("chunk", "chunk_pool" if bw.pool_blocks(touched, C, planes[0].dtype, impl) else "pool_chunks")
         with phase_scope("embed"):
             h = jnp.take(params["embedding"], tokens, axis=0).astype(jnp.float32)
 
@@ -343,7 +344,7 @@ class BlockWindowModel(nn.Module):
                         put = jnp.take_along_axis(new, jnp.clip(src, 0, n - 1)[:, None, :, None], axis=2)
                         return jnp.where(fresh[:, None, :, None], put.astype(rows.dtype), old)
 
-                    sk, sv = bw.pool_chunks(merged(rows_k, k), merged(rows_v, v), lp["mu"], lp["phi"], C)
+                    sk, sv = bw.pool_chunks(merged(rows_k, k), merged(rows_v, v), lp["mu"], lp["phi"], C, impl)
                     for b in range(B):
                         k_plane = _write_run(k_plane, li, b, NS - c0[b] - nch, sk[b, :, ::-1], every)
                         v_plane = _write_run(v_plane, li, b, NS - c0[b] - nch, sv[b, :, ::-1], every)
@@ -426,10 +427,10 @@ class BlockWindowModel(nn.Module):
             room = c.window_size - c.chunk_size
             m = max(d for d in range(1, min(S, room) + 1) if S % d == 0)
             if m == S:
-                h, planes, closed = self._chunk(params, tokens, positions, t, live, planes)
+                h, planes, closed = self._chunk(params, tokens, positions, t, live, planes, impl)
             else:
                 def piece(planes, xs):
-                    h, planes, closed = self._chunk(params, *xs, planes)
+                    h, planes, closed = self._chunk(params, *xs, planes, impl)
                     return planes, (h, closed)
 
                 cut = lambda a: a.reshape(B, S // m, m).swapaxes(0, 1)  # noqa: E731

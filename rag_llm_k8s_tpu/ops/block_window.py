@@ -23,12 +23,18 @@ Three forms, one rule:
   plane as it was BEFORE the chunk's writes plus the chunk's own keys (a
   verify step, a chunk of a chunked prefill, the exact scorer): a mask by
   the position every slot holds.
+
+The pooling is one rule at two call shapes: ``pool_chunks`` (rows of keys ->
+their summaries: a prompt row, a chunk call's touched chunks; the kernel
+``chunk_pool`` where the shape tiles, else the jnp body, which is the oracle)
+and ``pool_ring_chunks`` (a decode step: every row's current chunk, from the
+ring into its summary's slot, by ONE call of ``chunk_pool_in_place``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,26 +44,207 @@ from jax.experimental.pallas import tpu as pltpu
 from rag_llm_k8s_tpu.ops.attention import NEG_INF, _fit_block
 
 
-def pool_chunks(k: jax.Array, v: jax.Array, mu: jax.Array, phi: jax.Array, chunk: int
-                ) -> Tuple[jax.Array, jax.Array]:
+POOL_PIECE = 16  # chunks ``chunk_pool`` pools at once: 256 positions of k and of v, 64 float32 registers
+POOL_STEP = 2048  # positions of k and of v a grid step of ``chunk_pool`` holds (0.5 MB of bf16 each)
+
+
+def _chunk_weights(kc: jax.Array, vec: jax.Array) -> jax.Array:
+    """``softmax_j(k_j . vec)`` over a chunk's positions, logits unscaled:
+    ``kc [..., chunk, hd]`` float32, ``vec [..., 1, hd]`` -> ``[..., chunk, 1]``."""
+    s = jnp.sum(kc * vec.astype(jnp.float32), axis=-1, keepdims=True)
+    e = jnp.exp(s - jnp.max(s, axis=-2, keepdims=True))
+    return e / jnp.sum(e, axis=-2, keepdims=True)
+
+
+def _whole_tiles(rows: int, hd: int, dtype, impl: str) -> bool:
+    """Whether Mosaic can hold ``[rows, hd]`` of ``dtype`` as whole tiles (16
+    rows of bf16, 8 of float32, 128 lanes); the interpreter takes any shape."""
+    return impl == "pallas_interpret" or (rows % (32 // jnp.dtype(dtype).itemsize) == 0 and hd % 128 == 0)
+
+
+def pool_blocks(shape, chunk: int, dtype, impl: str) -> Optional[Tuple[int, int]]:
+    """``(positions a grid step, chunks a piece)`` by which ``chunk_pool`` takes
+    keys of ``shape [..., S, hd]``, or None where the jnp body serves: under
+    ``"xla"``, and, compiled, where a row's chunks are no whole number of
+    pieces of whole tiles (a chunk is read, ``[piece, hd]`` stored)."""
+    S, hd = shape[-2:]
+    per = S // chunk
+    piece = _fit_block(per, POOL_PIECE)
+    if impl == "xla" or not (_whole_tiles(piece, hd, dtype, impl) and _whole_tiles(chunk, hd, dtype, impl)):
+        return None
+    return chunk * piece * _fit_block(per // piece, max(1, POOL_STEP // (chunk * piece))), piece
+
+
+def pool_chunks(k: jax.Array, v: jax.Array, mu: jax.Array, phi: jax.Array, chunk: int,
+                impl: str = "xla") -> Tuple[jax.Array, jax.Array]:
     """One pooled key and value a chunk: ``k, v [..., H, n * chunk, hd]``
     (rotated keys) and ``mu, phi [H, hd]`` -> ``[..., H, n, hd]`` each, in
     ``k``'s type. ``k~ = sum_j softmax_j(k_j . mu) k_j``, ``v~ = sum_j
     softmax_j(k_j . phi) v_j``: both weightings read the KEYS, logits
-    unscaled, float32 throughout."""
+    unscaled, float32 throughout. Through the kernel ``chunk_pool`` where
+    ``pool_blocks`` gives it blocks; else the jnp body below (the oracle, the
+    CPU's form), whose float32 copies of ``k`` and ``v`` XLA WRITES OUT (``kc``
+    has three consumers): fit for a call's few chunks, not for a prompt row."""
     *lead, H, S, hd = k.shape
+    n = S // chunk
+    blocks = pool_blocks(k.shape, chunk, k.dtype, impl)
+    if blocks is not None:
+        sk, sv = chunk_pool(k.reshape(-1, S, hd), v.reshape(-1, S, hd), mu, phi, chunk=chunk, blocks=blocks,
+                            interpret=impl == "pallas_interpret")
+        return sk.reshape(*lead, H, n, hd), sv.reshape(*lead, H, n, hd)
     f32 = jnp.float32
-    # elementwise products and small reductions (16 positions a chunk): the
-    # float32 copies of k and v fuse into them and never exist in memory
-    kc = k.reshape(*lead, H, S // chunk, chunk, hd).astype(f32)
-    vc = v.reshape(*lead, H, S // chunk, chunk, hd).astype(f32)
-
-    def weights(vec):  # [..., H, n, chunk]
-        return jax.nn.softmax(jnp.sum(kc * vec.astype(f32)[:, None, None, :], axis=-1), axis=-1)
-
-    sk = jnp.sum(weights(mu)[..., None] * kc, axis=-2)
-    sv = jnp.sum(weights(phi)[..., None] * vc, axis=-2)
+    kc = k.reshape(*lead, H, n, chunk, hd).astype(f32)
+    vc = v.reshape(*lead, H, n, chunk, hd).astype(f32)
+    mu, phi = mu[:, None, None, :], phi[:, None, None, :]
+    sk = jnp.sum(_chunk_weights(kc, mu) * kc, axis=-2)
+    sv = jnp.sum(_chunk_weights(kc, phi) * vc, axis=-2)
     return sk.astype(k.dtype), sv.astype(v.dtype)
+
+
+def _chunk_pool_kernel(k_ref, v_ref, mu_ref, phi_ref, sk_ref, sv_ref, *, chunk: int, piece: int):
+    """One grid step: ``k_ref, v_ref [1, bs, hd]`` of one row, ``mu_ref,
+    phi_ref [1, 1, hd]`` its head's -> ``sk_ref, sv_ref [1, bs // chunk,
+    hd]``, a piece of ``piece`` chunks at a time: float32 exists only of a
+    piece, in registers and VMEM."""
+    bs, hd = k_ref.shape[1:]
+    n = piece * chunk
+
+    def pooled(j, carry):
+        at, to = pl.multiple_of(j * n, n), pl.multiple_of(j * piece, piece)
+        kc = k_ref[0, pl.ds(at, n), :].astype(jnp.float32).reshape(piece, chunk, hd)
+        vc = v_ref[0, pl.ds(at, n), :].astype(jnp.float32).reshape(piece, chunk, hd)
+        sk_ref[0, pl.ds(to, piece), :] = jnp.sum(_chunk_weights(kc, mu_ref[...]) * kc, axis=-2).astype(sk_ref.dtype)
+        sv_ref[0, pl.ds(to, piece), :] = jnp.sum(_chunk_weights(kc, phi_ref[...]) * vc, axis=-2).astype(sv_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, bs // n, pooled, None)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "blocks", "interpret"))
+def chunk_pool(k, v, mu, phi, *, chunk: int, blocks: Tuple[int, int], interpret: bool = False):
+    """The pooling kernel: ``k, v [N, S, hd]`` (``N`` = rows x heads,
+    head-minor: the prefill kernel's own operands), ``mu, phi [H, hd]`` ->
+    ``sk, sv [N, S // chunk, hd]`` in ``k``'s type. One pass over ``k`` and
+    ``v`` as they are stored; ``blocks`` are ``pool_blocks``'s."""
+    N, S, hd = k.shape
+    H = mu.shape[0]
+    bs, piece = blocks
+    kv = pl.BlockSpec((1, bs, hd), lambda n, j: (n, j, 0))
+    vec = pl.BlockSpec((1, 1, hd), lambda n, j: (n % H, 0, 0))
+    out = pl.BlockSpec((1, bs // chunk, hd), lambda n, j: (n, j, 0))
+    return pl.pallas_call(
+        functools.partial(_chunk_pool_kernel, chunk=chunk, piece=piece),
+        grid=(N, S // bs),
+        in_specs=[kv, kv, vec, vec],
+        out_specs=[out, out],
+        out_shape=[jax.ShapeDtypeStruct((N, S // chunk, hd), k.dtype), jax.ShapeDtypeStruct((N, S // chunk, hd), v.dtype)],
+        compiler_params=None if interpret else pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="chunk_pool",
+    )(k, v, mu[:, None], phi[:, None])
+
+
+def _chunk_pool_in_place_kernel(layer_ref, src_ref, dst_ref, mu_ref, phi_ref, k_in, v_in, k_plane, v_plane,
+                                kbuf, vbuf, skbuf, svbuf, sem, *, chunk: int):
+    """Every row ``b`` of layer ``layer_ref[0]``'s planes ``[L, B, H, P, hd]``
+    (left in HBM; ``k_in, v_in`` are the same buffers): the chunk at slots
+    ``[src, src + chunk)`` pooled into slot ``dst``. A slot of bf16 shares a
+    word with its neighbour, so what is written back is the aligned run of
+    ``chunk`` slots around ``dst`` with the one row replaced. All the rows'
+    reads are in flight before the first is pooled."""
+    del k_in, v_in
+    B = kbuf.shape[0]
+    P = k_plane.shape[3]
+    layer = layer_ref[0]
+    planes = ((k_plane, kbuf, skbuf), (v_plane, vbuf, svbuf))
+
+    def slots(b):  # the ring chunk's first slot, the run's, and the summary's place in the run
+        dst = jnp.clip(dst_ref[b], 0, P - 1)
+        ring = jnp.clip(src_ref[b], 0, P - chunk) // chunk * chunk
+        return pl.multiple_of(ring, chunk), pl.multiple_of(dst // chunk * chunk, chunk), dst % chunk
+
+    def reads(b):
+        ring, run, _ = slots(b)
+        return [pltpu.make_async_copy(plane.at[layer, b, :, pl.ds(at, chunk), :], buf.at[b], sem.at[2 * i + j, b])
+                for i, (plane, *bufs) in enumerate(planes) for j, (at, buf) in enumerate(zip((ring, run), bufs))]
+
+    def writes(b):
+        run = slots(b)[1]
+        return [pltpu.make_async_copy(runbuf.at[b], plane.at[layer, b, :, pl.ds(run, chunk), :], sem.at[4 + i, b])
+                for i, (plane, _, runbuf) in enumerate(planes)]
+
+    for b in range(B):
+        for c in reads(b):
+            c.start()
+    for b in range(B):
+        for c in reads(b):
+            c.wait()
+        kc, vc = kbuf[b].astype(jnp.float32), vbuf[b].astype(jnp.float32)  # [H, chunk, hd]
+        sk = jnp.sum(_chunk_weights(kc, mu_ref[...]) * kc, axis=-2, keepdims=True)
+        sv = jnp.sum(_chunk_weights(kc, phi_ref[...]) * vc, axis=-2, keepdims=True)
+        row = jax.lax.broadcasted_iota(jnp.int32, kc.shape, 1) == slots(b)[2]
+        skbuf[b] = jnp.where(row, sk, skbuf[b].astype(jnp.float32)).astype(skbuf.dtype)
+        svbuf[b] = jnp.where(row, sv, svbuf[b].astype(jnp.float32)).astype(svbuf.dtype)
+        for c in writes(b):
+            c.start()
+    for b in range(B):
+        for c in writes(b):
+            c.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def chunk_pool_in_place(k_plane, v_plane, mu, phi, layer, src, dst, *, chunk: int, interpret: bool = False):
+    """The pooling kernel of a decode step: planes ``[L, B, H, P, hd]``, ``mu,
+    phi [H, hd]``, ``src, dst [B]`` -> the planes with, in every row of
+    ``layer``, slot ``dst`` holding the pooled chunk ``[src, src + chunk)``
+    (``src`` and ``P`` multiples of ``chunk``). One call for all the rows, in
+    place: the planes alias the outputs."""
+    L, B, H, P, hd = k_plane.shape
+    vec = pl.BlockSpec((H, 1, hd), lambda i, *_: (0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((B, H, chunk, hd), k_plane.dtype)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_chunk_pool_in_place_kernel, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(1,), in_specs=[vec, vec, hbm, hbm], out_specs=[hbm, hbm],
+            scratch_shapes=[buf, buf, buf, buf, pltpu.SemaphoreType.DMA((6, B))]),
+        out_shape=[jax.ShapeDtypeStruct(k_plane.shape, k_plane.dtype), jax.ShapeDtypeStruct(v_plane.shape, v_plane.dtype)],
+        input_output_aliases={5: 0, 6: 1},
+        interpret=interpret,
+        name="chunk_pool_in_place",
+    )(i32(layer).reshape(1), i32(src), i32(dst), mu[:, None], phi[:, None], k_plane, v_plane)
+
+
+def in_place_pool_serves(shape, chunk: int, dtype, impl: str) -> bool:
+    """Whether ``chunk_pool_in_place`` takes planes of ``shape [L, B, H, P,
+    hd]``: not under ``"xla"``; runs of ``chunk`` slots must tile the plane
+    and, compiled, be whole tiles; the rows' four buffers must fit VMEM."""
+    L, B, H, P, hd = shape
+    return (impl != "xla" and P % chunk == 0 and _whole_tiles(chunk, hd, dtype, impl)
+            and 4 * B * H * chunk * hd * jnp.dtype(dtype).itemsize <= 8 << 20)
+
+
+def pool_ring_chunks(k_plane, v_plane, mu, phi, layer, src, dst, chunk: int, impl: str = "xla"):
+    """A decode step's pooling: in every row ``b`` of ``layer``'s planes ``[L,
+    B, H, P, hd]``, the chunk at slots ``[src[b], src[b] + chunk)`` (the ring's,
+    with the position just written) pooled into slot ``dst[b]`` (its summary's).
+    One kernel call for all the rows where ``in_place_pool_serves``; else the
+    rows' chunks gathered, pooled once by the jnp body and written a row at a time."""
+    if in_place_pool_serves(k_plane.shape, chunk, k_plane.dtype, impl):
+        return chunk_pool_in_place(k_plane, v_plane, mu, phi, layer, src, dst, chunk=chunk,
+                                   interpret=impl == "pallas_interpret")
+    L, B, H, P, hd = k_plane.shape
+    rows = range(B)
+    size = (1, 1, H, chunk, hd)
+    sk, sv = pool_chunks(
+        jnp.concatenate([jax.lax.dynamic_slice(k_plane, (layer, b, 0, src[b], 0), size)[0] for b in rows]),
+        jnp.concatenate([jax.lax.dynamic_slice(v_plane, (layer, b, 0, src[b], 0), size)[0] for b in rows]),
+        mu, phi, chunk)
+    for b in rows:
+        k_plane = jax.lax.dynamic_update_slice(k_plane, sk[b][None, None], (layer, b, 0, dst[b], 0))
+        v_plane = jax.lax.dynamic_update_slice(v_plane, sv[b][None, None], (layer, b, 0, dst[b], 0))
+    return k_plane, v_plane
 
 
 def _softmax_av(s: jax.Array, ok: jax.Array, v: jax.Array) -> jax.Array:

@@ -221,18 +221,109 @@ def test_prefill_kernel_is_the_dense_form():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want)[:, :80], atol=2e-6)
 
 
-def test_pooling_is_the_softmax_weighted_sum():
+def pooled_by_hand(k, v, mu, phi, chunk):
+    """``[..., H, S, hd]`` float64 numpy -> the two pooled planes, a chunk at a time."""
+    *lead, H, S, hd = k.shape
+    sk, sv = np.zeros((*lead, H, S // chunk, hd)), np.zeros((*lead, H, S // chunk, hd))
+    for at in np.ndindex(*lead, H, S // chunk):
+        h, c = at[-2], at[-1]
+        kc, vc = k[at[:-1]][chunk * c:chunk * (c + 1)], v[at[:-1]][chunk * c:chunk * (c + 1)]
+        wk, wv = np.exp(kc @ mu[h]), np.exp(kc @ phi[h])
+        sk[at], sv[at] = (wk / wk.sum()) @ kc, (wv / wv.sum()) @ vc
+    return sk, sv
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_pooling_is_the_softmax_weighted_sum(impl):
     rng = np.random.default_rng(1)
     k, v = (rng.standard_normal((2, 8, 16)) for _ in range(2))
     mu, phi = rng.standard_normal((2, 16)), rng.standard_normal((2, 16))
-    sk, sv = bw.pool_chunks(jnp.asarray(k, jnp.float32), jnp.asarray(v, jnp.float32), jnp.asarray(mu, jnp.float32),
-                            jnp.asarray(phi, jnp.float32), 4)
-    for h in range(2):
-        for c in range(2):
-            kc, vc = k[h, 4 * c:4 * c + 4], v[h, 4 * c:4 * c + 4]
-            wk, wv = np.exp(kc @ mu[h]), np.exp(kc @ phi[h])
-            np.testing.assert_allclose(np.asarray(sk)[h, c], (wk / wk.sum()) @ kc, atol=1e-5)
-            np.testing.assert_allclose(np.asarray(sv)[h, c], (wv / wv.sum()) @ vc, atol=1e-5)
+    assert (bw.pool_blocks(k.shape, 4, jnp.float32, impl) is None) == (impl == "xla")
+    sk, sv = bw.pool_chunks(*(jnp.asarray(a, jnp.float32) for a in (k, v, mu, phi)), 4, impl)
+    want_k, want_v = pooled_by_hand(k, v, mu, phi, 4)
+    np.testing.assert_allclose(np.asarray(sk), want_k, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(sv), want_v, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape,dtype,why", [
+    ((4, 96, 16), "float32", "a prompt row of three windows: pieces of 8 chunks, one grid step a head"),
+    ((4, 2560, 16), "float32", "a row of 80 windows: 40 pieces of 16 chunks in 5 grid steps a head"),
+    ((4, 100, 16), "float32", "25 chunks a row: pieces of one chunk"),
+    ((3, 4, 4, 16), "float32", "a batch of rows' single chunks"),
+    ((2, 4, 28, 16), "float32", "a chunk call's merged rows: 7 chunks a row"),
+    ((4, 256, 16), "bfloat16", "bf16 keys and values against the float32 oracle"),
+])
+def test_the_pooling_kernel_is_the_jnp_body_at_the_served_shapes(shape, dtype, why):
+    rng = np.random.default_rng(2)
+    k, v = (rng.standard_normal(shape) for _ in range(2))
+    mu, phi = rng.standard_normal((4, 16)) / 2, rng.standard_normal((4, 16)) / 2
+    args = [jnp.asarray(a, dtype) for a in (k, v, mu, phi)]
+    bs, piece = bw.pool_blocks(shape, C, dtype, "pallas_interpret")
+    assert bs % (piece * C) == 0 and shape[-2] % bs == 0
+    got = bw.pool_chunks(*args, C, "pallas_interpret")
+    assert all(g.dtype == dtype and g.shape == (*shape[:-2], shape[-2] // C, 16) for g in got)
+    # the oracle on the values the kernel was given, in float64: nothing but the OUTPUT's rounding may differ
+    exact = pooled_by_hand(*(np.asarray(a.astype(jnp.float32), np.float64) for a in args), C)
+    for g, b, e in zip(got, bw.pool_chunks(*args, C), exact):
+        if dtype == "bfloat16":
+            assert np.abs(np.asarray(g.astype(jnp.float32)) - e).max() <= 2.0 ** -8 * np.abs(e).max()
+        else:
+            np.testing.assert_allclose(np.asarray(g), e, atol=2e-6)
+            np.testing.assert_allclose(np.asarray(g), np.asarray(b), atol=2e-6)
+
+
+def test_compiled_the_kernel_takes_whole_tiles_only():
+    """What Mosaic stores is ``[piece, hd]`` by whole tiles: a prompt row and a
+    scorer's 2032 positions go through the kernel, a verify step's few chunks
+    and a toy width through the jnp body; ``"xla"`` never asks."""
+    bf16 = jnp.bfloat16
+    assert bw.pool_blocks((32, 20480, 128), 16, bf16, "pallas") == (2048, 16)
+    assert bw.pool_blocks((8, 32, 2048, 128), 16, bf16, "pallas") == (2048, 16)
+    assert bw.pool_blocks((1, 32, 48, 128), 16, bf16, "pallas") is None
+    assert bw.pool_blocks((4, 96, 16), 4, jnp.float32, "pallas") is None
+    assert bw.pool_blocks((32, 20480, 128), 16, bf16, "xla") is None
+    plane = (8, 8, 32, 1408 + 2048, 128)
+    assert bw.in_place_pool_serves(plane, 16, bf16, "pallas") and not bw.in_place_pool_serves(plane, 16, bf16, "xla")
+    assert not bw.in_place_pool_serves((8, 64, 32, 3456, 128), 16, bf16, "pallas")  # 64 rows' buffers: 16 MB
+    assert not bw.in_place_pool_serves((2, 3, 4, 72, 16), 4, jnp.float32, "pallas")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_decode_steps_batched_pooling_leaves_the_planes_the_per_row_form_does(dtype):
+    """Rows at different ``t % chunk`` (and in different windows): one kernel
+    call a layer for all of them writes the summaries the per-row form writes,
+    and nothing else."""
+    rng = np.random.default_rng(3)
+    NS, B, H, hd = 40, 5, 4, 16
+    planes = [jnp.asarray(rng.standard_normal((2, B, H, NS + W, hd)), dtype) for _ in range(2)]
+    mu, phi = (jnp.asarray(rng.standard_normal((H, hd)) / 2, dtype) for _ in range(2))
+    t = jnp.asarray([0, 5, 38, 63, 159], jnp.int32)  # t % 4 = 0, 1, 2, 3, 3; windows 0, 0, 1, 1, 4
+    src, dst = NS + t % W // C * C, NS - 1 - t // C
+    want = list(planes)
+    for b in range(B):  # the parent's lines: a row at a time
+        at = (1, b, 0, int(src[b]), 0)
+        sk, sv = bw.pool_chunks(*(jax.lax.dynamic_slice(p, at, (1, 1, H, C, hd))[0, 0] for p in want), mu, phi, C)
+        want = [jax.lax.dynamic_update_slice(p, s[None, None], (1, b, 0, int(dst[b]), 0)) for p, s in zip(want, (sk, sv))]
+    for impl in ("xla", "pallas_interpret"):
+        assert bw.in_place_pool_serves(planes[0].shape, C, dtype, impl) == (impl != "xla")
+        got = jax.jit(lambda kp, vp: bw.pool_ring_chunks(kp, vp, mu, phi, jnp.int32(1), src, dst, C, impl))(*planes)
+        for g, w, p in zip(got, want, planes):
+            g, w, p = (np.asarray(a.astype(jnp.float32)) for a in (g, w, p))
+            np.testing.assert_allclose(g, w, atol=2e-6 if dtype == "float32" else 2.0 ** -8 * np.abs(w).max())
+            changed = np.argwhere((g != p).any(axis=(2, 4)))  # (layer, row, slot)
+            assert sorted(map(tuple, changed)) == sorted((1, b, int(dst[b])) for b in range(B))
+
+
+def test_the_model_counts_the_pooling_form_it_built(params):
+    from rag_llm_k8s_tpu.obs import tracing
+
+    before = tracing.kernel_builds()
+    tokens = prompt_of(34, 8)
+    (got,), _ = through_the_cache(params, [tokens], 32, [32], impl="pallas_interpret")
+    np.testing.assert_allclose(got, reference(params, tokens), atol=ATOL)
+    built = {key for key, n in tracing.kernel_builds().items() if n > before.get(key, 0)}
+    assert {("prefill", "chunk_pool"), ("decode", "chunk_pool_in_place")} <= built
+    assert not {("prefill", "pool_chunks"), ("decode", "pool_chunks")} & built
 
 
 def test_the_live_range_is_one_run_of_slots():

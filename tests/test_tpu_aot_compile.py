@@ -284,6 +284,35 @@ CASES.update({
 })
 
 
+def _pool(shape):
+    from rag_llm_k8s_tpu.ops import block_window as bw
+
+    def fn(k, v, mu, phi):
+        return bw.pool_chunks(k, v, mu, phi, 16, "pallas")
+
+    return fn, [(shape, BF16), (shape, BF16), ((32, HD), BF16), ((32, HD), BF16)]
+
+
+def _pool_in_place():
+    from rag_llm_k8s_tpu.ops import block_window as bw
+
+    def fn(k_plane, v_plane, mu, phi, layer, src, dst):
+        return bw.pool_ring_chunks(k_plane, v_plane, mu, phi, layer, src, dst, 16, "pallas")
+
+    plane = ((8, 8, 32, 1408 + 2048, HD), BF16)
+    return fn, [plane, plane, ((32, HD), BF16), ((32, HD), BF16), ((), I32), ((8,), I32), ((8,), I32)]
+
+
+# the block-window family's pooling at the byte-model cell's shapes: a prompt
+# row of 20480 positions (32 heads, 1280 chunks of 16 each), a scorer's 2032
+# positions over a batch of 8, and a decode step's eight rows in the planes
+CASES.update({
+    "chunk_pool[a prompt row]": _pool((32, 20480, HD)),
+    "chunk_pool[a chunk call, 128 chunks a row]": _pool((8, 32, 2048, HD)),
+    "chunk_pool_in_place[batch 8]": _pool_in_place(),
+})
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, uncached):
     fn, args = CASES[name]
@@ -318,6 +347,12 @@ def test_kernel_compiles_for_v5e(name, one_chip, uncached):
         # pass's keys takes this compiler 18 s a program)
         assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
         assert "output_to_operand_aliasing" in text and not re.search(r" sort\(", text), name
+    if name.startswith("chunk_pool"):
+        # by name, inside the default scoped VMEM, no float32 of the operands'
+        # size beside it; the in-place form's planes alias its results
+        assert f"%{name.split('[')[0]}" in text and '"scoped_memory_configs":[]' in text, name
+        assert not re.search(r"f32\[(8,)?32,\d{4,},128\]", text), f"{name}: a float32 copy of the keys"
+        assert ("output_to_operand_aliasing" in text) == ("in_place" in name), name
     if re.match(r"(mla_)?decode_attention", name):
         # the benchmark finds the decode kernels by name and by result shape
         # (``mla_decode_attention_roofline``: [rows, heads, rank]; the phases'
@@ -478,7 +513,7 @@ def test_block_window_programs_compile_with_their_kernels(one_chip, uncached):
         return jax.jit(fn).lower(params, *args).compile().as_text()
 
     text = compiled(eng._make_gen(2, 4096, 8), tok(2, 4096), tok(2, 4096), rng)
-    for kernel in ("%window_summary_flash_attention", "%ring_summary_decode_attention"):
+    for kernel in ("%window_summary_flash_attention", "%ring_summary_decode_attention", "%chunk_pool.", "%chunk_pool_in_place"):
         assert kernel in text, f"{kernel}: not in the batched generate program"
     assert "%decode_attention" not in text  # the walk carries the family's name here
     text = compiled(eng._make_gen_spec(4096, 8), tok(1, 4096), tok(1, 4096), rng)
