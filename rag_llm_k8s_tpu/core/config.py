@@ -608,6 +608,89 @@ class BlockWindowConfig:
 
 
 @dataclass(frozen=True)
+class HybridSSMConfig:
+    """The hybrid state-space decoder family (``models/hybrid_ssm.py``): most
+    layers mix tokens by a SELECTIVE STATE-SPACE recurrence (a depthwise
+    causal convolution of ``mamba_d_conv`` taps, then a state ``[d_inner,
+    mamba_d_state]`` a row carried from position to position in float32,
+    with RMS norms on the time step, ``B`` and ``C`` projections), and every
+    ``attn_layer_period``-th layer (those with ``i % attn_layer_period ==
+    attn_layer_offset``) by grouped-query attention WITHOUT any position
+    term. Every layer then has a dense SwiGLU. A state layer keeps no keys or
+    values: its state has no position axis and is overwritten in place. Field
+    names are the published ``config.json``'s.
+
+    Defaults are the published widths and depth of the 3B model."""
+
+    vocab_size: int = 65536
+    hidden_size: int = 2560
+    intermediate_size: int = 8192
+    num_hidden_layers: int = 28
+    num_attention_heads: int = 20
+    num_key_value_heads: int = 1
+    attn_layer_period: int = 14
+    attn_layer_offset: int = 7
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 160
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    rms_norm_eps: float = 1e-6
+    max_seq_len: int = 262144
+    tie_word_embeddings: bool = True
+    bos_token_id: int = 1
+    eos_token_ids: Tuple[int, ...] = (2,)
+
+    def __post_init__(self):
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size is a whole number of heads")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads is a whole number of groups of num_key_value_heads")
+        if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+            raise ValueError("attn_layer_offset names a layer of the period")
+        if self.mamba_proj_bias or not self.mamba_conv_bias:
+            raise ValueError("this family's state layers have a bias on the convolution and on no projection")
+        if self.mamba_d_conv < 2:
+            raise ValueError("mamba_d_conv: the convolution keeps at least one earlier input")
+
+    # the names the rest of the program reads a decoder's sizes by
+    num_layers = property(lambda self: self.num_hidden_layers)
+    num_heads = property(lambda self: self.num_attention_heads)
+    num_kv_heads = property(lambda self: self.num_key_value_heads)
+    head_dim = property(lambda self: self.hidden_size // self.num_attention_heads)
+    d_inner = property(lambda self: self.mamba_expand * self.hidden_size)
+
+    def is_attention(self, layer: int) -> bool:
+        return layer % self.attn_layer_period == self.attn_layer_offset
+
+    @property
+    def attention_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.num_hidden_layers) if self.is_attention(i))
+
+    @property
+    def num_attention_layers(self) -> int:
+        return len(self.attention_layers)
+
+    @property
+    def num_state_layers(self) -> int:
+        return self.num_hidden_layers - self.num_attention_layers
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 256, **overrides) -> "HybridSSMConfig":
+        """Miniature config for CPU tests: 8 layers, attention at 1 and 5,
+        4 query heads over 1 KV head of 16, d_inner 128, state 16, dt rank 4."""
+        base = dict(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=128, num_hidden_layers=8,
+            num_attention_heads=4, num_key_value_heads=1, attn_layer_period=4, attn_layer_offset=1,
+            mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=4, max_seq_len=512,
+            bos_token_id=1, eos_token_ids=(2,),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
 class EncoderConfig:
     """Bidirectional encoder config for the embedding model.
 

@@ -479,10 +479,13 @@ class InferenceEngine:
         repeat of the trailing ``n``-gram — through the offset-causal
         chunked model (ONE forward ≈ one decode step's weight traffic),
         then keeps the longest accepted proposal prefix plus one correction
-        token. Rejected proposals cost nothing to undo: the KV frontier
-        simply doesn't advance over their slots, and later iterations
-        overwrite them (the same windowed-mask machinery chunked prefill
-        already relies on).
+        token. Rejected proposals cost nothing to undo where the cache is by
+        position: the KV frontier simply doesn't advance over their slots,
+        and later iterations overwrite them (the same windowed-mask machinery
+        chunked prefill already relies on). A family whose cache holds state
+        that is overwritten in place gives ``Family.commit``: its verify
+        model leaves every fed position's state, and the loop tells the cache
+        how many positions it kept.
 
         Acceptance rule per position ``j`` with proposal ``x``:
         - **greedy** (``do_sample=False``): accept iff ``x`` equals the
@@ -512,7 +515,8 @@ class InferenceEngine:
         — shared with the device-assembled RAG variant."""
         cfg, dt = self.config, self.dtypes
         model = self.model
-        mc = self.model_chunked
+        commit = self.family.commit
+        mc = self.model_chunked if commit is None else self.model_chunked.copy(keep_steps=True)
         sampling = self.sampling
         sampled = sampling.do_sample and sampling.temperature > 0.0
         n = max(1, self.engine_config.spec_ngram)
@@ -639,6 +643,8 @@ class InferenceEngine:
                 m_eff = jnp.minimum(jnp.minimum(m, eos_pos), max_new - e - 1)
                 if span is not None:  # nothing is kept past what was written
                     m_eff = jnp.minimum(m_eff, writes - 1)
+                if commit is not None:  # the pending token and m_eff proposals were kept
+                    cache = commit(cache, m_eff + 1)
                 emit = j_idx <= m_eff
                 out_idx = e + j_idx  # unique lanes (slack-padded buffer)
                 out_row = out[0].at[out_idx].set(

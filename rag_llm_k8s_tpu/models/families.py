@@ -13,7 +13,9 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
-from rag_llm_k8s_tpu.core.config import BlockWindowConfig, LatentMoEConfig, LlamaConfig, WindowedMoEConfig
+from rag_llm_k8s_tpu.core.config import (
+    BlockWindowConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig, WindowedMoEConfig,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,6 +42,10 @@ class Family:
     # from ``position`` on may write the cache (None: all of them; a ring
     # cannot take back a write past its window's end)
     verify_span: Optional[Callable] = None
+    # (cache, kept) -> cache: after a verify step (the model built with
+    # ``keep_steps=True``), how many of the fed positions the loop KEPT (None:
+    # the frontier does the job; a state overwritten in place must be told)
+    commit: Optional[Callable] = None
 
 
 def _llama_model(config, dtypes, engine_config, mesh, *, fused: bool, quantized: bool):
@@ -214,8 +220,54 @@ def _block_window() -> Family:
     )
 
 
+def _hybrid_ssm() -> Family:
+    from rag_llm_k8s_tpu.models import hybrid_ssm as hs
+    from rag_llm_k8s_tpu.parallel.sharding import hybrid_ssm_param_specs
+
+    def unsupported(engine_config, mesh, engine):
+        if engine == "continuous" or getattr(engine_config, "batching", "coalesce") == "continuous":
+            return ("the continuous engine (batching='continuous') or its paged KV pool",
+                    "a recurrent state has no blocks to page, and preemption, resume and a per-row "
+                    "frontier need snapshots of it that nothing takes yet; use 'coalesce'")
+        pc = getattr(engine_config, "prefix_cache", None)
+        if pc is not None and pc.enabled:
+            return ("the KV prefix cache (prefix_cache.enabled)",
+                    "a recurrent state can be reused only for an exact prefix, and only if a snapshot "
+                    "was kept at its end: a spliced segment's keys and values say nothing of it")
+        if engine_config.kv_quant != "bf16":
+            return (f"kv_quant={engine_config.kv_quant!r}",
+                    "the state is float32 and the attention layers' planes have no int8 form here")
+        if engine_config.weight_quant != "bf16":
+            return (f"weight_quant={engine_config.weight_quant!r}",
+                    "quantize_llama_params does not know this tree (leaves stacked by layer kind, "
+                    "float32 A_log, D and time-step bias)")
+        if mesh is not None and (mesh.tp > 1 or getattr(mesh, "sp", 1) > 1):
+            return (f"tp={mesh.tp}, sp={getattr(mesh, 'sp', 1)}",
+                    "this tree has no partition rules (one KV head cannot be split, and a scan over "
+                    "a sequence split across chips hands its state from chip to chip)")
+        return None
+
+    return Family(
+        name="the hybrid state-space family (HybridSSMConfig)",
+        build_model=lambda config, dtypes, engine_config, mesh, *, fused, quantized: hs.HybridSSMModel(
+            config, dtypes, attn_impl=engine_config.attn_impl),
+        make_cache=lambda config, batch_size, max_seq_len, dtype, quant: hs.make_hybrid_cache(
+            config, batch_size, max_seq_len, dtype),
+        param_specs=hybrid_ssm_param_specs,
+        counters_width=hs.N_COUNTERS,
+        counter_names=hs.COUNTER_NAMES,
+        fold_counters=hs.fold_counters,
+        unsupported=unsupported,
+        checkpoint_loader_refusal=(
+            "the checkpoint loader has no name map for the hybrid state-space family's "
+            "tensors; serve it through assemble_service with a parameter tree of your own"),
+        commit=hs.commit,
+    )
+
+
 # configuration type -> its family (a thunk where building it imports the model)
 _TABLE: Tuple[Tuple[type, Callable[[], Family]], ...] = (
+    (HybridSSMConfig, _hybrid_ssm),
     (BlockWindowConfig, _block_window),
     (WindowedMoEConfig, _windowed_moe),
     (LatentMoEConfig, _latent_moe),
@@ -244,5 +296,5 @@ def refuse_unsupported(config, engine_config, mesh, *, engine: str = "one-shot")
 
 
 def make_cache(config, batch_size: int, max_seq_len: int, dtype=jnp.bfloat16, quant: str = "bf16"):
-    """A fresh cache of the family's kind (per-head K/V planes, or latents)."""
+    """A fresh cache of the family's kind (per-head K/V planes, latents, a ring, a state)."""
     return of(config).make_cache(config, batch_size, max_seq_len, dtype, quant)

@@ -316,6 +316,30 @@ def roofline_for_block_window(cfg, *, peak_tflops: float, hbm_gbs: float) -> Roo
         peak_tflops=peak_tflops, hbm_gbs=hbm_gbs)
 
 
+def roofline_for_hybrid_ssm(cfg, *, peak_tflops: float, hbm_gbs: float) -> RooflineModel:
+    """The roofline of the hybrid state-space family, from a
+    ``HybridSSMConfig``'s fields (duck-typed). A token's matmuls: every
+    layer's SwiGLU, a state layer's four projections, an attention layer's
+    four, the head. ``kv_bytes_per_token`` is what one more position of
+    context costs a decode step to read: the attention layers' keys and
+    values only (a state layer's state does not grow with the context; its
+    bytes, read and written once a step, ride ``weight_bytes``)."""
+    D, F, Di = cfg.hidden_size, cfg.intermediate_size, cfg.d_inner
+    N, R = cfg.mamba_d_state, cfg.mamba_dt_rank
+    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    ffn = 3 * D * F
+    state = 2 * D * Di + Di * (R + 2 * N) + R * Di + Di * D
+    attention = 2 * D * H * hd + 2 * D * K * hd
+    M, Na = cfg.num_state_layers, cfg.num_attention_layers
+    head = D * cfg.vocab_size
+    params = cfg.num_layers * ffn + M * state + Na * attention + head
+    state_bytes = M * 2 * (4 * N * Di + 2 * (cfg.mamba_d_conv - 1) * Di)  # read and written a step
+    return RooflineModel(
+        flops_per_token=2.0 * params, weight_bytes=2.0 * params + state_bytes,
+        kv_bytes_per_token=2.0 * Na * 2 * K * hd,
+        peak_tflops=peak_tflops, hbm_gbs=hbm_gbs)
+
+
 def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger":
     """THE ledger constructor both serving engines share (duck-typed over
     the config dataclasses — still no package imports). One site means the
@@ -337,14 +361,16 @@ def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger"
             hbm_gbs = hbm_gbs if hbm_gbs > 0 else kind_gbs
         # the family is told by what the configuration HAS (no package
         # imports here): a latent cache's rank, layers of several kinds, a
-        # pooled summary every chunk, or per-head K/V alike in every layer
+        # pooled summary every chunk, a recurrent state, or per-head K/V alike in every layer
         roofline = roofline_for_latent_moe(
             model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
         ) if hasattr(model_config, "kv_lora_rank") else roofline_for_windowed_moe(
             model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
         ) if hasattr(model_config, "layer_types") else roofline_for_block_window(
             model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
-        ) if hasattr(model_config, "chunk_size") else roofline_for_llama(
+        ) if hasattr(model_config, "chunk_size") else roofline_for_hybrid_ssm(
+            model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
+        ) if hasattr(model_config, "mamba_d_state") else roofline_for_llama(
             model_config.num_layers, model_config.hidden_size,
             model_config.num_heads, model_config.num_kv_heads,
             model_config.head_dim, model_config.intermediate_size,

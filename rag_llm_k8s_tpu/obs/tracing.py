@@ -81,11 +81,15 @@ SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
 # (models/block_window.py) opens ``attn/ring`` around attention over a
 # window's exact keys and the pooled summaries of the windows before it (one
 # softmax, one call) and ``attn/pool`` around the pooling of chunks into
-# summaries. A
+# summaries. The hybrid state-space family (models/hybrid_ssm.py) opens
+# ``attn/scan`` around a state layer's selective scan (the kernel or the XLA
+# form; in a decode step the one-position update and the contraction with C),
+# ``attn/conv`` around its causal convolution and the roll of that state, and
+# ``attn/global`` around its attention layers' attention. A
 # reader that files an operation under the first sub-scope it knows keeps
 # reading ``attn`` and ``mlp``; one that knows these sees the finer split.
 FINE_SCOPES = ("latent", "router", "experts", "shared", "zero", "dense", "window", "global", "gate",
-               "ring", "pool")
+               "ring", "pool", "scan", "conv")
 SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES + FINE_SCOPES)
 
 

@@ -139,6 +139,11 @@ def block_window_param_specs(params, ctx: MeshContext):
     return _replicated_specs(params, ctx, "block-window pooled-summary")
 
 
+def hybrid_ssm_param_specs(params, ctx: MeshContext):
+    """The same for the ``HybridSSMModel`` layout (leaves stacked by layer kind)."""
+    return _replicated_specs(params, ctx, "hybrid state-space")
+
+
 def shard_params(params, specs, ctx: MeshContext):
     """Place a param pytree on the mesh per its spec tree.
 

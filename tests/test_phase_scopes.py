@@ -272,7 +272,7 @@ def test_latent_moe_fine_scopes_sit_beneath_attn_and_mlp(latent_engine):
     leading dense layer stands outside the layers' loop and the MoE layers in
     it, which is what a prefill's rows are counted by."""
     assert set(tracing.FINE_SCOPES) == {"latent", "router", "experts", "shared", "zero", "dense",
-                                        "window", "global", "gate", "ring", "pool"}
+                                        "window", "global", "gate", "ring", "pool", "scan", "conv"}
     assert set(tracing.FINE_SCOPES) <= tracing.SCOPE_NAMES
     paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](latent_engine))]
     for phase in ("prefill", "decode"):
@@ -360,6 +360,46 @@ def test_block_window_fine_scopes_sit_beneath_attn(block_window_engine):
             assert hits and all(_scope(p) == (phase, "attn") for p in hits), (phase, fine)
     looped = [p for p in paths if "/prefill/rows2/" in p and "/attn/ring/" in p]
     assert looped and all(p.split("/prefill/rows2/")[1].split("/").count("while") == 1 for p in looped)
+
+
+@pytest.fixture(scope="module")
+def hybrid_ssm_engine():
+    """The hybrid state-space family through the same programs."""
+    import dataclasses
+
+    from rag_llm_k8s_tpu.core.config import HybridSSMConfig
+    from rag_llm_k8s_tpu.models.hybrid_ssm import init_hybrid_ssm_params
+
+    cfg = HybridSSMConfig.tiny(vocab_size=300, num_hidden_layers=4, attn_layer_period=2)
+    params = init_hybrid_ssm_params(jax.random.PRNGKey(0), cfg, FP32)
+    ec = dataclasses.replace(EC, prefix_cache=PrefixCacheConfig(enabled=False), attn_impl="xla")
+    return InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_PROGRAMS))
+def test_hybrid_ssm_programs_arrive_scoped(hybrid_ssm_engine, name):
+    """Every operation of the fifth decoder family carries a phase: the
+    verify loop's ``commit`` among them."""
+    _assert_scoped(name, LATENT_PROGRAMS[name](hybrid_ssm_engine))
+
+
+def test_hybrid_ssm_fine_scopes_sit_beneath_attn(hybrid_ssm_engine):
+    """``attn/scan``, ``attn/conv`` (a state layer) and ``attn/global`` (an
+    attention layer) are BENEATH ``attn`` in a prefill and in a decode step.
+    Layers of both kinds are trips of ONE loop: the mixers are two loops deep
+    in a decode step (the step loop, the layers' loop) and one in a prefill,
+    in a branch; the norms and the SwiGLU stand in the layers' loop outside
+    any branch, which is what ``benchmark/lib/phases.py`` counts a prefill's
+    rows by."""
+    paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](hybrid_ssm_engine))]
+    for phase in ("prefill", "decode"):
+        for fine in ("scan", "conv", "global"):
+            hits = [p for p in paths if f"/{phase}/" in p and "/attn/" in p and f"/{fine}/" in p.split("/attn/")[1]]
+            assert hits and all(_scope(p) == (phase, "attn") for p in hits), (phase, fine)
+            assert all("/cond/" in p or "/branch_" in p for p in hits), (phase, fine)
+    looped = [p for p in paths if "/prefill/rows2/" in p and "/mlp/" in p]
+    assert looped and all(p.split("/prefill/rows2/")[1].split("/").count("while") == 1 for p in looped)
+    assert not any("/cond/" in p or "/branch_" in p for p in looped)
 
 
 def test_shortcut_block_arrives_scoped_with_its_own_fine_scopes():
