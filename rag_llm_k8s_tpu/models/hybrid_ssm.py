@@ -31,6 +31,14 @@ reaches it. Two rules follow.
   pad slot (``slot < kv_start[row]``) the convolution's input is forced to 0
   and the time step to 0 (``exp(0 A) = 1``, ``0 u B = 0``), so the first real
   token sees the zero history and zero state a row alone starts from.
+  A fresh prompt call of which only the last position's logits leave does
+  not compute the pads that EVERY row has: the state layers' matmuls, norms
+  and convolution and every SwiGLU run on the token suffix ``[off, S)``,
+  ``off`` the largest rung of ``live_rungs(S)`` (nothing, or a quarter of the
+  bucket) that is <= every row's ``kv_start``, chosen on the device
+  (``live_trip`` below says why the result is the same; the scan kernel,
+  which passes a pad's chunk already, and the few attention layers keep the
+  bucket's shape). Every other call computes what it is fed.
 - *A verify step keeps some of what it fed.* The frontier takes back a
   rejected position's keys by not advancing; a state cannot be taken back.
   The model built with ``keep_steps`` (the verify loop's) leaves the state
@@ -46,6 +54,7 @@ layer is one loop beneath its phase whatever the pattern of kinds.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import flax
@@ -54,17 +63,26 @@ import jax
 import jax.numpy as jnp
 
 from rag_llm_k8s_tpu.core.config import DTypePolicy, HybridSSMConfig
-from rag_llm_k8s_tpu.models.llama import attend, resolve_attn_impl, rms_norm
+from rag_llm_k8s_tpu.models.llama import (
+    _rows_from,
+    _set_rows_from,
+    attend,
+    live_offsets,
+    resolve_attn_impl,
+    rms_norm,
+)
 from rag_llm_k8s_tpu.obs.tracing import count_kernel_build, phase_scope
 from rag_llm_k8s_tpu.ops import ssm as ssm_ops
 from rag_llm_k8s_tpu.ops.attention import decode_slots_streamed, gqa_decode_step
 
 # HybridCache.counters. A fresh multi-token call at a time: token rows the
-# layers' matmuls ran on and token rows of the padded batch (models/llama.py's
-# names). A single-token step through the decode kernel at a time: the slots
-# an attention layer's walk fetches over the rows, and rows x the slots
-# allocated (both attention layers fetch the same: a step counts once). A
-# multi-token call at a time: row-positions the scan ran over, pads included.
+# state layers' and the SwiGLUs' matmuls ran on (rows x the live suffix where
+# the call took a rung of ``live_rungs``, else rows x the bucket) and token
+# rows of the padded batch (models/llama.py's names). A single-token step
+# through the decode kernel at a time: the slots an attention layer's walk
+# fetches over the rows, and rows x the slots allocated (both attention
+# layers fetch the same: a step counts once). A multi-token call at a time:
+# row-positions the scan was handed, pads included (it passes their chunks).
 # A single-token step at a time: states written, rows x state layers. And
 # what ``commit`` was told: positions a verify step fed, and kept.
 COUNTER_NAMES = ("prefill_tokens_computed", "prefill_tokens_bucketed",
@@ -129,6 +147,35 @@ def commit(cache: HybridCache, kept: jax.Array) -> HybridCache:
 
 def _at(stacked: jax.Array, index) -> jax.Array:
     return jax.lax.dynamic_index_in_dim(stacked, index, 0, keepdims=False)
+
+
+_SMALL = ("conv_w", "conv_b", "dt_norm", "b_norm", "c_norm", "dt_bias", "A_log", "D")
+
+
+def _small_leaves(sp: dict, mi) -> dict:
+    """State layer ``mi``'s leaves that no matmul streams (a few KB each)."""
+    return {name: _at(sp[name], mi) for name in _SMALL}
+
+
+def live_rungs(S: int) -> Tuple[int, ...]:
+    """The leading tokens a fresh ``S``-token call of this family may skip:
+    every second rung of ``models/llama.py live_offsets`` (nothing, or a
+    quarter of the bucket). Quarters and not eighths by measurement (PERF.md,
+    PR 41): a rung is a copy of a trip's matmuls, norms and convolution in
+    every fresh-prompt executable, nine of them a deployment, and warm on one
+    machine their tracing, lowering and loading cost ``setup_s`` 2 s of 67 at
+    quarters and 7.5 s at eighths, against a bound of a tenth. (Of a four-way
+    ``lax.switch`` the compiler also copies the residual stream in the third
+    branch, twice a trip; a two-way conditional writes in place.)"""
+    return live_offsets(S)[::2]
+
+
+def _switch_rung(rung: jax.Array, offsets: Tuple[int, int], fn, *operands):
+    """``fn(off, *operands)`` for the rung's ``off`` of the two ``offsets``,
+    behind the barrier of ``models/llama.py _switch_live`` (it keeps what
+    reads the result out of the branches)."""
+    whole, suffix = (functools.partial(fn, off) for off in offsets)
+    return jax.lax.optimization_barrier(jax.lax.cond(rung > 0, suffix, whole, *operands))
 
 
 def _mm(x, w, out=None):
@@ -214,12 +261,12 @@ class HybridSSMModel(nn.Module):
                 o = attend(q, k, v, kv_start, kv_len, ai, mode="prefill", impl=impl)
         return _mm(o.reshape(B, S, H * hd), _at(ap["wo"], ai)), (k_plane, v_plane)
 
-    def _state_mixer(self, sp, mi, x, conv, ssm, start, impl, keep):
-        """The state-space mixer of state layer ``mi`` on ``x [B, S, D]``,
-        from the state in ``conv[mi]`` / ``ssm[mi]``; ``start [B]``: indices
-        of ``x`` in front of it are pads. Returns the mixer's output, the
-        convolution's run of inputs (its last ``d_conv - 1`` rows are the new
-        history), the last state and (``keep``) every position's."""
+    def _scan_operands(self, sp, small, mi, x, history, start):
+        """What the selective scan of state layer ``mi`` reads of ``x [B, S,
+        D]``: ``(u, delta, z, B, C)``, and the convolution's run of inputs
+        from ``history`` on (its last ``d_conv - 1`` rows are the new
+        history); ``start [B]``: indices of ``x`` in front of it are pads.
+        ``small``: the layer's ``_small_leaves``."""
         c, dt = self.config, self.dtypes
         Di, N, R = c.d_inner, c.mamba_d_state, c.mamba_dt_rank
         S = x.shape[1]
@@ -227,20 +274,34 @@ class HybridSSMModel(nn.Module):
         live = (jnp.arange(S, dtype=jnp.int32)[None, :] >= start[:, None])[..., None]
         u, z = jnp.where(live, xz[..., :Di], 0), xz[..., Di:]
         with phase_scope("conv"):
-            u, run = ssm_ops.causal_conv(u, _at(conv, mi), _at(sp["conv_w"], mi), _at(sp["conv_b"], mi))
+            u, run = ssm_ops.causal_conv(u, history, small["conv_w"], small["conv_b"])
         dbc = _mm(u, _at(sp["x_proj"], mi), jnp.float32)
         eps = c.rms_norm_eps
-        delta = _rms(dbc[..., :R], _at(sp["dt_norm"], mi), eps).astype(dt.compute_dtype)
-        Bm = _rms(dbc[..., R:R + N], _at(sp["b_norm"], mi), eps)
-        Cm = _rms(dbc[..., R + N:], _at(sp["c_norm"], mi), eps)
-        delta = _mm(delta, _at(sp["dt_proj"], mi))
-        A = -jnp.exp(_at(sp["A_log"], mi))
-        args = (u, delta, z, A, Bm, Cm, _at(sp["D"], mi), _at(sp["dt_bias"], mi), _at(ssm, mi), start)
+        delta = _rms(dbc[..., :R], small["dt_norm"], eps).astype(dt.compute_dtype)
+        Bm = _rms(dbc[..., R:R + N], small["b_norm"], eps)
+        Cm = _rms(dbc[..., R + N:], small["c_norm"], eps)
+        return (u, _mm(delta, _at(sp["dt_proj"], mi)), z, Bm, Cm), run
+
+    def _scan(self, small, operands, h0, start, impl, keep=False):
+        """``(y, last state, every position's under keep)`` of a state
+        layer's recurrence over ``_scan_operands``' five, from ``h0``."""
+        u, delta, z, Bm, Cm = operands
+        A = -jnp.exp(small["A_log"])
+        args = (u, delta, z, A, Bm, Cm, small["D"], small["dt_bias"], h0, start)
         with phase_scope("scan"):
             if keep:
-                y, last, steps = ssm_ops.selective_scan_xla(*args, keep_steps=True)
-            else:
-                (y, last), steps = ssm_ops.selective_scan(*args, impl=impl), None
+                return ssm_ops.selective_scan_xla(*args, keep_steps=True)
+            return ssm_ops.selective_scan(*args, impl=impl) + (None,)
+
+    def _state_mixer(self, sp, mi, x, conv, ssm, start, impl, keep):
+        """The state-space mixer of state layer ``mi`` on ``x [B, S, D]``,
+        from the state in ``conv[mi]`` / ``ssm[mi]``; ``start [B]``: indices
+        of ``x`` in front of it are pads. Returns the mixer's output, the
+        convolution's run of inputs (its last ``d_conv - 1`` rows are the new
+        history), the last state and (``keep``) every position's."""
+        small = _small_leaves(sp, mi)
+        operands, run = self._scan_operands(sp, small, mi, x, _at(conv, mi), start)
+        y, last, steps = self._scan(small, operands, _at(ssm, mi), start, impl, keep)
         return _mm(y, _at(sp["out_proj"], mi)), run, last, steps
 
     @nn.compact
@@ -265,6 +326,15 @@ class HybridSSMModel(nn.Module):
         keep = self.keep_steps and S > 1
         mode = "decode" if S == 1 else "chunk" if self.chunked else "prefill"
         count_kernel_build(mode, "selective_scan_xla" if keep else ssm_ops.scan_form(S, impl))
+        # a fresh prompt call of which one position's logits leave runs its
+        # layers on the live suffix (``live_trip``); the rung is the batch's
+        # smallest left pad, read here on the device
+        fresh = mode == "prefill" and not self.keep_steps and last_logit_only
+        offsets = live_rungs(S) if fresh else ()
+        rung, skipped = None, 0
+        if offsets:
+            rung = jnp.minimum(jnp.min(start) // offsets[1], 1).astype(jnp.int32)
+            skipped = rung * offsets[1]
 
         counters = cache.counters
         add = jnp.zeros_like(counters)
@@ -278,7 +348,8 @@ class HybridSSMModel(nn.Module):
         else:
             add = add.at[_AT["ssm_positions_scanned"]].set(B * S)
             if not self.chunked:
-                add = add.at[_AT["prefill_tokens_computed"]].set(B * S).at[_AT["prefill_tokens_bucketed"]].set(B * S)
+                add = add.at[_AT["prefill_tokens_computed"]].set(B * (S - skipped))
+                add = add.at[_AT["prefill_tokens_bucketed"]].set(B * S)
         counters = counters + add
 
         with phase_scope("embed"):
@@ -327,8 +398,89 @@ class HybridSSMModel(nn.Module):
                 h = h + _mm(y, lp["w_down"])
             return (h, state), None
 
-        (h, state), _ = jax.lax.scan(
-            layer, (h, state), (params["layers"], jnp.arange(c.num_layers, dtype=jnp.int32)))
+        def live_trip(carry, i):
+            """``layer`` with the state layers' matmuls, norms and convolution
+            and every SwiGLU on ``h[:, o:]``, ``o`` the rung's offset: indices
+            in front of it are pads in EVERY row, and nothing reads a pad's
+            output (``last_logit_only``), so the result is ``layer``'s. A
+            state layer forces a pad's convolution input and time step to 0:
+            the convolution's history at ``o`` is zeros (``o`` > ``d_conv -
+            1`` pads stand in front of it), and the scan, which runs at the
+            bucket's shape on buffers whose rows in front of ``o`` stay the
+            zeros they start as, passes the pads' chunks as it always did.
+            The two attention layers run the bucket.
+
+            What PR 28 found in ``models/llama.py`` holds here (PERF.md): a
+            branch reads the STACKED leaves at the trip's index inside the
+            matmul that streams them (sliced by the scan in front of a
+            conditional, a layer's weights are copied first); the residual
+            stream and the scan's operands keep the bucket's shape through
+            the loop and a branch writes its suffix rows in place; the
+            kernels stand outside the rungs' branches, so a program traces
+            and lowers each ONCE (in a branch a rung, the scan kernel alone
+            cost every fresh-prompt program 4 s of set-up: PERF.md, PR 41);
+            and the writes into the stacked ``conv`` / ``ssm`` stand outside
+            every branch, where ``benchmark/lib/phases.py`` counts a
+            prefill's rows: by the median executions of the instructions
+            whose scope path holds no branch. The trip's small leaves are
+            sliced there too, behind a barrier: sliced in a branch, the
+            compiler's relayouts of them carry the operand's path, which
+            holds no branch either, and two of those beside the two writes
+            read 23.1 rows for 24 (PERF.md, PR 41)."""
+            h, (k_plane, v_plane, conv, ssm), operands = carry
+            layers, sp = params["layers"], params["ssm"]
+            before = (i - off + P - 1) // P
+            mi = i - before  # on an attention trip the NEXT state layer's: its slices are written back as read
+            taps = c.mamba_d_conv - 1
+            small, norm_in, norm_ff = jax.lax.optimization_barrier(
+                (_small_leaves(sp, mi), _at(layers["input_norm"], i), _at(layers["ff_norm"], i)))
+
+            def project(o, h, *operands):
+                x = rms_norm(_rows_from(h, o), norm_in, c.rms_norm_eps, dt)
+                history = jnp.zeros((B, taps, c.d_inner), x.dtype) if o else _at(conv, mi)
+                new, run = self._scan_operands(sp, small, mi, x, history, start - o)
+                history = jax.lax.slice_in_dim(run, S - o, S - o + taps, axis=1)
+                return tuple(_set_rows_from(buf, o, rows.astype(buf.dtype)) for buf, rows in zip(operands, new)), history
+
+            def project_out(o, h, y):
+                return _set_rows_from(h, o, _rows_from(h, o) + _mm(_rows_from(y, o), _at(sp["out_proj"], mi)))
+
+            def state_space(h, planes, operands):
+                operands, history = _switch_rung(rung, offsets, project, h, *operands)
+                y, last, _ = self._scan(small, operands, _at(ssm, mi), start, impl)
+                h = _switch_rung(rung, offsets, project_out, h, y)
+                return h, planes, history.astype(conv.dtype), last, operands
+
+            def attention(h, planes, operands):
+                x = rms_norm(h, norm_in, c.rms_norm_eps, dt)
+                out, planes = self._attention(params["attn"], before, x, planes, kv_start, kv_len, wi, impl)
+                return h + out, planes, _at(conv, mi), _at(ssm, mi), operands
+
+            def add_ffn(o, h):
+                hs = _rows_from(h, o)
+                x = rms_norm(hs, norm_ff, c.rms_norm_eps, dt)
+                y = nn.silu(_mm(x, _at(layers["w_gate"], i))) * _mm(x, _at(layers["w_up"], i))
+                return _set_rows_from(h, o, hs + _mm(y, _at(layers["w_down"], i)))
+
+            with phase_scope("attn"):
+                if c.num_attention_layers:
+                    h, planes, history, last, operands = jax.lax.cond(
+                        i % P == off, attention, state_space, h, (k_plane, v_plane), operands)
+                else:
+                    h, planes, history, last, operands = state_space(h, (k_plane, v_plane), operands)
+                conv, ssm = put(conv, mi, history), put(ssm, mi, last)
+            with phase_scope("mlp"):
+                h = _switch_rung(rung, offsets, add_ffn, h)
+            return (h, planes + (conv, ssm), operands), None
+
+        trips = jnp.arange(c.num_layers, dtype=jnp.int32)
+        if offsets:
+            cd, f32 = dt.compute_dtype, jnp.float32
+            operands = tuple(jnp.zeros((B, S, n), t) for n, t in (
+                (c.d_inner, cd), (c.d_inner, cd), (c.d_inner, cd), (c.mamba_d_state, f32), (c.mamba_d_state, f32)))
+            (h, state, _), _ = jax.lax.scan(live_trip, (h, state, operands), trips)
+        else:
+            (h, state), _ = jax.lax.scan(layer, (h, state), (params["layers"], trips))
         new_cache = HybridCache(*state[:4], counters, *state[4:])
 
         with phase_scope("norm_rope"):

@@ -35,6 +35,7 @@ from rag_llm_k8s_tpu.core.config import (
 from rag_llm_k8s_tpu.core.mesh import make_mesh
 from rag_llm_k8s_tpu.engine.engine import InferenceEngine
 from rag_llm_k8s_tpu.models import families, hybrid_ssm as hs
+from rag_llm_k8s_tpu.models.llama import live_offsets
 from rag_llm_k8s_tpu.ops import ssm
 
 FP32 = DTypePolicy.fp32()
@@ -394,3 +395,158 @@ def test_tensor_parallel_is_refused_by_name():
     assert family.commit is hs.commit and family.verify_span is None
     assert "name map" in family.checkpoint_loader_refusal
     assert families.of(LlamaConfig.tiny()).commit is None  # a frontier does the job there
+
+
+# ---- (g) a fresh prompt call computes the live suffix of a left-padded bucket ----
+
+LIVE_S = 1280  # the smallest kind of bucket with rungs (``hs.live_rungs``: every second of llama's eighths): 0, 320
+
+
+def _no_rungs(monkeypatch):
+    """The parent's program, for a reference: steered here, in the test; the
+    program has no option for it."""
+    monkeypatch.setattr(hs, "live_offsets", lambda S: ())
+
+
+def _rung_offset(kv_start) -> int:
+    offsets = live_offsets(LIVE_S)[::2]  # from llama's own: ``_no_rungs`` patches the name this family reads
+    return offsets[min(int(min(kv_start)) // offsets[1], len(offsets) - 1)]
+
+
+def live_prefill(lens, impl="xla", tied=True, steps=0, seed=0):
+    """The engine's fresh prompt call on rows of ``lens`` tokens left-padded
+    to ``LIVE_S``, then ``steps`` decode steps on tokens of the seed:
+    ``(last-position logits, cache, kv_start, [a step's logits])``."""
+    cfg, params = (CFG, PARAMS[True]) if tied else (UNTIED, PARAMS[False])
+    B, lens = len(lens), np.asarray(lens)
+    rng = np.random.default_rng(seed)
+    tokens = np.zeros((B, LIVE_S), np.int32)
+    for b, n in enumerate(lens):
+        tokens[b, LIVE_S - n:] = rng.integers(3, V, n)
+    kv_start = jnp.asarray(np.where(lens > 0, LIVE_S - lens, 0), jnp.int32)  # mask_window reads 0 for an empty row
+    positions = jnp.maximum(jnp.arange(LIVE_S)[None, :] - kv_start[:, None], 0)
+    model = hs.HybridSSMModel(cfg, FP32, attn_impl=impl)
+
+    @jax.jit
+    def prompt(tokens):
+        cache = hs.make_hybrid_cache(cfg, B, LIVE_S + 128, jnp.float32)
+        return model.apply({"params": params}, tokens, positions, cache, kv_start,
+                           jnp.full((B,), LIVE_S, jnp.int32), jnp.int32(0), last_logit_only=True)
+
+    step = jax.jit(lambda tok, pos, cache, t: model.apply(
+        {"params": params}, tok, pos, cache, kv_start, jnp.broadcast_to(LIVE_S + t + 1, (B,)), LIVE_S + t))
+    logits, cache = prompt(jnp.asarray(tokens))
+    stepped = []
+    for t in range(steps):
+        tok = jnp.asarray(rng.integers(3, V, (B, 1)), jnp.int32)
+        out, cache = step(tok, jnp.asarray(lens + t, jnp.int32)[:, None], cache, jnp.int32(t))
+        stepped.append(np.asarray(out[:, 0]))
+    return np.asarray(logits[:, -1]), cache, np.asarray(kv_start), stepped
+
+
+def _assert_same_behind_kv_start(got, want, kv_start, live):
+    """The state, and the attention planes on every slot a row can ever read."""
+    for name in ("conv", "ssm"):
+        np.testing.assert_allclose(np.asarray(getattr(got, name))[:, live], np.asarray(getattr(want, name))[:, live],
+                                   atol=ATOL, err_msg=name)
+    for name in ("k", "v"):
+        a, b = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
+        for row in live:  # [attention layers, B, K, T, hd]
+            np.testing.assert_allclose(a[:, row, :, kv_start[row]:], b[:, row, :, kv_start[row]:], atol=ATOL, err_msg=name)
+
+
+@pytest.mark.parametrize("lens,impl,tied", [
+    ((900,), "xla", True), ((900,), "xla", False),
+    ((700, 1000, 1279), "xla", True), ((700, 1000, 1279), "xla", False),
+    ((900, 850), "pallas_interpret", True),
+], ids=lambda v: {True: "tied", False: "untied"}.get(v, str(v)))
+def test_suffix_prefill_equals_full_prefill(lens, impl, tied, monkeypatch):
+    """The same last-position logits, the same state and planes behind every
+    row's ``kv_start``, the same four decode steps after it; the batch's
+    SMALLEST pad governs (a row of 1279 tokens beside one of 700 skips
+    nothing), and the counters say what was computed."""
+    got, cache, kv_start, steps = live_prefill(lens, impl, tied, steps=4)
+    _no_rungs(monkeypatch)
+    want, ref, _, ref_steps = live_prefill(lens, impl, tied, steps=4)
+    np.testing.assert_allclose(got, want, atol=ATOL)
+    _assert_same_behind_kv_start(cache, ref, kv_start, range(len(lens)))
+    for a, b in zip(steps, ref_steps):
+        np.testing.assert_allclose(a, b, atol=ATOL)
+    off = _rung_offset(kv_start)
+    assert off == {(900,): 320, (700, 1000, 1279): 0, (900, 850): 320}[lens]
+    B = len(lens)
+    counted, plain = hs.fold_counters(np.asarray(cache.counters)), hs.fold_counters(np.asarray(ref.counters))
+    assert (counted["prefill_tokens_computed"], counted["prefill_tokens_bucketed"]) == (B * (LIVE_S - off), B * LIVE_S)
+    assert counted["ssm_positions_scanned"] == B * LIVE_S  # the scan is handed the bucket and passes the pads' chunks
+    assert plain["prefill_tokens_computed"] == plain["prefill_tokens_bucketed"] == plain["ssm_positions_scanned"] == B * LIVE_S
+
+
+@pytest.mark.parametrize("lens, off", [
+    ((100,), 320),  # shorter than the shortest suffix: the rung clamps, still right
+    ((LIVE_S,), 0),  # kv_start 0 (a full bucket): the full branch
+    ((0, 900), 0),  # mask_window reads 0 for a row with no valid slot: the safe side
+    ((1, 900), 320),  # a batch-padding row (one token at slot S - 1) does not move the minimum
+], ids=["short_prompt_clamps", "kv_start_0_full_branch", "empty_row_full_branch", "padding_row"])
+def test_which_rung_a_batch_takes(lens, off, monkeypatch):
+    got, cache, kv_start, _ = live_prefill(lens)
+    assert _rung_offset(kv_start) == off
+    counted = hs.fold_counters(np.asarray(cache.counters))
+    assert (counted["prefill_tokens_computed"], counted["prefill_tokens_bucketed"]) == (
+        len(lens) * (LIVE_S - off), len(lens) * LIVE_S)
+    _no_rungs(monkeypatch)
+    want, ref, _, _ = live_prefill(lens)
+    live = [i for i, n in enumerate(lens) if n]  # an empty row's logits and state are nobody's
+    np.testing.assert_allclose(got[live], want[live], atol=ATOL)
+    _assert_same_behind_kv_start(cache, ref, kv_start, live)
+
+
+def test_only_a_fresh_prompt_call_branches(params):
+    """The scorer (every position's logits leave), a chunk over the cache, a
+    verify step (``keep_steps``), a decode step and a bucket without rungs
+    trace the mixers' ``cond`` and no other: the programs the parent built.
+    A fresh prompt call at a bucket with rungs traces the rungs too."""
+    def conds(model, s, **kw):
+        cache = hs.make_hybrid_cache(CFG, 1, 2 * LIVE_S, jnp.float32)
+        z = jnp.zeros((1, s), jnp.int32)
+        jaxpr = jax.make_jaxpr(lambda p: model.apply(
+            {"params": p}, z, z, cache, jnp.zeros((1,), jnp.int32),
+            jnp.full((1,), s, jnp.int32), jnp.int32(0), **kw))(params)
+        return str(jaxpr).count(" cond[")
+
+    plain = hs.HybridSSMModel(CFG, FP32, attn_impl="xla")
+    assert conds(plain, LIVE_S, last_logit_only=True) > 1
+    assert conds(plain, LIVE_S) == 1  # the scorer: every position leaves
+    assert conds(plain, 1024, last_logit_only=True) == 1
+    assert conds(plain, 1, last_logit_only=True) == 1
+    assert conds(plain.copy(chunked=True), LIVE_S, last_logit_only=True) == 1
+    assert conds(plain.copy(chunked=True, keep_steps=True), LIVE_S, last_logit_only=True) == 1
+    assert conds(plain.copy(keep_steps=True), LIVE_S, last_logit_only=True) == 1
+
+
+def test_engine_counts_what_the_suffix_prefill_did(params, monkeypatch):
+    """Three prompts ride a batch of four: the padding row (one token at slot
+    S - 1) does not move the minimum, 1280 - 900 = 380 -> the rung at 320;
+    the speculative program opens with the same call."""
+    ec = dict(prompt_buckets=(LIVE_S,), max_seq_len=LIVE_S + 128, max_chunked_prompt=2 * LIVE_S)
+    prompts = [prompt_of(n, n) for n in (700, 800, 900)]
+    repeat = [repeating(600, 7, 31)]  # 1280 - 600 = 680 -> the last rung, 320
+    spec = dict(ec, speculative="prompt_lookup", spec_tokens=5, spec_ngram=2)
+
+    def served():
+        engine = engine_for(params, **ec)
+        out = [engine.generate(prompts), engine.generate(prompts[:1])]
+        counted = {k: v for k, v in engine.stats.family_counters.items() if k.startswith("prefill_")}
+        lookup = engine_for(params, **spec)
+        out.append(lookup.generate(repeat))
+        assert lookup.stats.spec_verify_steps > 0
+        return out, counted, lookup.stats.family_counters["prefill_tokens_computed"]
+
+    got, counted, spec_computed = served()
+    assert counted == {"prefill_tokens_computed": 4 * (LIVE_S - 320) + (LIVE_S - 320),
+                       "prefill_tokens_bucketed": 5 * LIVE_S}
+    assert spec_computed == LIVE_S - 320
+    _no_rungs(monkeypatch)
+    want, plain, spec_plain = served()
+    assert got == want
+    assert plain == {"prefill_tokens_computed": 5 * LIVE_S, "prefill_tokens_bucketed": 5 * LIVE_S}
+    assert spec_plain == LIVE_S

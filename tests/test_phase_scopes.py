@@ -388,9 +388,10 @@ def test_hybrid_ssm_fine_scopes_sit_beneath_attn(hybrid_ssm_engine):
     attention layer) are BENEATH ``attn`` in a prefill and in a decode step.
     Layers of both kinds are trips of ONE loop: the mixers are two loops deep
     in a decode step (the step loop, the layers' loop) and one in a prefill,
-    in a branch; the norms and the SwiGLU stand in the layers' loop outside
-    any branch, which is what ``benchmark/lib/phases.py`` counts a prefill's
-    rows by."""
+    in a branch; at this bucket, which has no rungs, the norms and the SwiGLU
+    stand in the layers' loop outside any branch, which is what
+    ``benchmark/lib/phases.py`` counts a prefill's rows by (a bucket with
+    rungs: ``test_a_hybrid_live_suffix_prefill_still_counts_its_rows``)."""
     paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](hybrid_ssm_engine))]
     for phase in ("prefill", "decode"):
         for fine in ("scan", "conv", "global"):
@@ -400,6 +401,50 @@ def test_hybrid_ssm_fine_scopes_sit_beneath_attn(hybrid_ssm_engine):
     looped = [p for p in paths if "/prefill/rows2/" in p and "/mlp/" in p]
     assert looped and all(p.split("/prefill/rows2/")[1].split("/").count("while") == 1 for p in looped)
     assert not any("/cond/" in p or "/branch_" in p for p in looped)
+
+
+def test_a_hybrid_live_suffix_prefill_still_counts_its_rows(tmp_path):
+    """The twin of ``test_a_live_suffix_prefill_still_counts_its_rows`` for
+    the hybrid state-space family: at a bucket with rungs both norms, the
+    mixer and the SwiGLU of a trip stand in branches (``models/hybrid_ssm.py
+    live_trip``: the kind of mixer, and inside it the rung), and what stays
+    outside every branch is the write of the layer's new state into the
+    stacked ``conv`` / ``ssm``. The benchmark's reader needs ONE such
+    operation a trip, not all of them: it still reads the rows, and the fine
+    scopes still sit beneath ``attn``."""
+    import dataclasses
+
+    from benchmark.lib import phases, ssm_scopes, trace
+    from rag_llm_k8s_tpu.core.config import HybridSSMConfig
+    from rag_llm_k8s_tpu.models.hybrid_ssm import init_hybrid_ssm_params
+
+    cfg = HybridSSMConfig.tiny(vocab_size=300, num_hidden_layers=4, attn_layer_period=2)
+    params = init_hybrid_ssm_params(jax.random.PRNGKey(0), cfg, FP32)
+    ec = dataclasses.replace(EC, prompt_buckets=(1280,), max_seq_len=1408, attn_impl="xla",
+                             prefix_cache=PrefixCacheConfig(enabled=False))
+    eng = InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+    prompts = [list(range(5, 295)) * 3, list(range(7, 297)) * 3][:2]  # 870 tokens: 1280 - 870 = 410 -> the rung at 320
+    eng.generate(prompts)  # compiled outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    for _ in range(2):
+        eng.generate(prompts)
+    jax.profiler.stop_trace()
+    data = phases.load(trace.find_xplane(str(tmp_path)))
+    reduced = phases.reduce_phases(data, cfg.num_layers)
+    assert reduced["prefill_rows"] == 2 * 2  # the batch, twice
+    assert reduced["steps"].get("decode", 0) > 0
+    assert eng.stats.family_counters["prefill_tokens_computed"] == 3 * 2 * (1280 - 320)
+    assert eng.stats.family_counters["prefill_tokens_bucketed"] == 3 * 2 * 1280
+    by = ssm_scopes.seconds_by_fine_scope(data)
+    assert by["prefill"].get("scan", 0) > 0 and by["prefill"].get("conv", 0) > 0
+    paths = [path for _, path in _traced(_compiled(eng._build_generate(2, 1280, 6)))]
+    for fine in ("scan", "conv", "global"):
+        hits = [p for p in paths if "/prefill/" in p and "/attn/" in p and f"/{fine}/" in p.split("/attn/")[1]]
+        assert hits and all(_scope(p) == ("prefill", "attn") for p in hits), fine
+        assert all("cond/" in p.split("/attn/")[1] for p in hits), fine  # the kind of mixer; the convolution in a rung too
+    outside = [p for p in paths if "/prefill/rows2/" in p and "/while/body/" in p
+               and "/cond/" not in p and "/branch_" not in p]
+    assert outside, "no operation of a trip stands outside every branch"
 
 
 def test_shortcut_block_arrives_scoped_with_its_own_fine_scopes():

@@ -582,6 +582,69 @@ def test_hybrid_ssm_programs_compile_with_their_kernels(one_chip, uncached):
     assert "f32[8,4096,16,5120]" not in alone.as_text()  # no state a position anywhere
 
 
+def test_hybrid_live_suffix_branches_copy_neither_stream_nor_stacked_weight(one_chip, uncached):
+    """The batch-8 prefill of the hybrid state-space family at the 4096
+    bucket, every width and all 28 layers as published (a small vocabulary):
+    a trip's matmuls and norms run in the rung's branch (``models/hybrid_ssm.py
+    live_trip``), which reads its weights from the STACKED leaves at the
+    trip's index and writes its rows of the residual stream in place; the
+    scan kernel stands outside the rungs, once a program, at the bucket's
+    shape. A copy of the stream ``bf16[8,4096,2560]`` in a branch (a four-way
+    ``lax.switch`` compiles to one in its third branch, twice a trip: PERF.md,
+    PR 41; the two rungs of ``live_rungs`` are one ``lax.cond``), or of a stacked
+    SwiGLU or mixer kernel anywhere but the entry computation, fails here. The
+    depth is the published one because the compiler's copies depend on it (a
+    loop of two trips is unrolled, and compiles to other copies)."""
+    from rag_llm_k8s_tpu.core.config import (
+        DTypePolicy, EngineConfig, GoodputConfig, HybridSSMConfig, PrefixCacheConfig, SamplingConfig,
+    )
+    from rag_llm_k8s_tpu.engine import engine as engine_mod
+    from rag_llm_k8s_tpu.models.hybrid_ssm import init_hybrid_ssm_params
+
+    cfg = HybridSSMConfig(vocab_size=1024, tie_word_embeddings=False)
+    dt = DTypePolicy()
+    shapes = jax.eval_shape(lambda: init_hybrid_ssm_params(jax.random.PRNGKey(0), cfg, dt))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    ec = EngineConfig(prompt_buckets=(4096,), max_seq_len=4096 + 256, attn_impl="pallas", speculative="off",
+                      goodput=GoodputConfig(enabled=False), prefix_cache=PrefixCacheConfig(enabled=False))
+    eng = engine_mod.InferenceEngine(
+        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=2), engine_config=ec, dtypes=dt)
+    tok = jax.ShapeDtypeStruct((8, 4096), I32, sharding=one_chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(eng._make_gen(8, 4096, 2)).lower(params, tok, tok, rng).compile().as_text()
+    assert " conditional(" in text
+    assert "bf16[8,4096,40,128]" in text and "bf16[8,3072,40,128]" not in text  # the scan kernel: once, the bucket
+    assert "bf16[8,3072,8192]" in text  # a SwiGLU on the suffix behind a quarter of the bucket
+    L, M = cfg.num_layers, cfg.num_state_layers
+    unwanted = re.compile(rf"= bf16\[(8,4096,2560|{L},\d+,\d+|{M},(2560|5120),\d+)\]\S* copy\(")
+    for computation in text.split("\n\n"):
+        head = computation.lstrip()
+        if head.startswith(("ENTRY", "%fused", "fused")):  # once a call, as before / inside a fusion: no pass of its own
+            continue
+        found = [line.strip()[:160] for line in computation.splitlines() if unwanted.search(line)]
+        assert not found, found
+    # What ``benchmark/lib/phases.py`` counts a prefill's rows by: the median
+    # executions of the instructions whose scope path holds one loop and no
+    # branch. The compiler files what it puts in a branch for the branch's
+    # operands (a relayout of a small stacked leaf sliced there) under the
+    # OPERAND's path, which holds no branch: two of those beside the two
+    # state writes read 23.1 rows for 24 on the chip (PERF.md, PR 41). So a
+    # trip's small leaves are sliced in the loop's body, behind a barrier, and
+    # the body's such instructions outnumber every other computation's.
+    from benchmark.lib.phases import one_loop_beneath
+
+    counted = {}
+    for computation in text.split("\n\n"):
+        lines = computation.strip().splitlines()
+        if not lines or lines[0].lstrip().startswith(("%fused", "fused")):
+            continue
+        paths = (re.search(r'op_name="([^"]*)"', line) for line in lines[1:] if re.search(r" (fusion|copy)\(", line))
+        units = [one_loop_beneath(m.group(1)) for m in paths if m]
+        counted[lines[0].split(" ")[0]] = sum(1 for u in units if u and u[0] == "prefill")
+    body, *others = sorted(counted.values(), reverse=True)
+    assert body >= 8 and 4 * sum(others) <= body, counted
+
+
 @pytest.mark.parametrize("batch", [1, 8])
 def test_live_suffix_branches_copy_no_stacked_weight(batch, one_chip, uncached):
     """The prefill of a bucket with rungs (``models/llama.py live_offsets``)
