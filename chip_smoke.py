@@ -12,7 +12,9 @@ device from ``--seed``:
 1. device gate — no accelerator, no run (there is no CPU fallback);
 2. every main-path Pallas kernel, compiled by Mosaic, against its XLA oracle
    at 8B head geometry; the sparse experts' combine against the XLA
-   scatter-add at the three served prefill shapes;
+   scatter-add at the three served prefill shapes; the router's kernel
+   against its jnp body; the block-window prefill kernel told a row's live
+   length against the same kernel without one;
 3. the default deployment shape (coalesce batching, single-fetch RAG,
    speculation auto) assembled by ``server.main.assemble_service``, warmed,
    served by a real werkzeug server over sockets: /healthz, /upload_pdf,
@@ -600,6 +602,39 @@ def phase_route(seed: int, cases=None, interpret: bool = False) -> None:
             jnp_body_us_a_call=route_us_a_call(body, logits, bias))
 
 
+def phase_live_window(seed: int, shape=(32, 20480, 128), window: int = 2048, chunk: int = 16,
+                      block: int = 0, lengths=(17353, 20480), interpret: bool = False) -> None:
+    """``ops/block_window.py window_summary_flash_attention`` told a row's
+    live length (``models/block_window.py``: whole blocks of ``LIVE_BLOCK``
+    positions) against the same kernel without one, at the served shape: the
+    live blocks bit-equal (what is behind them is unwritten, and not looked
+    at), and microseconds a call both ways."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from rag_llm_k8s_tpu.models.block_window import LIVE_BLOCK
+    from rag_llm_k8s_tpu.ops import block_window as bw
+
+    block = block or LIVE_BLOCK
+    H, S, hd = shape
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k, v = (jax.random.normal(key, shape, jnp.bfloat16) for key in keys[:3])
+    mu, phi = (jax.random.normal(key, (H, hd), jnp.bfloat16) * 1.5 / hd ** 0.5 for key in keys[3:])
+    sk, sv = bw.pool_chunks(k, v, mu, phi, chunk, "pallas_interpret" if interpret else "pallas")
+    attend = functools.partial(bw.window_summary_flash_attention, window=window, chunk=chunk, interpret=interpret)
+    whole = np.asarray(attend(q, k, v, sk, sv).astype(jnp.float32))
+    many = jax.jit(lambda x, n: jax.lax.fori_loop(0, 8, lambda i, x: attend(x, k, v, sk, sv, n), x), static_argnums=1)
+    whole_us = best_us_a_call(many, 8, q, None)
+    for n in lengths:
+        live = -(-n // block) * block
+        got = np.asarray(attend(q, k, v, sk, sv, live).astype(jnp.float32))[:, :live]
+        check(np.isfinite(got).all() and np.array_equal(got, whole[:, :live]),
+              f"{n} live positions: the first {live} differ from the whole row's")
+        say("kernel", name=f"window_summary_flash_attention[{n} of {S} positions]", live_positions=live,
+            live_blocks_bit_equal=True, us_a_call=best_us_a_call(many, 8, q, live), whole_row_us_a_call=whole_us)
+
+
 # ---------------------------------------------------------------------------
 # inputs made from the seed
 # ---------------------------------------------------------------------------
@@ -916,6 +951,7 @@ def run_one_chip(args, counter, errors) -> None:
     phase_kernels(args.seed)
     phase_combine(args.seed)
     phase_route(args.seed)
+    phase_live_window(args.seed)
     gc.collect()
 
     tokenizers = load_tokenizers()

@@ -36,6 +36,10 @@ of its positions is written, so the last write leaves it whole; it is not
 visible before its window has closed). A prefill computes a row at a time
 through ALL layers, shifted left so that index = position: what a prompt row
 expands to (its K/V of a layer, its FFN) is live once, not a batch of them.
+The shift puts a row's pads BEHIND its real positions, and attention is
+causal, so a layer of a row runs over the blocks of ``LIVE_BLOCK`` positions
+its real ones fill and no further: loops whose trip count is read from
+``kv_start``, the window kernel told the same bound, no branch.
 
 A chunk that is VERIFIED (speculative proposals, some of which are thrown
 away) must not write the ring past its window's end: slot ``t % W`` of the
@@ -51,13 +55,14 @@ from typing import Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from rag_llm_k8s_tpu.core.config import BlockWindowConfig, DTypePolicy
 from rag_llm_k8s_tpu.models.llama import KVCache, apply_rope, resolve_attn_impl, rope_cos_sin
 from rag_llm_k8s_tpu.obs.tracing import count_kernel_build, phase_scope
 from rag_llm_k8s_tpu.ops import block_window as bw
 from rag_llm_k8s_tpu.ops.attention import (
-    DECODE_ALIGN, decode_attention, decode_attention_xla, decode_block_plan, gqa_decode_step,
+    DECODE_ALIGN, _fit_block, decode_attention, decode_attention_xla, decode_block_plan, gqa_decode_step,
 )
 
 # KVCache.counters, a decode step through the kernel at a time (one layer's
@@ -65,12 +70,26 @@ from rag_llm_k8s_tpu.ops.attention import (
 # slots the walk fetches over the rows, and the positions they stand for
 # (ring + chunk_size x summaries). And, a call that writes the cache at a
 # time: positions written that end a chunk and that end a window (a verify
-# step counts what it writes, kept or not).
+# step counts what it writes, kept or not). And, a single-shot prefill call at
+# a time: positions its rows' layers ran over (whole blocks of ``LIVE_BLOCK``)
+# and rows x the bucket (models/llama.py's names).
 COUNTER_NAMES = ("decode_ring_slots_fetched", "decode_summary_slots_fetched",
-                 "decode_slots_attended_positions", "chunks_closed", "windows_closed")
+                 "decode_slots_attended_positions", "chunks_closed", "windows_closed",
+                 "prefill_tokens_computed", "prefill_tokens_bucketed")
 N_COUNTERS = len(COUNTER_NAMES)
 DECODE_KERNEL = "ring_summary_decode_attention"  # what a trace calls the decode walk here
-MLP_BLOCK = 4096  # positions of a prompt row the FFN takes at once
+# Positions a trip of a prompt row's loops takes (the window, where it is
+# shorter): it tiles the window in whole query blocks of the prefill kernel
+# and whole chunks; a row's layers run over ceil(real positions / this) of
+# them. The kernel's query block, the finest such: on the chip a row of 17.5 k
+# positions in the 20480 bucket took 409.8 ms at 512, 439.6 at 1024 and 423.4
+# at 2048, a full row 468-488 at all three (PERF.md section 6, PR 42).
+LIVE_BLOCK = 512
+
+
+def live_block(config: BlockWindowConfig) -> int:
+    """Positions a trip of a prompt row's loops takes: see ``LIVE_BLOCK``."""
+    return _fit_block(config.window_size, LIVE_BLOCK)
 
 
 def fold_counters(row) -> dict:
@@ -143,6 +162,20 @@ def _mm(x, w, out=jnp.float32):
     return jnp.einsum("...d,df->...f", x, w.astype(x.dtype), preferred_element_type=out)
 
 
+def _block(x, at, n):
+    """``x[:, at : at + n]``, ``at`` traced."""
+    return jax.lax.dynamic_slice_in_dim(x, at, n, axis=1)
+
+
+def _put_block(x, block, at):
+    """``x`` with ``block`` at ``[:, at : at + block.shape[1]]``, in place and
+    row-major (the layout a kernel takes: left to the compiler, a loop may
+    carry ``x`` in the layout its block was computed in, and re-lay ALL of
+    ``x`` behind the loop)."""
+    x = jax.lax.dynamic_update_slice_in_dim(x, block.astype(x.dtype), at, axis=1)
+    return with_layout_constraint(x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
 class BlockWindowModel(nn.Module):
     config: BlockWindowConfig
     dtypes: DTypePolicy = DTypePolicy()
@@ -166,17 +199,8 @@ class BlockWindowModel(nn.Module):
         with phase_scope("norm_rope"):
             x = _norm(h, lp["post_attn_norm"], c.rms_norm_eps, dt.compute_dtype)
         with phase_scope("mlp"):
-            def ffn(x):
-                y = nn.silu(_mm(x, lp["w_gate"], dt.compute_dtype)) * _mm(x, lp["w_up"], dt.compute_dtype)
-                return _mm(y, lp["w_down"])
-
-            B, S, D = x.shape
-            if B == 1 and S > MLP_BLOCK and S % MLP_BLOCK == 0:
-                # a long prompt row goes through in blocks: [S, intermediate] never exists whole
-                y = jax.lax.map(ffn, x.reshape(S // MLP_BLOCK, MLP_BLOCK, D)).reshape(1, S, D)
-            else:
-                y = ffn(x)
-            return h + y
+            y = nn.silu(_mm(x, lp["w_gate"], dt.compute_dtype)) * _mm(x, lp["w_up"], dt.compute_dtype)
+            return h + _mm(y, lp["w_down"])
 
     def _through_layers(self, params, layer, h, planes):
         """``layer((h, k_plane, v_plane), (a layer's leaves, its index))`` over the stacked layers."""
@@ -190,10 +214,16 @@ class BlockWindowModel(nn.Module):
         return h + _mm(o.transpose(0, 2, 1, 3).reshape(B, S, H * hd), lp["wo"])
 
     # -- a bucket of prompt rows: index = position ----------------------------
-    def _prefill_layer_row(self, lp, li, h, n, row, planes, impl):
+    def _prefill_layer_row(self, lp, li, h, bufs, n, live, row, planes, impl):
         """One layer of one row: ``h [1, Sp, D]`` the row's stream (shifted
-        left: index = position; ``n`` real positions); writes the row's ring
-        and summaries of plane ``li``; returns the stream and the planes."""
+        left: index = position; ``n`` real positions, in the first ``live``
+        blocks of ``LIVE_BLOCK``); ``bufs`` the row's head-major q, k, v ``[H,
+        Sp, hd]``; writes the row's ring and summaries of plane ``li``;
+        returns the stream, ``bufs`` and the planes. At and past ``live``
+        blocks the stream and ``bufs`` keep what they held (finite: a layer
+        before's, a row before's, zeros), the pooling pools that, and the
+        window kernel's result holds ANYTHING: the loop behind it stops where
+        the kernel does."""
         c, dt = self.config, self.dtypes
         W, C = c.window_size, c.chunk_size
         k_plane, v_plane = planes
@@ -201,26 +231,39 @@ class BlockWindowModel(nn.Module):
         NS = k_plane.shape[3] - W
         kept = min(Sp // C, NS)  # summaries the plane has room for
         first = (jnp.maximum(n - 1, 0) // W * W).astype(jnp.int32)  # the last window's first position
-        with phase_scope("norm_rope"):
-            x = _norm(h, lp["input_norm"], c.rms_norm_eps, dt.compute_dtype)
+        size = live_block(c)
+
+        def front(i, bufs):  # a block's q, k, v into the row's buffers
+            at = i * size
+            with phase_scope("norm_rope"):
+                x = _norm(_block(h, at, size), lp["input_norm"], c.rms_norm_eps, dt.compute_dtype)
+            with phase_scope("attn"):
+                qkv = self._qkv(lp, x, at + jnp.arange(size, dtype=jnp.int32)[None])
+                return tuple(_put_block(buf, a[0], at) for buf, a in zip(bufs, qkv))
+
+        q, k, v = bufs = jax.lax.fori_loop(0, live, front, bufs)
         with phase_scope("attn"):
-            q, k, v = self._qkv(lp, x, jnp.arange(Sp, dtype=jnp.int32)[None])
             with phase_scope("pool"):
-                sk, sv = bw.pool_chunks(k[0], v[0], lp["mu"], lp["phi"], C, impl)
+                sk, sv = bw.pool_chunks(k, v, lp["mu"], lp["phi"], C, impl)
             with phase_scope("ring"):  # the kernel's one softmax holds the summaries' part too
                 if impl == "xla":
-                    o = bw.window_summary_attention_xla(q[0], k[0], v[0], sk, sv, window=W, chunk=C)
+                    o = bw.window_summary_attention_xla(q, k, v, sk, sv, window=W, chunk=C)
                 else:
                     o = bw.window_summary_flash_attention(
-                        q[0], k[0], v[0], sk, sv, window=W, chunk=C, interpret=impl == "pallas_interpret")
-            ring_k = jax.lax.dynamic_slice_in_dim(k[0], first, W, axis=1)
-            ring_v = jax.lax.dynamic_slice_in_dim(v[0], first, W, axis=1)
-            new_k = jnp.concatenate([sk[:, :kept][:, ::-1], ring_k], axis=1).astype(k_plane.dtype)
-            new_v = jnp.concatenate([sv[:, :kept][:, ::-1], ring_v], axis=1).astype(v_plane.dtype)
-            k_plane = jax.lax.dynamic_update_slice(k_plane, new_k[None, None], (li, row, 0, NS - kept, 0))
-            v_plane = jax.lax.dynamic_update_slice(v_plane, new_v[None, None], (li, row, 0, NS - kept, 0))
-            h = self._out(lp, h, o[None])
-        return self._mlp(lp, h), (k_plane, v_plane)
+                        q, k, v, sk, sv, live * size, window=W, chunk=C, interpret=impl == "pallas_interpret")
+            new_k = jnp.concatenate([sk[:, :kept][:, ::-1], _block(k, first, W)], axis=1)
+            new_v = jnp.concatenate([sv[:, :kept][:, ::-1], _block(v, first, W)], axis=1)
+            at = (li, row, 0, NS - kept, 0)
+            k_plane = jax.lax.dynamic_update_slice(k_plane, new_k.astype(k_plane.dtype)[None, None], at)
+            v_plane = jax.lax.dynamic_update_slice(v_plane, new_v.astype(v_plane.dtype)[None, None], at)
+
+        def back(i, h):  # a block's attention output onto the stream, and its FFN
+            at = i * size
+            with phase_scope("attn"):
+                x = self._out(lp, _block(h, at, size), _block(o, at, size)[None])
+            return _put_block(h, self._mlp(lp, x), at)
+
+        return jax.lax.fori_loop(0, live, back, h), bufs, (k_plane, v_plane)
 
     def _prefill(self, params, tokens, kv_start, planes, impl, last_logit_only, logit_index):
         """The rows of a bucket, ONE AT A TIME through all the layers: what a
@@ -228,14 +271,18 @@ class BlockWindowModel(nn.Module):
         once, never a batch of them. The rows are unrolled here (each its own
         trip through the layers' loop, tied to the row before it through the
         planes it wrote), so an operation of the layers' loop is one loop deep
-        in every row. Returns the final stream at the positions asked for."""
+        in every row; a layer's matmuls, norms and rotation are a loop deeper,
+        over the row's live blocks. Returns the final stream at the positions
+        asked for, the planes and what to count."""
         c = self.config
         B, S = tokens.shape
         W = c.window_size
         Sp = -(-S // W) * W
+        size = live_block(c)
         n_rows = S - kv_start  # [B] real lengths
+        live = jnp.clip(-(-n_rows // size), 1, Sp // size).astype(jnp.int32)  # [B] blocks that hold them
         # shift every row left by its pad: index = position (the tail is
-        # whatever wraps around; no query that anyone reads sees it)
+        # whatever wraps around; no layer computes it)
         toks = jax.vmap(lambda row, by: jnp.roll(row, by))(jnp.pad(tokens, ((0, 0), (0, Sp - S))), -kv_start)
         one = last_logit_only or logit_index is not None  # the stream at one slot a row, not at all of them
         if one:
@@ -246,6 +293,8 @@ class BlockWindowModel(nn.Module):
         row = (c.num_heads, Sp, c.head_dim)
         count_kernel_build("prefill", "chunk_pool" if bw.pool_blocks(
             row, c.chunk_size, self.dtypes.compute_dtype, impl) else "pool_chunks")
+        # one shape for both kernels' operands; a row's dead blocks keep the row before's
+        bufs = (jnp.zeros(row, self.dtypes.compute_dtype),) * 3
         outs = []
         for b in range(B):
             if b:  # this row starts when the one before it is in the planes
@@ -254,16 +303,17 @@ class BlockWindowModel(nn.Module):
                 h = jnp.take(params["embedding"], toks[b], axis=0).astype(jnp.float32)[None]
 
             def layer(carry, xs, b=b):
-                h, k_plane, v_plane = carry
-                h, (k_plane, v_plane) = self._prefill_layer_row(
-                    xs[0], xs[1], h, n_rows[b], b, (k_plane, v_plane), impl)
-                return (h, k_plane, v_plane), None
+                h, k_plane, v_plane, *bufs = carry
+                h, bufs, planes = self._prefill_layer_row(
+                    xs[0], xs[1], h, tuple(bufs), n_rows[b], live[b], b, (k_plane, v_plane), impl)
+                return (h, *planes, *bufs), None
 
-            h, planes = self._through_layers(params, layer, h, planes)
-            outs.append(jax.lax.dynamic_slice_in_dim(h, at[b], 1, axis=1) if one
-                        else jnp.roll(h, kv_start[b], axis=1)[:, :S])
-        closed = jnp.stack([jnp.sum(n_rows // c.chunk_size), jnp.sum(n_rows // W)])
-        return jnp.concatenate(outs, axis=0), planes, closed
+            h, carried = self._through_layers(params, layer, h, (*planes, *bufs))
+            planes, bufs = carried[:2], carried[2:]
+            outs.append(_block(h, at[b], 1) if one else jnp.roll(h, kv_start[b], axis=1)[:, :S])
+        counted = {"chunks_closed": jnp.sum(n_rows // c.chunk_size), "windows_closed": jnp.sum(n_rows // W),
+                   "prefill_tokens_computed": jnp.sum(live) * size, "prefill_tokens_bucketed": B * S}
+        return jnp.concatenate(outs, axis=0), planes, counted
 
     # -- one position a row over the planes -----------------------------------
     def _decode(self, params, tokens, positions, t, planes, impl):
@@ -304,7 +354,7 @@ class BlockWindowModel(nn.Module):
             return (self._mlp(lp, h), k_plane, v_plane), None
 
         h, planes = self._through_layers(params, layer, h, planes)
-        return h, planes, jnp.stack([jnp.sum(t % C == C - 1), jnp.sum(t % W == W - 1)])
+        return h, planes, {"chunks_closed": jnp.sum(t % C == C - 1), "windows_closed": jnp.sum(t % W == W - 1)}
 
     # -- a chunk of positions a row over the planes ---------------------------
     def _chunk(self, params, tokens, positions, t, live, planes, impl):
@@ -364,7 +414,8 @@ class BlockWindowModel(nn.Module):
             return (self._mlp(lp, h), k_plane, v_plane), None
 
         h, planes = self._through_layers(params, layer, h, planes)
-        return h, planes, jnp.stack([jnp.sum(live & (t % C == C - 1)), jnp.sum(live & (t % W == W - 1))])
+        return h, planes, {"chunks_closed": jnp.sum(live & (t % C == C - 1)),
+                           "windows_closed": jnp.sum(live & (t % W == W - 1))}
 
     @nn.compact
     def __call__(
@@ -409,16 +460,16 @@ class BlockWindowModel(nn.Module):
         t = wi + jnp.arange(S, dtype=jnp.int32)[None] - kv_start[:, None]  # [B, S]
 
         if S == 1:
-            h, planes, closed = self._decode(params, tokens, positions, t[:, 0], planes, impl)
-            if impl != "xla" and counters is not None:
+            h, planes, counted = self._decode(params, tokens, positions, t[:, 0], planes, impl)
+            if impl != "xla":
                 P = cache.k.shape[3]
                 step = gqa_decode_step(P, H, 1, hd, cache.k.dtype)
                 first, end = live_range(t[:, 0], c, NS)
                 origin, steps = decode_block_plan(first, end, P, step)
                 pooled = jnp.sum(NS - jnp.minimum(origin, NS))
                 ring = jnp.sum(steps) * step - pooled
-                counters = counters.at[:3].add(jnp.stack(
-                    [ring, pooled, ring + c.chunk_size * pooled]).astype(counters.dtype))
+                counted.update(decode_ring_slots_fetched=ring, decode_summary_slots_fetched=pooled,
+                               decode_slots_attended_positions=ring + c.chunk_size * pooled)
         elif self.chunked:
             live = (t >= 0) & (wi + jnp.arange(S, dtype=jnp.int32)[None] < kv_len[:, None])
             # a call over the ring takes at most window - chunk positions (what it
@@ -427,22 +478,23 @@ class BlockWindowModel(nn.Module):
             room = c.window_size - c.chunk_size
             m = max(d for d in range(1, min(S, room) + 1) if S % d == 0)
             if m == S:
-                h, planes, closed = self._chunk(params, tokens, positions, t, live, planes, impl)
+                h, planes, counted = self._chunk(params, tokens, positions, t, live, planes, impl)
             else:
                 def piece(planes, xs):
-                    h, planes, closed = self._chunk(params, *xs, planes, impl)
-                    return planes, (h, closed)
+                    h, planes, counted = self._chunk(params, *xs, planes, impl)
+                    return planes, (h, counted)
 
                 cut = lambda a: a.reshape(B, S // m, m).swapaxes(0, 1)  # noqa: E731
-                planes, (h, closed) = jax.lax.scan(
+                planes, (h, counted) = jax.lax.scan(
                     piece, planes, tuple(cut(a) for a in (tokens, positions, t, live)))
-                h, closed = h.swapaxes(0, 1).reshape(B, S, -1), closed.sum(0)
+                h, counted = h.swapaxes(0, 1).reshape(B, S, -1), {name: x.sum(0) for name, x in counted.items()}
         else:  # a single-shot prefill: the rows' prompts end at the bucket's end
-            h, planes, closed = self._prefill(
+            h, planes, counted = self._prefill(
                 params, tokens, kv_start, planes, impl, last_logit_only, logit_index)
             last_logit_only, logit_index = False, None  # taken where the row was computed
         if counters is not None:
-            counters = counters.at[3:].add(closed.astype(counters.dtype))
+            counters = counters + jnp.stack(
+                [jnp.asarray(counted.get(name, 0), counters.dtype) for name in COUNTER_NAMES])
 
         with phase_scope("norm_rope"):
             h = _norm(h, params["final_norm"], c.rms_norm_eps, jnp.float32)

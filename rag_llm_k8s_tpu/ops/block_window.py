@@ -305,68 +305,75 @@ def ring_summary_chunk_attention_xla(q, k_new, v_new, k_plane, v_plane, t, live,
     return _softmax_av(s, ok, vals).astype(q.dtype)
 
 
-def _window_summary_kernel(q_ref, k_ref, v_ref, sk_ref, sv_ref, o_ref, *, window: int, per: int,
+def _window_summary_kernel(live_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref, o_ref, *, window: int, per: int,
                            bq: int, bs: int, scale: float):
     """One query block of one head: the causal part of its window's strip
     (``k_ref``/``v_ref [1, W, hd]``, key blocks of ``bq``: the last one is
     the diagonal), then the summaries ``[0, per * w)`` of ``sk_ref``/``sv_ref
-    [1, NS, hd]`` in blocks of ``bs``, one running softmax."""
+    [1, NS, hd]`` in blocks of ``bs``, one running softmax. A block whose
+    first query is at or past ``live_ref[0]`` does nothing."""
     qi = pl.program_id(1)
     w = qi * bq // window
     at = qi * bq - w * window  # the block's first query, within its window
-    q = q_ref[0]
-    hd = q.shape[-1]
 
-    def fold(carry, k, v, ok):
-        m, l, acc = carry
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-        if ok is not None:
-            s = jnp.where(ok, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        if ok is not None:
-            p = jnp.where(ok, p, 0.0)
-        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return m_new, l, acc
+    @pl.when(qi * bq < live_ref[0])
+    def _():
+        q = q_ref[0]
+        hd = q.shape[-1]
 
-    def exact(j, carry):  # an interior key block of the window: no mask
-        off = pl.multiple_of(j * bq, bq)
-        return fold(carry, k_ref[0, pl.ds(off, bq), :], v_ref[0, pl.ds(off, bq), :], None)
+        def fold(carry, k, v, ok):
+            m, l, acc = carry
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+            if ok is not None:
+                s = jnp.where(ok, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            if ok is not None:
+                p = jnp.where(ok, p, 0.0)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            return m_new, l, acc
 
-    carry = (jnp.full((bq, 1), NEG_INF, jnp.float32), jnp.zeros((bq, 1), jnp.float32),
-             jnp.zeros((bq, hd), jnp.float32))
-    carry = jax.lax.fori_loop(0, at // bq, exact, carry)
-    row = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1)
-    off = pl.multiple_of(at, bq)
-    carry = fold(carry, k_ref[0, pl.ds(off, bq), :], v_ref[0, pl.ds(off, bq), :], col <= row)
+        def exact(j, carry):  # an interior key block of the window: no mask
+            off = pl.multiple_of(j * bq, bq)
+            return fold(carry, k_ref[0, pl.ds(off, bq), :], v_ref[0, pl.ds(off, bq), :], None)
 
-    n_sum = per * w  # live summaries: those of the windows before this one
+        carry = (jnp.full((bq, 1), NEG_INF, jnp.float32), jnp.zeros((bq, 1), jnp.float32),
+                 jnp.zeros((bq, hd), jnp.float32))
+        carry = jax.lax.fori_loop(0, at // bq, exact, carry)
+        row = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1)
+        off = pl.multiple_of(at, bq)
+        carry = fold(carry, k_ref[0, pl.ds(off, bq), :], v_ref[0, pl.ds(off, bq), :], col <= row)
 
-    def pooled(j, carry):
-        off = pl.multiple_of(j * bs, bs)
-        ok = off + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1) < n_sum
-        # a summary past the live ones may be pooled from a row's junk tail:
-        # zero it before any matmul (0 * inf)
-        okc = off + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0) < n_sum
-        return fold(carry, jnp.where(okc, sk_ref[0, pl.ds(off, bs), :], 0),
-                    jnp.where(okc, sv_ref[0, pl.ds(off, bs), :], 0), ok)
+        n_sum = per * w  # live summaries: those of the windows before this one
 
-    m, l, acc = jax.lax.fori_loop(0, (n_sum + bs - 1) // bs, pooled, carry)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)  # the diagonal always holds a live key
+        def pooled(j, carry):
+            off = pl.multiple_of(j * bs, bs)
+            ok = off + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1) < n_sum
+            # a summary past the live ones may be pooled from a row's junk tail:
+            # zero it before any matmul (0 * inf)
+            okc = off + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0) < n_sum
+            return fold(carry, jnp.where(okc, sk_ref[0, pl.ds(off, bs), :], 0),
+                        jnp.where(okc, sv_ref[0, pl.ds(off, bs), :], 0), ok)
+
+        m, l, acc = jax.lax.fori_loop(0, (n_sum + bs - 1) // bs, pooled, carry)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)  # the diagonal always holds a live key
 
 
 @functools.partial(jax.jit, static_argnames=("window", "chunk", "interpret"))
-def window_summary_flash_attention(q, k, v, sk, sv, *, window: int, chunk: int,
+def window_summary_flash_attention(q, k, v, sk, sv, live=None, *, window: int, chunk: int,
                                    interpret: bool = False) -> jax.Array:
     """The prefill kernel over rows whose index is their position: ``q, k, v
     [N, S, hd]`` (``N`` = rows x heads), ``sk, sv [N, S // chunk, hd]`` (the
     rows' pooled chunks, ``pool_chunks``) -> ``[N, S, hd]``. ``S`` is padded
     to whole windows here; a query past a row's end computes on whatever the
-    row holds there, and nobody reads it."""
+    row holds there, and nobody reads it. ``live`` (traced; None: all): only
+    the query blocks that start under it are computed, and the result past
+    them is UNWRITTEN (anything, NaN included): what a caller that knows its
+    rows end by then need not pay for."""
     N, S, hd = q.shape
     W, per = window, window // chunk
     Sp = -(-S // W) * W
@@ -378,30 +385,37 @@ def window_summary_flash_attention(q, k, v, sk, sv, *, window: int, chunk: int,
         sk, sv = pad(sk[:, :S // chunk], Sp // chunk), pad(sv[:, :S // chunk], Sp // chunk)
     NS = sk.shape[1]
 
-    def q_index(h, qi):
-        return (h, qi, 0)
+    def live_block(qi, live):
+        # a dead query block names the last live one, so its step fetches and
+        # writes nothing (``ops/attention.py``'s dead blocks)
+        return jnp.minimum(qi, (live[0] - 1) // bq)
 
-    def window_index(h, qi):
-        return (h, qi * bq // W, 0)
+    def q_index(h, qi, live):
+        return (h, live_block(qi, live), 0)
 
-    def summary_index(h, qi):
+    def window_index(h, qi, live):
+        return (h, live_block(qi, live) * bq // W, 0)
+
+    def summary_index(h, qi, live):
         return (h, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(_window_summary_kernel, window=W, per=per, bq=bq, bs=bs, scale=hd**-0.5),
-        grid=(N, Sp // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, hd), q_index),
-            pl.BlockSpec((1, W, hd), window_index),
-            pl.BlockSpec((1, W, hd), window_index),
-            pl.BlockSpec((1, NS, hd), summary_index),
-            pl.BlockSpec((1, NS, hd), summary_index),
-        ],
-        out_specs=pl.BlockSpec((1, bq, hd), q_index),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N, Sp // bq),
+            in_specs=[
+                pl.BlockSpec((1, bq, hd), q_index),
+                pl.BlockSpec((1, W, hd), window_index),
+                pl.BlockSpec((1, W, hd), window_index),
+                pl.BlockSpec((1, NS, hd), summary_index),
+                pl.BlockSpec((1, NS, hd), summary_index),
+            ],
+            out_specs=pl.BlockSpec((1, bq, hd), q_index)),
         out_shape=jax.ShapeDtypeStruct((N, Sp, hd), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="window_summary_flash_attention",
-    )(q, k, v, sk, sv)
+    )(jnp.clip(jnp.asarray(Sp if live is None else live, jnp.int32), 1, Sp).reshape(1), q, k, v, sk, sv)
     return out[:, :S]

@@ -141,6 +141,74 @@ def test_prefill_then_decode_matches_reference_at_every_position(params, impl, p
     assert ring.shape[-2] == W and pooled.shape[-2] == cache.k.shape[3] - W < len(tokens)  # no plane as long
 
 
+BLOCK = bwm.live_block(CFG)  # positions a trip of a prompt row's loops takes: the window, at this size
+
+
+def live_slots(cache, row, n):
+    """What a decode step may ever read of ``row``'s planes after a prefill of
+    ``n`` positions: the last window's ring slots and every complete chunk's
+    summary, keys and values, layer by layer."""
+    out = []
+    for plane in (cache.k, cache.v):
+        ring = np.asarray(bwm.ring_of(plane, CFG))[:, row, :, :(n - 1) % W + 1]
+        pooled = np.asarray(bwm.summaries_of(plane, CFG))[:, row, :, :n // C]
+        out += [ring, pooled]
+    return out
+
+
+@pytest.mark.parametrize("lengths,why", [
+    ((1,), "one position: one block"), ((BLOCK - 1,), "a position short of a block"), ((BLOCK,), "a whole block"),
+    ((BLOCK + 1,), "a position into the second block"), ((96,), "the whole bucket: every block"),
+    ((1, BLOCK + 1, 96), "rows of one, two and three live blocks in one batch"),
+])
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_a_prefill_runs_the_blocks_a_rows_positions_fill(params, impl, lengths, why):
+    """A row of ``n`` real positions in the bucket of 96 runs its layers over
+    ``ceil(n / BLOCK)`` blocks of the three (``LIVE_BLOCK``; the kernels are
+    told the same bound): all 8 heads' logits at every prefilled position and
+    at 40 decoded ones (across a window's end, where the prefilled window's
+    summaries are first read) are the reference's; the planes' live slots are
+    those of the same row prefilled in the smallest bucket that holds it
+    whole, where every block runs; and the program counts what it ran."""
+    rows = [prompt_of(n + 40, 50 + n) for n in lengths]
+    got, cache = through_the_cache(params, rows, 96, list(lengths), impl=impl)
+    for row, g in zip(rows, got):
+        np.testing.assert_allclose(g, reference(params, row), atol=ATOL)
+    counted = bwm.fold_counters(np.asarray(cache.counters))
+    assert counted["prefill_tokens_computed"] == sum(-(-n // BLOCK) * BLOCK for n in lengths)
+    assert counted["prefill_tokens_bucketed"] == 96 * len(lengths)
+    prompts = [row[:n] for row, n in zip(rows, lengths)]
+    _, fresh = through_the_cache(params, prompts, 96, list(lengths), T=136, impl=impl)  # the prefill, no step behind it
+    for b, (prompt, n) in enumerate(zip(prompts, lengths)):
+        _, whole = through_the_cache(params, [prompt], -(-n // W) * W, [n], T=136, impl=impl)
+        for mine, full in zip(live_slots(fresh, b, n), live_slots(whole, 0, n)):
+            np.testing.assert_allclose(mine, full, atol=ATOL)
+
+
+def test_what_the_kernel_leaves_unwritten_reaches_nothing(params, monkeypatch):
+    """The prefill kernel writes nothing behind a row's live blocks: fill
+    what it left with NaN (interpret mode writes zeros there) and nothing that
+    leaves the layer holds one: not the stream behind the live blocks (every
+    slot's logits), not the planes."""
+    kernel = bw.window_summary_flash_attention
+
+    def poisoned(q, k, v, sk, sv, live, **kw):
+        out = kernel(q, k, v, sk, sv, live, **kw)
+        return jnp.where((jnp.arange(out.shape[1]) >= live)[None, :, None], jnp.nan, out)
+
+    monkeypatch.setattr(bw, "window_summary_flash_attention", poisoned)
+    n = BLOCK + 1
+    tokens = prompt_of(n, 9)
+    model = bwm.BlockWindowModel(CFG, FP32, attn_impl="pallas_interpret", all_heads=True)
+    padded = jnp.asarray([[0] * (96 - n) + tokens], jnp.int32)
+    kv_start = jnp.asarray([96 - n], jnp.int32)
+    logits, cache = model.apply(
+        {"params": params}, padded, jnp.maximum(jnp.arange(96)[None] - kv_start[:, None], 0),
+        bwm.make_block_window_cache(CFG, 1, 160, jnp.float32), kv_start, jnp.full((1,), 96, jnp.int32), jnp.int32(0))
+    assert all(np.isfinite(np.asarray(a)).all() for a in (logits, cache.k, cache.v))
+    np.testing.assert_allclose(np.asarray(logits[0, 96 - n:]).reshape(n, -1, V), reference(params, tokens), atol=ATOL)
+
+
 def test_two_rows_of_one_bucket_with_different_left_padding(params):
     rows = [prompt_of(100, 2), prompt_of(77, 3), prompt_of(50, 4)]
     lengths = [90, 41, 33]  # windows and chunks fall at different slots in every row
@@ -439,8 +507,8 @@ def test_continuous_engine_and_tp_refuse(params):
 
 def test_the_family_row(params):
     fam = families.of(CFG)
-    assert fam.counter_names == bwm.COUNTER_NAMES and fam.counters_width == 5
-    assert set(fam.counter_names) == set(bwm.fold_counters(np.zeros(5)))
+    assert fam.counter_names == bwm.COUNTER_NAMES and fam.counters_width == 7
+    assert set(fam.counter_names) == set(bwm.fold_counters(np.zeros(7)))
     assert fam.checkpoint_loader_refusal and "name map" in fam.checkpoint_loader_refusal
     assert fam.verify_span is bwm.verify_span
     from rag_llm_k8s_tpu.core.config import LlamaConfig

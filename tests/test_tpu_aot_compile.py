@@ -645,6 +645,72 @@ def test_hybrid_live_suffix_branches_copy_neither_stream_nor_stacked_weight(one_
     assert body >= 8 and 4 * sum(others) <= body, counted
 
 
+def test_block_window_live_blocks_loop_without_branch_or_copy(one_chip, uncached):
+    """The block-window family's prefill at the served bucket and the
+    published widths (two rows of three layers: the fewest at which the
+    layers' loop stays rolled; +7 s of tier 1): a row's layer runs its norms,
+    projections, rotation and FFN in two loops over the row's live blocks
+    (``models/block_window.py LIVE_BLOCK``; trip counts read from ``kv_start``)
+    around the two kernels. No branch anywhere; no copy of the stream
+    ``f32[1,20480,4096]`` or of a row buffer ``bf16[32,20480,128]`` outside
+    the entry computation (left to itself the compiler carries q's and k's
+    buffers in the rotation's layout and re-lays all of them behind the loop,
+    twice a layer-row: PERF.md, PR 42), none of a layer's kernel in an inner
+    loop's body (once a block, not once a layer-row); and what
+    ``benchmark/lib/phases.py`` counts a prefill's rows by, the instructions one
+    loop beneath ``prefill``, are the layers' loop body's: the kernels, the
+    planes' writes, the ring's slices and the weights' slices stayed there."""
+    from rag_llm_k8s_tpu.core.config import (
+        BlockWindowConfig, DTypePolicy, EngineConfig, GoodputConfig, SamplingConfig,
+    )
+    from rag_llm_k8s_tpu.engine import engine as engine_mod
+    from rag_llm_k8s_tpu.models.block_window import init_block_window_params
+
+    cfg = BlockWindowConfig(num_hidden_layers=3)
+    dt = DTypePolicy()
+    shapes = jax.eval_shape(lambda: init_block_window_params(jax.random.PRNGKey(0), cfg, dt))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    ec = EngineConfig(prompt_buckets=(20480,), max_seq_len=20992, attn_impl="pallas", speculative="off",
+                      goodput=GoodputConfig(enabled=False))
+    eng = engine_mod.InferenceEngine(
+        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=2), engine_config=ec, dtypes=dt)
+    tok = jax.ShapeDtypeStruct((2, 20480), I32, sharding=one_chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(eng._make_gen(2, 20480, 2)).lower(params, tok, tok, rng).compile().as_text()
+    assert " conditional(" not in text
+    from benchmark.lib.phases import one_loop_beneath
+
+    row = re.compile(r"= (f32\[1,20480,4096\]|bf16\[32,20480,128\])\S* copy\(")
+    kernel = re.compile(r"= bf16\[(4096,4096|4096,11008|11008,4096)\]\S* copy\(")
+    computations, layer_bodies, counted = {}, [], {}
+    for computation in text.split("\n\n"):
+        lines = computation.strip().splitlines()
+        if not lines or lines[0].lstrip().startswith(("%fused", "fused")):
+            continue
+        name = lines[0].lstrip().split(" ")[0]
+        computations[name] = lines
+        if any("%window_summary_flash_attention" in line and " custom-call(" in line for line in lines):
+            layer_bodies.append(name)
+        if not name.startswith("ENTRY"):
+            found = [line.strip()[:160] for line in lines if row.search(line)]
+            assert not found, found
+        paths = (re.search(r'op_name="([^"]*)"', line) for line in lines[1:]
+                 if re.search(r" (fusion|copy|custom-call)\(", line))
+        units = [one_loop_beneath(m.group(1)) for m in paths if m]
+        counted[name] = sum(1 for u in units if u and u[0] == "prefill")
+    assert len(layer_bodies) == 2, layer_bodies  # a row each, three trips
+    for body in layer_bodies:
+        inner = re.findall(r"body=(%[\w.\-]+)", "\n".join(computations[body]))
+        assert len(inner) == 2, inner  # in front of the kernels, and behind them
+        for name in inner:
+            lines = computations[name]
+            assert any(" convolution(" in line or "convolution" in line for line in lines), name  # the matmuls are here
+            found = [line.strip()[:160] for line in lines if kernel.search(line)]
+            assert not found, found
+    others = sum(n for name, n in counted.items() if name not in layer_bodies)
+    assert all(counted[b] >= 8 and 4 * others <= counted[b] for b in layer_bodies), counted
+
+
 @pytest.mark.parametrize("batch", [1, 8])
 def test_live_suffix_branches_copy_no_stacked_weight(batch, one_chip, uncached):
     """The prefill of a bucket with rungs (``models/llama.py live_offsets``)
