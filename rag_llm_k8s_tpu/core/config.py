@@ -186,6 +186,21 @@ class LlamaConfig:
         )
 
 
+    def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
+        """(FLOPs a token, weight bytes, KV bytes a position of context): what
+        ``obs/goodput.py ledger_for`` asks of EVERY model configuration class,
+        under this name and signature (duck-typed: it imports nothing of the
+        package). The Llama arithmetic itself lives in ``obs/goodput.py
+        llama_roofline_terms``, over plain numbers, because the simulator
+        prices windows with it and may import no configuration."""
+        from rag_llm_k8s_tpu.obs.goodput import llama_roofline_terms
+
+        return llama_roofline_terms(
+            self.num_layers, self.hidden_size, self.num_heads, self.num_kv_heads, self.head_dim,
+            self.intermediate_size, self.vocab_size,
+            weight_bytes_per_param=1.0 if weight_quant == "int8" else 2.0, kv_quant=kv_quant)
+
+
 @dataclass(frozen=True)
 class YarnScalingConfig:
     """YaRN RoPE scaling as the latent-attention family publishes it
@@ -330,6 +345,44 @@ class LatentMoEConfig:
         every query head has keys and values of its own, rebuilt from the one
         latent; what divides over chips like a KV head count does."""
         return self.num_heads
+
+    def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
+        """(FLOPs a token, weight bytes, KV bytes a position of context), as
+        ``LlamaConfig.roofline_terms`` (the family is served in bf16 only:
+        ``models/families.py`` refuses the rest, so neither argument is read).
+
+        ``flops_per_token`` counts the parameters a token is multiplied by: the
+        latent attention's projections, the dense layers' FFN, and a MoE layer's
+        router, shared expert and the routed experts a balanced router sends to
+        those HELD here (``num_experts_per_tok * held`` over the router's
+        outputs, zero-computation ones included: those multiply nothing). A
+        shortcut-connected layer (``sublayers_per_layer`` 2) has two attentions
+        and a dense FFN beside each. ``weight_bytes`` is what a decode step
+        streams at batch 1: attention, router, dense FFNs and shared expert
+        whole, but only the held experts a token's choices hit, never all held
+        (a batch hits more; bf16, 2 bytes). ``kv_bytes_per_token`` is one
+        position's latent row over all cache planes."""
+        d, H = int(self.hidden_size), int(self.num_heads)
+        sub = self.sublayers_per_layer
+        attn = (
+            d * self.q_lora_rank + self.q_lora_rank * H * self.qk_head_dim
+            + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+            + self.kv_lora_rank * H * (self.qk_nope_head_dim + self.v_head_dim)
+            + H * self.v_head_dim * d
+        )
+        expert = 3 * d * self.moe_intermediate_size
+        dense_ffn = 3 * d * self.intermediate_size
+        routed_here = self.num_experts_per_tok * self.experts_held / self.router_width
+        moe_layer = sub * attn + d * self.router_width + (self.n_shared_experts + routed_here) * expert
+        if sub > 1:
+            moe_layer += sub * dense_ffn
+        dense_layer = attn + dense_ffn
+        active = (
+            self.first_k_dense * dense_layer + self.num_moe_layers * moe_layer
+            + self.vocab_size * d
+        )
+        return (2.0 * active, 2.0 * active,
+                2.0 * self.num_cache_planes * (self.kv_lora_rank + self.qk_rope_head_dim))
 
     @classmethod
     def tiny(cls, vocab_size: int = 256, **overrides) -> "LatentMoEConfig":
@@ -519,6 +572,25 @@ class WindowedMoEConfig:
     topk_group = property(lambda self: 1)
     zero_expert_num = property(lambda self: 0)
 
+    def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
+        """(FLOPs a token, weight bytes, KV bytes a position of context), as
+        ``LlamaConfig.roofline_terms`` (bf16 only, neither argument read):
+        every layer's attention at ITS head count (q, k, v, o and the per-head
+        gate), a dense layer's FFN, a sparse layer's router, shared expert and
+        the routed experts a balanced router sends to those held here (as
+        ``LatentMoEConfig.roofline_terms``). ``kv_bytes_per_token`` is one
+        position's K and V over all planes: every plane keeps every position,
+        a sliding layer's too."""
+        d, K, hd = int(self.hidden_size), int(self.num_kv_heads), int(self.head_dim)
+        expert = 3 * d * self.moe_intermediate_size
+        routed_here = self.num_experts_per_tok * self.experts_held / self.num_experts
+        sparse_ffn = d * self.num_experts + expert * routed_here + 3 * d * self.shared_expert_intermediate_size
+        active = self.vocab_size * d
+        for heads, ffn in zip(self.num_attention_heads_per_layer, self.mlp_layer_types):
+            active += 2 * d * heads * hd + 2 * d * K * hd + d * heads
+            active += 3 * d * self.intermediate_size if ffn == "dense" else sparse_ffn
+        return 2.0 * active, 2.0 * active, 2.0 * 2 * self.num_layers * K * hd
+
     @classmethod
     def tiny(cls, vocab_size: int = 256, **overrides) -> "WindowedMoEConfig":
         """Miniature config for CPU tests: a dense full layer, then two
@@ -593,6 +665,22 @@ class BlockWindowConfig:
     num_kv_heads = property(lambda self: self.num_key_value_heads)
     head_dim = property(lambda self: self.hidden_size // self.num_attention_heads)
     chunks_per_window = property(lambda self: self.window_size // self.chunk_size)
+
+    def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
+        """(FLOPs a token, weight bytes, KV bytes a position of context), as
+        ``LlamaConfig.roofline_terms`` (bf16 only, neither argument read): the
+        dense decoder's matmuls with the head's ``num_pred_heads`` column
+        blocks. ``kv_bytes_per_token`` is what one more position of context
+        costs a decode step to read: a pooled key and value every
+        ``chunk_size`` positions (the ring of the query's own window, at most
+        ``window_size`` exact slots, does not grow with the context and is
+        left out of this linear term)."""
+        from rag_llm_k8s_tpu.obs.goodput import llama_roofline_terms
+
+        flops, weight_bytes, kv_bytes = llama_roofline_terms(
+            self.num_layers, self.hidden_size, self.num_heads, self.num_kv_heads, self.head_dim,
+            self.intermediate_size, self.vocab_size * self.num_pred_heads)
+        return flops, weight_bytes, kv_bytes / self.chunk_size
 
     @classmethod
     def tiny(cls, vocab_size: int = 256, **overrides) -> "BlockWindowConfig":
@@ -675,6 +763,27 @@ class HybridSSMConfig:
     @property
     def num_state_layers(self) -> int:
         return self.num_hidden_layers - self.num_attention_layers
+
+    def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
+        """(FLOPs a token, weight bytes, KV bytes a position of context), as
+        ``LlamaConfig.roofline_terms`` (bf16 only, neither argument read). A
+        token's matmuls: every layer's SwiGLU, a state layer's four
+        projections, an attention layer's four, the head.
+        ``kv_bytes_per_token`` is what one more position of context costs a
+        decode step to read: the attention layers' keys and values only (a
+        state layer's state does not grow with the context; its bytes, read
+        and written once a step, ride ``weight_bytes``)."""
+        D, F, Di = self.hidden_size, self.intermediate_size, self.d_inner
+        N, R = self.mamba_d_state, self.mamba_dt_rank
+        H, K, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        ffn = 3 * D * F
+        state = 2 * D * Di + Di * (R + 2 * N) + R * Di + Di * D
+        attention = 2 * D * H * hd + 2 * D * K * hd
+        M, Na = self.num_state_layers, self.num_attention_layers
+        head = D * self.vocab_size
+        params = self.num_layers * ffn + M * state + Na * attention + head
+        state_bytes = M * 2 * (4 * N * Di + 2 * (self.mamba_d_conv - 1) * Di)  # read and written a step
+        return 2.0 * params, 2.0 * params + state_bytes, 2.0 * Na * 2 * K * hd
 
     @classmethod
     def tiny(cls, vocab_size: int = 256, **overrides) -> "HybridSSMConfig":

@@ -66,6 +66,7 @@ __all__ = [
     "GoodputLedger",
     "RooflineModel",
     "ledger_for",
+    "llama_roofline_terms",
     "merge_states",
     "peaks_for_device",
     "render_report",
@@ -182,21 +183,14 @@ class RooflineModel:
         return nbytes / max(seconds * self.peak_bytes, 1e-30)
 
 
-def roofline_for_llama(
-    num_layers: int,
-    hidden_size: int,
-    num_heads: int,
-    num_kv_heads: int,
-    head_dim: int,
-    intermediate_size: int,
-    vocab_size: int,
-    weight_bytes_per_param: float = 2.0,
-    kv_quant: str = "bf16",
-    *,
-    peak_tflops: float,
-    hbm_gbs: float,
-) -> RooflineModel:
-    """The serving stack's roofline from a LlamaConfig's fields.
+def llama_roofline_terms(
+    num_layers: int, hidden_size: int, num_heads: int, num_kv_heads: int, head_dim: int,
+    intermediate_size: int, vocab_size: int, weight_bytes_per_param: float = 2.0, kv_quant: str = "bf16",
+) -> Tuple[float, float, float]:
+    """(FLOPs a token, weight bytes, KV bytes a position) of a Llama-shaped
+    decoder, from plain numbers: THE place this arithmetic lives (the
+    configuration classes of ``core/config.py`` whose decoder has this shape
+    call it; the simulator reaches it through ``roofline_for_llama``).
 
     ``flops_per_token ≈ 2 × matmul params`` (attention-score FLOPs are
     context-dependent and second-order at serving context lengths —
@@ -222,122 +216,30 @@ def roofline_for_llama(
     # (lm head included via matmul_params); the embedding table is a
     # per-token row gather, not a full stream — counting it would
     # overstate decode bytes ~7% at 8B scale
-    return RooflineModel(
-        flops_per_token=2.0 * matmul_params,
-        weight_bytes=matmul_params * float(weight_bytes_per_param),
-        kv_bytes_per_token=float(kv_bytes),
-        peak_tflops=peak_tflops,
-        hbm_gbs=hbm_gbs,
+    return 2.0 * matmul_params, matmul_params * float(weight_bytes_per_param), float(kv_bytes)
+
+
+def roofline_for_llama(
+    num_layers: int,
+    hidden_size: int,
+    num_heads: int,
+    num_kv_heads: int,
+    head_dim: int,
+    intermediate_size: int,
+    vocab_size: int,
+    weight_bytes_per_param: float = 2.0,
+    kv_quant: str = "bf16",
+    *,
+    peak_tflops: float,
+    hbm_gbs: float,
+) -> RooflineModel:
+    """``llama_roofline_terms`` at a chip's peaks, for a caller that holds
+    numbers and no configuration (``sim/simulator.py``)."""
+    flops, weight_bytes, kv_bytes = llama_roofline_terms(
+        num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
+        intermediate_size, vocab_size, weight_bytes_per_param, kv_quant,
     )
-
-
-def roofline_for_latent_moe(cfg, *, peak_tflops: float, hbm_gbs: float) -> RooflineModel:
-    """The roofline of the latent-attention sparse-expert family, from a
-    ``LatentMoEConfig``'s fields (duck-typed, like ``roofline_for_llama``).
-
-    ``flops_per_token`` counts the parameters a token is multiplied by: the
-    latent attention's projections, the dense layers' FFN, and a MoE layer's
-    router, shared expert and the routed experts a balanced router sends to
-    those HELD here (``num_experts_per_tok * held`` over the router's
-    outputs, zero-computation ones included: those multiply nothing). A
-    shortcut-connected layer (``sublayers_per_layer`` 2) has two attentions
-    and a dense FFN beside each. ``weight_bytes`` is what a decode step
-    streams at batch 1: attention, router, dense FFNs and shared expert
-    whole, but only the held experts a token's choices hit, never all held
-    (a batch hits more; bf16, 2 bytes). ``kv_bytes_per_token`` is one
-    position's latent row over all cache planes."""
-    d, H = int(cfg.hidden_size), int(cfg.num_heads)
-    sub = cfg.sublayers_per_layer
-    attn = (
-        d * cfg.q_lora_rank + cfg.q_lora_rank * H * cfg.qk_head_dim
-        + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-        + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim + cfg.v_head_dim)
-        + H * cfg.v_head_dim * d
-    )
-    expert = 3 * d * cfg.moe_intermediate_size
-    dense_ffn = 3 * d * cfg.intermediate_size
-    routed_here = cfg.num_experts_per_tok * cfg.experts_held / cfg.router_width
-    moe_layer = sub * attn + d * cfg.router_width + (cfg.n_shared_experts + routed_here) * expert
-    if sub > 1:
-        moe_layer += sub * dense_ffn
-    dense_layer = attn + dense_ffn
-    active = (
-        cfg.first_k_dense * dense_layer + cfg.num_moe_layers * moe_layer
-        + cfg.vocab_size * d
-    )
-    return RooflineModel(
-        flops_per_token=2.0 * active,
-        weight_bytes=2.0 * active,
-        kv_bytes_per_token=2.0 * cfg.num_cache_planes * (cfg.kv_lora_rank + cfg.qk_rope_head_dim),
-        peak_tflops=peak_tflops,
-        hbm_gbs=hbm_gbs,
-    )
-
-
-def roofline_for_windowed_moe(cfg, *, peak_tflops: float, hbm_gbs: float) -> RooflineModel:
-    """The roofline of the windowed-attention sparse-expert family, from a
-    ``WindowedMoEConfig``'s fields (duck-typed): every layer's attention at
-    ITS head count (q, k, v, o and the per-head gate), a dense layer's FFN,
-    a sparse layer's router, shared expert and the routed experts a balanced
-    router sends to those held here (as ``roofline_for_latent_moe``).
-    ``kv_bytes_per_token`` is one position's K and V over all planes: every
-    plane keeps every position, a sliding layer's too."""
-    d, K, hd = int(cfg.hidden_size), int(cfg.num_kv_heads), int(cfg.head_dim)
-    expert = 3 * d * cfg.moe_intermediate_size
-    routed_here = cfg.num_experts_per_tok * cfg.experts_held / cfg.num_experts
-    sparse_ffn = d * cfg.num_experts + expert * routed_here + 3 * d * cfg.shared_expert_intermediate_size
-    active = cfg.vocab_size * d
-    for heads, ffn in zip(cfg.num_attention_heads_per_layer, cfg.mlp_layer_types):
-        active += 2 * d * heads * hd + 2 * d * K * hd + d * heads
-        active += 3 * d * cfg.intermediate_size if ffn == "dense" else sparse_ffn
-    return RooflineModel(
-        flops_per_token=2.0 * active,
-        weight_bytes=2.0 * active,
-        kv_bytes_per_token=2.0 * 2 * cfg.num_layers * K * hd,
-        peak_tflops=peak_tflops,
-        hbm_gbs=hbm_gbs,
-    )
-
-
-def roofline_for_block_window(cfg, *, peak_tflops: float, hbm_gbs: float) -> RooflineModel:
-    """The roofline of the block-window pooled-summary family, from a
-    ``BlockWindowConfig``'s fields (duck-typed): the dense decoder's matmuls
-    with the head's ``num_pred_heads`` column blocks. ``kv_bytes_per_token``
-    is what one more position of context costs a decode step to read: a
-    pooled key and value every ``chunk_size`` positions (the ring of the
-    query's own window, at most ``window_size`` exact slots, does not grow
-    with the context and is left out of this linear term)."""
-    base = roofline_for_llama(
-        cfg.num_layers, cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-        cfg.intermediate_size, cfg.vocab_size * cfg.num_pred_heads, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs)
-    return RooflineModel(
-        flops_per_token=base.flops_per_token, weight_bytes=base.weight_bytes,
-        kv_bytes_per_token=base.kv_bytes_per_token / cfg.chunk_size,
-        peak_tflops=peak_tflops, hbm_gbs=hbm_gbs)
-
-
-def roofline_for_hybrid_ssm(cfg, *, peak_tflops: float, hbm_gbs: float) -> RooflineModel:
-    """The roofline of the hybrid state-space family, from a
-    ``HybridSSMConfig``'s fields (duck-typed). A token's matmuls: every
-    layer's SwiGLU, a state layer's four projections, an attention layer's
-    four, the head. ``kv_bytes_per_token`` is what one more position of
-    context costs a decode step to read: the attention layers' keys and
-    values only (a state layer's state does not grow with the context; its
-    bytes, read and written once a step, ride ``weight_bytes``)."""
-    D, F, Di = cfg.hidden_size, cfg.intermediate_size, cfg.d_inner
-    N, R = cfg.mamba_d_state, cfg.mamba_dt_rank
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    ffn = 3 * D * F
-    state = 2 * D * Di + Di * (R + 2 * N) + R * Di + Di * D
-    attention = 2 * D * H * hd + 2 * D * K * hd
-    M, Na = cfg.num_state_layers, cfg.num_attention_layers
-    head = D * cfg.vocab_size
-    params = cfg.num_layers * ffn + M * state + Na * attention + head
-    state_bytes = M * 2 * (4 * N * Di + 2 * (cfg.mamba_d_conv - 1) * Di)  # read and written a step
-    return RooflineModel(
-        flops_per_token=2.0 * params, weight_bytes=2.0 * params + state_bytes,
-        kv_bytes_per_token=2.0 * Na * 2 * K * hd,
-        peak_tflops=peak_tflops, hbm_gbs=hbm_gbs)
+    return RooflineModel(flops, weight_bytes, kv_bytes, peak_tflops, hbm_gbs)
 
 
 def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger":
@@ -345,7 +247,11 @@ def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger"
     the config dataclasses — still no package imports). One site means the
     two engines' rooflines cannot drift: ``merge_states`` sums their
     states into one report, which is only meaningful when both were
-    derived from the same arithmetic. A peak the config leaves unpinned
+    derived from the same arithmetic. The arithmetic is the model
+    configuration's own: ``model_config.roofline_terms(weight_quant,
+    kv_quant)`` -> (FLOPs a token, weight bytes, KV bytes a position of
+    context); a configuration without it is an error, never another
+    family's figures. A peak the config leaves unpinned
     resolves from ``device_kind`` (``peaks_for_device``: an unknown kind
     raises) — only for an enabled ledger; a disabled one prices nothing
     and holds no roofline."""
@@ -359,30 +265,19 @@ def ledger_for(model_config, engine_config, device_kind: str) -> "GoodputLedger"
             kind_tflops, kind_gbs = peaks_for_device(device_kind)
             peak_tflops = peak_tflops if peak_tflops > 0 else kind_tflops
             hbm_gbs = hbm_gbs if hbm_gbs > 0 else kind_gbs
-        # the family is told by what the configuration HAS (no package
-        # imports here): a latent cache's rank, layers of several kinds, a
-        # pooled summary every chunk, a recurrent state, or per-head K/V alike in every layer
-        roofline = roofline_for_latent_moe(
-            model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
-        ) if hasattr(model_config, "kv_lora_rank") else roofline_for_windowed_moe(
-            model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
-        ) if hasattr(model_config, "layer_types") else roofline_for_block_window(
-            model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
-        ) if hasattr(model_config, "chunk_size") else roofline_for_hybrid_ssm(
-            model_config, peak_tflops=peak_tflops, hbm_gbs=hbm_gbs,
-        ) if hasattr(model_config, "mamba_d_state") else roofline_for_llama(
-            model_config.num_layers, model_config.hidden_size,
-            model_config.num_heads, model_config.num_kv_heads,
-            model_config.head_dim, model_config.intermediate_size,
-            model_config.vocab_size,
-            weight_bytes_per_param=(
-                1.0 if getattr(engine_config, "weight_quant", "bf16") == "int8"
-                else 2.0
-            ),
+        terms = getattr(model_config, "roofline_terms", None)
+        if terms is None:
+            raise TypeError(
+                f"{type(model_config).__name__} has no roofline_terms(weight_quant, "
+                "kv_quant): a model configuration states its own FLOPs a token, "
+                "weight bytes and KV bytes a position (core/config.py), or the "
+                "ledger is switched off (TPU_RAG_GOODPUT=0)"
+            )
+        flops, weight_bytes, kv_bytes = terms(
+            weight_quant=getattr(engine_config, "weight_quant", "bf16"),
             kv_quant=getattr(engine_config, "kv_quant", "bf16"),
-            peak_tflops=peak_tflops,
-            hbm_gbs=hbm_gbs,
         )
+        roofline = RooflineModel(flops, weight_bytes, kv_bytes, peak_tflops, hbm_gbs)
     return GoodputLedger(
         roofline,
         enabled=enabled,

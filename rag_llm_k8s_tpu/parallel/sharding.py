@@ -110,38 +110,20 @@ def llama_param_specs(params, ctx: MeshContext):
     return traverse_util.unflatten_dict(specs)
 
 
-def _replicated_specs(params, ctx: MeshContext, tree: str):
-    if ctx.tp > 1:
+def replicated_param_specs(params, ctx: MeshContext, tree: str):
+    """PartitionSpec pytree with every leaf replicated: THE partition rule of
+    a family that has none of its own (``models/families.py`` binds ``tree``,
+    the name a refusal gives the tree, from the family's row). Such a family
+    is served at tp = sp = 1: a mesh with more is refused here in the words
+    of ``families.refuse_unsupported``, so a caller that takes its specs from
+    the row directly cannot hand a replicated tree to a mesh that would split it."""
+    if ctx.tp > 1 or ctx.sp > 1:
         raise NotImplementedError(
-            f"tp={ctx.tp}: the {tree} tree has no "
-            "tensor-parallel partition rules (tp must be 1)"
+            f"tp={ctx.tp}, sp={ctx.sp}: the {tree} tree has no "
+            "partition rules (tp and sp must be 1)"
         )
     flat = traverse_util.flatten_dict(params)
     return traverse_util.unflatten_dict({p: P(*(None,) * leaf.ndim) for p, leaf in flat.items()})
-
-
-def latent_moe_param_specs(params, ctx: MeshContext):
-    """PartitionSpec pytree for the ``LatentMoEModel`` layout: every leaf
-    replicated. The family is served at tp = 1 (``models/families.py``
-    refuses more by name): its latent projections and its expert stack have
-    no partition rules yet, and experts across chips need the all-to-all."""
-    return _replicated_specs(params, ctx, "latent-attention sparse-expert")
-
-
-def windowed_moe_param_specs(params, ctx: MeshContext):
-    """The same for the ``WindowedMoEModel`` layout: its projections differ
-    in shape by layer kind and its expert stack has no partition rules yet."""
-    return _replicated_specs(params, ctx, "windowed-attention sparse-expert")
-
-
-def block_window_param_specs(params, ctx: MeshContext):
-    """The same for the ``BlockWindowModel`` layout (layers stacked a leaf)."""
-    return _replicated_specs(params, ctx, "block-window pooled-summary")
-
-
-def hybrid_ssm_param_specs(params, ctx: MeshContext):
-    """The same for the ``HybridSSMModel`` layout (leaves stacked by layer kind)."""
-    return _replicated_specs(params, ctx, "hybrid state-space")
 
 
 def shard_params(params, specs, ctx: MeshContext):

@@ -365,11 +365,9 @@ def test_tp_refuses_and_the_roofline_counts_the_block(params):
     mesh = make_mesh(MeshConfig(dp=1, sp=1, tp=2), devices=jax.devices()[:2])
     with pytest.raises(NotImplementedError, match="tp=2"):
         InferenceEngine(CFG, params, sampling=GREEDY, engine_config=EngineConfig(), dtypes=FP32, mesh=mesh)
-    from rag_llm_k8s_tpu.obs.goodput import roofline_for_latent_moe
-
     plain = dataclasses.replace(CFG, sublayers_per_layer=1, zero_expert_num=0, n_shared_experts=1)
-    one = roofline_for_latent_moe(plain, peak_tflops=197.0, hbm_gbs=819.0)
-    two = roofline_for_latent_moe(CFG, peak_tflops=197.0, hbm_gbs=819.0)
+    one_flops, _, one_kv = plain.roofline_terms()
+    two_flops, _, two_kv = CFG.roofline_terms()
     # a plane an attention sublayer; two attentions and two dense FFNs a layer
-    assert two.kv_bytes_per_token == 2 * one.kv_bytes_per_token
-    assert two.flops_per_token > one.flops_per_token
+    assert two_kv == 2 * one_kv
+    assert two_flops > one_flops
