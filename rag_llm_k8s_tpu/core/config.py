@@ -335,6 +335,8 @@ class LatentMoEConfig:
         """The router's outputs: the routed experts, then the zero ones."""
         return self.n_routed_experts + self.zero_expert_num
 
+    norm_topk_eps = property(lambda self: 1e-20)  # in the normalising sum of the chosen scores
+
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -571,6 +573,7 @@ class WindowedMoEConfig:
     n_group = property(lambda self: 1)
     topk_group = property(lambda self: 1)
     zero_expert_num = property(lambda self: 0)
+    norm_topk_eps = property(lambda self: 1e-20)
 
     def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
         """(FLOPs a token, weight bytes, KV bytes a position of context), as
@@ -794,6 +797,160 @@ class HybridSSMConfig:
             num_attention_heads=4, num_key_value_heads=1, attn_layer_period=4, attn_layer_offset=1,
             mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=4, max_seq_len=512,
             bos_token_id=1, eos_token_ids=(2,),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
+class ConvMoEConfig:
+    """The gated short-convolution, sparse-expert decoder family
+    (``models/conv_moe.py``). Every layer is ``h = x + Op(RMS(x))``, ``y = h +
+    FFN(RMS(h))``. ``layer_types`` names each layer's operator: ``conv``, a
+    GATED SHORT CONVOLUTION (``[B, C, u] = x W_in``, a depthwise causal
+    convolution of ``conv_L_cache`` taps over ``B * u`` with no activation and
+    no bias, ``W_out (C * c)``), which keeps the last ``conv_L_cache - 1``
+    gated inputs a row and nothing by position; or ``full_attention``,
+    grouped-query attention whose queries and keys are RMS-normed over the
+    head (one scale for all heads) before they are rotated. The first
+    ``num_dense_layers`` layers' FFN is a dense SwiGLU (``intermediate_size``),
+    every later layer's a routed mixture of ``num_experts`` SwiGLU experts
+    (``moe_intermediate_size``): sigmoid scores, the top
+    ``num_experts_per_tok`` of score plus ``expert_bias`` chosen, the scores
+    at the chosen normalised with the published ``1e-6`` (``norm_topk_eps``)
+    and scaled; no shared expert. Field names are the published
+    ``config.json``'s (``num_hidden_layers`` is ``len(layer_types)``).
+
+    The layers' loop runs a PERIOD a trip, because operator shapes differ by
+    kind: the dense layers sit outside it (``lead_<i>``), what follows is
+    whole periods of one pattern of operators (``period``: the shortest), and
+    a last part of a period, where the depth leaves one, sits behind the loop
+    (``tail_<i>``: the published 40 layers are 2 + 9 periods of 4 + 2).
+    ``ep_size`` / ``ep_rank``: this chip's share of the routed experts, as
+    ``LatentMoEConfig`` (1: every expert held).
+
+    Defaults are the published widths of the 24B-A2B model at the depth of
+    stage 0 of a four-stage pipeline (the ``lfm2-24b-a2b-pp4.solo`` cell)."""
+
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    intermediate_size: int = 11776
+    moe_intermediate_size: int = 1536
+    layer_types: Tuple[str, ...] = ("conv", "conv") + ("full_attention", "conv", "conv", "conv") * 2
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    use_expert_bias: bool = True
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    ep_size: int = 1
+    ep_rank: int = 0
+    norm_eps: float = 1e-5
+    rope_theta: float = 1000000.0
+    max_seq_len: int = 128000
+    tie_word_embeddings: bool = True
+    bos_token_id: int = 1
+    eos_token_ids: Tuple[int, ...] = (2,)
+
+    KINDS = ("conv", "full_attention")
+
+    def __post_init__(self):
+        if not self.layer_types or set(self.layer_types) - set(self.KINDS):
+            raise ValueError(f"layer_types names every layer once, each one of {self.KINDS}")
+        if not 0 <= self.num_dense_layers <= self.num_layers:
+            raise ValueError("num_dense_layers: a leading run of the layers")
+        if self.hidden_size % self.num_attention_heads or self.head_dim % 2:
+            raise ValueError("hidden_size is a whole number of heads of an even size")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads is a whole number of groups of num_key_value_heads")
+        if self.conv_L_cache < 2:
+            raise ValueError("conv_L_cache: the convolution keeps at least one earlier input")
+        if self.conv_bias or not self.use_expert_bias:
+            raise ValueError("this family's convolution has no bias and its router a selection bias")
+        if self.num_experts % self.ep_size or not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(
+                f"ep_size={self.ep_size}, ep_rank={self.ep_rank}: the {self.num_experts} routed "
+                "experts must divide evenly over the ranks and the rank must be one of them")
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError("num_experts_per_tok: at most every expert")
+
+    # the names the rest of the program reads a decoder's sizes by
+    num_layers = property(lambda self: len(self.layer_types))
+    num_heads = property(lambda self: self.num_attention_heads)
+    num_kv_heads = property(lambda self: self.num_key_value_heads)
+    head_dim = property(lambda self: self.hidden_size // self.num_attention_heads)
+    rms_norm_eps = property(lambda self: self.norm_eps)
+    num_lead = property(lambda self: self.num_dense_layers)  # outside the layers' loop
+
+    @property
+    def period(self) -> int:
+        """Layers a trip of the loop: the shortest pattern of operators that
+        the layers behind the dense ones repeat (its last copy may be cut)."""
+        rest = self.layer_types[self.num_lead:]
+        return next((p for p in range(1, len(rest) + 1)
+                     if all(rest[i] == rest[i % p] for i in range(len(rest)))), 1)
+
+    @property
+    def num_periods(self) -> int:
+        return (self.num_layers - self.num_lead) // self.period
+
+    @property
+    def num_tail(self) -> int:
+        """Layers of a last, cut period: behind the loop."""
+        return (self.num_layers - self.num_lead) % self.period
+
+    num_attention_layers = property(lambda self: self.layer_types.count("full_attention"))
+    num_conv_layers = property(lambda self: self.layer_types.count("conv"))
+
+    # what ``models/latent_moe.py``'s sparse FFN (SparseMLP, Experts) reads of
+    # a configuration, under the names it reads them by
+    num_moe_layers = property(lambda self: self.num_layers - self.num_lead)
+    experts_held = property(lambda self: self.num_experts // self.ep_size)
+    first_held = property(lambda self: self.ep_rank * self.experts_held)
+    n_routed_experts = property(lambda self: self.num_experts)
+    router_width = property(lambda self: self.num_experts)
+    n_shared_experts = property(lambda self: 0)
+    scoring_func = property(lambda self: "sigmoid")
+    n_group = property(lambda self: 1)
+    topk_group = property(lambda self: 1)
+    zero_expert_num = property(lambda self: 0)
+    norm_topk_eps = property(lambda self: 1e-6)  # as published, in the normalising sum
+
+    def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
+        """(FLOPs a token, weight bytes, KV bytes a position of context), as
+        ``LlamaConfig.roofline_terms`` (bf16 only, neither argument read). A
+        token's matmuls: a conv operator's two projections or an attention
+        operator's four, a dense layer's SwiGLU or a sparse layer's router
+        and the ``num_experts_per_tok`` experts it chooses of those held here,
+        the head. ``weight_bytes`` is what a decode step streams at batch 1:
+        the experts a step HITS, never all that are held (a batch hits more),
+        and the conv layers' kept inputs, read and written once a step.
+        ``kv_bytes_per_token`` is one position's keys and values over the
+        attention layers only: a conv layer's state does not grow."""
+        D, H, K, hd = self.hidden_size, self.num_heads, self.num_kv_heads, self.head_dim
+        conv, attention = 4 * D * D, 2 * D * H * hd + 2 * D * K * hd
+        routed_here = self.num_experts_per_tok * self.experts_held / self.num_experts
+        sparse_ffn = D * self.num_experts + routed_here * 3 * D * self.moe_intermediate_size
+        active = (self.num_conv_layers * conv + self.num_attention_layers * attention
+                  + self.num_lead * 3 * D * self.intermediate_size
+                  + self.num_moe_layers * sparse_ffn + self.vocab_size * D)
+        state_bytes = self.num_conv_layers * 2 * 2 * (self.conv_L_cache - 1) * D
+        return 2.0 * active, 2.0 * active + state_bytes, 2.0 * self.num_attention_layers * 2 * K * hd
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 256, **overrides) -> "ConvMoEConfig":
+        """Miniature config for CPU tests: two dense conv layers, then two
+        periods of (attention, conv, conv, conv); 4 query heads over 2 KV
+        heads of 16; 16 experts, all held, top 4."""
+        base = dict(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+            layer_types=("conv", "conv") + ("full_attention", "conv", "conv", "conv") * 2,
+            num_dense_layers=2, num_attention_heads=4, num_key_value_heads=2, num_experts=16,
+            num_experts_per_tok=4, max_seq_len=512, bos_token_id=1, eos_token_ids=(2,),
         )
         base.update(overrides)
         return cls(**base)

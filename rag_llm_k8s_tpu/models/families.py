@@ -31,7 +31,7 @@ from typing import Callable, Dict, Mapping, Optional, Tuple
 import jax.numpy as jnp
 
 from rag_llm_k8s_tpu.core.config import (
-    BlockWindowConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig, WindowedMoEConfig,
+    BlockWindowConfig, ConvMoEConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig, WindowedMoEConfig,
 )
 from rag_llm_k8s_tpu.parallel.sharding import llama_param_specs, replicated_param_specs
 
@@ -204,8 +204,29 @@ def _hybrid_ssm() -> Family:
         commit=hs.commit)
 
 
+def _conv_moe() -> Family:
+    from rag_llm_k8s_tpu.models import conv_moe as cm
+
+    return replicated_row(
+        "gated-convolution sparse-expert", ConvMoEConfig, cm.ConvMoEModel, cm.make_conv_cache,
+        refuses={
+            "continuous": "a convolution's kept inputs have no blocks to page, and preemption, resume and a "
+                          "per-row frontier need snapshots of them that nothing takes yet; use 'coalesce'",
+            "prefix_cache": "a convolution's state can be reused only for an exact prefix, and only if a snapshot "
+                            "was kept at its end: a spliced segment's keys and values say nothing of it",
+            "kv_quant": "the attention layers' planes (heads of 64) and the kept inputs have no int8 form here",
+            "weight_quant": "quantize_llama_params does not know this tree (operators that differ in shape "
+                            "by layer kind, the taps, stacked experts, the router)",
+            "mesh": "this tree has no partition rules (a depthwise convolution splits by channel, the heads "
+                    "by KV head), and experts across chips need the all-to-all",
+        },
+        counters_width=cm.N_COUNTERS, counter_names=cm.COUNTER_NAMES, fold_counters=cm.fold_counters,
+        commit=cm.commit)
+
+
 # configuration type -> its family (a thunk where building it imports the model)
 _TABLE: Tuple[Tuple[type, Callable[[], Family]], ...] = (
+    (ConvMoEConfig, _conv_moe),
     (HybridSSMConfig, _hybrid_ssm),
     (BlockWindowConfig, _block_window),
     (WindowedMoEConfig, _windowed_moe),

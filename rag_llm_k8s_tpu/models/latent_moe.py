@@ -363,7 +363,7 @@ class SparseMLP(nn.Module):
             experts, weights = moe.route(
                 logits, bias, top_k=c.num_experts_per_tok, n_group=c.n_group,
                 topk_group=c.topk_group, scaling=c.routed_scaling_factor,
-                normalize=c.norm_topk_prob, scoring=c.scoring_func, impl=impl)
+                normalize=c.norm_topk_prob, scoring=c.scoring_func, impl=impl, eps=c.norm_topk_eps)
         with phase_scope("experts"):
             y, counts = moe.held_expert_ffn(
                 flat, experts, weights, *experts_stack, moe_layer, c.first_held,

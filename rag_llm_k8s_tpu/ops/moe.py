@@ -102,7 +102,7 @@ def _route_kernel(
     topk_group: int,
     scaling: float,
     normalize: bool,
-    scoring: str,
+    scoring: str, eps: float = 1e-20,
 ):
     """One grid step: ``cols`` columns of ``LANES`` tokens, one after another.
     A column's logits are transposed so that experts lie along the sublanes
@@ -162,7 +162,7 @@ def _route_kernel(
             jnp.zeros((out_rows, LANES), jnp.int32), jnp.zeros((out_rows, LANES), jnp.float32),
             jnp.zeros((1, LANES), jnp.float32)))
         if normalize:
-            weights = weights / (total + 1e-20)
+            weights = weights / (total + eps)
         experts_ref[c] = experts[:top_k]
         weights_ref[c] = weights[:top_k] * scaling
         return carry
@@ -171,7 +171,7 @@ def _route_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "top_k", "n_group", "topk_group", "scaling", "normalize", "scoring", "cols", "interpret"))
+    "top_k", "n_group", "topk_group", "scaling", "normalize", "scoring", "cols", "interpret", "eps"))
 def route_topk(
     logits: jax.Array,  # [N, E] float32
     bias: jax.Array,  # [E] float32
@@ -183,7 +183,7 @@ def route_topk(
     normalize: bool,
     scoring: str,
     cols: int,  # ``route_blocks``'s
-    interpret: bool = False,
+    interpret: bool = False, eps: float = 1e-20,
 ) -> Tuple[jax.Array, jax.Array]:
     """``route`` as one kernel: the logits read once, ``experts`` and
     ``weights`` written once, and between them nothing leaves VMEM. What
@@ -198,7 +198,7 @@ def route_topk(
     shape = (N // LANES, top_k, LANES)
     experts, weights = pl.pallas_call(
         functools.partial(_route_kernel, n_group=n_group, topk_group=topk_group, scaling=scaling,
-                          normalize=normalize, scoring=scoring),
+                          normalize=normalize, scoring=scoring, eps=eps),
         grid=(N // LANES // cols,),
         in_specs=[
             pl.BlockSpec((cols, LANES, E), lambda i: (i, 0, 0)),
@@ -228,7 +228,7 @@ def route(
     topk_group: int,
     scaling: float,
     normalize: bool = True,
-    scoring: str = "sigmoid",
+    scoring: str = "sigmoid", eps: float = 1e-20,  # in the normalising sum
     impl: str = "xla",  # "xla" (lax.top_k) | "pallas" | "pallas_interpret"
 ) -> Tuple[jax.Array, jax.Array]:
     """``(experts [N, top_k] int32, weights [N, top_k] float32)``.
@@ -239,7 +239,7 @@ def route(
     ``top_k`` best experts inside them are chosen (ties to the lower index;
     ``n_group`` 1: the ``top_k`` best of all). The WEIGHT is ``s`` itself
     (never ``s + bias``) at the chosen experts, divided by their sum over all
-    ``top_k`` where ``normalize``, times ``scaling``.
+    ``top_k`` (plus ``eps``) where ``normalize``, times ``scaling``.
 
     Where ``route_blocks`` says so the kernel ``route_topk`` chooses and
     weighs in one pass over a token tile; under ``impl`` "xla" and for fewer
@@ -252,7 +252,7 @@ def route(
         return route_topk(
             logits, bias.astype(jnp.float32), top_k=top_k, n_group=n_group, topk_group=topk_group,
             scaling=float(scaling), normalize=bool(normalize), scoring=scoring, cols=cols,
-            interpret=impl == "pallas_interpret")
+            interpret=impl == "pallas_interpret", eps=float(eps))
     s = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
     choice = s + bias.astype(jnp.float32)[None, :]
     if n_group > 1:
@@ -264,7 +264,7 @@ def route(
     _, experts = jax.lax.top_k(choice, top_k)
     weights = jnp.take_along_axis(s, experts, axis=1)
     if normalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return experts.astype(jnp.int32), weights * scaling
 
 

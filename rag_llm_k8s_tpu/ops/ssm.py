@@ -199,17 +199,17 @@ def selective_scan(u, delta, z, A, B, C, D, dt_bias, h0, start, *, impl: str):
     return selective_scan_xla(u, delta, z, A, B, C, D, dt_bias, h0, start)
 
 
-def causal_conv(x, history, weight, bias):
-    """Depthwise causal convolution then ``silu``: ``x [R, S, Di]`` (zeros at
+def causal_conv(x, history, weight, bias, activate: bool = True):
+    """Depthwise causal convolution then ``silu`` (``activate``): ``x [R, S, Di]`` (zeros at
     pad positions), ``history [R, K - 1, Di]`` the inputs in front of it
-    (oldest first), ``weight [K, Di]``, ``bias [Di]``. Returns the activated
+    (oldest first), ``weight [K, Di]``, ``bias [Di]`` or None. Returns the activated
     ``[R, S, Di]`` in ``x``'s type and ``[R, K - 1 + S, Di]``, the history
     and the inputs in one run: the history after ``m`` of these positions is
     its rows ``[m, m + K - 1)``."""
     K, S = weight.shape[0], x.shape[1]
     run = jnp.concatenate([history.astype(x.dtype), x], axis=1)
     w = weight.astype(jnp.float32)
-    acc = bias.astype(jnp.float32)
+    acc = 0.0 if bias is None else bias.astype(jnp.float32)
     for j in range(K):  # out_t = b + sum_j w[j] * in_{t - (K - 1) + j}
         acc = acc + w[j] * jax.lax.slice_in_dim(run, j, j + S, axis=1).astype(jnp.float32)
-    return (acc * jax.nn.sigmoid(acc)).astype(x.dtype), run
+    return (acc * jax.nn.sigmoid(acc) if activate else acc).astype(x.dtype), run

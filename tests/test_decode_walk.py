@@ -311,7 +311,9 @@ def test_reader_of_decode_streamed_slot_share(case, before, after, want):
 def test_the_benchmark_lists_the_metric_for_the_four_batched_cells():
     """And, appended by PR 33, for the windowed family's cell, where it is a
     FULL layer's share (the sliding layers' is a metric of its own); by PR 40
-    for the hybrid state-space family's, whose two attention layers walk."""
+    for the hybrid state-space family's, whose two attention layers walk; by
+    PR 45 for the gated-convolution family's, whose heads of 64 the walk
+    refuses: its steps take the chunk form and the share reads 100."""
     import json
 
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
@@ -321,7 +323,7 @@ def test_the_benchmark_lists_the_metric_for_the_four_batched_cells():
     assert entry["source"] == "program_counter" and entry["moves"] == "latency_p50_ms"
     assert entry["workloads"] == ["mistral-7b-int8.closed8", "mistral-nemo-tp4.closed4",
                                   "dots-vlm1-ep16.closed8", "longcat-flash-ep32.closed8",
-                                  "laguna-s-ep16.closed8", "jamba2-3b.closed8"]
+                                  "laguna-s-ep16.closed8", "jamba2-3b.closed8", "lfm2-24b-a2b-pp4.solo"]
 
 
 # ---------------------------------------------------------------------------

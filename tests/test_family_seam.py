@@ -2,7 +2,8 @@
 knows of a decoder family is its configuration class, its module and its one
 row. Every literal here was computed ON THE PARENT COMMIT (902ff64) before the
 arithmetic and the refusals moved: ``ledger_for(...).roofline`` for the
-numbers, ``refuse_unsupported`` for the twenty messages."""
+numbers, ``refuse_unsupported`` for the twenty messages. (The gated-convolution
+family's, PR 45, are its own first readings: it came through the seam.)"""
 
 import dataclasses
 import pathlib
@@ -15,7 +16,7 @@ import pytest
 
 import longcat_flash_reference
 from rag_llm_k8s_tpu.core.config import (
-    BlockWindowConfig, EngineConfig, GoodputConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig,
+    BlockWindowConfig, ConvMoEConfig, EngineConfig, GoodputConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig,
     MeshConfig, PrefixCacheConfig, WindowedMoEConfig,
 )
 from rag_llm_k8s_tpu.core.mesh import make_mesh
@@ -42,6 +43,8 @@ ROOFLINES = {
     "windowed_moe-tiny": (lambda: WindowedMoEConfig.tiny(vocab_size=300), {}, (834560.0, 834560.0, 896.0)),
     "block_window-tiny": (lambda: BlockWindowConfig.tiny(vocab_size=40), {}, (204800.0, 204800.0, 128.0)),
     "hybrid_ssm-tiny": (lambda: HybridSSMConfig.tiny(vocab_size=48), {}, (796672.0, 904192.0, 128.0)),
+    # PR 45's family, by its own arithmetic: 4 experts a token-layer, the bytes of the experts a step hits
+    "conv_moe-tiny": (lambda: ConvMoEConfig.tiny(vocab_size=48), {}, (825344.0, 829440.0, 256.0)),
     # published widths, at the depth, vocabulary and expert share a cell serves
     "llama-3.1-8b": (LlamaConfig, {}, (15009316864.0, 15009316864.0, 131072.0)),
     "mistral-7b-v0.3-int8": (lambda: mistral(vocab_size=32768), INT8, (14227079168.0, 7113539584.0, 67584.0)),
@@ -61,6 +64,8 @@ ROOFLINES = {
     ), {}, (2776596480.0, 2776596480.0, 69632.0)),
     "evabyte-6.5b-stage": (BlockWindowConfig, {}, (3258974208.0, 3258974208.0, 8192.0)),
     "jamba2-3b": (HybridSSMConfig, {}, (6052249600.0, 6070886400.0, 1024.0)),
+    "lfm2-24b-a2b-stage": (lambda: ConvMoEConfig(tie_word_embeddings=False), {},
+                           (1474297856.0, 1474428928.0, 4096.0)),
 }
 
 
@@ -76,7 +81,7 @@ def test_roofline_terms_are_the_parent_s(case):
 
 TINY = {
     "latent_moe": LatentMoEConfig.tiny, "windowed_moe": WindowedMoEConfig.tiny,
-    "block_window": BlockWindowConfig.tiny, "hybrid_ssm": HybridSSMConfig.tiny,
+    "block_window": BlockWindowConfig.tiny, "hybrid_ssm": HybridSSMConfig.tiny, "conv_moe": ConvMoEConfig.tiny,
 }
 # mechanism -> every way an operator asks for it: (EngineConfig overrides, (tp, sp), engine)
 ASKED = {
@@ -127,6 +132,16 @@ REFUSALS = {
         "the hybrid state-space family (HybridSSMConfig) cannot be served with weight_quant='int8' yet: quantize_llama_params does not know this tree (leaves stacked by layer kind, float32 A_log, D and time-step bias)",
     ('hybrid_ssm', 'mesh'):
         'the hybrid state-space family (HybridSSMConfig) cannot be served with tp=2, sp=1 yet: this tree has no partition rules (one KV head cannot be split, and a scan over a sequence split across chips hands its state from chip to chip)',
+    ('conv_moe', 'continuous'):
+        "the gated-convolution sparse-expert family (ConvMoEConfig) cannot be served with the continuous engine (batching='continuous') or its paged KV pool yet: a convolution's kept inputs have no blocks to page, and preemption, resume and a per-row frontier need snapshots of them that nothing takes yet; use 'coalesce'",
+    ('conv_moe', 'prefix_cache'):
+        "the gated-convolution sparse-expert family (ConvMoEConfig) cannot be served with the KV prefix cache (prefix_cache.enabled) yet: a convolution's state can be reused only for an exact prefix, and only if a snapshot was kept at its end: a spliced segment's keys and values say nothing of it",
+    ('conv_moe', 'kv_quant'):
+        "the gated-convolution sparse-expert family (ConvMoEConfig) cannot be served with kv_quant='int8' yet: the attention layers' planes (heads of 64) and the kept inputs have no int8 form here",
+    ('conv_moe', 'weight_quant'):
+        "the gated-convolution sparse-expert family (ConvMoEConfig) cannot be served with weight_quant='int8' yet: quantize_llama_params does not know this tree (operators that differ in shape by layer kind, the taps, stacked experts, the router)",
+    ('conv_moe', 'mesh'):
+        'the gated-convolution sparse-expert family (ConvMoEConfig) cannot be served with tp=2, sp=1 yet: this tree has no partition rules (a depthwise convolution splits by channel, the heads by KV head), and experts across chips need the all-to-all',
 }
 
 
@@ -243,8 +258,8 @@ def test_a_sixth_family_is_a_class_a_module_and_a_row(monkeypatch):
 
 
 FAMILY_NAMES = re.compile(
-    r"hasattr\(model_config|LatentMoEConfig|WindowedMoEConfig|BlockWindowConfig|HybridSSMConfig"
-    r"|roofline_for_(latent_moe|windowed_moe|block_window|hybrid_ssm)")
+    r"hasattr\(model_config|LatentMoEConfig|WindowedMoEConfig|BlockWindowConfig|HybridSSMConfig|ConvMoEConfig"
+    r"|roofline_for_(latent_moe|windowed_moe|block_window|hybrid_ssm|conv_moe)")
 
 
 def test_nothing_outside_the_seam_names_a_family():

@@ -403,6 +403,53 @@ def test_hybrid_ssm_fine_scopes_sit_beneath_attn(hybrid_ssm_engine):
     assert not any("/cond/" in p or "/branch_" in p for p in looped)
 
 
+@pytest.fixture(scope="module")
+def conv_moe_engine():
+    """The gated-convolution sparse-expert family through the same programs."""
+    import dataclasses
+
+    from rag_llm_k8s_tpu.core.config import ConvMoEConfig
+    from rag_llm_k8s_tpu.models.conv_moe import init_conv_moe_params
+
+    cfg = ConvMoEConfig.tiny(vocab_size=300)
+    params = init_conv_moe_params(jax.random.PRNGKey(0), cfg, FP32)
+    ec = dataclasses.replace(EC, prefix_cache=PrefixCacheConfig(enabled=False), attn_impl="xla")
+    return InferenceEngine(cfg, params, sampling=GREEDY, engine_config=ec, dtypes=FP32)
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_PROGRAMS))
+def test_conv_moe_programs_arrive_scoped(conv_moe_engine, name):
+    """Every operation of the sixth decoder family carries a phase: the
+    verify loop's ``commit`` among them."""
+    _assert_scoped(name, LATENT_PROGRAMS[name](conv_moe_engine))
+
+
+def test_conv_moe_fine_scopes_sit_beneath_attn_and_mlp(conv_moe_engine):
+    """``attn/conv`` (a conv operator's gates, taps and state roll) and
+    ``attn/global`` (an attention layer) are BENEATH ``attn``, ``mlp/router``,
+    ``mlp/experts`` and ``mlp/dense`` beneath ``mlp``, in a prefill and in a
+    decode step, the operators in no branch (a trip is a period of layers, each
+    of its own kind). The two dense layers stand in front of the layers' loop and
+    the sparse ones in it, which is what a prefill's rows are counted by."""
+    paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](conv_moe_engine))]
+    for phase in ("prefill", "decode"):
+        for sub, fine in (("attn", "conv"), ("attn", "global"), ("mlp", "router"), ("mlp", "experts"), ("mlp", "dense")):
+            hits = [p for p in paths if f"/{phase}/" in p and -1 < p.find(f"/{sub}/") < p.find(f"/{fine}/")]
+            assert hits and all(_scope(p) == (phase, sub) for p in hits), (phase, sub, fine)
+            if sub == "attn":  # the operators stand in no branch (the experts' combine chooses its form in one)
+                assert not any("/cond/" in p or "/branch_" in p for p in hits), (phase, sub, fine)
+    dense = [p for p in paths if "/prefill/rows2/" in p and "/mlp/dense/" in p]
+    assert dense and all("/lead_" in p and "/while/" not in p.split("/lead_")[0] for p in dense)
+    looped = [p for p in paths if "/prefill/rows2/" in p and "/shortconv/conv/" in p and "/periods/" in p]
+    assert looped and all(p.split("/prefill/rows2/")[1].split("/shortconv/conv/")[0].split("/").count("while") == 1
+                          for p in looped)
+    # the operator's two projections are OUTSIDE ``attn/conv`` (its module is not named as the scope is)
+    assert not [p for p in paths if "/conv/" in p and ("in_proj" in p or "out_proj" in p)]
+    assert [p for p in paths if "/attn/shortconv/in_proj/" in p]
+    verify = [path for _, path in _traced(LATENT_PROGRAMS["generate_spec"](conv_moe_engine))]
+    assert [p for p in verify if "/verify/" in p and "/attn/shortconv/conv/" in p]
+
+
 def test_a_hybrid_live_suffix_prefill_still_counts_its_rows(tmp_path):
     """The twin of ``test_a_live_suffix_prefill_still_counts_its_rows`` for
     the hybrid state-space family: at a bucket with rungs both norms, the
