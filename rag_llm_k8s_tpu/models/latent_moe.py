@@ -66,18 +66,21 @@ from rag_llm_k8s_tpu.ops.attention import decode_slots_streamed
 # plus the layer-calls the mode made, and last the cache slots a step through
 # the decode kernel fetched over the rows and rows x the slots allocated
 # (``ops/attention.py decode_slots_streamed``; decode only), then the one-hot
-# entries the experts' combine set (appended last: the older fields keep
-# their places).
+# entries the experts' combine set, then the rows the down projection's
+# grouped kernel multiplied (each appended last: the older fields keep their
+# places).
 COUNTER_MODES = ("prefill", "decode", "chunk")
 COUNTER_FIELDS = ("tokens", "routed", "computed", "experts_hit", "layer_calls", "zero",
-                  "slots_streamed", "slots_allocated", "combined")
+                  "slots_streamed", "slots_allocated", "combined", "tile_rows")
 N_COUNTERS = len(COUNTER_MODES) * len(COUNTER_FIELDS)
 # what ``/metrics`` calls them (``engine_<name>``) -> (mode, field) of the
 # block; the first sums its field over the modes. Assignments to experts HELD
 # here, assignment rows the grouped kernel stored and (last) one-hot entries
 # the combine set, each by the kernel's own count, by how the model was
 # called; held experts hit, summed over decode layer-steps, and those steps;
-# assignments to zero-computation experts (none where a model has none)
+# assignments to zero-computation experts (none where a model has none); the
+# rows the down projection's grouped kernel multiplied (its visits x its row
+# tile: what ``computed`` is a share of is how full its tiles were)
 COUNTER_STATS = {
     "moe_tokens_routed": (None, "tokens"),
     "moe_prefill_assignments_held": ("prefill", "routed"),
@@ -97,6 +100,9 @@ COUNTER_STATS = {
     "moe_prefill_assignments_combined": ("prefill", "combined"),
     "moe_decode_assignments_combined": ("decode", "combined"),
     "moe_chunk_assignments_combined": ("chunk", "combined"),
+    "moe_prefill_tile_rows": ("prefill", "tile_rows"),
+    "moe_decode_tile_rows": ("decode", "tile_rows"),
+    "moe_chunk_tile_rows": ("chunk", "tile_rows"),
 }
 
 

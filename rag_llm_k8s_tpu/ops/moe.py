@@ -288,6 +288,45 @@ def zero_expert_term(
 # the grouped matmul (Mosaic)
 # ---------------------------------------------------------------------------
 
+# mean rows a group (``m / G``) under which the kernel's tiles follow the
+# groups, and what a whole-K weight block may take of VMEM (it is double
+# buffered): the sweep behind both is in PERF.md section 6, PR 46
+SMALL_GROUP_ROWS = 1024
+_WHOLE_K_BLOCK = 3 << 20
+
+
+def grouped_blocks(m: int, G: int, k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
+    """``grouped_matmul``'s ``(tm, tk, tn)``, from the shape alone (no option,
+    no model's name): the row tile, and the ``[tk, tn]`` block of a group's
+    weights a grid step multiplies it by.
+
+    A row tile that spans groups is visited once a group, so a buffer of
+    ``m`` rows in ``G`` groups costs about ``m / tm + G - 1`` visits of ``tm``
+    rows each, whatever the rows that exist. With ``k`` CUT (512 rows by 1024
+    of ``k``: what fits at any width) the weight block's index changes at
+    every grid step, so every visit fetches its group's weights again, and
+    few large tiles win. That is the plan where groups are large (a batched
+    prefill over a chip's share of the experts: ``m / G`` from
+    ``SMALL_GROUP_ROWS`` on, few tiles straddle) and where a ``[k, tn]`` block
+    is over ``_WHOLE_K_BLOCK``. Where groups are small and the block fits,
+    ``k`` is WHOLE: consecutive visits of one group then have the same block
+    index, the pipeline fetches a group's weights once a column of tiles, and
+    a straddling tile costs FLOPs only; so the row tile FOLLOWS the mean group
+    (its power of two, from ``ROW_ALIGN`` to 512), not the buffer. One row's
+    prefill with every expert held (16384 rows in 64 groups of about 256) runs
+    256-row tiles at 1.16 ms a call for 1.84 (128-row tiles fill better, 67%
+    for 50, and run no faster: 1.20); a decode step's or a verify chunk's
+    128-row buffer keeps its one tile and halves its grid steps, for 2% of a
+    gate or up call. The sweep (v5e): PERF.md section 6, PR 46."""
+    tm, tk, tn = _fit_block(m, 512), _fit_block(k, 1024), _fit_block(n, 1024)
+    mean = m // G
+    if mean >= SMALL_GROUP_ROWS or k * tn * itemsize > _WHOLE_K_BLOCK:
+        return tm, tk, tn
+    follow = ROW_ALIGN
+    while follow < 512 and 2 * follow <= mean:
+        follow *= 2
+    return _fit_block(m, follow), k, tn
+
 
 def _grouped_matmul_kernel(
     layer_ref,  # SMEM [1]: which layer's stack of groups (read by the index maps)
@@ -342,26 +381,32 @@ def grouped_matmul(
     layer: jax.Array,  # [] int32: the layer whose groups multiply
     *,
     interpret: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``out[rows of group g] = lhs[rows of group g] @ rhs[layer, g]``, ``[m,
-    n]`` in ``lhs``'s dtype, and the number of rows the kernel STORED: summed
+    n]`` in ``lhs``'s dtype; the number of rows the kernel STORED: summed
     by the kernel itself from the bounds of every store it made (the same
     two scalars its store mask is built from), so a (row tile, group) pair
     the grid never reached, or a bound that cut a group short, shows as fewer
-    rows than ``sum(group_sizes)``. The grid's middle dimension is the number of (row
+    rows than ``sum(group_sizes)``; and the rows it MULTIPLIED, visits times
+    the row tile (what ``stored`` is a share of says how full the tiles
+    were). The grid's middle dimension is the number of (row
     tile, group) pairs that hold rows, computed from ``group_sizes`` on the
     device: a group with no rows is never read, and rows past
     ``sum(group_sizes)`` are never written (the caller masks them). The layer
     rides scalar prefetch into the block index, so the kernel reads its tiles
     straight out of the stacked weights: no per-layer slice is materialized
     (a slice handed to a custom call is a copy, 1.3 GB a layer at the served
-    widths). The tiling scheme is that of JAX's megablox ``gmm``, whose
+    widths). The tiles are ``grouped_blocks``'s, which reads ``m``, the number
+    of groups, ``k``, ``n`` and the item's size: the whole ``k`` and a row tile
+    of the mean group's size where groups are small (a group's weights are
+    then fetched once a column of tiles, not once a visit), 512 rows by 1024
+    of ``k`` elsewhere. The tiling scheme is that of JAX's megablox ``gmm``, whose
     metadata builder this reuses."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import make_group_metadata
 
     m, k = lhs.shape
     _, G, _, n = rhs.shape
-    tm, tk, tn = _fit_block(m, 512), _fit_block(k, 1024), _fit_block(n, 1024)
+    tm, tk, tn = grouped_blocks(m, G, k, n, lhs.dtype.itemsize)
     (offsets, group_ids, m_tile_ids), num_tiles = make_group_metadata(
         group_sizes=group_sizes.astype(jnp.int32), m=m, tm=tm,
         start_group=jnp.int32(0), num_nonzero_groups=G, visit_empty_groups=False)
@@ -390,15 +435,17 @@ def grouped_matmul(
         name="grouped_matmul",
     )(jnp.asarray(layer, jnp.int32).reshape(1), offsets, group_ids, m_tile_ids, lhs, rhs)
     # with no rows at all the grid is empty and nothing was written
-    return out, jnp.where(num_tiles > 0, stored[0], 0)
+    return out, jnp.where(num_tiles > 0, stored[0], 0), num_tiles * tm
 
 
 def _grouped_xla(lhs, rhs, group_sizes, layer):
     """``grouped_matmul`` as ``ragged_dot``, which zeroes the rows past the
-    groups and reports nothing: the rows stored are those it was given."""
+    groups and reports nothing: the rows stored, and the rows multiplied, are
+    those it was given."""
     w = jax.lax.dynamic_index_in_dim(rhs, jnp.asarray(layer, jnp.int32).reshape(()), 0, keepdims=False)
     sizes = group_sizes.astype(jnp.int32)
-    return jax.lax.ragged_dot(lhs, w, sizes), jnp.sum(sizes)
+    given = jnp.sum(sizes)
+    return jax.lax.ragged_dot(lhs, w, sizes), given, given
 
 
 # ---------------------------------------------------------------------------
@@ -664,6 +711,7 @@ class ExpertCounts(NamedTuple):
     experts_hit: jax.Array  # held experts with at least one assignment
     zero: jax.Array = 0  # assignments to zero-computation experts (``zero_expert_term``)
     combined: jax.Array = 0  # one-hot entries the combine set, by its own count (== routed)
+    tile_rows: jax.Array = 0  # rows the down projection's kernel multiplied: its visits x its row tile
 
 
 def rows_per_pass(n_tokens: int, top_k: int, n_experts: int, held: int) -> int:
@@ -718,7 +766,7 @@ def held_expert_ffn(
         grouped = functools.partial(grouped_matmul, interpret=impl == "pallas_interpret")
 
     def one_pass(carry):
-        p, acc, computed, combined = carry
+        p, acc, computed, combined, tile_rows = carry
         lo = p * C
         a = jax.lax.dynamic_slice(order, (lo,), (C,))
         valid = lo + jnp.arange(C, dtype=jnp.int32) < total
@@ -726,16 +774,16 @@ def held_expert_ffn(
         rows = jnp.take(x, token, axis=0)
         sizes_here = jnp.clip(ends - lo, 0, C) - jnp.clip(starts - lo, 0, C)
         h = jax.nn.silu(grouped(rows, w_gate, sizes_here, layer)[0]) * grouped(rows, w_up, sizes_here, layer)[0]
-        y, stored = grouped(h, w_down, sizes_here, layer)
+        y, stored, tiled = grouped(h, w_down, sizes_here, layer)
         acc, hot = combine(acc, y, jnp.take(flat_w, a), jnp.where(valid, token, N),
                            jnp.where(valid, jnp.take(key, a), held), held, impl=impl)
-        return p + 1, acc, computed + stored, combined + hot
+        return p + 1, acc, computed + stored, combined + hot, tile_rows + tiled
 
     n_pass = (total + C - 1) // C
-    _, y, computed, combined = jax.lax.while_loop(
+    _, y, computed, combined, tile_rows = jax.lax.while_loop(
         lambda c: c[0] < n_pass, one_pass,
-        (jnp.int32(0), jnp.zeros((N, D), x.dtype), jnp.int32(0), jnp.int32(0)))
+        (jnp.int32(0), jnp.zeros((N, D), x.dtype), jnp.int32(0), jnp.int32(0), jnp.int32(0)))
     counts = ExpertCounts(
         tokens=jnp.int32(N), routed=total.astype(jnp.int32), computed=computed,
-        experts_hit=jnp.sum(sizes > 0).astype(jnp.int32), combined=combined)
+        experts_hit=jnp.sum(sizes > 0).astype(jnp.int32), combined=combined, tile_rows=tile_rows)
     return y, counts

@@ -521,7 +521,17 @@ def test_build_service_refuses_the_family_s_checkpoint_by_name(monkeypatch, tmp_
         server_main.build_service()
 
 
-# ---- the grouped kernel counts the rows it stores ----
+# ---- the grouped kernel counts the rows it stores, and its tiles follow its groups ----
+
+
+def _tiles_visited(sizes, tm):
+    """(row tile, group) pairs that hold rows: what the grid's middle dimension is."""
+    lo, visits = 0, 0
+    for size in sizes:
+        if size:
+            visits += (lo + size - 1) // tm - lo // tm + 1
+        lo += size
+    return visits
 
 
 @pytest.mark.parametrize("sizes", [(0, 0, 0, 0), (5, 0, 130, 1), (128, 128, 0, 0), (300, 3, 3, 206)])
@@ -530,9 +540,12 @@ def test_grouped_matmul_reports_the_rows_it_stored(sizes):
     m, k, n = 512, 128, 256
     lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
     rhs = jnp.asarray(rng.standard_normal((2, 4, k, n)) / 8, jnp.float32)
+    tm = moe.grouped_blocks(m, 4, k, n, 4)[0]
     with jax.default_matmul_precision("highest"):
-        out, stored = moe.grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32), jnp.int32(1), interpret=True)
+        out, stored, tile_rows = moe.grouped_matmul(
+            lhs, rhs, jnp.asarray(sizes, jnp.int32), jnp.int32(1), interpret=True)
         assert int(stored) == sum(sizes)
+        assert int(tile_rows) == _tiles_visited(sizes, tm) * tm  # num_tiles * tm
         lo = 0
         for g, size in enumerate(sizes):
             np.testing.assert_allclose(np.asarray(out[lo:lo + size]), np.asarray(lhs[lo:lo + size] @ rhs[1, g]),
@@ -556,8 +569,106 @@ def test_a_tile_the_grid_never_reaches_shows_in_the_count(monkeypatch):
     lhs = jnp.ones((256, 128), jnp.float32)
     rhs = jnp.ones((1, 2, 128, 128), jnp.float32)
     sizes = jnp.asarray([128, 100], jnp.int32)
-    _, stored = moe.grouped_matmul.__wrapped__(lhs, rhs, sizes, jnp.int32(0), interpret=True)
+    _, stored, _ = moe.grouped_matmul.__wrapped__(lhs, rhs, sizes, jnp.int32(0), interpret=True)
     assert int(stored) == 128 < int(sizes.sum())
+
+
+TODAY = (512, 1024)  # the row tile and the cut of ``k`` every shape had before the plan read the groups
+
+
+@pytest.mark.parametrize("case,m,G,k,n,want", [
+    # (tokens x top-k in rows_per_pass's buffer, experts held, k, n) of the four sparse cells
+    ("lfm2 prefill, gate and up", 16384, 64, 2048, 1536, (256, 2048, 512)),
+    ("lfm2 prefill, down", 16384, 64, 1536, 2048, (256, 1536, 1024)),
+    ("lfm2 decode, up", 128, 64, 2048, 1536, (128, 2048, 512)),
+    ("lfm2 decode, down", 128, 64, 1536, 2048, (128, 1536, 1024)),
+    ("lfm2 verify chunk (16 positions), up", 128, 64, 2048, 1536, (128, 2048, 512)),
+    ("lfm2, a 512-token prefill chunk, up", 2048, 64, 2048, 1536, (128, 2048, 512)),
+    ("lfm2, eight callers' prefill, up", 131072, 64, 2048, 1536, TODAY + (512,)),
+    ("lfm2, eight callers' prefill, down", 131072, 64, 1536, 2048, (512, 512, 1024)),
+    ("dots prefill, up", 32768, 16, 7168, 2048, TODAY + (1024,)),
+    ("dots prefill, down", 32768, 16, 2048, 7168, TODAY + (1024,)),
+    ("dots decode, up", 128, 16, 7168, 2048, (128, 1024, 1024)),
+    ("dots decode, down", 128, 16, 2048, 7168, (128, 1024, 1024)),
+    ("dots, a 512-token prefill chunk, down", 512, 16, 2048, 7168, TODAY + (1024,)),
+    ("longcat prefill, up", 16384, 16, 6144, 2048, TODAY + (1024,)),
+    ("longcat prefill, down", 16384, 16, 2048, 6144, TODAY + (1024,)),
+    ("longcat decode, up", 128, 16, 6144, 2048, (128, 1024, 1024)),
+    ("longcat decode, down", 128, 16, 2048, 6144, (128, 1024, 1024)),
+    ("laguna prefill, up", 40960, 16, 3072, 1024, TODAY + (1024,)),
+    ("laguna prefill, down", 40960, 16, 1024, 3072, TODAY + (1024,)),
+    ("laguna decode, up", 128, 16, 3072, 1024, (128, 1024, 1024)),
+    ("laguna decode, down", 128, 16, 1024, 3072, (128, 1024, 1024)),
+    ("32 held of lfm2's, one row: groups of 512", 16384, 32, 2048, 1536, (512, 2048, 512)),
+    ("small groups whose whole-K block does not fit", 16384, 64, 7168, 2048, TODAY + (1024,)),
+])
+def test_the_grouped_kernel_s_tiles_follow_the_groups(case, m, G, k, n, want):
+    """Small mean groups (``m / G`` under ``SMALL_GROUP_ROWS``) whose ``[k,
+    tn]`` block fits take the whole ``k`` and a row tile of the mean group's
+    size; every other shape, the three controls' every buffer among them,
+    takes exactly what it took before the plan read ``G``."""
+    got = moe.grouped_blocks(m, G, k, n, 2)
+    assert got == want
+    today = (min(512, m), 1024 if k % 1024 == 0 else 512, 1024 if n % 1024 == 0 else 512)
+    if not case.startswith(("lfm2", "32 held")) or m // G >= moe.SMALL_GROUP_ROWS:
+        assert got == today
+
+
+@pytest.mark.parametrize("cells,held,want", [
+    ("lfm2 prefill", (4096, 4, 64, 64), 16384), ("lfm2 decode", (1, 4, 64, 64), 128),
+    ("lfm2 verify chunk", (16, 4, 64, 64), 128), ("lfm2, eight callers", (8 * 4096, 4, 64, 64), 131072),
+])
+def test_the_shape_table_s_buffers_are_rows_per_pass_s(cells, held, want):
+    assert moe.rows_per_pass(*held) == want
+
+
+@pytest.mark.parametrize("case,m,sizes", [
+    ("empty groups between full ones", 1024, (200, 0, 0, 130, 0, 90, 0, 60)),
+    ("every group under a tile", 1024, (100, 90, 70, 50, 30, 20, 10, 5)),
+    ("a tile spanning five groups", 1024, (120, 2, 3, 1, 2, 300, 0, 40)),
+    ("no rows at all", 1024, (0,) * 8),
+    ("the whole buffer", 1024, (128,) * 8),
+    ("one group takes nearly all", 1024, (1, 0, 1000, 0, 0, 1, 0, 0)),
+    ("256-row tiles, three groups in one", 2048, (250, 3, 2, 700, 0, 130, 1, 260)),
+])
+def test_grouped_matmul_under_the_small_group_plan(case, m, sizes):
+    """8 groups of a mean 128 or 256 rows: a row tile of that size and one K
+    tile. Against ``ragged_dot``; rows past the groups are never written, and
+    both counts are the kernel's own."""
+    rng = np.random.default_rng(11)
+    k, n, G = 256, 128, len(sizes)
+    tm, tk, tn = moe.grouped_blocks(m, G, k, n, 4)
+    assert (tm, tk, tn) == (m // G, k, n)
+    lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((2, G, k, n)) / 8, jnp.float32)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        out, stored, tile_rows = moe.grouped_matmul(lhs, rhs, group_sizes, jnp.int32(1), interpret=True)
+        want, given, _ = moe._grouped_xla(lhs, rhs, group_sizes, jnp.int32(1))
+    total = sum(sizes)
+    assert int(stored) == int(given) == total
+    assert int(tile_rows) == _tiles_visited(sizes, tm) * tm
+    np.testing.assert_allclose(np.asarray(out[:total]), np.asarray(want[:total]), atol=1e-4)
+    # rows past the groups are never written: they keep what interpret mode allocates (NaN)
+    assert np.isnan(np.asarray(out[total:])).all()
+
+
+@pytest.mark.parametrize("field,at", [
+    ("tokens", 0), ("routed", 1), ("computed", 2), ("experts_hit", 3), ("layer_calls", 4), ("zero", 5),
+    ("slots_streamed", 6), ("slots_allocated", 7), ("combined", 8), ("tile_rows", 9),
+])
+def test_the_counter_block_keeps_its_older_fields_in_place(field, at):
+    assert lm.COUNTER_FIELDS.index(field) == at and len(lm.COUNTER_FIELDS) == 10
+
+
+@pytest.mark.parametrize("mode", lm.COUNTER_MODES)
+def test_fold_counters_carries_the_tile_rows(mode):
+    row = np.arange(lm.N_COUNTERS) * 3
+    at = lm.COUNTER_MODES.index(mode) * len(lm.COUNTER_FIELDS)
+    got = lm.fold_counters(row)
+    assert got[f"moe_{mode}_tile_rows"] == row[at + lm.COUNTER_FIELDS.index("tile_rows")]
+    assert got[f"moe_{mode}_assignments_computed"] == row[at + 2]
+    assert got[f"moe_{mode}_assignments_combined"] == row[at + 8]
 
 
 # ---- a large batch prefills a row at a time, by shape ----
@@ -658,7 +769,8 @@ def test_held_expert_ffn_holds_no_scatter():
         # what is left adds into vectors of a few entries (the grouped kernel's
         # metadata: histograms over experts and row tiles), never into rows
         adds = re.findall(r"\w+\[([\d,]*)\] = scatter[-_]add", text)
-        assert adds and all("," not in shape and int(shape) <= 4 * held for shape in adds), adds
+        few = C // moe.ROW_ALIGN + held  # the smallest row tile's count of tiles, and the groups
+        assert adds and all("," not in shape and int(shape) <= few for shape in adds), adds
 
 
 def test_combine_rule_for_the_served_shapes():

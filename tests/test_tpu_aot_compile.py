@@ -243,6 +243,30 @@ CASES.update({
 })
 
 
+# every expert held (64 of 2048 x 1536, 8 sparse layers), one row's prefill:
+# 16384 rows in groups of about 256, so ``grouped_blocks`` gives 256-row tiles
+# and the whole k: a [2048, 512] / [1536, 1024] weight block, double buffered,
+# inside the scoped VMEM limit as it stands; so are a decode step's 128 rows
+# over it, and the largest tile the rule gives a whole k (32 held: groups of 512)
+CASES.update({
+    "grouped_matmul[all experts held, prefill up]": _latent(
+        "grouped_matmul",
+        [((16384, 2048), BF16), ((8, 64, 2048, 1536), BF16), ((64,), I32), ((), I32)]),
+    "grouped_matmul[all experts held, prefill down]": _latent(
+        "grouped_matmul",
+        [((16384, 1536), BF16), ((8, 64, 1536, 2048), BF16), ((64,), I32), ((), I32)]),
+    "grouped_matmul[all experts held, decode up]": _latent(
+        "grouped_matmul",
+        [((128, 2048), BF16), ((8, 64, 2048, 1536), BF16), ((64,), I32), ((), I32)]),
+    "grouped_matmul[all experts held, decode down]": _latent(
+        "grouped_matmul",
+        [((128, 1536), BF16), ((8, 64, 1536, 2048), BF16), ((64,), I32), ((), I32)]),
+    "grouped_matmul[groups of 512, whole k, down]": _latent(
+        "grouped_matmul",
+        [((16384, 1536), BF16), ((8, 32, 1536, 2048), BF16), ((32,), I32), ((), I32)]),
+})
+
+
 def _combine(N: int, C: int, D: int):
     from rag_llm_k8s_tpu.ops import moe
 

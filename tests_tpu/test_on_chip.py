@@ -472,12 +472,31 @@ class TestShortcutBlockKernelsOnChip:
         for k, n in ((6144, 2048), (2048, 6144)):  # gate / up, then down
             lhs = jnp.asarray(rng.standard_normal((512, k)), jnp.bfloat16)
             rhs = jnp.asarray(rng.standard_normal((2, 16, k, n)) / np.sqrt(k), jnp.bfloat16)
-            got, stored = moe.grouped_matmul(lhs, rhs, sizes, jnp.int32(1))
-            want, _ = moe._grouped_xla(lhs, rhs, sizes, jnp.int32(1))
+            got, stored, _ = moe.grouped_matmul(lhs, rhs, sizes, jnp.int32(1))
+            want, _, _ = moe._grouped_xla(lhs, rhs, sizes, jnp.int32(1))
             rows = int(sizes.sum())
             assert int(stored) == rows
             np.testing.assert_allclose(np.asarray(got[:rows], np.float32), np.asarray(want[:rows], np.float32),
                                        rtol=2e-2, atol=2e-2)  # both accumulate in float32: bf16's last place
+
+    def test_grouped_matmul_where_every_expert_is_held(self):
+        """2048 <-> 1536 over 64 groups of one row's prefill: ``grouped_blocks``
+        gives 256-row tiles and the whole k, a tile spans several groups."""
+        from rag_llm_k8s_tpu.ops import moe
+
+        rng = np.random.default_rng(2)
+        sizes = rng.multinomial(12000, rng.dirichlet(np.full(64, 2.0))).astype(np.int32)
+        sizes[[3, 40]] = 0
+        rows = int(sizes.sum())
+        for k, n in ((2048, 1536), (1536, 2048)):
+            assert moe.grouped_blocks(16384, 64, k, n, 2)[:2] == (256, k)
+            lhs = jnp.asarray(rng.standard_normal((16384, k)), jnp.bfloat16)
+            rhs = jnp.asarray(rng.standard_normal((2, 64, k, n)) / np.sqrt(k), jnp.bfloat16)
+            got, stored, tile_rows = moe.grouped_matmul(lhs, rhs, jnp.asarray(sizes), jnp.int32(1))
+            want, _, _ = moe._grouped_xla(lhs, rhs, jnp.asarray(sizes), jnp.int32(1))
+            assert int(stored) == rows and rows <= int(tile_rows) <= rows + 64 * 256
+            np.testing.assert_allclose(np.asarray(got[:rows], np.float32), np.asarray(want[:rows], np.float32),
+                                       rtol=2e-2, atol=2e-2)
 
     def test_mla_kernels_at_64_heads(self):
         from rag_llm_k8s_tpu.ops import mla
