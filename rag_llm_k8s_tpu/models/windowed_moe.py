@@ -40,7 +40,8 @@ outside it (``lead_<i>``), as the latent family runs two sublayers a trip.
 The cache carries the family's counters (``KVCache.counters``): the latent
 family's block (the experts' assignments by mode; a FULL layer's decode
 slots, a step counted once) and, appended last, the slots the SLIDING layers'
-walks fetched and were allocated, summed over those layers.
+walks fetched and were allocated, summed over those layers, then the
+query-key pairs their prefill kernel's steps multiplied and the live ones.
 """
 
 from __future__ import annotations
@@ -57,13 +58,18 @@ from rag_llm_k8s_tpu.models.llama import (
     KVCache, RMSNorm, apply_rope, attend, resolve_attn_impl, rope_cos_sin,
 )
 from rag_llm_k8s_tpu.obs.tracing import phase_scope
-from rag_llm_k8s_tpu.ops.attention import decode_slots_streamed, gqa_decode_step
+from rag_llm_k8s_tpu.ops.attention import decode_slots_streamed, flash_window_pairs, gqa_decode_step
 
 # KVCache.counters: the latent family's [mode, what] block (``lm.COUNTER_FIELDS``;
 # its decode slots are a FULL layer's, counted once a step), then the slots the
 # sliding layers' decode walks fetched and rows x the slots allocated to them,
-# summed over the sliding layers (a step through the decode kernel at a time)
-WINDOW_STATS = ("decode_slots_streamed_window", "decode_slots_allocated_window")
+# summed over the sliding layers (a step through the decode kernel at a time),
+# then one query head's query-key pairs that the steps of those layers' prefill
+# kernel multiplied and the pairs of them that were live (a single-shot prefill
+# through ``flash_attention_window`` at a time: ``ops/attention.py flash_window_pairs``)
+WINDOW_STATS = ("decode_slots_streamed_window", "decode_slots_allocated_window",
+                "prefill_window_pairs_multiplied", "prefill_window_pairs_live")
+_WINDOW_PAIRS = lm.N_COUNTERS + WINDOW_STATS.index("prefill_window_pairs_multiplied")
 N_COUNTERS = lm.N_COUNTERS + len(WINDOW_STATS)
 COUNTER_NAMES = tuple(lm.COUNTER_STATS) + WINDOW_STATS
 SLIDING = "sliding_attention"
@@ -259,24 +265,33 @@ class WindowedMoEModel(nn.Module):
             tables = {kind: rope_table(positions, c, c.rope_of(kind)) for kind in sorted(set(c.layer_types))}
 
         counters = cache.counters
-        if tokens.shape[1] == 1 and resolve_attn_impl(self.attn_impl) != "xla":
+        kernels = resolve_attn_impl(self.attn_impl) != "xla"
+        n_win = c.num_sliding_layers
+        heads = dict(zip(c.layer_types, c.num_attention_heads_per_layer))
+        if tokens.shape[1] > 1 and not self.chunked and n_win and kernels:
+            # a single-shot prefill through ``flash_attention_window``: what
+            # the steps of a sliding layer's call multiply, over those layers
+            pairs = flash_window_pairs(kv_start, kv_len, tokens.shape[1], heads[SLIDING] // c.num_kv_heads,
+                                       c.head_dim, c.head_dim, c.sliding_window,
+                                       jnp.dtype(dt.compute_dtype).itemsize)
+            counters = counters.at[_WINDOW_PAIRS:_WINDOW_PAIRS + 2].add(
+                (n_win * jnp.stack(pairs)).astype(counters.dtype))
+        if tokens.shape[1] == 1 and kernels:
             # a step through ``decode_attention``: what a full layer's walk
             # fetches of its plane (a step counts once), and what the sliding
             # layers' walks fetch of theirs, over those layers
             B, K, T = cache.k.shape[1:4]
-            n_win = c.num_sliding_layers
 
-            def step(heads):
-                return gqa_decode_step(T, K, heads // K, c.head_dim, cache.k.dtype)
+            def step(n_heads):
+                return gqa_decode_step(T, K, n_heads // K, c.head_dim, cache.k.dtype)
 
-            heads = dict(zip(c.layer_types, c.num_attention_heads_per_layer))
             add = jnp.zeros_like(counters)
             if "full_attention" in heads:
                 add = add.at[_DECODE_SLOTS:_DECODE_SLOTS + 2].set(jnp.stack(
                     [decode_slots_streamed(kv_start, kv_len, T, step(heads["full_attention"])),
                      B * T]).astype(counters.dtype))
             if n_win:
-                add = add.at[lm.N_COUNTERS:].set(jnp.stack(
+                add = add.at[lm.N_COUNTERS:lm.N_COUNTERS + 2].set(jnp.stack(
                     [n_win * decode_slots_streamed(jnp.maximum(kv_start, kv_len - c.sliding_window),
                                                    kv_len, T, step(heads[SLIDING])),
                      n_win * B * T]).astype(counters.dtype))

@@ -58,8 +58,8 @@ def flash_block_plan(qi, kv_start, kv_len, S: int, bq: int, bk: int, causal: boo
     ``int_lo..int_hi`` are INTERIOR: wholly inside the window and wholly
     under the block's diagonal (and wholly behind its window edge), so every
     pair in them is live; the others (on the diagonal, on the window's edge,
-    or straddling ``kv_start`` or ``kv_len``) are EDGE
-    blocks. Integer arithmetic only, so it serves Python ints, numpy arrays
+    or straddling ``kv_start`` or ``kv_len``) are EDGE blocks (a window that
+    ONE step holds walks none: ``flash_window_step``). Integers only, so it serves Python ints, numpy arrays
     and the kernel's traced scalars alike: the kernel's loop bounds and the
     tests read this one rule. ``S`` is the key length (bounds stay inside it).
     ``window=None`` is the plan without one, bit for bit."""
@@ -244,7 +244,7 @@ def _flash_kernel(
         block(lo, 1, True, first=True)
         # (without a window an edge block between ``lo`` and the interior
         # cannot be: ``int_lo`` is ``lo`` or ``lo + 1``; a window's edge is a
-        # second diagonal, as many blocks as a query block spans)
+        # second diagonal; one too wide for ``flash_window_step`` walks here)
         if window is not None:
             jax.lax.fori_loop(0, jnp.minimum(first_int, hi + 1) - (lo + 1), step(lo + 1, 1, True), 0)
         jax.lax.fori_loop(0, n_int // wide, step(first_int, wide, False), 0)
@@ -282,8 +282,8 @@ def flash_blocks(S: int, G: int, dq: int, dv: int, causal: bool = True, itemsize
     step (bq 256 at G = 4; where G is no power of two, the power of two
     nearest ``1024 / G``, since plain halving of 113 or 170 queries ends at a
     block of one or two: G = 9 takes 128 queries, 1152 rows, 7-8% faster than
-    64 with or without a window; G = 6 takes 128, 768 rows, its best: swept at
-    72 / 48 heads in PR 33, PERF.md §6). Under ``causal`` the 1024 keys are ``FLASH_WIDE``
+    64 on this walk and 8% in ``flash_window_step``'s one step (PR 47); G = 6
+    takes 128, 768 rows: PRs 33, 47, PERF.md §6). Under ``causal`` the 1024 keys are ``FLASH_WIDE``
     interior blocks of ``bk`` = 512, so that the blocks a mask can touch are
     512 wide, and bq stops at 512: a taller query block only widens the
     diagonal's waste (MLA, G = 1: 1024 × 512 lost 11% to 512 × 512). Finer
@@ -317,22 +317,22 @@ def _flash_call(qt, kt, vt, kv_start, kv_len, *, scale, causal, bq, bk, interpre
     """The one flash prefill ``pallas_call``: ``qt [B*H, Sq, dq]`` against
     ``kt [B*K, Sk, dq]`` / ``vt [B*K, Sk, dv]`` (the G = H // K query heads of
     a KV head are consecutive rows of ``qt``); returns ``[B*H, Sq, dv]``.
-    ``bq`` / ``bk`` left ``None`` come from ``flash_blocks``. ``resident``
-    (``None``: where they fit) keeps a KV head's K/V strips in VMEM across
-    its query blocks; otherwise the grid gains a key-block axis whose index
-    map is clamped into the plan's ``lo..hi``, so any length runs. ``window``
-    (causal only) bounds every query to its last ``window`` keys."""
-    BH, Sq, dq = qt.shape
-    BK, Sk, dv = vt.shape
+    ``bq`` / ``bk`` left ``None`` and ``resident`` (``None``: where the strips
+    fit) are ``flash_walk_blocks``' to fill. ``window`` (causal only) bounds
+    every query to its last ``window`` keys; where the shape lets a query block
+    take its window in ONE step (``flash_window_step``) that kernel is built,
+    not this one (``bk`` names the WALK's key block: a call that gives one, or
+    asks for streamed blocks, keeps the walk)."""
+    (BH, Sq, dq), (BK, Sk, dv) = qt.shape, vt.shape
     G = BH // BK
     kv_heads = BK // kv_start.shape[0]
     wide = FLASH_WIDE if causal else 1
-    rule_bq, rule_bk = flash_blocks(max(Sq, Sk), G, dq, dv, causal, kt.dtype.itemsize)
-    bq = _fit_block(Sq, bq or rule_bq)
-    if resident is None:
-        resident = _flash_fits(Sk, G * bq, min(bk or rule_bk, Sk), wide, dq, dv, kt.dtype.itemsize)
-    # streamed, a step is one block: it takes a wide step's keys
-    bk = _fit_block(Sk, bk or (rule_bk if resident else wide * rule_bk))
+    if window and bk is None and resident is not False and Sq == Sk:
+        step = flash_window_step(Sk, G, dq, dv, window, kt.dtype.itemsize, bq)
+        if step:
+            return _window_call(qt, kt, vt, kv_start, kv_len, *step, scale=scale, window=window,
+                                interpret=interpret, name=name)
+    bq, bk, resident = flash_walk_blocks(Sq, Sk, G, dq, dv, causal, kt.dtype.itemsize, bq, bk, resident)
 
     if resident:
         grid, kv_block = (BK, Sq // bq), Sk
@@ -2539,3 +2539,230 @@ def decode_attention_xla_q8(
     kd = dequantize_layer_slice(k_cache, k_scale, layer, kv_start, kv_len, q.dtype)
     vd = dequantize_layer_slice(v_cache, v_scale, layer, kv_start, kv_len, q.dtype)
     return decode_attention_xla(q, kd, vd, kv_start, kv_len, jnp.int32(0))
+
+
+# ---------------------------------------------------------------------------
+# a sliding layer's prefill where the window fits ONE step
+# (``flash_attention_window``, built by ``_flash_call`` where
+# ``flash_window_step`` names a span). At the end of the file so that the
+# kernels above keep their lines, which the compile cache keys on.
+# ---------------------------------------------------------------------------
+
+
+def flash_walk_blocks(Sq: int, Sk: int, G: int, dq: int, dv: int, causal: bool, itemsize: int,
+                      bq: Optional[int] = None, bk: Optional[int] = None,
+                      resident: Optional[bool] = None) -> Tuple[int, int, bool]:
+    """``(bq, bk, resident)`` of the block walk (``_flash_kernel``): what the
+    caller gave, else ``flash_blocks``' rule on the shape; the strips resident
+    where they fit beside the query block, and a streamed step one block of a
+    wide step's keys."""
+    wide = FLASH_WIDE if causal else 1
+    rule_bq, rule_bk = flash_blocks(max(Sq, Sk), G, dq, dv, causal, itemsize)
+    bq = _fit_block(Sq, bq or rule_bq)
+    if resident is None:
+        resident = _flash_fits(Sk, G * bq, min(bk or rule_bk, Sk), wide, dq, dv, itemsize)
+    return bq, _fit_block(Sk, bk or (rule_bk if resident else wide * rule_bk)), resident
+
+
+def flash_window_step(S: int, G: int, dq: int, dv: int, window: int, itemsize: int = 2,
+                      bq: Optional[int] = None) -> Optional[Tuple[int, int]]:
+    """``(bq, span)`` where a causal windowed prefill over ``S`` keys takes a
+    query block's window in ONE step, ``None`` where it keeps the block walk;
+    from the shape alone (no option, no model's name).
+
+    A block of ``bq`` queries under a window of ``W`` sees at most ``W + bq -
+    1`` keys. The walk cuts them at multiples of ``bk`` = 512, so at ``W`` =
+    512 they always straddle TWO blocks, both under the mask, and the second
+    rescales sum and accumulator: 1024 keys multiplied for 512 live (1.405 ms
+    a call at ``[72, 4096, 128]``: PERF.md §6, PR 47). One slice of ``span`` =
+    ``W`` (rounded up to ``bq``, so that the slice starts on a query block's
+    boundary) + ``bq`` keys, from ``bq·(qi + 1) - span``, holds them all: one
+    softmax pass, no running state. It is taken where the slice is shorter
+    than the strip (else the window bounds nothing worth a second kernel) and
+    the resident strips and ``G·bq`` rows of ``span`` masked keys fit
+    (``_flash_fits``: 1152 rows × 640 keys at G = 9, W = 512). A window in the
+    thousands, or a strip that is streamed (S ≥ 16384), keeps the walk."""
+    bq = _fit_block(S, bq or flash_blocks(S, G, dq, dv, True, itemsize)[0])
+    span = -(-window // bq) * bq + bq
+    if span >= S or not _flash_fits(S, G * bq, span, 1, dq, dv, itemsize):
+        return None
+    return bq, span
+
+
+def flash_window_plan(qi, kv_start, kv_len, bq: int, span: int, window: int):
+    """The one step of query block ``qi``: ``(off, live, steady)``. The step
+    multiplies the block's queries by the ``span`` keys from ``off`` (a
+    multiple of ``bq``, inside the strip), which hold every key a query of
+    the block may see: ``k <= q``, ``k > q - window``. ``live``: some pair of
+    them is (else the block's output is zeros and nothing is multiplied: a
+    block in the left pad, an empty row). ``steady``: the slice lies wholly
+    inside ``[kv_start, kv_len)`` and starts where the rule puts it, so the
+    mask is the same two edges at every such block. Integer arithmetic only:
+    the kernel's scalars, the model's counters and the tests read this rule."""
+    q_lo = qi * bq
+    at = q_lo + bq - span  # where the oldest key the block's first query sees is rounded down to
+    off = jnp.maximum(at, 0)
+    live = (kv_len > kv_start) & (q_lo + bq - 1 >= kv_start) & (kv_len > q_lo - window + 1)
+    steady = (at >= 0) & (kv_start <= at) & (kv_len >= q_lo + bq)
+    return off, live, steady
+
+
+def flash_window_pairs(kv_start, kv_len, S: int, G: int, dq: int, dv: int, window: int, itemsize: int = 2):
+    """``(multiplied, live)``: the query-key pairs of ONE query head that a
+    windowed prefill call's steps multiply over the rows ``kv_start`` /
+    ``kv_len`` describe, and the pairs of them that are live (``kv_start <= k
+    < kv_len``, ``q - window < k <= q``). In the form the shape takes: a live
+    block's ``bq × span`` under ``flash_window_step``, else the blocks the
+    walk visits (``flash_block_plan``). int32: a row of a 4096 bucket is 2.6e6
+    pairs, so 2^31 are 800 row-layers; a cache's counters start a call at 0."""
+    kv_start, kv_len = kv_start[:, None], kv_len[:, None]
+    step = flash_window_step(S, G, dq, dv, window, itemsize)
+    if step:
+        bq, span = step
+        _, live, _ = flash_window_plan(jnp.arange(S // bq)[None, :], kv_start, kv_len, bq, span, window)
+        multiplied = jnp.sum(live) * (bq * span)
+    else:
+        bq, bk, _ = flash_walk_blocks(S, S, G, dq, dv, True, itemsize)
+        lo, hi, _, _ = flash_block_plan(jnp.arange(S // bq)[None, :], kv_start, kv_len, S, bq, bk, True, window)
+        multiplied = jnp.sum(jnp.maximum(hi - lo + 1, 0)) * (bq * bk)
+    q = jnp.arange(S)[None, :]
+    keys = jnp.minimum(q, kv_len - 1) - jnp.maximum(kv_start, q - window + 1) + 1
+    return multiplied, jnp.sum(jnp.maximum(keys, 0))
+
+
+def _window_kernel(
+    kv_start_ref,  # SMEM [B]
+    kv_len_ref,  # SMEM [B]
+    q_ref,  # [G, bq, dq]: the G query heads of one KV head
+    k_ref,  # [1, S, dq]: that KV head's whole strip, resident across q blocks
+    v_ref,  # [1, S, dv]
+    o_ref,  # [G, bq, dv]
+    *,
+    bq: int,
+    span: int,
+    window: int,
+    scale: float,
+    kv_heads: int,
+):
+    """One grid cell = one step: the block's ``G·bq`` rows against the
+    ``span`` keys ``flash_window_plan`` names, a whole softmax (no running
+    max, sum or accumulator: nothing came before and nothing comes after)."""
+    G = q_ref.shape[0]
+    rows = G * bq
+    qi = pl.program_id(1)
+    b = pl.program_id(0) // kv_heads
+    start, end = kv_start_ref[b], kv_len_ref[b]
+    off, live, steady = flash_window_plan(qi, start, end, bq, span, window)
+    off = pl.multiple_of(off, bq)
+    slack = span - bq - window  # keys the rounding put in front of the oldest a first query sees
+    # in a steady step a pair can be masked only by the window's edge, in the
+    # first columns, or by the diagonal, in the last ``bq``: the columns
+    # between are live whoever asks
+    left = bq * (1 + (slack > 0))
+    edges = left + bq < span
+
+    def tile():
+        # row r is query r % bq of the block: peeled off by G - 1 selects on
+        # one column (no vector division)
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        t = r
+        for g in range(1, G):
+            t = jnp.where(r >= g * bq, r - g * bq, t)
+        return t
+
+    def scores(k):
+        q = q_ref[:].reshape(rows, q_ref.shape[2])
+        return jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [rows, span]
+
+    def store(p, l, v):
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        out = (pv / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)  # a row with no live key: zeros
+        for g in range(G):
+            o_ref[g] = out[g * bq:(g + 1) * bq]
+
+    @pl.when(~live)
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live & ~(steady & edges))
+    def _general():
+        k = k_ref[0, pl.ds(off, span), :]
+        v = v_ref[0, pl.ds(off, span), :]
+        # zero K/V rows outside the valid window BEFORE any matmul: cache
+        # slots past the frontier may be uninitialized device memory, and a
+        # NaN there survives even a zero-weight product (0 * NaN = NaN)
+        cpos = off + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+        cok = (cpos >= start) & (cpos < end)
+        k = jnp.where(cok, k, 0)
+        v = jnp.where(cok, v, 0)
+        s = scores(k)
+        # a key outside the live slots sits past every query: the window, the
+        # diagonal and the row's slots are then two compares over the step
+        k_pos = off + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        k_pos = jnp.where((k_pos >= start) & (k_pos < end), k_pos, _NO_KEY)
+        q_pos = qi * bq + tile()
+        ok = (k_pos <= q_pos) & (k_pos > q_pos - window)
+        s = jnp.where(ok, s, NEG_INF)
+        # the max is floored far above NEG_INF, so a masked entry is an exact
+        # zero even where a whole row is masked (s and its max both at NEG_INF
+        # would make exp(s - m) 1): no second select over the step
+        m = jnp.maximum(jnp.max(s, axis=1, keepdims=True), 0.5 * NEG_INF)
+        p = jnp.exp(s - m)
+        store(p, jnp.sum(p, axis=1, keepdims=True), v)
+
+    if not edges:
+        return
+
+    @pl.when(live & steady)
+    def _steady():
+        # every key of the slice is a live slot and every query has its own
+        # key: no zeroing, no dead row, and a mask on the two edges alone.
+        # Column c is key ``off + c``, row r query ``off + span - bq + t``
+        k = k_ref[0, pl.ds(off, span), :]
+        v = v_ref[0, pl.ds(off, span), :]
+        s = scores(k)
+        t = tile()
+        c = jax.lax.broadcasted_iota(jnp.int32, (1, left), 1)
+        head = jnp.where(c > t + slack, s[:, :left], NEG_INF)  # k > q - window
+        c = jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+        tail = jnp.where(c <= t, s[:, span - bq:], NEG_INF)  # k <= q
+        s = jnp.concatenate([head, s[:, left:span - bq], tail], axis=1)
+        m = jnp.max(s, axis=1, keepdims=True)
+        p = jnp.exp(s - m)
+        store(p, jnp.sum(p, axis=1, keepdims=True), v)
+
+
+def _window_call(qt, kt, vt, kv_start, kv_len, bq, span, *, scale, window, interpret, name):
+    """The one-step windowed prefill ``pallas_call``: ``_flash_call``'s
+    arrays, grid and result (so a trace reads it under the same name and
+    result type), no scratch."""
+    BH, S, dq = qt.shape
+    BK, _, dv = vt.shape
+    G = BH // BK
+
+    def q_index(h, qi, *s_):
+        return (h, qi, 0)
+
+    def kv_index(h, qi, *s_):
+        return (h, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_window_kernel, bq=bq, span=span, window=window, scale=scale,
+                          kv_heads=BK // kv_start.shape[0]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BK, S // bq),
+            in_specs=[
+                pl.BlockSpec((G, bq, dq), q_index),
+                pl.BlockSpec((1, S, dq), kv_index),
+                pl.BlockSpec((1, S, dv), kv_index),
+            ],
+            out_specs=pl.BlockSpec((G, bq, dv), q_index),
+        ),
+        out_shape=jax.ShapeDtypeStruct((BH, S, dv), qt.dtype),
+        interpret=interpret,
+        name=name,
+    )(kv_start.astype(jnp.int32), kv_len.astype(jnp.int32), qt, kt, vt)

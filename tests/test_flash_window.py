@@ -222,3 +222,213 @@ def test_windowed_counters_are_the_plan_s():
     assert got["decode_slots_allocated"] == new * B * T
     assert got["decode_slots_streamed_window"] == want_win
     assert got["decode_slots_allocated_window"] == new * B * T * cfg.num_sliding_layers
+
+
+# ---------------------------------------------------------------------------
+# a window that fits ONE step (``flash_window_step``): the kernel without a
+# walk, its plan, the pairs it multiplies, and what it must leave alone
+# ---------------------------------------------------------------------------
+
+from rag_llm_k8s_tpu.ops.attention import (  # noqa: E402
+    flash_walk_blocks,
+    flash_window_pairs,
+    flash_window_plan,
+    flash_window_step,
+)
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+# name: (S, W, bq, kv_start, kv_len, the form it must take); two rows a case,
+# the second always whole, so that no case is all one kind of block
+ONE_STEP_CASES = {
+    "whole rows": (256, 64, 32, 0, 256, "one step"),
+    "kv_start inside the first window": (256, 64, 32, 37, 256, "one step"),
+    "kv_start inside a later query block": (256, 64, 32, 130, 256, "one step"),
+    "kv_len short of S": (256, 64, 32, 20, 200, "one step"),
+    "an empty row": (256, 64, 32, 50, 50, "one step"),
+    "W no multiple of the query block": (256, 80, 32, 37, 256, "one step"),
+    "W odd, under one query block": (256, 33, 64, 70, 250, "one step"),
+    "W + bq reaches S: the walk": (128, 96, 32, 37, 128, "walk"),
+}
+
+
+@pytest.mark.parametrize("G", [9, 6])
+@pytest.mark.parametrize("case", sorted(ONE_STEP_CASES))
+def test_one_step_window_matches_the_windowed_oracle(case, G):
+    """``flash_attention(window=)`` where the window fits one step, against
+    ``attention_xla(window=)``: NaN planted outside ``[kv_start, kv_len)``
+    reaches no output, a query in the left pad reads zeros, and the call is
+    the kernel without scratch exactly where ``flash_window_step`` says."""
+    S, W, bq, start, end, form = ONE_STEP_CASES[case]
+    B, K, hd = 2, 2, 32
+    q, k, v = _problem(G + S, B, S, G * K, K, hd)
+    kv_start, kv_len = jnp.array([start, 0], jnp.int32), jnp.array([end, S], jnp.int32)
+    pos = jnp.arange(S)[None, :, None, None]
+    ok = (pos >= kv_start[:, None, None, None]) & (pos < kv_len[:, None, None, None])
+    k, v = jnp.where(ok, k, jnp.nan), jnp.where(ok, v, jnp.nan)
+    want = attention_xla(q, jnp.nan_to_num(k), jnp.nan_to_num(v), kv_start, kv_len, window=W)
+
+    step = flash_window_step(S, G, hd, hd, W, 4, bq)
+    assert (step is not None) == (form == "one step"), step
+    if step is not None:
+        assert step[0] == bq and step[1] % bq == 0 and W + bq <= step[1] < min(S, W + 2 * bq)
+
+    def call(q, k, v):
+        return flash_attention(q, k, v, kv_start, kv_len, bq=bq, interpret=True, window=W)
+
+    (eqn,) = _pallas_calls(jax.make_jaxpr(call)(q, k, v).jaxpr)
+    assert eqn.params["name"] == "flash_attention_window"
+    assert (eqn.params["grid_mapping"].num_scratch_operands == 0) == (form == "one step")
+    got = np.asarray(call(q, k, v))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-4, atol=2e-5)
+    assert not got[0, :start].any() and (start == end) == (not got[0].any())
+
+
+class TestOneStepPlan:
+    """``flash_window_plan`` and ``flash_window_pairs`` against the
+    brute-force mask."""
+
+    S = 1024
+
+    @pytest.mark.parametrize("window", [1, 64, 200, 256, 512, 700])
+    @pytest.mark.parametrize("bq", [32, 64, 128])
+    @pytest.mark.parametrize("kv_start,kv_len", [(0, 1024), (1, 1024), (402, 1024), (255, 700), (950, 951),
+                                                 (1023, 1024), (0, 300), (300, 300)])
+    def test_the_slice_holds_every_live_pair_and_steady_means_two_edges(self, kv_start, kv_len, bq, window):
+        S = self.S
+        span = -(-window // bq) * bq + bq
+        assert span < S
+        live = _live(S, kv_start, kv_len, window)
+        slack = span - bq - window
+        for qi in range(S // bq):
+            off, alive, steady = (int(x) for x in flash_window_plan(qi, kv_start, kv_len, bq, span, window))
+            assert off % bq == 0 and 0 <= off <= S - span
+            rows = live[qi * bq:(qi + 1) * bq]
+            assert bool(alive) == bool(rows.any()), (qi, off)
+            assert not rows[:, :off].any() and not rows[:, off + span:].any(), (qi, "a live pair outside the slice")
+            if steady:
+                # the kernel's steady body: every key a live slot, the mask
+                # two compares of a column against the query's place in the block
+                assert alive and off == (qi + 1) * bq - span
+                t, c = np.arange(bq)[:, None], np.arange(span)[None, :]
+                np.testing.assert_array_equal(rows[:, off:off + span], (c > t + slack) & (c <= t + span - bq))
+
+    @pytest.mark.parametrize("G,window,form", [(9, 200, "one step"), (6, 512, "one step"), (4, 100, "one step"),
+                                               (9, 900, "walk"), (6, 2000, "walk")])
+    def test_pairs_multiplied_and_live(self, G, window, form):
+        S, hd = self.S, 128
+        kv_start = np.array([0, 1, 402, 255, 950, 300, 0])
+        kv_len = np.array([1024, 1024, 1024, 700, 951, 300, 300])
+        step = flash_window_step(S, G, hd, hd, window)
+        assert (step is not None) == (form == "one step")
+        if step is None:
+            bq, width, _ = flash_walk_blocks(S, S, G, hd, hd, True, 2)
+        else:
+            bq, width = step
+        multiplied = pairs = 0
+        for start, end in zip(kv_start, kv_len):
+            live = _live(S, start, end, window)
+            pairs += int(live.sum())
+            for qi in range(S // bq):
+                rows = live[qi * bq:(qi + 1) * bq]
+                if step is None:  # the key blocks that hold a live pair
+                    multiplied += bq * width * sum(
+                        bool(rows[:, kj * width:(kj + 1) * width].any()) for kj in range(S // width))
+                else:  # one slice a live block
+                    multiplied += bq * width * bool(rows.any())
+        got = flash_window_pairs(jnp.asarray(kv_start), jnp.asarray(kv_len), S, G, hd, hd, window)
+        assert (int(got[0]), int(got[1])) == (multiplied, pairs)
+        assert pairs <= multiplied
+
+
+def test_the_served_shapes_take_the_form_the_sweep_gave():
+    """A window of 512 under a 4096 bucket at 72 / 8 heads of 128 (the
+    sliding layers of ``laguna-s-ep16.closed8``): 128 queries against one
+    slice of 640 keys. A window in the thousands, a strip that is streamed
+    and a bucket the slice fills keep the walk."""
+    assert flash_window_step(4096, 9, 128, 128, 512) == (128, 640)
+    assert flash_window_step(4096, 6, 128, 128, 512) == (128, 640)
+    assert flash_window_step(4096, 4, 128, 128, 512) == (256, 768)
+    assert flash_window_step(8192, 9, 128, 128, 4096) is None  # 1152 rows x 4224 keys: over VMEM
+    assert flash_window_step(16384, 9, 128, 128, 512) is None  # the strips are streamed
+    assert flash_window_step(512, 9, 128, 128, 512) is None
+
+
+# sha256 (first 16 hex digits) of ``str(jax.make_jaxpr(call)(*shapes))`` as the
+# commit before the one-step form traced it (PR 46's tree). The text holds the
+# kernel's whole body, its grid, blocks and scratch, and no file or line. To
+# re-record after a change that MEANS to move one of these programs, print the
+# digest this test computes.
+NO_WINDOW_PROGRAMS = {
+    "Mistral-7B prefill [32, 4096, 128]": (
+        "06b30de1912bbc4e", "flash", dict(), [(1, 4096, 32, 128), (1, 4096, 8, 128), (1, 4096, 8, 128)]),
+    "a full layer beside the sliding ones [48, 4096, 128]": (
+        "cb76126efbb554ab", "flash", dict(), [(1, 4096, 48, 128), (1, 4096, 8, 128), (1, 4096, 8, 128)]),
+    "MLA's expanded form [128, 4096, 192 / 128]": (
+        "11f367d2dec79259", "mla", dict(scale=0.1), [(1, 4096, 128, 192), (1, 4096, 128, 192), (1, 4096, 128, 128)]),
+    "the encoder [8 x 16, 1536, 64]": (
+        "7d08a7b620ceb89a", "flash", dict(causal=False), [(8, 1536, 16, 64)] * 3),
+    "streamed strips [32, 16384, 128]": (
+        "b6f2810b924d1a68", "flash", dict(), [(1, 16384, 32, 128), (1, 16384, 8, 128), (1, 16384, 8, 128)]),
+    "a window too wide for one step [72, 8192, 128], W 4096": (
+        "7c5d175aac2a97e6", "flash", dict(window=4096), [(1, 8192, 72, 128), (1, 8192, 8, 128), (1, 8192, 8, 128)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NO_WINDOW_PROGRAMS))
+def test_calls_without_a_one_step_window_trace_to_the_programs_they_were(name):
+    import hashlib
+
+    from rag_llm_k8s_tpu.ops.mla import mla_flash_attention
+
+    digest, kind, static, shapes = NO_WINDOW_PROGRAMS[name]
+    fn = mla_flash_attention if kind == "mla" else flash_attention
+    B = shapes[0][0]
+    avals = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes] + [jax.ShapeDtypeStruct((B,), jnp.int32)] * 2
+    text = str(jax.make_jaxpr(lambda q, k, v, s, e: fn(q, k, v, s, e, **static))(*avals))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest, name
+
+
+def test_prefill_pair_counters_are_the_rule_s():
+    """``prefill_window_pairs_multiplied`` / ``_live`` after one single-shot
+    prefill through the kernels: the sliding layers × ``flash_window_pairs``
+    of the batch, the live ones also the brute-force mask's; nothing on the
+    XLA path, nothing from a decode step."""
+    from rag_llm_k8s_tpu.core.config import DTypePolicy, WindowedMoEConfig
+    from rag_llm_k8s_tpu.models import families, windowed_moe as wm
+
+    cfg = WindowedMoEConfig.tiny(vocab_size=64)
+    dt = DTypePolicy.fp32()
+    params = wm.init_windowed_moe_params(jax.random.PRNGKey(0), cfg, dt)
+    B, S, T = 2, 64, 128
+    lens = np.array([64, 23])
+    tokens = jnp.asarray(np.random.RandomState(0).randint(3, 64, (B, S)), jnp.int32)
+    kv_start = jnp.asarray(S - lens, jnp.int32)
+    kv_len = jnp.full((B,), S, jnp.int32)
+    positions = jnp.maximum(jnp.arange(S)[None, :] - kv_start[:, None], 0)
+    heads = dict(zip(cfg.layer_types, cfg.num_attention_heads_per_layer))[wm.SLIDING]
+    W, n_win = cfg.sliding_window, cfg.num_sliding_layers
+    want = flash_window_pairs(kv_start, kv_len, S, heads // cfg.num_kv_heads, cfg.head_dim, cfg.head_dim, W, 4)
+    live = sum(int(_live(S, s, S, W).sum()) for s in np.asarray(kv_start))
+    assert int(want[1]) == live and n_win > 0
+    for impl, counted in (("pallas_interpret", True), ("xla", False)):
+        model = wm.WindowedMoEModel(cfg, dt, attn_impl=impl)
+        logits, cache = model.apply({"params": params}, tokens, positions, families.make_cache(cfg, B, T, jnp.float32),
+                                    kv_start, kv_len, jnp.int32(0), last_logit_only=True)
+        got = wm.fold_counters(np.asarray(cache.counters))
+        assert got["prefill_window_pairs_multiplied"] == counted * n_win * int(want[0])
+        assert got["prefill_window_pairs_live"] == counted * n_win * live
+        tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+        _, cache = model.apply({"params": params}, tok, jnp.asarray(lens[:, None], jnp.int32), cache, kv_start,
+                               kv_len + 1, jnp.int32(S))
+        after = wm.fold_counters(np.asarray(cache.counters))
+        assert after["prefill_window_pairs_live"] == got["prefill_window_pairs_live"]

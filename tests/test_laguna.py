@@ -346,9 +346,10 @@ def test_continuous_engine_and_tp_refuse(params):
 
 def test_the_family_row_and_the_other_two_s(params):
     fam = families.of(CFG)
-    assert fam.counters_width == wm.N_COUNTERS == lm.N_COUNTERS + 2
-    assert fam.counter_names[-2:] == ("decode_slots_streamed_window", "decode_slots_allocated_window")
-    assert fam.counter_names[:-2] == tuple(lm.COUNTER_STATS)  # the latent family's, under their names
+    assert fam.counters_width == wm.N_COUNTERS == lm.N_COUNTERS + 4
+    assert fam.counter_names[-4:] == ("decode_slots_streamed_window", "decode_slots_allocated_window",
+                                      "prefill_window_pairs_multiplied", "prefill_window_pairs_live")
+    assert fam.counter_names[:-4] == tuple(lm.COUNTER_STATS)  # the latent family's, under their names
     assert set(fam.counter_names) == set(wm.fold_counters(np.zeros(wm.N_COUNTERS)))
     assert fam.checkpoint_loader_refusal and "name map" in fam.checkpoint_loader_refusal
     from rag_llm_k8s_tpu.core.config import LatentMoEConfig, LlamaConfig

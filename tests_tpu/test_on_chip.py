@@ -58,6 +58,40 @@ class TestAttentionKernels:
             rtol=2e-4, atol=2e-4,
         )
 
+    def test_windowed_prefill_takes_its_window_in_one_step(self):
+        """72 query heads over 8 of 128 under a window of 512 in a 4096
+        bucket (``flash_window_step``: 128 queries against one slice of 640
+        keys), bf16 as served: against the oracle and against the block walk
+        (``bk`` names the walk) on rows with a left pad inside the first
+        window, inside a later query block and a frontier short of the
+        bucket; NaN outside the live slots reaches no output."""
+        from rag_llm_k8s_tpu.ops.attention import attention_xla, flash_attention, flash_window_step
+
+        B, S, H, K, hd, W = 3, 4096, 72, 8, 128, 512
+        assert flash_window_step(S, H // K, hd, hd, W) == (128, 640)
+        ks = jax.random.split(jax.random.PRNGKey(3), 3)
+        q = jax.random.normal(ks[0], (B, S, H, hd), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (B, S, K, hd), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (B, S, K, hd), jnp.bfloat16)
+        kv_start = jnp.array([0, 150, 1000], jnp.int32)
+        kv_len = jnp.array([S, S, 3000], jnp.int32)
+        pos = jnp.arange(S)[None, :, None, None]
+        ok = (pos >= kv_start[:, None, None, None]) & (pos < kv_len[:, None, None, None])
+        kn, vn = jnp.where(ok, k, jnp.nan), jnp.where(ok, v, jnp.nan)
+        got = np.asarray(flash_attention(q, kn, vn, kv_start, kv_len, window=W), np.float32)
+        walk = np.asarray(flash_attention(q, kn, vn, kv_start, kv_len, window=W, bk=512), np.float32)
+        assert np.isfinite(got).all()
+        # bf16 probabilities into the PV matmul and a bf16 result on both kernels
+        np.testing.assert_allclose(got, walk, rtol=2e-2, atol=2e-2)
+        # the oracle a row and two KV heads at a time (its scores are [heads, S, S] float32)
+        f32 = lambda x: jnp.where(ok, x, 0).astype(jnp.float32)  # noqa: E731
+        for b in range(B):
+            with jax.default_matmul_precision("highest"):
+                want = attention_xla(q[b:b + 1, :, :18].astype(jnp.float32), f32(k)[b:b + 1, :, :2],
+                                     f32(v)[b:b + 1, :, :2], kv_start[b:b + 1], kv_len[b:b + 1], window=W)
+            np.testing.assert_allclose(got[b:b + 1, :, :18], np.asarray(want), rtol=3e-2, atol=3e-2)
+        assert not got[1, :150].any() and not got[2, :1000].any()
+
     def test_decode_matches_oracle(self):
         from rag_llm_k8s_tpu.ops.attention import decode_attention, decode_attention_xla
 
