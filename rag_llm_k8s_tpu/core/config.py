@@ -957,6 +957,174 @@ class ConvMoEConfig:
 
 
 @dataclass(frozen=True)
+class DeltaMoEConfig:
+    """The gated delta-rule, sparse-expert decoder family
+    (``models/delta_moe.py``). Every layer is ``h = x + Mixer(RMS(x))``, ``y =
+    h + FFN(RMS(h))``, and a layer's mixer is one of two kinds, named by the
+    published lists (1-indexed, as published):
+
+    - ``kda_layers``: LINEAR ATTENTION by the gated delta rule
+      (``ops/delta_rule.py``). ``q, k, v`` (``kda_num_heads`` heads of
+      ``kda_head_dim``) each go through a depthwise causal convolution of
+      ``short_conv_kernel_size`` taps and a SiLU; ``q`` and ``k`` are
+      L2-normed over the head; a low-rank projection gives a log decay for
+      EVERY key channel, ``g = -exp(A_log) * softplus(W_fb W_fa x + dt_bias)``,
+      another the output gate, a third ``beta = sigmoid(W_b x)``. The state is
+      a float32 ``[kda_head_dim, kda_head_dim]`` matrix a head: no position
+      axis, overwritten in place. The output is RMS-normed over the head,
+      gated by a sigmoid and projected.
+    - ``full_attn_layers``: multi-head latent attention as
+      ``LatentMoEConfig``'s with a DIRECT query projection (``q_lora_rank``
+      is None, as published) and, under ``mla_use_nope``, no rotation of the
+      shared key slice: the model has no position term at all.
+
+    The first ``first_k_dense_replace`` layers' FFN is a dense SwiGLU, every
+    later layer's ``models/latent_moe.py``'s sparse FFN: sigmoid scores over
+    ``num_experts``, the top ``num_experts_per_token`` of score plus bias
+    (``num_expert_group`` groups, ``topk_group`` kept), weights normalised
+    over the chosen (``moe_renormalize``) times ``routed_scaling_factor``,
+    ``num_shared_experts`` shared experts. ``ep_size`` / ``ep_rank``: this
+    chip's share of the routed experts, as ``LatentMoEConfig``. Field names
+    are the published ``config.json``'s (``linear_attn_config``'s keys flat:
+    ``kda_num_heads``, ``kda_head_dim``).
+
+    Defaults are the published widths and depth of the 48B-A3B model the
+    ``kimi-linear-ep16.solo`` cell serves a share of."""
+
+    vocab_size: int = 163840
+    hidden_size: int = 2304
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 27
+    first_k_dense_replace: int = 1
+    full_attn_layers: Tuple[int, ...] = (4, 8, 12, 16, 20, 24, 27)
+    kda_layers: Tuple[int, ...] = (1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22, 23, 25, 26)
+    kda_num_heads: int = 32
+    kda_head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    kda_gate_rank: int = 128  # the two low-rank gates' inner width (not a published key: the head's size)
+    num_attention_heads: int = 32
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    num_experts: int = 256
+    num_shared_experts: int = 1
+    num_experts_per_token: int = 8
+    moe_renormalize: bool = True
+    routed_scaling_factor: float = 2.446
+    num_expert_group: int = 1
+    topk_group: int = 1
+    ep_size: int = 1
+    ep_rank: int = 0
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    max_seq_len: int = 1048576
+    tie_word_embeddings: bool = False
+    bos_token_id: int = 1
+    eos_token_ids: Tuple[int, ...] = (2,)
+
+    def __post_init__(self):
+        layers = tuple(range(1, self.num_hidden_layers + 1))
+        if tuple(sorted(self.kda_layers + self.full_attn_layers)) != layers:
+            raise ValueError("kda_layers and full_attn_layers name every layer 1..num_hidden_layers once")
+        if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
+            raise ValueError("first_k_dense_replace: a leading run of the layers")
+        if any(i in self.full_attn_layers for i in range(1, self.first_k_dense_replace + 1)):
+            raise ValueError("the leading dense layers are linear-attention layers")
+        if self.q_lora_rank is not None:
+            raise ValueError("this family's latent attention projects its queries directly (q_lora_rank null)")
+        if self.short_conv_kernel_size < 2:
+            raise ValueError("short_conv_kernel_size: the convolution keeps at least one earlier input")
+        if self.num_experts % self.ep_size or not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(
+                f"ep_size={self.ep_size}, ep_rank={self.ep_rank}: the {self.num_experts} routed "
+                "experts must divide evenly over the ranks and the rank must be one of them")
+        if self.num_experts % self.num_expert_group or not 0 < self.topk_group <= self.num_expert_group:
+            raise ValueError("num_expert_group must divide num_experts; topk_group <= num_expert_group")
+        if self.tie_word_embeddings:
+            raise ValueError("this family serves an untied head only")
+
+    # the names the rest of the program reads a decoder's sizes by
+    num_layers = property(lambda self: self.num_hidden_layers)
+    num_heads = property(lambda self: self.num_attention_heads)
+    num_kv_heads = property(lambda self: self.num_attention_heads)
+    first_k_dense = property(lambda self: self.first_k_dense_replace)
+    num_kda_layers = property(lambda self: len(self.kda_layers))
+    num_mla_layers = property(lambda self: len(self.full_attn_layers))
+    kda_width = property(lambda self: self.kda_num_heads * self.kda_head_dim)
+    qk_head_dim = property(lambda self: self.qk_nope_head_dim + self.qk_rope_head_dim)
+    num_cache_planes = property(lambda self: len(self.full_attn_layers))  # a latent plane a full layer
+
+    def is_full(self, layer: int) -> bool:
+        """Whether 0-indexed ``layer`` is a latent-attention layer."""
+        return layer + 1 in self.full_attn_layers
+
+    # what ``models/latent_moe.py``'s attention and sparse FFN (LatentAttention,
+    # SparseMLP, Experts) read of a configuration, under the names they read
+    num_moe_layers = property(lambda self: self.num_hidden_layers - self.first_k_dense_replace)
+    experts_held = property(lambda self: self.num_experts // self.ep_size)
+    first_held = property(lambda self: self.ep_rank * self.experts_held)
+    n_routed_experts = property(lambda self: self.num_experts)
+    router_width = property(lambda self: self.num_experts)
+    n_shared_experts = property(lambda self: self.num_shared_experts)
+    num_experts_per_tok = property(lambda self: self.num_experts_per_token)
+    norm_topk_prob = property(lambda self: self.moe_renormalize)
+    n_group = property(lambda self: self.num_expert_group)
+    scoring_func = property(lambda self: "sigmoid")
+    zero_expert_num = property(lambda self: 0)
+    norm_topk_eps = property(lambda self: 1e-20)  # in the normalising sum of the chosen scores
+    rope_scaling = property(lambda self: None)
+    mla_scale_q_lora = property(lambda self: False)
+    mla_scale_kv_lora = property(lambda self: False)
+
+    def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
+        """(FLOPs a token, weight bytes, KV bytes a position of context), as
+        ``LlamaConfig.roofline_terms`` (bf16 only, neither argument read). A
+        token's matmuls: a linear-attention mixer's projections (q, k, v, o,
+        the two low-rank gates, beta) or a latent one's, a dense layer's
+        SwiGLU or a sparse layer's router, shared expert and the routed
+        experts a balanced router sends to those HELD here, the head.
+        ``weight_bytes`` is what a decode step streams at batch 1, and with it
+        the linear-attention layers' state and kept convolution inputs, read
+        and written once a step: they are CONSTANT in the context.
+        ``kv_bytes_per_token`` is one position's latent row over the full
+        layers' planes only."""
+        D, W, R = self.hidden_size, self.kda_width, self.kda_gate_rank
+        H = self.num_attention_heads
+        kda = 4 * D * W + 2 * (D * R + R * W) + D * self.kda_num_heads
+        mla = (D * H * self.qk_head_dim + D * (self.kv_lora_rank + self.qk_rope_head_dim)
+               + self.kv_lora_rank * H * (self.qk_nope_head_dim + self.v_head_dim) + H * self.v_head_dim * D)
+        expert = 3 * D * self.moe_intermediate_size
+        routed_here = self.num_experts_per_token * self.experts_held / self.num_experts
+        sparse_ffn = D * self.num_experts + (self.num_shared_experts + routed_here) * expert
+        active = (self.num_kda_layers * kda + self.num_mla_layers * mla
+                  + self.first_k_dense_replace * 3 * D * self.intermediate_size
+                  + self.num_moe_layers * sparse_ffn + self.vocab_size * D)
+        state = 4 * self.kda_num_heads * self.kda_head_dim ** 2 + 2 * 3 * W * (self.short_conv_kernel_size - 1)
+        return (2.0 * active, 2.0 * active + 2.0 * self.num_kda_layers * state,
+                2.0 * self.num_mla_layers * (self.kv_lora_rank + self.qk_rope_head_dim))
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 256, **overrides) -> "DeltaMoEConfig":
+        """Miniature config for CPU tests with the published pattern cut to
+        eleven layers (1 dense linear layer, then K K M | K K K M | K K M):
+        4 heads of 16 on both kinds, 16 experts of which rank 1 of 2 holds 8."""
+        base = dict(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+            num_hidden_layers=11, first_k_dense_replace=1, full_attn_layers=(4, 8, 11),
+            kda_layers=(1, 2, 3, 5, 6, 7, 9, 10), kda_num_heads=4, kda_head_dim=16, kda_gate_rank=16,
+            num_attention_heads=4, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            num_experts=16, num_experts_per_token=4, ep_size=2, ep_rank=1, max_seq_len=512,
+            bos_token_id=1, eos_token_ids=(2,),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
 class EncoderConfig:
     """Bidirectional encoder config for the embedding model.
 

@@ -31,7 +31,8 @@ from typing import Callable, Dict, Mapping, Optional, Tuple
 import jax.numpy as jnp
 
 from rag_llm_k8s_tpu.core.config import (
-    BlockWindowConfig, ConvMoEConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig, WindowedMoEConfig,
+    BlockWindowConfig, ConvMoEConfig, DeltaMoEConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig,
+    WindowedMoEConfig,
 )
 from rag_llm_k8s_tpu.parallel.sharding import llama_param_specs, replicated_param_specs
 
@@ -224,8 +225,31 @@ def _conv_moe() -> Family:
         commit=cm.commit)
 
 
+def _delta_moe() -> Family:
+    from rag_llm_k8s_tpu.models import delta_moe as dm
+
+    return replicated_row(
+        "gated delta-rule sparse-expert", DeltaMoEConfig, dm.DeltaMoEModel, dm.make_delta_cache,
+        refuses={
+            "continuous": "a float32 matrix state a head has no blocks to page, and preemption, resume and a "
+                          "per-row frontier need snapshots of it (2 MB a row-layer) that nothing takes yet; "
+                          "use 'coalesce'",
+            "prefix_cache": "a recurrent matrix state can be reused only for an exact prefix, and only if a "
+                            "snapshot was kept at its end: a spliced segment's latents say nothing of it",
+            "kv_quant": "the state is float32 by construction (a rank-one correction every token) and the "
+                        "latent planes and the kept convolution inputs have no int8 form",
+            "weight_quant": "quantize_llama_params does not know this tree (mixers stacked by kind, float32 "
+                            "A_log and time-step bias, stacked experts, the router)",
+            "mesh": "this tree has no partition rules (the state splits by head, the latent projections do "
+                    "not), and experts across chips need the all-to-all",
+        },
+        counters_width=dm.N_COUNTERS, counter_names=dm.COUNTER_NAMES, fold_counters=dm.fold_counters,
+        commit=dm.commit)
+
+
 # configuration type -> its family (a thunk where building it imports the model)
 _TABLE: Tuple[Tuple[type, Callable[[], Family]], ...] = (
+    (DeltaMoEConfig, _delta_moe),
     (ConvMoEConfig, _conv_moe),
     (HybridSSMConfig, _hybrid_ssm),
     (BlockWindowConfig, _block_window),

@@ -208,6 +208,8 @@ class LatentAttention(nn.Module):
     dtypes: DTypePolicy
     attn_impl: str = "auto"
     chunked: bool = False  # S > 1 calls attend over the cache (offset causality)
+    q_direct: bool = False  # one query projection ``wq`` from the stream: no low-rank step, no norm
+    rotate: bool = True  # False: the shared key slice and the queries' are not rotated (no position term)
 
     @nn.compact
     def __call__(self, x, planes, layer, kv_start, kv_len, cos, sin, write_index):
@@ -219,14 +221,19 @@ class LatentAttention(nn.Module):
         impl = resolve_impl(self.attn_impl)
         scale = softmax_scale(c)
 
-        c_q = RMSNorm(c.rms_norm_eps, dt, name="q_norm")(dense(c.q_lora_rank, "wq_a")(x))
-        wq_b, wo = dense(H * (dn + R), "wq_b"), dense(c.hidden_size, "wo")
+        if self.q_direct:
+            c_q, wq_b = x, dense(H * (dn + R), "wq")
+        else:
+            c_q = RMSNorm(c.rms_norm_eps, dt, name="q_norm")(dense(c.q_lora_rank, "wq_a")(x))
+            wq_b = dense(H * (dn + R), "wq_b")
+        wo = dense(c.hidden_size, "wo")
+        rope = apply_rope if self.rotate else lambda x, cos, sin: x
         latent = dense(C + R, "wkv_a")(x)
         c_kv = RMSNorm(c.rms_norm_eps, dt, name="kv_norm")(latent[..., :C])
         if c.mla_scale_kv_lora:  # the latent is cached scaled: both forms read it alike
             c_kv = c_kv * jnp.asarray((c.hidden_size / C) ** 0.5, c_kv.dtype)
         q_scale = (c.hidden_size / c.q_lora_rank) ** 0.5 if c.mla_scale_q_lora else None
-        k_rope = apply_rope(latent[..., None, C:], cos, sin)[:, :, 0]  # [B, S, R]
+        k_rope = rope(latent[..., None, C:], cos, sin)[:, :, 0]  # [B, S, R]
         w_ukv = Kernel((C, H * (dn + dv)), dt, name="wkv_b")().astype(dt.compute_dtype)
 
         c_cache, r_cache = planes
@@ -240,7 +247,7 @@ class LatentAttention(nn.Module):
             if q_scale is not None:  # nope and rope slices alike, before the rotation
                 q = q * jnp.asarray(q_scale, q.dtype)
             q = q.reshape(*c_q.shape[:2], H, dn + R)
-            return q[..., :dn], apply_rope(q[..., dn:], cos, sin)
+            return q[..., :dn], rope(q[..., dn:], cos, sin)
 
         if S > 1 and not self.chunked:
             # single-shot prefill: the prompt's own latents, expanded
@@ -300,9 +307,10 @@ def rowwise(config: LatentMoEConfig, batch: int, seq: int, dtype) -> bool:
 def by_rows(fn, *args):
     """``fn`` over the batch a row at a time, in order: each row starts when
     the one before it is written (an optimization barrier ties them), so what
-    a row expands to is live once. Rows are written into one buffer by
-    updates traced HERE, under the caller's scope: joined by a concatenate,
-    the compiler writes them with copies of its own that carry no scope."""
+    a row expands to is live once. Rows are written into one buffer (one a
+    leaf, where ``fn`` returns a tuple) by updates traced HERE, under the
+    caller's scope: joined by a concatenate, the compiler writes them with
+    copies of its own that carry no scope."""
     out = None
     for b in range(args[0].shape[0]):
         row = tuple(a[b:b + 1] for a in args)
@@ -310,8 +318,9 @@ def by_rows(fn, *args):
             row, out = jax.lax.optimization_barrier((row, out))
         y = fn(*row)
         if out is None:
-            out = jnp.zeros((args[0].shape[0],) + y.shape[1:], y.dtype)
-        out = jax.lax.dynamic_update_slice(out, y, (b,) + (0,) * (y.ndim - 1))
+            out = jax.tree.map(lambda leaf: jnp.zeros((args[0].shape[0],) + leaf.shape[1:], leaf.dtype), y)
+        out = jax.tree.map(lambda buf, leaf: jax.lax.dynamic_update_slice(buf, leaf, (b,) + (0,) * (leaf.ndim - 1)),
+                           out, y)
     return out
 
 

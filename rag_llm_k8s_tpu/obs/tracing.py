@@ -85,11 +85,17 @@ SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
 # ``attn/scan`` around a state layer's selective scan (the kernel or the XLA
 # form; in a decode step the one-position update and the contraction with C),
 # ``attn/conv`` around its causal convolution and the roll of that state, and
-# ``attn/global`` around its attention layers' attention. A
+# ``attn/global`` around its attention layers' attention. The gated
+# delta-rule family (models/delta_moe.py) opens ``attn/kda`` around a
+# linear-attention layer's mixer, and beneath it ``attn/kda/conv`` (the causal
+# convolution of q, k and v), ``attn/kda/gate`` (the decay's, beta's and the
+# output gate's projections) and ``attn/kda/delta`` (the recurrence: the chunk
+# form, the single-token step, a verify step's chunk and ``commit``'s
+# replay); its full layers open ``attn/latent`` and its FFNs ``mlp/*``. A
 # reader that files an operation under the first sub-scope it knows keeps
 # reading ``attn`` and ``mlp``; one that knows these sees the finer split.
 FINE_SCOPES = ("latent", "router", "experts", "shared", "zero", "dense", "window", "global", "gate",
-               "ring", "pool", "scan", "conv")
+               "ring", "pool", "scan", "conv", "kda", "delta")
 SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES + FINE_SCOPES)
 
 

@@ -295,6 +295,17 @@ SMALL_GROUP_ROWS = 1024
 _WHOLE_K_BLOCK = 3 << 20
 
 
+def _fit_width(width: int, pref: int) -> int:
+    """``_fit_block`` for a weight's side: where halving ``pref`` lands under
+    512 (a width that 512 does not divide: 2304 = 9 * 256 halves to 256), the
+    widest multiple of ``LANES`` up to ``pref`` that tiles it (768). Every
+    width 512 divides keeps its halving."""
+    block = _fit_block(width, pref)
+    if block >= 512:
+        return block
+    return max([b for b in range(LANES, min(pref, width) + 1, LANES) if width % b == 0] + [block])
+
+
 def grouped_blocks(m: int, G: int, k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
     """``grouped_matmul``'s ``(tm, tk, tn)``, from the shape alone (no option,
     no model's name): the row tile, and the ``[tk, tn]`` block of a group's
@@ -317,8 +328,9 @@ def grouped_blocks(m: int, G: int, k: int, n: int, itemsize: int) -> Tuple[int, 
     256-row tiles at 1.16 ms a call for 1.84 (128-row tiles fill better, 67%
     for 50, and run no faster: 1.20); a decode step's or a verify chunk's
     128-row buffer keeps its one tile and halves its grid steps, for 2% of a
-    gate or up call. The sweep (v5e): PERF.md section 6, PR 46."""
-    tm, tk, tn = _fit_block(m, 512), _fit_block(k, 1024), _fit_block(n, 1024)
+    gate or up call. The sweep (v5e): PERF.md section 6, PR 46. A side that
+    512 does not divide (2304) takes ``_fit_width``'s divisor, 768."""
+    tm, tk, tn = _fit_block(m, 512), _fit_width(k, 1024), _fit_width(n, 1024)
     mean = m // G
     if mean >= SMALL_GROUP_ROWS or k * tn * itemsize > _WHOLE_K_BLOCK:
         return tm, tk, tn

@@ -323,7 +323,8 @@ def test_the_benchmark_lists_the_metric_for_the_four_batched_cells():
     assert entry["source"] == "program_counter" and entry["moves"] == "latency_p50_ms"
     assert entry["workloads"] == ["mistral-7b-int8.closed8", "mistral-nemo-tp4.closed4",
                                   "dots-vlm1-ep16.closed8", "longcat-flash-ep32.closed8",
-                                  "laguna-s-ep16.closed8", "jamba2-3b.closed8", "lfm2-24b-a2b-pp4.solo"]
+                                  "laguna-s-ep16.closed8", "jamba2-3b.closed8", "lfm2-24b-a2b-pp4.solo",
+                                  "kimi-linear-ep16.solo"]
 
 
 # ---------------------------------------------------------------------------
