@@ -37,7 +37,11 @@ does to a frontier reaches them. So:
   the recurrence: the convolution's input is forced to 0 there and so are
   ``g`` and ``beta``; a row of nothing but pads leaves its state exactly
   zero. A fresh prompt's recurrence starts at the first chunk that holds a
-  real token (``first_chunk``): the live suffix, not the bucket.
+  real token (``first_chunk``): the live suffix, not the bucket. Where the
+  program builds kernels, a prefill's and a prompt chunk's recurrence is ONE
+  call of the kernel ``ops/delta_rule.py delta_rule_chunked`` a layer-row
+  (the state in VMEM for the row's whole walk); a verify step's one chunk
+  and ``commit``'s replay stay XLA's chunk form.
 - *A verify step keeps some of what it fed*, and a state cannot be taken
   back. Keeping every fed position's state (as ``models/hybrid_ssm.py`` keeps
   its 327 kB) would write ``n`` x 2.1 MB a row-layer; the model built with
@@ -207,6 +211,7 @@ class DeltaAttention(nn.Module):
 
     config: DeltaMoEConfig
     dtypes: DTypePolicy
+    attn_impl: str = "auto"
     chunked: bool = False  # S > 1 calls run from the state the cache holds
     keep_steps: bool = False  # leave the state as it was and the step's inputs for ``commit``
 
@@ -214,6 +219,7 @@ class DeltaAttention(nn.Module):
     def __call__(self, x, history, s0, start):
         c, dt = self.config, self.dtypes
         B, S, D = x.shape
+        impl = resolve_attn_impl(self.attn_impl)
         H, hd, W, R = c.kda_num_heads, c.kda_head_dim, c.kda_width, c.kda_gate_rank
         taps, f32 = c.short_conv_kernel_size - 1, jnp.float32
         dense = lm._dense(self, dt)
@@ -249,10 +255,10 @@ class DeltaAttention(nn.Module):
                     o, s1 = delta_rule.delta_rule_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s0)
                     o = o[:, None]
                 elif keep:  # one chunk from the state, which stays: ``commit`` replays what was kept
-                    o, s1 = delta_rule.delta_rule_chunked(q, k, v, g, beta, s0, chunk=S)[0], s0
-                else:
+                    o, s1 = delta_rule.delta_rule_chunked_xla(q, k, v, g, beta, s0, chunk=S)[0], s0
+                else:  # the kernel where ``impl`` builds kernels: a walk from the first live chunk
                     o, s1 = delta_rule.delta_rule_chunked(
-                        q, k, v, g, beta, s0, first_chunk=first_chunk(start) if fresh else None)
+                        q, k, v, g, beta, s0, first_chunk=first_chunk(start) if fresh else None, impl=impl)
             o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + c.rms_norm_eps)
             y = (o * o_scale.astype(f32) * gate).astype(dt.compute_dtype).reshape(rows, S, W)
             if keep:
@@ -347,7 +353,7 @@ class Layer(nn.Module):
 
 def mixer_modules(config, dtypes, attn_impl: str, chunked: bool, keep_steps: bool):
     """The two mixers, detached: their parameters are the model's stacks."""
-    return (DeltaAttention(config, dtypes, chunked, keep_steps, parent=None),
+    return (DeltaAttention(config, dtypes, attn_impl, chunked, keep_steps, parent=None),
             lm.LatentAttention(config, dtypes, attn_impl, chunked, q_direct=True,
                                rotate=not config.mla_use_nope, parent=None))
 
@@ -378,7 +384,8 @@ class DeltaMoEModel(nn.Module):
         start = jnp.maximum(kv_start.astype(jnp.int32) - wi, 0)  # [B]: indices of this call in front of it are pads
         keep = self.keep_steps and S > 1
         mode = "decode" if S == 1 else "chunk" if self.chunked else "prefill"
-        count_kernel_build(mode, "delta_rule_step" if S == 1 else "delta_rule_chunked_xla")
+        # a verify step is ONE chunk from the state, in XLA's form whatever ``impl``
+        count_kernel_build(mode, "delta_rule_step" if S == 1 else delta_rule.chunk_form("xla" if keep else impl))
         with phase_scope("embed"):
             embedding = self.param("embedding", nn.initializers.normal(stddev=0.02),
                                    (c.vocab_size, c.hidden_size), dt.param_dtype)

@@ -40,6 +40,7 @@ from rag_llm_k8s_tpu.engine.engine import InferenceEngine
 from rag_llm_k8s_tpu.models import delta_moe as dm
 from rag_llm_k8s_tpu.models import families
 from rag_llm_k8s_tpu.models import latent_moe as lm
+from rag_llm_k8s_tpu.obs import tracing
 from rag_llm_k8s_tpu.ops import delta_rule, moe
 
 FP32 = DTypePolicy.fp32()
@@ -166,6 +167,8 @@ def test_prefill_then_decode_matches_reference_at_every_position(impl, prompt_le
     tokens = prompt_of(prompt_len + 5, 1)
     (got,), _, _ = through_the_cache([tokens], S0, [prompt_len], impl)
     np.testing.assert_allclose(got, reference(tokens), atol=ATOL)
+    if impl != "xla":  # the bucket's recurrence went through the kernel (80 positions: two of its chunks)
+        assert tracing.kernel_builds()[("prefill", "delta_rule_chunked_pallas")] > 0
 
 
 def test_the_published_depth_is_a_dense_linear_layer_then_three_to_one_to_the_last_layer():
@@ -308,18 +311,45 @@ def test_a_chunk_over_the_cache_starts_from_the_state_it_is_handed(impl, start, 
     step, _ = model_call(impl)(jnp.asarray([[tokens[start + n]]], jnp.int32), jnp.asarray([[start + n]]), cache, ks,
                                jnp.full((1,), slot + 1, jnp.int32), jnp.int32(slot))
     np.testing.assert_allclose(np.asarray(step[0, 0]), reference(tokens)[-1], atol=ATOL)
+    if impl != "xla":  # the kernel starts from the state it is handed, as from zeros
+        assert tracing.kernel_builds()[("chunk", "delta_rule_chunked_pallas")] > 0
 
 
-def test_a_batch_goes_through_the_mixer_a_row_at_a_time_by_shape(monkeypatch):
+@pytest.mark.parametrize("impl,S,kw,mode,kernel", [
+    ("pallas_interpret", S0, {}, "prefill", "delta_rule_chunked_pallas"),
+    ("pallas_interpret", 16, {"chunked": True}, "chunk", "delta_rule_chunked_pallas"),
+    ("pallas_interpret", 16, {"chunked": True, "keep_steps": True}, "chunk", "delta_rule_chunked_xla"),
+    ("xla", S0, {}, "prefill", "delta_rule_chunked_xla"),
+    ("xla", 16, {"chunked": True}, "chunk", "delta_rule_chunked_xla"),
+    ("pallas_interpret", 1, {}, "decode", "delta_rule_step")])
+def test_the_build_counter_names_the_form_of_the_recurrence(impl, S, kw, mode, kernel):
+    """``rag_attend_kernel_builds_total{mode, kernel}``: the kernel for a
+    fresh prefill and a prompt chunk from a held state where ``impl`` builds
+    kernels; XLA's chunk form for a verify step (one chunk from the state,
+    whatever ``impl``) and on the CPU; the step form for a token."""
+    model = dm.DeltaMoEModel(CFG, FP32, attn_impl=impl, **kw)
+    cache = dm.make_delta_cache(CFG, 1, 256, jnp.float32)
+    ids = jnp.zeros((1, S), jnp.int32)
+    before = tracing.kernel_builds()
+    jax.eval_shape(lambda *a: model.apply({"params": PARAMS}, *a), ids, ids, cache, jnp.zeros((1,), jnp.int32),
+                   jnp.full((1,), 100, jnp.int32), jnp.int32(0 if mode == "prefill" else 80))
+    built = {key for key, n in tracing.kernel_builds().items() if n > before.get(key, 0)}
+    assert (mode, kernel) in built
+    assert not {name for _, name in built if name.startswith("delta_rule")} - {kernel}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_a_batch_goes_through_the_mixer_a_row_at_a_time_by_shape(monkeypatch, impl):
     """The rule reads the shape alone, and a batch served a row at a time is
-    the batch: each row's recurrence then starts at its OWN first live chunk."""
+    the batch: each row's recurrence then starts at its OWN first live chunk
+    (through the kernel too: each row's call meets it at batch 1)."""
     assert not dm.mixer_by_rows(DeltaMoEConfig(), 1, 4096) and not dm.mixer_by_rows(DeltaMoEConfig(), 2, 2048)
     assert dm.mixer_by_rows(DeltaMoEConfig(), 2, 4096) and dm.mixer_by_rows(DeltaMoEConfig(), 8, 2048)
     rows = [prompt_of(150, 2), prompt_of(40, 3)]
     want, _, _ = through_the_cache(rows, 192, [150, 40])
     monkeypatch.setattr(lm, "ROWWISE_BYTES", 1)
     _CALLS.clear()
-    got, after, _ = through_the_cache(rows, 192, [150, 40])
+    got, after, _ = through_the_cache(rows, 192, [150, 40], impl)
     _CALLS.clear()
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=ATOL)

@@ -843,8 +843,8 @@ def test_delta_moe_programs_compile_with_their_kernels(one_chip, uncached):
     width here that 512 does not divide; 32 heads of 128 on both kinds; four
     taps; 256 experts of 1024 of which 16 are held, top 8) with a narrow dense
     FFN, a small vocabulary and the pattern K | K K M, through the Pallas path:
-    the bucketed prefill (the chunked recurrence beside the latent flash
-    kernel at 32 heads; the grouped expert matmul at 768-wide tiles of 2304)
+    the bucketed prefill (the chunked recurrence's kernel beside the latent
+    flash kernel at 32 heads; the grouped expert matmul at 768-wide tiles of 2304)
     with the decode loop (the single-token step beside the absorbed decode
     kernel), the verify loop with ``commit`` (the step's k, v, g and beta kept
     for the replay; the state as it was), and the exact scorer all lower for
@@ -871,9 +871,12 @@ def test_delta_moe_programs_compile_with_their_kernels(one_chip, uncached):
 
     state_copy = re.compile(r"= f32\[3,1,32,128,128\]\S* copy\(")
     text = compiled(eng._make_gen(1, 4096, 8), tok, tok, rng)
-    for kernel in ("%mla_flash_attention", "%mla_decode_attention", "%grouped_matmul", "%route_topk"):
+    for kernel in ("%delta_rule_chunked", "%mla_flash_attention", "%mla_decode_attention", "%grouped_matmul",
+                   "%route_topk"):
         assert kernel in text, f"{kernel}: not in the batch-1 generate program"
     assert "f32[1,32,128,128]" in text and " conditional(" in text and not state_copy.search(text)
+    # the bucket's recurrence is the kernel's: no triangular solve of a 64-position chunk is left
+    assert "f32[1,32,1,64,64]" not in text
     text = compiled(eng._make_gen_spec(4096, 8), tok, tok, rng)
     assert "f32[3,1,16,32,128]" in text  # sixteen fed positions' k, v and g a linear layer, for commit's replay
     assert "f32[3,1,16,32,128,128]" not in text and not state_copy.search(text)  # and no state a position

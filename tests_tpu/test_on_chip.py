@@ -573,3 +573,37 @@ class TestRouterKernelOnChip:
         import chip_smoke
 
         chip_smoke.phase_route(0, {name: chip_smoke.SERVING_ROUTE[name]})
+
+
+class TestDeltaRuleKernelOnChip:
+    def test_the_kernel_is_the_xla_chunk_form_at_the_served_shape(self):
+        """``ops/delta_rule.py``'s kernel against the XLA chunk form at the
+        highest matmul precision, at the shape ``kimi-linear-ep16.solo``
+        prefills: one row of a 4096 bucket, 3400 live positions behind left
+        pads, 32 heads of 128, ``v`` in bfloat16, from a state that is not
+        zero. Interpret mode cannot see the MXU's passes; this can."""
+        from rag_llm_k8s_tpu.ops import delta_rule as dr
+
+        B, S, H, D, live = 1, 4096, 32, 128, 3400
+        ks = jax.random.split(jax.random.PRNGKey(50), 6)
+        q = jax.random.normal(ks[0], (B, S, H, D))
+        k = jax.random.normal(ks[1], (B, S, H, D))
+        q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
+        k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+        v = jax.random.normal(ks[2], (B, S, H, D)).astype(jnp.bfloat16)
+        real = jnp.arange(S) >= S - live
+        # alpha log-uniform from 1e-3 to 0.9999 a channel: a memory of one token to one of thousands
+        g = -jnp.exp(jax.random.uniform(ks[3], (B, S, H, D), minval=np.log(1e-4), maxval=np.log(6.9)))
+        g = jnp.where(real[None, :, None, None], g, 0.0)
+        beta = jnp.where(real[None, :, None], jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H))), 0.0)
+        s0 = 0.1 * jax.random.normal(ks[5], (B, H, D, D))
+        first = jnp.int32((S - live) // dr.CHUNK)
+        with jax.default_matmul_precision("highest"):
+            want_o, want_s = jax.jit(lambda *a: dr.delta_rule_chunked_xla(*a, first_chunk=first))(q, k, v, g, beta, s0)
+            got_o, got_s = dr.delta_rule_chunked(q, k, v, g, beta, s0, first_chunk=first, impl="pallas")
+        assert not np.asarray(got_o[:, :first * dr.CHUNK]).any()  # the skipped chunks' rows are zeros
+        err_o = float(jnp.abs(got_o - want_o).max())
+        err_s = float(jnp.abs(got_s - want_s).max())
+        print(f"delta_rule_chunked on the chip: |o| error {err_o:.3g} (of {float(jnp.abs(want_o).max()):.3g}), "
+              f"state error {err_s:.3g} (of {float(jnp.abs(want_s).max()):.3g})")
+        assert err_o <= 2e-5 and err_s <= 2e-5
