@@ -56,6 +56,8 @@ class _Pending:
     t_enqueue: float = field(default_factory=time.monotonic)  # wait anchor
     rows: int = 0  # size of the dispatch this request rode (set by the worker)
     wait_s: float = 0.0  # enqueue -> dispatch
+    trace_id: Optional[str] = None  # the submitting request's, for the dispatch's riders
+    record: Optional[tracing.DispatchRecord] = None  # the dispatch it rode (set by the worker)
 
 
 @dataclass
@@ -101,6 +103,7 @@ class Coalescer:
         hint_grace_ms: float = 4.0,
     ):
         self.batch_fn = batch_fn
+        self.stage = "retrieve"  # the counters' label, and the worker's ``<stage>_batch`` span
         self.max_batch = max_batch
         self.max_wait_ms = max_wait_ms
         self.pending_hint = pending_hint
@@ -111,6 +114,13 @@ class Coalescer:
         self.wait_histogram = None
         # optional obs Counter — shutdown join timeouts (see _join_worker)
         self.join_timeout_counter = None
+        # optional obs counter FAMILIES (settable after construction), as
+        # BatchScheduler's: rag_coalesce_dispatch_rows_total{stage, rows}
+        # counts the items of each batch by its size, and
+        # rag_coalesce_dispatch_reason_total{stage, reason} why the drain
+        # loop stopped ("full" | "hint" | "deadline")
+        self.dispatch_counter = None
+        self.reason_counter = None
         self._queue: "queue.Queue[_PendingItem]" = queue.Queue()
         self._stop = threading.Event()
         self._lifecycle_lock = threading.Lock()
@@ -147,6 +157,7 @@ class Coalescer:
                 now = time.monotonic()
                 deadline = now + self.max_wait_ms / 1e3
                 hint_from = now + min(self.hint_grace_ms, self.max_wait_ms) / 1e3
+                reason = "full"  # why the drain stops, unless a break says otherwise
                 while len(batch) < self.max_batch:
                     hint = self.pending_hint
                     now = time.monotonic()
@@ -158,6 +169,7 @@ class Coalescer:
                         # aboard — waiting longer can only add latency. The
                         # grace window has passed, so a cold burst's
                         # stragglers have had time to register themselves.
+                        reason = "hint"
                         break
                     # with a hint, sleep only until the grace boundary first
                     # — a timeout there re-evaluates the hint, not the batch
@@ -178,8 +190,10 @@ class Coalescer:
                     except queue.Empty:
                         if wait_until < deadline:
                             continue  # grace elapsed; re-check the hint
+                        reason = "deadline"
                         break
                     if nxt is None:
+                        reason = None  # the shutdown wake-up: not a decision
                         break
                     batch.append(nxt)
                 hist = self.wait_histogram
@@ -187,8 +201,14 @@ class Coalescer:
                     now = time.monotonic()
                     for b in batch:
                         hist.observe(now - b.t_enqueue)
+                if self.dispatch_counter is not None:
+                    self.dispatch_counter.labels(
+                        stage=self.stage, rows=str(len(batch))).inc(len(batch))
+                if self.reason_counter is not None and reason is not None:
+                    self.reason_counter.labels(stage=self.stage, reason=reason).inc()
                 try:
-                    results = self.batch_fn([b.value for b in batch])
+                    with tracing.annotate(f"{self.stage}_batch"):
+                        results = self.batch_fn([b.value for b in batch])
                     if len(results) != len(batch):
                         raise RuntimeError(
                             f"batch_fn returned {len(results)} results for "
@@ -241,6 +261,9 @@ class BatchScheduler:
         # needs another executable and leads the next round)
         self.dispatch_counter = None
         self.reason_counter = None
+        # optional obs.tracing.DispatchSink (settable after construction):
+        # where each dispatch's stage seconds and its own span tree go
+        self.dispatch_sink = None
         # size of the batch currently inside engine.generate (0 between
         # dispatches) — the rag_batch_occupancy gauge reads this; plain
         # int assignment, so no lock needed for the scrape-time read
@@ -277,7 +300,9 @@ class BatchScheduler:
         surviving members."""
         if timeout is None and deadline is not None:
             timeout = deadline.wait_timeout()
-        item = _Pending(prompt=list(prompt), max_new=max_new_tokens, seed=seed)
+        tr = tracing.current_trace()
+        item = _Pending(prompt=list(prompt), max_new=max_new_tokens, seed=seed,
+                        trace_id=tr.trace_id if tr is not None else None)
         with self._lifecycle_lock:  # stop-check + enqueue must be atomic
             if self._stop.is_set():
                 raise RuntimeError("scheduler is shut down")
@@ -286,9 +311,10 @@ class BatchScheduler:
             raise TimeoutError("generation timed out")
         if item.error is not None:
             raise item.error
-        if info is not None:
-            info["dispatch_rows"] = item.rows
-            info["queue_wait_ms"] = item.wait_s * 1e3
+        if info is not None and item.record is not None:
+            # dispatch_seq, dispatch_rows, queue_wait_ms, launch_ms, device_ms,
+            # deliver_ms: which dispatch this request rode, and its intervals
+            info.update(item.record.link(item.wait_s))
         return item.result
 
     def shutdown(self):
@@ -331,62 +357,83 @@ class BatchScheduler:
             carry = None
             if first is None:
                 continue
-            batch = [first]
-            cap = self.engine.engine_config.max_batch_size
-            # drain compatible requests within the coalescing window — an
-            # ABSOLUTE deadline (a per-get timeout resets on every arrival:
-            # worst case (cap-1) x window under trickle load)
-            deadline = time.monotonic() + self.max_wait_ms / 1e3
-            reason = "full"  # why the drain stops, unless a break says otherwise
-            while len(batch) < cap:
-                hint = self.pending_hint
-                if hint is not None and len(batch) >= hint():
-                    # every in-flight request is already aboard (solo query:
-                    # immediately) — don't burn the window waiting for nobody
-                    reason = "hint"
-                    break
-                remaining = deadline - time.monotonic()
-                try:
-                    # past the deadline, still drain already-queued items
-                    # (zero wait) — they accumulated while this worker ran
-                    nxt = (
-                        self._queue.get(timeout=remaining)
-                        if remaining > 0 else self._queue.get_nowait()
-                    )
-                except queue.Empty:
-                    reason = "deadline"
-                    break
-                if nxt is None:
-                    reason = None  # the shutdown wake-up: not a decision
-                    break
-                if nxt.max_new == first.max_new and nxt.seed == first.seed:
-                    batch.append(nxt)
-                else:
-                    # different executable: lead the NEXT round (a tail
-                    # re-queue would reorder it behind later arrivals and
-                    # could starve it under sustained mixed load)
-                    carry = nxt
-                    reason = "incompatible"
-                    break
-            rows = len(batch)
-            hist = self.wait_histogram
-            now = time.monotonic()
-            for b in batch:
-                b.rows, b.wait_s = rows, now - b.t_enqueue
-                if hist is not None:
-                    hist.observe(b.wait_s)
-            if self.dispatch_counter is not None:
-                self.dispatch_counter.labels(path="batched", rows=str(rows)).inc(rows)
-            if self.reason_counter is not None and reason is not None:
-                self.reason_counter.labels(reason=reason).inc()
-            self.in_flight = rows
+            # the coalescing window on the profiler's clock, from the instant
+            # a request is in hand: never open while the queue is empty
+            t_first = time.monotonic()
+            with tracing.annotate("gather"):
+                batch, reason, carry = self._gather(first)
+            self._dispatch(batch, reason, t_first)
+        return carry
+
+    def _gather(self, first: _Pending):
+        """Drain compatible requests behind ``first`` within the coalescing
+        window: ``(batch, reason, carry)``, ``reason`` why the drain stopped
+        and ``carry`` the request that leads the next round, if one does."""
+        carry: Optional[_Pending] = None
+        batch = [first]
+        cap = self.engine.engine_config.max_batch_size
+        # an ABSOLUTE deadline (a per-get timeout resets on every arrival:
+        # worst case (cap-1) x window under trickle load)
+        deadline = time.monotonic() + self.max_wait_ms / 1e3
+        reason = "full"  # why the drain stops, unless a break says otherwise
+        while len(batch) < cap:
+            hint = self.pending_hint
+            if hint is not None and len(batch) >= hint():
+                # every in-flight request is already aboard (solo query:
+                # immediately) — don't burn the window waiting for nobody
+                reason = "hint"
+                break
+            remaining = deadline - time.monotonic()
             try:
-                with tracing.span("dispatch", rows=rows):
-                    outs = self.engine.generate(
-                        [b.prompt for b in batch],
-                        max_new_tokens=first.max_new,
-                        seed=first.seed,
-                    )
+                # past the deadline, still drain already-queued items
+                # (zero wait) — they accumulated while this worker ran
+                nxt = (
+                    self._queue.get(timeout=remaining)
+                    if remaining > 0 else self._queue.get_nowait()
+                )
+            except queue.Empty:
+                reason = "deadline"
+                break
+            if nxt is None:
+                reason = None  # the shutdown wake-up: not a decision
+                break
+            if nxt.max_new == first.max_new and nxt.seed == first.seed:
+                batch.append(nxt)
+            else:
+                # different executable: lead the NEXT round (a tail
+                # re-queue would reorder it behind later arrivals and
+                # could starve it under sustained mixed load)
+                carry = nxt
+                reason = "incompatible"
+                break
+        return batch, reason, carry
+
+    def _dispatch(self, batch: List[_Pending], reason: Optional[str], t_first: float) -> None:
+        """One device program for ``batch``, under its own record
+        (``tracing.dispatch_record``): ``gather`` runs from ``t_first`` to the
+        record's entry, and ``deliver`` ends behind the last rider's release."""
+        first, rows = batch[0], len(batch)
+        hist = self.wait_histogram
+        now = time.monotonic()
+        for b in batch:
+            b.rows, b.wait_s = rows, now - b.t_enqueue
+            if hist is not None:
+                hist.observe(b.wait_s)
+        if self.dispatch_counter is not None:
+            self.dispatch_counter.labels(path="batched", rows=str(rows)).inc(rows)
+        if self.reason_counter is not None and reason is not None:
+            self.reason_counter.labels(reason=reason).inc()
+        self.in_flight = rows
+        with tracing.dispatch_record(
+            "batched", rows, reason, time.monotonic() - t_first,
+            riders=[b.trace_id for b in batch if b.trace_id], sink=self.dispatch_sink,
+        ) as rec:
+            try:
+                outs = self.engine.generate(
+                    [b.prompt for b in batch],
+                    max_new_tokens=first.max_new,
+                    seed=first.seed,
+                )
                 for b, out in zip(batch, outs):
                     b.result = out
             except BaseException as e:  # noqa: BLE001 — deliver to all waiters
@@ -394,6 +441,8 @@ class BatchScheduler:
                     b.error = e
             finally:
                 self.in_flight = 0
-                for b in batch:
-                    b.done.set()
-        return carry
+                rec.settle()  # the riders read its launch and device seconds
+                with tracing.span("deliver"):
+                    for b in batch:
+                        b.record = rec
+                        b.done.set()

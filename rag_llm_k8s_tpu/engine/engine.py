@@ -264,14 +264,14 @@ class InferenceEngine:
         """Point this engine's metric handles at ``registry`` — called at
         construction with the process default and again by RagService with
         the service's own registry, so one scrape carries the engine's
-        generate/inter-token histograms (what it builds is counted process-
-        wide: obs/tracing.py ``build_span``)."""
+        inter-token histogram (what it builds is counted process-wide:
+        obs/tracing.py ``build_span``)."""
         self._obs = registry
-        self._m_generate = registry.histogram(
-            "rag_generate_duration_seconds",
-            "one generate call: prefill + decode + output fetch",
-            buckets=obs_metrics.REQUEST_BUCKETS,
-        )
+        # a generate call's own duration (the enqueue to the end of the
+        # fetch) is not observed here: the dispatch that wraps the call
+        # (obs/tracing.py ``dispatch_record``) reads it from the ``launch``
+        # and ``fetch`` spans below and files it by the path that launched it,
+        # ``rag_generate_dispatch_stage_seconds{path, stage="device"}``.
         # the one-shot engine's whole generate is ONE device program, so
         # its per-token figure is an ESTIMATE (call duration / decode
         # steps, prefill share included) — labeled to distinguish it from
@@ -284,7 +284,7 @@ class InferenceEngine:
         ).labels(mode="oneshot_est")
 
     def _observe_generate(self, seconds: float, decode_steps: int) -> None:
-        self._m_generate.observe(seconds)
+        """The per-token estimate of one generate call, and nothing else."""
         self._m_itl.observe(seconds / max(decode_steps, 1))
 
     def _record_oneshot(
@@ -815,48 +815,48 @@ class InferenceEngine:
         with tracing.span("fetch"):
             out = self._split_counters(np.asarray(out_dev))  # the ONE per-query fetch
         call_s = time.perf_counter() - t_call
-        iters = 0
-        if spec:
-            iters = int(out[0, max_new])
-            out = out[:, :max_new]
-        eos = set(self.config.eos_token_ids)
-        row: List[int] = []
-        for t in out[0]:
-            if int(t) in eos:
-                break
-            row.append(int(t))
-        spec_accept = None
-        if spec and iters > 0:
-            emitted = len(row) + (1 if len(row) < max_new else 0) - 1
-            self._spec_record(max(emitted, 0), iters)
-            spec_accept = round(max(emitted, 0) / iters, 4)
-        self._observe_generate(call_s, len(row))
-        with self._lock:
-            self.stats.generate_calls += 1
-            self.stats.decode_tokens += len(row)
-            # prompt length is decided on device; the head + tail are the
-            # host-known share (the service adds the gathered chunk share
-            # post-hoc once the ids fetch lands — record_prefill)
-            self.stats.prefill_tokens += LA + int(b.shape[0])
-        # goodput ledger: the assembled prompt length is decided ON DEVICE
-        # (fetching it would put a round-trip back on the path this mode
-        # exists to remove), so the computed-token figure is the host-known
-        # head + tail plus an n-chunks × max-segment ESTIMATE of the
-        # gathered share, clamped to the bucket — category split and MFU
-        # for this kind are estimates by construction (docs/GOODPUT.md)
-        self._record_oneshot(
-            call_s, bucket=S, batch=1,
-            computed=min(LA + int(b.shape[0]) + n * Lc, S),
-            decode_tokens=len(row), decode_steps=max(len(row), 1),
-            info=info,
-        )
-        if info is not None and spec_accept is not None and self.ledger.enabled:
-            info.setdefault("goodput", {})["spec_accept_len_mean"] = spec_accept
-        if info is not None and spec and iters > 0:
-            # approximation fingerprint (obs/shadow.py): see generate()
-            ap = info.setdefault("approx", [])
-            if "spec_verify" not in ap:
-                ap.append("spec_verify")
+        with tracing.span("deliver"):  # the trim, the stats and the goodput folds
+            iters = 0
+            if spec:
+                iters = int(out[0, max_new])
+                out = out[:, :max_new]
+            eos = set(self.config.eos_token_ids)
+            row: List[int] = []
+            for t in out[0]:
+                if int(t) in eos:
+                    break
+                row.append(int(t))
+            spec_accept = None
+            if spec and iters > 0:
+                emitted = len(row) + (1 if len(row) < max_new else 0) - 1
+                self._spec_record(max(emitted, 0), iters)
+                spec_accept = round(max(emitted, 0) / iters, 4)
+            self._observe_generate(call_s, len(row))
+            with self._lock:
+                self.stats.generate_calls += 1
+                self.stats.decode_tokens += len(row)
+                # prompt length is decided on device; the head + tail are the
+                # host-known share (the service adds the gathered chunk share
+                # post-hoc once the ids fetch lands — record_prefill)
+                self.stats.prefill_tokens += LA + int(b.shape[0])
+            # goodput ledger: the assembled prompt length is decided ON DEVICE
+            # (fetching it would put a round-trip back on the path this mode
+            # exists to remove), so the computed-token figure is the host-known
+            # head + tail plus an n-chunks × max-segment ESTIMATE of the
+            # gathered share, clamped to the bucket — category split and MFU
+            # for this kind are estimates by construction (docs/GOODPUT.md)
+            self._record_oneshot(
+                call_s, bucket=S, batch=1,
+                computed=min(LA + int(b.shape[0]) + n * Lc, S),
+                decode_tokens=len(row), decode_steps=max(len(row), 1), info=info,
+            )
+            if info is not None and spec_accept is not None and self.ledger.enabled:
+                info.setdefault("goodput", {})["spec_accept_len_mean"] = spec_accept
+            if info is not None and spec and iters > 0:
+                # approximation fingerprint (obs/shadow.py): see generate()
+                ap = info.setdefault("approx", [])
+                if "spec_verify" not in ap:
+                    ap.append("spec_verify")
         return row
 
     def _get_rag_compiled(
@@ -1378,23 +1378,24 @@ class InferenceEngine:
         with tracing.span("fetch"):
             out = np.asarray(out_dev)
         call_s = time.perf_counter() - t_call
-        eos = set(self.config.eos_token_ids)
-        row: List[int] = []
-        for t in out[0]:
-            if int(t) in eos:
-                break
-            row.append(int(t))
-        self._observe_generate(call_s, len(row))
-        with self._lock:
-            self.stats.generate_calls += 1
-            self.stats.prefill_tokens += len(suffix_ids)
-            self.stats.prefill_tokens_skipped += int(prefix.reused_tokens)
-            self.stats.decode_tokens += len(row)
-        self._record_oneshot(
-            call_s, bucket=S_suf, batch=1, computed=len(suffix_ids),
-            decode_tokens=len(row), decode_steps=max(len(row), 1),
-            skipped=int(prefix.reused_tokens), info=info,
-        )
+        with tracing.span("deliver"):  # the trim, the stats and the goodput folds
+            eos = set(self.config.eos_token_ids)
+            row: List[int] = []
+            for t in out[0]:
+                if int(t) in eos:
+                    break
+                row.append(int(t))
+            self._observe_generate(call_s, len(row))
+            with self._lock:
+                self.stats.generate_calls += 1
+                self.stats.prefill_tokens += len(suffix_ids)
+                self.stats.prefill_tokens_skipped += int(prefix.reused_tokens)
+                self.stats.decode_tokens += len(row)
+            self._record_oneshot(
+                call_s, bucket=S_suf, batch=1, computed=len(suffix_ids),
+                decode_tokens=len(row), decode_steps=max(len(row), 1),
+                skipped=int(prefix.reused_tokens), info=info,
+            )
         return row
 
     def warm_prefixed(
@@ -1610,52 +1611,52 @@ class InferenceEngine:
             iters = int(out[0, max_new])  # packed in the slack slot
             out = out[:, :max_new]
         call_s = time.perf_counter() - t_call
-
-        results: List[List[int]] = []
-        eos = set(self.config.eos_token_ids)
-        n_decode = 0
-        for i in range(len(prompts)):
-            row = []
-            for t in out[i]:
-                if int(t) in eos:
-                    break
-                row.append(int(t))
-            results.append(row)
-            n_decode += len(row)
-        spec_accept = None
-        if spec and int(iters) > 0:
-            # tokens the VERIFY forwards emitted: answer tokens + the EOS
-            # that ended it (if any) MINUS tok0 (sampled at prefill, not by
-            # a verify); measured acceptance feeds the auto mode and the
-            # /metrics counters
-            emitted = len(results[0]) + (1 if len(results[0]) < max_new else 0) - 1
-            self._spec_record(max(emitted, 0), int(iters))
-            spec_accept = round(max(emitted, 0) / int(iters), 4)
-        self._observe_generate(call_s, max((len(r) for r in results), default=1))
-        with self._lock:
-            self.stats.generate_calls += 1
-            self.stats.prefill_tokens += int(pad_mask.sum())
-            self.stats.decode_tokens += n_decode
-        self._record_oneshot(
-            call_s, bucket=S, batch=B, computed=int(pad_mask.sum()),
-            decode_tokens=n_decode,
-            decode_steps=max((len(r) for r in results), default=1),
-            info=info,
-        )
-        if info is not None and spec_accept is not None and self.ledger.enabled:
-            # one-shot speculation: the device-side matcher folds draft
-            # outcomes into emitted/iters — the per-call acceptance mean
-            # is the only per-request figure it can expose. Gated on the
-            # ledger like every other goodput key: TPU_RAG_GOODPUT=0
-            # means NO goodput block in info, not a partial one
-            info.setdefault("goodput", {})["spec_accept_len_mean"] = spec_accept
-        if info is not None and spec and int(iters) > 0:
-            # approximation fingerprint (obs/shadow.py): speculation ran
-            # for this request — byte-identical by contract, and exactly
-            # what the shadow auditor exists to verify on live traffic
-            ap = info.setdefault("approx", [])
-            if "spec_verify" not in ap:
-                ap.append("spec_verify")
+        with tracing.span("deliver"):  # the trim, the stats and the goodput folds
+            results: List[List[int]] = []
+            eos = set(self.config.eos_token_ids)
+            n_decode = 0
+            for i in range(len(prompts)):
+                row = []
+                for t in out[i]:
+                    if int(t) in eos:
+                        break
+                    row.append(int(t))
+                results.append(row)
+                n_decode += len(row)
+            spec_accept = None
+            if spec and int(iters) > 0:
+                # tokens the VERIFY forwards emitted: answer tokens + the EOS
+                # that ended it (if any) MINUS tok0 (sampled at prefill, not by
+                # a verify); measured acceptance feeds the auto mode and the
+                # /metrics counters
+                emitted = len(results[0]) + (1 if len(results[0]) < max_new else 0) - 1
+                self._spec_record(max(emitted, 0), int(iters))
+                spec_accept = round(max(emitted, 0) / int(iters), 4)
+            self._observe_generate(call_s, max((len(r) for r in results), default=1))
+            with self._lock:
+                self.stats.generate_calls += 1
+                self.stats.prefill_tokens += int(pad_mask.sum())
+                self.stats.decode_tokens += n_decode
+            self._record_oneshot(
+                call_s, bucket=S, batch=B, computed=int(pad_mask.sum()),
+                decode_tokens=n_decode,
+                decode_steps=max((len(r) for r in results), default=1),
+                info=info,
+            )
+            if info is not None and spec_accept is not None and self.ledger.enabled:
+                # one-shot speculation: the device-side matcher folds draft
+                # outcomes into emitted/iters — the per-call acceptance mean
+                # is the only per-request figure it can expose. Gated on the
+                # ledger like every other goodput key: TPU_RAG_GOODPUT=0
+                # means NO goodput block in info, not a partial one
+                info.setdefault("goodput", {})["spec_accept_len_mean"] = spec_accept
+            if info is not None and spec and int(iters) > 0:
+                # approximation fingerprint (obs/shadow.py): speculation ran
+                # for this request — byte-identical by contract, and exactly
+                # what the shadow auditor exists to verify on live traffic
+                ap = info.setdefault("approx", [])
+                if "spec_verify" not in ap:
+                    ap.append("spec_verify")
         return results
 
     def _place_inputs(self, tokens: np.ndarray, pad_mask: np.ndarray, rng: jax.Array):

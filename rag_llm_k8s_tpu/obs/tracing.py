@@ -9,11 +9,25 @@ contract: top-level spans sum to within 5% of ``timings.total_ms``).
 
 Where a stage runs as ONE fused device program (the whole generate loop is
 a single executable — by design, see engine/engine.py), the host cannot
-observe finer structure wall-clock. ``generate`` therefore holds one
-``dispatch`` span per device program launched for the request, with two
-children — ``launch`` (host preparation up to the enqueue) and ``fetch``
-(the blocking device→host read of the output tokens) — and the interior of
-the program is named on the DEVICE's clock instead:
+observe finer structure wall-clock. Every device program launched for
+``/generate`` therefore keeps a record of its own (``dispatch_record``): a
+``dispatch`` span with the children ``launch`` (host preparation up to the
+enqueue), ``fetch`` (the blocking device→host read of the output tokens) and
+``deliver`` (the EOS trim, the stats and goodput folds, and on the batched
+path the release of every rider), behind a ``gather`` span where a scheduler
+coalesced it (the window, from the first request in hand to the last
+aboard). On a request thread (``fused``, ``prefixed``, ``direct``) the
+``dispatch`` sits under the request's ``generate``; on the scheduler's worker
+(``batched``), where no request's trace is current, it is a tree of its own in
+a second ring (``GET /debug/traces?kind=dispatch``) that names its riders'
+trace ids, and each rider's ``generate`` span carries its ``seq``. In every
+run, traced or not, its four stages (``gather``, ``launch``, ``device`` = the
+end of ``launch`` to the end of ``fetch``, ``deliver``) feed
+``rag_generate_dispatch_stage_seconds{path, stage}`` and the response's
+``timings`` (``dispatch_seq``, ``dispatch_rows``, ``queue_wait_ms``,
+``launch_ms``, ``device_ms``, ``deliver_ms``). The retrieve coalescer's worker
+opens ``retrieve_batch`` around its one batched call. The interior of a
+program is named on the DEVICE's clock instead:
 
 - every span body is wrapped in ``jax.profiler.TraceAnnotation``, so an
   xprof capture (``/profile``) shows the named stages on the host timeline
@@ -41,6 +55,7 @@ its own tree inline in the response.
 from __future__ import annotations
 
 import contextvars
+import itertools
 import json
 import logging
 import os
@@ -48,9 +63,9 @@ import threading
 import time
 import uuid
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 logger = logging.getLogger("rag_llm_k8s_tpu.trace")
 
@@ -425,6 +440,128 @@ def span(name: str, **attrs):
             ann.__exit__(None, None, None)
         if tr is not None and idx is not None:
             tr.end(idx)
+
+
+def annotate(name: str):
+    """A bare ``TraceAnnotation``: a name on the profiler's host timeline for
+    an interval that ends before the tree it belongs to begins (the
+    scheduler's ``gather``, recorded afterwards with ``add_span``) or that has
+    no tree (the coalescer worker's ``retrieve_batch``)."""
+    return _TraceAnnotation(name) if _TraceAnnotation is not None else nullcontext()
+
+
+DISPATCH_STAGES = ("gather", "launch", "device", "deliver")
+_dispatch_seq = itertools.count(1)  # process-wide: one number a dispatch
+
+
+class DispatchSink(NamedTuple):
+    """Where a service keeps its dispatches: the family
+    ``rag_generate_dispatch_stage_seconds{path, stage}`` and the ring behind
+    ``GET /debug/traces?kind=dispatch``. ``RagService`` hands one to its
+    ``_dispatch`` and to the ``BatchScheduler`` it was given."""
+
+    stage_seconds: object  # obs.metrics labeled histogram family
+    ring: "TraceBuffer"
+
+
+class DispatchRecord:
+    """One dispatch's clock, as ``dispatch_record`` yields it. ``launch_s``,
+    ``device_s`` and ``fetch_end`` hold once ``settle()`` has read the
+    dispatch span's children (the scheduler calls it before it releases a
+    rider, who reads them); ``deliver_s`` once the dispatch has exited."""
+
+    __slots__ = ("seq", "rows", "gather_s", "launch_s", "device_s", "deliver_s",
+                 "fetch_end", "built", "_trace", "_idx")
+    # the keys of ``link()``: the response's ``timings`` take them as they are,
+    # the request's ``generate`` span without the ``dispatch_`` prefix
+    LINK_KEYS = ("dispatch_seq", "dispatch_rows", "queue_wait_ms", "launch_ms",
+                 "device_ms", "deliver_ms")
+
+    def __init__(self, rows: int, gather_s: float, trace: Trace, idx: int):
+        self.seq = next(_dispatch_seq)
+        self.rows, self.gather_s = int(rows), float(gather_s)
+        self.launch_s = self.device_s = self.deliver_s = 0.0
+        self.fetch_end: Optional[float] = None
+        self.built = False
+        self._trace, self._idx = trace, idx
+
+    def _children(self, name: str) -> List[Span]:
+        return [sp for sp in self._trace.spans[self._idx + 1:]
+                if sp.parent == self._idx and sp.name == name and sp.end_s is not None]
+
+    def settle(self) -> None:
+        """``launch`` = the dispatch's start to the end of its first ``launch``
+        span, ``device`` = from there to the end of its last ``fetch``. An
+        engine call that raised before either ended leaves the time so far
+        under the stage it was in and nothing under the later ones."""
+        if self.fetch_end is not None:
+            return
+        start = self._trace.spans[self._idx].start_s
+        launches, fetches = self._children("launch"), self._children("fetch")
+        launch_end = launches[0].end_s if launches else time.monotonic()
+        self.fetch_end = max(fetches[-1].end_s, launch_end) if fetches else launch_end
+        self.launch_s, self.device_s = launch_end - start, self.fetch_end - launch_end
+
+    def _close(self) -> None:
+        self.settle()
+        self.deliver_s = time.monotonic() - self.fetch_end
+        self.built = any(sp.name.startswith("build/") for sp in self._trace.spans[self._idx + 1:])
+        self._trace.spans[self._idx].attrs.update(seq=float(self.seq), built=float(self.built))
+
+    def stages(self) -> Dict[str, float]:
+        return dict(zip(DISPATCH_STAGES,
+                        (self.gather_s, self.launch_s, self.device_s, self.deliver_s)))
+
+    def link(self, queue_wait_s: float = 0.0) -> Dict[str, float]:
+        """The request's side of the link, for its ``generate`` span and its
+        response's ``timings``: which dispatch it rode, with how many rows,
+        and its own four intervals. ``deliver_ms`` runs to the instant of this
+        call on the caller's thread (a rider woken first waits less than the
+        dispatch's ``deliver`` stage, one woken last as long)."""
+        return dict(zip(self.LINK_KEYS, (
+            float(self.seq), float(self.rows), queue_wait_s * 1e3, self.launch_s * 1e3,
+            self.device_s * 1e3, (time.monotonic() - self.fetch_end) * 1e3)))
+
+
+@contextmanager
+def dispatch_record(path: str, rows: int, reason: Optional[str] = None,
+                    gather_s: float = 0.0, riders: Sequence[str] = (),
+                    sink: Optional[DispatchSink] = None):
+    """Around one engine call that launches a device program for
+    ``/generate``: the ``dispatch`` span, on the thread's current trace where
+    there is one; where there is none (the scheduler's worker) on a trace of
+    its own, begun ``gather_s`` ago with the ``gather`` span that ended before
+    it, carrying ``seq``, ``path``, ``rows``, ``reason``, ``riders`` (the trace
+    ids of the requests aboard) and ``built`` (1 where a ``build/<program>``
+    span fell inside: a cold shape met in serving), and finished into
+    ``sink.ring``. On exit, raised or not, one sample a stage of
+    ``DISPATCH_STAGES`` goes to ``sink.stage_seconds{path, stage}``: the four
+    sum to the wall time from ``gather_s`` before the entry to the exit.
+    Yields the ``DispatchRecord``."""
+    tr = _current.get()
+    own = tr is None
+    if own:
+        tr = start_trace()
+        tr.t0 -= gather_s
+        tr.started_at -= gather_s
+        if gather_s > 0.0:
+            tr.add_span("gather", tr.t0, gather_s)
+    rec = DispatchRecord(rows, gather_s, tr, len(tr.spans))  # the span opened next
+    try:
+        with span("dispatch", rows=rows):
+            try:
+                yield rec
+            finally:
+                rec._close()
+    finally:
+        if sink is not None:
+            for stage, seconds in rec.stages().items():
+                sink.stage_seconds.labels(path=path, stage=stage).observe(seconds)
+        if own:
+            tr.attrs.update(
+                kind="dispatch", seq=rec.seq, path=path, rows=int(rows), reason=reason,
+                riders=list(riders), built=int(rec.built))
+            finish_trace(tr, sink.ring if sink is not None else None)
 
 
 class TraceBuffer:

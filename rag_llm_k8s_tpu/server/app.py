@@ -155,6 +155,9 @@ class RagService:
         # facade keeps the seed's service.metrics API working unchanged
         self.metrics = obs_metrics.MetricsRegistry()
         self.traces = tracing.TraceBuffer(128)
+        # every dispatch launched where no request's trace is current (the
+        # scheduler's worker): its own tree, GET /debug/traces?kind=dispatch
+        self.dispatches = tracing.TraceBuffer(128)
         self.started_at = time.monotonic()
         # warmup()'s own span tree, kept here (the ring of 128 would evict
         # it) and served by GET /debug/traces under "boot"
@@ -318,6 +321,8 @@ class RagService:
                 self._m_coalesce_wait.labels(stage="embed"),
             )
             self.retrieve_coalescer.join_timeout_counter = self._m_join_timeouts
+            self.retrieve_coalescer.dispatch_counter = self._m_coalesce_rows
+            self.retrieve_coalescer.reason_counter = self._m_coalesce_reason
         else:
             self.retrieve_coalescer = None
         if scheduler is not None:
@@ -464,6 +469,28 @@ class RagService:
             "rag_generate_dispatch_reason_total",
             "why the batch scheduler's drain loop stopped "
             "(full|hint|deadline|incompatible)",
+        )
+        # the same two for the stage where a split round is born: the
+        # retrieve coalescer's batches by size, and why each drain stopped
+        self._m_coalesce_rows = reg.labeled_counter(
+            "rag_coalesce_dispatch_rows_total",
+            "items dispatched by a coalescing stage (stage label), by rows of the batch",
+        )
+        self._m_coalesce_reason = reg.labeled_counter(
+            "rag_coalesce_dispatch_reason_total",
+            "why a coalescing stage's drain loop stopped (full|hint|deadline)",
+        )
+        # each dispatch's own clock, one sample a stage a dispatch: gather
+        # (the scheduler's window; 0 on the batch-1 paths), launch, device
+        # (the end of launch to the end of fetch), deliver. The four sum to
+        # the dispatch's wall time (obs/tracing.py dispatch_record).
+        self._dispatch_sink = tracing.DispatchSink(
+            reg.labeled_histogram(
+                "rag_generate_dispatch_stage_seconds",
+                "seconds of each dispatch by path (fused|prefixed|batched|direct) "
+                "and stage (gather|launch|device|deliver)",
+            ),
+            self.dispatches,
         )
         # which attention kernel each compiled program was built with
         # (obs/tracing.count_kernel_build, where LlamaModel._attend chooses
@@ -1003,6 +1030,7 @@ class RagService:
         if self.scheduler is not None and hasattr(self.scheduler, "dispatch_counter"):
             self.scheduler.dispatch_counter = self._m_dispatch_rows
             self.scheduler.reason_counter = self._m_dispatch_reason
+            self.scheduler.dispatch_sink = self._dispatch_sink
         # the decision layer: SLO specs evaluated over sliding windows of
         # the histograms/counters registered above; exports rag_slo_* gauges
         # into the same registry and backs GET /slo (obs/slo.py)
@@ -1613,7 +1641,9 @@ class RagService:
         timings block (the same numbers the response carries) — called
         EXACTLY ONCE per answered request, which is what keeps stage
         counts equal to request counts. The assemble/detokenize stages
-        have no public timings key (the response contract is pinned), so
+        have no public timings key (the response's keys only ever grow:
+        ``chip_ms`` and ``goodput_frac`` joined them with the goodput ledger,
+        the six of ``DispatchRecord.LINK_KEYS`` with the dispatch's record), so
         their span sites record private ``_*_s`` entries that are popped
         and observed here: a fallback path that re-runs a stage just
         overwrites the entry, never double-counts it."""
@@ -2309,13 +2339,12 @@ class RagService:
                                 "generate", deadline.budget_ms
                             ) from None
                         raise
-                    # the dispatch itself is the scheduler worker's span; the
-                    # request's own tree says how many rows it rode with and
-                    # how long it queued (BatchScheduler fills both)
-                    gen_attrs = {}
-                    if "dispatch_rows" in gen_info:
-                        gen_attrs = {"rows": gen_info.pop("dispatch_rows"),
-                                     "queue_wait_ms": gen_info.pop("queue_wait_ms")}
+                    # the dispatch itself is the scheduler worker's tree; the
+                    # request's own says which one it rode, with how many
+                    # rows, how long it queued and its share of the
+                    # dispatch's stages (BatchScheduler fills all six)
+                    link = {k: gen_info.pop(k) for k in tracing.DispatchRecord.LINK_KEYS
+                            if k in gen_info}
                 else:
                     # prompts beyond the scheduler's capability need chunked
                     # prefill, which fixed-length continuous slots cannot do —
@@ -2327,13 +2356,12 @@ class RagService:
                     with self._inflight_lock:
                         self._inflight_generate -= 1
                     in_generate = False
-                    gen_attrs = {"rows": 1, "queue_wait_ms": 0.0}
-                    with self._dispatch("direct"):
+                    with self._dispatch("direct") as rec:
                         out_ids = self.engine.generate(
                             [prompt_ids], info=gen_info
                         )[0]
-                if gen_span is not None:
-                    gen_span.attrs.update(gen_attrs)
+                    link = rec.link()
+                self._link_dispatch(gen_span, timings, link)
             if in_generate:
                 with self._inflight_lock:
                     self._inflight_generate -= 1
@@ -2390,10 +2418,23 @@ class RagService:
         ``BatchScheduler``): its one row under ``path`` in
         ``rag_generate_dispatch_rows_total``, counted at the launch whether
         or not the program then succeeds (as the scheduler counts its
-        batches), and the ``dispatch`` span."""
+        batches), and the dispatch's record (``tracing.dispatch_record``: the
+        ``dispatch`` span under the request's ``generate``, its stage
+        seconds), which it yields."""
         self._m_dispatch_rows.labels(path=path, rows="1").inc()
-        with tracing.span("dispatch", rows=1):
-            yield
+        with tracing.dispatch_record(path, 1, sink=self._dispatch_sink) as rec:
+            yield rec
+
+    @staticmethod
+    def _link_dispatch(gen_span, timings: Dict[str, float], link: Dict[str, float]) -> None:
+        """Put a dispatch's ``link()`` (obs/tracing.py ``DispatchRecord``, or
+        what ``BatchScheduler.submit`` returned of it) into the response's
+        ``timings`` and, without the ``dispatch_`` prefix, onto the request's
+        ``generate`` span."""
+        timings.update(link)
+        if gen_span is not None:
+            gen_span.attrs.update(
+                {k.removeprefix("dispatch_"): float(v) for k, v in link.items()})
 
     def _prefix_enabled(self) -> bool:
         """KV prefix cache applicability (engine/prefix_cache.py)."""
@@ -2474,14 +2515,15 @@ class RagService:
         timings["prefix_resolve_ms"] = (time.monotonic() - t_r) * 1e3
         t0 = time.monotonic()
         gen_info: Dict[str, float] = {}
-        with tracing.span("generate", rows=1, queue_wait_ms=0.0):
+        with tracing.span("generate") as gen_span:
             try:
-                with self._dispatch("prefixed"):
+                with self._dispatch("prefixed") as rec:
                     out_ids = self.engine.generate_prefixed(
                         b_ids, cp, info=gen_info
                     )
             except ValueError:
                 return None  # tail over the suffix ladder: cold path serves
+            self._link_dispatch(gen_span, timings, rec.link())
         t_de = time.monotonic()
         with tracing.span("detokenize"):
             completion = self.llm_tokenizer.decode(out_ids)
@@ -2581,12 +2623,13 @@ class RagService:
         th.start()
         t0 = time.monotonic()
         gen_info: Dict[str, float] = {}
-        with tracing.span("generate", rows=1, queue_wait_ms=0.0), \
-                self._dispatch("fused"):
-            out_ids = self.engine.generate_rag(
-                a_ids, b_ids, packed_dev, toks_dev, lens_dev, n_chunks=n_ctx,
-                info=gen_info,
-            )
+        with tracing.span("generate") as gen_span:
+            with self._dispatch("fused") as rec:
+                out_ids = self.engine.generate_rag(
+                    a_ids, b_ids, packed_dev, toks_dev, lens_dev, n_chunks=n_ctx,
+                    info=gen_info,
+                )
+            self._link_dispatch(gen_span, timings, rec.link())
         t_de = time.monotonic()
         with tracing.span("detokenize"):
             completion = self.llm_tokenizer.decode(out_ids)
@@ -3304,12 +3347,17 @@ class WsgiApp:
 
     def ep_debug_traces(self, request):
         """Recent request span trees from the in-memory ring buffer, and
-        under ``boot`` the tree of ``warmup()`` (None until it has returned).
+        under ``boot`` the tree of ``warmup()`` (None until it has returned);
+        with ``?kind=dispatch`` the ring of the dispatches launched where no
+        request's trace was current (the batch scheduler's), each tree with
+        its ``seq``, ``path``, ``rows``, ``reason`` and its riders' trace ids.
         Same 403-unless-armed contract as every ``/debug`` route."""
         if not self._debug_enabled():
             return self._debug_forbidden()
         try:
             limit = request.args.get("limit", type=int)
+            if request.args.get("kind") == "dispatch":
+                return self._jsonify({"traces": self.service.dispatches.list(limit)})
             return self._jsonify({"traces": self.service.traces.list(limit),
                                   "boot": self.service.boot_trace})
         except Exception as e:  # noqa: BLE001

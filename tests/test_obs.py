@@ -536,10 +536,13 @@ class TestTracedGenerate:
         body = r.get_json()
         # trace is additive: the timings contract is untouched (chip_ms /
         # goodput_frac are the goodput ledger's per-request attribution —
-        # ISSUE 14; cost_usd joins them only when a chip-hour price is set)
+        # ISSUE 14; cost_usd joins them only when a chip-hour price is set;
+        # the last six link the request to the dispatch it rode — ISSUE 51)
         assert set(body["timings"]) == {
             "tokenize_ms", "embed_retrieve_ms", "generate_ms", "total_ms",
             "chip_ms", "goodput_frac",
+            "dispatch_seq", "dispatch_rows", "queue_wait_ms",
+            "launch_ms", "device_ms", "deliver_ms",
         }
         tree = body["trace"]
         names = [s["name"] for s in tree["spans"]]
@@ -627,8 +630,11 @@ class TestOneShotEngineInstrumentation:
     def test_generate_feeds_histograms(self, served):
         svc, _ = served
         reg = svc.metrics
-        gen = reg.histogram("rag_generate_duration_seconds")
-        assert gen.count >= 1  # the fixture's query went through generate
+        stages = reg.labeled_histogram("rag_generate_dispatch_stage_seconds")
+        device = [child for labels, child in stages.items() if dict(labels)["stage"] == "device"]
+        # the fixture's query went through generate: its call's duration is
+        # the dispatch's ``device`` stage, by the path that launched it
+        assert sum(child.count for child in device) >= 1
         itl = reg.labeled_histogram("rag_decode_inter_token_seconds")
         assert itl.labels(mode="oneshot_est").count >= 1
         svc._sync_kernel_builds()  # what a scrape does: the census' children

@@ -708,14 +708,14 @@ class TestDispatchThroughTheService:
         # a solo request takes the fused single-fetch path: one row, its own dispatch
         moved = {k: v - before.get(k, 0) for k, v in after_one.items() if v != before.get(k, 0)}
         assert moved == {("fused", "1"): 1.0}
-        # (d) generate -> dispatch -> {launch, fetch}, rows set, and the
-        # top-level spans still sum to the request's total
+        # (d) generate -> dispatch -> {launch, fetch, deliver}, rows set, and
+        # the top-level spans still sum to the request's total
         tree = body["trace"]
         gen = _find(tree["spans"], "generate")[0]
         assert gen["attrs"]["rows"] == 1.0 and gen["attrs"]["queue_wait_ms"] == 0.0
         dispatch = _find(gen["spans"], "dispatch")[0]
         assert dispatch["attrs"]["rows"] == 1.0
-        assert [s["name"] for s in dispatch["spans"]] == ["launch", "fetch"]
+        assert [s["name"] for s in dispatch["spans"]] == ["launch", "fetch", "deliver"]
         stage_sum = sum(s["duration_ms"] for s in tree["spans"])
         assert stage_sum == pytest.approx(body["timings"]["total_ms"], rel=0.05)
 
@@ -749,3 +749,254 @@ class TestDispatchThroughTheService:
             with svc._dispatch("direct"):
                 raise RuntimeError("device lost")
         assert _rows(svc.metrics)[("direct", "1")] == before + 1.0
+
+
+# ---------------------------------------------------------------------------
+# (e) a dispatch keeps its own clock (ISSUE 51): stage seconds in every run, a
+# tree of its own on the scheduler's worker, the request's side of the link,
+# and the retrieve coalescer's two counters
+# ---------------------------------------------------------------------------
+
+STAGE_FAMILY = "rag_generate_dispatch_stage_seconds"
+
+
+def _stages(reg, path):
+    """{stage: (count, sum)} of one path's children of the stage family."""
+    fam = reg.get_family(STAGE_FAMILY)
+    return {dict(k)["stage"]: (c.count, c.sum) for k, c in (fam.items() if fam else [])
+            if dict(k)["path"] == path}
+
+
+def _stage_delta(before, after):
+    return {s: (after[s][0] - before.get(s, (0, 0.0))[0], after[s][1] - before.get(s, (0, 0.0))[1])
+            for s in after}
+
+
+def _toy_engine_call(fail_in=None):
+    """What an engine's generate opens inside a dispatch, a few ms each."""
+    for name, seconds in (("launch", 0.004), ("fetch", 0.006), ("deliver", 0.002)):
+        with tracing.span(name):
+            time.sleep(seconds)
+            if name == fail_in:
+                raise RuntimeError("device lost")
+
+
+class ClockedStub(StubEngine):
+    def __init__(self, cap, fail_in=None):
+        super().__init__(cap)
+        self.fail_in = fail_in
+
+    def generate(self, prompts, max_new_tokens=None, seed=None):
+        _toy_engine_call(self.fail_in)
+        return super().generate(prompts, max_new_tokens, seed)
+
+
+def _sink():
+    reg = obs_metrics.MetricsRegistry()
+    return reg, tracing.DispatchSink(reg.labeled_histogram(STAGE_FAMILY), tracing.TraceBuffer(8))
+
+
+def _names(node):
+    return [s["name"] for s in node.get("spans", [])]
+
+
+class TestADispatchKeepsItsOwnClock:
+    @pytest.mark.parametrize("path", ["fused", "prefixed", "direct", "batched"])
+    def test_one_sample_a_stage_and_the_four_sum_to_the_wall(self, path):
+        reg, sink = _sink()
+        if path == "batched":
+            sched = BatchScheduler(ClockedStub(cap=4), max_wait_ms=30.0)
+            sched.dispatch_sink = sink
+            try:
+                info = _submit_all(sched, [([1], {})])[0]
+            finally:
+                sched.shutdown()
+            (tree,) = sink.ring.list()
+            wall_ms = tree["total_ms"]
+            assert _names(tree) == ["gather", "dispatch"]
+            dispatch = tree["spans"][1]
+            assert tree["attrs"]["reason"] == "deadline" and tree["attrs"]["rows"] == 1
+        else:
+            # on a request's thread the dispatch sits in the request's tree
+            tr = tracing.start_trace()
+            with tracing.dispatch_record(path, 1, sink=sink) as rec:
+                _toy_engine_call()
+            info = rec.link()
+            (dispatch,) = tracing.finish_trace(tr)["spans"]
+            wall_ms = dispatch["duration_ms"]
+            assert len(sink.ring) == 0
+        # launch, fetch and the engine's deliver; the scheduler adds its own
+        # deliver (the riders' release) under the same name
+        assert _names(dispatch)[:3] == ["launch", "fetch", "deliver"]
+        assert set(_names(dispatch)) == {"launch", "fetch", "deliver"}
+        got = _stages(reg, path)
+        assert set(got) == set(tracing.DISPATCH_STAGES)
+        assert {s: n for s, (n, _) in got.items()} == dict.fromkeys(tracing.DISPATCH_STAGES, 1)
+        assert sum(sec for _, sec in got.values()) * 1e3 == pytest.approx(wall_ms, abs=2.0)
+        assert got["launch"][1] >= 0.004 and got["device"][1] >= 0.006 and got["deliver"][1] >= 0.002
+        if path == "batched":
+            assert 0.030 <= got["gather"][1] < 0.5  # the window ran out with one aboard
+        else:
+            assert got["gather"][1] == 0.0 and info["queue_wait_ms"] == 0.0
+        # the request's side: the same dispatch, and its own four intervals
+        assert info["dispatch_seq"] == dispatch["attrs"]["seq"] and info["dispatch_rows"] == 1
+        assert info["launch_ms"] == pytest.approx(got["launch"][1] * 1e3, abs=0.01)
+        assert info["device_ms"] == pytest.approx(got["device"][1] * 1e3, abs=0.01)
+        assert info["deliver_ms"] >= 2.0
+
+    @pytest.mark.parametrize("fail_in", ["launch", "fetch"])
+    def test_a_dispatch_whose_engine_call_raises_is_still_recorded(self, fail_in):
+        reg, sink = _sink()
+        sched = BatchScheduler(ClockedStub(cap=4, fail_in=fail_in), max_wait_ms=1.0)
+        sched.dispatch_sink = sink
+        try:
+            with pytest.raises(RuntimeError, match="device lost"):
+                sched.submit([1])
+        finally:
+            sched.shutdown()
+        got = _stages(reg, "batched")
+        assert {s: n for s, (n, _) in got.items()} == dict.fromkeys(tracing.DISPATCH_STAGES, 1)
+        (tree,) = sink.ring.list()
+        assert sum(sec for _, sec in got.values()) * 1e3 == pytest.approx(tree["total_ms"], abs=2.0)
+        # what the call did not reach holds nothing: the riders' release is all of ``deliver``
+        assert got["launch"][1] >= 0.004 and got["deliver"][1] < 0.05, got
+        assert (got["device"][1] == 0.0) if fail_in == "launch" else (got["device"][1] >= 0.006), got
+
+    def test_seq_is_process_wide_and_built_marks_a_cold_shape(self):
+        _, sink = _sink()
+        seqs = []
+        for build in (False, True):
+            with tracing.dispatch_record("direct", 1, sink=sink) as rec:
+                if build:
+                    with tracing.span("build/generate"):
+                        pass
+                _toy_engine_call()
+            seqs.append(rec.seq)
+        cold_free, cold = sink.ring.list()
+        assert seqs[1] == seqs[0] + 1 == cold["attrs"]["seq"]
+        assert (cold_free["attrs"]["built"], cold["attrs"]["built"]) == (0, 1)
+        assert cold["attrs"]["kind"] == "dispatch" and cold["attrs"]["path"] == "direct"
+
+    def test_gather_is_never_opened_on_an_empty_queue(self, monkeypatch):
+        opened = []
+        real = tracing.annotate
+        monkeypatch.setattr(tracing, "annotate", lambda name: (opened.append(name), real(name))[1])
+        sched = BatchScheduler(StubEngine(cap=4), max_wait_ms=1.0)
+        try:
+            time.sleep(0.05)  # the worker waits on an empty queue
+            assert opened == []
+            sched.submit([1])
+            sched.submit([1])
+            time.sleep(0.05)
+        finally:
+            sched.shutdown()
+        assert opened == ["gather", "gather"]
+
+    def test_a_batch_through_the_service(self, served, monkeypatch):
+        """Three callers, one batch: its tree is in the dispatch ring with its
+        riders' trace ids, and each rider's ``generate`` span and ``timings``
+        name the same dispatch."""
+        svc, client = served
+        monkeypatch.setenv("TPU_RAG_FAULTS", "")  # arms the /debug routes
+        # past the admission race (one caller retrieved alone takes the fused
+        # path): the retrieve stage asks its hint only once all are in flight
+        monkeypatch.setattr(svc.retrieve_coalescer, "hint_grace_ms", 300.0)
+        monkeypatch.setattr(svc.retrieve_coalescer, "max_wait_ms", 2000.0)
+        before = _stages(svc.metrics, "batched")
+        rows_before = _coalesce(svc.metrics, "rows")
+        results = []
+        threads = [threading.Thread(target=lambda i=i: results.append(
+            client.post("/generate", json={"prompt": f"question {i}?", "trace": True})))
+            for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60.0)
+        bodies = [x.get_json() for x in results]
+        assert [x.status_code for x in results] == [200] * 3, bodies
+        tree = client.get("/debug/traces?kind=dispatch&limit=1").get_json()["traces"][-1]
+        attrs = tree["attrs"]
+        assert attrs["path"] == "batched" and attrs["rows"] == 3 and attrs["reason"] == "hint"
+        assert sorted(attrs["riders"]) == sorted(b["trace"]["trace_id"] for b in bodies)
+        assert _names(tree) == ["gather", "dispatch"]
+        assert _names(tree["spans"][1]) == ["launch", "fetch", "deliver", "deliver"]
+        for body in bodies:
+            gen = _find(body["trace"]["spans"], "generate")[0]["attrs"]
+            t = body["timings"]
+            assert gen["seq"] == attrs["seq"] == t["dispatch_seq"]
+            assert gen["rows"] == 3.0 == t["dispatch_rows"]
+            for key in ("queue_wait_ms", "launch_ms", "device_ms", "deliver_ms"):
+                assert gen[key] == pytest.approx(t[key], abs=0.01)
+            # the four are the request's own time inside ``generate``
+            parts = t["queue_wait_ms"] + t["launch_ms"] + t["device_ms"] + t["deliver_ms"]
+            assert parts <= t["generate_ms"] + 1.0
+        # one sample a stage for the one dispatch, and they sum to its wall
+        moved = _stage_delta(before, _stages(svc.metrics, "batched"))
+        assert {s: n for s, (n, _) in moved.items()} == dict.fromkeys(tracing.DISPATCH_STAGES, 1)
+        assert sum(sec for _, sec in moved.values()) * 1e3 == pytest.approx(tree["total_ms"], abs=2.0)
+        # and the round's retrievals were one batch of three
+        rows = _coalesce(svc.metrics, "rows")
+        assert {k: v - rows_before.get(k, 0.0) for k, v in rows.items() if v != rows_before.get(k, 0.0)} \
+            == {("retrieve", "3"): 3.0}
+        # the request ring is still the default view
+        assert "boot" in client.get("/debug/traces").get_json()
+
+
+def _coalesce(reg, what):
+    fam = reg.get_family(f"rag_coalesce_dispatch_{what}_total")
+    label = "rows" if what == "rows" else "reason"
+    return {(dict(k)["stage"], dict(k)[label]): c.value for k, c in (fam.items() if fam else [])}
+
+
+def _coalescer(max_batch, max_wait_ms, hint=None, hold=None):
+    from rag_llm_k8s_tpu.engine.batching import Coalescer
+
+    def batch_fn(items):
+        if hold is not None:
+            hold.wait(5.0)
+        return list(items)
+
+    reg = obs_metrics.MetricsRegistry()
+    co = Coalescer(batch_fn, max_batch=max_batch, max_wait_ms=max_wait_ms, pending_hint=hint,
+                   hint_grace_ms=1.0)
+    co.dispatch_counter = reg.labeled_counter("rag_coalesce_dispatch_rows_total")
+    co.reason_counter = reg.labeled_counter("rag_coalesce_dispatch_reason_total")
+    return reg, co
+
+
+def _coalesce_all(co, n):
+    threads = [threading.Thread(target=co.submit, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+        time.sleep(0.01)
+    for t in threads:
+        t.join(10.0)
+
+
+class TestTheRetrieveStageCountsItsBatches:
+    @pytest.mark.parametrize("reason,kw,n", [
+        ("full", dict(max_batch=2, max_wait_ms=2000.0), 2),
+        ("hint", dict(max_batch=4, max_wait_ms=2000.0, hint=lambda: 3), 3),
+        ("deadline", dict(max_batch=4, max_wait_ms=20.0), 1),
+    ])
+    def test_rows_and_each_stop_reason(self, reason, kw, n):
+        reg, co = _coalescer(**kw)
+        try:
+            _coalesce_all(co, n)
+        finally:
+            co.shutdown()
+        assert _coalesce(reg, "rows") == {("retrieve", str(n)): float(n)}
+        assert _coalesce(reg, "reason") == {("retrieve", reason): 1.0}
+
+    def test_the_shutdown_wake_up_is_not_a_decision_and_the_batch_has_a_span(self, monkeypatch):
+        opened = []
+        real = tracing.annotate
+        monkeypatch.setattr(tracing, "annotate", lambda name: (opened.append(name), real(name))[1])
+        reg, co = _coalescer(max_batch=4, max_wait_ms=5000.0)
+        t = threading.Thread(target=co.submit, args=(1,))
+        t.start()
+        time.sleep(0.05)
+        co.shutdown()
+        t.join(10.0)
+        assert _coalesce(reg, "reason") == {}
+        assert opened == ["retrieve_batch"]

@@ -133,10 +133,13 @@ class TestRoutes:
             assert "Document 'doc" in body["context"]
             assert "score:" in body["context"]
             # chip_ms / goodput_frac: the goodput ledger's per-request
-            # attribution (ISSUE 14, additive; cost_usd only when priced)
+            # attribution (ISSUE 14, additive; cost_usd only when priced);
+            # the last six: the dispatch the request rode (ISSUE 51, additive)
             assert set(body["timings"]) == {
                 "tokenize_ms", "embed_retrieve_ms", "generate_ms",
                 "total_ms", "chip_ms", "goodput_frac",
+                "dispatch_seq", "dispatch_rows", "queue_wait_ms",
+                "launch_ms", "device_ms", "deliver_ms",
             }
 
     def test_healthz_and_metrics(self, client):
