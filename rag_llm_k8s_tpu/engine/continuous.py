@@ -55,6 +55,7 @@ from rag_llm_k8s_tpu.core.mesh import MeshContext, serving_device_kind
 from rag_llm_k8s_tpu.engine.engine import (
     EngineStats,
     _isin,
+    build_identity,
     maybe_fuse_params,
     maybe_quantize_params,
     param_avals,
@@ -381,6 +382,9 @@ class ContinuousEngine:
             # paged variants: same static switches + the block-table arg
             self.model_step_paged = self.model.copy(row_frontier=True, paged=True)
             self.model_chunked_paged = self.model.copy(chunked=True, paged=True)
+        self._build_identity = build_identity(
+            "continuous", families.of(config), config, engine_config, dtypes, sampling, mesh,
+            pad_id, fused=fused, quantized=quantized)
         self._compiled: Dict[Tuple[str, int, int], jax.stages.Compiled] = {}
         # ---- persistent device state -----------------------------------
         # the cache rides as a TUPLE pytree through every executable:
@@ -648,7 +652,8 @@ class ContinuousEngine:
                 "verify_paged": lambda: self._build_verify_paged(S),  # S: the draft count K
                 "mixed_step": lambda: self._build_mixed_step(S),  # S: the chunk width
             }[kind]
-            fn = tracing.build_span("continuous", key, build, rows=n, bucket=S)
+            fn = tracing.build_span("continuous", key, build, identity=self._build_identity,
+                                    rows=n, bucket=S)
             self._compiled[key] = fn
         return fn
 

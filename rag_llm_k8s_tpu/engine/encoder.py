@@ -59,6 +59,9 @@ class EncoderRunner:
                 {"params": params}, tokens, mask
             )
         )
+        # what the traced forward closes over (``tracing.build_span`` keys a
+        # build on it); the server's fused embed+kNN program holds the same model
+        self.build_identity = ("encoder", config, dtypes, attn_impl, jax.default_backend())
         self._compiled = {}  # (batch, length bucket) -> executable
 
     def _get(self, B: int, S: int):
@@ -67,7 +70,7 @@ class EncoderRunner:
             i32 = jax.ShapeDtypeStruct((B, S), jnp.int32)
             fn = self._compiled[(B, S)] = tracing.build_span(
                 "encode", (B, S), lambda: (self._jit, (param_avals(self.params), i32, i32)),
-                rows=B, bucket=S)
+                identity=self.build_identity, rows=B, bucket=S)
         return fn
 
     def prepare_batch(self, ids: Sequence[int]):

@@ -97,6 +97,20 @@ def param_avals(params):
     )
 
 
+def build_identity(engine: str, family, config, engine_config: EngineConfig, dtypes: DTypePolicy,
+                   sampling: SamplingConfig, mesh: Optional[MeshContext], pad_id: int, **flags):
+    """What an engine's traced programs close over, for ``tracing.build_span``
+    to key a build on without tracing it (``core/compile_cache.py``): every
+    array they use is an argument, so these and the abstract arguments are
+    the whole of an executable's inputs. Shared by both engines."""
+    return (
+        engine, family.name, config, engine_config, dtypes, sampling, int(pad_id),
+        # what ``attn_impl="auto"`` resolves by, where the models are traced
+        jax.default_backend(), sorted(flags.items()),
+        None if mesh is None else dict(mesh.mesh.shape),
+    )
+
+
 def maybe_fuse_params(params, engine_config: EngineConfig, mesh):
     """Fuse q/k/v and gate/up projection weights once at engine construction
     when the config allows it and tp == 1 (the fused concat layout cannot be
@@ -229,6 +243,9 @@ class InferenceEngine:
         # bucket prefill through the cache chunk by chunk (offset-causal
         # chunk_prefill_attention) instead of being silently truncated
         self.model_chunked = self.model.copy(chunked=True)
+        self._build_identity = build_identity(
+            "one-shot", self.family, config, engine_config, dtypes, sampling, mesh, pad_id,
+            fused=fused, quantized=quantized)
         self._compiled: Dict[Tuple[int, int, int, Optional[int]], jax.stages.Compiled] = {}
         # mesh-replicated chunk-token sidecar copies (see _placed_sidecar)
         self._sidecar_placed: Dict[Tuple[int, int], tuple] = {}
@@ -1445,7 +1462,8 @@ class InferenceEngine:
                     fn = self._compiled.get(key)
                 if fn is None:
                     fn = tracing.build_span(
-                        program, key, build, rows=key[0], bucket=key[1], max_new=key[2])
+                        program, key, build, identity=self._build_identity,
+                        rows=key[0], bucket=key[1], max_new=key[2])
                     with self._lock:
                         self._compiled[key] = fn
         return fn

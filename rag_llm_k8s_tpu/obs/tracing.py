@@ -142,9 +142,16 @@ _kernel_builds: Dict[Tuple[str, str], int] = {}
 _kernel_builds_lock = threading.Lock()
 
 
-def count_kernel_build(mode: str, kernel: str) -> None:
+def count_kernel_build(mode: str, kernel: str, n: int = 1) -> None:
+    """Called where a program is traced. Inside a ``build_span`` the increment
+    is also noted on the build: an executable kept in the store carries the
+    increments its trace made, and a later boot that loads it replays them
+    (``n``), so the counter reads the same warm as cold."""
     with _kernel_builds_lock:
-        _kernel_builds[(mode, kernel)] = _kernel_builds.get((mode, kernel), 0) + 1
+        _kernel_builds[(mode, kernel)] = _kernel_builds.get((mode, kernel), 0) + n
+    traced = getattr(_building, "kernels", None)
+    if traced is not None:
+        traced[(mode, kernel)] = traced.get((mode, kernel), 0) + n
 
 
 def kernel_builds() -> Dict[Tuple[str, str], int]:
@@ -210,38 +217,64 @@ def _count_build(program: str, seconds: Dict[str, float], cache=False) -> None:
             _compile_events[key] = _compile_events.get(key, 0) + 1
 
 
-def build_span(program: str, key, make, **ints):
+def build_span(program: str, key, make, identity=None, **ints):
     """Build one executable where every site that builds one does: ``make()``
     gives ``(jitted, avals)``, and ``jitted.trace(*avals).lower().compile()``
     runs under a span ``build/<program>`` with each stage on the host's
     monotonic clock. The span (on the current trace if there is one, and a
     ``TraceAnnotation`` either way, so a build inside a capture sits on its
     host timeline) carries ``trace_s``, ``lower_s``, ``compile_s``, ``other_s``
-    (its wall time less the three: ``make()``), ``cache_hit``
-    (1 | 0 | -1 where the persistent cache said neither) and ``ints`` (the
-    key's ``rows``, ``bucket``, ``max_new``); the census counts the same.
-    ``program`` is a name of ``BUILD_PROGRAMS``; any other raises."""
+    (its wall time less the three: ``make()``, the store's key and write),
+    ``cache_hit`` (2 where the executable store held it | 1 | 0 | -1 where the
+    persistent cache said neither) and ``ints`` (the key's ``rows``, ``bucket``,
+    ``max_new``); the census counts the same. ``program`` is a name of
+    ``BUILD_PROGRAMS``; any other raises.
+
+    ``identity`` is what the site's traced function closes over (its
+    configuration objects, by ``repr``). With it, and where a compile cache
+    directory is placed, the build is keyed WITHOUT tracing
+    (``core/compile_cache.py entry_for``): an entry found is loaded, nothing
+    is traced, lowered or compiled (``trace_s = lower_s = 0``, the read under
+    ``compile_s``, outcome ``stored``) and the ``count_kernel_build``
+    increments its original trace made are replayed; an entry not found is
+    built as ever and kept. Without it the build is as it always was."""
     if program not in BUILD_PROGRAMS or program == "undeclared":
         raise ValueError(f"build program {program!r} is not in the vocabulary {BUILD_PROGRAMS}")
-    outer = getattr(_building, "stage", None)
+    from rag_llm_k8s_tpu.core import compile_cache  # imports jax, which this module may lack
+
+    outer = getattr(_building, "stage", None), getattr(_building, "kernels", None)
     with span(f"build/{program}", **ints) as sp:
-        t = [time.monotonic()]
+        t0 = time.monotonic()
         try:
             staged, avals = make()
-            for stage in ("trace", "lower", "compile"):
-                t.append(time.monotonic())
-                _building.stage, _building.cache = stage, None
-                staged = staged.trace(*avals) if stage == "trace" else getattr(staged, stage)()
-            cache = _building.cache
+            entry = compile_cache.entry_for(program, key, avals, identity)
+            _building.stage, _building.cache, _building.kernels = "compile", None, None
+            t = time.monotonic()
+            held = entry.load() if entry is not None else None
+            if held is not None:  # the read is the whole build
+                staged, cache = held[0], "stored"
+                for mode, kernel, n in held[1]:
+                    count_kernel_build(mode, kernel, n)
+                secs = {"trace": 0.0, "lower": 0.0, "compile": time.monotonic() - t}
+            else:
+                _building.kernels, secs = {}, {}
+                for stage in ("trace", "lower", "compile"):
+                    t = time.monotonic()
+                    _building.stage, _building.cache = stage, None
+                    lowered = staged  # after the loop: what ``compile`` was called on
+                    staged = staged.trace(*avals) if stage == "trace" else getattr(staged, stage)()
+                    secs[stage] = time.monotonic() - t
+                cache = _building.cache
+                if entry is not None:
+                    entry.save(staged, compile_cache.lowered_text_sha256(lowered),
+                               [(m, k, n) for (m, k), n in _building.kernels.items()])
         finally:
-            _building.stage = outer
-        t.append(time.monotonic())
-        secs = {"trace": t[2] - t[1], "lower": t[3] - t[2], "compile": t[4] - t[3]}
-        secs["other"] = t[4] - t[0] - sum(secs.values())
+            _building.stage, _building.kernels = outer
+        secs["other"] = time.monotonic() - t0 - sum(secs.values())
         _count_build(program, secs, cache)
         if sp is not None:
             sp.attrs.update({f"{k}_s": v for k, v in secs.items()})
-            sp.attrs["cache_hit"] = {"hit": 1.0, "miss": 0.0}.get(cache, -1.0)
+            sp.attrs["cache_hit"] = {"stored": 2.0, "hit": 1.0, "miss": 0.0}.get(cache, -1.0)
     logger.debug("build %s %r: %s cache=%s", program, key, secs, cache)
     return staged
 

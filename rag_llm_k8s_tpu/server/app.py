@@ -506,13 +506,14 @@ class RagService:
         self._m_compile_events = reg.labeled_counter(
             "rag_compile_events_total",
             "executables built, by program (obs/tracing.BUILD_PROGRAMS) and "
-            "what the persistent cache said (hit|miss|off: neither)",
+            "where they came from (stored: loaded from the executable store, "
+            "nothing traced; else what the persistent cache said: hit|miss|off: neither)",
         )
         self._m_compile_seconds = reg.labeled_counter(
             "rag_compile_seconds_total",
             "seconds building executables, by program and stage "
-            "(trace|lower|compile|other; compile is the backend's compile "
-            "or the persistent cache's read)",
+            "(trace|lower|compile|other; compile is the backend's compile, "
+            "the persistent cache's read or the executable store's)",
         )
         self._m_ingest_stage = reg.labeled_histogram(
             "rag_ingest_stage_seconds",
@@ -1863,7 +1864,7 @@ class RagService:
                 "retrieve", key,
                 lambda: (jax.jit(fused), (param_avals(self.encoder.params), i32, i32,
                                           *param_avals((emb, norms)))),
-                rows=B_pad, bucket=S)
+                identity=("retrieve", self.encoder.build_identity, k_eff), rows=B_pad, bucket=S)
         return fn
 
     def _retrieve_many(self, texts: List[str], allow_device: bool = False):

@@ -45,6 +45,7 @@ NOT_IN_TREE = {
     "model.safetensors.index.json": "a checkpoint's index",
     # written at run time and gitignored
     ".jax_cache/": "the compile cache, built at run time",
+    "rag_executables/": "the executable store inside the compile cache directory, built at run time",
     ".bench_state/": "a benchmark run's state, built at run time",
     "chiprun_out/": "what the chip tool brings back",
     "tpu_rag_trace/": "a profiler capture's directory",

@@ -80,9 +80,13 @@ def engine_for(params, cfg=CFG, **kw):
 
 
 def greedy_reference(params, cfg, prompt, n=NEW):
+    """The reference's greedy continuation. Sequences are padded on the right
+    to a multiple of 16 (causal: a pad changes nothing in front of it), so the
+    eager operations compile for a few lengths and not for every one."""
     tokens = list(prompt)
     for _ in range(n):
-        tokens.append(int(np.argmax(ref.forward(params, cfg, tokens)[-1])))
+        padded = tokens + [0] * (-len(tokens) % 16)
+        tokens.append(int(np.argmax(ref.forward(params, cfg, padded)[len(tokens) - 1])))
     return tokens[len(prompt):]
 
 

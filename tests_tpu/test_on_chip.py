@@ -607,3 +607,110 @@ class TestDeltaRuleKernelOnChip:
         print(f"delta_rule_chunked on the chip: |o| error {err_o:.3g} (of {float(jnp.abs(want_o).max()):.3g}), "
               f"state error {err_s:.3g} (of {float(jnp.abs(want_s).max()):.3g})")
         assert err_o <= 2e-5 and err_s <= 2e-5
+
+
+class TestExecutableStoreOnChip:
+    def test_a_second_boot_loads_every_declared_executable(self, tmp_path):
+        """Boot a tiny fused-RAG service twice on one compile cache directory
+        (fresh engine, encoder and service: nothing of the first boot in
+        memory). The second boot traces nothing (every declared build reads
+        ``stored``: Mosaic kernels, donated caches and all come back from
+        ``core/compile_cache.py``'s store), answers token-equal, counts the same
+        attention kernels, and one program lowered again hashes to what its
+        manifest recorded."""
+        import os
+
+        from jax._src import compilation_cache
+
+        from rag_llm_k8s_tpu.core import compile_cache
+        from rag_llm_k8s_tpu.core.config import AppConfig, EncoderConfig
+        from rag_llm_k8s_tpu.engine.batching import BatchScheduler
+        from rag_llm_k8s_tpu.engine.encoder import EncoderRunner
+        from rag_llm_k8s_tpu.engine.engine import InferenceEngine
+        from rag_llm_k8s_tpu.index.store import VectorStore
+        from rag_llm_k8s_tpu.models.bge_m3 import init_encoder_params
+        from rag_llm_k8s_tpu.models.llama import init_llama_params
+        from rag_llm_k8s_tpu.obs import tracing
+        from rag_llm_k8s_tpu.server.app import RagService, create_app
+
+        class ByteTokenizer:
+            def encode(self, text):
+                return [b + 3 for b in text.encode("utf-8")]
+
+            def decode(self, ids, skip_special_tokens=True):
+                return bytes((i - 3) % 256 for i in ids if i >= 3).decode("utf-8", "replace")
+
+        dtypes = DTypePolicy()
+        # heads of 128 on both models: the widths the kernels serve (Mosaic
+        # refuses the decode walk at a head of 64; compiled for a described
+        # v5e before the first chip run)
+        llama_cfg = LlamaConfig.tiny(vocab_size=512)
+        llama_cfg = type(llama_cfg)(**{
+            **llama_cfg.__dict__, "num_heads": 8, "num_kv_heads": 2, "head_dim": 128,
+            "hidden_size": 1024, "intermediate_size": 2048, "max_seq_len": 1024})
+        enc_cfg = EncoderConfig(
+            vocab_size=300, hidden_size=512, intermediate_size=1024, num_layers=2, num_heads=4,
+            max_position_embeddings=128, embed_dim=512, max_encode_len=64)
+        llama_params = init_llama_params(jax.random.PRNGKey(0), llama_cfg, dtypes)
+        enc_params = init_encoder_params(jax.random.PRNGKey(1), enc_cfg, dtypes)
+        texts = ["alpha beta gamma", "delta epsilon", "zeta eta theta"]
+
+        def gained(before, now):
+            return {k: n - before.get(k, 0) for k, n in now.items() if n != before.get(k, 0)}
+
+        def boot():
+            engine = InferenceEngine(
+                llama_cfg, llama_params,
+                sampling=SamplingConfig(do_sample=False, max_new_tokens=8),
+                engine_config=EngineConfig(prompt_buckets=(128, 256), max_batch_size=4,
+                                           max_seq_len=512, rag_fused=True),
+                dtypes=dtypes)
+            encoder = EncoderRunner(enc_cfg, enc_params, dtypes=dtypes, length_buckets=(32,),
+                                    max_batch=4)
+            store = VectorStore(dim=enc_cfg.hidden_size)
+            svc = RagService(
+                AppConfig(model=llama_cfg, encoder=enc_cfg, system_message="SYS"), engine,
+                ByteTokenizer(), encoder, ByteTokenizer(), store,
+                scheduler=BatchScheduler(engine, max_wait_ms=25.0))
+            events, kernels = tracing.compile_census()[1], tracing.kernel_builds()
+            vecs = encoder.encode([ByteTokenizer().encode(t) for t in texts])
+            store.add(list(vecs), [{"filename": "f", "chunk_id": i, "text": t}
+                                   for i, t in enumerate(texts)])
+            svc.warmup()
+            try:
+                client = create_app(svc).test_client()
+                answers = []
+                for prompt in ("what is alpha?", "and zeta?"):
+                    r = client.post("/generate", json={"prompt": prompt})
+                    assert r.status_code == 200, r.get_json()
+                    answers.append(r.get_json()["generated_text"])
+            finally:
+                svc.shutdown()
+            declared = {k: n for k, n in gained(events, tracing.compile_census()[1]).items()
+                        if k[0] != "undeclared"}
+            return engine, answers, declared, gained(kernels, tracing.kernel_builds())
+
+        was = jax.config.jax_compilation_cache_dir
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        compilation_cache.reset_cache()
+        try:
+            _, cold_answers, cold, cold_kernels = boot()
+            assert cold and not [k for k in cold if k[1] == "stored"]
+            store_dir = compile_cache.store_dir()
+            names = [n for n in os.listdir(store_dir) if n.endswith(".rexe")]
+            assert len(names) == sum(cold.values())  # every declared executable was kept
+            engine, warm_answers, warm, warm_kernels = boot()
+            assert {k[1] for k in warm} == {"stored"}, warm
+            assert sum(warm.values()) == sum(cold.values())
+            assert warm_answers == cold_answers and warm_kernels == cold_kernels
+            assert [n for n in os.listdir(store_dir) if n.endswith(".rexe")] == names
+            # the key still covers its program: lower one again, by hand
+            key = next(k for k in engine._compiled if k[3] is None)
+            jitted, avals = engine._build_generate(*key)
+            entry = compile_cache.entry_for("generate", key, avals, engine._build_identity)
+            manifest = compile_cache.read_manifest(entry.path)
+            assert manifest["lowered_sha256"] == compile_cache.lowered_text_sha256(
+                jitted.trace(*avals).lower())
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+            compilation_cache.reset_cache()
