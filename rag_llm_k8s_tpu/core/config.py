@@ -803,6 +803,131 @@ class HybridSSMConfig:
 
 
 @dataclass(frozen=True)
+class CrossDecoderConfig:
+    """The decoder-hybrid-decoder family (``models/cross_decoder.py``): a
+    SELF-decoder of state-space and attention layers that builds the caches,
+    and a CROSS-decoder that keeps no state of its own and reads the
+    self-decoder's. Every layer is ``x += mixer(LN(x))``, ``x +=
+    SwiGLU(LN(x))`` with a LayerNorm that has a mean and a bias, and no layer
+    has a position term. The mixer of layer ``i`` of ``L`` follows from the
+    depth as the published code derives it (``kind_of``; ``mb_per_layer`` 2:
+    every second layer a state-space slot): below ``L / 2`` even layers are
+    Mamba-1 (plain: no norm on the time step, ``B`` or ``C``) and odd layers
+    DIFFERENTIAL attention over a window of ``sliding_window`` keys; layer ``L
+    / 2`` is a Mamba layer whose scan output in front of its gate is the
+    MEMORY; layer ``L / 2 + 1`` is full differential attention whose keys and
+    values are the one plane that grows with the context; above them even
+    layers are gated memory units (``W_out (silu(W_in h) * memory)``, the
+    memory at the same position) and odd layers cross-attention: queries of
+    their own against layer ``L / 2 + 1``'s keys and values. Differential
+    attention pairs heads up: two softmaxes of 64-wide query / key heads over
+    the same 128-wide value pair, the second subtracted at a learned ``lambda``,
+    a 128-wide RMS norm behind. Field names are the published ``config.json``'s;
+    the state-space sizes are the family's published defaults (the file does
+    not state them).
+
+    Defaults are the published widths and depth of the 3.8B model."""
+
+    vocab_size: int = 200064
+    hidden_size: int = 2560
+    intermediate_size: int = 10240
+    num_hidden_layers: int = 32
+    num_attention_heads: int = 40
+    num_key_value_heads: int = 20
+    mb_per_layer: int = 2
+    sliding_window: int = 512
+    layer_norm_eps: float = 1e-5
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 160
+    max_seq_len: int = 262144
+    tie_word_embeddings: bool = True
+    bos_token_id: int = 1
+    eos_token_ids: Tuple[int, ...] = (2,)
+
+    def __post_init__(self):
+        if self.mb_per_layer != 2:
+            raise ValueError("mb_per_layer: this family runs a state-space slot every second layer (2) only")
+        if self.num_hidden_layers % 4 or self.num_hidden_layers < 8:
+            raise ValueError("num_hidden_layers is a whole number of fours, at least 8: half the depth is "
+                             "(state, attention) pairs, the other half (memory unit, cross-attention) pairs")
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size is a whole number of heads")
+        if self.num_attention_heads % 2 or self.num_key_value_heads % 2:
+            raise ValueError("differential attention pairs heads up: both head counts are even")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads is a whole number of groups of num_key_value_heads")
+        if self.mamba_d_conv < 2:
+            raise ValueError("mamba_d_conv: the convolution keeps at least one earlier input")
+
+    # the names the rest of the program reads a decoder's sizes by
+    num_layers = property(lambda self: self.num_hidden_layers)
+    num_heads = property(lambda self: self.num_attention_heads)
+    num_kv_heads = property(lambda self: self.num_key_value_heads)
+    head_dim = property(lambda self: self.hidden_size // self.num_attention_heads)
+    d_inner = property(lambda self: self.mamba_expand * self.hidden_size)
+    # the cache's view of differential attention: a pair of key heads is one
+    # head of twice the width, a pair of value heads too
+    num_pair_heads = property(lambda self: self.num_key_value_heads // 2)
+    pair_dim = property(lambda self: 2 * (self.hidden_size // self.num_attention_heads))
+    # layers by kind: (state, window) pairs, then the memory's state layer
+    # and the full layer, then (memory unit, cross-attention) pairs
+    num_window_layers = property(lambda self: self.num_hidden_layers // 4)
+    num_state_layers = property(lambda self: self.num_hidden_layers // 4 + 1)
+    num_plane_layers = property(lambda self: self.num_hidden_layers // 4 + 1)  # layers that own a K/V plane
+    num_cross_layers = property(lambda self: self.num_hidden_layers // 4 - 1)
+    memory_layer = property(lambda self: self.num_hidden_layers // 2)
+    shared_layer = property(lambda self: self.num_hidden_layers // 2 + 1)
+
+    def kind_of(self, layer: int) -> str:
+        """``mamba`` | ``window`` | ``full`` | ``gmu`` | ``cross``."""
+        half = self.num_hidden_layers // 2
+        if layer % 2 == 0:
+            return "mamba" if layer <= half else "gmu"
+        return "window" if layer < half else "full" if layer == half + 1 else "cross"
+
+    def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
+        """(FLOPs a token, weight bytes, KV bytes a position of context), as
+        ``LlamaConfig.roofline_terms`` (bf16 only, neither argument read). A
+        token's matmuls: every layer's SwiGLU, a state layer's four
+        projections, a self-attention layer's four, a cross layer's two, a
+        memory unit's two, the head. ``kv_bytes_per_token`` is what one more
+        position of context costs a decode step to READ: the full layer's
+        plane, once by its own layer and once by every cross layer (the window
+        layers read ``sliding_window`` slots whatever the context, and the
+        states do not grow: both ride ``weight_bytes``, read and, the states,
+        written once a step). ONE plane grows by a position of context."""
+        D, F, Di = self.hidden_size, self.intermediate_size, self.d_inner
+        N, R = self.mamba_d_state, self.mamba_dt_rank
+        plane = 2 * self.num_pair_heads * self.pair_dim  # a position's keys and values of one layer
+        ffn = 3 * D * F
+        state = 2 * D * Di + Di * (R + 2 * N) + R * Di + Di * D
+        attention = 2 * D * D + D * plane
+        cross, gmu = 2 * D * D, 2 * D * Di
+        M, Na, Nc = self.num_state_layers, self.num_plane_layers, self.num_cross_layers
+        params = self.num_layers * ffn + M * state + Na * attention + Nc * (cross + gmu) + D * self.vocab_size
+        state_bytes = M * 2 * (4 * N * Di + 2 * (self.mamba_d_conv - 1) * Di)
+        window_bytes = self.num_window_layers * 2 * plane * self.sliding_window
+        return 2.0 * params, 2.0 * params + state_bytes + window_bytes, 2.0 * plane * (1 + Nc)
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 256, **overrides) -> "CrossDecoderConfig":
+        """Miniature config for CPU tests: 12 layers (three (state, window)
+        pairs, the memory's layer 6 and the full layer 7, two (memory unit,
+        cross) pairs), 4 query heads over 2 KV heads of 16 (two query pairs
+        over one key pair), window 8, d_inner 128, state 16, dt rank 4."""
+        base = dict(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=128, num_hidden_layers=12,
+            num_attention_heads=4, num_key_value_heads=2, sliding_window=8,
+            mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=4, max_seq_len=512,
+            bos_token_id=1, eos_token_ids=(2,),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
 class ConvMoEConfig:
     """The gated short-convolution, sparse-expert decoder family
     (``models/conv_moe.py``). Every layer is ``h = x + Op(RMS(x))``, ``y = h +

@@ -31,8 +31,8 @@ from typing import Callable, Dict, Mapping, Optional, Tuple
 import jax.numpy as jnp
 
 from rag_llm_k8s_tpu.core.config import (
-    BlockWindowConfig, ConvMoEConfig, DeltaMoEConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig,
-    WindowedMoEConfig,
+    BlockWindowConfig, ConvMoEConfig, CrossDecoderConfig, DeltaMoEConfig, HybridSSMConfig, LatentMoEConfig,
+    LlamaConfig, WindowedMoEConfig,
 )
 from rag_llm_k8s_tpu.parallel.sharding import llama_param_specs, replicated_param_specs
 
@@ -247,8 +247,29 @@ def _delta_moe() -> Family:
         commit=dm.commit)
 
 
+def _cross_decoder() -> Family:
+    from rag_llm_k8s_tpu.models import cross_decoder as cd
+
+    return replicated_row(
+        "decoder-hybrid-decoder", CrossDecoderConfig, cd.CrossDecoderModel, cd.make_cross_cache,
+        refuses={
+            "continuous": "a recurrent state has no blocks to page, and preemption, resume and a per-row "
+                          "frontier need snapshots of it that nothing takes yet; use 'coalesce'",
+            "prefix_cache": "a recurrent state can be reused only for an exact prefix, and only if a snapshot "
+                            "was kept at its end: a spliced segment's keys and values say nothing of it",
+            "kv_quant": "the state is float32 and the attention layers' planes have no int8 form here",
+            "weight_quant": "quantize_llama_params does not know this tree (leaves stacked by layer kind, "
+                            "float32 A_log, D, time-step bias and subtraction weights)",
+            "mesh": "this tree has no partition rules (the heads split by pair, the scan by channel, and "
+                    "a scan over a sequence split across chips hands its state from chip to chip)",
+        },
+        counters_width=cd.N_COUNTERS, counter_names=cd.COUNTER_NAMES, fold_counters=cd.fold_counters,
+        commit=cd.commit)
+
+
 # configuration type -> its family (a thunk where building it imports the model)
 _TABLE: Tuple[Tuple[type, Callable[[], Family]], ...] = (
+    (CrossDecoderConfig, _cross_decoder),
     (DeltaMoEConfig, _delta_moe),
     (ConvMoEConfig, _conv_moe),
     (HybridSSMConfig, _hybrid_ssm),

@@ -106,11 +106,17 @@ SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
 # convolution of q, k and v), ``attn/kda/gate`` (the decay's, beta's and the
 # output gate's projections) and ``attn/kda/delta`` (the recurrence: the chunk
 # form, the single-token step, a verify step's chunk and ``commit``'s
-# replay); its full layers open ``attn/latent`` and its FFNs ``mlp/*``. A
+# replay); its full layers open ``attn/latent`` and its FFNs ``mlp/*``. The
+# decoder-hybrid-decoder family (models/cross_decoder.py) opens ``attn/scan``,
+# ``attn/conv``, ``attn/window`` and ``attn/global`` as above, ``attn/cross``
+# around a cross-attention layer's attention over the shared plane,
+# ``attn/gmu`` around a gated memory unit, ``attn/diff`` around differential
+# attention's subtraction, lambda and norm, and ``cross`` ABOVE the sub-scopes
+# around its cross-decoder half (the layers that own no state). A
 # reader that files an operation under the first sub-scope it knows keeps
 # reading ``attn`` and ``mlp``; one that knows these sees the finer split.
 FINE_SCOPES = ("latent", "router", "experts", "shared", "zero", "dense", "window", "global", "gate",
-               "ring", "pool", "scan", "conv", "kda", "delta")
+               "ring", "pool", "scan", "conv", "kda", "delta", "cross", "gmu", "diff")
 SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES + FINE_SCOPES)
 
 

@@ -5,7 +5,11 @@ float32 whatever the inputs' type:
 
     dt_t = softplus(delta_t + dt_bias)            (0 at a pad position)
     s_t  = exp(dt_t * A) * s_{t-1} + (dt_t * u_t) * B_t
-    y_t  = (s_t . C_t + D * u_t) * silu(z_t)
+    m_t  = s_t . C_t + D * u_t
+    y_t  = m_t * silu(z_t)
+
+(``ungated``, static: ``m`` is returned beside ``y``, for a family whose
+later layers read a scan's output from in front of its gate.)
 
 ``A [N, d_inner]`` is negative, ``B_t`` and ``C_t [N]`` are shared by a row's
 channels, and ``s_{-1}`` is the state handed in. Every array here has the
@@ -56,12 +60,14 @@ def _softplus(x):
     return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
 
 
-def selective_scan_xla(u, delta, z, A, B, C, D, dt_bias, h0, start, *, keep_steps: bool = False):
+def selective_scan_xla(u, delta, z, A, B, C, D, dt_bias, h0, start, *, keep_steps: bool = False,
+                       ungated: bool = False):
     """The rule above by a ``lax.scan`` over time. ``u``, ``delta``, ``z``
     ``[R, S, Di]``; ``A [N, Di]``; ``B``, ``C`` ``[R, S, N]``; ``D``,
     ``dt_bias`` ``[Di]``; ``h0 [R, N, Di]``; ``start [R]``. Returns ``y [R, S,
-    Di]`` in ``u``'s type, the last state ``[R, N, Di]`` float32 and, under
-    ``keep_steps``, the state after every position ``[R, S, N, Di]``."""
+    Di]`` in ``u``'s type, the last state ``[R, N, Di]`` float32, under
+    ``keep_steps`` the state after every position ``[R, S, N, Di]`` and, last,
+    under ``ungated`` ``m [R, S, Di]`` in ``u``'s type."""
     f32 = jnp.float32
     S = u.shape[1]
     live = jnp.arange(S, dtype=jnp.int32)[None, :] >= start[:, None]  # [R, S]
@@ -78,13 +84,15 @@ def selective_scan_xla(u, delta, z, A, B, C, D, dt_bias, h0, start, *, keep_step
     last, out = jax.lax.scan(step, h0.astype(f32), over_time)
     y, steps = out if keep_steps else (out, None)
     zf = z.astype(f32)
-    y = (y.swapaxes(0, 1) + D.astype(f32) * uf) * (zf * jax.nn.sigmoid(zf))
-    y = y.astype(u.dtype)
-    return (y, last, steps.swapaxes(0, 1)) if keep_steps else (y, last)
+    m = y.swapaxes(0, 1) + D.astype(f32) * uf
+    y = (m * (zf * jax.nn.sigmoid(zf))).astype(u.dtype)
+    out = (y, last, steps.swapaxes(0, 1)) if keep_steps else (y, last)
+    return out + (m.astype(u.dtype),) if ungated else out
 
 
 def _scan_kernel(start_ref, b_ref, c_ref, u_ref, dt_ref, z_ref, a_ref, d_ref, bias_ref, h0_ref,
-                 y_ref, hout_ref, h_scr, *, N: int, Tc: int, unroll: int):
+                 y_ref, hout_ref, *rest, N: int, Tc: int, unroll: int):
+    m_ref, h_scr = rest if len(rest) == 2 else (None, rest[0])  # ``ungated``: a third output in front of the scratch
     r, k = pl.program_id(0), pl.program_id(2)
     f32 = jnp.float32
 
@@ -97,6 +105,8 @@ def _scan_kernel(start_ref, b_ref, c_ref, u_ref, dt_ref, z_ref, a_ref, d_ref, bi
     @pl.when(first >= Tc)
     def _():  # a chunk of pads: the state passes it; what it writes is read by nobody, but is finite
         y_ref[...] = jnp.zeros_like(y_ref)
+        if m_ref is not None:
+            m_ref[...] = jnp.zeros_like(m_ref)
 
     @pl.when(first < Tc)
     def _():
@@ -112,6 +122,8 @@ def _scan_kernel(start_ref, b_ref, c_ref, u_ref, dt_ref, z_ref, a_ref, d_ref, bi
                 h = jnp.exp(dt * a_ref[n]) * hs[n] + dtu * b_ref[t * N + n]
                 y = y + h * c_ref[t * N + n]
                 new.append(h)
+            if m_ref is not None:
+                m_ref[0, t] = y.astype(m_ref.dtype)
             zt = z_ref[0, t].astype(f32)
             y_ref[0, t] = (y * zt * (1.0 / (1.0 + jnp.exp(-zt)))).astype(y_ref.dtype)
             return tuple(new)
@@ -139,8 +151,9 @@ def time_chunk(S: int) -> Optional[int]:
     return None
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def selective_scan_pallas(u, delta, z, A, B, C, D, dt_bias, h0, start, *, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("interpret", "ungated"))
+def selective_scan_pallas(u, delta, z, A, B, C, D, dt_bias, h0, start, *, interpret: bool = False,
+                          ungated: bool = False):
     """The rule above by the kernel; shapes as ``selective_scan_xla``. ``S``
     is a whole number of ``time_chunk(S)``; ``d_inner`` is padded to a whole
     number of 1024-channel tiles here (a padded channel has ``A = 0``, ``u =
@@ -162,7 +175,7 @@ def selective_scan_pallas(u, delta, z, A, B, C, D, dt_bias, h0, start, *, interp
     per_channel = pl.BlockSpec((SUBLANES, LANES), lambda r, j, k, *_: (j, 0))
     state = pl.BlockSpec((1, N, SUBLANES, LANES), lambda r, j, k, *_: (r, 0, j, 0))
     scalars = pl.BlockSpec((Tc * N,), lambda r, j, k, *_: (r * nk + k,), memory_space=pltpu.SMEM)
-    y, h_last = pl.pallas_call(
+    y, h_last, *m = pl.pallas_call(
         functools.partial(_scan_kernel, N=N, Tc=Tc, unroll=UNROLL),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -170,19 +183,20 @@ def selective_scan_pallas(u, delta, z, A, B, C, D, dt_bias, h0, start, *, interp
             in_specs=[scalars, scalars, seq, seq, seq,
                       pl.BlockSpec((N, SUBLANES, LANES), lambda r, j, k, *_: (0, j, 0)),
                       per_channel, per_channel, state],
-            out_specs=[seq, state],
+            out_specs=[seq, state] + [seq] * ungated,
             scratch_shapes=[pltpu.VMEM((N, SUBLANES, LANES), f32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((R, S, rows, LANES), u.dtype),
-                   jax.ShapeDtypeStruct((R, N, rows, LANES), f32)],
+                   jax.ShapeDtypeStruct((R, N, rows, LANES), f32)]
+        + [jax.ShapeDtypeStruct((R, S, rows, LANES), u.dtype)] * ungated,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=KERNEL,
     )(start.astype(jnp.int32), B.astype(f32).reshape(-1), C.astype(f32).reshape(-1),
       tiled(u), tiled(delta), tiled(z), tiled(A.astype(f32)), tiled(D.astype(f32)), tiled(dt_bias.astype(f32)),
       tiled(h0.astype(f32)))
-    y = y.reshape(R, S, Di + pad)[..., :Di]
-    return y, h_last.reshape(R, N, Di + pad)[..., :Di]
+    y, *m = (a.reshape(R, S, Di + pad)[..., :Di] for a in [y] + m)
+    return (y, h_last.reshape(R, N, Di + pad)[..., :Di], *m)
 
 
 def scan_form(S: int, impl: str) -> str:
@@ -191,12 +205,12 @@ def scan_form(S: int, impl: str) -> str:
     return KERNEL if impl != "xla" and S >= 128 and time_chunk(S) is not None else "selective_scan_xla"
 
 
-def selective_scan(u, delta, z, A, B, C, D, dt_bias, h0, start, *, impl: str):
-    """``(y, last state)`` by the form ``scan_form`` names."""
+def selective_scan(u, delta, z, A, B, C, D, dt_bias, h0, start, *, impl: str, ungated: bool = False):
+    """``(y, last state)``, and ``m`` under ``ungated``, by the form ``scan_form`` names."""
     if scan_form(u.shape[1], impl) == KERNEL:
         return selective_scan_pallas(u, delta, z, A, B, C, D, dt_bias, h0, start,
-                                     interpret=impl == "pallas_interpret")
-    return selective_scan_xla(u, delta, z, A, B, C, D, dt_bias, h0, start)
+                                     interpret=impl == "pallas_interpret", ungated=ungated)
+    return selective_scan_xla(u, delta, z, A, B, C, D, dt_bias, h0, start, ungated=ungated)
 
 
 def causal_conv(x, history, weight, bias, activate: bool = True):

@@ -313,7 +313,9 @@ def test_the_benchmark_lists_the_metric_for_the_four_batched_cells():
     FULL layer's share (the sliding layers' is a metric of its own); by PR 40
     for the hybrid state-space family's, whose two attention layers walk; by
     PR 45 for the gated-convolution family's, whose heads of 64 the walk
-    refuses: its steps take the chunk form and the share reads 100."""
+    refuses: its steps take the chunk form and the share reads 100; by PR 53
+    for the decoder-hybrid-decoder family's: the full layer's own walk of the
+    plane seven cross layers also read."""
     import json
 
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
@@ -324,7 +326,7 @@ def test_the_benchmark_lists_the_metric_for_the_four_batched_cells():
     assert entry["workloads"] == ["mistral-7b-int8.closed8", "mistral-nemo-tp4.closed4",
                                   "dots-vlm1-ep16.closed8", "longcat-flash-ep32.closed8",
                                   "laguna-s-ep16.closed8", "jamba2-3b.closed8", "lfm2-24b-a2b-pp4.solo",
-                                  "kimi-linear-ep16.solo"]
+                                  "kimi-linear-ep16.solo", "phi4-mini-flash.solo-12chunk"]
 
 
 # ---------------------------------------------------------------------------

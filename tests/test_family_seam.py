@@ -16,7 +16,7 @@ import pytest
 
 import longcat_flash_reference
 from rag_llm_k8s_tpu.core.config import (
-    BlockWindowConfig, ConvMoEConfig, EngineConfig, GoodputConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig,
+    BlockWindowConfig, ConvMoEConfig, CrossDecoderConfig, EngineConfig, GoodputConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig,
     MeshConfig, PrefixCacheConfig, WindowedMoEConfig,
 )
 from rag_llm_k8s_tpu.core.mesh import make_mesh
@@ -66,6 +66,11 @@ ROOFLINES = {
     "jamba2-3b": (HybridSSMConfig, {}, (6052249600.0, 6070886400.0, 1024.0)),
     "lfm2-24b-a2b-stage": (lambda: ConvMoEConfig(tie_word_embeddings=False), {},
                            (1474297856.0, 1474428928.0, 4096.0)),
+    # PR 53's family, by its own arithmetic: ONE plane grows with the context and eight layers read it (a
+    # position's 2 x 10 x 128 values, twice 8), the window layers' 512 slots and the states ride the weights' bytes
+    "cross_decoder-tiny": (lambda: CrossDecoderConfig.tiny(vocab_size=48), {}, (1030144.0, 1104896.0, 384.0)),
+    "phi4-mini-flash": (lambda: CrossDecoderConfig(tie_word_embeddings=False), {},
+                        (7702118400.0, 7729541120.0, 40960.0)),
 }
 
 
