@@ -72,10 +72,14 @@ from rag_llm_k8s_tpu.ops.attention import (
 # time: positions written that end a chunk and that end a window (a verify
 # step counts what it writes, kept or not). And, a single-shot prefill call at
 # a time: positions its rows' layers ran over (whole blocks of ``LIVE_BLOCK``)
-# and rows x the bucket (models/llama.py's names).
+# and rows x the bucket (models/llama.py's names); and, through the kernel, the
+# softmax steps ONE head's live query blocks took and those blocks, summed over
+# the rows and the layers (``ops/block_window.py window_summary_steps``: the
+# rule the kernel's own bounds follow).
 COUNTER_NAMES = ("decode_ring_slots_fetched", "decode_summary_slots_fetched",
                  "decode_slots_attended_positions", "chunks_closed", "windows_closed",
-                 "prefill_tokens_computed", "prefill_tokens_bucketed")
+                 "prefill_tokens_computed", "prefill_tokens_bucketed",
+                 "prefill_window_softmax_steps", "prefill_window_query_blocks")
 N_COUNTERS = len(COUNTER_NAMES)
 DECODE_KERNEL = "ring_summary_decode_attention"  # what a trace calls the decode walk here
 # Positions a trip of a prompt row's loops takes (the window, where it is
@@ -313,6 +317,11 @@ class BlockWindowModel(nn.Module):
             outs.append(_block(h, at[b], 1) if one else jnp.roll(h, kv_start[b], axis=1)[:, :S])
         counted = {"chunks_closed": jnp.sum(n_rows // c.chunk_size), "windows_closed": jnp.sum(n_rows // W),
                    "prefill_tokens_computed": jnp.sum(live) * size, "prefill_tokens_bucketed": B * S}
+        if impl != "xla":
+            steps, blocks = bw.window_summary_steps(
+                live * size, Sp, W, c.chunk_size, c.head_dim, jnp.dtype(self.dtypes.compute_dtype).itemsize)
+            counted.update(prefill_window_softmax_steps=c.num_layers * steps,
+                           prefill_window_query_blocks=c.num_layers * blocks)
         return jnp.concatenate(outs, axis=0), planes, counted
 
     # -- one position a row over the planes -----------------------------------

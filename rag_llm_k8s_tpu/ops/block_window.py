@@ -11,9 +11,11 @@ Three forms, one rule:
 
 - ``window_summary_flash_attention``: the prefill kernel, over one prompt
   row whose index IS its position (the model shifts a left-padded row to the
-  left first). A query block walks the causal part of its window's K/V strip
+  left first). A query block takes the causal part of its window's K/V strip
   (resident: a window is ``W`` keys, whatever the prompt's length) and then
-  the summary plane up to ``(W // C) * w``, under one running max and sum.
+  the summary plane up to ``(W // C) * w``, under one softmax: each as one
+  slice where the shape lets VMEM hold it, else in blocks under a running max
+  and sum (``window_summary_plan``).
   ``window_summary_attention_xla`` is its dense oracle and CPU form.
 - a decode step reads the cache's joined plane (``models/block_window.py``:
   summaries stored DOWNWARD from a seam, the ring upward, so that the live
@@ -41,7 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from rag_llm_k8s_tpu.ops.attention import NEG_INF, _fit_block
+from rag_llm_k8s_tpu.ops.attention import _FLASH_VMEM, NEG_INF, _fit_block
 
 
 POOL_PIECE = 16  # chunks ``chunk_pool`` pools at once: 256 positions of k and of v, 64 float32 registers
@@ -305,62 +307,159 @@ def ring_summary_chunk_attention_xla(q, k_new, v_new, k_plane, v_plane, t, live,
     return _softmax_av(s, ok, vals).astype(q.dtype)
 
 
-def _window_summary_kernel(live_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref, o_ref, *, window: int, per: int,
-                           bq: int, bs: int, scale: float):
-    """One query block of one head: the causal part of its window's strip
-    (``k_ref``/``v_ref [1, W, hd]``, key blocks of ``bq``: the last one is
-    the diagonal), then the summaries ``[0, per * w)`` of ``sk_ref``/``sv_ref
-    [1, NS, hd]`` in blocks of ``bs``, one running softmax. A block whose
-    first query is at or past ``live_ref[0]`` does nothing."""
-    qi = pl.program_id(1)
+WINDOW_PIECE = 512  # summaries a piece (the query block's rows: a piece is a key block's size)
+# Key blocks of straight-line code the sliced form's bodies may hold together:
+# the cell's 34 (43 k bundles) run at the schedule's speed on a v5e, 64 (77 k:
+# window and summaries in ONE step, sixteen bodies) nine times slower, the
+# program no longer resident (PERF.md section 6, PR 54); nothing between was run
+WINDOW_CODE_BLOCKS = 40
+
+
+def window_summary_plan(S: int, window: int, chunk: int, hd: int, itemsize: int = 2) -> Tuple[int, int, bool]:
+    """``(bq, bs, sliced)`` of the prefill kernel over rows of ``S`` positions,
+    from the shape alone (no option, no model's name): ``bq`` queries a grid
+    step, ``bs`` summaries a piece (the operands are padded to whole pieces),
+    and whether a query block takes its keys as SLICES: the window's part, the
+    ``at + bq`` keys in front of its last query, as one slice of the resident
+    strip in ONE softmax step (the mask on its last ``bq`` columns only,
+    nothing carried between the window's key blocks), and its summaries as one
+    slice of whole pieces in a second. Else it walks key blocks of ``bq`` and
+    pieces of ``bs`` under a running softmax.
+
+    A step's cost is its keys' and, a ROW, its bookkeeping (the row max, the
+    correction's ``exp``, sum and accumulator re-scaled; a walk carries the
+    three through every trip of its loops): ``ops/attention.py flash_blocks``;
+    PERF.md section 6, PRs 30, 47, 54. So the fewest steps the scoped VMEM
+    holds win. Slices are taken where the operands' blocks (q, o, the window's
+    strips, the summaries; twice: the pipeline's buffers) and the wider step's
+    scores fit ``_flash_fits``'s budget at its prices: 1.25 KB a row, 12 bytes
+    a key under a mask, 7.25 of an unmasked one; and where the bodies (one a
+    value of ``at``, in each one a count of pieces) stay under
+    ``WINDOW_CODE_BLOCKS``. A window of 2048 at 128 lanes fits (13.7 of 15.5
+    MiB beside 1536 summaries, 34 blocks of code); a window of 4096, 2048
+    summaries, heads of 256 or float32 operands keep the walk."""
+    W = window
+    NS = -(-S // W) * W // chunk
+    bq = _fit_block(W, 512)
+    bs = min(WINDOW_PIECE, NS)
+    NS = -(-NS // bs) * bs
+    lanes = -(-hd // 128) * 128
+    blocks = 2 * 2 * itemsize * lanes * (bq + W + NS)
+    masked = max(bq, bs)  # the diagonal's key block, the summaries' last piece
+    step = bq * (1280 + 12 * masked + 7.25 * (max(W, NS) - masked))
+    nb, pieces = W // bq, NS // bs
+    code = nb * (nb + 1) // 2 + nb * pieces * (pieces + 1) // 2
+    return bq, bs, blocks + step <= _FLASH_VMEM and code <= WINDOW_CODE_BLOCKS
+
+
+def window_summary_block_plan(qi, bq: int, bs: int, window: int, per: int):
+    """What query block ``qi`` of a row sees: ``(at, pieces, n_sum)``. Its first
+    query is at ``at`` within its window ``w = qi * bq // window``, so the
+    window's part is keys ``[0, at + bq)`` of the strip, the last ``bq`` under
+    the diagonal. Its summaries are the first ``n_sum = per * w`` (the windows
+    before its own), in ``pieces`` pieces of ``bs``, of which only the last may
+    hold dead ones. Integers only: the kernel's scalars, the model's counters
+    and the tests read this one rule."""
     w = qi * bq // window
-    at = qi * bq - w * window  # the block's first query, within its window
+    n_sum = per * w
+    return qi * bq - w * window, (n_sum + bs - 1) // bs, n_sum
+
+
+def window_summary_steps(live, S: int, window: int, chunk: int, hd: int, itemsize: int = 2):
+    """``(steps, blocks)``: softmax steps ONE head's call of the prefill kernel
+    takes over rows whose first ``live`` positions (``[rows]``) are computed,
+    and the query blocks that take them; in the form the shape takes
+    (``window_summary_plan``): a live block's window in one step and its
+    summaries, where it has any, in another; or ``at // bq + 1`` key blocks and
+    a step a piece."""
+    bq, bs, sliced = window_summary_plan(S, window, chunk, hd, itemsize)
+    Sp = -(-S // window) * window
+    qi = jnp.arange(Sp // bq, dtype=jnp.int32)[None, :]
+    at, pieces, _ = window_summary_block_plan(qi, bq, bs, window, window // chunk)
+    steps = 1 + jnp.minimum(pieces, 1) if sliced else at // bq + 1 + pieces
+    alive = qi * bq < jnp.clip(jnp.asarray(live, jnp.int32).reshape(-1, 1), 1, Sp)
+    return jnp.sum(jnp.where(alive, steps, 0)), jnp.sum(alive)
+
+
+def _window_summary_kernel(live_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref, o_ref, *, window: int, per: int,
+                           bq: int, bs: int, sliced: bool, scale: float):
+    """One query block of one head, one softmax (``window_summary_block_plan``):
+    the keys ``[0, at + bq)`` of its window's strip (``k_ref``/``v_ref [1, W,
+    hd]``), the last ``bq`` under the diagonal, then the summaries ``[0,
+    n_sum)`` of ``sk_ref``/``sv_ref [1, NS, hd]``. ``sliced``: the window's part
+    is ONE step and the summaries' another, each a static slice (a body for
+    each of the ``W // bq`` values of ``at`` and for each count of pieces), the
+    mask on the diagonal's key block and the last piece only; else key blocks
+    of ``bq`` and pieces of ``bs``, every piece under the mask, through loops
+    that carry the running max, sum and accumulator. A block whose first query
+    is at or past ``live_ref[0]`` does nothing."""
+    qi = pl.program_id(1)
+    at, pieces, n_sum = window_summary_block_plan(qi, bq, bs, window, per)
+
+    def scores(q, k):
+        return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+
+    def fold(state, parts):
+        """``parts`` (scores and their values, masked scores at ``NEG_INF``)
+        into the running ``(max, sum, accumulator)``; ``None``: nothing came
+        before, so nothing is re-scaled. Every row has seen a live key by
+        then (its own, on the diagonal), so its max is a score's and a
+        masked entry's ``exp`` an exact zero: no select on the probabilities."""
+        m = functools.reduce(jnp.maximum, [jnp.max(s, axis=1, keepdims=True) for s, _ in parts])
+        l = acc = None
+        if state is not None:
+            m_old, l, acc = state
+            m = jnp.maximum(m_old, m)
+            alpha = jnp.exp(m_old - m)
+            l, acc = l * alpha, acc * alpha
+        for s, v in parts:
+            p = jnp.exp(s - m)
+            row = jnp.sum(p, axis=1, keepdims=True)
+            pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            l, acc = (row, pv) if l is None else (l + row, acc + pv)
+        return m, l, acc
+
+    def run(k_ref, v_ref, q, off, n):  # keys (or summaries) ``[off, off + n)``, all live: no mask
+        return scores(q, k_ref[0, pl.ds(off, n), :]), v_ref[0, pl.ds(off, n), :]
+
+    def diagonal(q, off):  # the key block the queries themselves are in
+        row = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1)
+        s, v = run(k_ref, v_ref, q, off, bq)
+        return jnp.where(col <= row, s, NEG_INF), v
+
+    def ragged(q, off):  # a piece the live summaries may end in
+        # a summary past the live ones may be pooled from a row's junk tail:
+        # zero it before any matmul (0 * inf)
+        okc = off + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0) < n_sum
+        ok = off + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1) < n_sum
+        k = jnp.where(okc, sk_ref[0, pl.ds(off, bs), :], 0)
+        return jnp.where(ok, scores(q, k), NEG_INF), jnp.where(okc, sv_ref[0, pl.ds(off, bs), :], 0)
+
+    def emit(state):
+        _, l, acc = state
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
 
     @pl.when(qi * bq < live_ref[0])
     def _():
         q = q_ref[0]
-        hd = q.shape[-1]
-
-        def fold(carry, k, v, ok):
-            m, l, acc = carry
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-            if ok is not None:
-                s = jnp.where(ok, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)
-            if ok is not None:
-                p = jnp.where(ok, p, 0.0)
-            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-            return m_new, l, acc
-
-        def exact(j, carry):  # an interior key block of the window: no mask
-            off = pl.multiple_of(j * bq, bq)
-            return fold(carry, k_ref[0, pl.ds(off, bq), :], v_ref[0, pl.ds(off, bq), :], None)
-
-        carry = (jnp.full((bq, 1), NEG_INF, jnp.float32), jnp.zeros((bq, 1), jnp.float32),
-                 jnp.zeros((bq, hd), jnp.float32))
-        carry = jax.lax.fori_loop(0, at // bq, exact, carry)
-        row = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (bq, bq), 1)
-        off = pl.multiple_of(at, bq)
-        carry = fold(carry, k_ref[0, pl.ds(off, bq), :], v_ref[0, pl.ds(off, bq), :], col <= row)
-
-        n_sum = per * w  # live summaries: those of the windows before this one
-
-        def pooled(j, carry):
-            off = pl.multiple_of(j * bs, bs)
-            ok = off + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1) < n_sum
-            # a summary past the live ones may be pooled from a row's junk tail:
-            # zero it before any matmul (0 * inf)
-            okc = off + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0) < n_sum
-            return fold(carry, jnp.where(okc, sk_ref[0, pl.ds(off, bs), :], 0),
-                        jnp.where(okc, sv_ref[0, pl.ds(off, bs), :], 0), ok)
-
-        m, l, acc = jax.lax.fori_loop(0, (n_sum + bs - 1) // bs, pooled, carry)
-        o_ref[0] = (acc / l).astype(o_ref.dtype)  # the diagonal always holds a live key
+        if not sliced:  # the diagonal first: the state is never empty
+            state = fold(None, [diagonal(q, pl.multiple_of(at, bq))])
+            state = jax.lax.fori_loop(
+                0, at // bq, lambda j, state: fold(state, [run(k_ref, v_ref, q, pl.multiple_of(j * bq, bq), bq)]), state)
+            emit(jax.lax.fori_loop(
+                0, pieces, lambda j, state: fold(state, [ragged(q, pl.multiple_of(j * bs, bs))]), state))
+            return
+        for j in range(window // bq):
+            @pl.when(at == j * bq)
+            def _(j=j):
+                state = fold(None, ([run(k_ref, v_ref, q, 0, j * bq)] if j else []) + [diagonal(q, j * bq)])
+                pl.when(pieces == 0)(lambda: emit(state))
+                for n in range(1, sk_ref.shape[1] // bs + 1):
+                    @pl.when(pieces == n)
+                    def _(n=n):
+                        head = [run(sk_ref, sv_ref, q, 0, (n - 1) * bs)] if n > 1 else []
+                        emit(fold(state, head + [ragged(q, (n - 1) * bs)]))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "chunk", "interpret"))
@@ -369,7 +468,9 @@ def window_summary_flash_attention(q, k, v, sk, sv, live=None, *, window: int, c
     """The prefill kernel over rows whose index is their position: ``q, k, v
     [N, S, hd]`` (``N`` = rows x heads), ``sk, sv [N, S // chunk, hd]`` (the
     rows' pooled chunks, ``pool_chunks``) -> ``[N, S, hd]``. ``S`` is padded
-    to whole windows here; a query past a row's end computes on whatever the
+    to whole windows here and the summaries to whole pieces
+    (``window_summary_plan``, which also says in how many steps a query block
+    takes its window); a query past a row's end computes on whatever the
     row holds there, and nobody reads it. ``live`` (traced; None: all): only
     the query blocks that start under it are computed, and the result past
     them is UNWRITTEN (anything, NaN included): what a caller that knows its
@@ -377,13 +478,11 @@ def window_summary_flash_attention(q, k, v, sk, sv, live=None, *, window: int, c
     N, S, hd = q.shape
     W, per = window, window // chunk
     Sp = -(-S // W) * W
-    bq = _fit_block(W, 512)
-    bs = _fit_block(Sp // chunk, 512)
-    if Sp != S:
-        pad = lambda x, n: jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))  # noqa: E731
-        q, k, v = pad(q, Sp), pad(k, Sp), pad(v, Sp)
-        sk, sv = pad(sk[:, :S // chunk], Sp // chunk), pad(sv[:, :S // chunk], Sp // chunk)
-    NS = sk.shape[1]
+    bq, bs, sliced = window_summary_plan(S, W, chunk, hd, q.dtype.itemsize)
+    NS = -(-(Sp // chunk) // bs) * bs
+    pad = lambda x, n: x if n == x.shape[1] else jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))  # noqa: E731
+    q, k, v = pad(q, Sp), pad(k, Sp), pad(v, Sp)
+    sk, sv = pad(sk[:, :S // chunk], NS), pad(sv[:, :S // chunk], NS)
 
     def live_block(qi, live):
         # a dead query block names the last live one, so its step fetches and
@@ -400,7 +499,7 @@ def window_summary_flash_attention(q, k, v, sk, sv, live=None, *, window: int, c
         return (h, 0, 0)
 
     out = pl.pallas_call(
-        functools.partial(_window_summary_kernel, window=W, per=per, bq=bq, bs=bs, scale=hd**-0.5),
+        functools.partial(_window_summary_kernel, window=W, per=per, bq=bq, bs=bs, sliced=sliced, scale=hd**-0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(N, Sp // bq),

@@ -177,6 +177,11 @@ def test_a_prefill_runs_the_blocks_a_rows_positions_fill(params, impl, lengths, 
     counted = bwm.fold_counters(np.asarray(cache.counters))
     assert counted["prefill_tokens_computed"] == sum(-(-n // BLOCK) * BLOCK for n in lengths)
     assert counted["prefill_tokens_bucketed"] == 96 * len(lengths)
+    # through the kernel, a live block is its window's step and, past window 0, one piece of summaries
+    blocks = [-(-n // BLOCK) for n in lengths]
+    through = (0, 0) if impl == "xla" else (sum(2 * n - 1 for n in blocks), sum(blocks))
+    assert (counted["prefill_window_softmax_steps"], counted["prefill_window_query_blocks"]) == tuple(
+        CFG.num_layers * n for n in through)
     prompts = [row[:n] for row, n in zip(rows, lengths)]
     _, fresh = through_the_cache(params, prompts, 96, list(lengths), T=136, impl=impl)  # the prefill, no step behind it
     for b, (prompt, n) in enumerate(zip(prompts, lengths)):
@@ -287,6 +292,112 @@ def test_prefill_kernel_is_the_dense_form():
     got = bw.window_summary_flash_attention(q[:, :80], k[:, :80], v[:, :80], sk[:, :20], sv[:, :20],
                                             window=W, chunk=C, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want)[:, :80], atol=2e-6)
+
+
+# The kernel's step plan is from the shape alone (``window_summary_plan``), so its
+# forms are reached by shapes: a window of 2048 is four query blocks of 512
+# (``at`` = 0, 512, 1024, 1536) and 256 summaries (chunks of 8), so window 1
+# reads one ragged piece, window 2 one whole piece of 512, window 3 a whole one
+# and a ragged one, window 4 two whole ones; 8704 positions are padded to five
+# windows, whose 1280 summaries are no whole number of pieces (the served
+# shape's count). In bfloat16 the slices fit VMEM: the window's part is ONE
+# step and the summaries' another; in float32 the blocks are twice the bytes
+# and the walk over key blocks and pieces stays.
+KW, KC, KS, KHD = 2048, 8, 8704, 16
+KERNEL_FORMS = {"bfloat16": True, "float32": False}  # dtype -> the keys as slices
+
+
+@pytest.fixture(scope="module", params=sorted(KERNEL_FORMS))
+def kernel_row(request):
+    """One row through the kernel (interpret mode) and through the dense form."""
+    dtype = jnp.dtype(request.param)
+    rng = np.random.default_rng(54)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, KS, KHD)), dtype) for _ in range(3))
+    mu, phi = (jnp.asarray(rng.standard_normal((1, KHD)) * 0.4, dtype) for _ in range(2))
+    sk, sv = bw.pool_chunks(k, v, mu, phi, KC)
+    want = np.asarray(bw.window_summary_attention_xla(q, k, v, sk, sv, window=KW, chunk=KC), np.float32)
+    attend = lambda sk, sv, live=None: np.asarray(bw.window_summary_flash_attention(  # noqa: E731
+        q, k, v, sk, sv, live, window=KW, chunk=KC, interpret=True), np.float32)
+    # bfloat16: both round the probabilities to 8 bits before p v, in another order
+    tol = dict(atol=2e-6) if dtype == jnp.float32 else dict(atol=1e-2, rtol=2e-2)
+    return {"dtype": request.param, "sk": sk, "sv": sv, "want": want, "got": attend(sk, sv), "attend": attend,
+            "tol": tol}
+
+
+def test_the_step_plan_is_from_the_shape(kernel_row):
+    plan = bw.window_summary_plan(KS, KW, KC, KHD, jnp.dtype(kernel_row["dtype"]).itemsize)
+    assert plan == (512, 512, KERNEL_FORMS[kernel_row["dtype"]])
+
+
+@pytest.mark.parametrize("qi", range(KS // 512), ids=lambda qi: (
+    f"window{qi // 4}_at{qi % 4 * 512}_" + ("no_summaries", "a_ragged_piece", "a_whole_piece",
+                                          "a_whole_and_a_ragged_piece", "two_whole_pieces")[qi // 4]))
+def test_the_kernel_is_the_dense_form_in_every_step_form(kernel_row, qi):
+    at, pieces, n_sum = bw.window_summary_block_plan(qi, 512, 512, KW, KW // KC)
+    assert (at, pieces, n_sum) == (qi % 4 * 512, (qi // 4 + 1) // 2, qi // 4 * 256)
+    rows = slice(qi * 512, (qi + 1) * 512)
+    np.testing.assert_allclose(kernel_row["got"][:, rows], kernel_row["want"][:, rows], **kernel_row["tol"])
+
+
+@pytest.mark.parametrize("junk", [None, "nan", "inf"])
+def test_a_row_cut_inside_a_window_and_junk_behind_its_summaries(kernel_row, junk):
+    """``live`` ends the row one block into window 3: the blocks before it are
+    the whole row's, bit for bit, the ones past it are not computed; a summary
+    past the live ones (pooled from a row's junk tail) reaches nothing, though
+    it shares the ragged piece with live ones."""
+    live = 3 * KW + 512
+    sk, sv = kernel_row["sk"], kernel_row["sv"]
+    if junk:
+        dead = jnp.arange(sk.shape[1])[None, :, None] >= 3 * (KW // KC)
+        sk, sv = (jnp.where(dead, getattr(jnp, junk), x) for x in (sk, sv))
+    got = kernel_row["attend"](sk, sv, live)
+    np.testing.assert_array_equal(got[:, :live], kernel_row["got"][:, :live])
+    assert not np.allclose(got[:, live:], kernel_row["got"][:, live:], atol=1e-3)
+
+
+def steps_by_hand(live, S, window, chunk, bq, bs, sliced):
+    """Softmax steps and query blocks, a block at a time from the plan's words."""
+    steps = blocks = 0
+    for first in range(0, -(-S // window) * window, bq):
+        if first >= live:
+            break
+        w, at = divmod(first, window)
+        n_sum = window // chunk * w
+        steps += 1 + (n_sum > 0) if sliced else at // bq + 1 + -(-n_sum // bs)
+        blocks += 1
+    return steps, blocks
+
+
+@pytest.mark.parametrize("shape,sliced,why", [
+    ((20480, 2048, 16, 128, 2), True, "the served shape: 13.7 of 15.5 MiB"),
+    ((24576, 2048, 16, 128, 2), True, "a longer bucket: still three pieces of summaries"),
+    ((32768, 2048, 16, 128, 2), False, "four pieces: 50 key blocks of code in the bodies, past what ran at speed"),
+    ((65536, 2048, 16, 128, 2), False, "4096 summaries: their slice is too wide"),
+    ((16384, 8192, 16, 128, 2), False, "a window in the many thousands keeps the walk"),
+    ((16384, 4096, 16, 128, 2), False, "a window of 4096: 3584 unmasked keys a row"),
+    ((20480, 2048, 16, 256, 2), False, "heads of 256: the strips are twice the bytes"),
+    ((20480, 2048, 16, 128, 4), False, "float32 operands"),
+    ((4096, 1024, 16, 64, 2), True, "a short window, 64 lanes padded to 128"),
+    ((128, 32, 4, 16, 4), True, "the toy: one query block a window, one piece of 32 summaries"),
+])
+def test_the_fit_rule_and_the_step_count(shape, sliced, why):
+    S, W, chunk, hd, itemsize = shape
+    bq, bs, got = bw.window_summary_plan(S, W, chunk, hd, itemsize)
+    assert got == sliced, why
+    assert W % bq == 0 and bq <= 512 and bs == min(512, -(-S // W) * W // chunk)
+    lives = [1, bq, S // 3, S - 7, S]
+    steps, blocks = bw.window_summary_steps(jnp.asarray(lives), S, W, chunk, hd, itemsize)
+    want = [steps_by_hand(n, S, W, chunk, bq, bs, sliced) for n in lives]
+    assert (int(steps), int(blocks)) == (sum(s for s, _ in want), sum(b for _, b in want))
+
+
+def test_the_served_row_takes_two_steps_a_block_past_its_first_window():
+    """A 17.4 k-byte prompt in the 20480 bucket (35 live blocks of 512): 66
+    steps where the walk over key blocks of 512 and summary blocks of 256 took 162."""
+    steps, blocks = bw.window_summary_steps(jnp.asarray([35 * 512]), 20480, 2048, 16, 128)
+    assert (int(steps), int(blocks)) == (4 + 2 * 31, 35)
+    walk = sum(first % 2048 // 512 + 1 + -(-(first // 2048 * 128) // 256) for first in range(0, 35 * 512, 512))
+    assert walk == 162
 
 
 def pooled_by_hand(k, v, mu, phi, chunk):
@@ -479,6 +590,9 @@ def test_pallas_path_is_the_xla_path(params):
     ring, pooled = counted["decode_ring_slots_fetched"], counted["decode_summary_slots_fetched"]
     assert ring > 0 and pooled > 0
     assert counted["decode_slots_attended_positions"] == ring + C * pooled
+    # the prefill kernel's steps, both layers: rows of three and of two live blocks, a block past
+    # window 0 its window's step and one piece of summaries
+    assert (counted["prefill_window_softmax_steps"], counted["prefill_window_query_blocks"]) == (2 * (5 + 3), 2 * 5)
 
 
 # ---- (e) what the family cannot be served with refuses by name ----
@@ -507,8 +621,8 @@ def test_continuous_engine_and_tp_refuse(params):
 
 def test_the_family_row(params):
     fam = families.of(CFG)
-    assert fam.counter_names == bwm.COUNTER_NAMES and fam.counters_width == 7
-    assert set(fam.counter_names) == set(bwm.fold_counters(np.zeros(7)))
+    assert fam.counter_names == bwm.COUNTER_NAMES and fam.counters_width == 9
+    assert set(fam.counter_names) == set(bwm.fold_counters(np.zeros(9)))
     assert fam.checkpoint_loader_refusal and "name map" in fam.checkpoint_loader_refusal
     assert fam.verify_span is bwm.verify_span
     from rag_llm_k8s_tpu.core.config import LlamaConfig

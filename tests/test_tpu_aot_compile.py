@@ -337,6 +337,29 @@ CASES.update({
 })
 
 
+def _window_summary(S, window, dtype):
+    from rag_llm_k8s_tpu.ops import block_window as bw
+
+    def fn(q, k, v, sk, sv, live):
+        return bw.window_summary_flash_attention(q, k, v, sk, sv, live, window=window, chunk=16)
+
+    row, pooled = ((32, S, HD), dtype), ((32, S // 16, HD), dtype)
+    return fn, [row, row, row, pooled, pooled, ((), I32)]
+
+
+# the block-window family's prefill kernel at the byte-model cell's shape (one
+# prompt row of 20480 positions, 32 heads, windows of 2048, chunks of 16): a
+# query block's window as ONE slice of 512 x 2048 scores beside the strips and
+# 1536 summaries (``window_summary_plan``: a scoped-VMEM refusal shows here,
+# not on the chip); and the walk over key blocks, which the same rule keeps
+# for a window of 8192 and for float32 operands
+CASES.update({
+    "window_summary[a prompt row: the window in one step]": _window_summary(20480, 2048, BF16),
+    "window_summary[a window of 8192: the walk]": _window_summary(16384, 8192, BF16),
+    "window_summary[float32: the walk]": _window_summary(20480, 2048, F32),
+})
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, uncached):
     fn, args = CASES[name]
@@ -371,6 +394,17 @@ def test_kernel_compiles_for_v5e(name, one_chip, uncached):
         # pass's keys takes this compiler 18 s a program)
         assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
         assert "output_to_operand_aliasing" in text and not re.search(r" sort\(", text), name
+    if name.startswith("window_summary"):
+        # the roofline's reader finds the kernel by its name and a result [heads,
+        # bucket, head_dim], whatever form runs; inside the default scoped VMEM
+        from rag_llm_k8s_tpu.ops import block_window as bw
+
+        (_, S, _), dtype = args[0]
+        window = 8192 if "8192" in name else 2048
+        assert bw.window_summary_plan(S, window, 16, HD, jnp.dtype(dtype).itemsize)[2] == ("one step" in name), name
+        kind = "bf16" if dtype == BF16 else "f32"
+        assert re.search(rf"%window_summary_flash_attention(\.\d+)? = {kind}\[32,{S},{HD}\]\S* custom-call\(", text), name
+        assert '"scoped_memory_configs":[]' in text, f"{name}: asks for more than the default scoped VMEM"
     if name.startswith("chunk_pool"):
         # by name, inside the default scoped VMEM, no float32 of the operands'
         # size beside it; the in-place form's planes alias its results

@@ -609,6 +609,38 @@ class TestDeltaRuleKernelOnChip:
         assert err_o <= 2e-5 and err_s <= 2e-5
 
 
+class TestBlockWindowKernelOnChip:
+    def test_the_prefill_kernel_is_the_dense_form_at_the_served_shape(self):
+        """``ops/block_window.py window_summary_flash_attention`` at the shape
+        ``evabyte-pp4.closed8`` prefills (one row of the 20480 bucket, 32 heads
+        of 128, windows of 2048 in chunks of 16, bf16, 35 live blocks of 512):
+        the window's part of a query block is ONE step there
+        (``window_summary_plan``). Against the float32 dense form a head at a
+        time (its scores are ``[20480, 21760]``); NaN in the summaries no live
+        query may see reaches nothing."""
+        from rag_llm_k8s_tpu.ops import block_window as bw
+
+        N, S, hd, W, C, live = 32, 20480, 128, 2048, 16, 35 * 512
+        assert bw.window_summary_plan(S, W, C, hd) == (512, 512, True)
+        ks = jax.random.split(jax.random.PRNGKey(54), 5)
+        q, k, v = (jax.random.normal(key, (N, S, hd), jnp.bfloat16) for key in ks[:3])
+        mu, phi = (jax.random.normal(key, (N, hd), jnp.bfloat16) * 1.5 / hd ** 0.5 for key in ks[3:])
+        sk, sv = bw.pool_chunks(k, v, mu, phi, C, "pallas")
+        dead = jnp.arange(S // C)[None, :, None] >= (live - 1) // W * (W // C)
+        got = bw.window_summary_flash_attention(q, k, v, jnp.where(dead, jnp.nan, sk), jnp.where(dead, jnp.nan, sv),
+                                                live, window=W, chunk=C)
+        got = np.asarray(got[:, :live], np.float32)
+        assert np.isfinite(got).all()
+        f32 = lambda x, h: x[h:h + 1].astype(jnp.float32)  # noqa: E731
+        for h in (0, N - 1):
+            with jax.default_matmul_precision("highest"):
+                want = bw.window_summary_attention_xla(*(f32(x, h) for x in (q, k, v, sk, sv)), window=W, chunk=C)
+            err = float(np.abs(got[h] - np.asarray(want)[0, :live]).max())
+            print(f"window_summary_flash_attention on the chip, head {h}: |o| error {err:.3g}")
+            # bf16 probabilities into the PV matmul and a bf16 result
+            np.testing.assert_allclose(got[h], np.asarray(want)[0, :live], rtol=3e-2, atol=3e-2)
+
+
 class TestExecutableStoreOnChip:
     def test_a_second_boot_loads_every_declared_executable(self, tmp_path):
         """Boot a tiny fused-RAG service twice on one compile cache directory
