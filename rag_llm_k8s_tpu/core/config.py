@@ -1250,6 +1250,156 @@ class DeltaMoEConfig:
 
 
 @dataclass(frozen=True)
+class SSDMoEConfig:
+    """The state-space-duality, latent-expert decoder family
+    (``models/ssd_moe.py``). A layer is ONE thing, named by its letter of
+    ``hybrid_override_pattern``, behind one pre-norm and one residual: ``x +
+    F_k(RMS(x))``. No layer pairs a mixer with a feed-forward part.
+
+    - ``M``: a MAMBA-2 mixer (``ops/ssd.py``). ``mamba_num_heads`` heads of
+      ``mamba_head_dim`` channels, a state ``[mamba_head_dim,
+      ssm_state_size]`` float32 a head, ONE decay a head (``A_log``, the time
+      step's bias and ``D`` are ``[mamba_num_heads]``), ``B`` and ``C`` shared
+      by the heads of a group (``n_groups`` groups), a depthwise causal
+      convolution of ``conv_kernel`` taps (with a bias) over ``x | B | C``,
+      the output gated by ``silu(z)`` and THEN RMS-normed a group.
+    - ``*``: grouped-query attention (``num_attention_heads`` over
+      ``num_key_value_heads`` heads of ``head_dim``) with no position term.
+    - ``E``: a LATENT expert layer. The router scores the stream
+      (``n_routed_experts`` sigmoid outputs, the ``num_experts_per_tok``
+      largest of score plus bias, weights over their sum times
+      ``routed_scaling_factor``); the stream is projected to
+      ``moe_latent_size``, the experts (two matrices, ``relu`` squared
+      between) work there, their weighted sum is projected back; a shared
+      expert of ``moe_shared_expert_intermediate_size`` works on the stream.
+      ``ep_size`` / ``ep_rank``: this chip's share of the routed experts, as
+      ``LatentMoEConfig``.
+
+    Field names are the published ``config.json``'s. Defaults are the
+    published widths and depth of the 120B-A12B model the
+    ``nemotron-3-super-ep4.solo`` cell serves a share of."""
+
+    vocab_size: int = 131072
+    hidden_size: int = 4096
+    num_hidden_layers: int = 88
+    hybrid_override_pattern: str = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                                    "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    n_routed_experts: int = 512
+    num_experts_per_tok: int = 22
+    moe_intermediate_size: int = 2688
+    moe_latent_size: int = 1024
+    moe_shared_expert_intermediate_size: int = 5376
+    n_shared_experts: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 5.0
+    ep_size: int = 1
+    ep_rank: int = 0
+    layer_norm_epsilon: float = 1e-5
+    # the time step's INITIALISATION (``dt_bias`` is the inverse softplus of a
+    # log-uniform draw over [min, max], floored): ranges of a draw, not clamps
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    max_position_embeddings: int = 262144
+    tie_word_embeddings: bool = False
+    bos_token_id: int = 1
+    eos_token_ids: Tuple[int, ...] = (2,)
+
+    KINDS = "M*E"  # a layer's kind is its letter's place here
+
+    def __post_init__(self):
+        if len(self.hybrid_override_pattern) != self.num_hidden_layers:
+            raise ValueError("hybrid_override_pattern has one letter a layer")
+        if set(self.hybrid_override_pattern) - set(self.KINDS):
+            raise ValueError("hybrid_override_pattern: 'M' (Mamba-2), '*' (attention) and 'E' (experts) only; "
+                             "a '-' (dense MLP) layer is not served")
+        if self.mamba_num_heads % self.n_groups:
+            raise ValueError("mamba_num_heads is a whole number of groups of n_groups")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads is a whole number of groups of num_key_value_heads")
+        if self.conv_kernel < 2:
+            raise ValueError("conv_kernel: the convolution keeps at least one earlier input")
+        if self.n_routed_experts % self.ep_size or not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(
+                f"ep_size={self.ep_size}, ep_rank={self.ep_rank}: the {self.n_routed_experts} routed "
+                "experts must divide evenly over the ranks and the rank must be one of them")
+        if self.n_routed_experts % self.n_group or not 0 < self.topk_group <= self.n_group:
+            raise ValueError("n_group must divide n_routed_experts; topk_group <= n_group")
+        if self.tie_word_embeddings:
+            raise ValueError("this family serves an untied head only")
+
+    # the names the rest of the program reads a decoder's sizes by
+    num_layers = property(lambda self: self.num_hidden_layers)
+    num_heads = property(lambda self: self.num_attention_heads)
+    num_kv_heads = property(lambda self: self.num_key_value_heads)
+    max_seq_len = property(lambda self: self.max_position_embeddings)
+    rms_norm_eps = property(lambda self: self.layer_norm_epsilon)
+    d_inner = property(lambda self: self.mamba_num_heads * self.mamba_head_dim)
+    conv_width = property(lambda self: self.d_inner + 2 * self.n_groups * self.ssm_state_size)  # x | B | C
+    in_proj_width = property(lambda self: self.d_inner + self.conv_width + self.mamba_num_heads)  # z | xBC | dt
+    layer_kinds = property(lambda self: tuple(self.KINDS.index(k) for k in self.hybrid_override_pattern))
+    num_mamba_layers = property(lambda self: self.hybrid_override_pattern.count("M"))
+    num_attention_layers = property(lambda self: self.hybrid_override_pattern.count("*"))
+    num_moe_layers = property(lambda self: self.hybrid_override_pattern.count("E"))
+    experts_held = property(lambda self: self.n_routed_experts // self.ep_size)
+    first_held = property(lambda self: self.ep_rank * self.experts_held)
+
+    def roofline_terms(self, weight_quant: str = "bf16", kv_quant: str = "bf16") -> Tuple[float, float, float]:
+        """(FLOPs a token, weight bytes, KV bytes a position of context), as
+        ``LlamaConfig.roofline_terms`` (bf16 only, neither argument read). A
+        token's matmuls BY LAYER KIND: a Mamba-2 layer's two projections; an
+        attention layer's four; an expert layer's router, its two latent
+        projections, the shared expert and the routed experts a balanced
+        router sends to those HELD here (``num_experts_per_tok * held /
+        n_routed_experts`` of two matrices each); the head. ``weight_bytes``
+        is what a decode step streams at batch 1, and with it the Mamba-2
+        layers' float32 state and kept convolution inputs, read and written
+        once a step: they are CONSTANT in the context. ``kv_bytes_per_token``
+        is one position's keys and values over the ``*`` layers only."""
+        D, Z = self.hidden_size, self.moe_latent_size
+        mamba = D * self.in_proj_width + self.d_inner * D
+        attention = 2 * D * self.num_heads * self.head_dim + 2 * D * self.num_kv_heads * self.head_dim
+        routed_here = self.num_experts_per_tok * self.experts_held / self.n_routed_experts
+        experts = (D * self.n_routed_experts + 2 * D * Z
+                   + self.n_shared_experts * 2 * D * self.moe_shared_expert_intermediate_size
+                   + routed_here * 2 * Z * self.moe_intermediate_size)
+        active = (self.num_mamba_layers * mamba + self.num_attention_layers * attention
+                  + self.num_moe_layers * experts + self.vocab_size * D)
+        state = (4 * self.mamba_num_heads * self.mamba_head_dim * self.ssm_state_size
+                 + 2 * (self.conv_kernel - 1) * self.conv_width)
+        return (2.0 * active, 2.0 * active + 2.0 * self.num_mamba_layers * state,
+                2.0 * self.num_attention_layers * 2 * self.num_kv_heads * self.head_dim)
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 256, **overrides) -> "SSDMoEConfig":
+        """Miniature config for CPU tests with the published structure: seven
+        layers ``MEM*EME``, 8 Mamba-2 heads of 16 in 2 groups at state 16,
+        chunks of 8, 4 query heads over 2 KV heads of 16, 16 experts top-3 in
+        a latent of 64 of which rank 1 of 2 holds 8."""
+        base = dict(
+            vocab_size=vocab_size, hidden_size=128, num_hidden_layers=7, hybrid_override_pattern="MEM*EME",
+            mamba_num_heads=8, mamba_head_dim=16, n_groups=2, ssm_state_size=16, conv_kernel=4, chunk_size=8,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16, n_routed_experts=16,
+            num_experts_per_tok=3, moe_intermediate_size=48, moe_latent_size=64,
+            moe_shared_expert_intermediate_size=96, ep_size=2, ep_rank=1, max_position_embeddings=512,
+            bos_token_id=1, eos_token_ids=(2,),
+        )
+        base.update(overrides)
+        return cls(**base)
+
+
+@dataclass(frozen=True)
 class EncoderConfig:
     """Bidirectional encoder config for the embedding model.
 

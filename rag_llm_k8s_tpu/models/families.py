@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from rag_llm_k8s_tpu.core.config import (
     BlockWindowConfig, ConvMoEConfig, CrossDecoderConfig, DeltaMoEConfig, HybridSSMConfig, LatentMoEConfig,
-    LlamaConfig, WindowedMoEConfig,
+    LlamaConfig, SSDMoEConfig, WindowedMoEConfig,
 )
 from rag_llm_k8s_tpu.parallel.sharding import llama_param_specs, replicated_param_specs
 
@@ -267,8 +267,31 @@ def _cross_decoder() -> Family:
         commit=cd.commit)
 
 
+def _ssd_moe() -> Family:
+    from rag_llm_k8s_tpu.models import ssd_moe as sm
+
+    return replicated_row(
+        "state-space-duality latent-expert", SSDMoEConfig, sm.SSDMoEModel, sm.make_ssd_cache,
+        refuses={
+            "continuous": "a float32 matrix state a head has no blocks to page, and preemption, resume and a "
+                          "per-row frontier need snapshots of it (4 MB a row-layer) that nothing takes yet; "
+                          "use 'coalesce'",
+            "prefix_cache": "a recurrent state can be reused only for an exact prefix, and only if a snapshot "
+                            "was kept at its end: a spliced segment's keys and values say nothing of it",
+            "kv_quant": "the state is float32 by construction and the attention layers' planes and the kept "
+                        "convolution inputs have no int8 form here",
+            "weight_quant": "quantize_llama_params does not know this tree (leaves stacked by layer kind, "
+                            "float32 A_log, D and time-step bias, stacked experts, the router)",
+            "mesh": "this tree has no partition rules (the state splits by head, two KV heads by two at "
+                    "most), and experts across chips need the all-to-all",
+        },
+        counters_width=sm.N_COUNTERS, counter_names=sm.COUNTER_NAMES, fold_counters=sm.fold_counters,
+        commit=sm.commit)
+
+
 # configuration type -> its family (a thunk where building it imports the model)
 _TABLE: Tuple[Tuple[type, Callable[[], Family]], ...] = (
+    (SSDMoEConfig, _ssd_moe),
     (CrossDecoderConfig, _cross_decoder),
     (DeltaMoEConfig, _delta_moe),
     (ConvMoEConfig, _conv_moe),

@@ -112,11 +112,19 @@ SUB_SCOPES = ("embed", "knn", "attn", "mlp", "lm_head", "norm_rope")
 # around a cross-attention layer's attention over the shared plane,
 # ``attn/gmu`` around a gated memory unit, ``attn/diff`` around differential
 # attention's subtraction, lambda and norm, and ``cross`` ABOVE the sub-scopes
-# around its cross-decoder half (the layers that own no state). A
+# around its cross-decoder half (the layers that own no state). The
+# state-space-duality family (models/ssd_moe.py) opens ``attn/conv`` and
+# ``attn/global`` as above, ``attn/ssd`` around a Mamba-2 layer's recurrence
+# (the chunked matmul form, the single-position step, a verify step's chunk
+# and ``commit``'s replay: other work than ``scan``'s, so another name),
+# ``attn/gate`` around the gated group norm, and under ``mlp`` ``router``,
+# ``experts``, ``shared`` and ``latent`` (``mlp/latent``: the two projections
+# between the stream and the experts' latent; ``attn/latent`` is latent
+# ATTENTION, and the path tells them apart). A
 # reader that files an operation under the first sub-scope it knows keeps
 # reading ``attn`` and ``mlp``; one that knows these sees the finer split.
 FINE_SCOPES = ("latent", "router", "experts", "shared", "zero", "dense", "window", "global", "gate",
-               "ring", "pool", "scan", "conv", "kda", "delta", "cross", "gmu", "diff")
+               "ring", "pool", "scan", "conv", "kda", "delta", "cross", "gmu", "diff", "ssd")
 SCOPE_NAMES = frozenset(PHASES + SUB_SCOPES + FINE_SCOPES)
 
 

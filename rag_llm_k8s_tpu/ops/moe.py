@@ -740,7 +740,7 @@ def held_expert_ffn(
     x: jax.Array,  # [N, D]
     experts: jax.Array,  # [N, top_k] int32, over ALL the router's outputs
     weights: jax.Array,  # [N, top_k] float32
-    w_gate: jax.Array,  # [L, held, D, F]: every MoE layer's held experts
+    w_gate: Optional[jax.Array],  # [L, held, D, F]: every MoE layer's held experts; None: an expert has no gate
     w_up: jax.Array,  # [L, held, D, F]
     w_down: jax.Array,  # [L, held, F, D]
     layer: jax.Array,  # [] int32: which of the L
@@ -750,14 +750,17 @@ def held_expert_ffn(
     impl: str = "xla",  # "xla" (ragged_dot) | "pallas" | "pallas_interpret"
 ) -> Tuple[jax.Array, ExpertCounts]:
     """``sum_i w_i * E_i(x)`` over the chosen experts in ``[first_held,
-    first_held + held)``, every ``E`` a SwiGLU; ``[N, D]`` in ``x``'s dtype.
-    A pass gathers its rows, runs the three grouped matmuls and sums the
+    first_held + held)``, every ``E`` a SwiGLU, or under ``w_gate=None`` (a
+    static choice: the gated callers trace what they always did) two matrices
+    with a squared ``relu`` between, ``relu(x W_up)^2 W_down``; ``[N, D]`` in
+    ``x``'s dtype (``D`` the width the experts work at: the stream's, or a
+    latent's). A pass gathers its rows, runs the grouped matmuls and sums the
     weighted rows back into their tokens (``combine``); ``counts.computed``
     and ``counts.combined`` are the down projection's and the combine's own
     counts of what they did, both equal to ``counts.routed``."""
     N, D = x.shape
     top_k = experts.shape[1]
-    held = w_gate.shape[1]
+    held = w_up.shape[1]
     A = N * top_k
     local = experts.reshape(A) - first_held
     mine = (local >= 0) & (local < held)
@@ -785,7 +788,10 @@ def held_expert_ffn(
         token = a // top_k
         rows = jnp.take(x, token, axis=0)
         sizes_here = jnp.clip(ends - lo, 0, C) - jnp.clip(starts - lo, 0, C)
-        h = jax.nn.silu(grouped(rows, w_gate, sizes_here, layer)[0]) * grouped(rows, w_up, sizes_here, layer)[0]
+        if w_gate is None:
+            h = jnp.square(jax.nn.relu(grouped(rows, w_up, sizes_here, layer)[0]))
+        else:
+            h = jax.nn.silu(grouped(rows, w_gate, sizes_here, layer)[0]) * grouped(rows, w_up, sizes_here, layer)[0]
         y, stored, tiled = grouped(h, w_down, sizes_here, layer)
         acc, hot = combine(acc, y, jnp.take(flat_w, a), jnp.where(valid, token, N),
                            jnp.where(valid, jnp.take(key, a), held), held, impl=impl)

@@ -315,7 +315,8 @@ def test_the_benchmark_lists_the_metric_for_the_four_batched_cells():
     PR 45 for the gated-convolution family's, whose heads of 64 the walk
     refuses: its steps take the chunk form and the share reads 100; by PR 53
     for the decoder-hybrid-decoder family's: the full layer's own walk of the
-    plane seven cross layers also read."""
+    plane seven cross layers also read; by PR 55 for the state-space-duality
+    family's one attention layer of eleven (32 query heads over 2 KV heads)."""
     import json
 
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
@@ -326,7 +327,8 @@ def test_the_benchmark_lists_the_metric_for_the_four_batched_cells():
     assert entry["workloads"] == ["mistral-7b-int8.closed8", "mistral-nemo-tp4.closed4",
                                   "dots-vlm1-ep16.closed8", "longcat-flash-ep32.closed8",
                                   "laguna-s-ep16.closed8", "jamba2-3b.closed8", "lfm2-24b-a2b-pp4.solo",
-                                  "kimi-linear-ep16.solo", "phi4-mini-flash.solo-12chunk"]
+                                  "kimi-linear-ep16.solo", "phi4-mini-flash.solo-12chunk",
+                                  "nemotron-3-super-ep4.solo"]
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,7 @@ import pytest
 import longcat_flash_reference
 from rag_llm_k8s_tpu.core.config import (
     BlockWindowConfig, ConvMoEConfig, CrossDecoderConfig, EngineConfig, GoodputConfig, HybridSSMConfig, LatentMoEConfig, LlamaConfig,
-    MeshConfig, PrefixCacheConfig, WindowedMoEConfig,
+    MeshConfig, PrefixCacheConfig, SSDMoEConfig, WindowedMoEConfig,
 )
 from rag_llm_k8s_tpu.core.mesh import make_mesh
 from rag_llm_k8s_tpu.models import families
@@ -71,6 +71,15 @@ ROOFLINES = {
     "cross_decoder-tiny": (lambda: CrossDecoderConfig.tiny(vocab_size=48), {}, (1030144.0, 1104896.0, 384.0)),
     "phi4-mini-flash": (lambda: CrossDecoderConfig(tie_word_embeddings=False), {},
                         (7702118400.0, 7729541120.0, 40960.0)),
+    # PR 55's family, by its own arithmetic, BY LAYER KIND (a layer is a mixer OR a feed-forward part): a
+    # Mamba-2 layer's two projections (4096 x 18560 + 8192 x 4096), the one attention layer's four, an expert
+    # layer's router, two latent projections, shared expert and 22 x 128 / 512 = 5.5 experts of two matrices
+    # (1024 x 2688 each) a token; the five states (float32 [128, 64, 128], read and written a step) and kept
+    # convolution inputs ride the weights' bytes; K/V bytes of the ONE attention layer only (2 x 2 x 128 x 2)
+    "ssd_moe-tiny": (lambda: SSDMoEConfig.tiny(vocab_size=48), {}, (724992.0, 781056.0, 128.0)),
+    "nemotron-3-super-ep4": (lambda: SSDMoEConfig(vocab_size=32768, num_hidden_layers=11,
+                                                  hybrid_override_pattern="MEMEMEM*EME", ep_size=4), {},
+                             (2283536384.0, 2326093824.0, 1024.0)),
 }
 
 

@@ -757,7 +757,7 @@ def test_reader_of_a_fine_scope_s_prefill_ms_per_row(metric, scope, case, by, ro
     ``router_prefill_ms_per_row.py`` divide ``prefill/.../mlp/experts`` and
     ``prefill/.../mlp/router`` by the slice's prefill rows, return None (and
     do not raise) where either is missing, and ``BENCHMARK.json`` lists each
-    for the five sparse-expert cells."""
+    for the six sparse-expert cells (PR 55: the latent experts' cell)."""
     import importlib.util
     import json
     import os
@@ -780,4 +780,4 @@ def test_reader_of_a_fine_scope_s_prefill_ms_per_row(metric, scope, case, by, ro
     assert entry == {"name": metric, "unit": "ms", "better": "lower",
                      "source": "device_trace", "layer": "model step", "moves": "latency_p50_ms",
                      "workloads": ["dots-vlm1-ep16.closed8", "longcat-flash-ep32.closed8", "laguna-s-ep16.closed8",
-                                   "lfm2-24b-a2b-pp4.solo", "kimi-linear-ep16.solo"]}
+                                   "lfm2-24b-a2b-pp4.solo", "kimi-linear-ep16.solo", "nemotron-3-super-ep4.solo"]}

@@ -273,7 +273,7 @@ def test_latent_moe_fine_scopes_sit_beneath_attn_and_mlp(latent_engine):
     it, which is what a prefill's rows are counted by."""
     assert set(tracing.FINE_SCOPES) == {"latent", "router", "experts", "shared", "zero", "dense",
                                         "window", "global", "gate", "ring", "pool", "scan", "conv",
-                                        "kda", "delta", "cross", "gmu", "diff"}
+                                        "kda", "delta", "cross", "gmu", "diff", "ssd"}
     assert set(tracing.FINE_SCOPES) <= tracing.SCOPE_NAMES
     paths = [path for _, path in _traced(LATENT_PROGRAMS["generate"](latent_engine))]
     for phase in ("prefill", "decode"):
