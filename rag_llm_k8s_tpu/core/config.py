@@ -1857,20 +1857,6 @@ class EngineConfig:
     # row plus one prefill token (validate_interleave).
     # Env: TPU_RAG_WINDOW_TOKEN_BUDGET.
     window_token_budget: int = 0
-    # disaggregated pool role (docs/ROUTER.md): which half of the serving
-    # work this engine's pool runs. "unified" (default) is the single-pool
-    # scheduler, untouched. "prefill" runs admission / chunked-prefill
-    # windows only and hands each request's pool blocks to a decode-role
-    # engine the moment its first token samples (same [L, N, K, bs, hd]
-    # arena layout on both sides, so the hand-off is block-table surgery
-    # plus one gather/scatter of the owned blocks — ContinuousEngine.
-    # export_request / import_request). "decode" accepts migrated requests
-    # and runs decode sync windows; its own admission path stays available
-    # as the fallback when a migration dies mid-flight (the scheduler
-    # re-prefills prompt+emitted there — streams stay byte-identical).
-    # Disaggregated roles require kv_paged=True (validate_pool_role).
-    # Env: TPU_RAG_POOL_ROLE.
-    pool_role: str = "unified"  # "unified" | "prefill" | "decode"
     # cross-request KV prefix cache (see PrefixCacheConfig)
     prefix_cache: PrefixCacheConfig = field(default_factory=PrefixCacheConfig)
 
@@ -1924,24 +1910,6 @@ class EngineConfig:
                 f"cover max_batch_size={self.max_batch_size} decode lanes "
                 "plus one prefill token — raise the budget or set 0 for "
                 "auto (max_batch_size + prefill_chunk_tokens)"
-            )
-
-    def validate_pool_role(self) -> None:
-        """Cross-field rules for disaggregated pool roles. Called from
-        ``from_env`` (with the env applied) and at continuous-engine
-        construction: a bad pairing fails with the fix spelled out, not
-        as a missing-executable error at the first migration."""
-        if self.pool_role not in ("unified", "prefill", "decode"):
-            raise ValueError(
-                f"pool_role={self.pool_role!r}: expected 'unified', "
-                "'prefill', or 'decode' (TPU_RAG_POOL_ROLE)"
-            )
-        if self.pool_role != "unified" and not self.kv_paged:
-            raise ValueError(
-                f"pool_role={self.pool_role!r} requires kv_paged=True — "
-                "the prefill→decode hand-off moves POOL BLOCKS between "
-                "same-layout arenas; set TPU_RAG_KV_PAGED=1 or run "
-                "TPU_RAG_POOL_ROLE=unified"
             )
 
 
@@ -2366,78 +2334,6 @@ class TenantConfig:
         return out
 
 
-@dataclass(frozen=True)
-class RouterConfig:
-    """Front-tier replica router (server/router.py, docs/ROUTER.md).
-
-    Scores prefill candidates by chunk/prefix/session affinity against
-    each replica's bounded hot-chunk registry (so PR 12's canonical
-    hot-chunk KV is actually re-hit across a fleet instead of scattered
-    by round-robin), balances the residue by load, respects breaker /
-    draining readiness as the health signal, and journals every decision
-    as a ``route_decision`` flight event. The router is a host-side
-    scorer — it never touches a device.
-    """
-
-    # relative weight of chunk/prefix affinity in the prefill-candidate
-    # score (0 disables affinity — pure load balancing).
-    # Env: TPU_RAG_ROUTER_AFFINITY_WEIGHT.
-    affinity_weight: float = 1.0
-    # relative weight of free capacity (free slots / batch) in the score —
-    # the counterweight that keeps a hot replica from absorbing the whole
-    # fleet once its chunks are everywhere.
-    # Env: TPU_RAG_ROUTER_LOAD_WEIGHT.
-    load_weight: float = 0.5
-    # per-replica hot-chunk registry bound (LRU past it): the router's
-    # host-side mirror of which chunk keys each replica has served — the
-    # affinity signal's working set. Env: TPU_RAG_ROUTER_HOT_CHUNKS.
-    hot_chunks: int = 512
-    # session stickiness TTL: a ``session_id`` re-routes to its previous
-    # replica within this window (conversation KV warmth), after which the
-    # score decides fresh. Env: TPU_RAG_ROUTER_SESSION_TTL_S.
-    session_ttl_s: float = 600.0
-
-    def validate(self) -> None:
-        if self.affinity_weight < 0 or self.load_weight < 0:
-            raise ValueError(
-                f"RouterConfig weights must be >= 0 (affinity_weight="
-                f"{self.affinity_weight}, load_weight={self.load_weight})"
-            )
-        if self.hot_chunks < 1:
-            raise ValueError(
-                f"RouterConfig.hot_chunks={self.hot_chunks}: expected >= 1"
-            )
-        if self.session_ttl_s <= 0:
-            raise ValueError(
-                f"RouterConfig.session_ttl_s={self.session_ttl_s}: "
-                "expected > 0"
-            )
-
-    @classmethod
-    def from_env(cls, env: Optional[dict] = None) -> "RouterConfig":
-        env = dict(os.environ if env is None else env)
-        out = cls()
-        if "TPU_RAG_ROUTER_AFFINITY_WEIGHT" in env:
-            out = dataclasses.replace(
-                out,
-                affinity_weight=float(env["TPU_RAG_ROUTER_AFFINITY_WEIGHT"]),
-            )
-        if "TPU_RAG_ROUTER_LOAD_WEIGHT" in env:
-            out = dataclasses.replace(
-                out, load_weight=float(env["TPU_RAG_ROUTER_LOAD_WEIGHT"])
-            )
-        if "TPU_RAG_ROUTER_HOT_CHUNKS" in env:
-            out = dataclasses.replace(
-                out, hot_chunks=int(env["TPU_RAG_ROUTER_HOT_CHUNKS"])
-            )
-        if "TPU_RAG_ROUTER_SESSION_TTL_S" in env:
-            out = dataclasses.replace(
-                out, session_ttl_s=float(env["TPU_RAG_ROUTER_SESSION_TTL_S"])
-            )
-        out.validate()
-        return out
-
-
 # ---------------------------------------------------------------------------
 # top-level
 # ---------------------------------------------------------------------------
@@ -2470,7 +2366,6 @@ class AppConfig:
     flight: FlightConfig = field(default_factory=FlightConfig)
     shadow: ShadowConfig = field(default_factory=ShadowConfig)
     tenants: TenantConfig = field(default_factory=TenantConfig)
-    router: RouterConfig = field(default_factory=RouterConfig)
     system_message: str = SYSTEM_MESSAGE
 
     @classmethod
@@ -2754,16 +2649,7 @@ class AppConfig:
             )
         goodput.validate()  # range rules once, with the env applied
         engine = dataclasses.replace(engine, goodput=goodput)
-        if "TPU_RAG_POOL_ROLE" in env:
-            role = env["TPU_RAG_POOL_ROLE"]
-            if role not in ("unified", "prefill", "decode"):
-                raise ValueError(
-                    f"TPU_RAG_POOL_ROLE={role!r}: expected 'unified', "
-                    "'prefill', or 'decode'"
-                )
-            engine = dataclasses.replace(engine, pool_role=role)
         engine.validate_interleave()  # cross-field rules, with the env applied
-        engine.validate_pool_role()
         resilience = cfg.resilience
 
         def _res_int(var: str, field_name: str, minimum: int):
@@ -2832,5 +2718,4 @@ class AppConfig:
             flight=FlightConfig.from_env(env),
             shadow=ShadowConfig.from_env(env),
             tenants=TenantConfig.from_env(env),
-            router=RouterConfig.from_env(env),
         )

@@ -194,15 +194,6 @@ class ContinuousEngine:
         self.kv_quant = engine_config.kv_quant
         # ---- paged KV (block-pool arena; EngineConfig.kv_paged) ---------
         self.paged = bool(getattr(engine_config, "kv_paged", False))
-        # ---- disaggregated pool role (ISSUE 20) -------------------------
-        # "prefill" engines run admission only and hand each request's
-        # pool blocks to a "decode"-role twin (export_request /
-        # import_request); "unified" keeps single-replica behavior. Role
-        # is POLICY, not capability: a prefill engine whose export fails
-        # keeps decoding the request locally, so a disaggregated tier
-        # degrades to unified instead of failing requests.
-        engine_config.validate_pool_role()
-        self.pool_role = engine_config.pool_role
         self.kv_pool: Optional[KVBlockPool] = None
         if self.paged:
             # tp>1 is served by the HEAD-SHARDED arena (each device holds
@@ -646,8 +637,6 @@ class ContinuousEngine:
                 "prefill_px_paged": lambda: self._build_prefill_px_paged(S),  # S: the suffix bucket
                 "prefix_scatter": lambda: self._build_prefix_scatter(S),  # S: the buffer width
                 "chunk_splice": lambda: self._build_chunk_splice(S),  # S: the block count
-                "migrate_out": lambda: self._build_migrate_out(S),  # S: the block count
-                "migrate_in": lambda: self._build_migrate_in(S),  # S: the block count
                 "boundary_px": lambda: self._build_boundary_px_paged(S),  # S: the window
                 "verify_paged": lambda: self._build_verify_paged(S),  # S: the draft count K
                 "mixed_step": lambda: self._build_mixed_step(S),  # S: the chunk width
@@ -1985,91 +1974,6 @@ class ContinuousEngine:
             jax.ShapeDtypeStruct((), i32, sharding=rep),
         )
 
-    def _packet_avals(self, nb: int):
-        """The migration packet's plane avals: the arena tuple with its
-        block axis cut to ``nb`` — same dtypes, same shardings (kv heads
-        sit at dim 2 either way), so a packet gathered on one engine
-        feeds another engine's import executable with no reshard."""
-        out = []
-        for av in self._arena_avals():
-            shape = (av.shape[0], nb) + av.shape[2:]
-            out.append(
-                jax.ShapeDtypeStruct(shape, av.dtype, sharding=av.sharding)
-            )
-        return tuple(out)
-
-    def _build_migrate_out(self, nb: int):
-        """Gather one migrating row's ``nb`` pool blocks out of the arena
-        as a self-contained plane tuple (``[L, nb, K, bs, hd]`` payload +
-        int8 scale planes) — the prefill→decode hand-off's device copy.
-        NOTHING is donated: a failure here leaves the source engine fully
-        intact (the scheduler just keeps decoding the request locally).
-        Ids are padded to the admission bucket's block count with the
-        NULL block, so the executable ladder stays as bounded as
-        admission's. One executable per block count, like chunk_splice."""
-        def gather(arena, ids):
-            return tuple(jnp.take(a, ids, axis=1) for a in arena)
-
-        rep = self.mesh.replicated if self.mesh is not None else None
-        if self.mesh is not None:
-            pay_sh, sc_sh, _ = self._shardings()
-            out_shardings = tuple(
-                pay_sh if len(av.shape) == 5 else sc_sh
-                for av in self._arena_avals()
-            )
-        else:
-            out_shardings = None
-        return jax.jit(gather, out_shardings=out_shardings), (
-            self._arena_avals(),
-            jax.ShapeDtypeStruct((nb,), jnp.int32, sharding=rep),
-        )
-
-    def _build_migrate_in(self, nb: int):
-        """Scatter a migrated packet's planes into freshly allocated
-        destination blocks + splice the row's sampling state (kv_len,
-        last_tok, active, UNFOLDED rng key) in the same device call —
-        the decode-role twin of ``insert_paged`` for a row whose KV was
-        computed elsewhere. The copy is bit-exact (same dtype both
-        sides) and the state triple reproduces the source row, so the
-        next decode step folds ``(row_key, kv_len + 1)`` exactly as a
-        unified run would: streams are byte-identical by construction.
-        Padded slabs carry the NULL block id — their junk lands in the
-        reserved null block, the same don't-care discipline as insert."""
-        i32 = jnp.int32
-
-        def splice(arena, planes, kv_len, last_tok, active, rng_keys,
-                   row, dst, length, tok, row_key):
-            new = tuple(
-                a.at[:, dst].set(p.astype(a.dtype))
-                for a, p in zip(arena, planes)
-            )
-            kv_len = kv_len.at[row].set(length)
-            last_tok = last_tok.at[row].set(tok)
-            active = active.at[row].set(True)
-            rng_keys = rng_keys.at[row].set(row_key)
-            return new, kv_len, last_tok, active, rng_keys
-
-        rep = self.mesh.replicated if self.mesh is not None else None
-        out_shardings = (
-            (self._arena_shardings(), rep, rep, rep, rep)
-            if self.mesh is not None else None
-        )
-        return jax.jit(
-            splice, donate_argnums=(0, 2, 3, 5), out_shardings=out_shardings
-        ), (
-            self._arena_avals(),
-            self._packet_avals(nb),
-            jax.ShapeDtypeStruct((self.B,), i32, sharding=rep),
-            jax.ShapeDtypeStruct((self.B,), i32, sharding=rep),
-            jax.ShapeDtypeStruct((self.B,), bool, sharding=rep),
-            jax.ShapeDtypeStruct((self.B, 2), jnp.uint32, sharding=rep),
-            jax.ShapeDtypeStruct((), i32, sharding=rep),
-            jax.ShapeDtypeStruct((nb,), i32, sharding=rep),
-            jax.ShapeDtypeStruct((), i32, sharding=rep),
-            jax.ShapeDtypeStruct((), i32, sharding=rep),
-            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
-        )
-
     def _build_boundary_px_paged(self, W: int):
         """Boundary-correction re-prefill straight into pool blocks: the
         first ``W`` tokens of a spliced chunk recompute THROUGH the model
@@ -2991,132 +2895,6 @@ class ContinuousEngine:
         }
         results[i] = (row, None)
 
-    # ------------------------------------------------------------------
-    # prefill→decode migration (disaggregated pools; ISSUE 20)
-    # ------------------------------------------------------------------
-    def export_request(self, request_id: int) -> Optional[dict]:
-        """Pull a just-admitted request OFF this engine as a migration
-        packet: its pool blocks' planes (one non-donating gather), its
-        sampling state (kv_len, last token, UNFOLDED rng key) and its
-        budget — everything a decode-role twin's ``import_request``
-        needs to continue the stream byte-identically. The source row is
-        released before returning (blocks back to the pool, device row
-        deactivated), so after a successful export this engine holds
-        NOTHING for the request. Returns None when the request is not
-        exportable (unknown, still chunk-prefilling, already finished) —
-        the scheduler then keeps decoding it locally. A gather failure
-        propagates with the engine fully intact (nothing was donated)."""
-        if not self.paged:
-            return None
-        if request_id in self._chunk_admissions:
-            # interleaved admission still staging: no tok0 yet, nothing
-            # to hand off — the mixed windows will finish it locally
-            return None
-        row = next(
-            (i for i, s in enumerate(self.slots)
-             if s.active and s.request_id == request_id), None,
-        )
-        if row is None:
-            return None
-        slot = self.slots[row]
-        ids = list(self._slot_blocks[row])
-        S = sim_policy.bucket_len(max(slot.prompt_len, 1), self.buckets)
-        nb_pad = S // self.block_size
-        padded = ids + [NULL_BLOCK] * (nb_pad - len(ids))
-        t0 = time.perf_counter()
-        # the row's base key: ONE tiny ([2] uint32) fetch per migration —
-        # the decode twin must seed its row with the UNFOLDED key so its
-        # step fold sequence continues exactly where admission left off
-        row_key = np.asarray(self._rng_keys[row])
-        planes = self._get("migrate_out", nb_pad)(
-            self._cache, self._put(jnp.asarray(np.asarray(padded, np.int32)))
-        )
-        packet = {
-            "request_id": request_id,
-            "planes": planes,
-            "n_blocks": len(ids),
-            "nb_pad": nb_pad,
-            "kv_len": slot.kv_ub,
-            "tokens": list(slot.tokens),
-            "remaining": slot.remaining,
-            "prompt_len": slot.prompt_len,
-            "row_key": row_key,
-            "history": list(slot.history) if self.spec_on else [],
-        }
-        # the gather succeeded: NOW release the source side — record the
-        # footprint first (the scheduler forwards it into the timings)
-        self._blocks_at_retire[request_id] = len(ids)
-        m = np.ones(self.B, bool)
-        m[row] = False
-        self._active = self._active & self._put(jnp.asarray(m))
-        self._release_row(row)
-        self.slots[row] = _Slot()
-        flight.emit(
-            "migrate_begin", request_id, blocks=len(ids),
-            kv_len=packet["kv_len"],
-            duration_ms=round((time.perf_counter() - t0) * 1e3, 3),
-            **_tenant_attr(self.ledger, request_id),
-        )
-        return packet
-
-    def import_request(self, packet: dict) -> int:
-        """Land a migrated packet in a fresh row: allocate destination
-        blocks (``PoolExhausted`` propagates BEFORE anything is donated —
-        the packet stays valid and the scheduler requeues it), scatter
-        the planes + splice the sampling state in one donating call, and
-        rebuild the host slot so decode continues the same (seed,
-        position) fold sequence. A failure inside the donating call
-        resets this engine (``EngineStateLost``) — the scheduler
-        re-prefills prompt+emitted here, streams still byte-identical.
-        Returns the row index."""
-        assert self.paged, "import_request() requires kv_paged=True"
-        free = self.free_slots()
-        assert free, "import_request() without a free slot"
-        rid = packet["request_id"]
-        n_real = packet["n_blocks"]
-        nb_pad = packet["nb_pad"]
-        ids = self.kv_pool.alloc(n_real)  # PoolExhausted = backpressure
-        row = free[0]
-        dst = ids + [NULL_BLOCK] * (nb_pad - n_real)
-        t0 = time.perf_counter()
-        self._assign_row_blocks(row, ids)
-        self._device_tables()  # refresh before anything can step
-        try:
-            # fault site "migrate": a device fault inside the donated
-            # import — the decode engine resets and the scheduler
-            # re-prefills prompt+emitted (docs/ROUTER.md)
-            faults.maybe_fail("migrate")
-            (self._cache, self._kv_len, self._last_tok,
-             self._active, self._rng_keys) = self._get("migrate_in", nb_pad)(
-                self._cache, packet["planes"],
-                self._kv_len, self._last_tok, self._active, self._rng_keys,
-                self._put(jnp.int32(row)),
-                self._put(jnp.asarray(np.asarray(dst, np.int32))),
-                self._put(jnp.int32(packet["kv_len"])),
-                self._put(jnp.int32(packet["tokens"][-1])),
-                self._put(jnp.asarray(packet["row_key"])),
-            )
-        except BaseException as e:  # noqa: BLE001 — donated arena invalidated
-            self.reset()
-            raise EngineStateLost(
-                "migrate import failed; engine state reset"
-            ) from e
-        self._admit_seq += 1
-        self.slots[row] = _Slot(
-            request_id=rid, tokens=list(packet["tokens"]),
-            remaining=packet["remaining"], active=True,
-            kv_ub=packet["kv_len"], admit_seq=self._admit_seq,
-            prompt_len=packet["prompt_len"],
-            history=list(packet["history"]) if self.spec_on else [],
-        )
-        flight.emit(
-            "migrate_done", rid, slot=row, blocks=n_real,
-            kv_len=packet["kv_len"],
-            duration_ms=round((time.perf_counter() - t0) * 1e3, 3),
-            **_tenant_attr(self.ledger, rid),
-        )
-        return row
-
     def _alloc_chunk_blocks(self, n: int) -> Optional[List[int]]:
         """Allocate ``n`` blocks for a scheduled prefill chunk, reclaiming
         re-buildable registrations under pressure in ``admission_state``'s
@@ -3825,65 +3603,6 @@ class ContinuousScheduler:
             ap = info.setdefault("approx", [])
             if "spec_verify" not in ap:
                 ap.append("spec_verify")
-        if info is not None and item.migrate is not None:
-            # prefill-role hand-off (disaggregated pools): the returned
-            # tokens are only the admission token — the caller (the
-            # router) forwards this packet to a decode-role replica's
-            # ``submit_migrated``, which finishes the stream
-            info["migrate_packet"] = item.migrate
-        return item.result
-
-    def submit_migrated(
-        self,
-        packet: Dict,
-        timeout: Optional[float] = None,
-        deadline: Optional[Deadline] = None,
-        info: Optional[Dict] = None,
-        tenant: Optional[str] = None,
-    ) -> List[int]:
-        """Land a prefill-role peer's migration packet on THIS scheduler's
-        engine and block until the stream completes. The request keeps
-        its process-global id, so the flight journal shows ONE lifecycle
-        across both engines (arrival/admit/migrate_begin on the prefill
-        side; migrate_done/complete here). Returns the FULL stream — the
-        packet's admission token plus everything decoded here —
-        byte-identical to a unified run by the (seed, position) fold."""
-        if self._stop.is_set():
-            raise RuntimeError("scheduler is shut down")
-        rid = packet["request_id"]
-        tenant = tenant if tenant is not None else packet.get("tenant")
-        item = _Pending(
-            request_id=rid,
-            # a decode-side reset re-prefills prompt+emitted from these
-            # — the same fold path as any reset recovery
-            prompt=list(packet.get("prompt", ())),
-            max_new=packet["remaining"] + len(packet["tokens"]),
-            seed=packet.get("seed"),
-            deadline=deadline, retries_left=self.retries, tenant=tenant,
-            migrate=packet,
-        )
-        if info is not None:
-            info["request_id"] = rid
-        if tenant is not None:
-            self.engine.ledger.note_tenant(rid, tenant)
-        with self._lifecycle_lock:  # stop-check + enqueue must be atomic
-            if self._stop.is_set():
-                raise RuntimeError("scheduler is shut down")
-            self._queue.put(item)
-        wait_t = timeout
-        if wait_t is None and deadline is not None:
-            wait_t = deadline.wait_timeout() + 0.25
-        if not item.done.wait(wait_t):
-            if deadline is not None and deadline.expired():
-                item.abandoned = True
-                raise DeadlineExceeded("generate", deadline.budget_ms)
-            raise TimeoutError("generation timed out")
-        if item.error is not None:
-            raise item.error
-        if info is not None and item.blocks_allocated is not None:
-            info["kv_blocks_allocated"] = item.blocks_allocated
-        if info is not None and item.goodput is not None:
-            info["goodput"] = item.goodput
         return item.result
 
     def busy_seconds(self) -> float:
@@ -3991,14 +3710,6 @@ class ContinuousScheduler:
                     # overload this is what keeps dead work off the device
                     item = self._next_nowait()
                     continue
-                if item.migrate is not None:
-                    # a prefill-role peer's migration packet: lands via
-                    # its own import path (no prefill, no bucketing)
-                    leftover = self._admit_migrated(item, waiting)
-                    if leftover is not None:
-                        return leftover
-                    item = self._next_nowait()
-                    continue
                 # paged backpressure: a pool that can't take this prompt NOW
                 # keeps it QUEUED (decode frees blocks every window; the
                 # growing queue is what trips the PR-4 admission gate's 429s
@@ -4039,12 +3750,6 @@ class ContinuousScheduler:
                         continue
                     if self._expire_queued(nxt):
                         continue  # dead on arrival: no prefill for it
-                    if nxt.migrate is not None:
-                        # migrated packets never batch with prefills:
-                        # requeue and stop draining (a bare put-back
-                        # here would re-pull it in this very loop)
-                        self._queue.put(nxt)
-                        break
                     batch.append(nxt)
                 try:
                     t_busy = time.perf_counter()
@@ -4091,11 +3796,6 @@ class ContinuousScheduler:
                             eng._m_ttft.observe(time.monotonic() - b.t_submit)
                         if finished is not None:
                             self._deliver(b, finished)
-                        elif eng.pool_role == "prefill":
-                            # disaggregated hand-off: the request leaves
-                            # this engine as a packet; export failure
-                            # keeps it decoding locally (role is policy)
-                            self._export_or_keep(b, waiting)
                         else:
                             waiting[b.request_id] = b
                 except EngineStateLost as e:
@@ -4178,104 +3878,6 @@ class ContinuousScheduler:
             stream_fnv=flight.stream_hash(item.result), **extra,
         )
         item.done.set()
-
-    def _export_or_keep(self, item: "_Pending", waiting) -> None:
-        """Prefill-role hand-off: pull the freshly admitted request off
-        the engine as a migration packet and deliver it to the submitter
-        (the router forwards it to a decode-role replica). Any failure
-        keeps the request decoding LOCALLY — a broken hand-off degrades
-        to unified service instead of failing the request. No
-        ``complete`` event fires here: the decode-role engine that
-        imports the packet finishes the stream and emits it."""
-        eng = self.engine
-        packet = None
-        try:
-            t_busy = time.perf_counter()
-            try:
-                packet = eng.export_request(item.request_id)
-            finally:
-                self._busy_s += time.perf_counter() - t_busy
-        except BaseException:  # noqa: BLE001 — nothing donated; state intact
-            logger.exception(
-                "migration export failed; serving request %d locally",
-                item.request_id,
-            )
-        if packet is None:
-            waiting[item.request_id] = item
-            return
-        # the packet needs what only the scheduler knows: the original
-        # prompt and seed — a decode-side reset re-prefills from them
-        packet["prompt"] = list(item.prompt)
-        packet["seed"] = item.seed
-        packet["tenant"] = item.tenant
-        item.blocks_allocated = eng.pop_blocks_allocated(item.request_id)
-        item.goodput = eng.pop_request_goodput(
-            item.request_id, tokens=len(packet["tokens"])
-        )
-        item.migrate = packet
-        item.result = list(packet["tokens"])
-        item.done.set()
-
-    def _admit_migrated(
-        self, item: "_Pending", waiting
-    ) -> Optional["_Pending"]:
-        """Land a migration packet on the engine, with the same
-        backpressure discipline as admission: while the pool or the slot
-        map can't take it NOW, decode windows run (they retire rows and
-        free blocks every iteration) and the import retries. Only a
-        packet the whole pool could never hold fails outright. Returns
-        the item when interrupted by shutdown (the caller's drain fails
-        it); None otherwise."""
-        eng = self.engine
-        pkt = item.migrate
-        need = pkt["n_blocks"]
-        while not self._stop.is_set():
-            usable = eng.kv_pool.usable_blocks() if eng.kv_pool else 0
-            if not eng.paged or need > usable:
-                item.error = PoolExhausted(need, usable)
-                item.done.set()
-                return None
-            if self._expire_queued(item):
-                return None
-            if (not eng.free_slots()
-                    or not eng.kv_pool.can_alloc(need)) and eng.has_active():
-                self._safe_step(waiting)
-                self._evict_expired(waiting)
-                continue
-            try:
-                t_busy = time.perf_counter()
-                try:
-                    eng.import_request(pkt)
-                finally:
-                    self._busy_s += time.perf_counter() - t_busy
-            except PoolExhausted as e:
-                if eng.has_active():
-                    # blocks free as decode retires rows — try again
-                    self._safe_step(waiting)
-                    self._evict_expired(waiting)
-                    continue
-                item.error = e
-                item.done.set()
-                return None
-            except EngineStateLost as e:
-                # the donated import died and the engine reset: this
-                # item re-enters as a plain resubmission — prompt + the
-                # tokens the prefill side already emitted re-prefill
-                # HERE through the fold path, streams byte-identical
-                item.migrate = None
-                self._handle_reset(
-                    e, waiting, extra=[item],
-                    emitted={item.request_id: list(pkt["tokens"])},
-                )
-                return None
-            except BaseException as e:  # noqa: BLE001
-                item.error = e
-                item.done.set()
-                return None
-            item.migrate = None  # imported: a later reset resubmits by prompt
-            waiting[item.request_id] = item
-            return None
-        return item  # stopping mid-wait: hand back like the admit loop
 
     def _fold_emitted(self, it: "_Pending", toks: List[int]) -> None:
         """Fold already-emitted tokens into a request about to resubmit:
@@ -4436,4 +4038,3 @@ class _Pending:
     goodput: Optional[Dict] = None  # ledger attribution (chip_ms/cost/spec)
     spec_seen: bool = False  # verify windows judged drafts for this request
     tenant: Optional[str] = None  # edge-interned tenant (complete stamp)
-    migrate: Optional[Dict] = None  # disagg: migration packet (in or out)

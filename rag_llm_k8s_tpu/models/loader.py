@@ -84,7 +84,7 @@ def _quantize_np(arr: np.ndarray, axis: int):
     whole-tensor pass holds ~3 fp32 copies (cast + |w| + rounded quotient),
     which for a 70B lm_head (2.1 GiB bf16) is a ~13 GiB spike that defeats
     the streaming loader's whole memory contract (caught by
-    tests/test_loader_70b.py's transient bound)."""
+    tests/test_loader_8b.py's transient bound)."""
     if arr.ndim == 3:
         assert axis == 1
         out_q = np.empty(arr.shape, np.int8)

@@ -181,21 +181,6 @@ EVENTS: Dict[str, str] = {
                           "approx — the approximations the divergence is "
                           "attributed to); a second one inside the burst "
                           "window spools an incident bundle",
-    # -- disaggregated pools + router (engine/continuous.py,
-    #    server/router.py) --------------------------------------------------
-    "route_decision": "the front-tier router picked replicas for a request "
-                      "(prefill/decode targets, mode: disagg | unified, "
-                      "affinity score and affinity_hit, candidates "
-                      "considered) — flightview --router aggregates these "
-                      "into the affinity hit rate",
-    "migrate_begin": "a prefill-role engine exported a request's pool "
-                     "blocks for hand-off to a decode-role engine (blocks, "
-                     "kv_len; every exported block is released on the "
-                     "prefill side before the event returns)",
-    "migrate_done": "a decode-role engine imported a migrated request into "
-                    "a fresh row (slot, blocks, kv_len) — decode continues "
-                    "the same (seed, position) sampling sequence, so the "
-                    "stream is byte-identical to a unified run",
     # -- resilience (resilience/) ----------------------------------------
     "shed": "request rejected at the admission gate (reason, status)",
     "deadline": "a request's end-to-end deadline expired (stage)",

@@ -2,11 +2,10 @@
 
 The obs stack up to PR 11 says *what happened* (metrics/traces), *when to
 care* (SLO burn), and *why* (the flight journal) — but nothing measures
-how efficiently the chips were USED. ROADMAP item 3 (disaggregated
-prefill/decode pools + affinity router) is gated on NinjaLLM's cost
-framing — tokens/s/$ under concurrency, not per-chip peak — and splitting
-prefill (MFU-bound) from decode (bandwidth-bound) into separately-scaled
-pools first needs telemetry that proves where chip-seconds actually go.
+how efficiently the chips were USED. NinjaLLM's cost framing is
+tokens/s/$ under concurrency, not per-chip peak, and that needs telemetry
+that proves where chip-seconds actually go: prefill (MFU-bound) or decode
+(bandwidth-bound), useful lanes or padding.
 
 This module is that substrate:
 
@@ -20,7 +19,7 @@ This module is that substrate:
   the chip's ridge point) and yields per-window MFU / bandwidth-
   utilization estimates. MFU here credits only REAL token lanes —
   padding lanes execute but earn nothing, so ``mfu × peak`` reads as
-  useful-work throughput, the router's capacity signal.
+  useful-work throughput (``GET /debug/goodput``, docs/GOODPUT.md).
 - :class:`GoodputLedger` — the engine-side step ledger. The engines call
   ``record_*`` once per device sync window (scheduler/dispatcher thread
   only); each call updates the rolling per-category chip-second totals,
@@ -930,10 +929,10 @@ def state_from_events(events: Sequence[Dict]) -> Dict:
 
 
 def render_report(state: Dict, chip_hour_usd: float = 0.0) -> Dict:
-    """The capacity picture the future disaggregation router consumes —
-    ONE renderer for both sources (live ledger state, offline journal
-    reconstruction), so ``GET /debug/goodput`` and ``flightview
-    --goodput`` cannot drift apart."""
+    """The capacity picture (docs/GOODPUT.md): ONE renderer for both
+    sources (live ledger state, offline journal reconstruction), so
+    ``GET /debug/goodput`` and ``flightview --goodput`` cannot drift
+    apart."""
     busy = float(state.get("busy_s", 0.0))
     wall = max(float(state.get("wall_s", 0.0)), busy)
     idle = max(0.0, wall - busy)

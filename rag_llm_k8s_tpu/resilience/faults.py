@@ -65,15 +65,9 @@ __all__ = [
 #                  block assembly in engine/continuous.py): a failed splice
 #                  must fall back to recompute-from-tokens (cache) or the
 #                  buffer-scatter path (pool) and leak zero blocks/entries.
-#   migrate      — a prefill→decode pool-block hand-off landing on the
-#                  decode-role engine (engine/continuous.py import_request):
-#                  fires inside the donated region, so the decode engine
-#                  resets (EngineStateLost) and the scheduler re-prefills
-#                  prompt+emitted there — streams stay byte-identical and
-#                  neither engine leaks a block (docs/ROUTER.md).
 SITES = (
     "store_lookup", "embed", "insert", "decode_step", "generate",
-    "lookahead_retrieve", "kv_swap_in", "chunk_splice", "migrate",
+    "lookahead_retrieve", "kv_swap_in", "chunk_splice",
 )
 
 ENV_VAR = "TPU_RAG_FAULTS"

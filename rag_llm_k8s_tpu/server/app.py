@@ -918,7 +918,7 @@ class RagService:
         reg.gauge(
             "rag_goodput_busy_frac",
             "busy / wall chip time since the ledger started (1 - this is "
-            "the idle fraction the disaggregation router wants to shrink)",
+            "the idle fraction)",
             fn=lambda: self._goodput_stats().get("busy_frac", 0.0),
         )
         gp_mfu = reg.labeled_gauge(
@@ -3410,11 +3410,10 @@ class WsgiApp:
         """The goodput/cost capacity picture (obs/goodput.py,
         docs/GOODPUT.md): per-category chip-time split, roofline
         classification + rolling MFU per executable kind, and
-        cost-per-query percentiles — the report the future
-        prefill/decode disaggregation router consumes. Same
-        403-unless-armed contract as every ``/debug`` route;
-        ``scripts/flightview.py --goodput`` renders the same report
-        offline from a journal or incident bundle."""
+        cost-per-query percentiles. Same 403-unless-armed contract as
+        every ``/debug`` route; ``scripts/flightview.py --goodput``
+        renders the same report offline from a journal or incident
+        bundle."""
         if not self._debug_enabled():
             return self._debug_forbidden()
         try:

@@ -215,39 +215,6 @@ def plan_mixed_window(
 
 
 # ----------------------------------------------------------------------
-# disaggregated pool sizing
-# ----------------------------------------------------------------------
-
-def pool_split(
-    prefill_chip_s: float,
-    decode_chip_s: float,
-    span_s: float,
-    target_util: float = 0.6,
-    min_each: int = 1,
-) -> Dict[str, float]:
-    """How many prefill-role vs decode-role replicas a recorded load
-    needs: each category's chip-seconds over the trace span, divided by
-    the per-replica busy budget ``span_s * target_util``, rounded up,
-    floored at ``min_each`` (an empty decode tier strands every migration
-    packet; an empty prefill tier admits nothing). Returns the counts
-    plus ``prefill_util``/``decode_util`` — each tier's busy fraction AT
-    the returned count, the sanity read that the plan is neither
-    saturated nor idle. Pure arithmetic so the capacity question is
-    answerable on a bare-stdlib host from a journal alone."""
-    span = max(float(span_s), 1e-9)
-    budget = span * min(1.0, max(1e-6, float(target_util)))
-    floor = max(1, int(min_each))
-    n_pre = max(floor, int(-(-float(prefill_chip_s) // budget)))
-    n_dec = max(floor, int(-(-float(decode_chip_s) // budget)))
-    return {
-        "prefill": n_pre,
-        "decode": n_dec,
-        "prefill_util": round(float(prefill_chip_s) / (n_pre * span), 6),
-        "decode_util": round(float(decode_chip_s) / (n_dec * span), 6),
-    }
-
-
-# ----------------------------------------------------------------------
 # resubmission (reset recovery / pool-preemption resume)
 # ----------------------------------------------------------------------
 

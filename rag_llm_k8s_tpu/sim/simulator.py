@@ -484,81 +484,6 @@ class SimEngine:
 
 
 # ----------------------------------------------------------------------
-# disaggregated pool sizing (pool-role awareness)
-# ----------------------------------------------------------------------
-
-#: executable kinds that are pure prefill work vs pure decode work; the
-#: mixed kinds split per-window by their stamped decode-token share
-_PREFILL_KINDS = frozenset({"prefill", "prefill_px"})
-_DECODE_KINDS = frozenset({"decode", "verify"})
-
-
-def split_chip_time(events: Iterable[Dict]) -> Dict[str, float]:
-    """Walk a flight journal (real or synthetic) and attribute every
-    ``goodput_window``'s duration to the prefill or the decode side of a
-    disaggregated deployment. Pure-prefill and pure-decode kinds map
-    whole; ``oneshot``/``mixed`` windows (which carry both phases in one
-    dispatch) split by their ``decode_tokens``/``tokens`` ratio — the
-    same stamps ``state_from_events`` reads, so no model config is
-    needed offline. Returns ``{"prefill_s", "decode_s", "span_s"}``
-    (span = journal timestamp extent, floored at total busy time)."""
-    pre_s = dec_s = busy_s = 0.0
-    t_lo = t_hi = None
-    for e in events:
-        if not isinstance(e, dict):
-            continue
-        t = e.get("t")
-        if t is not None:
-            t_lo = t if t_lo is None else min(t_lo, t)
-            t_hi = t if t_hi is None else max(t_hi, t)
-        if e.get("type") != "goodput_window":
-            continue
-        dur = float(e.get("dur_ms", 0.0)) / 1e3
-        if dur <= 0:
-            continue
-        busy_s += dur
-        kind = e.get("kind", "decode")
-        if kind in _PREFILL_KINDS:
-            pre_s += dur
-        elif kind in _DECODE_KINDS:
-            dec_s += dur
-        else:  # oneshot / mixed: both phases in one window
-            tokens = float(e.get("tokens", 0.0))
-            dfrac = (float(e.get("decode_tokens", 0.0)) / tokens
-                     if tokens > 0 else 0.5)
-            dfrac = min(1.0, max(0.0, dfrac))
-            dec_s += dur * dfrac
-            pre_s += dur * (1.0 - dfrac)
-    span = 0.0 if t_lo is None else float(t_hi) - float(t_lo)
-    return {
-        "prefill_s": round(pre_s, 9),
-        "decode_s": round(dec_s, 9),
-        "span_s": round(max(span, busy_s, 1e-9), 9),
-    }
-
-
-def pool_plan(events: Iterable[Dict], target_util: float = 0.6,
-              span_s: Optional[float] = None, min_each: int = 1) -> Dict:
-    """The offline answer to "how many prefill vs decode replicas does
-    this trace need?": split the journal's chip time by phase
-    (:func:`split_chip_time`), then size each tier with
-    ``policy.pool_split``. Works on any flight journal — a live
-    deployment's, or a ``simulate()`` run's synthetic one, which is the
-    capacity-planning loop: record once, re-simulate the load shape
-    you expect, read the split. ``span_s`` overrides the journal's
-    timestamp extent (e.g. the wall duration a trace was recorded
-    over). Returns the split inputs plus the sized plan."""
-    split = split_chip_time(events)
-    span = float(span_s) if span_s is not None else split["span_s"]
-    plan = policy.pool_split(
-        split["prefill_s"], split["decode_s"], span,
-        target_util=target_util, min_each=min_each,
-    )
-    return {**split, "span_s": round(span, 9),
-            "target_util": float(target_util), **plan}
-
-
-# ----------------------------------------------------------------------
 # the top-level run
 # ----------------------------------------------------------------------
 
@@ -593,10 +518,6 @@ def simulate(trace, engine: Optional[SimEngine] = None, retries: int = 1,
         "steps_per_s": round(eng.decode_steps / virtual_s, 4),
         "tokens_out": sum(len(v) for v in results.values()),
         "report": _goodput.render_report(state, eng.chip_hour_usd),
-        # disaggregated sizing: how many prefill- vs decode-role replicas
-        # this load needs at 60% target busy (re-plan at a different
-        # target with pool_plan(result["journal"], target_util=...))
-        "pool_plan": pool_plan(eng.journal),
         # per-tenant cost split (tracegen traces carry tenant mixes): the
         # SAME renderer /debug/tenants and flightview --tenants use, so
         # "which tenant pays for the next replica" is answerable offline

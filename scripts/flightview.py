@@ -434,123 +434,6 @@ def render_tenant_ascii(report: Dict) -> str:
     return "\n".join(lines)
 
 
-def _pct(vals: List[float], q: float) -> float:
-    if not vals:
-        return 0.0
-    s = sorted(vals)
-    idx = min(len(s) - 1, int(round(q * (len(s) - 1))))
-    return s[idx]
-
-
-def build_router_report(events: List[Dict]) -> Dict:
-    """The router's offline scorecard, rebuilt from ``route_decision`` /
-    ``migrate_begin`` / ``migrate_done`` events: did affinity routing
-    actually hit (fraction of decisions whose chosen replica already held
-    some of the request's chunks), how the disagg/unified split landed
-    per replica, and what migration cost (export and import device time
-    from the events' own duration stamps; end-to-end hand-off latency
-    from the begin→done timestamp pair per request)."""
-    decisions = 0
-    modes: Dict[str, int] = {}
-    hits = 0
-    affinities: List[float] = []
-    per_replica: Dict[str, Dict[str, int]] = {}
-    export_ms: List[float] = []
-    import_ms: List[float] = []
-    begin_t: Dict[int, float] = {}
-    e2e_ms: List[float] = []
-    migrated_blocks = 0
-    for e in events:
-        et = e.get("type")
-        a = _attrs(e)
-        if et == "route_decision":
-            decisions += 1
-            modes[a.get("mode", "?")] = modes.get(a.get("mode", "?"), 0) + 1
-            if a.get("affinity_hit"):
-                hits += 1
-            affinities.append(float(a.get("affinity", 0.0)))
-            for role_key in ("prefill", "decode"):
-                name = a.get(role_key)
-                if name:
-                    pr = per_replica.setdefault(
-                        name, {"prefill": 0, "decode": 0}
-                    )
-                    pr[role_key] += 1
-        elif et == "migrate_begin":
-            if "duration_ms" in a:
-                export_ms.append(float(a["duration_ms"]))
-            migrated_blocks += int(a.get("blocks", 0))
-            if e.get("rid") is not None and e.get("t") is not None:
-                begin_t[e["rid"]] = float(e["t"])
-        elif et == "migrate_done":
-            if "duration_ms" in a:
-                import_ms.append(float(a["duration_ms"]))
-            t0 = begin_t.pop(e.get("rid"), None)
-            if t0 is not None and e.get("t") is not None:
-                e2e_ms.append((float(e["t"]) - t0) * 1e3)
-    return {
-        "decisions": decisions,
-        "modes": modes,
-        "affinity": {
-            "hit_rate": round(hits / decisions, 6) if decisions else 0.0,
-            "mean": round(sum(affinities) / len(affinities), 6)
-            if affinities else 0.0,
-            "p50": round(_pct(affinities, 0.50), 6),
-        },
-        "per_replica": per_replica,
-        "migrations": {
-            "begun": len(export_ms),
-            "completed": len(import_ms),
-            # an unmatched begin is a hand-off that died mid-flight (the
-            # chaos path: decode import reset, request re-prefilled)
-            "unmatched": len(begin_t),
-            "blocks_moved": migrated_blocks,
-            "export_ms": {"p50": round(_pct(export_ms, 0.50), 3),
-                          "p95": round(_pct(export_ms, 0.95), 3)},
-            "import_ms": {"p50": round(_pct(import_ms, 0.50), 3),
-                          "p95": round(_pct(import_ms, 0.95), 3)},
-            "handoff_ms": {"p50": round(_pct(e2e_ms, 0.50), 3),
-                           "p95": round(_pct(e2e_ms, 0.95), 3)},
-        },
-    }
-
-
-def render_router_ascii(report: Dict) -> str:
-    aff = report["affinity"]
-    mig = report["migrations"]
-    lines = [
-        "router report",
-        f"  decisions={report['decisions']}  modes=" + "  ".join(
-            f"{k}={v}" for k, v in sorted(report["modes"].items())
-        ),
-        f"  affinity: hit_rate={aff['hit_rate']:.4f}"
-        f"  mean={aff['mean']:.4f}  p50={aff['p50']:.4f}",
-        "  per replica (times chosen):",
-    ]
-    for name, v in sorted(report["per_replica"].items()):
-        lines.append(
-            f"    {name:<20} prefill={v['prefill']:<6}"
-            f" decode={v['decode']}"
-        )
-    lines.append(
-        f"  migrations: begun={mig['begun']}  completed={mig['completed']}"
-        f"  unmatched={mig['unmatched']}  blocks={mig['blocks_moved']}"
-    )
-    lines.append(
-        f"    export_ms  p50={mig['export_ms']['p50']}"
-        f"  p95={mig['export_ms']['p95']}"
-    )
-    lines.append(
-        f"    import_ms  p50={mig['import_ms']['p50']}"
-        f"  p95={mig['import_ms']['p95']}"
-    )
-    lines.append(
-        f"    handoff_ms p50={mig['handoff_ms']['p50']}"
-        f"  p95={mig['handoff_ms']['p95']}"
-    )
-    return "\n".join(lines)
-
-
 def render_quality_ascii(report: Dict) -> str:
     a = report["audits"]
     lines = [
@@ -642,12 +525,6 @@ def main(argv=None) -> int:
                     help="render the per-tenant attribution report rebuilt "
                          "from the journal's arrival/complete/shed/"
                          "shadow_audit events instead of the lifecycle view")
-    ap.add_argument("--router", action="store_true",
-                    help="render the disaggregation router scorecard "
-                         "rebuilt from the journal's route_decision/"
-                         "migrate_begin/migrate_done events: affinity "
-                         "hit rate, per-replica routing split, migration "
-                         "latency percentiles")
     ap.add_argument("--chip-hour-usd", type=float, default=0.0,
                     help="chip rental price for the --goodput/--tenants "
                          "cost figures (defaults to 0: attribution only, "
@@ -696,13 +573,6 @@ def main(argv=None) -> int:
                 diff, args.bundle, args.replay_diff
             ))
         return 0 if diff["identical"] else 1
-    if args.router:
-        report = build_router_report(events)
-        if args.as_json:
-            print(json.dumps(report, indent=1))
-        else:
-            print(render_router_ascii(report))
-        return 0
     if args.quality:
         report = build_quality_report(events)
         if args.as_json:

@@ -85,11 +85,16 @@ def reference(params, tokens):
 
 
 def greedy_reference(params, prompt, n):
+    """The reference's greedy continuation. Sequences are padded on the right
+    to a multiple of 16, so the eager operations compile for a few lengths and
+    not for every one. No real position reads a pad: it attends to nothing
+    behind it in its own window (causal), and the chunk a pad completes lies
+    in the last real position's window or a later one, while a query sees the
+    summaries of EARLIER windows only (the reference's rule 4)."""
     tokens = list(prompt)
     for _ in range(n):
-        # a pad behind the sequence changes nothing in front of it (causal),
-        # but completes chunks: pad with whole windows only past the end
-        tokens.append(int(np.argmax(head0(ref.forward(params, CFG, tokens))[-1])))
+        padded = tokens + [0] * (-len(tokens) % 16)
+        tokens.append(int(np.argmax(head0(ref.forward(params, CFG, padded))[len(tokens) - 1])))
     return tokens[len(prompt):]
 
 

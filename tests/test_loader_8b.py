@@ -35,6 +35,17 @@ def synth_dir(tmp_path_factory):
     return str(out)
 
 
+def assert_streamed(got):
+    """The TRANSIENT host overhead of a probed load, above its final resident
+    set, is a few vocab-sized tensors and not the checkpoint."""
+    embed_bytes = CFG_8B_L2.vocab_size * CFG_8B_L2.hidden_size * 2
+    transient = got["peak"] - max(got["rss_after"], got["peak_before"])
+    assert transient < 3 * embed_bytes + 512 * (1 << 20), (
+        f"transient host overhead {transient / GB:.2f} GB suggests the "
+        f"loader materialized more than a streamed group"
+    )
+
+
 class TestStreaming8B:
     def test_tp_streamed_load_shapes_shardings_and_memory(self, synth_dir):
         """Stream the 4-shard checkpoint onto the 8-device mesh: every tensor
@@ -79,13 +90,48 @@ class TestStreaming8B:
         # claim is about the TRANSIENT above the final resident set: at most
         # a couple of vocab-sized tensors (embed read + lm_head transpose),
         # never the multi-GB whole-checkpoint spike from_pretrained makes.
-        embed_bytes = c.vocab_size * c.hidden_size * 2
-        transient = got["peak"] - max(got["rss_after"], got["peak_before"])
         assert got["peak"] > got["peak_before"]  # the load is what set the high-water mark
-        assert transient < 3 * embed_bytes + 512 * (1 << 20), (
-            f"transient host overhead {transient / GB:.2f} GB suggests the "
-            f"loader materialized more than a streamed group"
-        )
+        assert_streamed(got)
+
+    def test_int8_streamed_load_is_sharded_and_quantized(self, synth_dir):
+        """The int8 deployment mode (`quant="int8"`) over the same checkpoint:
+        tensors must arrive TP-sharded in the quantized layout without the
+        bf16 tree ever materializing, and the loaded tree must run a forward.
+        A property of the loader, so it is shown at the widths this file
+        already streams (it stood at one 70B layer until PR 57)."""
+        c = CFG_8B_L2
+        got = loader_probe.probe(synth_dir, "llama_3_1_8b", 2, quant="int8", forward_tokens=4)
+        leaves = got["leaves"]
+        wq = {k: leaves[f"layers/attn/wq/{k}"] for k in ("kernel_q", "qscale")}
+        assert wq["kernel_q"]["dtype"] == "int8"
+        assert wq["kernel_q"]["shape"] == [2, c.hidden_size, c.num_heads * c.head_dim]
+        assert "tp" in wq["kernel_q"]["spec"]
+        assert wq["qscale"]["dtype"] == "float32"
+        assert leaves["layers/mlp/w_gate/kernel_q"]["shape"] == [2, c.hidden_size, c.intermediate_size]
+        # EVERY projection group must be quantized — a per-group dtype check
+        # (the byte bound alone can't see one small group slipping to bf16)
+        for grp, names in (("attn", ("wq", "wk", "wv", "wo")),
+                           ("mlp", ("w_gate", "w_up", "w_down"))):
+            for name in names:
+                sub = f"layers/{grp}/{name}"
+                assert leaves[f"{sub}/kernel_q"]["dtype"] == "int8", (grp, name)
+                assert leaves[f"{sub}/qscale"]["dtype"] == "float32", (grp, name)
+                assert f"{sub}/kernel" not in leaves, (grp, name)
+        assert leaves["lm_head_q"]["dtype"] == "int8"  # 8B is untied
+        assert leaves["embedding"]["dtype"] == "bfloat16"  # gather-only
+        # int8 halves the placed bytes of everything but the embedding
+        # (bf16 by design): ~1.87 GiB against the ~2.77 GiB bf16 tree. The
+        # bound must sit BELOW the bf16 figure or a silently-skipped
+        # quantization of the head or the MLPs would still pass.
+        dev_bytes = sum(x["nbytes"] for x in leaves.values())
+        assert dev_bytes < 2.2 * GB, f"{dev_bytes / GB:.2f} GiB"
+
+        # streaming claim, as for bf16 above
+        assert_streamed(got)
+
+        # the loaded quantized tree must drive a forward end to end
+        assert got["logits_shape"] == [1, 4, c.vocab_size]
+        assert got["logits_finite"]
 
     def test_loaded_tree_runs_a_forward(self, synth_dir, mesh_tp8):
         """The placed 8B-shaped tree must actually execute one sharded

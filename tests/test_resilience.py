@@ -541,83 +541,6 @@ class TestResetRecovery:
             sched.shutdown()
 
 
-class TestMigrationChaos:
-    """ISSUE 20 chaos contract: a fault INSIDE the migration import's
-    donated region resets the decode-role engine (EngineStateLost); the
-    scheduler re-prefills the packet's prompt + already-emitted tokens
-    there, so the client stream stays byte-identical to a unified run —
-    seeded, not just greedy, because every draw is (seed, position)
-    keyed — and NEITHER engine leaks a block (the prefill engine already
-    released the row at export; the decode engine's reset returns the
-    partially-donated blocks)."""
-
-    PAGED = EngineConfig(
-        prompt_buckets=(16, 32), max_batch_size=4, max_seq_len=64,
-        kv_paged=True, kv_block_size=16,
-    )
-    PROMPTS = [[5, 6, 7, 8, 9, 10, 11], [12, 13, 14], [3] * 20]
-
-    def test_mid_migration_reset_recovers_byte_identical(self, tiny):
-        import dataclasses
-
-        cfg, params, _ = tiny
-        seeded = SamplingConfig(do_sample=True, temperature=0.8,
-                                max_new_tokens=8)
-        uni = ContinuousScheduler(
-            ContinuousEngine(cfg, params, sampling=seeded,
-                             engine_config=self.PAGED, dtypes=FP32),
-            retry_backoff_s=0.0,
-        )
-        try:
-            base = [uni.submit(p, seed=50 + i)
-                    for i, p in enumerate(self.PROMPTS)]
-        finally:
-            uni.shutdown()
-        pre = ContinuousScheduler(
-            ContinuousEngine(
-                cfg, params, sampling=seeded,
-                engine_config=dataclasses.replace(
-                    self.PAGED, pool_role="prefill"
-                ),
-                dtypes=FP32,
-            ),
-            retry_backoff_s=0.0,
-        )
-        dec = ContinuousScheduler(
-            ContinuousEngine(
-                cfg, params, sampling=seeded,
-                engine_config=dataclasses.replace(
-                    self.PAGED, pool_role="decode"
-                ),
-                dtypes=FP32,
-            ),
-            retry_backoff_s=0.0,
-        )
-        try:
-            got = []
-            for i, p in enumerate(self.PROMPTS):
-                if i == 1:  # fault fires mid-import, inside donation
-                    faults.arm("migrate", times=1)
-                info = {}
-                toks = pre.submit(p, seed=50 + i, info=info, timeout=120)
-                pkt = info.get("migrate_packet")
-                got.append(
-                    dec.submit_migrated(pkt, timeout=120)
-                    if pkt is not None else toks
-                )
-            assert faults.armed() == {}, "migrate fault never fired"
-            assert got == base
-            assert pre.engine.kv_pool.blocks_in_use() == 0, (
-                pre.engine.kv_pool.stats()
-            )
-            assert dec.engine.kv_pool.blocks_in_use() == 0, (
-                dec.engine.kv_pool.stats()
-            )
-        finally:
-            pre.shutdown()
-            dec.shutdown()
-
-
 class TestSchedulerLifecycle:
     @pytest.mark.filterwarnings(
         "ignore::pytest.PytestUnhandledThreadExceptionWarning"

@@ -506,203 +506,6 @@ def test_latent_moe_generate_program_compiles_with_its_kernels(one_chip, uncache
         assert kernel in text, f"{kernel}: not in the compiled program"
 
 
-def test_windowed_moe_generate_program_compiles_with_its_kernels(one_chip, uncached):
-    """The third decoder family's batched generate program, at toy widths but
-    the published attention geometry (72 and 48 query heads over 8 KV heads of
-    128, window 512 under a 4096 bucket, a batch that goes a row at a time),
-    through the Pallas path: the windowed and the full flash prefill, the
-    decode kernel on both layer kinds and the grouped expert matmul all lower
-    for the chip inside one program. The windowed prefill is the ONE-STEP
-    kernel at ``[72, 4096, 128]`` (``flash_window_step``: 1152 rows against a
-    slice of 640 keys beside the resident strips, which ``_flash_fits`` reckons
-    at 12 bytes a key), inside the default scoped VMEM."""
-    from rag_llm_k8s_tpu.core.config import (
-        DTypePolicy, EngineConfig, GoodputConfig, SamplingConfig, WindowedMoEConfig,
-    )
-    from rag_llm_k8s_tpu.engine import engine as engine_mod
-    from rag_llm_k8s_tpu.models.windowed_moe import init_windowed_moe_params
-    kinds = ("full_attention", "sliding_attention", "sliding_attention", "full_attention")
-    cfg = WindowedMoEConfig.tiny(
-        vocab_size=512, hidden_size=256, intermediate_size=512, moe_intermediate_size=128,
-        shared_expert_intermediate_size=128, num_kv_heads=K, head_dim=HD, sliding_window=512,
-        layer_types=kinds, num_attention_heads_per_layer=(48, 72, 72, 48),
-        mlp_layer_types=("dense", "sparse", "sparse", "sparse"), max_seq_len=8192)
-    dt = DTypePolicy()
-    shapes = jax.eval_shape(lambda: init_windowed_moe_params(jax.random.PRNGKey(0), cfg, dt))
-    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
-    ec = EngineConfig(prompt_buckets=(4096,), max_seq_len=T, attn_impl="pallas", speculative="off",
-                      goodput=GoodputConfig(enabled=False))
-    eng = engine_mod.InferenceEngine(
-        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=8),
-        engine_config=ec, dtypes=dt)
-    fn = eng._make_gen(4, 4096, 8)
-    tok = jax.ShapeDtypeStruct((4, 4096), I32, sharding=one_chip)
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    assert A.flash_window_step(4096, 72 // K, HD, HD, cfg.sliding_window) == (128, 640)
-    built = []
-    one_step = A._window_call
-    A._window_call = lambda qt, *a, **kw: built.append(qt.shape) or one_step(qt, *a, **kw)
-    try:
-        text = jax.jit(fn).lower(params, tok, tok, rng).compile().as_text()
-    finally:
-        A._window_call = one_step
-    for kernel in ("%flash_attention_window", "%flash_attention.", "%decode_attention", "%grouped_matmul"):
-        assert kernel in text, f"{kernel}: not in the compiled program"
-    # a row at a time: the call the benchmark's roofline reader finds, built by
-    # the one-step form, and no kernel of the program asks for more scoped VMEM
-    assert built and set(built) == {(72, 4096, HD)}, built
-    assert re.search(rf"%flash_attention_window(\.\d+)? = bf16\[72,4096,{HD}\]\S* custom-call\(", text)
-    assert "scoped_memory_configs" in text and '"scoped_memory_configs":[{' not in text
-
-
-def test_block_window_programs_compile_with_their_kernels(one_chip, uncached):
-    """The fourth decoder family's five one-shot programs, at toy widths but
-    the published attention geometry (heads of 128, windows of 2048 positions
-    in chunks of 16, a 4096 bucket: two windows), through the Pallas path: the
-    bucketed prefill (the window-and-summaries kernel) with the decode loop
-    (the decode walk over the joined ring-and-summary plane), the verify loop,
-    a prompt chunked past the largest bucket and the exact scorer (the XLA
-    chunk form over the plane) all lower for the chip."""
-    from rag_llm_k8s_tpu.core.config import (
-        BlockWindowConfig, DTypePolicy, EngineConfig, GoodputConfig, SamplingConfig,
-    )
-    from rag_llm_k8s_tpu.engine import engine as engine_mod
-    from rag_llm_k8s_tpu.models.block_window import init_block_window_params
-    cfg = BlockWindowConfig(vocab_size=320, hidden_size=256, intermediate_size=512, num_hidden_layers=2,
-                            num_attention_heads=2, num_key_value_heads=2, max_seq_len=16384)
-    dt = DTypePolicy()
-    shapes = jax.eval_shape(lambda: init_block_window_params(jax.random.PRNGKey(0), cfg, dt))
-    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
-    ec = EngineConfig(prompt_buckets=(4096,), max_seq_len=4096 + 128, attn_impl="pallas", speculative="prompt_lookup",
-                      goodput=GoodputConfig(enabled=False), max_chunked_prompt=8192)
-    eng = engine_mod.InferenceEngine(
-        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=8),
-        engine_config=ec, dtypes=dt)
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-
-    def tok(B, S):
-        return jax.ShapeDtypeStruct((B, S), I32, sharding=one_chip)
-
-    def compiled(fn, *args):
-        return jax.jit(fn).lower(params, *args).compile().as_text()
-
-    text = compiled(eng._make_gen(2, 4096, 8), tok(2, 4096), tok(2, 4096), rng)
-    for kernel in ("%window_summary_flash_attention", "%ring_summary_decode_attention", "%chunk_pool.", "%chunk_pool_in_place"):
-        assert kernel in text, f"{kernel}: not in the batched generate program"
-    assert "%decode_attention" not in text  # the walk carries the family's name here
-    text = compiled(eng._make_gen_spec(4096, 8), tok(1, 4096), tok(1, 4096), rng)
-    assert "%window_summary_flash_attention" in text and "tpu_custom_call" in text
-    text = compiled(eng._make_gen(1, 8192, 8, 4096), tok(1, 8192), tok(1, 8192), rng)
-    assert "%ring_summary_decode_attention" in text  # chunks through the ring, then the decode walk
-    score, avals = eng._build_score_exact(4096 + 256, 256)
-    assert score.lower(params, *avals[1:]).compile() is not None
-
-
-def test_hybrid_ssm_programs_compile_with_their_kernels(one_chip, uncached):
-    """The fifth decoder family's five one-shot programs, at the published
-    mixer geometry (hidden 2560: 20 query heads over ONE KV head of 128, a
-    group no other family has; d_inner 5120, 16 states, dt rank 160) with a
-    narrow SwiGLU, a small vocabulary and one layer of each kind, through the
-    Pallas path: the bucketed prefill (the selective-scan kernel, the flash
-    kernel) with the decode loop (the decode walk at a group of 20), the
-    verify loop with ``commit`` (the XLA scan that keeps every position's
-    state), a prompt chunked past the largest bucket and the exact scorer
-    (the scan kernel from the state it is handed) all lower for the chip. The
-    scan kernel alone at the served batch: 8 rows of 4096."""
-    from rag_llm_k8s_tpu.core.config import (
-        DTypePolicy, EngineConfig, GoodputConfig, HybridSSMConfig, PrefixCacheConfig, SamplingConfig,
-    )
-    from rag_llm_k8s_tpu.engine import engine as engine_mod
-    from rag_llm_k8s_tpu.models.hybrid_ssm import init_hybrid_ssm_params
-    from rag_llm_k8s_tpu.ops import ssm
-    cfg = HybridSSMConfig(vocab_size=1024, intermediate_size=512, num_hidden_layers=2, attn_layer_period=2,
-                          attn_layer_offset=1, tie_word_embeddings=False)
-    dt = DTypePolicy()
-    shapes = jax.eval_shape(lambda: init_hybrid_ssm_params(jax.random.PRNGKey(0), cfg, dt))
-    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
-    ec = EngineConfig(prompt_buckets=(4096,), max_seq_len=4096 + 256, attn_impl="pallas", speculative="prompt_lookup",
-                      goodput=GoodputConfig(enabled=False), prefix_cache=PrefixCacheConfig(enabled=False),
-                      max_chunked_prompt=8192)
-    eng = engine_mod.InferenceEngine(
-        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=8), engine_config=ec, dtypes=dt)
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-
-    def tok(B, S):
-        return jax.ShapeDtypeStruct((B, S), I32, sharding=one_chip)
-
-    def compiled(fn, *args):
-        return jax.jit(fn).lower(params, *args).compile().as_text()
-
-    text = compiled(eng._make_gen(2, 4096, 8), tok(2, 4096), tok(2, 4096), rng)
-    for kernel in ("%selective_scan", "%flash_attention", "%decode_attention"):
-        assert kernel in text, f"{kernel}: not in the batched generate program"
-    text = compiled(eng._make_gen_spec(4096, 8), tok(1, 4096), tok(1, 4096), rng)
-    assert "%selective_scan" in text  # the prefill's; the verify steps' scan is XLA's
-    assert "f32[1,1,16,16,5120]" in text  # every fed position's state, kept for commit
-    text = compiled(eng._make_gen(1, 8192, 8, 4096), tok(1, 8192), tok(1, 8192), rng)
-    assert "%selective_scan" in text and "%decode_attention" in text
-    score, avals = eng._build_score_exact(4096 + 256, 256)
-    assert "%selective_scan" in score.lower(params, *avals[1:]).compile().as_text()
-
-    def aval(shape, dtype=BF16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    R, S, Di, N = 8, 4096, 5120, 16
-    seq, scalars = aval((R, S, Di)), aval((R, S, N), F32)
-    alone = jax.jit(ssm.selective_scan_pallas).lower(
-        seq, seq, seq, aval((N, Di), F32), scalars, scalars, aval((Di,), F32), aval((Di,), F32),
-        aval((R, N, Di), F32), aval((R,), I32)).compile()
-    assert "%selective_scan" in alone.as_text()
-    assert "f32[8,4096,16,5120]" not in alone.as_text()  # no state a position anywhere
-
-
-def test_conv_moe_programs_compile_with_their_kernels(one_chip, uncached):
-    """The sixth decoder family's batch-1 programs (what its one-caller cell
-    runs), at the published operator geometry (hidden 2048: 32 query heads over
-    8 KV heads of SIXTY-FOUR, a head no other decoder has; three taps; 64
-    experts, all held, top 4) with narrow FFNs, a small vocabulary and one
-    layer of each kind behind a dense one, through the Pallas path: the
-    bucketed prefill (the flash kernel at 64 lanes) with the decode loop, whose
-    single-token step takes the grouped chunk kernel (Mosaic refuses the decode
-    walk's copy out of a 64-lane plane: PERF.md section 7), the verify loop
-    with ``commit`` (the run of gated inputs kept for it), a prompt chunked
-    past the largest bucket and the exact scorer all lower for the chip."""
-    from rag_llm_k8s_tpu.core.config import (
-        ConvMoEConfig, DTypePolicy, EngineConfig, GoodputConfig, PrefixCacheConfig, SamplingConfig,
-    )
-    from rag_llm_k8s_tpu.engine import engine as engine_mod
-    from rag_llm_k8s_tpu.models.conv_moe import init_conv_moe_params
-    cfg = ConvMoEConfig(vocab_size=1024, intermediate_size=512, moe_intermediate_size=256,
-                        layer_types=("conv", "full_attention", "conv"), num_dense_layers=1,
-                        tie_word_embeddings=False)
-    dt = DTypePolicy()
-    shapes = jax.eval_shape(lambda: init_conv_moe_params(jax.random.PRNGKey(0), cfg, dt))
-    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
-    ec = EngineConfig(prompt_buckets=(4096,), max_seq_len=4096 + 256, attn_impl="pallas", speculative="prompt_lookup",
-                      goodput=GoodputConfig(enabled=False), prefix_cache=PrefixCacheConfig(enabled=False),
-                      max_chunked_prompt=8192)
-    eng = engine_mod.InferenceEngine(
-        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=8), engine_config=ec, dtypes=dt)
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-
-    def tok(B, S):
-        return jax.ShapeDtypeStruct((B, S), I32, sharding=one_chip)
-
-    def compiled(fn, *args):
-        return jax.jit(fn).lower(params, *args).compile().as_text()
-
-    text = compiled(eng._make_gen(1, 4096, 8), tok(1, 4096), tok(1, 4096), rng)
-    for kernel in ("%flash_attention", "%chunk_attention_grouped", "%grouped_matmul"):
-        assert kernel in text, f"{kernel}: not in the batch-1 generate program"
-    assert "%decode_attention" not in text  # a head of 64: the step is a chunk of one position
-    text = compiled(eng._make_gen_spec(4096, 8), tok(1, 4096), tok(1, 4096), rng)
-    assert "%chunk_attention_grouped" in text and "bf16[2,1,18,2048]" in text  # 2 + 16 gated inputs a conv layer, for commit
-    text = compiled(eng._make_gen(1, 8192, 8, 4096), tok(1, 8192), tok(1, 8192), rng)
-    assert "%chunk_prefill_attention" in text and "%chunk_attention_grouped" in text
-    score, avals = eng._build_score_exact(4096 + 256, 256)
-    assert "%grouped_matmul" in score.lower(params, *avals[1:]).compile().as_text()
-
-
 def test_hybrid_live_suffix_branches_copy_neither_stream_nor_stacked_weight(one_chip, uncached):
     """The batch-8 prefill of the hybrid state-space family at the 4096
     bucket, every width and all 28 layers as published (a small vocabulary):
@@ -869,117 +672,3 @@ def test_live_suffix_branches_copy_no_stacked_weight(batch, one_chip, uncached):
         if not computation.lstrip().startswith("ENTRY"):
             found = [line.strip()[:160] for line in computation.splitlines() if stacked_copy.search(line)]
             assert not found, found
-
-
-def test_delta_moe_programs_compile_with_their_kernels(one_chip, uncached):
-    """The seventh decoder family's batch-1 programs (what its one-caller cell
-    runs) at the published mixer geometry (hidden 2304 = 9 * 256, the first
-    width here that 512 does not divide; 32 heads of 128 on both kinds; four
-    taps; 256 experts of 1024 of which 16 are held, top 8) with a narrow dense
-    FFN, a small vocabulary and the pattern K | K K M, through the Pallas path:
-    the bucketed prefill (the chunked recurrence's kernel beside the latent
-    flash kernel at 32 heads; the grouped expert matmul at 768-wide tiles of 2304)
-    with the decode loop (the single-token step beside the absorbed decode
-    kernel), the verify loop with ``commit`` (the step's k, v, g and beta kept
-    for the replay; the state as it was), and the exact scorer all lower for
-    the chip; no program copies the float32 state stack."""
-    from rag_llm_k8s_tpu.core.config import (
-        DeltaMoEConfig, DTypePolicy, EngineConfig, GoodputConfig, PrefixCacheConfig, SamplingConfig,
-    )
-    from rag_llm_k8s_tpu.engine import engine as engine_mod
-    from rag_llm_k8s_tpu.models.delta_moe import init_delta_moe_params
-    cfg = DeltaMoEConfig(vocab_size=1024, intermediate_size=512, num_hidden_layers=4, kda_layers=(1, 2, 3),
-                         full_attn_layers=(4,), ep_size=16)
-    dt = DTypePolicy()
-    shapes = jax.eval_shape(lambda: init_delta_moe_params(jax.random.PRNGKey(0), cfg, dt))
-    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
-    ec = EngineConfig(prompt_buckets=(4096,), max_seq_len=4096 + 256, attn_impl="pallas", speculative="prompt_lookup",
-                      goodput=GoodputConfig(enabled=False), prefix_cache=PrefixCacheConfig(enabled=False))
-    eng = engine_mod.InferenceEngine(
-        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=8), engine_config=ec, dtypes=dt)
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    tok = jax.ShapeDtypeStruct((1, 4096), I32, sharding=one_chip)
-
-    def compiled(fn, *args):
-        return jax.jit(fn).lower(params, *args).compile().as_text()
-
-    state_copy = re.compile(r"= f32\[3,1,32,128,128\]\S* copy\(")
-    text = compiled(eng._make_gen(1, 4096, 8), tok, tok, rng)
-    for kernel in ("%delta_rule_chunked", "%mla_flash_attention", "%mla_decode_attention", "%grouped_matmul",
-                   "%route_topk"):
-        assert kernel in text, f"{kernel}: not in the batch-1 generate program"
-    assert "f32[1,32,128,128]" in text and " conditional(" in text and not state_copy.search(text)
-    # the bucket's recurrence is the kernel's: no triangular solve of a 64-position chunk is left
-    assert "f32[1,32,1,64,64]" not in text
-    text = compiled(eng._make_gen_spec(4096, 8), tok, tok, rng)
-    assert "f32[3,1,16,32,128]" in text  # sixteen fed positions' k, v and g a linear layer, for commit's replay
-    assert "f32[3,1,16,32,128,128]" not in text and not state_copy.search(text)  # and no state a position
-    score, avals = eng._build_score_exact(4096 + 256, 256)
-    assert "%grouped_matmul" in score.lower(params, *avals[1:]).compile().as_text()
-
-
-def test_cross_decoder_programs_compile_with_their_kernels(one_chip, uncached):
-    """The eighth decoder family's one-shot programs at the published mixer
-    geometry (hidden 2560: 40 query heads of 64 over 20 KV heads, served as 40
-    zero-padded heads of 128 over 10 pair heads, a group of 4; d_inner 5120,
-    16 states, dt rank 160) with a narrow SwiGLU, a small vocabulary and the
-    shallowest depth the rule derives (8 layers: two (Mamba, window) pairs,
-    the memory's layer, the full layer, one (memory unit, cross) pair), at the
-    cell's buckets, through the Pallas path: the fresh prompt call (the scan
-    kernel, gated and with its un-gated output; the windowed flash kernel in
-    its one-step form; the flash kernel; the cross-decoder's one position, a
-    decode walk INSIDE the prefill) with the decode loop, the verify loop
-    with ``commit``, and the exact scorer all lower for the chip. 11776 and
-    not 11264 is the lower bucket: there the full layer's flash call asks for
-    92 KB more scoped VMEM than Mosaic has (PERF.md section 7)."""
-    from rag_llm_k8s_tpu.core.config import (
-        CrossDecoderConfig, DTypePolicy, EngineConfig, GoodputConfig, PrefixCacheConfig, SamplingConfig,
-    )
-    from rag_llm_k8s_tpu.engine import engine as engine_mod
-    from rag_llm_k8s_tpu.models.cross_decoder import init_cross_decoder_params
-    from rag_llm_k8s_tpu.ops import ssm
-
-    cfg = CrossDecoderConfig(vocab_size=1024, intermediate_size=512, num_hidden_layers=8, tie_word_embeddings=False)
-    dt = DTypePolicy()
-    shapes = jax.eval_shape(lambda: init_cross_decoder_params(jax.random.PRNGKey(0), cfg, dt))
-    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
-    S = 13312
-    assert A.flash_window_step(S, 4, 128, 128, 512) == (64, 576)  # the window in one step: the strips are resident
-    ec = EngineConfig(prompt_buckets=(11776, S), max_seq_len=16384, max_batch_size=2, attn_impl="pallas",
-                      speculative="prompt_lookup", goodput=GoodputConfig(enabled=False),
-                      prefix_cache=PrefixCacheConfig(enabled=False))
-    eng = engine_mod.InferenceEngine(
-        cfg, params, sampling=SamplingConfig(do_sample=False, max_new_tokens=8), engine_config=ec, dtypes=dt)
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-
-    def tok(B, S):
-        return jax.ShapeDtypeStruct((B, S), I32, sharding=one_chip)
-
-    def compiled(fn, *args):
-        return jax.jit(fn).lower(params, *args).compile().as_text()
-
-    text = compiled(eng._make_gen(1, S, 8), tok(1, S), tok(1, S), rng)
-    for kernel in ("%selective_scan", "%flash_attention_window", "%flash_attention", "%decode_attention"):
-        assert kernel in text, f"{kernel}: not in the generate program"
-    assert re.search(rf"%flash_attention_window(\.\d+)? = bf16\[40,{S},128\]", text)  # what the roofline readers find
-    assert re.search(rf"%flash_attention(\.\d+)? = bf16\[40,{S},128\]", text)
-    assert re.search(r"%decode_attention(\.\d+)? = bf16\[1,10,4,128\]", text)
-    assert "f32[1,%d,16,5120]" % S not in text  # no state a position anywhere
-    text = compiled(eng._make_gen_spec(S, 8), tok(1, S), tok(1, S), rng)
-    assert "%selective_scan" in text  # the prefill's; the verify steps' scan is XLA's
-    assert "f32[3,1,16,16,5120]" in text  # every fed position's state of the three state layers, kept for commit
-    score, avals = eng._build_score_exact(S + 256, 256)
-    assert "%selective_scan" in score.lower(params, *avals[1:]).compile().as_text()
-
-    def aval(shape, dtype=BF16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    # the lower bucket's full-layer flash call fits the scoped VMEM (at 11264 it does not)
-    q, kv = aval((1, 11776, 40, 128)), aval((1, 11776, 10, 128))
-    assert "%flash_attention" in jax.jit(A.flash_attention).lower(q, kv, kv).compile().as_text()
-    R, Di, N = 1, 5120, 16
-    seq, scalars = aval((R, S, Di)), aval((R, S, N), F32)
-    alone = jax.jit(ssm.selective_scan_pallas, static_argnames=("ungated",)).lower(
-        seq, seq, seq, aval((N, Di), F32), scalars, scalars, aval((Di,), F32), aval((Di,), F32),
-        aval((R, N, Di), F32), aval((R,), I32), ungated=True).compile().as_text()
-    assert "%selective_scan" in alone and alone.count(f"bf16[1,{S},40,128]") >= 2  # y and, beside it, the memory
